@@ -22,6 +22,7 @@ python/ray/remote_function.py:41, python/ray/actor.py:602):
     ray.get(ref)
 """
 
+from ray_tpu import _compile_cache
 from ray_tpu._version import __version__
 from ray_tpu.core.api import (
     ObjectRef,
@@ -43,6 +44,8 @@ from ray_tpu.core.api import (
     shutdown,
     wait,
 )
+
+_compile_cache.configure()
 
 __all__ = [
     "__version__",

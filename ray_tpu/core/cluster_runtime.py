@@ -394,12 +394,11 @@ class ClusterRuntime:
             res = dict(resources)
             res.setdefault("CPU", float(num_cpus if num_cpus is not None
                                         else os.cpu_count() or 4))
+            from ray_tpu import accelerators
+
+            res = {**accelerators.detect_node_resources(), **res}
             if num_tpus is not None:
                 res["TPU"] = float(num_tpus)
-            else:
-                ntpu = _detect_tpu_chips()
-                if ntpu:
-                    res["TPU"] = float(ntpu)
             nodelet = Nodelet(head.address, res, labels=labels,
                               session_dir=session_dir,
                               store_capacity=store_capacity).start()
@@ -2806,23 +2805,3 @@ def json_stable(d) -> str:
     import json
 
     return json.dumps(d, sort_keys=True, default=str)
-
-
-def _detect_tpu_chips() -> int:
-    """TPU chip detection (reference: TPUAcceleratorManager,
-    python/ray/_private/accelerators/tpu.py:98-115 — /dev/accel* and
-    vfio device files)."""
-    import glob
-
-    n = len(glob.glob("/dev/accel*"))
-    if n == 0:
-        n = len(glob.glob("/dev/vfio/*")) - (1 if os.path.exists("/dev/vfio/vfio")
-                                             else 0)
-        n = max(0, n)
-    env = os.environ.get("RAY_TPU_NUM_CHIPS")
-    if env:
-        try:
-            n = int(env)
-        except ValueError:
-            pass
-    return n

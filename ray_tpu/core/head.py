@@ -329,10 +329,22 @@ class Head:
     def _monitor_loop(self):
         """Health checks (reference: gcs_health_check_manager.h:45 — the
         GCS probes nodes; here nodes push heartbeats and we age them)."""
+        last_tick = time.monotonic()
         while not self._stopped.wait(HEARTBEAT_INTERVAL_S):
             now = time.monotonic()
+            # A late tick means THIS process (or its whole VM) was
+            # paused — heartbeats that arrived meanwhile are still
+            # queued behind the same pause, so their age says nothing
+            # about the nodes. Credit every node with the pause instead
+            # of declaring it dead (a TPU worker opening its chips
+            # freezes a small VM for several seconds).
+            paused = now - last_tick - HEARTBEAT_INTERVAL_S
+            last_tick = now
             dead = []
             with self._lock:
+                if paused > 1.0:
+                    for nid in self._last_beat:
+                        self._last_beat[nid] += paused
                 for nid, info in self._nodes.items():
                     if info.alive and now - self._last_beat.get(nid, 0) > NODE_DEATH_AFTER_S:
                         info.alive = False

@@ -1,14 +1,11 @@
 """Bench trajectory index: merge every bench artifact the repo has
 accumulated into one chronological markdown table.
 
-The repo's bench history lives in heterogeneous JSON artifacts written
-by different eras of the bench driver: ``BENCH_r01..r05`` wrap the
-parsed result in ``{"n", "cmd", "rc", "tail", "parsed": {...}}``,
-``BENCH_r06``/``SERVE_BENCH``/``RL_BENCH`` put the primary metric at
-the top level with ``secondary_metrics``, ``CORE_BENCH`` is a flat
-``{scenario: {value, unit, baseline, vs_baseline}}`` map, and
-``MULTICHIP_r0N`` records dryrun gate outcomes. Nobody can read a
-trajectory off six shapes — this module can, and regenerates
+The repo's bench history lives in heterogeneous JSON artifacts:
+``BENCH_rNN``/``SERVE_BENCH``/``RL_BENCH`` put the primary metric at
+the top level with ``secondary_metrics``, and ``CORE_BENCH`` is a flat
+``{scenario: {value, unit, baseline, vs_baseline}}`` map. Nobody can
+read a trajectory off several shapes — this module can, and regenerates
 ``BENCH_INDEX.md`` from them:
 
     python -m ray_tpu.devtools.bench_report            # writes BENCH_INDEX.md
@@ -42,22 +39,16 @@ def _fmt(v) -> str:
 
 def _primary(doc: dict) -> dict | None:
     """The {metric, value, unit, vs_baseline, secondary_metrics} record
-    of a train/serve/rl bench doc, whichever era wrapped it."""
-    if isinstance(doc.get("parsed"), dict):
-        return doc["parsed"]
-    if "metric" in doc:
-        return doc
-    return None
+    of a train/serve/rl bench doc."""
+    return doc if "metric" in doc else None
 
 
 def collect(root: str) -> dict:
     """Parse every bench artifact under ``root`` into
-    ``{"rounds": [...], "core": [...], "gates": [...],
-    "files": [names]}``. Raises on unparseable JSON or an artifact
+    ``{"rounds": [...], "core": [...], "files": [names]}``. Raises on unparseable JSON or an artifact
     matching no known shape — the drift gate wants loud failures."""
     rounds: list[dict] = []
     core: list[dict] = []
-    gates: list[dict] = []
     files: list[str] = []
 
     def load(path):
@@ -69,11 +60,9 @@ def collect(root: str) -> dict:
         doc = load(path)
         name = os.path.basename(path)
         rec = _primary(doc)
-        if rec is None and doc.get("rc", 0) == 0:
-            raise ValueError(f"{name}: no parsed bench record")
-        rounds.append({
-            "source": name, "lane": "train",
-            "rc": doc.get("rc", 0), "record": rec})
+        if rec is None:
+            raise ValueError(f"{name}: no primary metric")
+        rounds.append({"source": name, "lane": "train", "record": rec})
     for name, lane in (("SERVE_BENCH.json", "serve"),
                        ("RL_BENCH.json", "rl")):
         path = os.path.join(root, name)
@@ -82,8 +71,7 @@ def collect(root: str) -> dict:
         rec = _primary(load(path))
         if rec is None:
             raise ValueError(f"{name}: no primary metric")
-        rounds.append({"source": name, "lane": lane, "rc": 0,
-                       "record": rec})
+        rounds.append({"source": name, "lane": lane, "record": rec})
 
     path = os.path.join(root, "CORE_BENCH.json")
     if os.path.exists(path):
@@ -95,17 +83,7 @@ def collect(root: str) -> dict:
                 raise ValueError(f"CORE_BENCH.json: bad row {scenario!r}")
             core.append({"scenario": scenario, **row})
 
-    for path in sorted(glob.glob(os.path.join(root, "MULTICHIP_r*.json"))):
-        doc = load(path)
-        name = os.path.basename(path)
-        if "ok" not in doc and "rc" not in doc:
-            raise ValueError(f"{name}: not a dryrun gate record")
-        gates.append({"source": name, **{
-            k: doc.get(k) for k in
-            ("n_devices", "rc", "ok", "skipped")}})
-
-    return {"rounds": rounds, "core": core, "gates": gates,
-            "files": files}
+    return {"rounds": rounds, "core": core, "files": files}
 
 
 def bubble_regression(root: str | None = None) -> dict | None:
@@ -229,10 +207,6 @@ def render(data: dict) -> str:
     out.append("|--------|------|--------|-------|------|-------------|")
     for r in data["rounds"]:
         rec = r["record"]
-        if rec is None:
-            out.append(f"| {r['source']} | {r['lane']} | (no result, "
-                       f"rc={r['rc']}) | - | - | - |")
-            continue
         out.append(
             f"| {r['source']} | {r['lane']} | {rec.get('metric', '-')} "
             f"| {_fmt(rec.get('value'))} | {rec.get('unit', '-')} "
@@ -255,17 +229,6 @@ def render(data: dict) -> str:
                 f"| {c['scenario']} | {_fmt(c.get('value'))} "
                 f"| {c.get('unit', '-')} | {_fmt(c.get('baseline'))} "
                 f"| {_fmt(c.get('vs_baseline'))} |")
-
-    if data["gates"]:
-        out.append("\n## Multichip dryrun gates\n")
-        out.append("| Source | Devices | Outcome |")
-        out.append("|--------|---------|---------|")
-        for g in data["gates"]:
-            outcome = ("skipped" if g.get("skipped")
-                       else "ok" if g.get("ok")
-                       else f"FAILED (rc={g.get('rc')})")
-            out.append(f"| {g['source']} | {_fmt(g.get('n_devices'))} "
-                       f"| {outcome} |")
 
     out.append(f"\n{len(data['files'])} artifacts indexed: "
                + ", ".join(data["files"]) + "\n")

@@ -464,8 +464,8 @@ def gpt2_decode_kv(
 # residuals shared via the `attend` hook), but the attention core is the
 # ops/paged_attention.py kernel indexing the page pool in place — no
 # dense (L, B, C, H, D) context gather. k_pages/v_pages are the pool
-# arrays (L, num_blocks, block_size, H, D); the scan walks layers and
-# per-layer page arrays together.
+# arrays (L, num_blocks, block_size, H, D); the scan walks layer indices
+# and the kernel picks the layer's pages out of the whole pool.
 
 
 def gpt2_decode_paged_kv(
@@ -489,19 +489,19 @@ def gpt2_decode_paged_kv(
         + params["wpe"].astype(dt)[positions]
 
     def body(carry, xs):
-        p, kp, vp = xs
+        p, layer = xs
 
         def attend(q, k, v):
             o = paged_attention(q[:, None], k[:, None], v[:, None],
-                                kp, vp, tables, positions,
-                                interpret=interpret)
+                                k_pages, v_pages, tables, positions,
+                                layer=layer, interpret=interpret)
             return o[:, 0]
 
         return _decode_block(carry, p, None, None, None, cfg,
                              attend=attend)
 
     x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["blocks"], k_pages, v_pages))
+        body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
     x = _layer_norm(x, params["lnf"]["scale"], params["lnf"]["bias"])
     logits = x @ params["wte"].astype(dt).T
     return logits.astype(jnp.float32), k_new, v_new
@@ -534,16 +534,18 @@ def gpt2_verify_paged_kv(
     ctx_len = jnp.reshape(jnp.asarray(start, jnp.int32), (1,))
 
     def body(carry, xs):
-        p, kp, vp = xs
+        p, layer = xs
 
         def attend(q, k, v):
-            return paged_attention(q, k, v, kp, vp, tables, ctx_len,
+            return paged_attention(q, k, v, k_pages, v_pages, tables,
+                                   ctx_len, layer=layer,
                                    interpret=interpret)
 
         return _chunk_block(carry, p, None, None, None, None, cfg,
                             attend=attend)
 
-    x, (k, v) = jax.lax.scan(body, x, (params["blocks"], k_pages, v_pages))
+    x, (k, v) = jax.lax.scan(
+        body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
     x = _layer_norm(x, params["lnf"]["scale"], params["lnf"]["bias"])
     logits = x @ params["wte"].astype(dt).T
     return logits.astype(jnp.float32), k, v

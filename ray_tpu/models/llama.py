@@ -441,19 +441,19 @@ def llama_decode_paged_kv(
     x = params["wte"].astype(dt)[tokens]
 
     def body(carry, xs):
-        p, kp, vp = xs
+        p, layer = xs
 
         def attend(q, k, v):
             o = paged_attention(q[:, None], k[:, None], v[:, None],
-                                kp, vp, tables, positions,
-                                interpret=interpret)
+                                k_pages, v_pages, tables, positions,
+                                layer=layer, interpret=interpret)
             return o[:, 0]
 
         return _decode_block(carry, p, None, None, None, positions,
                              cfg, attend=attend)
 
     x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["blocks"], k_pages, v_pages))
+        body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
     x = _rmsnorm(x, params["lnf"], cfg.rms_eps)
     logits = x @ params["wte"].astype(dt).T
     return logits.astype(jnp.float32), k_new, v_new
@@ -481,16 +481,18 @@ def llama_verify_paged_kv(
     ctx_len = jnp.reshape(jnp.asarray(start, jnp.int32), (1,))
 
     def body(carry, xs):
-        p, kp, vp = xs
+        p, layer = xs
 
         def attend(q, k, v):
-            return paged_attention(q, k, v, kp, vp, tables, ctx_len,
+            return paged_attention(q, k, v, k_pages, v_pages, tables,
+                                   ctx_len, layer=layer,
                                    interpret=interpret)
 
         return _chunk_block(carry, p, None, None, None, None, start,
                             cfg, attend=attend)
 
-    x, (k, v) = jax.lax.scan(body, x, (params["blocks"], k_pages, v_pages))
+    x, (k, v) = jax.lax.scan(
+        body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
     x = _rmsnorm(x, params["lnf"], cfg.rms_eps)
     logits = x @ params["wte"].astype(dt).T
     return logits.astype(jnp.float32), k, v

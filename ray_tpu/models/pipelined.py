@@ -110,19 +110,9 @@ def pipelined_loss(params, batch, cfg: PipelinedConfig, mesh,
     S = pipe
     order = jnp.argsort(jnp.arange(cfg.n_virtual_stages) % S, stable=True)
     blocks = jax.tree.map(lambda p: p[order], params["blocks"])
-    sm_specs = dict(in_specs=(P("pipe"), P(None, "fsdp", None)),
-                    out_specs=P(None, "fsdp", None))
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map(body, mesh=mesh, axis_names={"pipe", "fsdp"},
-                           check_vma=False, **sm_specs)
-    else:
-        # jax<0.5: experimental entry point; manual-axes subset is
-        # expressed as its complement (`auto`), check_vma as check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        sm = _shard_map(body, mesh=mesh, check_rep=False,
-                        auto=frozenset(mesh.axis_names) -
-                        {"pipe", "fsdp"}, **sm_specs)
+    sm = jax.shard_map(body, mesh=mesh, axis_names={"pipe", "fsdp"},
+                       in_specs=(P("pipe"), P(None, "fsdp", None)),
+                       out_specs=P(None, "fsdp", None), check_vma=False)
     h = sm(blocks, h)
     logits = _rms(h * params["ln_f"]) @ params["head"]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32))

@@ -27,14 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu is importable without TPU; interpret mode needs no hardware
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -48,10 +41,7 @@ class _Cfg:
     interpret: bool
 
 
-def _vmem_spec(shape, index_map):
-    if _VMEM is not None:
-        return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
-    return pl.BlockSpec(shape, index_map)
+_vmem_spec = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
 
 
 # ---------------------------------------------------------------- forward
@@ -113,9 +103,9 @@ def _fwd(q, k, v, cfg: _Cfg):
     Bq, Bk = cfg.block_q, cfg.block_k
     kernel = functools.partial(_fwd_kernel, cfg=cfg, nk=nk)
     scratch = [
-        _scratch((Bq, D), jnp.float32),
-        _scratch((Bq, 128), jnp.float32),
-        _scratch((Bq, 128), jnp.float32),
+        pltpu.VMEM((Bq, D), jnp.float32),
+        pltpu.VMEM((Bq, 128), jnp.float32),
+        pltpu.VMEM((Bq, 128), jnp.float32),
     ]
     o, lse = pl.pallas_call(
         kernel,
@@ -137,12 +127,6 @@ def _fwd(q, k, v, cfg: _Cfg):
         interpret=cfg.interpret,
     )(q, k, v)
     return o, lse
-
-
-def _scratch(shape, dtype):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    return pl.ANY(shape, dtype)  # pragma: no cover
 
 
 # ---------------------------------------------------------------- backward
@@ -256,7 +240,7 @@ def _bwd(q, k, v, o, lse, do, cfg: _Cfg):
         ],
         out_specs=_vmem_spec((1, Bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-        scratch_shapes=[_scratch((Bq, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((Bq, D), jnp.float32)],
         interpret=cfg.interpret,
     )(q, k, v, do, lse, delta)
 
@@ -279,8 +263,8 @@ def _bwd(q, k, v, o, lse, do, cfg: _Cfg):
             jax.ShapeDtypeStruct((BH, T, D), k.dtype),
             jax.ShapeDtypeStruct((BH, T, D), v.dtype),
         ],
-        scratch_shapes=[_scratch((Bk, D), jnp.float32),
-                        _scratch((Bk, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((Bk, D), jnp.float32),
+                        pltpu.VMEM((Bk, D), jnp.float32)],
         interpret=cfg.interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -1,23 +1,26 @@
 """Paged attention — pallas TPU kernel over the serve.llm KV page pool.
 
-The serve decode/verify programs historically gathered each lane's
-pages into a dense ``(L, S, max_blocks_per_seq * block_size, H_kv, D)``
-context before attending (runner.py) — O(max_model_len) HBM traffic per
-step regardless of how long the sequence actually is. This kernel is
-the vLLM-PagedAttention shape instead (PAPERS.md): queries index the
-page pool *in place* through the block table, one page per grid step,
-with the table and context lengths delivered via scalar prefetch so the
-page id is known before the page's DMA is issued.
+The dense decode/verify programs gather each lane's pages into a
+``(L, S, max_blocks_per_seq * block_size, H_kv, D)`` context before
+attending (runner.py) — O(max_model_len) HBM traffic per step
+regardless of how long the sequence actually is. This kernel is the
+vLLM-PagedAttention shape instead (PAPERS.md): queries index the page
+pool *in place* through the block table, one page per grid step, with
+the layer index, the table and the context lengths delivered via scalar
+prefetch so the page id is known before the page's DMA is issued.
 
-Layout (one layer at a time — the models scan layers and call this
-inside the scan body, so it compiles once):
+Operands:
 
 - ``q``                (S, W, H, D)  — W query positions per sequence:
   W=1 is plain decode, W=K+1 is the speculative verify window;
 - ``own_k``/``own_v``  (S, W, H_kv, D) — the window's OWN keys/values
   (they are never in the pages: decode/verify scatter them after the
   step), attended causally within the window;
-- ``k_pages``/``v_pages`` (num_blocks, block_size, H_kv, D) — the pool;
+- ``k_pages``/``v_pages`` — the pool as the runner holds it,
+  (L, num_blocks, block_size, H_kv, D) with ``layer`` (a traced i32)
+  selecting the layer, or one layer's (num_blocks, block_size, H_kv, D)
+  with ``layer=None``. The models scan over layer INDICES and close
+  over the whole pool: slicing the pool per layer would copy it;
 - ``tables``           (S, max_blocks_per_seq) i32 — logical page i of
   sequence s lives in physical page ``tables[s, i]`` (padding points at
   the null page 0, which the length mask excludes anyway);
@@ -25,18 +28,23 @@ inside the scan body, so it compiles once):
   < ctx_len[s] are real; everything else in the mapped pages is
   garbage past the lane's frontier).
 
-Grid is (S, H, max_blocks_per_seq): the page axis is innermost and
-sequential, carrying the online-softmax state (running max, sum, f32
-accumulator) in VMEM scratch exactly like ops/flash_attention.py; pages
-wholly past ``ctx_len`` are skipped with ``pl.when``; the final grid
-step folds in the causal own-window block and normalizes. GQA maps
-query head h to KV head ``h // (H // H_kv)`` in the index maps, so
-grouped heads re-read the same page block.
+Blocking. Mosaic requires the last two dims of every block to be
+(8, 128)-divisible or the array's full dims, so a block is never cut
+inside ``(H_kv, D)``: one grid step holds one whole page
+``(block_size, H_kv, D)``, and queries are regrouped outside the kernel
+to ``(S, W, rep, H_kv, D)`` (head ``h = hk * rep + r``). Grid is
+(S, max_blocks_per_seq), pages innermost and sequential, carrying the
+online-softmax state (running max, sum, f32 accumulator) in VMEM
+scratch like ops/flash_attention.py; pages wholly past ``ctx_len`` are
+skipped with ``pl.when``; the final grid step folds in the causal
+own-window block and normalizes. The arithmetic is a VPU
+multiply-reduce per (window row, group member) over all KV heads at
+once — decode is bound by reading pages, and this keeps ``(H_kv, D)``
+in its native layout with no in-kernel relayout.
 
 ``interpret=True`` runs the same kernel through the pallas interpreter
 on CPU (tests, parity gates); on TPU it compiles for real. The dense
-reference (`paged_attention_reference`) is the parity oracle at
-atol 1e-4.
+reference (`paged_attention_reference`) is the parity oracle.
 """
 
 from __future__ import annotations
@@ -46,126 +54,104 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu imports fine without TPU; interpret mode needs no hardware
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def _vmem_spec(shape, index_map):
-    if _VMEM is not None:
-        return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
-    return pl.BlockSpec(shape, index_map)  # pragma: no cover
-
-
-def _scratch(shape, dtype):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    return pl.ANY(shape, dtype)  # pragma: no cover
-
-
-def _paged_kernel(tables_ref, ctxlen_ref, q_ref, ko_ref, vo_ref, kp_ref,
-                  vp_ref, o_ref, acc, m_s, l_s, *, scale, nb, bs):
+def _paged_kernel(layer_ref, tables_ref, ctxlen_ref, q_ref, ko_ref, vo_ref,
+                  kp_ref, vp_ref, o_ref, acc, m_s, l_s, *, scale, nb, bs):
+    del layer_ref, tables_ref  # consumed by the index maps
     s_i = pl.program_id(0)
-    b = pl.program_id(2)
-    W = q_ref.shape[1]
+    b = pl.program_id(1)
+    W, rep = q_ref.shape[0], q_ref.shape[1]
 
     @pl.when(b == 0)
     def _init():
-        m_s[:] = jnp.full_like(m_s, -jnp.inf)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc[:] = jnp.zeros_like(acc)
+        m_s[...] = jnp.full_like(m_s, -jnp.inf)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc[...] = jnp.zeros_like(acc)
 
     ctx = ctxlen_ref[s_i]
-    q = q_ref[0, :, 0, :]  # (W, D)
 
-    def _accum(k, v, valid):  # k/v (N, D); valid (W, N) bool
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid, s, DEFAULT_MASK_VALUE)
-        m_prev = m_s[:, :1]  # (W, 1)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+    def _accum(i, k, v, valid):
+        # row i = (w, r): one query per KV head, q (HK, D); k/v (N, HK, D)
+        # f32; valid (N, 1, 1). VPU formulation: the (HK, D) minor dims of
+        # the pool never leave their native layout.
+        q = q_ref[i // rep, i % rep].astype(jnp.float32)
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+        s = jnp.where(valid, s, DEFAULT_MASK_VALUE)  # (N, HK, 1)
+        m_prev = m_s[i, :, :1]  # (HK, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)  # (W, N) f32
-        l_new = alpha * l_s[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc[:] = acc[:] * alpha + pv
-        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-        l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
+        p = jnp.exp(s - m_new[None])
+        l_new = alpha * l_s[i, :, :1] + jnp.sum(p, axis=0)
+        acc[i] = acc[i] * alpha + jnp.sum(p * v, axis=0)
+        m_s[i] = jnp.broadcast_to(m_new, m_s.shape[1:])
+        l_s[i] = jnp.broadcast_to(l_new, l_s.shape[1:])
 
-    # pages wholly past the frontier are skipped (their DMA still
-    # lands, but no FLOPs are spent and the mask math never runs)
     @pl.when(b * bs < ctx)
     def _page():
-        cols = b * bs + jax.lax.broadcasted_iota(jnp.int32, (W, bs), 1)
-        _accum(kp_ref[0, :, 0, :], vp_ref[0, :, 0, :], cols < ctx)
+        k = kp_ref[...].astype(jnp.float32)
+        v = vp_ref[...].astype(jnp.float32)
+        cols = b * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
+        for i in range(W * rep):
+            _accum(i, k, v, cols < ctx)
 
-    # last grid step: fold in the window's own keys (causal within the
-    # window — query j sees keys 0..j) and emit the normalized output
     @pl.when(b == nb - 1)
     def _own_and_emit():
-        rows = jax.lax.broadcasted_iota(jnp.int32, (W, W), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (W, W), 1)
-        _accum(ko_ref[0, :, 0, :], vo_ref[0, :, 0, :], cols <= rows)
-        l = l_s[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc[:] / l_safe).astype(o_ref.dtype)
+        k = ko_ref[...].astype(jnp.float32)
+        v = vo_ref[...].astype(jnp.float32)
+        x = jax.lax.broadcasted_iota(jnp.int32, (W, 1, 1), 0)
+        for i in range(W * rep):
+            _accum(i, k, v, x <= i // rep)
+            l = l_s[i, :, :1]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[i // rep, i % rep] = (acc[i] / l_safe).astype(o_ref.dtype)
 
 
 def paged_attention(q, own_k, own_v, k_pages, v_pages, tables, ctx_len,
-                    *, sm_scale: float | None = None,
+                    *, layer=None, sm_scale: float | None = None,
                     interpret: bool = False):
     """One layer of paged attention; see the module docstring for the
     operand layout. Returns (S, W, H, D) in q's dtype. Every query row
     attends [cached slots < ctx_len[s]] ++ [own window, causally]."""
     S, W, H, D = q.shape
     HK = own_k.shape[2]
-    bs = k_pages.shape[1]
-    maxB = tables.shape[1]
     rep = H // HK
+    if layer is None:  # one layer's (num_blocks, bs, HK, D) pool
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    bs = k_pages.shape[2]
+    maxB = tables.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / (D**0.5)
-    kernel = functools.partial(_paged_kernel, scale=scale, nb=maxB,
-                               bs=bs)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, H, maxB),
-        in_specs=[
-            _vmem_spec((1, W, 1, D),
-                       lambda s, h, b, t, c: (s, 0, h, 0)),
-            _vmem_spec((1, W, 1, D),
-                       lambda s, h, b, t, c: (s, 0, h // rep, 0)),
-            _vmem_spec((1, W, 1, D),
-                       lambda s, h, b, t, c: (s, 0, h // rep, 0)),
-            _vmem_spec((1, bs, 1, D),
-                       lambda s, h, b, t, c: (t[s, b], 0, h // rep, 0)),
-            _vmem_spec((1, bs, 1, D),
-                       lambda s, h, b, t, c: (t[s, b], 0, h // rep, 0)),
-        ],
-        out_specs=_vmem_spec((1, W, 1, D),
-                             lambda s, h, b, t, c: (s, 0, h, 0)),
-        scratch_shapes=[
-            _scratch((W, D), jnp.float32),
-            _scratch((W, 128), jnp.float32),
-            _scratch((W, 128), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((S, W, H, D), q.dtype),
-        grid_spec=grid_spec,
+    # head h = hk * rep + r  ->  (S, W, rep, HK, D): every block then ends
+    # in the full (HK, D) dims of its array
+    qg = q.reshape(S, W, HK, rep, D).swapaxes(2, 3)
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    own = vmem((None, W, HK, D), lambda s, b, l, t, c: (s, 0, 0, 0))
+    page = vmem((None, None, bs, HK, D),
+                lambda s, b, l, t, c: (l[0], t[s, b], 0, 0, 0))
+    qspec = vmem((None, W, rep, HK, D),
+                 lambda s, b, l, t, c: (s, 0, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_paged_kernel, scale=scale, nb=maxB, bs=bs),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S, maxB),
+            in_specs=[qspec, own, own, page, page],
+            out_specs=qspec,
+            scratch_shapes=[
+                pltpu.VMEM((W * rep, HK, D), jnp.float32),
+                pltpu.VMEM((W * rep, HK, 128), jnp.float32),
+                pltpu.VMEM((W * rep, HK, 128), jnp.float32),
+            ],
+        ),
         interpret=interpret,
-    )(tables.astype(jnp.int32), ctx_len.astype(jnp.int32),
-      q, own_k, own_v, k_pages, v_pages)
+    )(jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
+      tables.astype(jnp.int32), ctx_len.astype(jnp.int32),
+      qg, own_k, own_v, k_pages, v_pages)
+    return out.swapaxes(2, 3).reshape(S, W, H, D)
 
 
 def paged_attention_reference(q, own_k, own_v, k_pages, v_pages, tables,

@@ -71,10 +71,7 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str):
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    # jax<0.5: psum of a unit weight is folded to the static axis size
-    return jax.lax.psum(1, axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 _HLO_COLLECTIVES = {
@@ -113,14 +110,6 @@ def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
     """`jax.shard_map` with varying-manual-axes checking off by default:
     collective-heavy SPMD bodies (all_gather outputs, ring schedules)
     routinely produce values that are replicated at runtime but not
-    statically inferable, and jax>=0.8 rejects those under check_vma.
-
-    Older jax (<0.5) only ships the experimental entry point, where the
-    same knob is spelled check_rep."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    statically inferable, and jax rejects those under check_vma."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

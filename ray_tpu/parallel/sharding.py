@@ -133,24 +133,11 @@ def constrain(x: jax.Array, *spec_entries) -> jax.Array:
     if mesh is None:
         return x
     spec = _prune_spec(PartitionSpec(*spec_entries), mesh)
-    if isinstance(mesh, Mesh):
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
     return jax.lax.with_sharding_constraint(x, spec)
 
 
 def _current_mesh():
-    """The ambient mesh, if model code runs under `jax.sharding.use_mesh`
-    (or a `with mesh:` block); None otherwise (single-device paths)."""
-    try:
-        env = jax.sharding.get_abstract_mesh()
-        if env is not None and env.axis_names:
-            return env
-    except Exception:
-        pass
-    try:
-        m = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        if m.axis_names:
-            return m
-    except Exception:
-        pass
-    return None
+    """The ambient mesh, if model code runs under `jax.set_mesh(mesh)`;
+    None otherwise (single-device paths)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None

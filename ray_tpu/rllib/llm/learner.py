@@ -204,7 +204,7 @@ class LLMLearner:
 
                 batch = jax.device_put(
                     batch, batch_shardings(self.mesh, batch))
-                with self.mesh:
+                with jax.set_mesh(self.mesh):
                     self.state, metrics = self._train_step(self.state,
                                                            batch)
             else:

@@ -65,6 +65,9 @@ def cmd_start(args):
     res = json.loads(args.resources) if args.resources else {}
     res.setdefault("CPU", float(args.num_cpus if args.num_cpus is not None
                                 else os.cpu_count() or 1))
+    from ray_tpu import accelerators
+
+    res = {**accelerators.detect_node_resources(), **res}
     if args.num_tpus:
         res["TPU"] = float(args.num_tpus)
 

@@ -273,8 +273,10 @@ def auto_num_blocks(
     profiling, here a static estimate: params are already resident, so
     take `memory_fraction` of the device's bytes_limit for KV).
 
-    Falls back to "every lane can reach max_model_len, twice over" when
-    the backend doesn't report memory (CPU jax in tests).
+    The CPU backend reports no memory and gets "every lane can reach
+    max_model_len, twice over" (tests). A TPU that reports none is an
+    error: the toy floor there would serve real traffic from a pool
+    sized for a test.
     """
     # mirror the runner's sharding rule: pages shard over `tensor` only
     # when the KV heads divide evenly, otherwise they are replicated —
@@ -285,18 +287,17 @@ def auto_num_blocks(
         heads_per_shard = n_kv_head
     per_block = 2 * n_layer * block_size * heads_per_shard \
         * head_dim * dtype_bytes
-    budget = None
     if device is None:
         import jax
 
         device = jax.local_devices()[0]
-    try:
-        stats = device.memory_stats()
-        if stats:
-            budget = int(stats.get("bytes_limit", 0) * memory_fraction)
-    except Exception:  # noqa: BLE001  (CPU backend: no memory_stats)
-        budget = None
     floor = max_batch_size * ((max_model_len + block_size - 1) // block_size)
-    if not budget:
+    if device.platform == "cpu":
         return 2 * floor + 1  # +1: the null page
+    stats = device.memory_stats()
+    if not stats or not stats.get("bytes_limit"):
+        raise RuntimeError(
+            f"{device} reports no memory_stats()['bytes_limit']; cannot "
+            f"size the KV pool — pass num_blocks explicitly")
+    budget = int(stats["bytes_limit"] * memory_fraction)
     return max(floor + 1, budget // per_block)

@@ -156,12 +156,19 @@ def build_llm_app(
 ) -> Any:
     """Bind an LLM application: ``serve.run(build_llm_app(...))``.
 
-    `engine_config` entries override the model/preset shorthand."""
+    `engine_config` entries override the model/preset shorthand. A
+    replica is one engine on one device: where the cluster has TPU
+    chips it claims one, unless `ray_actor_options` says otherwise —
+    a replica that claims none is kept off the chips by the runtime."""
+    import ray_tpu
     from ray_tpu import serve
 
     cfg = {"model": model, "preset": preset}
     cfg.update(engine_config or {})
     EngineConfig.from_dict(cfg)  # validate in the driver, not the replica
+    if ray_actor_options is None and ray_tpu.is_initialized() and \
+            ray_tpu.cluster_resources().get("TPU", 0) >= 1:
+        ray_actor_options = {"num_tpus": 1}
     dep = serve.deployment(
         LLMServer,
         name=f"llm-{cfg['model']}",

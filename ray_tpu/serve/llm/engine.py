@@ -791,6 +791,9 @@ class LLMEngine:
             return bool(self.scheduler.waiting or self.scheduler.running)
 
     def stats(self) -> dict:
+        import jax
+
+        devices = jax.devices()
         d = self.scheduler.depth()
         with self._lock:
             phase_totals = dict(self._phase_totals)
@@ -809,6 +812,10 @@ class LLMEngine:
             "spec_proposed": self._spec_proposed_total,
             "spec_accepted": self._spec_accepted_total,
             "paged_attention": self.runner.use_paged_attention,
+            # the device this replica's process runs on, as jax reports it
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
         })
         return d
 

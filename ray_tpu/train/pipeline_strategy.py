@@ -127,22 +127,6 @@ class PipelineStageWorker:
         self.emulate: tuple[float, float] | None = None
         self._last_state_bytes: dict[str, int] = {}
 
-    def setup_env(self, env: dict) -> bool:
-        import os
-
-        os.environ.update({k: str(v) for k, v in env.items()})
-        if "JAX_PLATFORMS" in env:
-            # jax is already imported in this process (the actor class
-            # pulls it in), so the env var alone cannot steer the
-            # backend — the config update can, as long as no jax call
-            # has initialized a backend yet (none has: load_stage is
-            # the first to touch arrays)
-            import jax
-
-            jax.config.update("jax_platforms",
-                              str(env["JAX_PLATFORMS"]) or None)
-        return True
-
     def ensure_cpu_devices(self, n: int) -> bool:
         """Give this worker >= n virtual CPU devices for its intra-stage
         data-parallel group (the test/laptop stand-in for a worker's
@@ -572,17 +556,20 @@ class PipelineStrategy:
             max_concurrency=1,
         )
         try:
-            on_cpu = jax.devices()[0].platform == "cpu"
-            if on_cpu:
-                # test/laptop path: stage workers must not grab a TPU
-                self.wg.execute("setup_env", {"JAX_PLATFORMS": "cpu"})
-                if self.data_parallel > 1:
-                    ok = self.wg.execute("ensure_cpu_devices",
-                                         self.data_parallel)
-                    if not all(ok):
-                        raise RuntimeError(
-                            "stage workers could not provision "
-                            f"{self.data_parallel} cpu devices")
+            # A stage that claims no TPU is kept on the CPU by the
+            # runtime and needs virtual devices for its data-parallel
+            # group; one that claims chips has them. Decided from the
+            # request, never by asking jax here: a driver that
+            # initializes a backend on a TPU host takes the chips its
+            # stages need.
+            if not (resources_per_worker or {}).get("TPU") and \
+                    self.data_parallel > 1:
+                ok = self.wg.execute("ensure_cpu_devices",
+                                     self.data_parallel)
+                if not all(ok):
+                    raise RuntimeError(
+                        "stage workers could not provision "
+                        f"{self.data_parallel} cpu devices")
             if params is None:
                 params = init_pipelined(jax.random.PRNGKey(seed),
                                         self.cfg)

@@ -401,7 +401,7 @@ def make_train_step(
     Sharding is carried by the arrays themselves (state from
     `init_sharded_state`, batch device_put with `batch_shardings`); jit
     propagates it and GSPMD inserts the collectives. Call under
-    `with mesh:` so in-model `constrain` calls resolve.
+    `jax.set_mesh(mesh)` so in-model `constrain` calls resolve.
 
     ``zero_stage`` picks the ladder rung (requires `mesh` + `rules` for
     stage >= 1; `shard_optimizer=True` is the stage-1 spelling; pair
@@ -665,7 +665,7 @@ def init_sharded_state(
     abstract = jax.eval_shape(make)
     shardings = state_shardings(rules, abstract, mesh,
                                 data_axis=data_axis, zero_stage=stage)
-    with mesh:
+    with jax.set_mesh(mesh):
         state = jax.jit(make, out_shardings=shardings)()
     _optimizer_bytes_gauge().set(
         float(optimizer_state_bytes(state.opt_state)),

@@ -324,12 +324,6 @@ class JaxTrainer:
             for rank, info in enumerate(infos):
                 by_node.setdefault(info["node_id"], []).append(rank)
             node_order = list(by_node)
-            node_ips = []
-            _seen_nodes = set()
-            for i in infos:
-                if i["node_id"] not in _seen_nodes:
-                    _seen_nodes.add(i["node_id"])
-                    node_ips.append(i["ip"])
             # slice-identity view: node labels + per-node IPs, for the
             # slice-derived topology env (reference: backend_executor.py
             # :306-322 shares the slice view across colocated workers)
@@ -366,9 +360,8 @@ class JaxTrainer:
 
                     labels = node_labels.get(node_id, {})
                     env.update(self._slice_topology_env(
-                        tpu_mod, labels, node_id, node_labels, node_ip_by_id,
-                        fallback_id=node_order.index(node_id),
-                        fallback_ips=node_ips))
+                        tpu_mod, labels, node_id, node_labels,
+                        node_ip_by_id))
                 if coordinator:
                     env["RAY_TPU_TRAIN_COORDINATOR"] = coordinator
                 env_refs.append((rank, env))
@@ -381,10 +374,19 @@ class JaxTrainer:
             refs = [
                 getattr(w, "setup_jax").remote(
                     coordinator, wg.num_workers, rank,
-                    sc.num_cpu_devices_per_worker)
+                    sc.num_cpu_devices_per_worker, sc.use_tpu)
                 for rank, w in enumerate(wg.workers)
             ]
             device_counts = ray_tpu.get(refs, timeout=180)
+            # every rank must see the devices it was sized for: its
+            # virtual CPU devices, or the chips its TPU claim bought
+            want = sc.num_cpu_devices_per_worker or (
+                int(sc.worker_resources().get("TPU", 0))
+                if sc.use_tpu else 0)
+            if want and any(n != want for n in device_counts):
+                raise WorkerGroupError(
+                    f"train workers see {device_counts} local devices, "
+                    f"expected {want} each")
             fn_blob = cloudpickle.dumps(self._fn)
             for rank, info in enumerate(infos):
                 node_id = info["node_id"]
@@ -406,7 +408,6 @@ class JaxTrainer:
                 wg.execute_single(
                     rank, "start_training", fn_blob, self._config, ctx,
                     resume.path if resume else None, shards_blob)
-            del device_counts
             return wg
         except Exception as e:
             wg.shutdown()
@@ -419,15 +420,16 @@ class JaxTrainer:
 
     @staticmethod
     def _slice_topology_env(tpu_mod, labels, node_id, node_labels,
-                            node_ip_by_id, fallback_id, fallback_ips):
+                            node_ip_by_id):
         """TPU topology env for one worker. Slice-labelled nodes get their
         asserted TPU_WORKER_ID and hostnames ordered by worker-id across
-        the gang's members of the same slice; unlabelled clusters fall
-        back to gang join order (single-slice assumption)."""
+        the gang's members of the same slice. A node without slice
+        labels keeps the TPU environment its platform gave it: gang
+        join order is not a topology, and on a single host libtpu needs
+        no help."""
         sl = labels.get(tpu_mod.SLICE_LABEL)
         if sl is None or labels.get(tpu_mod.WORKER_ID_LABEL) is None:
-            return {"TPU_WORKER_ID": fallback_id,
-                    "TPU_WORKER_HOSTNAMES": ",".join(fallback_ips)}
+            return {}
         members = sorted(
             ((int(lb[tpu_mod.WORKER_ID_LABEL]), nid)
              for nid, lb in node_labels.items()

@@ -53,32 +53,25 @@ class TrainWorker:
         return True
 
     def setup_jax(self, coordinator: str | None, num_processes: int,
-                  process_id: int, num_cpu_devices: int | None) -> int:
+                  process_id: int, num_cpu_devices: int | None,
+                  use_tpu: bool = False) -> int:
         """Configure jax in this process and join the distributed system
         (reference seam: Backend.on_start — _TorchBackend runs
         dist.init_process_group here, train/torch/config.py:66-124; the
         jax-native equivalent is jax.distributed.initialize with rank-0's
-        address)."""
+        address). Returns this process's local device count."""
         import jax
 
         if num_cpu_devices:
-            # strip any inherited --xla_force_host_platform_device_count
-            # (e.g. from a test driver): it would override
-            # jax_num_cpu_devices where that option exists, and fight
-            # the value we append for jax<0.5. The backend initializes
-            # lazily at the jax.devices() call below, so editing
-            # XLA_FLAGS after the import is still in time.
+            # an inherited --xla_force_host_platform_device_count (e.g.
+            # from a test driver) would override jax_num_cpu_devices.
+            # The backend initializes lazily at the first device query
+            # below, so editing XLA_FLAGS after the import is in time.
             flags = os.environ.get("XLA_FLAGS", "")
-            kept = [f for f in flags.split() if
-                    "--xla_force_host_platform_device_count" not in f]
-            if hasattr(jax.config, "jax_num_cpu_devices"):
-                jax.config.update("jax_num_cpu_devices",
-                                  int(num_cpu_devices))
-            else:
-                # jax<0.5: the XLA flag IS the device-count mechanism
-                kept.append("--xla_force_host_platform_device_count="
-                            f"{int(num_cpu_devices)}")
-            os.environ["XLA_FLAGS"] = " ".join(kept)
+            os.environ["XLA_FLAGS"] = " ".join(
+                f for f in flags.split()
+                if "--xla_force_host_platform_device_count" not in f)
+            jax.config.update("jax_num_cpu_devices", int(num_cpu_devices))
             jax.config.update("jax_platforms", "cpu")
         if coordinator and num_processes > 1:
             jax.distributed.initialize(
@@ -86,7 +79,14 @@ class TrainWorker:
                 num_processes=num_processes,
                 process_id=process_id,
             )
-        return len(jax.devices())
+        if use_tpu and not num_cpu_devices and \
+                jax.default_backend() != "tpu":
+            raise RuntimeError(
+                f"use_tpu=True but this worker's jax backend is "
+                f"{jax.default_backend()!r} (JAX_PLATFORMS="
+                f"{os.environ.get('JAX_PLATFORMS')!r}, TPU_VISIBLE_CHIPS="
+                f"{os.environ.get('TPU_VISIBLE_CHIPS')!r})")
+        return len(jax.local_devices())
 
     # -- training --------------------------------------------------------
 
@@ -203,7 +203,14 @@ class WorkerGroup:
             raise WorkerGroupError(
                 f"placement group for {num_workers} x {res} not placeable "
                 f"within {pg_timeout}s")
-        cls = ray_tpu.remote(num_cpus=0)(worker_cls or TrainWorker)
+        from ray_tpu import accelerators
+
+        # the actor claims its bundle's accelerators: that claim is what
+        # makes the nodelet hand those chips to the worker's process
+        claimed = {k: v for k, v in res.items()
+                   if k in accelerators.all_managers()}
+        cls = ray_tpu.remote(num_cpus=0, resources=claimed)(
+            worker_cls or TrainWorker)
         self.workers = [
             cls.options(
                 placement_group=self.pg,

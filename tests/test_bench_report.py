@@ -18,7 +18,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def test_every_bench_artifact_parses():
     paths = (glob.glob(os.path.join(ROOT, "BENCH_r*.json"))
-             + glob.glob(os.path.join(ROOT, "MULTICHIP_r*.json"))
              + [os.path.join(ROOT, n) for n in
                 ("CORE_BENCH.json", "SERVE_BENCH.json", "RL_BENCH.json")
                 if os.path.exists(os.path.join(ROOT, n))])
@@ -31,8 +30,7 @@ def test_every_bench_artifact_parses():
     assert data["rounds"], "no bench rounds collected"
     for r in data["rounds"]:
         rec = r["record"]
-        if rec is not None:
-            assert rec.get("metric") and rec.get("value") is not None, r
+        assert rec.get("metric") and rec.get("value") is not None, r
 
 
 def test_index_has_every_artifact_and_primary_metric():
@@ -41,8 +39,7 @@ def test_index_has_every_artifact_and_primary_metric():
     for name in data["files"]:
         assert name in text, f"{name} missing from index"
     for r in data["rounds"]:
-        if r["record"] is not None:
-            assert r["record"]["metric"] in text
+        assert r["record"]["metric"] in text
 
 
 def test_train_bubble_regression():

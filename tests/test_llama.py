@@ -112,7 +112,7 @@ def test_spmd_sharded_step_matches_single_device():
             llama_partition_rules())
         b = jax.device_put(batch, batch_shardings(mesh, batch))
         step = make_train_step(lambda p, bb: llama_loss(p, bb, cfg), tx)
-        with mesh:
+        with jax.set_mesh(mesh):
             state, metrics = step(state, b)
         losses[name] = float(metrics["loss"])
     np.testing.assert_allclose(losses["single"], losses["sharded"],

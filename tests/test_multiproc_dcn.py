@@ -14,7 +14,6 @@ import numpy as np
 import pytest
 
 import ray_tpu
-import conftest
 from ray_tpu.cluster_utils import Cluster
 from ray_tpu.core import tpu as tpu_mod
 from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
@@ -69,9 +68,6 @@ def _dcn_loop(config):
     })
 
 
-@pytest.mark.skipif(not conftest.jax_supports_multiprocess_cpu(),
-                    reason="multiprocess SPMD unimplemented on "
-                           "this jaxlib's CPU backend")
 def test_two_process_dcn_matches_single_process(slice_cluster, tmp_path):
     losses = {}
     for n_workers, devs in ((2, 4), (1, 8)):

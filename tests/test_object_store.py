@@ -165,3 +165,39 @@ def test_serialize_jax_array():
     x = jnp.arange(128, dtype=jnp.float32)
     out = ser.loads(ser.dumps({"x": x}))
     assert np.array_equal(np.asarray(out["x"]), np.asarray(x))
+
+
+def test_native_build_is_atomic_under_concurrent_first_use(tmp_path):
+    """Head, nodelet and workers of a fresh checkout all build the
+    native libraries on first use, at once. Every one of them must load
+    a complete library: one builds under the file lock, and the final
+    name only ever holds a finished file."""
+    import shutil
+    import subprocess
+    import sys
+
+    import ray_tpu._native as native
+
+    src_dir = os.path.dirname(native.__file__)
+    pkg = tmp_path / "_native"
+    pkg.mkdir()
+    for f in ("__init__.py", "object_store.cc"):
+        shutil.copy(os.path.join(src_dir, f), pkg / f)
+    child = (
+        "import ctypes, importlib.util, sys\n"
+        f"spec = importlib.util.spec_from_file_location('nat', {str(pkg / '__init__.py')!r})\n"
+        "nat = importlib.util.module_from_spec(spec); spec.loader.exec_module(nat)\n"
+        "path = nat.build_library('object_store')\n"
+        "assert path, 'no toolchain'\n"
+        "ctypes.CDLL(path).rts_init\n"
+        "print('LOADED')\n")
+    procs = [subprocess.Popen([sys.executable, "-c", child],
+                              stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                              text=True)
+             for _ in range(12)]
+    for p in procs:
+        out, err = p.communicate(timeout=240)
+        assert p.returncode == 0 and "LOADED" in out, err[-2000:]
+    left = sorted(os.listdir(pkg))
+    assert "libobject_store.so" in left
+    assert not [f for f in left if f.endswith(".tmp")], left

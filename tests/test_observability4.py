@@ -151,7 +151,7 @@ def test_train_waterfall_sums_to_step_time():
 
     spmd.enable_step_waterfall()
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             # two warmup steps: the first compiles for the init-time
             # state layout, the second for the steady-state layout the
             # jit output carries — the timed window must be compile-free

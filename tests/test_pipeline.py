@@ -133,10 +133,6 @@ def test_interleaved_differentiable(pipe_mesh):
     assert any(np.abs(a).sum() > 0 for a in flat)
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-auto shard_map on jax<0.5 lowers to a PartitionId "
-           "op XLA:CPU cannot SPMD-partition")
 def test_pipelined_transformer_hybrid_mesh():
     """Multi-stage transformer (ring attention over fsdp inside the
     blocks, interleaved pipeline over pipe, tensor/dcn left to GSPMD):
@@ -162,7 +158,7 @@ def test_pipelined_transformer_hybrid_mesh():
          "targets": jnp.asarray(toks[:, 1:])},
         NamedSharding(mesh, P(("dcn", "data"),)))
     step = pipelined_train_step(cfg, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         p1, l1 = step(params, batch)
         _, l2 = step(p1, batch)
     assert float(l2) < float(l1)

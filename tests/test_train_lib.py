@@ -13,7 +13,6 @@ import numpy as np
 import pytest
 
 import ray_tpu
-import conftest
 
 
 # train-loop functions below are module-level in a non-importable test
@@ -178,7 +177,7 @@ def _gpt2_loop(config):
         global_batch, sh)
 
     step_fn = make_train_step(lambda p, b: gpt2_loss(p, b, cfg), tx)
-    with mesh:
+    with jax.set_mesh(mesh):
         for step in range(start_step, config["steps"]):
             if config.get("crash_at") == step and ctx.get_world_rank() == 0 \
                     and train.get_checkpoint() is None:
@@ -211,9 +210,6 @@ def _gpt2_loop(config):
             train.report({"loss": loss, "step": step}, checkpoint=ckpt)
 
 
-@pytest.mark.skipif(not conftest.jax_supports_multiprocess_cpu(),
-                    reason="multiprocess SPMD unimplemented on "
-                           "this jaxlib's CPU backend")
 def test_gpt2_loss_parity_1_vs_2_workers(cluster, tmp_path):
     """Same global batch + init => identical loss whether the mesh spans
     one process or two (the SPMD-equivalence guarantee DDP tests assert

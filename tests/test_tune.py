@@ -153,7 +153,7 @@ def test_gpt2_tiny_lr_sweep(cluster, tmp_path):
                            ).astype(np.int32)
         batch = {"tokens": toks[:, :-1], "targets": toks[:, 1:]}
         step_fn = make_train_step(lambda p, b: gpt2_loss(p, b, cfg), tx)
-        with mesh:
+        with jax.set_mesh(mesh):
             for _ in range(5):
                 state, metrics = step_fn(state, batch)
                 tune.report({"loss": float(np.asarray(metrics["loss"]))})

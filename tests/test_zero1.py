@@ -60,7 +60,7 @@ def _run(mesh, rules, init_fn, loss_fn, tx, batch, shard, steps):
                            mesh=mesh if shard else None,
                            rules=rules if shard else None)
     losses = []
-    with mesh:
+    with jax.set_mesh(mesh):
         for _ in range(steps):
             state, m = step(state, batch)
             losses.append(float(m["loss"]))
@@ -187,7 +187,7 @@ def test_zero1_program_restructures_collectives(mesh):
                                mesh=mesh if shard else None,
                                rules=rules if shard else None,
                                donate=False)
-        with mesh:
+        with jax.set_mesh(mesh):
             txt = step.jitted.lower(state, batch).compile().as_text()
         return collective_op_counts(txt)
 
@@ -232,7 +232,7 @@ def test_waterfall_splits_collective_phase_and_censuses():
     spmd.waterfall.reset()
     spmd.enable_step_waterfall(True)
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             state, m = step(state, batch)
             state, m = step(state, batch)
     finally:

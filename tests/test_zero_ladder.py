@@ -51,7 +51,7 @@ def _run(mesh, rules, init_fn, loss_fn, tx, batch, stage, steps,
                            rules=rules if stage else None,
                            accum_steps=accum)
     losses = []
-    with mesh:
+    with jax.set_mesh(mesh):
         for _ in range(steps):
             state, m = step(state, batch)
             losses.append(float(m["loss"]))
@@ -213,7 +213,7 @@ def test_zero3_program_carries_param_gathers(mesh):
                                mesh=mesh if stage else None,
                                rules=rules if stage else None,
                                donate=False)
-        with mesh:
+        with jax.set_mesh(mesh):
             txt = step.jitted.lower(state, batch).compile().as_text()
         return collective_op_counts(txt)
 
@@ -273,7 +273,7 @@ def test_gather_share_gauge_populates_at_stage3(mesh):
     spmd.waterfall.reset()
     spmd.enable_step_waterfall(True)
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             state, _ = step(state, batch)
             state, _ = step(state, batch)
     finally:
