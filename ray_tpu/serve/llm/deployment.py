@@ -76,9 +76,11 @@ class LLMServer:
     def _step_loop(self):
         import time
 
+        idle = self.engine.phases.phase("idle")
         while self._alive:
             if not self.engine.step():
-                time.sleep(0.002)  # idle: nothing queued or running
+                with idle:
+                    time.sleep(0.002)  # nothing queued or running
 
     def __call__(self, payload: dict | None):
         payload = payload or {}

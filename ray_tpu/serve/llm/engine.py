@@ -30,6 +30,7 @@ from ray_tpu.serve.llm.scheduler import (
     Scheduler,
     Sequence,
 )
+from ray_tpu.util import tracing
 
 _FINAL = object()
 
@@ -95,6 +96,8 @@ class LLMEngine:
                  mesh=None):
         import jax
 
+        # before the first compile, so that start-up's share is counted
+        tracing.watch_compiles()
         self.config = config
         reg = adapters()
         if config.model not in reg:
@@ -117,8 +120,16 @@ class LLMEngine:
                 f"max_model_len {max_len} exceeds the model's positional "
                 f"range {cfg.block_size}")
 
+        # where a replica's start goes, host clock, seconds (stats())
+        self._startup = dict.fromkeys(
+            ("init_params", "build_runner", "warmup", "warmup_trace",
+             "warmup_lower", "warmup_compile"), 0.0)
+        self._warmup_cache = {"hits": 0, "misses": 0}
         if params is None:
-            params = adapter.init_fn(jax.random.PRNGKey(config.seed), cfg)
+            t0 = time.perf_counter()
+            params = jax.block_until_ready(
+                adapter.init_fn(jax.random.PRNGKey(config.seed), cfg))
+            self._startup["init_params"] = time.perf_counter() - t0
 
         num_blocks = config.num_blocks
         if num_blocks is None:
@@ -156,6 +167,7 @@ class LLMEngine:
         spec_cfg = config.speculative
         self._proposer = build_proposer(spec_cfg) if spec_cfg else None
         self._spec_k = spec_cfg.num_draft_tokens if spec_cfg else 0
+        t0 = time.perf_counter()
         self.runner = ModelRunner(
             adapter, cfg, params,
             block_size=config.block_size,
@@ -170,6 +182,17 @@ class LLMEngine:
             num_draft_tokens=self._spec_k,
             use_paged_attention=config.use_paged_attention,
         )
+        # weights cast and placed, both pools allocated
+        jax.block_until_ready((self.runner.params, self.runner.k_pages,
+                               self.runner.v_pages))
+        self._startup["build_runner"] = time.perf_counter() - t0
+        # seconds by phase of the step loop, step counts and bytes
+        # fetched to the host by step kind: plain numbers, written by
+        # the one thread that steps (under _step_lock), copied by stats()
+        self.phases = self.runner.phases
+        self._steps = {"decode": 0, "prefill": 0}
+        self._d2h = {"decode": 0, "prefill": 0}
+        self._fetched_seen = 0
         self.scheduler = Scheduler(
             self.pool, max_batch_size=config.max_batch_size,
             max_model_len=max_len,
@@ -308,6 +331,11 @@ class LLMEngine:
         self._m_paged.set(
             1.0 if self.runner.use_paged_attention else 0.0,
             tags=self._m_tags)
+        self._m_d2h = Counter(
+            "serve_llm_d2h_bytes_total",
+            "Bytes of device results fetched to the host by engine "
+            "steps (sampled tokens and logits), by step kind",
+            tag_keys=("model", "kind"))
         self._spec_proposed_total = 0
         self._spec_accepted_total = 0
         # counter deltas are computed against the last pump
@@ -340,7 +368,6 @@ class LLMEngine:
         # the request's trace context: a child of whatever span chain
         # submitted it (handle call, proxy request), so the finalize-
         # time waterfall spans correlate by trace_id
-        from ray_tpu.util import tracing
         from ray_tpu.utils.events import child_trace
 
         seq.trace = child_trace(tracing.current_trace())
@@ -385,58 +412,74 @@ class LLMEngine:
         when there was nothing to do. Serialized: concurrent callers
         queue behind `_step_lock` (the deployment runs a single loop
         thread; tests may drive from several)."""
+        phase = self.phases.phase
         with self._step_lock:
-            with self._lock:
-                pre = self.scheduler.preemption_count
-                work = self.scheduler.schedule()  # may preempt lanes
-                d_pre = self.scheduler.preemption_count - pre
-                retired = self.scheduler.take_retired()
-            if d_pre:
-                self._m_preempt.inc(d_pre, tags=self._m_tags)
-            for s in retired:  # schedule() closed these out itself
-                self._finalize(s)
+            with phase("schedule"):
+                with self._lock:
+                    pre = self.scheduler.preemption_count
+                    work = self.scheduler.schedule()  # may preempt lanes
+                    d_pre = self.scheduler.preemption_count - pre
+                    retired = self.scheduler.take_retired()
+                    # lanes a prefill step is holding back
+                    stalled = isinstance(work, PrefillWork) and any(
+                        s is not work.seq and not s.prefill_pending
+                        for s in self.scheduler.running)
+                if d_pre:
+                    self._m_preempt.inc(d_pre, tags=self._m_tags)
+                for s in retired:  # schedule() closed these out itself
+                    self._finalize(s)
             if work is None:
                 return retired != []
-            t0 = time.perf_counter()
-            if isinstance(work, PrefillWork):
-                with self._lock:
-                    # lanes this prefill step is holding back
-                    stalled = sum(
-                        1 for s in self.scheduler.running
-                        if s is not work.seq and not s.prefill_pending)
-                self._do_prefill(work)
-                kind = "prefill"
-                if stalled:
-                    self._m_stall.observe(
-                        (time.perf_counter() - t0) * 1e3,
-                        tags=self._m_tags)
-            else:
-                self._do_decode(work)
-                kind = "decode"
-            self._m_step.observe(
-                (time.perf_counter() - t0) * 1e3,
-                tags={"model": self.config.model, "kind": kind})
-            depth = self.scheduler.depth()
-            self._m_queue.set(depth["waiting"], tags=self._m_tags)
-            self._m_running.set(depth["running"], tags=self._m_tags)
-            self._m_cache.set(depth["cache_utilization"],
-                              tags=self._m_tags)
-            self._m_cached_blocks.set(depth["blocks_cached"],
-                                      tags=self._m_tags)
-            hits, misses, evict = (depth["prefix_hit_pages"],
-                                   depth["prefix_miss_pages"],
-                                   depth["prefix_evictions"])
-            lh, lm, le = self._last_prefix
-            self._last_prefix = (hits, misses, evict)
-            if hits > lh:
-                self._m_prefix_hits.inc(hits - lh, tags=self._m_tags)
-            if misses > lm:
-                self._m_prefix_misses.inc(misses - lm, tags=self._m_tags)
-            if evict > le:
-                self._m_prefix_evict.inc(evict - le, tags=self._m_tags)
+            kind = "prefill" if isinstance(work, PrefillWork) else "decode"
+            with tracing.annotate("llm.step." + kind):
+                t0 = time.perf_counter()
+                if kind == "prefill":
+                    tokens = self._do_prefill(work)
+                else:
+                    tokens = self._do_decode(work)
+                with phase("bookkeep"):
+                    self._bookkeep(kind, tokens, stalled,
+                                   (time.perf_counter() - t0) * 1e3)
             return True
 
-    def _do_prefill(self, work: PrefillWork) -> None:
+    def _bookkeep(self, kind: str, tokens: int, stalled: bool,
+                  step_ms: float) -> None:
+        """What a step writes down once its program's results are out:
+        the step's metrics, the scheduler's gauges, the prefix cache's
+        counters, the token rate, and the bytes it fetched."""
+        if stalled:
+            self._m_stall.observe(step_ms, tags=self._m_tags)
+        self._m_step.observe(
+            step_ms, tags={"model": self.config.model, "kind": kind})
+        depth = self.scheduler.depth()
+        self._m_queue.set(depth["waiting"], tags=self._m_tags)
+        self._m_running.set(depth["running"], tags=self._m_tags)
+        self._m_cache.set(depth["cache_utilization"], tags=self._m_tags)
+        self._m_cached_blocks.set(depth["blocks_cached"],
+                                  tags=self._m_tags)
+        hits, misses, evict = (depth["prefix_hit_pages"],
+                               depth["prefix_miss_pages"],
+                               depth["prefix_evictions"])
+        lh, lm, le = self._last_prefix
+        self._last_prefix = (hits, misses, evict)
+        if hits > lh:
+            self._m_prefix_hits.inc(hits - lh, tags=self._m_tags)
+        if misses > lm:
+            self._m_prefix_misses.inc(misses - lm, tags=self._m_tags)
+        if evict > le:
+            self._m_prefix_evict.inc(evict - le, tags=self._m_tags)
+        if tokens:
+            self._note_tokens(tokens)
+        self._steps[kind] += 1
+        fetched = self.runner.fetched_bytes - self._fetched_seen
+        self._fetched_seen += fetched
+        self._d2h[kind] += fetched
+        self._m_d2h.inc(fetched,
+                        tags={"model": self.config.model, "kind": kind})
+
+    def _do_prefill(self, work: PrefillWork) -> int:
+        """One prefill program; returns the tokens it produced (one on a
+        prompt's last chunk, none before)."""
         seq = work.seq
         sp = seq.sampling
         ver = self._weight_version  # stable: step holds _step_lock
@@ -455,59 +498,67 @@ class LLMEngine:
             with self._lock:
                 self.scheduler.abort(seq, f"error:{e!r}")
             self._finalize(seq)
-            return
-        self._m_chunks.inc(tags=self._m_tags)
-        seq.note_phase("prefill")  # chunk + its scheduling gap
-        with self._lock:
-            # full pages covered by this chunk are now shareable (the
-            # state check skips sequences aborted mid-flight: their
-            # pages may already belong to someone else)
-            self.scheduler.register_prefilled_pages(seq, work.end)
-        if not work.is_last:
-            return  # intermediate chunk: no token was produced
-        if seq.first_token_at is None:
-            now = time.monotonic()
-            self._m_ttft.observe(
-                (now - seq.enqueued_at) * 1e3, tags=self._m_tags)
-            # TTFT split for the SLO plane: queue vs prefill work
-            ph = seq.phases
-            self._m_slo_ttft.observe(
-                (ph.get("queue", 0.0) + ph.get("preempt", 0.0)) * 1e3,
-                tags={"model": self.config.model, "phase": "queue"})
-            self._m_slo_ttft.observe(
-                (ph.get("prefix_match", 0.0) + ph.get("prefill", 0.0))
-                * 1e3,
-                tags={"model": self.config.model, "phase": "prefill"})
-            self._m_slo_ttft.observe(
-                (now - seq.enqueued_at) * 1e3,
-                tags={"model": self.config.model, "phase": "total"})
-        if sp.logprobs:
-            seq.logprobs.append(self._logprob_of(last, nxt, sp.temperature))
-        with self._lock:
-            seq.token_versions.append(ver)
-            done = self.scheduler.commit_token(seq, nxt)
-        self._emit_token(seq, nxt, ver)
-        self._note_tokens(1)
-        if done:
-            self._finalize(seq)
+            return 0
+        with self.phases.phase("commit"):
+            self._m_chunks.inc(tags=self._m_tags)
+            seq.note_phase("prefill")  # chunk + its scheduling gap
+            with self._lock:
+                # full pages covered by this chunk are now shareable (the
+                # state check skips sequences aborted mid-flight: their
+                # pages may already belong to someone else)
+                self.scheduler.register_prefilled_pages(seq, work.end)
+            if not work.is_last:
+                return 0  # intermediate chunk: no token was produced
+            if seq.first_token_at is None:
+                self._observe_ttft(seq)
+            if sp.logprobs:
+                seq.logprobs.append(
+                    self._logprob_of(last, nxt, sp.temperature))
+            with self._lock:
+                seq.token_versions.append(ver)
+                done = self.scheduler.commit_token(seq, nxt)
+        with self.phases.phase("emit"):
+            self._emit_token(seq, nxt, ver)
+            if done:
+                self._finalize(seq)
+        return 1
 
-    def _do_decode(self, work: DecodeWork) -> None:
+    def _observe_ttft(self, seq: Sequence) -> None:
+        now = time.monotonic()
+        self._m_ttft.observe(
+            (now - seq.enqueued_at) * 1e3, tags=self._m_tags)
+        # TTFT split for the SLO plane: queue vs prefill work
+        ph = seq.phases
+        self._m_slo_ttft.observe(
+            (ph.get("queue", 0.0) + ph.get("preempt", 0.0)) * 1e3,
+            tags={"model": self.config.model, "phase": "queue"})
+        self._m_slo_ttft.observe(
+            (ph.get("prefix_match", 0.0) + ph.get("prefill", 0.0)) * 1e3,
+            tags={"model": self.config.model, "phase": "prefill"})
+        self._m_slo_ttft.observe(
+            (now - seq.enqueued_at) * 1e3,
+            tags={"model": self.config.model, "phase": "total"})
+
+    def _do_decode(self, work: DecodeWork) -> int:
+        """The decode programs of one step (one plain dispatch, plus one
+        verify dispatch per drafted lane); returns the tokens produced."""
         ver = self._weight_version  # stable: step holds _step_lock
         plain: list[Sequence] = []
         drafted: list[tuple[Sequence, list[int]]] = []
         if self._proposer is not None:
-            for s in work.seqs:
-                d = self._propose_for(s)
-                if d:
-                    drafted.append((s, d))
-                else:
-                    plain.append(s)
+            with self.phases.phase("prepare"):
+                for s in work.seqs:
+                    d = self._propose_for(s)
+                    if d:
+                        drafted.append((s, d))
+                    else:
+                        plain.append(s)
         else:
             plain = list(work.seqs)
-        if plain:
-            self._decode_plain(plain, ver)
+        tokens = self._decode_plain(plain, ver) if plain else 0
         for s, d in drafted:
-            self._verify_one(s, d, ver)
+            tokens += self._verify_one(s, d, ver)
+        return tokens
 
     def _propose_for(self, seq: Sequence) -> list[int]:
         """Draft tokens for one lane, clamped so every drafted write
@@ -525,13 +576,14 @@ class LLMEngine:
         return self._proposer.propose(
             list(seq.prompt) + list(seq.generated), k)[:k]
 
-    def _decode_plain(self, seqs: list[Sequence], ver: int) -> None:
+    def _decode_plain(self, seqs: list[Sequence], ver: int) -> int:
         # the lane feeds generated[-1], which LIVES at absolute position
         # pos-1 (it was sampled but never cached): rope/wpe index, the
         # context mask, and the KV scatter all key off that position
-        items = [DecodeItem(s.last_token, s.pos - 1, s.table,
-                            s.sampling.temperature, s.sampling.top_k,
-                            s.sampling.top_p) for s in seqs]
+        with self.phases.phase("prepare"):
+            items = [DecodeItem(s.last_token, s.pos - 1, s.table,
+                                s.sampling.temperature, s.sampling.top_k,
+                                s.sampling.top_p) for s in seqs]
         try:
             next_tokens, logits = self.runner.decode(items)
         except Exception as e:  # noqa: BLE001
@@ -540,33 +592,36 @@ class LLMEngine:
                     self.scheduler.abort(s, f"error:{e!r}")
             for s in seqs:
                 self._finalize(s)
-            return
-        for i, (s, tok) in enumerate(zip(seqs, next_tokens)):
-            if s.sampling.logprobs:
-                s.logprobs.append(self._logprob_of(
-                    logits[i], tok, s.sampling.temperature))
-        now = time.monotonic()
-        for s in seqs:
-            s.note_phase("decode", now)  # step + its scheduling gap
-        finished = []
-        with self._lock:
+            return 0
+        with self.phases.phase("commit"):
+            for i, (s, tok) in enumerate(zip(seqs, next_tokens)):
+                if s.sampling.logprobs:
+                    s.logprobs.append(self._logprob_of(
+                        logits[i], tok, s.sampling.temperature))
+            now = time.monotonic()
+            for s in seqs:
+                s.note_phase("decode", now)  # step + its scheduling gap
+            finished = []
+            with self._lock:
+                for s, tok in zip(seqs, next_tokens):
+                    s.token_versions.append(ver)
+                    if self.scheduler.commit_token(s, tok):
+                        finished.append(s)
+        with self.phases.phase("emit"):
             for s, tok in zip(seqs, next_tokens):
-                s.token_versions.append(ver)
-                if self.scheduler.commit_token(s, tok):
-                    finished.append(s)
-        for s, tok in zip(seqs, next_tokens):
-            self._emit_token(s, tok, ver)
-        self._note_tokens(len(next_tokens))
-        for s in finished:
-            self._finalize(s)
+                self._emit_token(s, tok, ver)
+            for s in finished:
+                self._finalize(s)
+        return len(next_tokens)
 
     def _verify_one(self, seq: Sequence, draft: list[int],
-                    ver: int) -> None:
+                    ver: int) -> int:
         """One speculative step for one lane: a single verify dispatch
         scores the frontier token plus the drafts, the acceptance rule
         runs in-jit, and every returned token is already backed by KV —
         commit them in order (stopping if the lane retires mid-run on
-        eos / max_tokens) and emit with explicit stream indices."""
+        eos / max_tokens) and emit with explicit stream indices.
+        Returns the tokens committed."""
         sp = seq.sampling
         t0 = time.perf_counter()
         try:
@@ -577,40 +632,42 @@ class LLMEngine:
             with self._lock:
                 self.scheduler.abort(seq, f"error:{e!r}")
             self._finalize(seq)
-            return
-        self._m_verify_ms.observe(
-            (time.perf_counter() - t0) * 1e3, tags=self._m_tags)
-        n_acc = len(tokens) - 1
-        self._spec_proposed_total += len(draft)
-        self._spec_accepted_total += n_acc
-        self._m_spec_proposed.inc(len(draft), tags=self._m_tags)
-        if n_acc:
-            self._m_spec_accepted.inc(n_acc, tags=self._m_tags)
-        if len(draft) > n_acc:
-            self._m_spec_rejected.inc(len(draft) - n_acc,
-                                      tags=self._m_tags)
-        self._m_spec_ratio.set(
-            self._spec_accepted_total
-            / max(1, self._spec_proposed_total), tags=self._m_tags)
-        seq.note_phase("verify", time.monotonic())
-        committed: list[int] = []
-        done = False
-        with self._lock:
-            for i, tok in enumerate(tokens):
-                if sp.logprobs:
-                    seq.logprobs.append(self._logprob_of(
-                        logits[i], tok, sp.temperature))
-                seq.token_versions.append(ver)
-                committed.append(tok)
-                if self.scheduler.commit_token(seq, tok):
-                    done = True
-                    break
-        base = len(seq.generated) - len(committed)
-        for j, tok in enumerate(committed):
-            self._emit_token(seq, tok, ver, index=base + j)
-        self._note_tokens(len(committed))
-        if done:
-            self._finalize(seq)
+            return 0
+        with self.phases.phase("commit"):
+            self._m_verify_ms.observe(
+                (time.perf_counter() - t0) * 1e3, tags=self._m_tags)
+            n_acc = len(tokens) - 1
+            self._spec_proposed_total += len(draft)
+            self._spec_accepted_total += n_acc
+            self._m_spec_proposed.inc(len(draft), tags=self._m_tags)
+            if n_acc:
+                self._m_spec_accepted.inc(n_acc, tags=self._m_tags)
+            if len(draft) > n_acc:
+                self._m_spec_rejected.inc(len(draft) - n_acc,
+                                          tags=self._m_tags)
+            self._m_spec_ratio.set(
+                self._spec_accepted_total
+                / max(1, self._spec_proposed_total), tags=self._m_tags)
+            seq.note_phase("verify", time.monotonic())
+            committed: list[int] = []
+            done = False
+            with self._lock:
+                for i, tok in enumerate(tokens):
+                    if sp.logprobs:
+                        seq.logprobs.append(self._logprob_of(
+                            logits[i], tok, sp.temperature))
+                    seq.token_versions.append(ver)
+                    committed.append(tok)
+                    if self.scheduler.commit_token(seq, tok):
+                        done = True
+                        break
+        with self.phases.phase("emit"):
+            base = len(seq.generated) - len(committed)
+            for j, tok in enumerate(committed):
+                self._emit_token(seq, tok, ver, index=base + j)
+            if done:
+                self._finalize(seq)
+        return len(committed)
 
     # ------------------------------------------------------------ output
 
@@ -711,7 +768,6 @@ class LLMEngine:
         decode — so the contiguous layout is the readable summary, and
         the durations are the exact per-phase totals). All hang off the
         request's propagated trace context."""
-        from ray_tpu.util import tracing
         from ray_tpu.utils.events import child_trace
 
         tracing.record_interval("llm.request", seq.enqueued_at, now,
@@ -754,8 +810,6 @@ class LLMEngine:
 
         Returns swap stats (previous version, wall time, in-flight
         stream count, registrations dropped)."""
-        from ray_tpu.util import tracing
-
         t0 = time.perf_counter()
         with self._step_lock:
             if version <= self._weight_version:
@@ -784,7 +838,23 @@ class LLMEngine:
         batch sizes) so no request pays a mid-stream XLA compile;
         returns the compiled-program count."""
         with self._step_lock:
-            return self.runner.warmup()
+            before = tracing.compile_totals()
+            t0 = time.perf_counter()
+            programs = self.runner.warmup()
+            wall = time.perf_counter() - t0
+            spent = {k: v - before[k]
+                     for k, v in tracing.compile_totals().items()}
+            # what warm-up fetched is no step's
+            self._fetched_seen = self.runner.fetched_bytes
+        up = self._startup
+        up["warmup"] += wall
+        up["warmup_trace"] += spent["trace"]
+        up["warmup_lower"] += spent["lower"]
+        # XLA's compile, or the load from the persistent cache on a hit
+        up["warmup_compile"] += spent["backend_compile"]
+        self._warmup_cache["hits"] += int(spent["hits"])
+        self._warmup_cache["misses"] += int(spent["misses"])
+        return programs
 
     def has_work(self) -> bool:
         with self._lock:
@@ -809,6 +879,14 @@ class LLMEngine:
             # per replica by util.state.llm_status()
             "phase_seconds": phase_totals,
             "finished_requests": finished,
+            # the step loop's own account (see OBSERVABILITY.md): host
+            # seconds by phase, steps and bytes fetched by step kind,
+            # and where this replica's start went
+            "step_phase_seconds": dict(self.phases.seconds),
+            "steps": dict(self._steps),
+            "d2h_bytes": dict(self._d2h),
+            "startup_seconds": dict(self._startup),
+            "warmup_cache": dict(self._warmup_cache),
             "spec_proposed": self._spec_proposed_total,
             "spec_accepted": self._spec_accepted_total,
             "paged_attention": self.runner.use_paged_attention,

@@ -42,6 +42,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.util import tracing
+
+# The step loop's phases, as `engine.stats()["step_phase_seconds"]` and
+# as `llm.<phase>` events in a device profile. The engine times
+# schedule, commit, emit and bookkeep; the runner prepare, dispatch and
+# fetch; the deployment's loop idle.
+STEP_PHASES = ("schedule", "prepare", "dispatch", "fetch", "commit",
+               "emit", "bookkeep", "idle")
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelAdapter:
@@ -285,6 +294,10 @@ class ModelRunner:
         # pages are mutated functionally; serialize compute just in case
         # a stats probe races the step loop
         self._jit_lock = threading.Lock()
+        self.phases = tracing.PhaseClock("llm.", STEP_PHASES)
+        # bytes of device results copied to the host, ever (tokens and
+        # logits, every `np.asarray` / `int()` of a program's output)
+        self.fetched_bytes = 0
         # compile observability: warmup() should account for ALL misses;
         # a mid-stream miss afterwards is the recompile bug these catch
         from ray_tpu.util.metrics import Counter, Histogram
@@ -299,8 +312,6 @@ class ModelRunner:
             tag_keys=("model", "kind"))
 
     def _note_compile(self, kind: str, jit_fn, before: int, dt: float):
-        from ray_tpu.util import tracing
-
         tracing.note_compile_if_grew(
             jit_fn, before, dt, self._m_compile_miss, self._m_compile_s,
             f"llm.compile.{kind}",
@@ -464,6 +475,14 @@ class ModelRunner:
 
     # -------------------------------------------------------------- host
 
+    def _fetch(self, *results) -> list[np.ndarray]:
+        """Device results as numpy arrays: the wait for the program that
+        makes them, then the copy to the host."""
+        with self.phases.phase("fetch"):
+            out = [np.asarray(r) for r in results]
+            self.fetched_bytes += sum(a.nbytes for a in out)
+        return out
+
     def _mesh_ctx(self):
         return (jax.set_mesh(self.mesh) if self.mesh is not None
                 else contextlib.nullcontext())
@@ -491,30 +510,32 @@ class ModelRunner:
         """Run one prompt through monolithic prefill; returns (first
         generated token, last-position logits). `table` must cover
         blocks_for_tokens(len(token_ids)) pages."""
-        n = len(token_ids)
-        Tb = self.prefill_bucket(n)
-        toks = np.zeros((1, Tb), np.int32)
-        toks[0, :n] = token_ids
-        block_ids = np.zeros((Tb,), np.int32)
-        offsets = np.arange(Tb, dtype=np.int32) % self.block_size
-        pos = np.arange(n)
-        block_ids[:n] = np.asarray(table, np.int32)[pos // self.block_size]
-        temp = np.asarray([temperature], np.float32)
-        topk = np.asarray([top_k], np.int32)
-        topp = np.asarray([top_p], np.float32)
-        self._step_counter += 1
-        from ray_tpu.util.tracing import jit_cache_size
-
-        before = jit_cache_size(self._prefill_jit)
-        t0 = time.perf_counter()
-        with self._mesh_ctx(), self._jit_lock:
-            nxt, last, self.k_pages, self.v_pages = self._prefill_jit(
-                self.params, self.k_pages, self.v_pages, toks,
-                np.int32(n - 1), block_ids, offsets, temp, topk, topp,
-                np.int32(self._step_counter))
-        self._note_compile("prefill", self._prefill_jit, before,
-                           time.perf_counter() - t0)
-        return int(nxt), np.asarray(last)
+        with self.phases.phase("prepare"):
+            n = len(token_ids)
+            Tb = self.prefill_bucket(n)
+            toks = np.zeros((1, Tb), np.int32)
+            toks[0, :n] = token_ids
+            block_ids = np.zeros((Tb,), np.int32)
+            offsets = np.arange(Tb, dtype=np.int32) % self.block_size
+            pos = np.arange(n)
+            block_ids[:n] = np.asarray(
+                table, np.int32)[pos // self.block_size]
+            temp = np.asarray([temperature], np.float32)
+            topk = np.asarray([top_k], np.int32)
+            topp = np.asarray([top_p], np.float32)
+            self._step_counter += 1
+        with self.phases.phase("dispatch"):
+            before = tracing.jit_cache_size(self._prefill_jit)
+            t0 = time.perf_counter()
+            with self._mesh_ctx(), self._jit_lock:
+                nxt, last, self.k_pages, self.v_pages = self._prefill_jit(
+                    self.params, self.k_pages, self.v_pages, toks,
+                    np.int32(n - 1), block_ids, offsets, temp, topk, topp,
+                    np.int32(self._step_counter))
+            self._note_compile("prefill", self._prefill_jit, before,
+                               time.perf_counter() - t0)
+        nxt, last = self._fetch(nxt, last)
+        return int(nxt), last
 
     def prefill_chunk(self, token_ids: Sequence[int], start: int,
                       table: Sequence[int], temperature: float,
@@ -527,74 +548,76 @@ class ModelRunner:
         must be page-aligned. Returns (sampled next token, last-chunk-
         position logits) — the caller only uses them on the final
         chunk."""
-        n = len(token_ids)
-        if start % self.block_size:
-            raise ValueError(
-                f"chunk start {start} not page-aligned "
-                f"(block_size={self.block_size})")
-        Tb = self.chunk_bucket(n)
-        toks = np.zeros((1, Tb), np.int32)
-        toks[0, :n] = token_ids
-        tab = np.zeros((self.max_blocks_per_seq,), np.int32)
-        tab[:len(table)] = table
-        block_ids = np.zeros((Tb,), np.int32)
-        pos = start + np.arange(n)
-        block_ids[:n] = tab[pos // self.block_size]
-        # padded tail positions keep in-range offsets but target page 0
-        offsets = np.asarray(
-            (start + np.arange(Tb)) % self.block_size, np.int32)
-        temp = np.asarray([temperature], np.float32)
-        topk = np.asarray([top_k], np.int32)
-        topp = np.asarray([top_p], np.float32)
-        self._step_counter += 1
-        from ray_tpu.util.tracing import jit_cache_size
-
-        before = jit_cache_size(self._chunk_jit)
-        t0 = time.perf_counter()
-        with self._mesh_ctx(), self._jit_lock:
-            nxt, last, self.k_pages, self.v_pages = self._chunk_jit(
-                self.params, self.k_pages, self.v_pages, toks,
-                np.int32(start), np.int32(n - 1), block_ids, offsets,
-                tab, temp, topk, topp, np.int32(self._step_counter))
-        self._note_compile("prefill_chunk", self._chunk_jit, before,
-                           time.perf_counter() - t0)
-        return int(nxt), np.asarray(last)
+        with self.phases.phase("prepare"):
+            n = len(token_ids)
+            if start % self.block_size:
+                raise ValueError(
+                    f"chunk start {start} not page-aligned "
+                    f"(block_size={self.block_size})")
+            Tb = self.chunk_bucket(n)
+            toks = np.zeros((1, Tb), np.int32)
+            toks[0, :n] = token_ids
+            tab = np.zeros((self.max_blocks_per_seq,), np.int32)
+            tab[:len(table)] = table
+            block_ids = np.zeros((Tb,), np.int32)
+            pos = start + np.arange(n)
+            block_ids[:n] = tab[pos // self.block_size]
+            # padded tail positions keep in-range offsets but target
+            # page 0
+            offsets = np.asarray(
+                (start + np.arange(Tb)) % self.block_size, np.int32)
+            temp = np.asarray([temperature], np.float32)
+            topk = np.asarray([top_k], np.int32)
+            topp = np.asarray([top_p], np.float32)
+            self._step_counter += 1
+        with self.phases.phase("dispatch"):
+            before = tracing.jit_cache_size(self._chunk_jit)
+            t0 = time.perf_counter()
+            with self._mesh_ctx(), self._jit_lock:
+                nxt, last, self.k_pages, self.v_pages = self._chunk_jit(
+                    self.params, self.k_pages, self.v_pages, toks,
+                    np.int32(start), np.int32(n - 1), block_ids, offsets,
+                    tab, temp, topk, topp, np.int32(self._step_counter))
+            self._note_compile("prefill_chunk", self._chunk_jit, before,
+                               time.perf_counter() - t0)
+        nxt, last = self._fetch(nxt, last)
+        return int(nxt), last
 
     def decode(self, items: Sequence[DecodeItem]
                ) -> tuple[list[int], np.ndarray]:
         """One decode step for up to max_batch_size sequences; returns
         (next token per item, logits (len(items), Vp))."""
-        S = len(items)
-        if not 0 < S <= self.max_batch_size:
-            raise ValueError(f"decode batch of {S}")
-        Sb = self.decode_bucket(S)
-        toks = np.zeros((Sb,), np.int32)
-        poss = np.zeros((Sb,), np.int32)
-        tables = np.zeros((Sb, self.max_blocks_per_seq), np.int32)
-        temps = np.zeros((Sb,), np.float32)
-        topks = np.zeros((Sb,), np.int32)
-        topps = np.ones((Sb,), np.float32)
-        for i, it in enumerate(items):
-            toks[i] = it.token
-            poss[i] = it.pos
-            tables[i, :len(it.table)] = it.table
-            temps[i] = it.temperature
-            topks[i] = it.top_k
-            topps[i] = it.top_p
-        self._step_counter += 1
-        from ray_tpu.util.tracing import jit_cache_size
-
-        before = jit_cache_size(self._decode_jit)
-        t0 = time.perf_counter()
-        with self._mesh_ctx(), self._jit_lock:
-            nxt, logits, self.k_pages, self.v_pages = self._decode_jit(
-                self.params, self.k_pages, self.v_pages, toks, poss,
-                tables, temps, topks, topps,
-                np.int32(self._step_counter))
-        self._note_compile("decode", self._decode_jit, before,
-                           time.perf_counter() - t0)
-        nxt = np.asarray(nxt)
-        return [int(t) for t in nxt[:S]], np.asarray(logits)[:S]
+        with self.phases.phase("prepare"):
+            S = len(items)
+            if not 0 < S <= self.max_batch_size:
+                raise ValueError(f"decode batch of {S}")
+            Sb = self.decode_bucket(S)
+            toks = np.zeros((Sb,), np.int32)
+            poss = np.zeros((Sb,), np.int32)
+            tables = np.zeros((Sb, self.max_blocks_per_seq), np.int32)
+            temps = np.zeros((Sb,), np.float32)
+            topks = np.zeros((Sb,), np.int32)
+            topps = np.ones((Sb,), np.float32)
+            for i, it in enumerate(items):
+                toks[i] = it.token
+                poss[i] = it.pos
+                tables[i, :len(it.table)] = it.table
+                temps[i] = it.temperature
+                topks[i] = it.top_k
+                topps[i] = it.top_p
+            self._step_counter += 1
+        with self.phases.phase("dispatch"):
+            before = tracing.jit_cache_size(self._decode_jit)
+            t0 = time.perf_counter()
+            with self._mesh_ctx(), self._jit_lock:
+                nxt, logits, self.k_pages, self.v_pages = self._decode_jit(
+                    self.params, self.k_pages, self.v_pages, toks, poss,
+                    tables, temps, topks, topps,
+                    np.int32(self._step_counter))
+            self._note_compile("decode", self._decode_jit, before,
+                               time.perf_counter() - t0)
+        nxt, logits = self._fetch(nxt, logits)
+        return [int(t) for t in nxt[:S]], logits[:S]
 
     def verify(self, token: int, pos: int, draft: Sequence[int],
                table: Sequence[int], temperature: float,
@@ -607,49 +630,50 @@ class ModelRunner:
         len(result[0]) is 1 (all rejected) .. len(draft)+1 (full accept
         plus the bonus token); the KV for every committed token is
         already in the pages when this returns."""
-        if not self.spec_width:
-            raise RuntimeError("runner built without num_draft_tokens")
-        n_draft = len(draft)
-        W = self.spec_width
-        if not 0 < n_draft < W:
-            raise ValueError(f"draft of {n_draft} tokens (max {W - 1})")
-        if pos + n_draft >= self.max_model_len:
-            raise ValueError(
-                f"drafted run past max_model_len: pos {pos} + "
-                f"{n_draft} drafts >= {self.max_model_len}")
-        toks = np.zeros((1, W), np.int32)
-        toks[0, 0] = token
-        toks[0, 1:1 + n_draft] = draft
-        tab = np.zeros((self.max_blocks_per_seq,), np.int32)
-        tab[:len(table)] = table
-        positions = pos + np.arange(W)
-        # padded tail rows write to the null page at in-range offsets
-        block_ids = np.where(np.arange(W) <= n_draft,
-                             tab[np.minimum(positions, self.max_model_len - 1)
-                                 // self.block_size],
-                             0).astype(np.int32)
-        offsets = np.asarray(positions % self.block_size, np.int32)
-        temps = np.full((W,), temperature, np.float32)
-        topks = np.full((W,), top_k, np.int32)
-        topps = np.full((W,), top_p, np.float32)
-        self._step_counter += 1
-        from ray_tpu.util.tracing import jit_cache_size
-
-        before = jit_cache_size(self._verify_jit)
-        t0 = time.perf_counter()
-        with self._mesh_ctx(), self._jit_lock:
-            emitted, n_acc, logits, self.k_pages, self.v_pages = \
-                self._verify_jit(
-                    self.params, self.k_pages, self.v_pages, toks,
-                    np.int32(pos), np.int32(n_draft), block_ids, offsets,
-                    tab, temps, topks, topps,
-                    np.int32(self._step_counter))
-        self._note_compile("verify", self._verify_jit, before,
-                           time.perf_counter() - t0)
+        with self.phases.phase("prepare"):
+            if not self.spec_width:
+                raise RuntimeError("runner built without num_draft_tokens")
+            n_draft = len(draft)
+            W = self.spec_width
+            if not 0 < n_draft < W:
+                raise ValueError(
+                    f"draft of {n_draft} tokens (max {W - 1})")
+            if pos + n_draft >= self.max_model_len:
+                raise ValueError(
+                    f"drafted run past max_model_len: pos {pos} + "
+                    f"{n_draft} drafts >= {self.max_model_len}")
+            toks = np.zeros((1, W), np.int32)
+            toks[0, 0] = token
+            toks[0, 1:1 + n_draft] = draft
+            tab = np.zeros((self.max_blocks_per_seq,), np.int32)
+            tab[:len(table)] = table
+            positions = pos + np.arange(W)
+            # padded tail rows write to the null page at in-range offsets
+            block_ids = np.where(
+                np.arange(W) <= n_draft,
+                tab[np.minimum(positions, self.max_model_len - 1)
+                    // self.block_size],
+                0).astype(np.int32)
+            offsets = np.asarray(positions % self.block_size, np.int32)
+            temps = np.full((W,), temperature, np.float32)
+            topks = np.full((W,), top_k, np.int32)
+            topps = np.full((W,), top_p, np.float32)
+            self._step_counter += 1
+        with self.phases.phase("dispatch"):
+            before = tracing.jit_cache_size(self._verify_jit)
+            t0 = time.perf_counter()
+            with self._mesh_ctx(), self._jit_lock:
+                emitted, n_acc, logits, self.k_pages, self.v_pages = \
+                    self._verify_jit(
+                        self.params, self.k_pages, self.v_pages, toks,
+                        np.int32(pos), np.int32(n_draft), block_ids,
+                        offsets, tab, temps, topks, topps,
+                        np.int32(self._step_counter))
+            self._note_compile("verify", self._verify_jit, before,
+                               time.perf_counter() - t0)
+        n_acc, emitted, logits = self._fetch(n_acc, emitted, logits)
         n_em = int(n_acc) + 1
-        emitted = np.asarray(emitted)
-        return ([int(t) for t in emitted[:n_em]],
-                np.asarray(logits)[:n_em])
+        return [int(t) for t in emitted[:n_em]], logits[:n_em]
 
     def warmup(self) -> int:
         """Compile every (bucket, kind) program up front so no request

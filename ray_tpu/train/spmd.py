@@ -507,8 +507,11 @@ def make_train_step(
     # a scaling cliff usually shows up first as recompiles or step-time
     # spread). Registry-backed, so worker-process numbers surface on the
     # head's cluster /metrics page tagged by node.
+    from ray_tpu.util import tracing
     from ray_tpu.util.metrics import Counter, Histogram
 
+    # jax's own account of its compiles (jax_compile_seconds_total)
+    tracing.watch_compiles()
     m_step = Histogram(
         "train_step_seconds",
         "Host-side train-step dispatch time (includes device wait on "
@@ -541,7 +544,6 @@ def make_train_step(
     def _attributed_step(state: TrainState, batch: PyTree):
         """Waterfall-mode step: wall-to-wall phase attribution. Adds a
         device sync per step (a profiling run, not a record run)."""
-        from ray_tpu.util import tracing
         from ray_tpu.util.collective import _collective_seconds
 
         data_wait = waterfall.take_data_wait()
@@ -621,8 +623,6 @@ def make_train_step(
     def instrumented(state: TrainState, batch: PyTree):
         if waterfall.enabled:
             return _attributed_step(state, batch)
-        from ray_tpu.util import tracing
-
         before = tracing.jit_cache_size(jitted)
         t0 = time.perf_counter()
         out = jitted(state, batch)

@@ -167,6 +167,19 @@ CATALOG: list[dict] = [
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "1 when decode/verify run the pallas paged-attention "
              "kernel, 0 on the dense gather fallback"},
+    {"name": "serve_llm_d2h_bytes_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "bytes of device results (tokens, logits) the engine's "
+             "steps fetched to the host, by step kind"},
+    # jax's own account of its compiles (every process that compiles)
+    {"name": "jax_compile_seconds_total", "type": "counter",
+     "where": "ray_tpu/util/tracing.py",
+     "what": "seconds jax spent per compile stage: trace, lower, "
+             "backend_compile (XLA or the cache load), cache_retrieval"},
+    {"name": "jax_compile_cache_total", "type": "counter",
+     "where": "ray_tpu/util/tracing.py",
+     "what": "persistent compile cache lookups that found (hits) or "
+             "wrote (misses) an entry"},
     # serve SLO attribution (the per-request waterfall's metric face)
     {"name": "serve_slo_ttft_ms", "type": "histogram",
      "where": "ray_tpu/serve/llm/engine.py",

@@ -22,6 +22,7 @@ exports."""
 from __future__ import annotations
 
 import contextlib
+import sys
 import threading
 import time
 
@@ -51,17 +52,70 @@ def current_trace() -> dict | None:
     return getattr(ctx, "trace", None)
 
 
+def annotate(name: str):
+    """A host event named `name` in the device profiler's own trace (a
+    `jax.profiler.TraceAnnotation`: plane `/host:CPU`, the calling
+    thread's line, the clock the device operations are on), so a gap
+    between two device programs can be laid against what the host was
+    doing in it. Recorded only while a profile is being taken; entering
+    and leaving one costs about half a microsecond otherwise. Nothing
+    goes to the span log: this is for loops too hot for it. A process
+    that has not imported jax gets a null context and still does not
+    import it."""
+    # (getattr: another thread may be half way through importing jax)
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    annotation = getattr(profiler, "TraceAnnotation", None)
+    if annotation is None:
+        return contextlib.nullcontext()
+    return annotation(name)
+
+
+class PhaseClock:
+    """Seconds a loop spent in each of its phases, always on: one
+    `perf_counter` pair per phase into a plain dict (`seconds`), and the
+    same interval as an `annotate(prefix + name)` event for a device
+    profile. Phases do not nest: they are the leaves, so `seconds` sums
+    to the loop's wall time. One thread drives the loop; readers copy
+    `seconds`."""
+
+    def __init__(self, prefix: str, names: tuple[str, ...]):
+        self.prefix = prefix
+        self.seconds: dict[str, float] = dict.fromkeys(names, 0.0)
+
+    def phase(self, name: str) -> "_Phase":
+        return _Phase(self, name)
+
+
+class _Phase:
+    __slots__ = ("clock", "name", "ann", "t0")
+
+    def __init__(self, clock: PhaseClock, name: str):
+        self.clock = clock
+        self.name = name
+
+    def __enter__(self):
+        self.ann = annotate(self.clock.prefix + self.name)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self.clock.seconds[self.name] += time.perf_counter() - self.t0
+        self.ann.__exit__(*exc)
+
+
 @contextlib.contextmanager
 def span(name: str, category: str = "user"):
     """Record a span around the enclosed block and make it the current
     trace context (children — nested spans, submitted tasks, actor
-    calls — link to it). Yields the span's trace context."""
+    calls — link to it). Yields the span's trace context. The block also
+    shows under the same name in a device profile taken while it runs
+    (`annotate`)."""
     ctx, log = _ctx_and_log()
     parent = getattr(ctx, "trace", None)
     trace = child_trace(parent)
     ctx.trace = trace
     try:
-        with log.span(name, category, trace=trace):
+        with log.span(name, category, trace=trace), annotate(name):
             yield trace
     finally:
         ctx.trace = parent
@@ -102,7 +156,14 @@ def profiler_capture(out_dir: str | None):
     time (collective vs. GEMM vs. copy) the host-side span plane cannot
     see. Guarded no-op on CPU and when `out_dir` is falsy, so bench
     drivers call it unconditionally: on TPU a `--trace` run captures N
-    timed steps, on CPU nothing is armed and nothing is written.
+    timed steps, on CPU nothing is armed and nothing is written. On any
+    other backend a capture that cannot start or stop raises: a profile
+    that silently was not taken is worse than none.
+
+    The capture holds device operations and host events (jax's own and
+    every `annotate` / `span`), without the Python call tracer, which
+    slows the host several times over and so inflates the very idle
+    time the profile is read for.
 
     The capture window rides the span API: a `profiler.capture` span
     (category `profiler`) covers the armed block, and its trace args
@@ -118,21 +179,13 @@ def profiler_capture(out_dir: str | None):
     if jax.devices()[0].platform in ("cpu",):
         yield None
         return
-    try:
-        profile = jax.profiler.trace(out_dir)
-        profile.__enter__()
-    except Exception:  # noqa: BLE001  # profiler unavailable on this
-        yield None  # backend/build: the bench still runs, un-profiled
-        return
-    with span("profiler.capture", category="profiler") as trace:
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    with jax.profiler.trace(out_dir, profiler_options=options), \
+            span("profiler.capture", category="profiler") as trace:
         trace["capture_path"] = out_dir
-        try:
-            yield out_dir
-        finally:
-            try:
-                profile.__exit__(None, None, None)
-            except Exception:  # noqa: BLE001
-                pass  # a failed stop must not eat the bench result
+        yield out_dir
 
 
 def jit_cache_size(jit_fn) -> int:
@@ -160,6 +213,108 @@ def note_compile_if_grew(jit_fn, before: int, duration_s: float,
     compile_hist.observe(duration_s, tags=tags)
     record_span(span_name, duration_s, category="compile")
     return True
+
+
+# what jax itself reports of a compile, by the stage it names
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # XLA's compile, or on a persistent-cache hit the load that
+    # replaces it: jax times both under this one event
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_RESULTS = {
+    "/jax/compilation_cache/cache_hits": "hits",
+    # jax counts a miss when it writes the entry, so a program too
+    # quick to be worth caching is neither
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+_compile_totals: dict[str, float] = dict.fromkeys(
+    (*_COMPILE_STAGES.values(), "cache_retrieval",
+     *_CACHE_RESULTS.values()), 0)
+_compile_lock = threading.Lock()
+_compile_watched = False
+_compile_open = threading.local()  # .spans: this thread's [start, end]s
+
+
+def _own_seconds(duration: float) -> float:
+    """jax reports a stage when it ends, and stages nest: a jitted
+    function called while another is traced reports its own trace
+    first, a helper jitted inside a lowering rule its own three. The
+    part of this report that no earlier report of this thread already
+    covers, so that the stages sum to no more than the wall clock."""
+    end = time.monotonic()
+    start = end - duration
+    spans = _compile_open.__dict__.setdefault("spans", [])
+    own = duration
+    # reports come in order of their ends, so what began inside this one
+    # is a suffix (100 us of grace: both starts are read a little late)
+    while spans and spans[-1][0] >= start - 1e-4:
+        s0, e0 = spans.pop()
+        own -= e0 - s0
+    spans.append((start, end))
+    return max(own, 0.0)
+
+
+def watch_compiles() -> None:
+    """Install, once per process, the `jax.monitoring` listeners that
+    sum what jax spends tracing, lowering and compiling (or loading
+    from the persistent cache), and how often that cache hit or missed:
+    `compile_totals()`, `jax_compile_seconds_total{stage}`,
+    `jax_compile_cache_total{result}`. They fire only when jax
+    compiles, never on a step path. Called by whoever is about to
+    compile (the serve engine, the train step builder)."""
+    global _compile_watched
+    with _compile_lock:
+        if _compile_watched:
+            return
+        _compile_watched = True
+    from jax import monitoring
+
+    from ray_tpu.util.metrics import Counter
+
+    seconds = Counter(
+        "jax_compile_seconds_total",
+        "Seconds jax spent per compile stage in this process: trace, "
+        "lower, backend_compile (XLA, or the cache load on a hit), each "
+        "without what nests inside it; cache_retrieval is the part of "
+        "backend_compile spent reading the persistent cache",
+        tag_keys=("stage",))
+    cache = Counter(
+        "jax_compile_cache_total",
+        "Persistent compile cache lookups that found (hits) or wrote "
+        "(misses) an entry", tag_keys=("result",))
+
+    def on_duration(event: str, duration: float, **_):
+        if event == _CACHE_RETRIEVAL:
+            stage = "cache_retrieval"
+        else:
+            stage = _COMPILE_STAGES.get(event)
+            if stage is None:
+                return
+            duration = _own_seconds(duration)
+        with _compile_lock:
+            _compile_totals[stage] += duration
+        seconds.inc(duration, tags={"stage": stage})
+
+    def on_event(event: str, **_):
+        result = _CACHE_RESULTS.get(event)
+        if result is not None:
+            with _compile_lock:
+                _compile_totals[result] += 1
+            cache.inc(tags={"result": result})
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_event_listener(on_event)
+
+
+def compile_totals() -> dict[str, float]:
+    """Process-wide sums since `watch_compiles()`: seconds under
+    `trace`, `lower`, `backend_compile`, `cache_retrieval`; counts under
+    `hits`, `misses`."""
+    with _compile_lock:
+        return dict(_compile_totals)
 
 
 def dump(filename: str):

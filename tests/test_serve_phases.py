@@ -1,0 +1,398 @@
+"""The serve engine's step loop and start-up, measured from inside
+(ISSUE 25): phase annotations in the device profiler's trace, the
+always-on phase / step / bytes-to-host counters of `engine.stats()`, the
+start-up split, and jax's own compile account."""
+
+import glob
+import os
+import subprocess
+import sys
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.util import tracing
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+STEP_PARENTS = ("llm.step.decode", "llm.step.prefill")
+INSIDE_A_STEP = ("llm.prepare", "llm.dispatch", "llm.fetch", "llm.commit",
+                 "llm.emit", "llm.bookkeep")
+BETWEEN_STEPS = ("llm.schedule", "llm.idle")
+VOCAB_PADDED = 128
+
+
+def _config(**overrides):
+    from ray_tpu.models import gpt2
+    from ray_tpu.serve.llm import EngineConfig
+
+    cfg = gpt2.GPT2Config(
+        vocab_size=100, n_layer=2, n_head=2, n_embd=64, block_size=64,
+        vocab_pad_multiple=VOCAB_PADDED, dtype=jnp.float32, remat=False)
+    kw = dict(model="gpt2", model_config=cfg, block_size=8, num_blocks=64,
+              max_model_len=64, max_batch_size=4, prefill_chunk_size=8,
+              seed=0)
+    kw.update(overrides)
+    return EngineConfig(**kw)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    from ray_tpu.serve.llm import LLMEngine
+
+    e = LLMEngine(_config())
+    e.warmup()
+    return e
+
+
+class _Capture:
+    """A CPU profiler capture as the benchmark takes it on the chip:
+    host events on, Python call tracer off. `.events` after the block:
+    (plane, line, name, start_ns, end_ns)."""
+
+    def __init__(self, out_dir):
+        self.out_dir = str(out_dir)
+
+    def __enter__(self):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(self.out_dir, profiler_options=options)
+        return self
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(
+            self.out_dir, "plugins", "profile", "*", "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(path)
+        self.events = [
+            (plane.name, line.name, ev.name, ev.start_ns,
+             ev.start_ns + ev.duration_ns)
+            for plane in data.planes for line in plane.lines
+            for ev in line.events]
+
+
+def _logged_names() -> set[str]:
+    return {e["name"] for e in tracing._fallback_log.chrome_trace()}
+
+
+# ---------------------------------------------------------------------------
+# (a) the annotations, in the profiler's own trace
+# ---------------------------------------------------------------------------
+
+def test_every_phase_shows_in_a_profile_and_nests(tmp_path):
+    from ray_tpu.serve.llm.config import SamplingParams
+    from ray_tpu.serve.llm.deployment import LLMServer
+
+    server = LLMServer(_config(), warmup=False)
+    try:
+        # compile outside the capture: a chunked prefill, then decode
+        server.engine.generate(list(range(1, 20)),
+                               SamplingParams(max_tokens=3), timeout=120)
+        with _Capture(tmp_path) as cap:
+            time.sleep(0.02)  # the loop idles
+            final = server.engine.generate(
+                list(range(2, 21)), SamplingParams(max_tokens=4),
+                timeout=120)
+            time.sleep(0.02)
+        assert final["finish_reason"] == "length"
+    finally:
+        server.shutdown_engine()
+        server._loop.join(timeout=10)
+    assert not server._loop.is_alive()
+
+    llm = [e for e in cap.events if e[2].startswith("llm.")]
+    names = {e[2] for e in llm}
+    assert set(STEP_PARENTS + INSIDE_A_STEP + BETWEEN_STEPS) <= names
+    assert len([e for e in llm if e[2] in STEP_PARENTS]) >= 6
+    # all in the host plane, on the line of the one thread that steps
+    assert {e[0] for e in llm} == {"/host:CPU"}
+    assert len({e[1] for e in llm}) == 1
+    # every phase of a step lies inside a step, the others outside all
+    # (a step the capture's edges cut leaves its phases without a parent)
+    steps = [(s, t) for _, _, n, s, t in llm if n in STEP_PARENTS]
+    first, last = min(a for a, _ in steps), max(b for _, b in steps)
+    for _, _, name, s, t in llm:
+        if s < first or t > last:
+            continue
+        inside = any(a <= s and t <= b for a, b in steps)
+        if name in INSIDE_A_STEP:
+            assert inside, name
+        elif name in BETWEEN_STEPS:
+            assert not any(a < t and s < b for a, b in steps), name
+    # the loop's phases go to the profile only, never to the span log
+    assert not _logged_names() & set(
+        STEP_PARENTS + INSIDE_A_STEP + BETWEEN_STEPS)
+
+
+# ---------------------------------------------------------------------------
+# (b) the counters
+# ---------------------------------------------------------------------------
+
+def test_phase_seconds_sum_to_the_loops_wall_time():
+    from ray_tpu.models import gpt2
+    from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.serve.llm.config import SamplingParams
+
+    # steps of a few ms, so that what lies between the phases (some tens
+    # of microseconds a step) stays well inside the tolerance
+    model = gpt2.GPT2Config(
+        vocab_size=1000, n_layer=4, n_head=4, n_embd=256, block_size=64,
+        vocab_pad_multiple=1024, dtype=jnp.float32, remat=False)
+    engine = LLMEngine(_config(model_config=model,
+                               enable_prefix_cache=False))
+
+    def drive():
+        streams = [engine.add_request(list(range(1, 30)),
+                                      SamplingParams(max_tokens=12))
+                   for _ in range(3)]
+        steps = 0
+        while engine.has_work():
+            assert engine.step()
+            steps += 1
+        assert all(s.final()["finish_reason"] == "length" for s in streams)
+        return steps
+
+    drive()  # compiles every program the measured pass runs
+    before = engine.stats()
+    t0 = time.perf_counter()
+    steps = drive()
+    wall = time.perf_counter() - t0
+    after = engine.stats()
+    spent = {k: after["step_phase_seconds"][k] - v
+             for k, v in before["step_phase_seconds"].items()}
+    assert set(spent) == {"schedule", "prepare", "dispatch", "fetch",
+                          "commit", "emit", "bookkeep", "idle"}
+    assert all(v >= 0 for v in spent.values())
+    assert spent["idle"] == 0  # only the deployment's loop idles
+    assert sum(spent.values()) == pytest.approx(wall, rel=0.05)
+    counted = sum(after["steps"][k] - before["steps"][k]
+                  for k in ("decode", "prefill"))
+    assert counted == steps
+
+
+def test_steps_and_bytes_match_what_was_driven(engine):
+    from ray_tpu.serve.llm.config import SamplingParams
+
+    before = engine.stats()
+    # 20 prompt tokens in chunks of 8: three prefill steps, the last one
+    # yields the first token; four decode steps of one lane yield the rest
+    final = engine.generate(list(range(3, 23)),
+                            SamplingParams(max_tokens=5), drive=True)
+    assert final["num_generated"] == 5
+    after = engine.stats()
+    steps = {k: after["steps"][k] - before["steps"][k]
+             for k in ("decode", "prefill")}
+    assert steps == {"decode": 4, "prefill": 3}
+    fetched = {k: after["d2h_bytes"][k] - before["d2h_bytes"][k]
+               for k in ("decode", "prefill")}
+    # a step fetches its sampled token(s) and its f32 logits rows
+    row = 4 + 4 * VOCAB_PADDED
+    assert fetched == {"decode": 4 * row, "prefill": 3 * row}
+    # four lanes at once: one decode step fetches four rows
+    streams = [engine.add_request([5, 6, 7], SamplingParams(max_tokens=2))
+               for _ in range(4)]
+    for _ in range(4):
+        engine.step()  # four one-chunk prefills
+    mid = engine.stats()
+    assert engine.step()
+    assert all(s.final() is not None for s in streams)
+    assert engine.stats()["d2h_bytes"]["decode"] \
+        - mid["d2h_bytes"]["decode"] == 4 * row
+    # and the metrics page carries the same total
+    from ray_tpu.util.metrics import prometheus_text
+
+    line = [ln for ln in prometheus_text().splitlines()
+            if ln.startswith("serve_llm_d2h_bytes_total{")
+            and 'kind="decode"' in ln]
+    assert line, "serve_llm_d2h_bytes_total{kind=decode} not exposed"
+
+
+# ---------------------------------------------------------------------------
+# (c) start-up
+# ---------------------------------------------------------------------------
+
+def test_startup_split(engine):
+    st = engine.stats()
+    up = st["startup_seconds"]
+    assert set(up) == {"init_params", "build_runner", "warmup",
+                       "warmup_trace", "warmup_lower", "warmup_compile"}
+    assert all(v >= 0 for v in up.values())
+    assert up["init_params"] > 0 and up["build_runner"] > 0
+    # warm-up traced, lowered and compiled (or loaded) its programs, and
+    # the three are parts of its wall time, not more than it
+    assert min(up["warmup_trace"], up["warmup_lower"],
+               up["warmup_compile"]) > 0
+    assert up["warmup_trace"] + up["warmup_lower"] + up["warmup_compile"] \
+        <= up["warmup"]
+    assert set(st["warmup_cache"]) == {"hits", "misses"}
+    assert all(v >= 0 for v in st["warmup_cache"].values())
+
+
+def test_params_handed_in_cost_no_init():
+    from ray_tpu.models import gpt2
+    from ray_tpu.serve.llm import LLMEngine
+
+    config = _config()
+    params = gpt2.init_gpt2(jax.random.PRNGKey(1), config.model_config)
+    e = LLMEngine(config, params=params)
+    up = e.stats()["startup_seconds"]
+    assert up["init_params"] == 0.0 and up["build_runner"] > 0
+    assert up["warmup"] == 0.0  # not warmed up yet
+
+
+def test_compile_stages_nest_without_counting_twice():
+    """A jitted function called while another is traced reports its own
+    trace: the listener counts each second once."""
+    tracing.watch_compiles()
+    salt = float(time.time_ns() % 1000)
+
+    @jax.jit
+    def inner(x):
+        return jnp.tanh(x) * salt
+
+    @jax.jit
+    def outer(x):
+        for _ in range(20):
+            x = inner(x) + jnp.sin(x)
+        return x
+
+    before = tracing.compile_totals()
+    t0 = time.perf_counter()
+    outer(jnp.ones((8, 8))).block_until_ready()
+    wall = time.perf_counter() - t0
+    spent = {k: v - before[k] for k, v in tracing.compile_totals().items()}
+    assert spent["trace"] > 0 and spent["lower"] > 0
+    assert spent["backend_compile"] > 0
+    assert spent["trace"] + spent["lower"] + spent["backend_compile"] \
+        <= wall
+    from ray_tpu.util.metrics import prometheus_text
+
+    assert 'jax_compile_seconds_total{stage="trace"}' in prometheus_text()
+
+
+# ---------------------------------------------------------------------------
+# (d), (e) the span API
+# ---------------------------------------------------------------------------
+
+def test_span_shows_in_a_profile_and_in_the_log(tmp_path):
+    with _Capture(tmp_path) as cap:
+        with tracing.span("phases-outer", category="test"):
+            with tracing.span("phases-inner", category="test"):
+                time.sleep(0.001)
+    spans = {e[2]: e for e in cap.events
+             if e[2] in ("phases-outer", "phases-inner")}
+    assert set(spans) == {"phases-outer", "phases-inner"}
+    assert spans["phases-outer"][0] == "/host:CPU"
+    assert spans["phases-outer"][3] <= spans["phases-inner"][3]
+    assert spans["phases-inner"][4] <= spans["phases-outer"][4]
+    assert {"phases-outer", "phases-inner"} <= _logged_names()
+
+
+def test_annotate_is_a_profile_event_only(tmp_path):
+    with _Capture(tmp_path) as cap:
+        with tracing.annotate("phases-annotated"):
+            pass
+    assert "phases-annotated" in {e[2] for e in cap.events}
+    assert "phases-annotated" not in _logged_names()
+
+
+def test_annotate_imports_no_jax():
+    code = ("import sys\n"
+            "from ray_tpu.util import tracing\n"
+            "with tracing.annotate('x'), tracing.span('y'):\n"
+            "    pass\n"
+            "clock = tracing.PhaseClock('p.', ('a',))\n"
+            "with clock.phase('a'):\n"
+            "    pass\n"
+            "assert clock.seconds['a'] > 0\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n")
+    done = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
+
+
+# ---------------------------------------------------------------------------
+# profiler_capture: a capture that did not happen must say so
+# ---------------------------------------------------------------------------
+
+class _Chip:
+    platform = "tpu"
+
+
+def test_profiler_capture_arms_host_events_without_python_tracer(
+        tmp_path, monkeypatch):
+    seen = {}
+
+    class Armed:
+        def __enter__(self):
+            seen["entered"] = True
+
+        def __exit__(self, *exc):
+            seen["left"] = True
+
+    def fake_trace(log_dir, profiler_options=None, **_):
+        seen["dir"] = log_dir
+        seen["python"] = profiler_options.python_tracer_level
+        seen["host"] = profiler_options.host_tracer_level
+        return Armed()
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Chip()])
+    monkeypatch.setattr(jax.profiler, "trace", fake_trace)
+    out = str(tmp_path / "prof")
+    with tracing.profiler_capture(out) as captured:
+        assert captured == out and seen["entered"]
+    assert seen == {"dir": out, "python": 0, "host": 2, "entered": True,
+                    "left": True}
+    assert "profiler.capture" in _logged_names()
+
+
+@pytest.mark.parametrize("fails_at", ["start", "stop"])
+def test_profiler_capture_failure_raises_on_a_chip(tmp_path, monkeypatch,
+                                                   fails_at):
+    class Broken:
+        def __enter__(self):
+            if fails_at == "start":
+                raise RuntimeError("profiler did not start")
+
+        def __exit__(self, *exc):
+            if fails_at == "stop":
+                raise RuntimeError("profiler did not stop")
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Chip()])
+    monkeypatch.setattr(jax.profiler, "trace", lambda *a, **k: Broken())
+    ran = []
+    with pytest.raises(RuntimeError, match="profiler did not " + fails_at):
+        with tracing.profiler_capture(str(tmp_path / "prof")):
+            ran.append(1)
+    assert ran == ([] if fails_at == "start" else [1])
+
+
+def test_phase_clock_is_shared_by_engine_and_runner(engine):
+    assert engine.phases is engine.runner.phases
+    # driven from two threads, steps still serialize and every one counts
+    from ray_tpu.serve.llm.config import SamplingParams
+
+    before = engine.stats()["steps"]
+    streams = [engine.add_request([9, 8, 7, 6], SamplingParams(max_tokens=6))
+               for _ in range(2)]
+    done = []
+
+    def drive():
+        n = 0
+        while engine.has_work():
+            n += bool(engine.step())
+        done.append(n)
+
+    threads = [threading.Thread(target=drive) for _ in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    assert all(s.final() is not None for s in streams)
+    after = engine.stats()["steps"]
+    assert sum(after.values()) - sum(before.values()) == sum(done)
