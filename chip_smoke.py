@@ -401,13 +401,10 @@ def phase_serve(paged: bool, dense: dict | None) -> dict:
     from ray_tpu.util import state
 
     app = "llm-paged" if paged else "llm"
+    # both at the default pool (0.3 of memory): the pool is read and
+    # written in place (cache.KVLayout), so no program needs room for a
+    # copy of it (PERF.md, findings of PR 26)
     engine_config = {"use_paged_attention": paged}
-    if paged:
-        # the kernel needs the pool row-major; XLA keeps the (.., 12, 64)
-        # bf16 pool pages-minor, so each program first copies both pools
-        # into a 2.7x padded temp (PERF.md, findings of PR 21). A pool
-        # at the default 0.3 of memory does not leave room for that.
-        engine_config["memory_fraction"] = 0.05
     t0 = time.monotonic()
     handle = serve.run(
         build_llm_app(model="gpt2", preset="small",

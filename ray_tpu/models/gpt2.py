@@ -263,10 +263,11 @@ def gpt2_loss(params: Params, batch: dict, cfg: GPT2Config) -> jax.Array:
 # --------------------------------------------------------------------------
 # KV-cache inference steps (serve.llm). Prefill runs the full-sequence
 # forward and additionally returns every layer's K/V heads; decode runs
-# ONE token per sequence against externally gathered context K/V (the
-# paged-cache gather/scatter lives in ray_tpu/serve/llm/runner.py — the
-# model layer only owns the math, so parity with the training forward is
-# checkable function-against-function).
+# ONE token per sequence against cached context K/V that each layer
+# fetches with ``read_ctx(layer) -> (k_ctx, v_ctx)`` inside the layer scan
+# (the page pool, its layout and the scatter of new rows belong to
+# ray_tpu/serve/llm — the model layer only owns the math, so parity with
+# the training forward is checkable function-against-function).
 
 
 def gpt2_prefill_kv(
@@ -347,8 +348,7 @@ def gpt2_prefill_chunk_kv(
     params: Params,
     tokens: jax.Array,
     start: jax.Array,
-    k_ctx: jax.Array,
-    v_ctx: jax.Array,
+    read_ctx,
     ctx_mask: jax.Array,
     chunk_mask: jax.Array,
     cfg: GPT2Config,
@@ -358,9 +358,10 @@ def gpt2_prefill_chunk_kv(
 
     tokens (B, T) sit at absolute positions start..start+T-1 (start is
     a traced scalar, so one compiled program serves every offset);
-    k_ctx/v_ctx (L, B, C, H, D) hold gathered cached context for
-    positions < start, ctx_mask (B, C) marks its valid slots and
-    chunk_mask (B, T) the chunk's real tokens. Returns
+    ``read_ctx(layer)`` gives that layer's k_ctx/v_ctx (B, C, H, D), the
+    cached context for positions < start (called inside the layer scan:
+    one layer's context exists at a time), ctx_mask (B, C) marks its
+    valid slots and chunk_mask (B, T) the chunk's real tokens. Returns
     (logits (B, T, Vp) f32, k, v (L, B, T, H, D)) — the caller scatters
     k/v into the paged cache at the chunk's positions.
     """
@@ -377,10 +378,12 @@ def gpt2_prefill_chunk_kv(
     x = constrain(x, ("data", "fsdp"), None, None)
 
     def body(carry, xs):
-        p, kc, vc = xs
+        p, layer = xs
+        kc, vc = read_ctx(layer)
         return _chunk_block(carry, p, kc, vc, ctx_mask, chunk_mask, cfg)
 
-    x, (k, v) = jax.lax.scan(body, x, (params["blocks"], k_ctx, v_ctx))
+    x, (k, v) = jax.lax.scan(
+        body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
     x = _layer_norm(x, params["lnf"]["scale"], params["lnf"]["bias"])
     logits = x @ params["wte"].astype(dt).T
     logits = constrain(logits, ("data", "fsdp"), None, "tensor")
@@ -433,15 +436,15 @@ def gpt2_decode_kv(
     params: Params,
     tokens: jax.Array,
     positions: jax.Array,
-    k_ctx: jax.Array,
-    v_ctx: jax.Array,
+    read_ctx,
     ctx_mask: jax.Array,
     cfg: GPT2Config,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One decode step for a batch of sequences.
 
-    tokens/positions (B,) i32; k_ctx/v_ctx (L, B, C, H, D) gathered
-    cache context; ctx_mask (B, C). Returns (logits (B, Vp) f32,
+    tokens/positions (B,) i32; ``read_ctx(layer)`` gives that layer's
+    k_ctx/v_ctx (B, C, H, D) cached context (see
+    gpt2_prefill_chunk_kv); ctx_mask (B, C). Returns (logits (B, Vp) f32,
     k_new, v_new (L, B, H, D)) — the caller scatters k_new/v_new into
     the cache at each sequence's current position.
     """
@@ -449,11 +452,12 @@ def gpt2_decode_kv(
     x = params["wte"].astype(dt)[tokens] + params["wpe"].astype(dt)[positions]
 
     def body(carry, xs):
-        p, kc, vc = xs
+        p, layer = xs
+        kc, vc = read_ctx(layer)
         return _decode_block(carry, p, kc, vc, ctx_mask, cfg)
 
     x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["blocks"], k_ctx, v_ctx))
+        body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
     x = _layer_norm(x, params["lnf"]["scale"], params["lnf"]["bias"])
     logits = x @ params["wte"].astype(dt).T
     return logits.astype(jnp.float32), k_new, v_new
@@ -463,15 +467,16 @@ def gpt2_decode_kv(
 # Paged-attention inference steps: same block math (projections, MLP,
 # residuals shared via the `attend` hook), but the attention core is the
 # ops/paged_attention.py kernel indexing the page pool in place — no
-# dense (L, B, C, H, D) context gather. k_pages/v_pages are the pool
-# arrays (L, num_blocks, block_size, H, D); the scan walks layer indices
-# and the kernel picks the layer's pages out of the whole pool.
+# dense context gather. k_pages/v_pages are the pool arrays as `layout`
+# (serve/llm/cache.py KVLayout) describes them; the scan walks layer
+# indices and the kernel picks the layer's pages out of the whole pool.
 
 
 def gpt2_decode_paged_kv(
     params: Params,
     tokens: jax.Array,
     positions: jax.Array,
+    layout,
     k_pages: jax.Array,
     v_pages: jax.Array,
     tables: jax.Array,
@@ -494,7 +499,8 @@ def gpt2_decode_paged_kv(
         def attend(q, k, v):
             o = paged_attention(q[:, None], k[:, None], v[:, None],
                                 k_pages, v_pages, tables, positions,
-                                layer=layer, interpret=interpret)
+                                layout=layout, layer=layer,
+                                interpret=interpret)
             return o[:, 0]
 
         return _decode_block(carry, p, None, None, None, cfg,
@@ -511,6 +517,7 @@ def gpt2_verify_paged_kv(
     params: Params,
     tokens: jax.Array,
     start: jax.Array,
+    layout,
     k_pages: jax.Array,
     v_pages: jax.Array,
     table: jax.Array,
@@ -538,7 +545,7 @@ def gpt2_verify_paged_kv(
 
         def attend(q, k, v):
             return paged_attention(q, k, v, k_pages, v_pages, tables,
-                                   ctx_len, layer=layer,
+                                   ctx_len, layout=layout, layer=layer,
                                    interpret=interpret)
 
         return _chunk_block(carry, p, None, None, None, None, cfg,

@@ -312,26 +312,27 @@ def llama_prefill_chunk_kv(
     params: Params,
     tokens: jax.Array,
     start: jax.Array,
-    k_ctx: jax.Array,
-    v_ctx: jax.Array,
+    read_ctx,
     ctx_mask: jax.Array,
     chunk_mask: jax.Array,
     cfg: LlamaConfig,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Chunked prefill from a position offset; see gpt2_prefill_chunk_kv.
-    k_ctx/v_ctx are (L, B, C, Hkv, D); returns (logits (B, T, Vp) f32,
-    k, v (L, B, T, Hkv, D))."""
+    ``read_ctx(layer)`` gives k_ctx/v_ctx (B, C, Hkv, D); returns
+    (logits (B, T, Vp) f32, k, v (L, B, T, Hkv, D))."""
     dt = cfg.dtype
     wte = constrain(params["wte"].astype(dt), None, None)
     x = wte[tokens]
     x = constrain(x, ("data", "fsdp"), None, None)
 
     def body(carry, xs):
-        p, kc, vc = xs
+        p, layer = xs
+        kc, vc = read_ctx(layer)
         return _chunk_block(carry, p, kc, vc, ctx_mask, chunk_mask,
                             start, cfg)
 
-    x, (k, v) = jax.lax.scan(body, x, (params["blocks"], k_ctx, v_ctx))
+    x, (k, v) = jax.lax.scan(
+        body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
     x = _rmsnorm(x, params["lnf"], cfg.rms_eps)
     logits = x @ params["wte"].astype(dt).T
     logits = constrain(logits, ("data", "fsdp"), None, "tensor")
@@ -393,23 +394,23 @@ def llama_decode_kv(
     params: Params,
     tokens: jax.Array,
     positions: jax.Array,
-    k_ctx: jax.Array,
-    v_ctx: jax.Array,
+    read_ctx,
     ctx_mask: jax.Array,
     cfg: LlamaConfig,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One decode step; see gpt2_decode_kv. k_ctx/v_ctx are
-    (L, B, C, Hkv, D); returns (logits (B, Vp) f32, k_new, v_new
-    (L, B, Hkv, D))."""
+    """One decode step; see gpt2_decode_kv. ``read_ctx(layer)`` gives
+    k_ctx/v_ctx (B, C, Hkv, D); returns (logits (B, Vp) f32, k_new,
+    v_new (L, B, Hkv, D))."""
     dt = cfg.dtype
     x = params["wte"].astype(dt)[tokens]
 
     def body(carry, xs):
-        p, kc, vc = xs
+        p, layer = xs
+        kc, vc = read_ctx(layer)
         return _decode_block(carry, p, kc, vc, ctx_mask, positions, cfg)
 
     x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["blocks"], k_ctx, v_ctx))
+        body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
     x = _rmsnorm(x, params["lnf"], cfg.rms_eps)
     logits = x @ params["wte"].astype(dt).T
     return logits.astype(jnp.float32), k_new, v_new
@@ -418,14 +419,16 @@ def llama_decode_kv(
 # --------------------------------------------------------------------------
 # Paged-attention inference steps — see models/gpt2.py: same block math
 # through the `attend` hook, attention core is the ops/paged_attention
-# kernel over the page pool (L, num_blocks, block_size, Hkv, D). The
-# kernel does the GQA head mapping, so K/V stay pre-replication.
+# kernel over the page pool that `layout` (serve/llm/cache.py KVLayout)
+# describes. The kernel does the GQA head mapping, so K/V stay
+# pre-replication.
 
 
 def llama_decode_paged_kv(
     params: Params,
     tokens: jax.Array,
     positions: jax.Array,
+    layout,
     k_pages: jax.Array,
     v_pages: jax.Array,
     tables: jax.Array,
@@ -446,7 +449,8 @@ def llama_decode_paged_kv(
         def attend(q, k, v):
             o = paged_attention(q[:, None], k[:, None], v[:, None],
                                 k_pages, v_pages, tables, positions,
-                                layer=layer, interpret=interpret)
+                                layout=layout, layer=layer,
+                                interpret=interpret)
             return o[:, 0]
 
         return _decode_block(carry, p, None, None, None, positions,
@@ -463,6 +467,7 @@ def llama_verify_paged_kv(
     params: Params,
     tokens: jax.Array,
     start: jax.Array,
+    layout,
     k_pages: jax.Array,
     v_pages: jax.Array,
     table: jax.Array,
@@ -485,7 +490,7 @@ def llama_verify_paged_kv(
 
         def attend(q, k, v):
             return paged_attention(q, k, v, k_pages, v_pages, tables,
-                                   ctx_len, layer=layer,
+                                   ctx_len, layout=layout, layer=layer,
                                    interpret=interpret)
 
         return _chunk_block(carry, p, None, None, None, None, start,

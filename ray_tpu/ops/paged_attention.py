@@ -1,9 +1,9 @@
 """Paged attention — pallas TPU kernel over the serve.llm KV page pool.
 
-The dense decode/verify programs gather each lane's pages into a
-``(L, S, max_blocks_per_seq * block_size, H_kv, D)`` context before
-attending (runner.py) — O(max_model_len) HBM traffic per step
-regardless of how long the sequence actually is. This kernel is the
+The dense decode/verify programs gather, layer by layer, each lane's
+pages into a ``(S, max_blocks_per_seq * block_size, H_kv, D)`` context
+before attending — O(max_model_len) HBM traffic per step regardless of
+how long the sequence actually is. This kernel is the
 vLLM-PagedAttention shape instead (PAPERS.md): queries index the page
 pool *in place* through the block table, one page per grid step, with
 the layer index, the table and the context lengths delivered via scalar
@@ -16,11 +16,13 @@ Operands:
 - ``own_k``/``own_v``  (S, W, H_kv, D) — the window's OWN keys/values
   (they are never in the pages: decode/verify scatter them after the
   step), attended causally within the window;
-- ``k_pages``/``v_pages`` — the pool as the runner holds it,
-  (L, num_blocks, block_size, H_kv, D) with ``layer`` (a traced i32)
-  selecting the layer, or one layer's (num_blocks, block_size, H_kv, D)
-  with ``layer=None``. The models scan over layer INDICES and close
-  over the whole pool: slicing the pool per layer would copy it;
+- ``k_pages``/``v_pages`` — the whole pool as the runner holds it, and
+  ``layout`` — the `serve/llm/cache.py` ``KVLayout`` that says how it
+  lies (one lane-dense row of ``H_kv * D`` per token, head ``h`` in
+  lanes ``[h * D, (h + 1) * D)``) and gives the kernel its page block;
+  ``layer`` (a traced i32, or an int) selects the layer. The models scan
+  over layer INDICES and close over the whole pool: slicing the pool per
+  layer, or reshaping it, would copy it;
 - ``tables``           (S, max_blocks_per_seq) i32 — logical page i of
   sequence s lives in physical page ``tables[s, i]`` (padding points at
   the null page 0, which the length mask excludes anyway);
@@ -28,19 +30,23 @@ Operands:
   < ctx_len[s] are real; everything else in the mapped pages is
   garbage past the lane's frontier).
 
-Blocking. Mosaic requires the last two dims of every block to be
-(8, 128)-divisible or the array's full dims, so a block is never cut
-inside ``(H_kv, D)``: one grid step holds one whole page
-``(block_size, H_kv, D)``, and queries are regrouped outside the kernel
-to ``(S, W, rep, H_kv, D)`` (head ``h = hk * rep + r``). Grid is
-(S, max_blocks_per_seq), pages innermost and sequential, carrying the
-online-softmax state (running max, sum, f32 accumulator) in VMEM
-scratch like ops/flash_attention.py; pages wholly past ``ctx_len`` are
-skipped with ``pl.when``; the final grid step folds in the causal
-own-window block and normalizes. The arithmetic is a VPU
-multiply-reduce per (window row, group member) over all KV heads at
-once — decode is bound by reading pages, and this keeps ``(H_kv, D)``
-in its native layout with no in-kernel relayout.
+Blocking. One grid step holds one whole page ``(block_size, H_kv * D)``,
+which the ``(8, 128)`` tiling divides (768 lanes at gpt2-small, 1280 at
+gpt2-large, 1024 at 8 x 128), and nothing in the kernel ever leaves that
+row: queries are regrouped outside the kernel to ``(S, W, rep,
+H_kv * D)`` (head ``h = hk * rep + r``, so one query row lines up with
+one page row, lane for lane), the window's own keys and values are
+flattened the same way, and the online-softmax state (running max, sum,
+f32 accumulator; VMEM scratch like ops/flash_attention.py) is kept per
+lane, every lane of a head holding that head's value. Grid is
+(S, max_blocks_per_seq), pages innermost and sequential; pages wholly
+past ``ctx_len`` are skipped with ``pl.when``; the final grid step folds
+in the causal own-window block and normalizes. The arithmetic is a VPU
+multiply per (window row, group member) over all KV heads at once, and
+the sum over a head's ``D`` lanes is a butterfly of lane rotations
+(``_head_sums``; ``D`` a power of two) that leaves the total in every
+lane of the head — decode is bound by reading pages, and there is no
+in-kernel relayout.
 
 ``interpret=True`` runs the same kernel through the pallas interpreter
 on CPU (tests, parity gates); on TPU it compiles for real. The dense
@@ -59,8 +65,24 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+def _head_sums(x, head_dim):
+    """x (N, H_kv * D) -> the same shape, every lane holding the sum over
+    its own head's D lanes: log2(D) butterfly steps, lane i adding lane
+    i ^ d (heads are D-aligned and D is a power of two, so the partner
+    never leaves the head)."""
+    row = x.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    d = 1
+    while d < head_dim:
+        x = x + jnp.where((lane & d) != 0, pltpu.roll(x, d, 1),
+                          pltpu.roll(x, row - d, 1))
+        d *= 2
+    return x
+
+
 def _paged_kernel(layer_ref, tables_ref, ctxlen_ref, q_ref, ko_ref, vo_ref,
-                  kp_ref, vp_ref, o_ref, acc, m_s, l_s, *, scale, nb, bs):
+                  kp_ref, vp_ref, o_ref, acc, m_s, l_s, *, scale, nb, bs,
+                  head_dim):
     del layer_ref, tables_ref  # consumed by the index maps
     s_i = pl.program_id(0)
     b = pl.program_id(1)
@@ -75,26 +97,28 @@ def _paged_kernel(layer_ref, tables_ref, ctxlen_ref, q_ref, ko_ref, vo_ref,
     ctx = ctxlen_ref[s_i]
 
     def _accum(i, k, v, valid):
-        # row i = (w, r): one query per KV head, q (HK, D); k/v (N, HK, D)
-        # f32; valid (N, 1, 1). VPU formulation: the (HK, D) minor dims of
-        # the pool never leave their native layout.
-        q = q_ref[i // rep, i % rep].astype(jnp.float32)
-        s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
-        s = jnp.where(valid, s, DEFAULT_MASK_VALUE)  # (N, HK, 1)
-        m_prev = m_s[i, :, :1]  # (HK, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        # row i = (w, r): one query per KV head, flattened like a page
+        # row, q (1, row); k/v (N, row) f32; valid (N, row). State rows
+        # are (1, row), a head's value repeated over its lanes.
+        one = pl.ds(i, 1)
+        q = q_ref[i // rep, pl.ds(i % rep, 1), :].astype(jnp.float32)
+        s = _head_sums(k * q, head_dim) * scale
+        s = jnp.where(valid, s, DEFAULT_MASK_VALUE)
+        m_prev = m_s[one, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[None])
-        l_new = alpha * l_s[i, :, :1] + jnp.sum(p, axis=0)
-        acc[i] = acc[i] * alpha + jnp.sum(p * v, axis=0)
-        m_s[i] = jnp.broadcast_to(m_new, m_s.shape[1:])
-        l_s[i] = jnp.broadcast_to(l_new, l_s.shape[1:])
+        p = jnp.exp(s - m_new)
+        l_s[one, :] = alpha * l_s[one, :] + jnp.sum(p, axis=0,
+                                                    keepdims=True)
+        acc[one, :] = acc[one, :] * alpha + jnp.sum(p * v, axis=0,
+                                                    keepdims=True)
+        m_s[one, :] = m_new
 
     @pl.when(b * bs < ctx)
     def _page():
         k = kp_ref[...].astype(jnp.float32)
         v = vp_ref[...].astype(jnp.float32)
-        cols = b * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
+        cols = b * bs + jax.lax.broadcasted_iota(jnp.int32, k.shape, 0)
         for i in range(W * rep):
             _accum(i, k, v, cols < ctx)
 
@@ -102,16 +126,17 @@ def _paged_kernel(layer_ref, tables_ref, ctxlen_ref, q_ref, ko_ref, vo_ref,
     def _own_and_emit():
         k = ko_ref[...].astype(jnp.float32)
         v = vo_ref[...].astype(jnp.float32)
-        x = jax.lax.broadcasted_iota(jnp.int32, (W, 1, 1), 0)
+        x = jax.lax.broadcasted_iota(jnp.int32, k.shape, 0)
         for i in range(W * rep):
             _accum(i, k, v, x <= i // rep)
-            l = l_s[i, :, :1]
+            l = l_s[pl.ds(i, 1), :]
             l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[i // rep, i % rep] = (acc[i] / l_safe).astype(o_ref.dtype)
+            o_ref[i // rep, pl.ds(i % rep, 1), :] = (
+                acc[pl.ds(i, 1), :] / l_safe).astype(o_ref.dtype)
 
 
 def paged_attention(q, own_k, own_v, k_pages, v_pages, tables, ctx_len,
-                    *, layer=None, sm_scale: float | None = None,
+                    *, layout, layer=0, sm_scale: float | None = None,
                     interpret: bool = False):
     """One layer of paged attention; see the module docstring for the
     operand layout. Returns (S, W, H, D) in q's dtype. Every query row
@@ -119,53 +144,50 @@ def paged_attention(q, own_k, own_v, k_pages, v_pages, tables, ctx_len,
     S, W, H, D = q.shape
     HK = own_k.shape[2]
     rep = H // HK
-    if layer is None:  # one layer's (num_blocks, bs, HK, D) pool
-        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
-    bs = k_pages.shape[2]
+    if D & (D - 1):
+        raise ValueError(f"head_dim {D} is not a power of two")
+    bs, row = layout.block_size, layout.row
     maxB = tables.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / (D**0.5)
-    # head h = hk * rep + r  ->  (S, W, rep, HK, D): every block then ends
-    # in the full (HK, D) dims of its array
-    qg = q.reshape(S, W, HK, rep, D).swapaxes(2, 3)
+    # head h = hk * rep + r  ->  (S, W, rep, HK * D): a query row then
+    # lines up with a page row, lane for lane
+    qg = q.reshape(S, W, HK, rep, D).swapaxes(2, 3).reshape(S, W, rep, row)
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
-    own = vmem((None, W, HK, D), lambda s, b, l, t, c: (s, 0, 0, 0))
-    page = vmem((None, None, bs, HK, D),
-                lambda s, b, l, t, c: (l[0], t[s, b], 0, 0, 0))
-    qspec = vmem((None, W, rep, HK, D),
-                 lambda s, b, l, t, c: (s, 0, 0, 0, 0))
+    own = vmem((None, W, row), lambda s, b, l, t, c: (s, 0, 0))
+    page = vmem(layout.page_block(),
+                lambda s, b, l, t, c: layout.page_index(l[0], t[s, b]))
+    qspec = vmem((None, W, rep, row), lambda s, b, l, t, c: (s, 0, 0, 0))
+    state = pltpu.VMEM((W * rep, row), jnp.float32)
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale, nb=maxB, bs=bs),
+        functools.partial(_paged_kernel, scale=scale, nb=maxB, bs=bs,
+                          head_dim=D),
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(S, maxB),
             in_specs=[qspec, own, own, page, page],
             out_specs=qspec,
-            scratch_shapes=[
-                pltpu.VMEM((W * rep, HK, D), jnp.float32),
-                pltpu.VMEM((W * rep, HK, 128), jnp.float32),
-                pltpu.VMEM((W * rep, HK, 128), jnp.float32),
-            ],
+            scratch_shapes=[state, state, state],
         ),
         interpret=interpret,
     )(jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
       tables.astype(jnp.int32), ctx_len.astype(jnp.int32),
-      qg, own_k, own_v, k_pages, v_pages)
-    return out.swapaxes(2, 3).reshape(S, W, H, D)
+      qg, own_k.reshape(S, W, row), own_v.reshape(S, W, row),
+      k_pages, v_pages)
+    return out.reshape(S, W, rep, HK, D).swapaxes(2, 3).reshape(S, W, H, D)
 
 
 def paged_attention_reference(q, own_k, own_v, k_pages, v_pages, tables,
-                              ctx_len):
-    """Dense jnp oracle for the kernel (tests): gather pages through the
-    table, mask by ctx_len, causal own window. Same operand layout."""
+                              ctx_len, *, layout, layer=0):
+    """Dense jnp oracle for the kernel (tests): read the layer's pages
+    through the table, mask by ctx_len, causal own window. Same operand
+    layout."""
     S, W, H, D = q.shape
     HK = own_k.shape[2]
-    bs = k_pages.shape[1]
-    maxB = tables.shape[1]
-    C = maxB * bs
     rep = H // HK
-    k_ctx = k_pages[tables].reshape(S, C, HK, D)
-    v_ctx = v_pages[tables].reshape(S, C, HK, D)
+    k_ctx = layout.read(k_pages, layer, tables)  # (S, C, HK, D)
+    v_ctx = layout.read(v_pages, layer, tables)
+    C = k_ctx.shape[1]
     k_ctx = jnp.repeat(k_ctx, rep, axis=2)
     v_ctx = jnp.repeat(v_ctx, rep, axis=2)
     ko = jnp.repeat(own_k, rep, axis=2)

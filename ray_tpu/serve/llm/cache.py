@@ -2,10 +2,12 @@
 content-addressed prefix index (automatic prefix caching).
 
 The device arrays themselves live in the ModelRunner (one K and one V
-array of shape (L, num_blocks, block_size, H_kv, D) per model); this
-module owns the *bookkeeping*: which physical pages are free, each
-sequence's logical-block -> physical-page table, and which pages hold
-which token content.
+pool per model). This module owns the *bookkeeping* (`BlockPool`: which
+physical pages are free, each sequence's logical-block -> physical-page
+table, which pages hold which token content) and the pools' *layout*
+(`KVLayout`: their shape and sharding, how a layer's context is read
+through block tables and how new rows are written). Nothing else in the
+package spells the pool's shape.
 
 Page 0 is reserved as a **null sink**: it is never handed out, padded
 lanes of a bucketed batch point their tables at it, and padded prefill
@@ -29,10 +31,14 @@ Prefix caching (reference shape: vLLM's automatic prefix caching):
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import threading
 from collections import OrderedDict
 from typing import Iterable, Sequence
+
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 
 class CacheExhausted(Exception):
@@ -62,6 +68,98 @@ def chain_hashes(tokens: Sequence[int], block_size: int,
         prev = hash_page(prev, tokens[k * block_size:(k + 1) * block_size])
         out.append(prev)
     return out
+
+
+@dataclasses.dataclass(frozen=True)
+class KVLayout:
+    """How one K (or V) page pool lies on the device, and the only code
+    that indexes it.
+
+    The pool is ``(n_layer, num_blocks, block_size, n_kv_head *
+    head_dim)``: one token's heads side by side in one lane-dense row
+    (1280 lanes at gpt2-large), so the bf16 tile ``(8, 128)(2, 1)`` over
+    ``(block_size, row)`` pads nothing and XLA keeps the array row-major.
+    Three things together keep every serve program from copying the pool
+    whole (each alone leaves the copies; PERF.md, PR 26): this row, a
+    context read per layer inside the layer scan (`read`), and a scatter
+    indexed on all three leading dimensions (`write`), which XLA then
+    does in place on the donated buffer.
+    """
+
+    n_layer: int
+    num_blocks: int
+    block_size: int
+    n_kv_head: int
+    head_dim: int
+
+    @property
+    def row(self) -> int:
+        """Lanes of one token's row: head ``h`` is ``[h * head_dim,
+        (h + 1) * head_dim)``."""
+        return self.n_kv_head * self.head_dim
+
+    @property
+    def shape(self) -> tuple[int, int, int, int]:
+        return (self.n_layer, self.num_blocks, self.block_size, self.row)
+
+    def shard_ways(self, tensor_ways: int) -> int:
+        """Over how many `tensor` shards the row splits: whole heads
+        only (contiguous head blocks), else the pool is replicated."""
+        if tensor_ways > 1 and self.n_kv_head % tensor_ways == 0:
+            return tensor_ways
+        return 1
+
+    def block_bytes(self, dtype_bytes: int, tensor_ways: int = 1) -> int:
+        """Bytes one page takes on one device, K and V together."""
+        return (2 * self.n_layer * self.block_size * self.row
+                * dtype_bytes // self.shard_ways(tensor_ways))
+
+    def spec(self, mesh) -> PartitionSpec:
+        ways = dict(mesh.shape).get("tensor", 1)
+        if self.shard_ways(ways) > 1:
+            return PartitionSpec(None, None, None, "tensor")
+        return PartitionSpec()
+
+    def zeros(self, dtype, mesh=None):
+        """An empty pool, placed by `spec` when there is a mesh."""
+        if mesh is None:
+            return jnp.zeros(self.shape, dtype)
+        return jnp.zeros(self.shape, dtype,
+                         device=NamedSharding(mesh, self.spec(mesh)))
+
+    def read(self, pages, layer, tables):
+        """Layer `layer`'s context for block tables ``(..., n)``:
+        ``(..., n * block_size, n_kv_head, head_dim)``, slot ``c`` being
+        position ``c`` of the table's sequence. `layer` may be traced:
+        call it inside the layer scan, so one layer's pages are gathered
+        at a time (gathering every layer at once makes XLA transpose
+        the result)."""
+        # one gather indexed by (layer, page): `pages[layer][tables]`
+        # first copies the layer out of the pool (21 MB a layer at
+        # gpt2-large; a tenth of both serve cells, PERF.md PR 26)
+        ctx = pages[layer, tables]  # (..., n, block_size, row)
+        return ctx.reshape(*tables.shape[:-1],
+                           tables.shape[-1] * self.block_size,
+                           self.n_kv_head, self.head_dim)
+
+    def write(self, pages, block_ids, offsets, rows):
+        """Store ``rows (n_layer, N, n_kv_head, head_dim)`` at slots
+        ``(block_ids[i], offsets[i])`` of every layer. Every scatter
+        dimension leads and the row is the only window: a leading ``:``
+        would make XLA transpose the pool around the scatter."""
+        n_layer, n = rows.shape[:2]
+        layers = jnp.arange(n_layer)[:, None]
+        return pages.at[layers, block_ids[None, :], offsets[None, :]].set(
+            rows.reshape(n_layer, n, self.row))
+
+    def page_block(self) -> tuple:
+        """BlockSpec shape of one page for a Pallas kernel: tile-aligned
+        ``(block_size, row)``, layer and page squeezed."""
+        return (None, None, self.block_size, self.row)
+
+    def page_index(self, layer, page) -> tuple:
+        """Block index of page `page` of layer `layer` for `page_block`."""
+        return (layer, page, 0, 0)
 
 
 class BlockPool:
@@ -278,15 +376,11 @@ def auto_num_blocks(
     error: the toy floor there would serve real traffic from a pool
     sized for a test.
     """
-    # mirror the runner's sharding rule: pages shard over `tensor` only
-    # when the KV heads divide evenly, otherwise they are replicated —
-    # sizing must not assume a split the runner won't make
-    if tensor_ways > 1 and n_kv_head % tensor_ways == 0:
-        heads_per_shard = n_kv_head // tensor_ways
-    else:
-        heads_per_shard = n_kv_head
-    per_block = 2 * n_layer * block_size * heads_per_shard \
-        * head_dim * dtype_bytes
+    # the layout's own sharding rule: sizing must not assume a split
+    # the runner won't make
+    per_block = KVLayout(
+        n_layer, 0, block_size, n_kv_head, head_dim
+    ).block_bytes(dtype_bytes, tensor_ways)
     if device is None:
         import jax
 

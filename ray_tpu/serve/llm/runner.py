@@ -1,16 +1,16 @@
 """ModelRunner: jit-compiled paged prefill / decode steps.
 
-Owns the device-side half of the KV cache (one K and one V array of
-shape ``(L, num_blocks, block_size, H_kv, D)``) and the two compiled
+Owns the device-side half of the KV cache (one K and one V pool, laid
+out, read and written only through `cache.KVLayout`) and the compiled
 programs that touch it:
 
 - **prefill**: full-sequence forward of one prompt (padded to a length
   bucket), scattering every position's K/V into its page and sampling
   the first generated token from the last valid position's logits;
 - **decode**: one token for a batch of sequences (padded to a batch
-  bucket), gathering each lane's pages through its block table,
-  attending with a validity mask, scattering the new K/V at the lane's
-  current position, and sampling the next token.
+  bucket), reading each lane's pages through its block table layer by
+  layer, attending with a validity mask, scattering the new K/V at the
+  lane's current position, and sampling the next token.
 
 Shapes are **bucketed** so the number of XLA compilations is bounded:
 prompt lengths round up to powers of two between
@@ -25,7 +25,7 @@ garbage out of the softmax.
 
 With a mesh, parameters are sharded via the model's own
 `parallel/sharding.py` partition rules and the cache pages are sharded
-over the ``tensor`` axis on the KV-head dimension; calls run under
+over the ``tensor`` axis by whole KV heads; calls run under
 ``jax.set_mesh(mesh)`` so in-model `constrain` calls resolve (same idiom
 as train/spmd.py).
 """
@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.serve.llm.cache import KVLayout
 from ray_tpu.util import tracing
 
 # The step loop's phases, as `engine.stats()["step_phase_seconds"]` and
@@ -61,17 +62,20 @@ class ModelAdapter:
     presets: dict[str, Callable[[], Any]]
     init_fn: Callable  # (key, cfg) -> params
     prefill_fn: Callable  # (params, tokens, cfg) -> (logits, k, v)
-    decode_fn: Callable  # (params, toks, pos, kc, vc, mask, cfg) -> ...
-    # (params, toks, start, kc, vc, ctx_mask, chunk_mask, cfg) -> ...
+    # read_ctx(layer) -> (k_ctx, v_ctx), that layer's cached context
+    decode_fn: Callable  # (params, toks, pos, read_ctx, mask, cfg) -> ...
+    # (params, toks, start, read_ctx, ctx_mask, chunk_mask, cfg) -> ...
     chunk_fn: Callable
     rules_fn: Callable  # () -> PartitionRules
     kv_heads: Callable[[Any], int]
     # paged-attention entry points (ops/paged_attention.py kernel in the
     # attention core instead of dense gathered context); None => family
     # has no paged path and the engine falls back to dense
-    # (params, toks, pos, k_pages, v_pages, tables, cfg, interpret) -> ...
+    # (params, toks, pos, layout, k_pages, v_pages, tables, cfg,
+    #  interpret) -> ...
     decode_paged_fn: Callable | None = None
-    # (params, toks, start, k_pages, v_pages, table, cfg, interpret) -> ...
+    # (params, toks, start, layout, k_pages, v_pages, table, cfg,
+    #  interpret) -> ...
     verify_paged_fn: Callable | None = None
 
 
@@ -254,33 +258,16 @@ class ModelRunner:
         # pallas interpret mode off-TPU (CPU CI); real kernel on TPU
         self._interpret = jax.default_backend() != "tpu"
 
-        hk = adapter.kv_heads(cfg)
-        hd = cfg.head_dim
-        L = cfg.n_layer
-        page_shape = (L, num_blocks, block_size, hk, hd)
-
+        self.layout = KVLayout(cfg.n_layer, num_blocks, block_size,
+                               adapter.kv_heads(cfg), cfg.head_dim)
         if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from ray_tpu.parallel.sharding import (
-                _prune_spec, shard_pytree)
+            from ray_tpu.parallel.sharding import shard_pytree
 
             self.params = shard_pytree(params, adapter.rules_fn(), mesh)
-            tensor_ways = dict(mesh.shape).get("tensor", 1)
-            if tensor_ways > 1 and hk % tensor_ways == 0:
-                kv_spec = _prune_spec(
-                    P(None, None, None, "tensor", None), mesh)
-            else:
-                kv_spec = P()  # uneven KV heads: replicate the pages
-            sharding = NamedSharding(mesh, kv_spec)
-            self.k_pages = jax.device_put(
-                jnp.zeros(page_shape, cfg.dtype), sharding)
-            self.v_pages = jax.device_put(
-                jnp.zeros(page_shape, cfg.dtype), sharding)
         else:
             self.params = params
-            self.k_pages = jnp.zeros(page_shape, cfg.dtype)
-            self.v_pages = jnp.zeros(page_shape, cfg.dtype)
+        self.k_pages = self.layout.zeros(cfg.dtype, mesh)
+        self.v_pages = self.layout.zeros(cfg.dtype, mesh)
 
         self._base_key = jax.random.PRNGKey(sample_seed)
         self._step_counter = 0
@@ -346,14 +333,22 @@ class ModelRunner:
         sampled = jax.random.categorical(key, logits / safe, axis=-1)
         return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
 
+    def _ctx_reader(self, k_pages, v_pages, tables):
+        """``read_ctx(layer) -> (k_ctx, v_ctx)``, each (B, C, HK, D), for
+        the dense forwards to call inside their layer scan; tables
+        (B, max_blocks_per_seq)."""
+        read = self.layout.read
+        return lambda layer: (read(k_pages, layer, tables),
+                              read(v_pages, layer, tables))
+
     def _prefill_impl(self, params, k_pages, v_pages, tokens, last_idx,
                       block_ids, offsets, temp, topk, topp, step):
         """tokens (1, Tb); block_ids/offsets (Tb,) map position t to its
         page slot (padded positions -> null page 0)."""
         logits, k, v = self.adapter.prefill_fn(params, tokens, self.cfg)
         # (L, 1, Tb, HK, D) -> (L, Tb, HK, D)
-        k_pages = k_pages.at[:, block_ids, offsets].set(k[:, 0])
-        v_pages = v_pages.at[:, block_ids, offsets].set(v[:, 0])
+        k_pages = self.layout.write(k_pages, block_ids, offsets, k[:, 0])
+        v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
         nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
         return nxt, last, k_pages, v_pages
@@ -365,25 +360,20 @@ class ModelRunner:
 
         tokens (1, Tb) at absolute positions start..start+Tb-1 (padded
         tail -> null page); table (maxB,) is the sequence's full block
-        table, gathered for context (positions < start); block_ids/
+        table, read for context (positions < start); block_ids/
         offsets (Tb,) map chunk position t to its page slot. `start` is
         traced, so one compiled program per chunk-length bucket serves
         every offset."""
-        L = self.cfg.n_layer
-        Bs = self.block_size
         Tb = tokens.shape[1]
-        C = self.max_blocks_per_seq * Bs
-        k_ctx = k_pages[:, table]  # (L, MaxB, Bs, HK, D)
-        k_ctx = k_ctx.reshape(L, 1, C, *k_ctx.shape[3:])
-        v_ctx = v_pages[:, table]
-        v_ctx = v_ctx.reshape(L, 1, C, *v_ctx.shape[3:])
+        C = self.max_blocks_per_seq * self.block_size
         ctx_mask = (jnp.arange(C)[None, :] < start)  # (1, C)
         chunk_mask = (jnp.arange(Tb)[None, :] <= last_idx)  # (1, Tb)
         logits, k, v = self.adapter.chunk_fn(
-            params, tokens, start, k_ctx, v_ctx, ctx_mask, chunk_mask,
-            self.cfg)
-        k_pages = k_pages.at[:, block_ids, offsets].set(k[:, 0])
-        v_pages = v_pages.at[:, block_ids, offsets].set(v[:, 0])
+            params, tokens, start,
+            self._ctx_reader(k_pages, v_pages, table[None]), ctx_mask,
+            chunk_mask, self.cfg)
+        k_pages = self.layout.write(k_pages, block_ids, offsets, k[:, 0])
+        v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
         nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
         return nxt, last, k_pages, v_pages
@@ -411,26 +401,21 @@ class ModelRunner:
 
         Returns (emitted (W,), n_acc scalar, logits (W, Vp), pages):
         the caller commits emitted[:n_acc + 1]."""
-        L = self.cfg.n_layer
-        Bs = self.block_size
         W = tokens.shape[1]
         if self.use_paged_attention:
             logits, k, v = self.adapter.verify_paged_fn(
-                params, tokens, start, k_pages, v_pages, table, self.cfg,
-                interpret=self._interpret)
+                params, tokens, start, self.layout, k_pages, v_pages,
+                table, self.cfg, interpret=self._interpret)
         else:
-            C = self.max_blocks_per_seq * Bs
-            k_ctx = k_pages[:, table]  # (L, MaxB, Bs, HK, D)
-            k_ctx = k_ctx.reshape(L, 1, C, *k_ctx.shape[3:])
-            v_ctx = v_pages[:, table]
-            v_ctx = v_ctx.reshape(L, 1, C, *v_ctx.shape[3:])
+            C = self.max_blocks_per_seq * self.block_size
             ctx_mask = (jnp.arange(C)[None, :] < start)  # (1, C)
             chunk_mask = (jnp.arange(W)[None, :] <= n_draft)  # (1, W)
             logits, k, v = self.adapter.chunk_fn(
-                params, tokens, start, k_ctx, v_ctx, ctx_mask,
-                chunk_mask, self.cfg)
-        k_pages = k_pages.at[:, block_ids, offsets].set(k[:, 0])
-        v_pages = v_pages.at[:, block_ids, offsets].set(v[:, 0])
+                params, tokens, start,
+                self._ctx_reader(k_pages, v_pages, table[None]),
+                ctx_mask, chunk_mask, self.cfg)
+        k_pages = self.layout.write(k_pages, block_ids, offsets, k[:, 0])
+        v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
         lg = logits[0]  # (W, Vp)
         target = self._sample(lg, temps, topks, topps, step)  # (W,)
         # target[j] is the model's own token FOR position start+j+1;
@@ -444,32 +429,27 @@ class ModelRunner:
     def _decode_impl(self, params, k_pages, v_pages, tokens, positions,
                      tables, temps, topks, topps, step):
         """tokens/positions/temps (Sb,); tables (Sb, max_blocks_per_seq).
-        Gather pages -> dense context, run the model's decode step,
-        scatter the new K/V at each lane's position, sample. With
-        paged attention the gather disappears: the kernel indexes pages
-        in place through the block table."""
-        L = self.cfg.n_layer
-        S = tokens.shape[0]
+        Run the model's decode step, each layer reading its dense
+        context through the tables, scatter the new K/V at each lane's
+        position, sample. With paged attention the gather disappears:
+        the kernel indexes pages in place through the block table."""
         Bs = self.block_size
         if self.use_paged_attention:
             logits, k_new, v_new = self.adapter.decode_paged_fn(
-                params, tokens, positions, k_pages, v_pages, tables,
-                self.cfg, interpret=self._interpret)
+                params, tokens, positions, self.layout, k_pages, v_pages,
+                tables, self.cfg, interpret=self._interpret)
         else:
             C = self.max_blocks_per_seq * Bs
-            k_ctx = k_pages[:, tables]  # (L, S, MaxB, Bs, HK, D)
-            k_ctx = k_ctx.reshape(L, S, C, *k_ctx.shape[4:])
-            v_ctx = v_pages[:, tables]
-            v_ctx = v_ctx.reshape(L, S, C, *v_ctx.shape[4:])
             ctx_mask = jnp.arange(C)[None, :] < positions[:, None]
             logits, k_new, v_new = self.adapter.decode_fn(
-                params, tokens, positions, k_ctx, v_ctx, ctx_mask,
+                params, tokens, positions,
+                self._ctx_reader(k_pages, v_pages, tables), ctx_mask,
                 self.cfg)
         block_ids = jnp.take_along_axis(
             tables, (positions // Bs)[:, None], axis=1)[:, 0]
         offsets = positions % Bs
-        k_pages = k_pages.at[:, block_ids, offsets].set(k_new)
-        v_pages = v_pages.at[:, block_ids, offsets].set(v_new)
+        k_pages = self.layout.write(k_pages, block_ids, offsets, k_new)
+        v_pages = self.layout.write(v_pages, block_ids, offsets, v_new)
         nxt = self._sample(logits, temps, topks, topps, step)
         return nxt, logits, k_pages, v_pages
 

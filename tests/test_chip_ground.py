@@ -51,34 +51,39 @@ def test_flash_attention_lowers_for_tpu(shape):
 @pytest.mark.parametrize("shape", sorted(SHAPES))
 def test_paged_attention_lowers_for_tpu(shape, width):
     from ray_tpu.ops.paged_attention import paged_attention
+    from ray_tpu.serve.llm.cache import KVLayout
 
     H, HK, D = SHAPES[shape]
     S, L, pages, bs, max_blocks = 8, 4, 256, 16, 64
+    layout = KVLayout(L, pages, bs, HK, D)
     bf16 = jnp.bfloat16
     q = jax.ShapeDtypeStruct((S, width, H, D), bf16)
     own = jax.ShapeDtypeStruct((S, width, HK, D), bf16)
-    pool = jax.ShapeDtypeStruct((L, pages, bs, HK, D), bf16)
+    pool = jax.ShapeDtypeStruct(layout.shape, bf16)
     tables = jax.ShapeDtypeStruct((S, max_blocks), jnp.int32)
     ctx = jax.ShapeDtypeStruct((S,), jnp.int32)
     layer = jax.ShapeDtypeStruct((), jnp.int32)
     _lowers_for_tpu(
         lambda q, ok, ov, kp, vp, t, c, l: paged_attention(
-            q, ok, ov, kp, vp, t, c, layer=l),
+            q, ok, ov, kp, vp, t, c, layout=layout, layer=l),
         q, own, own, pool, pool, tables, ctx, layer)
 
 
 def test_paged_attention_layer_of_whole_pool_matches_reference():
-    """The models hand the kernel the whole (L, pages, Bs, HK, D) pool and
-    a traced layer index (slicing the pool per layer would copy it)."""
+    """The models hand the kernel the whole pool, as its KVLayout shapes
+    it, and a traced layer index (slicing the pool per layer, or
+    reshaping it, would copy it)."""
     from ray_tpu.ops.paged_attention import (
         paged_attention,
         paged_attention_reference,
     )
+    from ray_tpu.serve.llm.cache import KVLayout
 
     rng = np.random.RandomState(1)
     S, W, H, HK, D, bs, maxB, pages, L = 2, 3, 4, 2, 16, 4, 5, 24, 3
-    kp = rng.normal(size=(L, pages, bs, HK, D)).astype(np.float32)
-    vp = rng.normal(size=(L, pages, bs, HK, D)).astype(np.float32)
+    layout = KVLayout(L, pages, bs, HK, D)
+    kp = rng.normal(size=layout.shape).astype(np.float32)
+    vp = rng.normal(size=layout.shape).astype(np.float32)
     tables = rng.permutation(np.arange(1, pages))[:S * maxB] \
         .reshape(S, maxB).astype(np.int32)
     ctx = np.asarray([9, maxB * bs], np.int32)
@@ -87,10 +92,15 @@ def test_paged_attention_layer_of_whole_pool_matches_reference():
     ov = rng.normal(size=(S, W, HK, D)).astype(np.float32)
     for layer in range(L):
         out = jax.jit(lambda l: paged_attention(
-            q, ok, ov, kp, vp, tables, ctx, layer=l,
+            q, ok, ov, kp, vp, tables, ctx, layout=layout, layer=l,
             interpret=True))(jnp.int32(layer))
-        ref = paged_attention_reference(q, ok, ov, kp[layer], vp[layer],
-                                        tables, ctx)
+        # the oracle on the same pool, and on the layer's rows spelled
+        # out with numpy: (pages, bs, HK * D) -> heads of D lanes
+        ref = paged_attention_reference(q, ok, ov, kp, vp, tables, ctx,
+                                        layout=layout, layer=layer)
+        k_ctx = kp[layer][tables].reshape(S, maxB * bs, HK, D)
+        np.testing.assert_array_equal(
+            np.asarray(layout.read(kp, layer, tables)), k_ctx)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-4)
 

@@ -1,0 +1,268 @@
+"""The KV pool's layout (`serve/llm/cache.py` KVLayout): what it reads and
+writes against a numpy pool, its sharding over `tensor`, and — compiled for
+the v5e without a chip — that no serve program copies the pool whole.
+
+The last is the guard of PERF.md (PR 26): the padded (HK, D) minor
+dimensions, a context gathered for every layer at once, and a scatter with
+a leading `:` window each made XLA copy both pools through a temporary in
+every program; any one of them coming back shows here as a pool-sized
+`copy` and as gigabytes of temporaries."""
+
+import dataclasses
+import math
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.serve.llm.cache import KVLayout, auto_num_blocks
+
+# (n_layer, num_blocks, block_size, n_kv_head, head_dim)
+LAYOUTS = {"mha": (3, 12, 4, 4, 16), "gqa": (2, 10, 16, 8, 128)}
+
+
+def _numpy_pool(layout, rng):
+    """The pool as plain numpy holds it: (L, pages, Bs, HK, D)."""
+    return rng.normal(size=(layout.n_layer, layout.num_blocks,
+                            layout.block_size, layout.n_kv_head,
+                            layout.head_dim)).astype(np.float32)
+
+
+def _as_device(layout, pool):
+    return jnp.asarray(pool.reshape(layout.shape))
+
+
+@pytest.mark.parametrize("name", sorted(LAYOUTS))
+def test_decode_rows_written_then_read_match_numpy(name):
+    """One new row per lane, padded lanes writing to the null page 0 and
+    reading it back through a table of zeros."""
+    layout = KVLayout(*LAYOUTS[name])
+    L, P, Bs, HK, D = LAYOUTS[name]
+    rng = np.random.RandomState(0)
+    ref = _numpy_pool(layout, rng)
+    pages = _as_device(layout, ref)
+    # lanes 0, 1 real (positions 5 and Bs, the second opening a page),
+    # lanes 2, 3 padding: position 0 of a table of zeros
+    tables = np.zeros((4, 3), np.int32)
+    tables[0], tables[1] = [7, 2, 9], [4, 8, 0]
+    positions = np.asarray([5, Bs, 0, 0])
+    block_ids = tables[np.arange(4), positions // Bs]
+    offsets = positions % Bs
+    rows = rng.normal(size=(L, 4, HK, D)).astype(np.float32)
+    pages = jax.jit(layout.write)(pages, block_ids, offsets, rows)
+    for lane in (0, 1):
+        ref[:, block_ids[lane], offsets[lane]] = rows[:, lane]
+    got = np.asarray(pages).reshape(ref.shape)
+    np.testing.assert_array_equal(got[:, 1:], ref[:, 1:])
+    # the null page took one of the padded lanes' rows, nothing else
+    assert any(np.array_equal(got[:, 0, 0], rows[:, lane])
+               for lane in (2, 3))
+    np.testing.assert_array_equal(got[:, 0, 1:], ref[:, 0, 1:])
+    for layer in range(L):
+        ctx = jax.jit(layout.read)(pages, jnp.int32(layer), tables)
+        assert ctx.shape == (4, 3 * Bs, HK, D)
+        np.testing.assert_array_equal(
+            np.asarray(ctx), got[layer][tables].reshape(4, 3 * Bs, HK, D))
+        # slot c of a lane's context is position c of its sequence
+        np.testing.assert_array_equal(np.asarray(ctx)[1, Bs], rows[layer, 1])
+
+
+@pytest.mark.parametrize("name", sorted(LAYOUTS))
+def test_chunk_rows_crossing_a_page_match_numpy(name):
+    """A chunk that starts mid-table and crosses page boundaries, its
+    padded tail pointed at the null page."""
+    layout = KVLayout(*LAYOUTS[name])
+    L, P, Bs, HK, D = LAYOUTS[name]
+    rng = np.random.RandomState(1)
+    ref = _numpy_pool(layout, rng)
+    table = np.asarray([3, 6, 1, 5], np.int32)
+    start, n, Tb = Bs, 2 * Bs + 1, 3 * Bs  # pages 6, 1 and a row of 5
+    pos = start + np.arange(Tb)
+    block_ids = np.where(np.arange(Tb) < n, table[pos // Bs], 0)
+    offsets = pos % Bs
+    rows = rng.normal(size=(L, Tb, HK, D)).astype(np.float32)
+    pages = jax.jit(layout.write)(_as_device(layout, ref), block_ids,
+                                  offsets, rows)
+    for t in range(n):
+        ref[:, block_ids[t], offsets[t]] = rows[:, t]
+    got = np.asarray(pages).reshape(ref.shape)
+    np.testing.assert_array_equal(got[:, 1:], ref[:, 1:])
+    for layer in range(L):
+        ctx = np.asarray(layout.read(pages, layer, table[None]))
+        assert ctx.shape == (1, 4 * Bs, HK, D)
+        np.testing.assert_array_equal(ctx[0, start:start + n],
+                                      rows[layer, :n])
+        np.testing.assert_array_equal(ctx[0, :start], ref[layer, 3])
+
+
+@pytest.mark.parametrize("n_kv_head,sharded", [(4, True), (3, False)])
+def test_pool_shards_whole_heads_over_tensor(cpu_mesh8, n_kv_head, sharded):
+    """tensor=2: contiguous head blocks when the heads divide, replicated
+    when they do not; writes and reads agree with the unsharded pool, and
+    the pool's size on a device follows the same rule."""
+    layout = KVLayout(2, 6, 4, n_kv_head, 16)
+    rng = np.random.RandomState(2)
+    pages = layout.zeros(jnp.float32, cpu_mesh8)
+    want = layout.shape[:3] + (layout.row // 2 if sharded else layout.row,)
+    assert pages.sharding.shard_shape(pages.shape) == want
+    assert layout.shard_ways(2) == (2 if sharded else 1)
+    tables = np.asarray([[2, 5], [3, 0]], np.int32)
+    block_ids, offsets = np.asarray([5, 3, 0]), np.asarray([1, 0, 2])
+    rows = rng.normal(size=(2, 3, n_kv_head, 16)).astype(np.float32)
+    with jax.set_mesh(cpu_mesh8):
+        pages = jax.jit(layout.write)(pages, block_ids, offsets, rows)
+        ctx = jax.jit(layout.read)(pages, jnp.int32(1), tables)
+    assert pages.sharding.shard_shape(pages.shape) == want
+    plain = layout.write(layout.zeros(jnp.float32), block_ids, offsets, rows)
+    np.testing.assert_array_equal(np.asarray(pages), np.asarray(plain))
+    np.testing.assert_array_equal(
+        np.asarray(ctx), np.asarray(layout.read(plain, 1, tables)))
+    np.testing.assert_array_equal(np.asarray(ctx)[0, 4 + 1], rows[1, 0])
+
+    class Dev:
+        platform = "tpu"
+
+        def memory_stats(self):
+            return {"bytes_limit": 1 << 30}
+
+    sized = {ways: auto_num_blocks(
+        n_layer=2, n_kv_head=n_kv_head, head_dim=16, block_size=4,
+        dtype_bytes=2, max_model_len=64, max_batch_size=2,
+        memory_fraction=0.5, tensor_ways=ways, device=Dev())
+        for ways in (1, 2)}
+    assert sized[2] == (2 * sized[1] if sharded else sized[1])
+
+
+@pytest.mark.parametrize("model", ["gpt2", "llama"])
+def test_runner_decodes_the_same_on_a_tensor_mesh(cpu_mesh8, model):
+    """A tensor-parallel replica's pool is sharded by the layout's spec;
+    prefill, chunk and decode give the tokens of the one-device runner."""
+    from ray_tpu.serve.llm.runner import DecodeItem, ModelRunner, adapters
+
+    adapter = adapters()[model]
+    cfg = dataclasses.replace(adapter.presets["tiny"](), dtype=jnp.float32,
+                              remat=False)
+    params = adapter.init_fn(jax.random.PRNGKey(0), cfg)
+
+    def run(mesh):
+        r = ModelRunner(adapter, cfg, params, block_size=4, num_blocks=16,
+                        max_model_len=32, max_batch_size=2,
+                        prefill_chunk_size=8, mesh=mesh)
+        table = [3, 7, 2, 9]
+        out = [r.prefill(list(range(1, 9)), table, 0.0)[0]]
+        tok, logits = r.prefill_chunk(list(range(9, 15)), 8, table, 0.0)
+        out.append(tok)
+        for pos in (14, 15):
+            toks, logits = r.decode([DecodeItem(out[-1], pos, table, 0.0)])
+            out.append(toks[0])
+        return out, logits, r
+
+    want, want_logits, _ = run(None)
+    got, got_logits, r = run(cpu_mesh8)
+    assert got == want
+    np.testing.assert_allclose(got_logits, want_logits, atol=2e-4)
+    ways = r.layout.shard_ways(2)
+    assert ways == 2
+    assert r.k_pages.sharding.shard_shape(r.k_pages.shape)[-1] \
+        == r.layout.row // ways
+
+
+# ----------------------------------------------- compiled for the v5e
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip. Only libtpu's absence skips:
+    a topology that cannot be described where libtpu is must fail."""
+    pytest.importorskip("libtpu")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    # an executable compiled for a described chip can be written to the
+    # persistent cache but not read back here: keep these out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def large_runner(one_chip):
+    """gpt2-large's runner as the serve cells configure it (512 pages of
+    16, 8 lanes, chunks of 256, verify width 5) over shapes alone: no
+    weights, and a two-page pool in place of the real one — the programs
+    take their pool as an argument, and get the real shape."""
+    from ray_tpu.serve.llm.runner import ModelRunner, adapters
+
+    adapter = adapters()["gpt2"]
+    cfg = adapter.presets["large"]()
+
+    def shape(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(shape, jax.eval_shape(
+        lambda k: adapter.init_fn(k, cfg), jax.random.PRNGKey(0)))
+    runner = ModelRunner(adapter, cfg, params, block_size=16, num_blocks=2,
+                         max_model_len=1024, max_batch_size=8,
+                         prefill_chunk_size=256, num_draft_tokens=4)
+    runner._interpret = False  # the kernel as the chip compiles it
+    pool = jax.ShapeDtypeStruct(
+        dataclasses.replace(runner.layout, num_blocks=512).shape,
+        cfg.dtype, sharding=one_chip)
+    return runner, params, pool
+
+
+# program -> (runner method, argument shapes after (params, k, v); "m" is
+# max_blocks_per_seq); temperature, top-k and top-p follow, then the step
+PROGRAMS = {
+    "prefill-512": ("_prefill_impl", [((1, 512), "i"), ((), "i"),
+                                      ((512,), "i"), ((512,), "i")], 1),
+    "chunk-256": ("_chunk_impl", [((1, 256), "i"), ((), "i"), ((), "i"),
+                                  ((256,), "i"), ((256,), "i"),
+                                  (("m",), "i")], 1),
+    "verify-5": ("_verify_impl", [((1, 5), "i"), ((), "i"), ((), "i"),
+                                  ((5,), "i"), ((5,), "i"),
+                                  (("m",), "i")], 5),
+    "decode-8": ("_decode_impl", [((8,), "i"), ((8,), "i"),
+                                  ((8, "m"), "i")], 8),
+}
+
+
+@pytest.mark.parametrize("program,paged", [
+    ("prefill-512", False), ("chunk-256", False), ("verify-5", False),
+    ("decode-8", False), ("decode-8", True), ("verify-5", True)])
+def test_no_serve_program_copies_the_pool(one_chip, large_runner, program,
+                                          paged, monkeypatch):
+    # kernels are chosen by `jax.default_backend()`: take the chip's side
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    runner, params, pool = large_runner
+    method, shapes, lanes = PROGRAMS[program]
+
+    def arg(shape, kind):
+        shape = tuple(runner.max_blocks_per_seq if d == "m" else d
+                      for d in shape)
+        return jax.ShapeDtypeStruct(
+            shape, jnp.int32 if kind == "i" else jnp.float32,
+            sharding=one_chip)
+
+    args = [arg(*s) for s in shapes] + [
+        arg((lanes,), "f"), arg((lanes,), "i"), arg((lanes,), "f"),
+        arg((), "i")]
+    runner.use_paged_attention = paged
+    compiled = jax.jit(getattr(runner, method), donate_argnums=(1, 2)) \
+        .lower(params, pool, pool, *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text or not paged
+    pool_elements = math.prod(pool.shape)
+    copies = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* copy\(", text)
+        if math.prod(map(int, m.group(1).split(","))) >= pool_elements]
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9

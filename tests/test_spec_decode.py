@@ -81,11 +81,13 @@ def test_paged_attention_kernel_matches_dense_reference():
         paged_attention,
         paged_attention_reference,
     )
+    from ray_tpu.serve.llm.cache import KVLayout
 
     rng = np.random.RandomState(0)
     S, H, HK, D, bs, maxB, npages = 3, 4, 2, 16, 4, 6, 32
-    k_pages = rng.normal(size=(npages, bs, HK, D)).astype(np.float32)
-    v_pages = rng.normal(size=(npages, bs, HK, D)).astype(np.float32)
+    layout = KVLayout(1, npages, bs, HK, D)  # a pool of one layer
+    k_pages = rng.normal(size=layout.shape).astype(np.float32)
+    v_pages = rng.normal(size=layout.shape).astype(np.float32)
     perm = rng.permutation(np.arange(1, npages))
     tables = perm[:S * maxB].reshape(S, maxB).astype(np.int32)
     ctx_len = np.asarray([0, 7, maxB * bs], np.int32)  # the edges
@@ -94,9 +96,9 @@ def test_paged_attention_kernel_matches_dense_reference():
         ok = rng.normal(size=(S, W, HK, D)).astype(np.float32)
         ov = rng.normal(size=(S, W, HK, D)).astype(np.float32)
         out = paged_attention(q, ok, ov, k_pages, v_pages, tables,
-                              ctx_len, interpret=True)
+                              ctx_len, layout=layout, interpret=True)
         ref = paged_attention_reference(q, ok, ov, k_pages, v_pages,
-                                        tables, ctx_len)
+                                        tables, ctx_len, layout=layout)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-4)
 
