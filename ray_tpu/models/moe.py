@@ -1,35 +1,99 @@
-"""Mixture-of-Experts layer with expert parallelism.
+"""Routed experts: top-k softmax gating in which no token is dropped.
 
-SURVEY.md §7.8: EP is a first-class capability the reference lacks
-natively (it schedules frameworks that do it). TPU-native design:
+One implementation serves the llama-family MoE block (OLMoE: 64 SwiGLU
+experts, 8 a token) and the stand-alone `moe_layer` (GELU experts):
 
-- top-k softmax gating with capacity-based token dropping (Switch/GShard
-  style): dispatch/combine are one-hot einsums — MXU-friendly, static
-  shapes, no sorting;
-- the expert dimension of expert weights carries the `expert` mesh axis
-  in its partition rule; with tokens sharded on (data, fsdp) and experts
-  sharded on `expert`, GSPMD lowers the dispatch einsum to the
-  all-to-all over ICI that a hand-written NCCL MoE would issue;
-- f32 gate statistics, bf16 expert compute; auxiliary load-balancing
-  loss (Switch §2.2 form) returned alongside.
+- **route** (`moe.route`): router logits and softmax in float32 over all
+  experts, `lax.top_k`, weights renormalised only when asked, and the
+  count of pairs per expert;
+- **dispatch** (`moe.dispatch`): the routing weights as an (N, E) matrix,
+  zero where a token did not choose an expert;
+- **experts** (`moe.experts`): every expert computes every row, as batched
+  products over the stacked weights;
+- **combine** (`moe.combine`): the matrix picks the chosen pairs out and
+  sums them.
+
+So every chosen pair is computed whatever the imbalance — there is no
+capacity — and nothing is sorted, gathered or scattered. The arithmetic
+is E / k times the chosen pairs'. That is the faster way on the v5e at
+every row count a serve program has, for two measured reasons (PERF.md,
+PR 27; one OLMoE layer, 805 MB of experts): from 16 rows on every expert
+has rows and the layer takes as long as reading the weights (1.22-1.40 ms
+at 16-256 rows, where `jax.lax.ragged_dot` over rows ordered by expert
+takes 1.22-2.85 ms and the Pallas `megablox` product 1.24-1.90 ms); and
+below that, where a grouped product alone is faster (0.39 ms at one row),
+a grouped kernel is a custom call, for which XLA copies each layer's
+experts out of the scanned stack first (three copies of 268 MB a layer).
+
+The per-expert pair counts leave the layer with its output: imbalance is
+what a router costs, and only the program can see it.
+
+Expert weights carry the `expert` mesh axis on their expert dimension
+(`moe_partition_rules`, and the llama rules for `we_*`); GSPMD partitions
+the batched products from there.
 """
 
 from __future__ import annotations
 
 import dataclasses
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.parallel.sharding import constrain
+
+def route(x: jax.Array, router: jax.Array, k: int, norm_topk: bool):
+    """x (N, Dm), router (Dm, E) -> (weights (N, k) f32, experts (N, k)
+    i32, pairs per expert (E,) i32, probs (N, E) f32). Softmax over ALL
+    experts, then the k largest; their weights sum to one only when
+    `norm_topk`."""
+    with jax.named_scope("moe.route"):
+        logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, k)
+        if norm_topk:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        counts = jnp.zeros((router.shape[-1],), jnp.int32).at[
+            experts.reshape(-1)].add(1)
+    return weights, experts, counts, probs
+
+
+def routed_experts(
+    x: jax.Array,
+    router: jax.Array,
+    expert_fn: Callable,
+    *,
+    k: int,
+    norm_topk: bool,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """x (N, Dm) -> (out (N, Dm), pairs per expert (E,) i32, router probs
+    (N, E) f32). ``expert_fn(rows, mm)`` is one expert's feed-forward
+    written for all experts at once: ``mm(a, w)`` multiplies rows ``a``
+    ((N, in) going in, (E, N, in) between the layers) with the stacked
+    weights ``w`` (E, in, out), expert by expert."""
+    N = x.shape[0]
+    weights, experts, counts, probs = route(x, router, k, norm_topk)
+    with jax.named_scope("moe.dispatch"):
+        per_expert = jnp.zeros((N, router.shape[-1]), weights.dtype).at[
+            jnp.arange(N)[:, None], experts].set(weights)
+    with jax.named_scope("moe.experts"):
+        y = expert_fn(x, lambda a, w: jnp.einsum(
+            "nd,edf->enf" if a.ndim == 2 else "end,edf->enf", a, w))
+    with jax.named_scope("moe.combine"):
+        out = jnp.einsum("ne,end->nd", per_expert.astype(y.dtype), y)
+    return out.astype(x.dtype), counts, probs
+
+
+# --------------------------------------------------------------------------
+# The stand-alone layer (GELU experts, weights renormalised over the chosen
+# k, Switch-style auxiliary loss): the training-side user of the above.
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     num_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
     d_model: int = 128
     d_ff: int = 512
     dtype: object = jnp.bfloat16
@@ -62,54 +126,13 @@ def moe_layer(params: dict, x: jax.Array, cfg: MoEConfig,
     """x: (B, T, Dm) -> (out (B, T, Dm), aux_loss scalar)."""
     B, T, Dm = x.shape
     E = cfg.num_experts
-    N = B * T
-    cap = max(1, int(cfg.capacity_factor * N * cfg.top_k / E))
-    xt = x.reshape(N, Dm)
-
-    gate_logits = (xt.astype(jnp.float32)
-                   @ params["gate"]["kernel"].astype(jnp.float32))  # (N, E)
-    probs = jax.nn.softmax(gate_logits, axis=-1)
-
-    # top-k expert choice per token
-    gate_vals, gate_idx = jax.lax.top_k(probs, cfg.top_k)  # (N, k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-
-    # capacity assignment: position of each token within its expert's
-    # queue, computed per (k)-choice with a running cumsum (GShard-style)
-    combine = jnp.zeros((N, E, cap), jnp.float32)
-    used = jnp.zeros((N, E), jnp.float32)  # one-hot accumulation for aux
-    position_in_expert = jnp.zeros((E,), jnp.int32)
-    for choice in range(cfg.top_k):
-        idx = gate_idx[:, choice]  # (N,)
-        onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)  # (N, E)
-        # rank of each token within this expert across the batch
-        pos = (jnp.cumsum(onehot, axis=0) - onehot) + \
-            position_in_expert[None, :].astype(jnp.float32)
-        position_in_expert = position_in_expert + \
-            jnp.sum(onehot, axis=0).astype(jnp.int32)
-        pos_tok = jnp.sum(pos * onehot, axis=-1)  # (N,)
-        keep = pos_tok < cap
-        w = gate_vals[:, choice] * keep.astype(jnp.float32)
-        pos_oh = jax.nn.one_hot(pos_tok.astype(jnp.int32), cap,
-                                dtype=jnp.float32)  # (N, cap)
-        combine = combine + w[:, None, None] * onehot[:, :, None] \
-            * pos_oh[:, None, :]
-        used = used + onehot
-
-    dispatch = (combine > 0.0).astype(cfg.dtype)  # (N, E, cap)
-
-    # dispatch: (N,E,cap) x (N,Dm) -> (E,cap,Dm); sharded over `expert`
-    xe = jnp.einsum("nec,nd->ecd", dispatch, xt.astype(cfg.dtype))
-    xe = constrain(xe, "expert", None, None)
-    h = jnp.einsum("ecd,edf->ecf", xe, params["wi"].astype(cfg.dtype))
-    h = jax.nn.gelu(h)
-    ye = jnp.einsum("ecf,efd->ecd", h, params["wo"].astype(cfg.dtype))
-    ye = constrain(ye, "expert", None, None)
-    # combine back: weighted sum over experts/capacity slots
-    out = jnp.einsum("nec,ecd->nd", combine.astype(cfg.dtype), ye)
-
+    wi = params["wi"].astype(cfg.dtype)
+    wo = params["wo"].astype(cfg.dtype)
+    out, counts, probs = routed_experts(
+        x.reshape(B * T, Dm).astype(cfg.dtype), params["gate"]["kernel"],
+        lambda rows, mm: mm(jax.nn.gelu(mm(rows, wi)), wo),
+        k=cfg.top_k, norm_topk=True)
     # Switch-style load balancing aux loss: E * sum_e f_e * p_e
-    frac_tokens = jnp.mean(used, axis=0) / cfg.top_k  # (E,)
-    frac_probs = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac_tokens * frac_probs)
+    frac_tokens = counts.astype(jnp.float32) / (B * T * cfg.top_k)
+    aux = E * jnp.sum(frac_tokens * jnp.mean(probs, axis=0))
     return out.reshape(B, T, Dm).astype(x.dtype), aux

@@ -21,6 +21,8 @@ import threading
 import time
 from typing import Any, Sequence as Seq
 
+import numpy as np
+
 from ray_tpu.serve.llm.cache import BlockPool, auto_num_blocks
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams
 from ray_tpu.serve.llm.runner import DecodeItem, ModelRunner, adapters
@@ -336,6 +338,26 @@ class LLMEngine:
             "Bytes of device results fetched to the host by engine "
             "steps (sampled tokens and logits), by step kind",
             tag_keys=("model", "kind"))
+        # routed experts: what the programs report of their routing, by
+        # step kind (a dense model's programs report nothing)
+        moe_tags = ("model", "kind")
+        self._m_moe_pairs = Counter(
+            "serve_llm_moe_pairs_total",
+            "(token, expert) pairs the routed-expert layers computed, "
+            "padded rows included, by step kind", tag_keys=moe_tags)
+        self._m_moe_touched = Counter(
+            "serve_llm_moe_experts_touched_total",
+            "Experts that received at least one pair, summed over "
+            "programs and layers, by step kind", tag_keys=moe_tags)
+        self._m_moe_calls = Counter(
+            "serve_llm_moe_layer_calls_total",
+            "Routed-expert layers run (programs x layers), by step kind",
+            tag_keys=moe_tags)
+        self._m_moe_imbalance = Gauge(
+            "serve_llm_moe_load_imbalance",
+            "Pairs of the most loaded expert over the mean expert's, "
+            "cumulative, by step kind", tag_keys=moe_tags)
+        self._moe: dict[str, dict] = {}
         self._spec_proposed_total = 0
         self._spec_accepted_total = 0
         # counter deltas are computed against the last pump
@@ -476,6 +498,28 @@ class LLMEngine:
         self._d2h[kind] += fetched
         self._m_d2h.inc(fetched,
                         tags={"model": self.config.model, "kind": kind})
+        if self.runner.expert_pairs:  # never, for a dense model
+            self._note_routing(kind, self.runner.take_expert_pairs())
+
+    def _note_routing(self, kind: str, routed: list) -> None:
+        """Account the step's routed-expert layers: `routed` holds one
+        (L, n_experts) array of pairs per program the step ran."""
+        c = np.stack(routed)  # (programs, L, n_experts)
+        pairs, touched = int(c.sum()), int(np.count_nonzero(c))
+        calls = c.shape[0] * c.shape[1]
+        acc = self._moe.setdefault(kind, {
+            "pairs": 0, "expert_pairs": np.zeros(c.shape[2], np.int64),
+            "experts_touched": 0, "layer_calls": 0})
+        acc["pairs"] += pairs
+        acc["experts_touched"] += touched
+        acc["layer_calls"] += calls
+        acc["expert_pairs"] += c.sum(axis=(0, 1))
+        tags = {"model": self.config.model, "kind": kind}
+        self._m_moe_pairs.inc(pairs, tags=tags)
+        self._m_moe_touched.inc(touched, tags=tags)
+        self._m_moe_calls.inc(calls, tags=tags)
+        per = acc["expert_pairs"]
+        self._m_moe_imbalance.set(float(per.max() / per.mean()), tags=tags)
 
     def _do_prefill(self, work: PrefillWork) -> int:
         """One prefill program; returns the tokens it produced (one on a
@@ -844,8 +888,9 @@ class LLMEngine:
             wall = time.perf_counter() - t0
             spent = {k: v - before[k]
                      for k, v in tracing.compile_totals().items()}
-            # what warm-up fetched is no step's
+            # what warm-up fetched and routed is no step's
             self._fetched_seen = self.runner.fetched_bytes
+            self.runner.take_expert_pairs()
         up = self._startup
         up["warmup"] += wall
         up["warmup_trace"] += spent["trace"]
@@ -890,6 +935,12 @@ class LLMEngine:
             "spec_proposed": self._spec_proposed_total,
             "spec_accepted": self._spec_accepted_total,
             "paged_attention": self.runner.use_paged_attention,
+            # routed experts by step kind: pairs computed, the same per
+            # expert (summed over layers), experts touched and layers run
+            # (both summed over programs and layers); {} for a dense model
+            "moe": {kind: {**acc, "expert_pairs":
+                           acc["expert_pairs"].tolist()}
+                    for kind, acc in list(self._moe.items())},
             # the device this replica's process runs on, as jax reports it
             "platform": devices[0].platform,
             "device_kind": devices[0].device_kind,

@@ -61,7 +61,10 @@ class ModelAdapter:
     config_cls: type
     presets: dict[str, Callable[[], Any]]
     init_fn: Callable  # (key, cfg) -> params
-    prefill_fn: Callable  # (params, tokens, cfg) -> (logits, k, v)
+    # (params, tokens, cfg) -> (logits, k, v), as every forward below; a
+    # family with routed experts appends their pairs per layer and
+    # expert, (L, n_experts) i32, which the programs hand on to the host
+    prefill_fn: Callable
     # read_ctx(layer) -> (k_ctx, v_ctx), that layer's cached context
     decode_fn: Callable  # (params, toks, pos, read_ctx, mask, cfg) -> ...
     # (params, toks, start, read_ctx, ctx_mask, chunk_mask, cfg) -> ...
@@ -109,6 +112,9 @@ def adapters() -> dict[str, ModelAdapter]:
             presets={
                 "tiny": llama.LlamaConfig.tiny,
                 "small": llama.LlamaConfig.small,
+                "olmoe_tiny": llama.LlamaConfig.olmoe_tiny,
+                "olmoe_1b_7b": llama.LlamaConfig.olmoe_1b_7b,
+                "olmoe_1b_7b_l8": llama.LlamaConfig.olmoe_1b_7b_l8,
             },
             init_fn=llama.init_llama,
             prefill_fn=llama.llama_prefill_kv,
@@ -285,6 +291,7 @@ class ModelRunner:
         # bytes of device results copied to the host, ever (tokens and
         # logits, every `np.asarray` / `int()` of a program's output)
         self.fetched_bytes = 0
+        self.expert_pairs: list[np.ndarray] = []
         # compile observability: warmup() should account for ALL misses;
         # a mid-stream miss afterwards is the recompile bug these catch
         from ray_tpu.util.metrics import Counter, Histogram
@@ -345,13 +352,14 @@ class ModelRunner:
                       block_ids, offsets, temp, topk, topp, step):
         """tokens (1, Tb); block_ids/offsets (Tb,) map position t to its
         page slot (padded positions -> null page 0)."""
-        logits, k, v = self.adapter.prefill_fn(params, tokens, self.cfg)
+        logits, k, v, *aux = self.adapter.prefill_fn(
+            params, tokens, self.cfg)
         # (L, 1, Tb, HK, D) -> (L, Tb, HK, D)
         k_pages = self.layout.write(k_pages, block_ids, offsets, k[:, 0])
         v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
         nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
-        return nxt, last, k_pages, v_pages
+        return nxt, last, k_pages, v_pages, tuple(aux)
 
     def _chunk_impl(self, params, k_pages, v_pages, tokens, start,
                     last_idx, block_ids, offsets, table, temp, topk,
@@ -368,7 +376,7 @@ class ModelRunner:
         C = self.max_blocks_per_seq * self.block_size
         ctx_mask = (jnp.arange(C)[None, :] < start)  # (1, C)
         chunk_mask = (jnp.arange(Tb)[None, :] <= last_idx)  # (1, Tb)
-        logits, k, v = self.adapter.chunk_fn(
+        logits, k, v, *aux = self.adapter.chunk_fn(
             params, tokens, start,
             self._ctx_reader(k_pages, v_pages, table[None]), ctx_mask,
             chunk_mask, self.cfg)
@@ -376,7 +384,7 @@ class ModelRunner:
         v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
         nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
-        return nxt, last, k_pages, v_pages
+        return nxt, last, k_pages, v_pages, tuple(aux)
 
     def _verify_impl(self, params, k_pages, v_pages, tokens, start,
                      n_draft, block_ids, offsets, table, temps, topks,
@@ -399,18 +407,18 @@ class ModelRunner:
         overwritten as the frontier advances — rollback is frontier
         arithmetic, not data movement.
 
-        Returns (emitted (W,), n_acc scalar, logits (W, Vp), pages):
-        the caller commits emitted[:n_acc + 1]."""
+        Returns (emitted (W,), n_acc scalar, logits (W, Vp), pages, the
+        forward's extras): the caller commits emitted[:n_acc + 1]."""
         W = tokens.shape[1]
         if self.use_paged_attention:
-            logits, k, v = self.adapter.verify_paged_fn(
+            logits, k, v, *aux = self.adapter.verify_paged_fn(
                 params, tokens, start, self.layout, k_pages, v_pages,
                 table, self.cfg, interpret=self._interpret)
         else:
             C = self.max_blocks_per_seq * self.block_size
             ctx_mask = (jnp.arange(C)[None, :] < start)  # (1, C)
             chunk_mask = (jnp.arange(W)[None, :] <= n_draft)  # (1, W)
-            logits, k, v = self.adapter.chunk_fn(
+            logits, k, v, *aux = self.adapter.chunk_fn(
                 params, tokens, start,
                 self._ctx_reader(k_pages, v_pages, table[None]),
                 ctx_mask, chunk_mask, self.cfg)
@@ -424,7 +432,7 @@ class ModelRunner:
             & (jnp.arange(W - 1) < n_draft)
         n_acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32)))
         emitted = jnp.where(jnp.arange(W) <= n_acc, target, -1)
-        return emitted, n_acc, lg, k_pages, v_pages
+        return emitted, n_acc, lg, k_pages, v_pages, tuple(aux)
 
     def _decode_impl(self, params, k_pages, v_pages, tokens, positions,
                      tables, temps, topks, topps, step):
@@ -435,13 +443,13 @@ class ModelRunner:
         the kernel indexes pages in place through the block table."""
         Bs = self.block_size
         if self.use_paged_attention:
-            logits, k_new, v_new = self.adapter.decode_paged_fn(
+            logits, k_new, v_new, *aux = self.adapter.decode_paged_fn(
                 params, tokens, positions, self.layout, k_pages, v_pages,
                 tables, self.cfg, interpret=self._interpret)
         else:
             C = self.max_blocks_per_seq * Bs
             ctx_mask = jnp.arange(C)[None, :] < positions[:, None]
-            logits, k_new, v_new = self.adapter.decode_fn(
+            logits, k_new, v_new, *aux = self.adapter.decode_fn(
                 params, tokens, positions,
                 self._ctx_reader(k_pages, v_pages, tables), ctx_mask,
                 self.cfg)
@@ -451,17 +459,31 @@ class ModelRunner:
         k_pages = self.layout.write(k_pages, block_ids, offsets, k_new)
         v_pages = self.layout.write(v_pages, block_ids, offsets, v_new)
         nxt = self._sample(logits, temps, topks, topps, step)
-        return nxt, logits, k_pages, v_pages
+        return nxt, logits, k_pages, v_pages, tuple(aux)
 
     # -------------------------------------------------------------- host
 
-    def _fetch(self, *results) -> list[np.ndarray]:
+    def _fetch(self, *results, aux=()) -> list[np.ndarray]:
         """Device results as numpy arrays: the wait for the program that
-        makes them, then the copy to the host."""
+        makes them, then the copy to the host. `aux` is what the family's
+        forward returned beside (logits, k, v): nothing, or the routed
+        experts' pairs per layer and expert, kept for the engine
+        (`take_expert_pairs`)."""
         with self.phases.phase("fetch"):
             out = [np.asarray(r) for r in results]
             self.fetched_bytes += sum(a.nbytes for a in out)
+            if aux:  # routed experts only
+                extra = [np.asarray(r) for r in aux]
+                self.fetched_bytes += sum(a.nbytes for a in extra)
+                self.expert_pairs.extend(extra)
         return out
+
+    def take_expert_pairs(self) -> list[np.ndarray]:
+        """The (L, n_experts) pairs-per-expert arrays of the programs run
+        since the last call, padded rows included (the device computed
+        them); always empty for a dense model."""
+        taken, self.expert_pairs = self.expert_pairs, []
+        return taken
 
     def _mesh_ctx(self):
         return (jax.set_mesh(self.mesh) if self.mesh is not None
@@ -508,13 +530,13 @@ class ModelRunner:
             before = tracing.jit_cache_size(self._prefill_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
-                nxt, last, self.k_pages, self.v_pages = self._prefill_jit(
+                nxt, last, self.k_pages, self.v_pages, aux = self._prefill_jit(
                     self.params, self.k_pages, self.v_pages, toks,
                     np.int32(n - 1), block_ids, offsets, temp, topk, topp,
                     np.int32(self._step_counter))
             self._note_compile("prefill", self._prefill_jit, before,
                                time.perf_counter() - t0)
-        nxt, last = self._fetch(nxt, last)
+        nxt, last = self._fetch(nxt, last, aux=aux)
         return int(nxt), last
 
     def prefill_chunk(self, token_ids: Sequence[int], start: int,
@@ -554,13 +576,13 @@ class ModelRunner:
             before = tracing.jit_cache_size(self._chunk_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
-                nxt, last, self.k_pages, self.v_pages = self._chunk_jit(
+                nxt, last, self.k_pages, self.v_pages, aux = self._chunk_jit(
                     self.params, self.k_pages, self.v_pages, toks,
                     np.int32(start), np.int32(n - 1), block_ids, offsets,
                     tab, temp, topk, topp, np.int32(self._step_counter))
             self._note_compile("prefill_chunk", self._chunk_jit, before,
                                time.perf_counter() - t0)
-        nxt, last = self._fetch(nxt, last)
+        nxt, last = self._fetch(nxt, last, aux=aux)
         return int(nxt), last
 
     def decode(self, items: Sequence[DecodeItem]
@@ -590,13 +612,14 @@ class ModelRunner:
             before = tracing.jit_cache_size(self._decode_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
-                nxt, logits, self.k_pages, self.v_pages = self._decode_jit(
+                nxt, logits, self.k_pages, self.v_pages, aux = \
+                    self._decode_jit(
                     self.params, self.k_pages, self.v_pages, toks, poss,
                     tables, temps, topks, topps,
                     np.int32(self._step_counter))
             self._note_compile("decode", self._decode_jit, before,
                                time.perf_counter() - t0)
-        nxt, logits = self._fetch(nxt, logits)
+        nxt, logits = self._fetch(nxt, logits, aux=aux)
         return [int(t) for t in nxt[:S]], logits[:S]
 
     def verify(self, token: int, pos: int, draft: Sequence[int],
@@ -643,7 +666,7 @@ class ModelRunner:
             before = tracing.jit_cache_size(self._verify_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
-                emitted, n_acc, logits, self.k_pages, self.v_pages = \
+                emitted, n_acc, logits, self.k_pages, self.v_pages, aux = \
                     self._verify_jit(
                         self.params, self.k_pages, self.v_pages, toks,
                         np.int32(pos), np.int32(n_draft), block_ids,
@@ -651,7 +674,8 @@ class ModelRunner:
                         np.int32(self._step_counter))
             self._note_compile("verify", self._verify_jit, before,
                                time.perf_counter() - t0)
-        n_acc, emitted, logits = self._fetch(n_acc, emitted, logits)
+        n_acc, emitted, logits = self._fetch(n_acc, emitted, logits,
+                                             aux=aux)
         n_em = int(n_acc) + 1
         return [int(t) for t in emitted[:n_em]], logits[:n_em]
 

@@ -171,6 +171,23 @@ CATALOG: list[dict] = [
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "bytes of device results (tokens, logits) the engine's "
              "steps fetched to the host, by step kind"},
+    # routed experts (a model with none reports none)
+    {"name": "serve_llm_moe_pairs_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "(token, expert) pairs the routed-expert layers computed, "
+             "padded rows included, by step kind"},
+    {"name": "serve_llm_moe_experts_touched_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "experts that received at least one pair, summed over "
+             "programs and layers, by step kind"},
+    {"name": "serve_llm_moe_layer_calls_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "routed-expert layers run (programs x layers), by step "
+             "kind"},
+    {"name": "serve_llm_moe_load_imbalance", "type": "gauge",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "pairs of the most loaded expert over the mean expert's, "
+             "cumulative, by step kind (1.0: an even router)"},
     # jax's own account of its compiles (every process that compiles)
     {"name": "jax_compile_seconds_total", "type": "counter",
      "where": "ray_tpu/util/tracing.py",

@@ -117,3 +117,33 @@ def test_spmd_sharded_step_matches_single_device():
         losses[name] = float(metrics["loss"])
     np.testing.assert_allclose(losses["single"], losses["sharded"],
                                rtol=1e-4)
+
+
+def test_tiny_keeps_its_tree_and_its_logits():
+    """The dense presets are what they were before the block was written
+    over three helpers (`_qkv`, `_ffn`, `_head`) and the config grew the
+    OLMoE fields: the same parameter tree (paths, shapes, dtypes) and,
+    in float32 on the CPU, logits within 1e-6 of values recorded from the
+    commit before (PR 26's, `llama_forward` on these tokens)."""
+    cfg = LlamaConfig.tiny()
+    params = init_llama(jax.random.PRNGKey(0), cfg)
+    L, E, F, KV = 2, 128, 384, 64
+    assert jax.tree.map(lambda a: (a.shape, str(a.dtype)), params) == {
+        "wte": ((512, E), "float32"), "lnf": ((E,), "float32"),
+        "blocks": {
+            "ln_attn": ((L, E), "float32"), "ln_mlp": ((L, E), "float32"),
+            "wq": ((L, E, E), "float32"), "wk": ((L, E, KV), "float32"),
+            "wv": ((L, E, KV), "float32"), "wo": ((L, E, E), "float32"),
+            "w_gate": ((L, E, F), "float32"), "w_up": ((L, E, F), "float32"),
+            "w_down": ((L, F, E), "float32")}}
+    toks = (jnp.arange(24, dtype=jnp.int32).reshape(2, 12) * 7 + 3) \
+        % cfg.vocab_size
+    logits = np.asarray(llama_forward(params, toks, cfg))
+    np.testing.assert_allclose(
+        logits[1, -1, :6],
+        [0.12285878, 0.028353298, -0.050259236, -0.23707722, 0.023302875,
+         -0.5333573], atol=1e-6, rtol=0)
+    np.testing.assert_allclose(
+        logits[0, 3, 100:104],
+        [-0.1831285, -0.17130451, 0.24605943, 0.073974], atol=1e-6, rtol=0)
+    assert abs(float(np.abs(logits).mean()) - 0.18084274) < 1e-6

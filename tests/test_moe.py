@@ -17,7 +17,7 @@ def expert_mesh():
 
 def _cfg(**kw):
     base = dict(num_experts=4, top_k=2, d_model=32, d_ff=64,
-                capacity_factor=2.0, dtype=jnp.float32)
+                dtype=jnp.float32)
     base.update(kw)
     return MoEConfig(**base)
 
@@ -33,7 +33,7 @@ def test_moe_forward_shapes_and_aux():
 
 def test_moe_matches_dense_single_expert():
     """With one expert and top_k=1, MoE reduces to a plain MLP."""
-    cfg = _cfg(num_experts=1, top_k=1, capacity_factor=4.0)
+    cfg = _cfg(num_experts=1, top_k=1)
     params = init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 8, cfg.d_model))
     out, _ = moe_layer(params, x, cfg)
@@ -60,8 +60,8 @@ def test_moe_differentiable():
 
 
 def test_moe_sharded_over_expert_axis(expert_mesh):
-    """Same numbers under jit with experts sharded over the mesh (GSPMD
-    inserts the dispatch all-to-all)."""
+    """Same numbers under jit with the experts' weights sharded over the
+    `expert` axis and the tokens over `data`."""
     cfg = _cfg(num_experts=8)
     params = init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))

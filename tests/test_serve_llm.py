@@ -150,6 +150,37 @@ def test_decode_parity_llama():
     _parity_case("llama", llama.LlamaConfig.tiny(), llama.llama_forward)
 
 
+def test_llama_tiny_serves_the_logits_it_always_did():
+    """The llama family's serve programs after the forwards were written
+    over three helpers: prefill, a chunk from an offset and a decode step
+    of the `tiny` preset give, in float32 on the CPU, the logits recorded
+    from the commit before (PR 26's), within 1e-6, and no routing
+    account."""
+    ad = adapters()["llama"]
+    cfg = ad.presets["tiny"]()
+    params = ad.init_fn(jax.random.PRNGKey(0), cfg)
+    r = ModelRunner(ad, cfg, params, block_size=4, num_blocks=16,
+                    max_model_len=32, max_batch_size=2,
+                    prefill_chunk_size=8)
+    table = [3, 7, 2, 9]
+    tok, last = r.prefill(list(range(1, 9)), table, 0.0)
+    assert tok == 8
+    np.testing.assert_allclose(
+        last[:4], [0.12197931, 0.09360814, -0.06515012, 0.10851755],
+        atol=1e-6, rtol=0)
+    tok, last = r.prefill_chunk(list(range(9, 15)), 8, table, 0.0)
+    assert tok == 14
+    np.testing.assert_allclose(
+        last[:4], [0.17846088, -0.032823294, 0.11180488, 0.051300142],
+        atol=1e-6, rtol=0)
+    toks, logits = r.decode([DecodeItem(tok, 14, table, 0.0)])
+    assert toks == [14]
+    np.testing.assert_allclose(
+        logits[0, :4], [0.20625733, -0.05909551, 0.0822587, 0.07995838],
+        atol=1e-6, rtol=0)
+    assert r.take_expert_pairs() == []  # a dense model reports no routing
+
+
 def test_decode_batch_parity_independent_sequences():
     """Batched decode lanes must not leak across sequences: two
     different prompts decoded in one batch match their solo runs."""
