@@ -102,6 +102,25 @@ def gpt2_partition_rules() -> PartitionRules:
     )
 
 
+def gpt2_resident_params(params: Params, cfg: GPT2Config) -> Params:
+    """The tree as a server holds it between programs: the leaves the
+    forwards apply `.astype(cfg.dtype)` to (`wte`, `wpe`, every `kernel`
+    and `bias` under `blocks`) in `cfg.dtype`, so that no program rounds
+    them again; the layer-norm leaves (`ln1`, `ln2`, `lnf`) as given,
+    because `_layer_norm` multiplies them in float32. A leaf already in
+    `cfg.dtype` is returned as it is, the same buffer. The trainer keeps
+    its float32 master copy and never calls this."""
+    dt = jnp.dtype(cfg.dtype)
+
+    def held(a):
+        return a if a.dtype == dt else jnp.asarray(a, dt)
+
+    blocks = {name: sub if name.startswith("ln") else jax.tree.map(held, sub)
+              for name, sub in params["blocks"].items()}
+    return {**params, "wte": held(params["wte"]),
+            "wpe": held(params["wpe"]), "blocks": blocks}
+
+
 def _dense_init(key, in_dim, out_dim, scale):
     return jax.random.normal(key, (in_dim, out_dim), jnp.float32) * scale
 

@@ -184,7 +184,9 @@ class LLMEngine:
             num_draft_tokens=self._spec_k,
             use_paged_attention=config.use_paged_attention,
         )
-        # weights cast and placed, both pools allocated
+        # weights cast to their resident dtypes and placed, both pools
+        # allocated. Nothing keeps the tree as given (float32 for GPT-2)
+        # beyond this constructor: it is freed before warm-up
         jax.block_until_ready((self.runner.params, self.runner.k_pages,
                                self.runner.v_pages))
         self._startup["build_runner"] = time.perf_counter() - t0
@@ -333,6 +335,16 @@ class LLMEngine:
         self._m_paged.set(
             1.0 if self.runner.use_paged_attention else 0.0,
             tags=self._m_tags)
+        self._m_weight_bytes = Gauge(
+            "serve_llm_weight_bytes",
+            "Bytes of the resident parameter tree, each leaf in the "
+            "dtype the programs consume it in", tag_keys=tags)
+        self._m_weight_cast = Gauge(
+            "serve_llm_weight_cast_leaves",
+            "Leaves the last weight install had to convert to their "
+            "resident dtype (0: the tree arrived as it is held)",
+            tag_keys=tags)
+        self._note_weights()
         self._m_d2h = Counter(
             "serve_llm_d2h_bytes_total",
             "Bytes of device results fetched to the host by engine "
@@ -362,6 +374,11 @@ class LLMEngine:
         self._spec_accepted_total = 0
         # counter deltas are computed against the last pump
         self._last_prefix = (0, 0, 0)
+
+    def _note_weights(self) -> None:
+        w = self.runner.weights
+        self._m_weight_bytes.set(w["resident_bytes"], tags=self._m_tags)
+        self._m_weight_cast.set(w["cast_leaves"], tags=self._m_tags)
 
     def _note_tokens(self, n: int) -> None:
         self._m_tokens.inc(n, tags=self._m_tags)
@@ -873,6 +890,7 @@ class LLMEngine:
         dt = time.perf_counter() - t0
         self._m_swaps.inc(tags=self._m_tags)
         self._m_swap_s.observe(dt, tags=self._m_tags)
+        self._note_weights()
         return {"version": version, "previous_version": previous,
                 "swap_seconds": dt, "in_flight_streams": in_flight,
                 "registrations_dropped": dropped}
@@ -932,6 +950,9 @@ class LLMEngine:
             "d2h_bytes": dict(self._d2h),
             "startup_seconds": dict(self._startup),
             "warmup_cache": dict(self._warmup_cache),
+            # the resident parameter tree: its bytes, the leaves the last
+            # install converted to their resident dtype, installs so far
+            "weights": dict(self.runner.weights),
             "spec_proposed": self._spec_proposed_total,
             "spec_accepted": self._spec_accepted_total,
             "paged_attention": self.runner.use_paged_attention,

@@ -71,6 +71,10 @@ class ModelAdapter:
     chunk_fn: Callable
     rules_fn: Callable  # () -> PartitionRules
     kv_heads: Callable[[Any], int]
+    # (params, cfg) -> the tree as the runner holds it: each leaf in the
+    # dtype the forwards consume it in, one already there as the same
+    # buffer (llama: `init_llama` creates every leaf in `param_dtype`)
+    resident_fn: Callable = lambda params, cfg: params
     # paged-attention entry points (ops/paged_attention.py kernel in the
     # attention core instead of dense gathered context); None => family
     # has no paged path and the engine falls back to dense
@@ -103,6 +107,7 @@ def adapters() -> dict[str, ModelAdapter]:
             chunk_fn=gpt2.gpt2_prefill_chunk_kv,
             rules_fn=gpt2.gpt2_partition_rules,
             kv_heads=lambda cfg: cfg.n_head,
+            resident_fn=gpt2.gpt2_resident_params,
             decode_paged_fn=gpt2.gpt2_decode_paged_kv,
             verify_paged_fn=gpt2.gpt2_verify_paged_kv,
         ),
@@ -266,12 +271,13 @@ class ModelRunner:
 
         self.layout = KVLayout(cfg.n_layer, num_blocks, block_size,
                                adapter.kv_heads(cfg), cfg.head_dim)
-        if mesh is not None:
-            from ray_tpu.parallel.sharding import shard_pytree
-
-            self.params = shard_pytree(params, adapter.rules_fn(), mesh)
-        else:
-            self.params = params
+        # pages are mutated functionally; serialize compute just in case
+        # a stats probe races the step loop
+        self._jit_lock = threading.Lock()
+        # the last install, for `engine.stats()["weights"]`
+        self.weights = {"resident_bytes": 0, "cast_leaves": 0,
+                        "installs": 0}
+        self._install(params, adapter.resident_fn(params, cfg))
         self.k_pages = self.layout.zeros(cfg.dtype, mesh)
         self.v_pages = self.layout.zeros(cfg.dtype, mesh)
 
@@ -284,9 +290,6 @@ class ModelRunner:
         self._decode_jit = jax.jit(self._decode_impl, donate_argnums=donate)
         self._chunk_jit = jax.jit(self._chunk_impl, donate_argnums=donate)
         self._verify_jit = jax.jit(self._verify_impl, donate_argnums=donate)
-        # pages are mutated functionally; serialize compute just in case
-        # a stats probe races the step loop
-        self._jit_lock = threading.Lock()
         self.phases = tracing.PhaseClock("llm.", STEP_PHASES)
         # bytes of device results copied to the host, ever (tokens and
         # logits, every `np.asarray` / `int()` of a program's output)
@@ -719,11 +722,33 @@ class ModelRunner:
             self.verify(1, 0, [1], null_table, 0.0)
         return self.compiled_signatures()
 
+    def _install(self, given: Any, resident: Any) -> None:
+        """Make `resident`, which is `given` with every leaf in its
+        resident dtype, the tree the programs run on, and account for
+        it: its bytes, and how many leaves had to be converted."""
+        if self.mesh is not None:
+            from ray_tpu.parallel.sharding import shard_pytree
+
+            resident = shard_pytree(resident, self.adapter.rules_fn(),
+                                    self.mesh)
+        leaves = jax.tree.leaves(resident)
+        weights = {
+            "resident_bytes": sum(int(np.prod(r.shape)) * r.dtype.itemsize
+                                  for r in leaves),
+            "cast_leaves": sum(
+                getattr(g, "dtype", None) != r.dtype
+                for g, r in zip(jax.tree.leaves(given), leaves)),
+            "installs": self.weights["installs"] + 1}
+        with self._jit_lock:
+            self.params, self.weights = resident, weights
+
     def set_params(self, params: Any) -> None:
         """Install a new parameter pytree (weight hot-swap). The tree
         structure and leaf shapes must match the resident params, and
-        leaves are cast to the resident dtypes, so a swap can NEVER
-        trigger a recompile — the compiled programs see new argument
+        leaves are cast to the resident dtypes (a trainer's float32 tree
+        is rounded once, here, where the resident leaf is bf16; a leaf
+        already in its resident dtype is taken as it is), so a swap can
+        NEVER trigger a recompile — the compiled programs see new argument
         values, not new signatures. With a mesh, leaves are re-sharded
         through the same partition rules as construction. The caller
         guarantees no device program is in flight (the engine holds its
@@ -744,14 +769,7 @@ class ModelRunner:
                     f"update has {arr.shape}")
             return arr
 
-        params = jax.tree.map(cast, params, self.params)
-        if self.mesh is not None:
-            from ray_tpu.parallel.sharding import shard_pytree
-
-            params = shard_pytree(params, self.adapter.rules_fn(),
-                                  self.mesh)
-        with self._jit_lock:
-            self.params = params
+        self._install(params, jax.tree.map(cast, params, self.params))
 
     def reset_cache(self) -> None:
         """Zero the pages (tests); allocator state lives in BlockPool."""

@@ -171,6 +171,14 @@ CATALOG: list[dict] = [
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "bytes of device results (tokens, logits) the engine's "
              "steps fetched to the host, by step kind"},
+    {"name": "serve_llm_weight_bytes", "type": "gauge",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "bytes of the resident parameter tree, each leaf in the "
+             "dtype the serve programs consume it in"},
+    {"name": "serve_llm_weight_cast_leaves", "type": "gauge",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "leaves the last weight install converted to their "
+             "resident dtype (0: the tree arrived as it is held)"},
     # routed experts (a model with none reports none)
     {"name": "serve_llm_moe_pairs_total", "type": "counter",
      "where": "ray_tpu/serve/llm/engine.py",
