@@ -113,8 +113,10 @@ class RLFlywheel:
                 :self.config.overlap_prompts]:
             probes.append(self.worker.engine.add_request(list(prompt),
                                                          sp))
-        for _ in range(2):  # streams genuinely mid-generation
-            self.worker.engine.step()
+        # streams genuinely mid-generation: one turn reads the first
+        # probe's prefill with the second's already on the device, and
+        # the swap reads that one before it installs
+        self.worker.engine.step()
         swap = self._install(version, weights)
         if swap["in_flight_streams"] < 1:
             # the probes finished before the swap landed — the lap

@@ -15,6 +15,9 @@ preemptions.
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import dataclasses
 import itertools
 import queue
 import threading
@@ -25,16 +28,48 @@ import numpy as np
 
 from ray_tpu.serve.llm.cache import BlockPool, auto_num_blocks
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams
-from ray_tpu.serve.llm.runner import DecodeItem, ModelRunner, adapters
+from ray_tpu.serve.llm.runner import (
+    DecodeItem,
+    Launched,
+    ModelRunner,
+    adapters,
+)
 from ray_tpu.serve.llm.scheduler import (
     DecodeWork,
+    NeedsResults,
     PrefillWork,
     Scheduler,
+    SeqState,
     Sequence,
 )
 from ray_tpu.util import tracing
 
 _FINAL = object()
+# why a step was read with none launched behind it
+DRAIN_REASONS = ("speculation", "preempt", "swap", "abort", "idle", "error")
+
+
+@dataclasses.dataclass
+class _Flight:
+    """One step from its planning to its commit."""
+
+    kind: str  # "prefill" | "decode"
+    work: PrefillWork | DecodeWork
+    # the lanes it samples a token for (none: an intermediate chunk),
+    # and how many tokens of each the host had not read at its launch
+    sampled: list[Sequence]
+    unread: list[int]
+    ver: int  # the weight version its program runs on
+    stalled: bool  # a prefill that holds decode-ready lanes back
+    ahead: bool  # planned while the step before it was unread
+    t0: float  # perf_counter at its planning
+    handle: Launched | None = None
+    error: Exception | None = None  # raised by its launch
+    # under speculation: the lanes of `sampled` in the plain program,
+    # and those with a draft, verified once the plain ones are committed
+    plain: list[Sequence] = dataclasses.field(default_factory=list)
+    drafted: list[tuple[Sequence, list[int]]] = dataclasses.field(
+        default_factory=list)
 
 
 class RequestStream:
@@ -197,6 +232,18 @@ class LLMEngine:
         self._steps = {"decode": 0, "prefill": 0}
         self._d2h = {"decode": 0, "prefill": 0}
         self._fetched_seen = 0
+        # steps launched and not yet read, oldest first: at most one
+        # between two calls of step(), two inside one (the one being
+        # read and the one behind it). Touched under _step_lock only
+        self._flights: collections.deque[_Flight] = collections.deque()
+        self._last_collect = 0.0  # perf_counter at the last step's end
+        # how often the next step was on the device before this one's
+        # results were read, and why not when it was not (stats())
+        self._overlap = {
+            "launched_ahead": {"decode": 0, "prefill": 0},
+            "launched_drained": {"decode": 0, "prefill": 0},
+            "drains": dict.fromkeys(DRAIN_REASONS, 0),
+            "discarded_tokens": 0}
         self.scheduler = Scheduler(
             self.pool, max_batch_size=config.max_batch_size,
             max_model_len=max_len,
@@ -209,6 +256,10 @@ class LLMEngine:
         self._streams: dict[int, RequestStream] = {}  # guarded_by(_lock)
         self._lock = threading.Lock()
         self._step_lock = threading.Lock()
+        # callers waiting for _step_lock that are not the loop (a swap,
+        # an abort): the loop lets them in before its next turn, which a
+        # plain lock released and taken again at once never does
+        self._urgent = 0  # guarded_by(_lock)
         self._tokens_window: list[tuple[float, int]] = []  # (t, n)
         # weight hot-swap state: bumped only by update_weights(), which
         # holds _step_lock — so within one step() every sampled token
@@ -369,6 +420,22 @@ class LLMEngine:
             "serve_llm_moe_load_imbalance",
             "Pairs of the most loaded expert over the mean expert's, "
             "cumulative, by step kind", tag_keys=moe_tags)
+        self._m_launched = Counter(
+            "serve_llm_steps_launched_total",
+            "Step programs enqueued, by step kind and by whether the "
+            "step before them was still unread (ahead=1) or the engine "
+            "had read everything (ahead=0)",
+            tag_keys=("model", "kind", "ahead"))
+        self._m_drains = Counter(
+            "serve_llm_step_drains_total",
+            "Steps read with none launched behind them, by what kept "
+            "the next one from being planned",
+            tag_keys=("model", "reason"))
+        self._m_discarded = Counter(
+            "serve_llm_discarded_tokens_total",
+            "Sampled ids dropped at commit: their lane had ended (an "
+            "eos in the step before, an abort) while the program ran",
+            tag_keys=tags)
         self._moe: dict[str, dict] = {}
         self._spec_proposed_total = 0
         self._spec_accepted_total = 0
@@ -447,39 +514,149 @@ class LLMEngine:
     # -------------------------------------------------------------- step
 
     def step(self) -> bool:
-        """One scheduler decision + one device program. Returns False
-        when there was nothing to do. Serialized: concurrent callers
-        queue behind `_step_lock` (the deployment runs a single loop
-        thread; tests may drive from several)."""
-        phase = self.phases.phase
+        """One turn of the loop: plan and launch the step behind the one
+        in flight, then read that one's results, commit and emit them.
+        Each call reads the results of exactly one step. Returns False
+        only when nothing was in flight and there was nothing to do.
+        Serialized: concurrent callers queue behind `_step_lock` (the
+        deployment runs a single loop thread; tests may drive from
+        several)."""
+        while self._urgent:  # a swap or an abort waits for the lock
+            time.sleep(0.0002)
         with self._step_lock:
-            with phase("schedule"):
-                with self._lock:
-                    pre = self.scheduler.preemption_count
-                    work = self.scheduler.schedule()  # may preempt lanes
-                    d_pre = self.scheduler.preemption_count - pre
-                    retired = self.scheduler.take_retired()
-                    # lanes a prefill step is holding back
-                    stalled = isinstance(work, PrefillWork) and any(
-                        s is not work.seq and not s.prefill_pending
-                        for s in self.scheduler.running)
-                if d_pre:
-                    self._m_preempt.inc(d_pre, tags=self._m_tags)
-                for s in retired:  # schedule() closed these out itself
-                    self._finalize(s)
-            if work is None:
-                return retired != []
-            kind = "prefill" if isinstance(work, PrefillWork) else "decode"
-            with tracing.annotate("llm.step." + kind):
-                t0 = time.perf_counter()
-                if kind == "prefill":
-                    tokens = self._do_prefill(work)
-                else:
-                    tokens = self._do_decode(work)
-                with phase("bookkeep"):
-                    self._bookkeep(kind, tokens, stalled,
-                                   (time.perf_counter() - t0) * 1e3)
+            planned = []
+            if not self._flights:
+                first, closed = self._plan(ahead=False)
+                if first is None:
+                    return closed  # nothing to run
+                planned.append(first)
+            if self._proposer is not None:
+                # drafts are read from the tokens this step commits
+                self._note_drain("speculation")
+            else:
+                behind, _ = self._plan(ahead=True)
+                if behind is not None:
+                    planned.append(behind)
+            self._turn(planned)
             return True
+
+    def _plan(self, ahead: bool) -> "tuple[_Flight | None, bool]":
+        """One scheduler decision. `ahead`: a step is in flight and its
+        results are unread, so every one of its lanes is taken to
+        continue. Returns the step to launch, if there is one, and
+        whether `schedule()` itself closed a sequence out."""
+        with self.phases.phase("schedule"):
+            t0 = time.perf_counter()
+            with self._lock:
+                pre = self.scheduler.preemption_count
+                try:
+                    # may preempt lanes, unless their tokens are in flight
+                    work = self.scheduler.schedule(may_preempt=not ahead)
+                except NeedsResults:
+                    self._note_drain("preempt")
+                    return None, False
+                d_pre = self.scheduler.preemption_count - pre
+                retired = self.scheduler.take_retired()
+                # lanes a prefill step is holding back
+                stalled = isinstance(work, PrefillWork) and any(
+                    s is not work.seq and not s.prefill_pending
+                    for s in self.scheduler.running)
+            if d_pre:
+                self._m_preempt.inc(d_pre, tags=self._m_tags)
+            for s in retired:  # schedule() closed these out itself
+                self._finalize(s)
+            if work is None:
+                if ahead:
+                    self._note_drain("idle")
+                return None, retired != []
+            if isinstance(work, PrefillWork):
+                kind = "prefill"
+                sampled = [work.seq] if work.is_last else []
+            else:
+                kind, sampled = "decode", work.seqs
+            # how many of a lane's tokens the host will not have read
+            # when this step is launched
+            unread = [s.inflight for s in sampled]
+            for s in sampled:
+                s.inflight += 1
+            return _Flight(kind, work, sampled, unread, self._weight_version,
+                           stalled, ahead, t0), retired != []
+
+    def _turn(self, planned: "list[_Flight]") -> None:
+        """Launch what was planned, then collect the oldest step in
+        flight: one `llm.step.<kind>` interval, named after the step
+        whose results it reads."""
+        oldest = self._flights[0] if self._flights else planned[0]
+        with tracing.annotate("llm.step." + oldest.kind):
+            for flight in planned:
+                self._launch(flight)
+            self._collect(self._flights.popleft())
+
+    def _drain(self, reason: str | None) -> None:
+        """Read every step in flight: for a caller that holds
+        `_step_lock` and needs the engine between steps."""
+        if self._flights and reason:
+            self._note_drain(reason)
+        while self._flights:
+            self._turn([])
+
+    def _note_drain(self, reason: str) -> None:
+        self._overlap["drains"][reason] += 1
+        self._m_drains.inc(tags={"model": self.config.model,
+                                 "reason": reason})
+
+    def _launch(self, flight: "_Flight") -> None:
+        """Enqueue a planned step's program; nothing is read."""
+        which = "launched_ahead" if flight.ahead else "launched_drained"
+        self._overlap[which][flight.kind] += 1
+        self._m_launched.inc(tags={
+            "model": self.config.model, "kind": flight.kind,
+            "ahead": "1" if flight.ahead else "0"})
+        try:
+            if flight.kind == "prefill":
+                flight.handle = self._launch_prefill(flight.work)
+            else:
+                self._launch_decode(flight)
+        except Exception as e:  # noqa: BLE001
+            flight.error = e  # its lanes are closed out at collect
+        self._flights.append(flight)
+
+    def _collect(self, flight: "_Flight") -> None:
+        """Wait for a launched step, commit and emit what it sampled,
+        and write the step down."""
+        for s in flight.sampled:
+            s.inflight -= 1
+        tokens = 0
+        try:
+            if flight.error is not None:
+                raise flight.error
+            if flight.handle is not None:
+                nxt, logits = self.runner.collect(flight.handle)
+        except Exception as e:  # noqa: BLE001
+            self._note_drain("error")
+            work = flight.work
+            lanes = [work.seq] if flight.kind == "prefill" else work.seqs
+            lanes = [s for s in lanes if s.state is SeqState.RUNNING]
+            with self._lock:
+                for s in lanes:
+                    self.scheduler.abort(s, f"error:{e!r}")
+            for s in lanes:
+                self._finalize(s)
+        else:
+            if flight.kind == "prefill":
+                tokens = self._commit_prefill(flight, nxt, logits)
+            else:
+                if flight.handle is not None:
+                    tokens = self._commit_decode(flight, nxt, logits)
+                for s, d in flight.drafted:
+                    tokens += self._verify_one(s, d, flight.ver)
+        with self.phases.phase("bookkeep"):
+            # the wall time this step cost: from the end of the step
+            # before it, or from its own planning after a pause
+            now = time.perf_counter()
+            step_ms = (now - max(flight.t0, self._last_collect)) * 1e3
+            self._last_collect = now
+            self._bookkeep(flight.kind, tokens, flight.stalled, step_ms)
 
     def _bookkeep(self, kind: str, tokens: int, stalled: bool,
                   step_ms: float) -> None:
@@ -538,35 +715,37 @@ class LLMEngine:
         per = acc["expert_pairs"]
         self._m_moe_imbalance.set(float(per.max() / per.mean()), tags=tags)
 
-    def _do_prefill(self, work: PrefillWork) -> int:
-        """One prefill program; returns the tokens it produced (one on a
-        prompt's last chunk, none before)."""
+    def _launch_prefill(self, work: PrefillWork) -> Launched:
+        """One prefill program: the whole prompt, or a chunk of it."""
         seq = work.seq
         sp = seq.sampling
-        ver = self._weight_version  # stable: step holds _step_lock
         tokens = seq.refill_tokens[work.start:work.end]
-        try:
-            if work.start == 0 and work.is_last:
-                # whole prompt in one go and nothing cached: the
-                # monolithic program skips the context gather
-                nxt, last = self.runner.prefill(
-                    tokens, seq.table, sp.temperature, sp.top_k, sp.top_p)
-            else:
-                nxt, last = self.runner.prefill_chunk(
-                    tokens, work.start, seq.table, sp.temperature,
-                    sp.top_k, sp.top_p)
-        except Exception as e:  # noqa: BLE001
-            with self._lock:
-                self.scheduler.abort(seq, f"error:{e!r}")
-            self._finalize(seq)
-            return 0
+        if work.start == 0 and work.is_last:
+            # whole prompt in one go and nothing cached: the
+            # monolithic program skips the context gather
+            return self.runner.launch_prefill(
+                tokens, seq.table, sp.temperature, sp.top_k, sp.top_p,
+                seq.slot)
+        return self.runner.launch_chunk(
+            tokens, work.start, seq.table, sp.temperature, sp.top_k,
+            sp.top_p, seq.slot)
+
+    def _commit_prefill(self, flight: "_Flight", nxt: int, last) -> int:
+        """What a prefill program leaves behind; returns the tokens it
+        produced (one on a prompt's last chunk, none before)."""
+        work, ver = flight.work, flight.ver
+        seq = work.seq
+        sp = seq.sampling
         with self.phases.phase("commit"):
+            if seq.state is not SeqState.RUNNING:
+                # aborted while the program ran: its pages may already
+                # belong to someone else
+                self._discard(len(flight.sampled))
+                return 0
             self._m_chunks.inc(tags=self._m_tags)
             seq.note_phase("prefill")  # chunk + its scheduling gap
             with self._lock:
-                # full pages covered by this chunk are now shareable (the
-                # state check skips sequences aborted mid-flight: their
-                # pages may already belong to someone else)
+                # full pages covered by this chunk are now shareable
                 self.scheduler.register_prefilled_pages(seq, work.end)
             if not work.is_last:
                 return 0  # intermediate chunk: no token was produced
@@ -584,6 +763,13 @@ class LLMEngine:
                 self._finalize(seq)
         return 1
 
+    def _discard(self, n: int) -> None:
+        """Sampled ids that no stream gets: their lane had ended (an
+        eos the plan could not know, an abort) when they were read."""
+        if n:
+            self._overlap["discarded_tokens"] += n
+            self._m_discarded.inc(n, tags=self._m_tags)
+
     def _observe_ttft(self, seq: Sequence) -> None:
         now = time.monotonic()
         self._m_ttft.observe(
@@ -600,26 +786,35 @@ class LLMEngine:
             (now - seq.enqueued_at) * 1e3,
             tags={"model": self.config.model, "phase": "total"})
 
-    def _do_decode(self, work: DecodeWork) -> int:
-        """The decode programs of one step (one plain dispatch, plus one
-        verify dispatch per drafted lane); returns the tokens produced."""
-        ver = self._weight_version  # stable: step holds _step_lock
-        plain: list[Sequence] = []
-        drafted: list[tuple[Sequence, list[int]]] = []
+    def _launch_decode(self, flight: "_Flight") -> None:
+        """The plain decode program of one step. Under speculation the
+        lanes with a draft are set aside (`flight.drafted`) for one
+        verify dispatch each once the plain lanes are committed."""
+        flight.plain, unread = flight.sampled, flight.unread
         if self._proposer is not None:
             with self.phases.phase("prepare"):
-                for s in work.seqs:
+                flight.plain = []
+                for s in flight.sampled:
                     d = self._propose_for(s)
                     if d:
-                        drafted.append((s, d))
+                        flight.drafted.append((s, d))
                     else:
-                        plain.append(s)
-        else:
-            plain = list(work.seqs)
-        tokens = self._decode_plain(plain, ver) if plain else 0
-        for s, d in drafted:
-            tokens += self._verify_one(s, d, ver)
-        return tokens
+                        flight.plain.append(s)
+                unread = [0] * len(flight.plain)  # never launched ahead
+        if not flight.plain:
+            return
+        # the lane feeds generated[-1], which LIVES at absolute position
+        # pos-1 (it was sampled but never cached): rope/wpe index, the
+        # context mask, and the KV scatter all key off that position. A
+        # lane with a token still unread is one position on, and feeds
+        # the id its last program left on the device
+        with self.phases.phase("prepare"):
+            items = [DecodeItem(s.last_token if n == 0 else -1,
+                                s.pos + n - 1, s.table,
+                                s.sampling.temperature, s.sampling.top_k,
+                                s.sampling.top_p, s.slot)
+                     for s, n in zip(flight.plain, unread)]
+        flight.handle = self.runner.launch_decode(items)
 
     def _propose_for(self, seq: Sequence) -> list[int]:
         """Draft tokens for one lane, clamped so every drafted write
@@ -637,43 +832,36 @@ class LLMEngine:
         return self._proposer.propose(
             list(seq.prompt) + list(seq.generated), k)[:k]
 
-    def _decode_plain(self, seqs: list[Sequence], ver: int) -> int:
-        # the lane feeds generated[-1], which LIVES at absolute position
-        # pos-1 (it was sampled but never cached): rope/wpe index, the
-        # context mask, and the KV scatter all key off that position
-        with self.phases.phase("prepare"):
-            items = [DecodeItem(s.last_token, s.pos - 1, s.table,
-                                s.sampling.temperature, s.sampling.top_k,
-                                s.sampling.top_p) for s in seqs]
-        try:
-            next_tokens, logits = self.runner.decode(items)
-        except Exception as e:  # noqa: BLE001
-            with self._lock:
-                for s in seqs:
-                    self.scheduler.abort(s, f"error:{e!r}")
-            for s in seqs:
-                self._finalize(s)
-            return 0
+    def _commit_decode(self, flight: "_Flight", next_tokens: list[int],
+                       logits) -> int:
+        """Commit and emit what a decode program sampled. A lane that
+        ended while the program ran (an eos in the step before it, an
+        abort) gets nothing: its id is dropped."""
+        ver = flight.ver
+        lanes = [(i, s, tok) for i, (s, tok) in enumerate(
+            zip(flight.plain, next_tokens))
+            if s.state is SeqState.RUNNING]
         with self.phases.phase("commit"):
-            for i, (s, tok) in enumerate(zip(seqs, next_tokens)):
+            self._discard(len(next_tokens) - len(lanes))
+            for i, s, tok in lanes:
                 if s.sampling.logprobs:
                     s.logprobs.append(self._logprob_of(
                         logits[i], tok, s.sampling.temperature))
             now = time.monotonic()
-            for s in seqs:
+            for _, s, _ in lanes:
                 s.note_phase("decode", now)  # step + its scheduling gap
             finished = []
             with self._lock:
-                for s, tok in zip(seqs, next_tokens):
+                for _, s, tok in lanes:
                     s.token_versions.append(ver)
                     if self.scheduler.commit_token(s, tok):
                         finished.append(s)
         with self.phases.phase("emit"):
-            for s, tok in zip(seqs, next_tokens):
+            for _, s, tok in lanes:
                 self._emit_token(s, tok, ver)
             for s in finished:
                 self._finalize(s)
-        return len(next_tokens)
+        return len(lanes)
 
     def _verify_one(self, seq: Sequence, draft: list[int],
                     ver: int) -> int:
@@ -852,11 +1040,11 @@ class LLMEngine:
     def update_weights(self, version: int, params: Any) -> dict:
         """Drain-free weight hot-swap, installed at a step boundary.
 
-        Taking `_step_lock` means no device program is in flight: the
-        swap slots cleanly BETWEEN engine steps, so every token sampled
-        by one decode step carries one weight version — in-flight
-        streams are never dropped, they simply continue on the new
-        weights. Semantics (documented in RL.md, test-gated):
+        Under `_step_lock`, with the step in flight read first, no
+        device program is pending: the swap slots cleanly BETWEEN engine
+        steps, so every token carries the version of the program that
+        sampled it — in-flight streams are never dropped, they simply
+        continue on the new weights. Semantics (documented in RL.md, test-gated):
 
         - tokens already sampled keep their old version tags; tokens
           sampled after the swap are tagged `version`;
@@ -872,11 +1060,14 @@ class LLMEngine:
         Returns swap stats (previous version, wall time, in-flight
         stream count, registrations dropped)."""
         t0 = time.perf_counter()
-        with self._step_lock:
+        with self._before_the_loop(), self._step_lock:
             if version <= self._weight_version:
                 raise ValueError(
                     f"weight version must increase: engine at "
                     f"{self._weight_version}, got {version}")
+            # the step in flight ran on the old weights: read it and
+            # tag its tokens so before the new tree is installed
+            self._drain("swap")
             with tracing.span("rl.weight_swap"):
                 self.runner.set_params(params)
                 dropped = self.pool.invalidate_prefix_cache()
@@ -900,6 +1091,7 @@ class LLMEngine:
         batch sizes) so no request pays a mid-stream XLA compile;
         returns the compiled-program count."""
         with self._step_lock:
+            self._drain(None)
             before = tracing.compile_totals()
             t0 = time.perf_counter()
             programs = self.runner.warmup()
@@ -919,9 +1111,22 @@ class LLMEngine:
         self._warmup_cache["misses"] += int(spent["misses"])
         return programs
 
+    @contextlib.contextmanager
+    def _before_the_loop(self):
+        """Around taking `_step_lock` in a caller that is not the loop:
+        the loop's next turn waits until the caller is done."""
+        with self._lock:
+            self._urgent += 1
+        try:
+            yield
+        finally:
+            with self._lock:
+                self._urgent -= 1
+
     def has_work(self) -> bool:
         with self._lock:
-            return bool(self.scheduler.waiting or self.scheduler.running)
+            return bool(self.scheduler.waiting or self.scheduler.running
+                        or self._flights)
 
     def stats(self) -> dict:
         import jax
@@ -948,6 +1153,11 @@ class LLMEngine:
             "step_phase_seconds": dict(self.phases.seconds),
             "steps": dict(self._steps),
             "d2h_bytes": dict(self._d2h),
+            # steps launched while the one before was unread, or not, by
+            # kind; why not, by reason; sampled ids no stream got
+            "overlap": {k: dict(v) if isinstance(v, dict) else v
+                        for k, v in self._overlap.items()},
+            "in_flight": len(self._flights),
             "startup_seconds": dict(self._startup),
             "warmup_cache": dict(self._warmup_cache),
             # the resident parameter tree: its bytes, the leaves the last
@@ -971,11 +1181,16 @@ class LLMEngine:
 
     def abort_request(self, stream: RequestStream,
                       reason: str = "aborted") -> None:
-        with self._lock:
-            seqs = [s for s in
-                    list(self.scheduler.waiting) + self.scheduler.running
-                    if s.seq_id == stream.seq_id]
-        for s in seqs:
+        """Close a request out between two steps. A token of its lane
+        that a step in flight samples is read and dropped."""
+        with self._before_the_loop(), self._step_lock:
             with self._lock:
-                self.scheduler.abort(s, reason)
-            self._finalize(s)
+                seqs = [s for s in
+                        list(self.scheduler.waiting) + self.scheduler.running
+                        if s.seq_id == stream.seq_id]
+                for s in seqs:
+                    self.scheduler.abort(s, reason)
+            if any(s.inflight for s in seqs):
+                self._drain("abort")
+            for s in seqs:
+                self._finalize(s)

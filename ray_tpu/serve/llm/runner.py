@@ -23,6 +23,17 @@ Padded lanes/positions point at **page 0** (the pool's null sink), so
 every gather/scatter is in-bounds; the attention mask keeps null-page
 garbage out of the softmax.
 
+A program is **launched** (`launch_prefill`, `launch_chunk`,
+`launch_decode`: inputs prepared, the jitted call made, nothing read)
+and **collected** (`collect`: the wait for it and the copy to the host)
+apart, so that the engine can enqueue the next program before it reads
+this one's results; `prefill`, `prefill_chunk` and `decode` are the two
+in one call. What the next program needs of this one's results, the
+sampled ids, stays on the device: `slot_tokens` holds the last sampled
+id of every lane slot, each of these programs writes its `nxt` there,
+and a decode lane whose token the host has not read yet (`token` -1)
+takes it from its slot.
+
 With a mesh, parameters are sharded via the model's own
 `parallel/sharding.py` partition rules and the cache pages are sharded
 over the ``tensor`` axis by whole KV heads; calls run under
@@ -41,6 +52,8 @@ from typing import Any, Callable, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ray_tpu.serve.llm.cache import KVLayout
 from ray_tpu.util import tracing
@@ -134,12 +147,25 @@ def adapters() -> dict[str, ModelAdapter]:
 
 
 class DecodeItem(NamedTuple):
-    token: int  # last sampled token (input to this step)
+    # last sampled token (input to this step); -1: not read from the
+    # device yet, the program takes it from `slot`
+    token: int
     pos: int  # its absolute position (== tokens written so far)
     table: Sequence[int]  # physical page ids, logical order
     temperature: float
     top_k: int = 0  # 0: disabled
     top_p: float = 1.0  # 1.0: disabled
+    # where the program leaves the id it samples for the next one to
+    # read (index into `ModelRunner.slot_tokens`); -1: nowhere
+    slot: int = -1
+
+
+class Launched(NamedTuple):
+    """A program on its way: its device outputs, nothing read."""
+
+    results: tuple  # (nxt, logits), on the device
+    aux: tuple  # the family's extras (routed experts' pairs)
+    rows: int | None  # decode: the real lanes; None: one row, a scalar id
 
 
 def _ordered_bits(x):
@@ -280,6 +306,11 @@ class ModelRunner:
         self._install(params, adapter.resident_fn(params, cfg))
         self.k_pages = self.layout.zeros(cfg.dtype, mesh)
         self.v_pages = self.layout.zeros(cfg.dtype, mesh)
+        # the last sampled id of every lane slot (see the module's head)
+        self.slot_tokens = jnp.zeros(
+            (max_batch_size,), jnp.int32,
+            device=(NamedSharding(mesh, PartitionSpec())
+                    if mesh is not None else None))
 
         self._base_key = jax.random.PRNGKey(sample_seed)
         self._step_counter = 0
@@ -351,8 +382,18 @@ class ModelRunner:
         return lambda layer: (read(k_pages, layer, tables),
                               read(v_pages, layer, tables))
 
-    def _prefill_impl(self, params, k_pages, v_pages, tokens, last_idx,
-                      block_ids, offsets, temp, topk, topp, step):
+    @staticmethod
+    def _keep_sampled(slot_tokens, slots, nxt):
+        """`slot_tokens` with the ids `nxt` left at `slots` (both scalars,
+        or both (S,)). A negative slot (a padded lane, a caller that
+        holds none) leaves nothing."""
+        out_of_range = slot_tokens.shape[0]
+        return slot_tokens.at[
+            jnp.where(slots >= 0, slots, out_of_range)].set(nxt, mode="drop")
+
+    def _prefill_impl(self, params, k_pages, v_pages, slot_tokens, tokens,
+                      last_idx, block_ids, offsets, slot, temp, topk, topp,
+                      step):
         """tokens (1, Tb); block_ids/offsets (Tb,) map position t to its
         page slot (padded positions -> null page 0)."""
         logits, k, v, *aux = self.adapter.prefill_fn(
@@ -362,11 +403,12 @@ class ModelRunner:
         v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
         nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
-        return nxt, last, k_pages, v_pages, tuple(aux)
+        slot_tokens = self._keep_sampled(slot_tokens, slot, nxt)
+        return nxt, last, k_pages, v_pages, slot_tokens, tuple(aux)
 
-    def _chunk_impl(self, params, k_pages, v_pages, tokens, start,
-                    last_idx, block_ids, offsets, table, temp, topk,
-                    topp, step):
+    def _chunk_impl(self, params, k_pages, v_pages, slot_tokens, tokens,
+                    start, last_idx, block_ids, offsets, table, slot, temp,
+                    topk, topp, step):
         """Prefill a chunk of ONE sequence from a position offset.
 
         tokens (1, Tb) at absolute positions start..start+Tb-1 (padded
@@ -387,7 +429,8 @@ class ModelRunner:
         v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
         nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
-        return nxt, last, k_pages, v_pages, tuple(aux)
+        slot_tokens = self._keep_sampled(slot_tokens, slot, nxt)
+        return nxt, last, k_pages, v_pages, slot_tokens, tuple(aux)
 
     def _verify_impl(self, params, k_pages, v_pages, tokens, start,
                      n_draft, block_ids, offsets, table, temps, topks,
@@ -437,14 +480,18 @@ class ModelRunner:
         emitted = jnp.where(jnp.arange(W) <= n_acc, target, -1)
         return emitted, n_acc, lg, k_pages, v_pages, tuple(aux)
 
-    def _decode_impl(self, params, k_pages, v_pages, tokens, positions,
-                     tables, temps, topks, topps, step):
-        """tokens/positions/temps (Sb,); tables (Sb, max_blocks_per_seq).
-        Run the model's decode step, each layer reading its dense
-        context through the tables, scatter the new K/V at each lane's
-        position, sample. With paged attention the gather disappears:
-        the kernel indexes pages in place through the block table."""
+    def _decode_impl(self, params, k_pages, v_pages, slot_tokens, tokens,
+                     slots, positions, tables, temps, topks, topps, step):
+        """tokens/slots/positions/temps (Sb,); tables (Sb,
+        max_blocks_per_seq). Run the model's decode step, each layer
+        reading its dense context through the tables, scatter the new
+        K/V at each lane's position, sample. With paged attention the
+        gather disappears: the kernel indexes pages in place through the
+        block table. A lane whose token is -1 feeds the id an earlier
+        program left at its slot."""
         Bs = self.block_size
+        tokens = jnp.where(tokens >= 0, tokens,
+                           slot_tokens[jnp.maximum(slots, 0)])
         if self.use_paged_attention:
             logits, k_new, v_new, *aux = self.adapter.decode_paged_fn(
                 params, tokens, positions, self.layout, k_pages, v_pages,
@@ -462,7 +509,8 @@ class ModelRunner:
         k_pages = self.layout.write(k_pages, block_ids, offsets, k_new)
         v_pages = self.layout.write(v_pages, block_ids, offsets, v_new)
         nxt = self._sample(logits, temps, topks, topps, step)
-        return nxt, logits, k_pages, v_pages, tuple(aux)
+        slot_tokens = self._keep_sampled(slot_tokens, slots, nxt)
+        return nxt, logits, k_pages, v_pages, slot_tokens, tuple(aux)
 
     # -------------------------------------------------------------- host
 
@@ -480,6 +528,16 @@ class ModelRunner:
                 self.fetched_bytes += sum(a.nbytes for a in extra)
                 self.expert_pairs.extend(extra)
         return out
+
+    def collect(self, launched: Launched) -> tuple:
+        """Wait for a launched program and read its results: (sampled
+        id, its logits row) of a prefill or a chunk, (ids, logits rows)
+        of a decode's real lanes."""
+        nxt, logits = self._fetch(*launched.results, aux=launched.aux)
+        if launched.rows is None:
+            return int(nxt), logits
+        return ([int(t) for t in nxt[:launched.rows]],
+                logits[:launched.rows])
 
     def take_expert_pairs(self) -> list[np.ndarray]:
         """The (L, n_experts) pairs-per-expert arrays of the programs run
@@ -509,12 +567,13 @@ class ModelRunner:
             raise ValueError(f"chunk of {n} tokens exceeds chunk size {cap}")
         return min(_next_pow2(n, self.prefill_bucket_min), cap)
 
-    def prefill(self, token_ids: Sequence[int], table: Sequence[int],
-                temperature: float, top_k: int = 0, top_p: float = 1.0
-                ) -> tuple[int, np.ndarray]:
-        """Run one prompt through monolithic prefill; returns (first
-        generated token, last-position logits). `table` must cover
-        blocks_for_tokens(len(token_ids)) pages."""
+    def launch_prefill(self, token_ids: Sequence[int],
+                       table: Sequence[int], temperature: float,
+                       top_k: int = 0, top_p: float = 1.0,
+                       slot: int = -1) -> Launched:
+        """Enqueue one prompt's monolithic prefill. `table` must cover
+        blocks_for_tokens(len(token_ids)) pages; the sampled id is also
+        left at `slot` of `slot_tokens`."""
         with self.phases.phase("prepare"):
             n = len(token_ids)
             Tb = self.prefill_bucket(n)
@@ -533,26 +592,35 @@ class ModelRunner:
             before = tracing.jit_cache_size(self._prefill_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
-                nxt, last, self.k_pages, self.v_pages, aux = self._prefill_jit(
-                    self.params, self.k_pages, self.v_pages, toks,
-                    np.int32(n - 1), block_ids, offsets, temp, topk, topp,
+                (nxt, last, self.k_pages, self.v_pages, self.slot_tokens,
+                 aux) = self._prefill_jit(
+                    self.params, self.k_pages, self.v_pages,
+                    self.slot_tokens, toks, np.int32(n - 1), block_ids,
+                    offsets, np.int32(slot), temp, topk, topp,
                     np.int32(self._step_counter))
             self._note_compile("prefill", self._prefill_jit, before,
                                time.perf_counter() - t0)
-        nxt, last = self._fetch(nxt, last, aux=aux)
-        return int(nxt), last
+        return Launched((nxt, last), aux, None)
 
-    def prefill_chunk(self, token_ids: Sequence[int], start: int,
-                      table: Sequence[int], temperature: float,
-                      top_k: int = 0, top_p: float = 1.0
-                      ) -> tuple[int, np.ndarray]:
-        """Prefill-from-offset: run `token_ids` (<= prefill_chunk_size)
-        at absolute positions start..start+n-1 against the cached
-        context in `table` (which must already hold valid KV for every
-        position < start, and own the pages the chunk writes). `start`
-        must be page-aligned. Returns (sampled next token, last-chunk-
-        position logits) — the caller only uses them on the final
-        chunk."""
+    def prefill(self, token_ids: Sequence[int], table: Sequence[int],
+                temperature: float, top_k: int = 0, top_p: float = 1.0
+                ) -> tuple[int, np.ndarray]:
+        """Run one prompt through monolithic prefill; returns (first
+        generated token, last-position logits)."""
+        return self.collect(self.launch_prefill(
+            token_ids, table, temperature, top_k, top_p))
+
+    def launch_chunk(self, token_ids: Sequence[int], start: int,
+                     table: Sequence[int], temperature: float,
+                     top_k: int = 0, top_p: float = 1.0,
+                     slot: int = -1) -> Launched:
+        """Enqueue a prefill-from-offset: `token_ids` (<=
+        prefill_chunk_size) at absolute positions start..start+n-1
+        against the cached context in `table` (which must already hold
+        valid KV for every position < start, and own the pages the chunk
+        writes). `start` must be page-aligned. The caller only uses the
+        results (sampled next token, last-chunk-position logits) of the
+        final chunk."""
         with self.phases.phase("prepare"):
             n = len(token_ids)
             if start % self.block_size:
@@ -579,25 +647,35 @@ class ModelRunner:
             before = tracing.jit_cache_size(self._chunk_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
-                nxt, last, self.k_pages, self.v_pages, aux = self._chunk_jit(
-                    self.params, self.k_pages, self.v_pages, toks,
-                    np.int32(start), np.int32(n - 1), block_ids, offsets,
-                    tab, temp, topk, topp, np.int32(self._step_counter))
+                (nxt, last, self.k_pages, self.v_pages, self.slot_tokens,
+                 aux) = self._chunk_jit(
+                    self.params, self.k_pages, self.v_pages,
+                    self.slot_tokens, toks, np.int32(start),
+                    np.int32(n - 1), block_ids, offsets, tab,
+                    np.int32(slot), temp, topk, topp,
+                    np.int32(self._step_counter))
             self._note_compile("prefill_chunk", self._chunk_jit, before,
                                time.perf_counter() - t0)
-        nxt, last = self._fetch(nxt, last, aux=aux)
-        return int(nxt), last
+        return Launched((nxt, last), aux, None)
 
-    def decode(self, items: Sequence[DecodeItem]
-               ) -> tuple[list[int], np.ndarray]:
-        """One decode step for up to max_batch_size sequences; returns
-        (next token per item, logits (len(items), Vp))."""
+    def prefill_chunk(self, token_ids: Sequence[int], start: int,
+                      table: Sequence[int], temperature: float,
+                      top_k: int = 0, top_p: float = 1.0
+                      ) -> tuple[int, np.ndarray]:
+        """`launch_chunk`, then its results: (sampled next token,
+        last-chunk-position logits)."""
+        return self.collect(self.launch_chunk(
+            token_ids, start, table, temperature, top_k, top_p))
+
+    def launch_decode(self, items: Sequence[DecodeItem]) -> Launched:
+        """Enqueue one decode step for up to max_batch_size sequences."""
         with self.phases.phase("prepare"):
             S = len(items)
             if not 0 < S <= self.max_batch_size:
                 raise ValueError(f"decode batch of {S}")
             Sb = self.decode_bucket(S)
             toks = np.zeros((Sb,), np.int32)
+            slots = np.full((Sb,), -1, np.int32)
             poss = np.zeros((Sb,), np.int32)
             tables = np.zeros((Sb, self.max_blocks_per_seq), np.int32)
             temps = np.zeros((Sb,), np.float32)
@@ -605,6 +683,7 @@ class ModelRunner:
             topps = np.ones((Sb,), np.float32)
             for i, it in enumerate(items):
                 toks[i] = it.token
+                slots[i] = it.slot
                 poss[i] = it.pos
                 tables[i, :len(it.table)] = it.table
                 temps[i] = it.temperature
@@ -615,15 +694,20 @@ class ModelRunner:
             before = tracing.jit_cache_size(self._decode_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
-                nxt, logits, self.k_pages, self.v_pages, aux = \
-                    self._decode_jit(
-                    self.params, self.k_pages, self.v_pages, toks, poss,
-                    tables, temps, topks, topps,
-                    np.int32(self._step_counter))
+                (nxt, logits, self.k_pages, self.v_pages, self.slot_tokens,
+                 aux) = self._decode_jit(
+                    self.params, self.k_pages, self.v_pages,
+                    self.slot_tokens, toks, slots, poss, tables, temps,
+                    topks, topps, np.int32(self._step_counter))
             self._note_compile("decode", self._decode_jit, before,
                                time.perf_counter() - t0)
-        nxt, logits = self._fetch(nxt, logits, aux=aux)
-        return [int(t) for t in nxt[:S]], logits[:S]
+        return Launched((nxt, logits), aux, S)
+
+    def decode(self, items: Sequence[DecodeItem]
+               ) -> tuple[list[int], np.ndarray]:
+        """One decode step; returns (next token per item, logits
+        (len(items), Vp))."""
+        return self.collect(self.launch_decode(items))
 
     def verify(self, token: int, pos: int, draft: Sequence[int],
                table: Sequence[int], temperature: float,

@@ -36,6 +36,15 @@ the decode step that fills its last slot. The hash chain covers
 prompt AND generated tokens, so shared prefixes survive preemption and
 even extend into generated text (RL-style rollouts forking one prompt).
 
+Planning ahead: the engine plans a step while the one before it is still
+on the device, its sampled tokens unread (`Sequence.inflight` counts
+them). `schedule()` then takes every lane in flight to continue: one
+token more, a page more where that crosses a page. A lane that the step
+in flight is known to end (`max_tokens`, `max_model_len`) leaves
+`running` at once, so it is in no later step and its lane can be given
+away; only its pages wait for the commit. What the plan cannot make
+without the results, a preemption, it refuses (`NeedsResults`).
+
 The scheduler owns no locks: the engine serializes calls. The pool's
 internal `_lock` is a leaf — taken inside pool calls only, never
 around scheduler state — so there is no lock-order cycle with the
@@ -57,6 +66,11 @@ from ray_tpu.serve.llm.cache import (
 from ray_tpu.serve.llm.config import SamplingParams
 
 
+class NeedsResults(Exception):
+    """`schedule(may_preempt=False)` would have to preempt: the caller
+    reads the step in flight first and schedules again."""
+
+
 class SeqState(enum.Enum):
     WAITING = "waiting"
     RUNNING = "running"
@@ -74,6 +88,12 @@ class Sequence:
     generated: list[int] = dataclasses.field(default_factory=list)
     table: list[int] = dataclasses.field(default_factory=list)
     last_token: int = -1  # input to the next decode step
+    # tokens sampled by steps that are launched and not yet committed
+    # (0..2: the step whose results are being read, and the one behind)
+    inflight: int = 0
+    # index into the runner's device-resident "last sampled id" array,
+    # held while the sequence is in `running`; -1: none
+    slot: int = -1
     preemptions: int = 0
     # chunked-prefill progress: [0, prefilled) of refill_tokens is
     # scattered into `table`; the goal is `prefill_target` (the refill
@@ -145,6 +165,15 @@ class Sequence:
         writes its KV there."""
         return len(self.prompt) + len(self.generated)
 
+    def ends_in_flight(self, max_model_len: int) -> bool:
+        """Will the tokens in flight end this sequence, whatever they
+        are? (An eos among them cannot be known: it costs one discarded
+        lane-step.)"""
+        n = self.inflight
+        return n > 0 and (
+            len(self.generated) + n >= self.sampling.max_tokens
+            or self.pos + n >= max_model_len)
+
     @property
     def prefill_pending(self) -> bool:
         return self.state is SeqState.RUNNING \
@@ -199,6 +228,7 @@ class Scheduler:
         self.spec_tokens = spec_tokens
         self.waiting: deque[Sequence] = deque()
         self.running: list[Sequence] = []  # admission order (LIFO victim)
+        self._free_slots = list(range(max_batch_size - 1, -1, -1))
         self.preemption_count = 0
         self.prefix_hit_pages = 0
         self.prefix_miss_pages = 0
@@ -219,7 +249,8 @@ class Scheduler:
 
     def abort(self, seq: Sequence, reason: str = "aborted") -> None:
         if seq.state is SeqState.RUNNING:
-            self.running.remove(seq)
+            if seq in self.running:  # not one its last step set aside
+                self.running.remove(seq)
         elif seq.state is SeqState.WAITING:
             try:
                 self.waiting.remove(seq)
@@ -229,9 +260,18 @@ class Scheduler:
 
     # ---------------------------------------------------------- planning
 
-    def schedule(self) -> PrefillWork | DecodeWork | None:
+    def schedule(self, may_preempt: bool = True
+                 ) -> PrefillWork | DecodeWork | None:
         """Pick the next unit of work. Admission never preempts: a
-        waiting sequence only enters when pages are genuinely free."""
+        waiting sequence only enters when pages are genuinely free.
+        With a step in flight the caller passes ``may_preempt=False``:
+        a decode step that would need a preemption raises
+        `NeedsResults` instead."""
+        for seq in [s for s in self.running
+                    if s.ends_in_flight(self.max_model_len)]:
+            # its last step is on the device: in no later one
+            self.running.remove(seq)
+            self._release_slot(seq)
         work = self._try_admit()
         if work is not None:
             self._last_was_prefill = True
@@ -249,7 +289,7 @@ class Scheduler:
                 return self._next_chunk(pending[0])
             return None
         self._last_was_prefill = False
-        self._grow_tables_or_preempt()
+        self._grow_tables_or_preempt(may_preempt)
         ready = [s for s in self.running if not s.prefill_pending]
         if not ready:
             return None
@@ -287,6 +327,7 @@ class Scheduler:
         seq.cached_tokens = seq.prefilled
         seq.registered_pages = len(matched)
         seq.state = SeqState.RUNNING
+        seq.slot = self._free_slots.pop()  # one a lane: never empty
         self.running.append(seq)
         return self._next_chunk(seq)
 
@@ -298,15 +339,17 @@ class Scheduler:
         return PrefillWork(seq=seq, start=start, end=end,
                            is_last=(end == total))
 
-    def _grow_tables_or_preempt(self) -> None:
+    def _grow_tables_or_preempt(self, may_preempt: bool = True) -> None:
         """Every decoding lane must own the page its next token writes
         into; preempt (LIFO) until the survivors all fit. Lanes still
         mid-prefill already own their whole table (admission allocates
-        it), so they pass through untouched."""
+        it), so they pass through untouched. A lane with a token in
+        flight is taken one position on."""
         i = 0
         while i < len(self.running):
             seq = self.running[i]
-            if seq.pos > self.max_model_len:
+            pos = seq.pos + seq.inflight
+            if pos > self.max_model_len:
                 # next decode would write at position pos-1 >= cap:
                 # close out at the length limit
                 self._retire(seq, "length")
@@ -314,7 +357,7 @@ class Scheduler:
                 continue
             # the decode step writes KV at position pos-1, so the table
             # must cover pos tokens
-            needed = self.pool.blocks_for_tokens(seq.pos)
+            needed = self.pool.blocks_for_tokens(pos)
             if len(seq.table) >= needed:
                 i += 1
                 continue
@@ -322,6 +365,8 @@ class Scheduler:
                 seq.table.extend(self.pool.alloc(needed - len(seq.table)))
                 i += 1
             except CacheExhausted:
+                if not may_preempt:
+                    raise NeedsResults from None
                 victim = self.running[-1]
                 if victim is seq and len(self.running) == 1:
                     # sole runner and the pool can't grow it: engine
@@ -362,6 +407,7 @@ class Scheduler:
         seq.note_phase("prefill" if seq.prefill_pending else "decode")
         seq._preempt_wait = True
         self.running.remove(seq)
+        self._release_slot(seq)
         self.pool.free(seq.table)
         seq.table = []
         seq.prefilled = 0
@@ -419,7 +465,13 @@ class Scheduler:
             self.running.remove(seq)
         self._finish(seq, reason)
 
+    def _release_slot(self, seq: Sequence) -> None:
+        if seq.slot >= 0:
+            self._free_slots.append(seq.slot)
+            seq.slot = -1
+
     def _finish(self, seq: Sequence, reason: str) -> None:
+        self._release_slot(seq)
         self.pool.free(seq.table)
         seq.table = []
         seq.state = SeqState.FINISHED

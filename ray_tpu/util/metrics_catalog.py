@@ -171,6 +171,18 @@ CATALOG: list[dict] = [
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "bytes of device results (tokens, logits) the engine's "
              "steps fetched to the host, by step kind"},
+    {"name": "serve_llm_steps_launched_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "step programs enqueued, by step kind and by whether the "
+             "step before them was still unread (ahead=1) or not"},
+    {"name": "serve_llm_step_drains_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "steps read with none launched behind them, by what kept "
+             "the next one from being planned"},
+    {"name": "serve_llm_discarded_tokens_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "sampled ids dropped at commit: their lane had ended "
+             "while the program ran"},
     {"name": "serve_llm_weight_bytes", "type": "gauge",
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "bytes of the resident parameter tree, each leaf in the "

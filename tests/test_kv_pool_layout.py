@@ -243,18 +243,21 @@ def served_runner(one_chip, request):
 
 # program -> (runner method, argument shapes after (params, k, v), rows
 # sampled); "m" is max_blocks_per_seq, "p" the monolithic prefill bucket,
-# "s" the decode lanes; temperature, top-k and top-p follow, then the step
+# "s" the decode lanes; temperature, top-k and top-p follow, then the step.
+# Prefill, chunk and decode take the device-resident last sampled ids
+# first ("s" of them) and the slot(s) they leave theirs at (PR 31)
 PROGRAMS = {
-    "prefill": ("_prefill_impl", [((1, "p"), "i"), ((), "i"),
-                                  (("p",), "i"), (("p",), "i")], 1),
-    "chunk-256": ("_chunk_impl", [((1, 256), "i"), ((), "i"), ((), "i"),
-                                  ((256,), "i"), ((256,), "i"),
-                                  (("m",), "i")], 1),
+    "prefill": ("_prefill_impl", [(("s",), "i"), ((1, "p"), "i"), ((), "i"),
+                                  (("p",), "i"), (("p",), "i"),
+                                  ((), "i")], 1),
+    "chunk-256": ("_chunk_impl", [(("s",), "i"), ((1, 256), "i"), ((), "i"),
+                                  ((), "i"), ((256,), "i"), ((256,), "i"),
+                                  (("m",), "i"), ((), "i")], 1),
     "verify-5": ("_verify_impl", [((1, 5), "i"), ((), "i"), ((), "i"),
                                   ((5,), "i"), ((5,), "i"),
                                   (("m",), "i")], 5),
-    "decode": ("_decode_impl", [(("s",), "i"), (("s",), "i"),
-                                (("s", "m"), "i")], "s"),
+    "decode": ("_decode_impl", [(("s",), "i"), (("s",), "i"), (("s",), "i"),
+                                (("s",), "i"), (("s", "m"), "i")], "s"),
 }
 
 

@@ -128,19 +128,28 @@ def test_hot_swap_8_streams_mid_generation():
     cfg = _tiny_cfg()
     eng = _engine(cfg)
     # spy: a swap must never change the version while a decode program
-    # is in flight (the no-mid-decode-step-version-mix contract)
-    orig_decode = eng.runner.decode
+    # is in flight, from its launch to the reading of its results (the
+    # no-mid-decode-step-version-mix contract)
+    launch, collect = eng.runner.launch_decode, eng.runner.collect
+    flying = {}
     batches = []
 
-    def spy(items):
-        v_in = eng.weight_version
-        out = orig_decode(items)
-        assert eng.weight_version == v_in, \
-            "weight swap landed inside a decode step"
-        batches.append((v_in, len(items)))
+    def spy_launch(items):
+        handle = launch(items)
+        flying[id(handle)] = (eng.weight_version, len(items))
+        return handle
+
+    def spy_collect(handle):
+        out = collect(handle)
+        if id(handle) in flying:
+            v_in, lanes = flying.pop(id(handle))
+            assert eng.weight_version == v_in, \
+                "weight swap landed inside a decode step"
+            batches.append((v_in, lanes))
         return out
 
-    eng.runner.decode = spy
+    eng.runner.launch_decode = spy_launch
+    eng.runner.collect = spy_collect
     rng = np.random.RandomState(0)
     sp = SamplingParams(max_tokens=16, logprobs=True)
     streams = [eng.add_request(rng.randint(1, 60, size=6).tolist(), sp)
