@@ -1,17 +1,21 @@
-"""Routed experts: top-k softmax gating in which no token is dropped.
+"""Routed experts: top-k gating in which no token is dropped.
 
 One implementation serves the llama-family MoE block (OLMoE: 64 SwiGLU
-experts, 8 a token) and the stand-alone `moe_layer` (GELU experts):
+experts, 8 a token, softmax), the nemotron_h block (128 relu2 experts of
+which a chip holds a share, 6 a token, sigmoid with a selection bias, one
+shared expert) and the stand-alone `moe_layer` (GELU experts):
 
-- **route** (`moe.route`): router logits and softmax in float32 over all
-  experts, `lax.top_k`, weights renormalised only when asked, and the
-  count of pairs per expert;
+- **route** (`moe.route`): router logits and scores (softmax over all
+  experts, or a sigmoid each) in float32, `lax.top_k` (of the scores plus
+  a selection bias where the router has one), weights renormalised only
+  when asked, and the count of pairs per expert, over ALL experts;
 - **dispatch** (`moe.dispatch`): the routing weights as an (N, E) matrix,
-  zero where a token did not choose an expert;
+  zero where a token did not choose an expert, cut to the experts held
+  here (`held`) where the stacked weights are a share of the router's;
 - **experts** (`moe.experts`): every expert computes every row, as batched
   products over the stacked weights;
 - **combine** (`moe.combine`): the matrix picks the chosen pairs out and
-  sums them.
+  sums them; an always-on expert (`shared`, `moe.shared`) is added.
 
 So every chosen pair is computed whatever the imbalance — there is no
 capacity — and nothing is sorted, gathered or scattered. The arithmetic
@@ -24,6 +28,14 @@ takes 1.22-2.85 ms and the Pallas `megablox` product 1.24-1.90 ms); and
 below that, where a grouped product alone is faster (0.39 ms at one row),
 a grouped kernel is a custom call, for which XLA copies each layer's
 experts out of the scanned stack first (three copies of 268 MB a layer).
+Measured again at the nemotron_h cut's widths (PERF.md, PR 32; one layer,
+32 held experts of 2688 x 1856 x 2, 639 MB, 6 of 128 a token, so a row
+has 1.5 pairs here and every-expert is 21 times the chosen pairs'
+arithmetic): 0.87 / 0.88 / 0.90 / 0.92 / 1.01 ms at 8 / 32 / 64 / 128 /
+256 rows against 2.45 / 3.12 / 4.38 / 5.25 / 6.18 ms for `ragged_dot`
+over the pairs ordered by expert, with no stack to copy from there: the
+same way wins at every row count a serve program has, by more, so the
+program still chooses nothing.
 
 The per-expert pair counts leave the layer with its output: imbalance is
 what a router costs, and only the program can see it.
@@ -43,17 +55,33 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
-def route(x: jax.Array, router: jax.Array, k: int, norm_topk: bool):
+def route(x: jax.Array, router: jax.Array, k: int, norm_topk: bool, *,
+          score: str = "softmax", select_bias: jax.Array | None = None,
+          scale: float = 1.0):
     """x (N, Dm), router (Dm, E) -> (weights (N, k) f32, experts (N, k)
-    i32, pairs per expert (E,) i32, probs (N, E) f32). Softmax over ALL
-    experts, then the k largest; their weights sum to one only when
-    `norm_topk`."""
+    i32, pairs per expert (E,) i32, scores (N, E) f32). `score` is
+    "softmax" (over ALL experts) or "sigmoid" (each expert for itself);
+    the k largest of score + `select_bias` (E,) are chosen, and their
+    weights are the scores WITHOUT the bias, summing to one only when
+    `norm_topk`, times `scale`."""
     with jax.named_scope("moe.route"):
         logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = jax.lax.top_k(probs, k)
+        if score == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+        elif score == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"unknown router score {score!r}")
+        if select_bias is None:
+            weights, experts = jax.lax.top_k(probs, k)
+        else:
+            _, experts = jax.lax.top_k(
+                probs + select_bias.astype(jnp.float32), k)
+            weights = jnp.take_along_axis(probs, experts, axis=-1)
         if norm_topk:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        if scale != 1.0:
+            weights = weights * scale
         counts = jnp.zeros((router.shape[-1],), jnp.int32).at[
             experts.reshape(-1)].add(1)
     return weights, experts, counts, probs
@@ -66,22 +94,41 @@ def routed_experts(
     *,
     k: int,
     norm_topk: bool,
+    score: str = "softmax",
+    select_bias: jax.Array | None = None,
+    scale: float = 1.0,
+    held: tuple[int, int] | None = None,
+    shared: Callable | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """x (N, Dm) -> (out (N, Dm), pairs per expert (E,) i32, router probs
+    """x (N, Dm) -> (out (N, Dm), pairs per expert (E,) i32, router scores
     (N, E) f32). ``expert_fn(rows, mm)`` is one expert's feed-forward
     written for all experts at once: ``mm(a, w)`` multiplies rows ``a``
     ((N, in) going in, (E, N, in) between the layers) with the stacked
-    weights ``w`` (E, in, out), expert by expert."""
+    weights ``w`` (E, in, out), expert by expert.
+
+    `held` = (offset, count) says which of the router's E experts the
+    stacked weights are: the routing and the pairs per expert are over all
+    E (the router's load is the model's, whatever is held), the result is
+    the part the held experts give, and what the absent ones would have
+    added is left out. ``shared(x) -> (N, Dm)`` is an expert every row
+    goes through, added to the routed sum."""
     N = x.shape[0]
-    weights, experts, counts, probs = route(x, router, k, norm_topk)
+    weights, experts, counts, probs = route(
+        x, router, k, norm_topk, score=score, select_bias=select_bias,
+        scale=scale)
     with jax.named_scope("moe.dispatch"):
         per_expert = jnp.zeros((N, router.shape[-1]), weights.dtype).at[
             jnp.arange(N)[:, None], experts].set(weights)
+        if held is not None and held != (0, router.shape[-1]):
+            per_expert = per_expert[:, held[0]:held[0] + held[1]]
     with jax.named_scope("moe.experts"):
         y = expert_fn(x, lambda a, w: jnp.einsum(
             "nd,edf->enf" if a.ndim == 2 else "end,edf->enf", a, w))
     with jax.named_scope("moe.combine"):
         out = jnp.einsum("ne,end->nd", per_expert.astype(y.dtype), y)
+    if shared is not None:
+        with jax.named_scope("moe.shared"):
+            out = out + shared(x).astype(out.dtype)
     return out.astype(x.dtype), counts, probs
 
 

@@ -1,0 +1,610 @@
+"""nemotron_h: a stack of three kinds of block in a given order.
+
+Third model family beside gpt2 and llama, after NVIDIA's Nemotron-H /
+Nemotron-3 hybrids (`model_type` nemotron_h). Every block is
+``x = x + mixer(rmsnorm(x))`` with ONE mixer, and `layer_pattern`, a
+string over three letters, says which:
+
+- ``M``, a Mamba-2 mixer: ``in_proj`` to z | xBC | dt, a causal depthwise
+  convolution over xBC then SiLU, the selective state-space recurrence
+  per head ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``,
+  ``y_t = S_t C_t + D x_t`` (B and C shared by the heads of a group), a
+  gate ``y * silu(z)`` under a grouped RMSNorm, ``out_proj``;
+- ``*``, causal softmax attention with grouped K and V heads, no bias and
+  NO rotation of q and k (the family applies no position embedding: the
+  Mamba layers carry order);
+- ``E``, routed experts (models/moe.py): a sigmoid router with a
+  selection bias, the chosen weights normalised and scaled, experts
+  ``down(relu(up(h))^2)``, not gated, and one shared expert of the same
+  form that every row goes through. `experts_held` and `expert_offset`
+  say which of the router's `n_routed_experts` this chip holds: it routes
+  over all of them and computes its own experts' part of the result.
+
+The pattern has no clean period, so the stack is a Python loop over the
+pattern (each kind written once) and not a scan over uniform blocks; the
+parameters are one dict a layer (`params["layers"][i]`), so no layer's
+weights are ever sliced out of a stack.
+
+Two kinds of cached state (serve/llm/cache.py): the attention layers' K
+and V in pages, `n_kv_layers` of them, and a Mamba layer's recurrent
+state a lane slot: the last ``conv_kernel - 1`` conv inputs (a part each) and the
+``(heads, head_dim, state)`` SSM state, in float32 as the decay is.
+Prompts and chunks run the chunked (SSD) form of the recurrence at
+`chunk_size` rows with an initial state; decode runs one step of the
+recurrence on every slot where the state lies. Rows past `n_valid`
+(bucket padding) get ``dt = 0`` and leave the conv window alone, and a
+slot no lane of a decode step owns is written back as read.
+
+Matrix products are in `dtype` (bf16: float32 accumulation on the MXU);
+the state, the decay, the norms, the softmax and the router are float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.moe import routed_experts
+from ray_tpu.parallel.sharding import PartitionRules
+
+Params = Any
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHConfig:
+    """Field names are the published config.json's, but for
+    `layer_pattern` (`hybrid_override_pattern`) and the three that say
+    what is held here."""
+
+    vocab_size: int = 131072
+    hidden_size: int = 2688
+    layer_pattern: str = "MEMEM*EME"
+    # M
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    ssm_state_size: int = 128
+    n_groups: int = 8
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # *
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    # E
+    n_routed_experts: int = 128
+    num_experts_per_tok: int = 6
+    moe_intermediate_size: int = 1856
+    moe_shared_expert_intermediate_size: int = 3712
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    experts_held: int = 128  # of n_routed_experts, from expert_offset on
+    expert_offset: int = 0
+    layer_norm_epsilon: float = 1e-5
+    initializer_range: float = 0.02  # std of a seeded matrix (published)
+    max_position_embeddings: int = 262144
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16  # what `init_nemotron_h` creates
+
+    def __post_init__(self):
+        if set(self.layer_pattern) - set("ME*"):
+            raise ValueError(f"layer_pattern {self.layer_pattern!r}: only "
+                             f"M, E and * are layer kinds")
+        if self.expert_offset + self.experts_held > self.n_routed_experts:
+            raise ValueError("experts held lie outside the router's range")
+
+    # what the engine asks of every family's config
+    @property
+    def n_layer(self) -> int:
+        return len(self.layer_pattern)
+
+    @property
+    def block_size(self) -> int:
+        return self.max_position_embeddings
+
+    @property
+    def padded_vocab(self) -> int:
+        return ((self.vocab_size + 127) // 128) * 128
+
+    @property
+    def n_kv_layers(self) -> int:
+        return self.layer_pattern.count("*")
+
+    @property
+    def n_ssm_layers(self) -> int:
+        return self.layer_pattern.count("M")
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.ssm_state_size
+
+    def state_parts(self) -> tuple:
+        """(name, shape a lane and layer, dtype) of a Mamba layer's
+        recurrent state, for `cache.StateLayout`: the conv window a part
+        a row (`conv0` the oldest), so that each buffer is (layers,
+        slots, conv_dim) and tiles without padding (a (3, conv_dim)
+        window a slot pads 3 rows to 16 and XLA relays the buffer out
+        around every program), and the SSM state."""
+        return tuple(
+            (f"conv{j}", (self.conv_dim,), self.dtype)
+            for j in range(self.conv_kernel - 1)) + (
+            ("ssm", (self.mamba_num_heads, self.mamba_head_dim,
+                     self.ssm_state_size), jnp.float32),)
+
+    @staticmethod
+    def tiny() -> "NemotronHConfig":
+        """Every layer kind at a size for CPU tests, float32: 16 experts
+        of which 8 (from the 4th on) are held, chunks of 8 rows."""
+        return NemotronHConfig(
+            vocab_size=512, hidden_size=64, layer_pattern="MEM*EME",
+            mamba_num_heads=8, mamba_head_dim=8, ssm_state_size=16,
+            n_groups=2, chunk_size=8, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, n_routed_experts=16,
+            num_experts_per_tok=3, moe_intermediate_size=32,
+            moe_shared_expert_intermediate_size=48, experts_held=8,
+            expert_offset=4, max_position_embeddings=256,
+            dtype=jnp.float32, param_dtype=jnp.float32)
+
+    @staticmethod
+    def nano_30b_a3b() -> "NemotronHConfig":
+        """NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 as published
+        (huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16,
+        config.json): 52 blocks of 2688, every expert held (63 GB in
+        bf16: the base of the cut below, served nowhere here)."""
+        return NemotronHConfig(
+            layer_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*"
+                          "EMEMEMEME")
+
+    @staticmethod
+    def nano_30b_a3b_l18_ep4() -> "NemotronHConfig":
+        """One chip's share where the four chips of a v5e host share each
+        layer: the first 18 of 52 blocks (8 M, 8 E, 2 *), 32 of the 128
+        experts and 32,768 of the 131,072 vocabulary rows; every width
+        as published (PERF.md section 4)."""
+        full = NemotronHConfig.nano_30b_a3b()
+        return dataclasses.replace(
+            full, layer_pattern=full.layer_pattern[:18], experts_held=32,
+            vocab_size=32768, max_position_embeddings=2560)
+
+
+def nemotron_h_partition_rules() -> PartitionRules:
+    """The held experts over `expert`; the vocabulary over `tensor`;
+    everything else (mixers, router, shared expert) whole on every
+    device, as the stated deployment has it."""
+    from jax.sharding import PartitionSpec as P
+
+    return PartitionRules([
+        (r"layers/\d+/(we_up|we_down)$", P("expert", None, None)),
+        (r"wte$", P("tensor", None)),
+        (r"lm_head$", P(None, "tensor")),
+        (r".*", P()),
+    ])
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def init_nemotron_h(key: jax.Array, cfg: NemotronHConfig) -> Params:
+    """One program for the whole tree, every leaf drawn in float32 and
+    written in `cfg.param_dtype` by the same fusion. Matrices are normal
+    with std `initializer_range`, those that write the residual stream
+    that over sqrt(L)
+    (`rescale_prenorm_residual`); norm scales 1. The state-space
+    parameters follow the family's rule: `A_log` the log of uniform
+    1..16, `dt_bias` the inverse softplus of a dt log-uniform in
+    `time_step_min`..`time_step_max` and floored at `time_step_floor`,
+    `D` 1, the conv as torch's Conv1d default (uniform within
+    1 / sqrt(conv_kernel)). The router's selection bias is small noise
+    (std 0.02, a sixth of the scores' spread at matrices of std 0.01: more
+    would send most rows to the same few experts), so that choosing (with
+    it) and weighting (without) differ."""
+    L, D, V = cfg.n_layer, cfg.hidden_size, cfg.padded_vocab
+    pdt = cfg.param_dtype
+    std = cfg.initializer_range
+    out_std = std / math.sqrt(L)
+    k_wte, k_head, k_layers = jax.random.split(key, 3)
+
+    def normal(k, shape, scale):
+        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(pdt)
+
+    def experts(k, shape, scale):
+        # drawn into place one expert at a time: no float32 copy of the
+        # whole stack exists beside it
+        keys = jax.random.split(k, shape[0])
+        return jax.lax.fori_loop(
+            0, shape[0],
+            lambda i, buf: buf.at[i].set(normal(keys[i], shape[1:], scale)),
+            jnp.zeros(shape, pdt))
+
+    def mamba(k):
+        ks = jax.random.split(k, 6)
+        H, C, K = cfg.mamba_num_heads, cfg.conv_dim, cfg.conv_kernel
+        bound = 1.0 / math.sqrt(K)
+        dt = jnp.exp(jax.random.uniform(ks[2], (H,), jnp.float32)
+                     * (math.log(cfg.time_step_max)
+                        - math.log(cfg.time_step_min))
+                     + math.log(cfg.time_step_min))
+        dt = jnp.maximum(dt, cfg.time_step_floor)
+        return {
+            "norm": jnp.ones((D,), pdt),
+            "in_proj": normal(ks[0], (D, cfg.d_inner + C + H), std),
+            "conv_w": jax.random.uniform(
+                ks[1], (K, C), jnp.float32, -bound, bound).astype(pdt),
+            "conv_b": jax.random.uniform(
+                ks[5], (C,), jnp.float32, -bound, bound).astype(pdt),
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pdt),
+            "A_log": jnp.log(jax.random.uniform(
+                ks[3], (H,), jnp.float32, 1.0, 16.0)).astype(pdt),
+            "D": jnp.ones((H,), pdt),
+            "gate_norm": jnp.ones((cfg.d_inner,), pdt),
+            "out_proj": normal(ks[4], (cfg.d_inner, D), out_std),
+        }
+
+    def attention(k):
+        ks = jax.random.split(k, 4)
+        q_dim = cfg.num_attention_heads * cfg.head_dim
+        kv_dim = cfg.num_key_value_heads * cfg.head_dim
+        return {
+            "norm": jnp.ones((D,), pdt),
+            "wq": normal(ks[0], (D, q_dim), std),
+            "wk": normal(ks[1], (D, kv_dim), std),
+            "wv": normal(ks[2], (D, kv_dim), std),
+            "wo": normal(ks[3], (q_dim, D), out_std),
+        }
+
+    def routed(k):
+        ks = jax.random.split(k, 6)
+        X, F = cfg.experts_held, cfg.moe_intermediate_size
+        Fs = cfg.moe_shared_expert_intermediate_size
+        return {
+            "norm": jnp.ones((D,), pdt),
+            "router": normal(ks[0], (D, cfg.n_routed_experts), std),
+            "router_bias": normal(ks[1], (cfg.n_routed_experts,), 0.02),
+            "we_up": experts(ks[2], (X, D, F), std),
+            "we_down": experts(ks[3], (X, F, D), out_std),
+            "ws_up": normal(ks[4], (D, Fs), std),
+            "ws_down": normal(ks[5], (Fs, D), out_std),
+        }
+
+    make = {"M": mamba, "*": attention, "E": routed}
+    layers = [make[kind](k) for kind, k in zip(
+        cfg.layer_pattern, jax.random.split(k_layers, L))]
+    return {"wte": normal(k_wte, (V, D), std), "layers": layers,
+            "lnf": jnp.ones((D,), pdt),
+            "lm_head": normal(k_head, (D, V), std)}
+
+
+# --------------------------------------------------------------------------
+# the three mixers, each written once
+
+
+def _rmsnorm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * rms * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _ssm_inputs(h, p, cfg: NemotronHConfig):
+    """Normed rows h (..., D) -> z (..., d_inner), xBC (..., conv_dim)
+    before the convolution, dt (..., H) before its bias."""
+    with jax.named_scope("ssm.in_proj"):
+        zxbcdt = h @ p["in_proj"].astype(cfg.dtype)
+    return jnp.split(zxbcdt, (cfg.d_inner, cfg.d_inner + cfg.conv_dim),
+                     axis=-1)
+
+
+def _ssm_split(xbc, dt, p, cfg: NemotronHConfig):
+    """The convolution's output (..., conv_dim) f32 and raw dt -> x
+    (..., H, P), B and C (..., G, N) in `dtype`, dt (..., H) f32 after
+    its bias and softplus, A (H,) f32."""
+    H, P = cfg.mamba_num_heads, cfg.mamba_head_dim
+    G, N = cfg.n_groups, cfg.ssm_state_size
+    xbc = jax.nn.silu(xbc).astype(cfg.dtype)
+    x, B, C = jnp.split(xbc, (H * P, H * P + G * N), axis=-1)
+    lead = xbc.shape[:-1]
+    dt = jax.nn.softplus(dt.astype(jnp.float32)
+                         + p["dt_bias"].astype(jnp.float32))
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))
+    return (x.reshape(*lead, H, P), B.reshape(*lead, G, N),
+            C.reshape(*lead, G, N), dt, A)
+
+
+def _ssm_output(y, x, z, p, cfg: NemotronHConfig):
+    """y (..., H, P) f32 from the recurrence -> the mixer's output
+    (..., D): the skip ``D x``, the gate under its grouped norm, and
+    `out_proj`."""
+    G = cfg.n_groups
+    with jax.named_scope("ssm.gate_norm"):
+        y = y + p["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+        lead = y.shape[:-2]
+        y = y.reshape(*lead, cfg.d_inner) \
+            * jax.nn.silu(z.astype(jnp.float32))
+        g = y.reshape(*lead, G, cfg.d_inner // G)
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                              + cfg.layer_norm_epsilon)
+        y = (g.reshape(*lead, cfg.d_inner)
+             * p["gate_norm"].astype(jnp.float32)).astype(cfg.dtype)
+    with jax.named_scope("ssm.out_proj"):
+        return y @ p["out_proj"].astype(cfg.dtype)
+
+
+def ssd_chunked(x, B, C, dt, A, state, chunk: int):
+    """The recurrence over T rows in its chunked form. x (T, H, P), B and
+    C (T, G, N), dt (T, H) f32 (0 for a row that must not count), A (H,),
+    state (H, P, N) f32 -> (y (T, H, P) f32 without the skip, the state
+    after the last row). Inside a chunk of Q rows, with ``a = dt A`` and
+    ``cum`` its running sum: ``y_t = sum_{s<=t} exp(cum_t - cum_s)
+    (C_t . B_s) dt_s x_s + exp(cum_t) S_0 C_t`` and ``S_Q = exp(cum_Q)
+    S_0 + sum_s exp(cum_Q - cum_s) dt_s x_s B_s^T``; a `lax.scan` carries
+    the state from chunk to chunk. The decay and the state are float32;
+    the products take their operands as the backend's default precision
+    gives them (bf16 on the MXU) and accumulate in float32."""
+    T, H, P = x.shape
+    G, N = B.shape[1:]
+    R = H // G  # heads that share a group's B and C
+    Q = chunk if T % chunk == 0 else T
+    if T % Q or (Q != chunk and T > chunk):
+        raise ValueError(f"{T} rows do not divide into chunks of {chunk}")
+    f32 = jnp.float32
+    xdt = (x.astype(f32) * dt[..., None]).reshape(T // Q, Q, G, R, P)
+    a = (dt * A).reshape(T // Q, Q, G, R)
+    Bc = B.astype(f32).reshape(T // Q, Q, G, N)
+    Cc = C.astype(f32).reshape(T // Q, Q, G, N)
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+
+    def one(S, xs):
+        xdt, a, Bq, Cq = xs
+        cum = jnp.cumsum(a, axis=0)  # (Q, G, R)
+        # (G, R, t, s): the decay from row s to row t, 0 above the diagonal
+        seg = cum.transpose(1, 2, 0)[:, :, :, None] \
+            - cum.transpose(1, 2, 0)[:, :, None, :]
+        decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+        cb = jnp.einsum("tgn,sgn->gts", Cq, Bq)
+        y = jnp.einsum("grts,sgrp->tgrp", cb[:, None] * decay, xdt)
+        y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+            "tgn,grpn->tgrp", Cq, S)
+        to_end = jnp.exp(cum[-1][None] - cum)  # (Q, G, R)
+        S = jnp.exp(cum[-1])[..., None, None] * S + jnp.einsum(
+            "sgrp,sgn->grpn", xdt * to_end[..., None], Bq)
+        return S, y
+
+    S, y = jax.lax.scan(one, state.astype(f32).reshape(G, R, P, N),
+                        (xdt, a, Bc, Cc))
+    return y.reshape(T, H, P), S.reshape(H, P, N)
+
+
+def _window(state: dict, cfg: NemotronHConfig):
+    """The conv window (..., K-1, C) of a layer's state parts."""
+    return jnp.stack([state[f"conv{j}"]
+                      for j in range(cfg.conv_kernel - 1)], axis=-2)
+
+
+def _mamba_rows(h, p, cfg: NemotronHConfig, view, index: int, n_valid):
+    """The Mamba mixer on one lane's normed rows h (T, D), from the state
+    in the lane's slot (zero on a sequence's first rows) and leaving the
+    state after row ``n_valid - 1`` there."""
+    T = h.shape[0]
+    K = cfg.conv_kernel
+    state = view.lane(index)
+    z, xbc, dt = _ssm_inputs(h, p, cfg)
+    with jax.named_scope("ssm.conv"):
+        # window[j] is the input K-1-j rows back; rows of the lane's
+        # earlier programs come from its slot
+        seen = jnp.concatenate([_window(state, cfg).astype(xbc.dtype), xbc])
+        w = p["conv_w"].astype(jnp.float32)
+        conv = p["conv_b"].astype(jnp.float32) + sum(
+            w[j] * seen[j:j + T].astype(jnp.float32) for j in range(K))
+        # the last K-1 REAL inputs: padded rows leave the window alone
+        window = jax.lax.dynamic_slice_in_dim(seen, n_valid, K - 1)
+    x, B, C, dt, A = _ssm_split(conv, dt, p, cfg)
+    dt = jnp.where(jnp.arange(T)[:, None] < n_valid, dt, 0.0)
+    with jax.named_scope("ssm.scan"):
+        y, ssm = ssd_chunked(x, B, C, dt, A, state["ssm"], cfg.chunk_size)
+    view.set_lane(index, {"ssm": ssm, **{
+        f"conv{j}": window[j] for j in range(K - 1)}})
+    return _ssm_output(y, x, z, p, cfg)
+
+
+def _mamba_step(h, p, cfg: NemotronHConfig, view, index: int):
+    """One step of the recurrence for a decode batch h (Sb, D). Both parts
+    of the state are updated where they lie, every slot of the layer in
+    one elementwise pass: a slot that no lane of this step owns keeps its
+    conv window, and gets dt = 0 and x = 0, and ``1 * S + 0`` is S to the
+    bit."""
+    G = cfg.n_groups
+    R = cfg.mamba_num_heads // G
+    z, xbc, dt = _ssm_inputs(h, p, cfg)
+    state = view.all(index)
+    with jax.named_scope("ssm.conv"):
+        window = _window(state, cfg)  # (slots, K-1, C)
+        seen = jnp.concatenate(
+            [window, view.to_slots(xbc).astype(window.dtype)[:, None]], 1)
+        for j in range(cfg.conv_kernel - 1):  # the window moves one row on
+            view.set_all(index, f"conv{j}", jnp.where(
+                view.owned[:, None], seen[:, j + 1], window[:, j]))
+        conv = p["conv_b"].astype(jnp.float32) + jnp.einsum(
+            "kc,bkc->bc", p["conv_w"].astype(jnp.float32),
+            view.from_slots(seen).astype(jnp.float32))
+    x, B, C, dt, A = _ssm_split(conv, dt, p, cfg)
+    with jax.named_scope("ssm.step"):
+        f32 = jnp.float32
+        S = state["ssm"]  # (slots, H, P, N) f32
+        slots, H, P, N = S.shape
+        dts = view.to_slots(dt)  # (slots, H); 0 where no lane
+        xdt = view.to_slots(x.astype(f32)) * dts[..., None]
+        Bs = view.to_slots(B.astype(f32))  # (slots, G, N)
+        Cs = view.to_slots(C.astype(f32))
+        S5 = S.reshape(slots, G, R, P, N)
+        new = jnp.exp(dts * A).reshape(slots, G, R, 1, 1) * S5 \
+            + xdt.reshape(slots, G, R, P, 1) * Bs[:, :, None, None, :]
+        y = jnp.sum(new * Cs[:, :, None, None, :], axis=-1)
+        view.set_all(index, "ssm", new.reshape(S.shape))
+        y = view.from_slots(y.reshape(slots, H, P))
+    return _ssm_output(y, x, z, p, cfg)
+
+
+def _qkv(h, p, cfg: NemotronHConfig):
+    """Normed rows h (..., D) -> q (..., HK, R, hd), k and v (..., HK,
+    hd): the R query heads of a group side by side, not rotated."""
+    dt = cfg.dtype
+    HK, hd = cfg.num_key_value_heads, cfg.head_dim
+    R = cfg.num_attention_heads // HK
+    lead = h.shape[:-1]
+    return ((h @ p["wq"].astype(dt)).reshape(*lead, HK, R, hd),
+            (h @ p["wk"].astype(dt)).reshape(*lead, HK, hd),
+            (h @ p["wv"].astype(dt)).reshape(*lead, HK, hd))
+
+
+def _attend(q, segments, p, cfg: NemotronHConfig):
+    """q (B, T, HK, R, hd) against the rows of every segment ``(keys,
+    values (B, S, HK, hd), valid (B, T, S))`` (the cached context, the
+    program's own rows) under one softmax in float32 -> (B, T, D) after
+    `wo`. The segments' scores are joined, never their rows (the gathered
+    context is not copied again), and K and V are never repeated R
+    times: the heads of a group share them in the product."""
+    B, T = q.shape[:2]
+    scale = 1.0 / math.sqrt(cfg.head_dim)
+    s = jnp.concatenate(
+        [jnp.where(valid[:, None, None],
+                   jnp.einsum("btgrd,bsgd->bgrts", q, keys)
+                   .astype(jnp.float32) * scale, -1e30)
+         for keys, _, valid in segments], axis=-1)
+    probs = jax.nn.softmax(s, axis=-1).astype(cfg.dtype)
+    att, at = 0, 0
+    for _, values, valid in segments:
+        n = valid.shape[-1]
+        att = att + jnp.einsum("bgrts,bsgd->btgrd", probs[..., at:at + n],
+                               values)
+        at += n
+    return att.reshape(B, T, -1) @ p["wo"].astype(cfg.dtype)
+
+
+def _experts(h, p, cfg: NemotronHConfig):
+    """Normed rows h (N, D) -> (the held experts' part of the routed sum
+    plus the shared expert, pairs per expert over ALL experts)."""
+    dt = cfg.dtype
+    up, down = p["we_up"].astype(dt), p["we_down"].astype(dt)
+
+    def shared(rows):
+        a = jax.nn.relu(rows @ p["ws_up"].astype(dt))
+        return (a * a) @ p["ws_down"].astype(dt)
+
+    def expert_fn(rows, mm):
+        a = jax.nn.relu(mm(rows, up))
+        return mm(a * a, down)
+
+    y, counts, _ = routed_experts(
+        h, p["router"], expert_fn, k=cfg.num_experts_per_tok,
+        norm_topk=cfg.norm_topk_prob, score="sigmoid",
+        select_bias=p["router_bias"], scale=cfg.routed_scaling_factor,
+        held=(cfg.expert_offset, cfg.experts_held), shared=shared)
+    return y, counts
+
+
+def _stack(params, x, cfg: NemotronHConfig, mamba, attention):
+    """The blocks in the pattern's order on x (B, T, D) or (B, D).
+    ``mamba(h, p, i)`` and ``attention(h, p, i) -> (out, k, v)`` are the
+    program's way through those two kinds, `i` counting the layers of
+    that kind; the expert kind is the same in every program. Returns
+    (logits f32, k, v stacked over the attention layers, pairs per
+    expert stacked over the expert layers)."""
+    eps = cfg.layer_norm_epsilon
+    seen = {"M": 0, "*": 0}
+    ks, vs, counts = [], [], []
+    for kind, p in zip(cfg.layer_pattern, params["layers"]):
+        h = _rmsnorm(x, p["norm"], eps)
+        if kind == "M":
+            y = mamba(h, p, seen["M"])
+            seen["M"] += 1
+        elif kind == "*":
+            y, k, v = attention(h, p, seen["*"])
+            seen["*"] += 1
+            ks.append(k)
+            vs.append(v)
+        else:
+            y, c = _experts(h.reshape(-1, h.shape[-1]), p, cfg)
+            y = y.reshape(h.shape)
+            counts.append(c)
+        x = x + y
+    x = _rmsnorm(x, params["lnf"], eps)
+    logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    return logits, jnp.stack(ks), jnp.stack(vs), jnp.stack(counts)
+
+
+# --------------------------------------------------------------------------
+# KV-cache and state inference steps (serve.llm): the models own the
+# mathematics, serve/llm/runner.py the pages, `state` (a cache.StateView)
+# the recurrent state's reads and writes.
+
+
+def nemotron_h_prefill_kv(params: Params, tokens: jax.Array,
+                          cfg: NemotronHConfig, *, state, n_valid):
+    """A whole prompt from position 0: tokens (1, T), of which the first
+    `n_valid` are real -> (logits (1, T, Vp) f32, k, v (n_kv_layers, 1,
+    T, HK, hd), pairs (n_expert_layers, n_routed_experts))."""
+    T = tokens.shape[1]
+    valid = jnp.tril(jnp.ones((T, T), bool))[None]
+
+    def mamba(h, p, i):
+        return _mamba_rows(h[0], p, cfg, state, i, n_valid)[None]
+
+    def attention(h, p, i):
+        q, k, v = _qkv(h, p, cfg)
+        return _attend(q, [(k, v, valid)], p, cfg), k, v
+
+    x = params["wte"].astype(cfg.dtype)[tokens]
+    return _stack(params, x, cfg, mamba, attention)
+
+
+def nemotron_h_prefill_chunk_kv(params: Params, tokens: jax.Array, start,
+                                read_ctx, ctx_mask, chunk_mask,
+                                cfg: NemotronHConfig, *, state, n_valid):
+    """A chunk at positions start..start+T-1: ``read_ctx(i)`` gives the
+    i-th attention layer's cached k_ctx / v_ctx (1, C, HK, hd), the
+    Mamba layers start from the state the lane's last chunk left."""
+    T = tokens.shape[1]
+    own = jnp.tril(jnp.ones((T, T), bool))[None] & chunk_mask[:, None, :]
+    cached = jnp.broadcast_to(ctx_mask[:, None, :],
+                              (1, T, ctx_mask.shape[1]))
+
+    def mamba(h, p, i):
+        return _mamba_rows(h[0], p, cfg, state, i, n_valid)[None]
+
+    def attention(h, p, i):
+        q, k, v = _qkv(h, p, cfg)
+        kc, vc = read_ctx(i)
+        return _attend(q, [(kc, vc, cached), (k, v, own)], p, cfg), k, v
+
+    x = params["wte"].astype(cfg.dtype)[tokens]
+    return _stack(params, x, cfg, mamba, attention)
+
+
+def nemotron_h_decode_kv(params: Params, tokens: jax.Array, positions,
+                         read_ctx, ctx_mask, cfg: NemotronHConfig, *,
+                         state):
+    """One token a lane: tokens (B,) -> (logits (B, Vp) f32, k_new, v_new
+    (n_kv_layers, B, HK, hd), pairs)."""
+    B = tokens.shape[0]
+    own = jnp.ones((B, 1, 1), bool)
+
+    def mamba(h, p, i):
+        return _mamba_step(h, p, cfg, state, i)
+
+    def attention(h, p, i):
+        q, k, v = _qkv(h, p, cfg)
+        kc, vc = read_ctx(i)
+        out = _attend(q[:, None], [(kc, vc, ctx_mask[:, None]),
+                                   (k[:, None], v[:, None], own)], p, cfg)
+        return out[:, 0], k, v
+
+    x = params["wte"].astype(cfg.dtype)[tokens]
+    return _stack(params, x, cfg, mamba, attention)

@@ -283,11 +283,22 @@ class _Replica:
         os._exit(1)
 
 
-def _wait_replicas_ready(replicas, timeout: float = 180.0) -> None:
+# How long a replica may take to construct, wherever one is started
+# (deploy, heal, scale-up). An LLM replica compiles every bucketed
+# program in its __init__: with an empty compile cache the init and the
+# 16 programs of an 18-block nemotron_h stack took 143-185 s on a v5e
+# host (PR 32), on either side of the 180 s this barrier used to be.
+# A constructor that RAISES is not waited for: ActorDiedError
+# propagates at once.
+REPLICA_READY_TIMEOUT_S = 600.0
+
+
+def _wait_replicas_ready(replicas,
+                         timeout: float = REPLICA_READY_TIMEOUT_S) -> None:
     """Readiness barrier that outlives the runtime's internal actor-
     resolution window: a replica still CONSTRUCTING (heavy __init__ —
-    an LLM replica compiles every bucketed program during warmup, ~1
-    min for several replicas on a small box) surfaces as
+    an LLM replica compiles every bucketed program during warmup,
+    minutes with an empty compile cache) surfaces as
     ActorUnavailableError from a 60s resolve cap, which is 'not yet',
     not 'failed'. Retry pings until this barrier's own deadline; real
     deaths (ActorDiedError) propagate immediately."""
@@ -438,7 +449,7 @@ class ServeController:
                                    autoscaling["max_replicas"]))
         replicas = [self._make_replica(app) for _ in range(num_replicas)]
         # readiness barrier: every replica constructed
-        _wait_replicas_ready(replicas, timeout=180)
+        _wait_replicas_ready(replicas)
         with self._lock:
             app["replicas"] = replicas
             app["num_replicas"] = num_replicas
@@ -629,7 +640,7 @@ class ServeController:
                 new = None
                 try:
                     new = self._make_replica(app)
-                    _wait_replicas_ready([new], timeout=180)
+                    _wait_replicas_ready([new])
                 except Exception as e:  # noqa: BLE001
                     with self._lock:
                         self._lifecycle_locked(
@@ -828,7 +839,7 @@ class ServeController:
                         len(replicas) < cfg["max_replicas"]:
                     new = self._make_replica(app)
                     try:
-                        _wait_replicas_ready([new], timeout=120)
+                        _wait_replicas_ready([new])
                         with self._lock:
                             if self._apps.get(name) is not app:
                                 raise RuntimeError("app redeployed")
@@ -1699,7 +1710,7 @@ def run(app: Application, *, name: str = "default",
             app_name, blob, dep.num_replicas, dep.ray_actor_options,
             init_args, init_kwargs, dep.max_ongoing_requests,
             autoscaling, dep.payload_affinity, health),
-            timeout=180)
+            timeout=REPLICA_READY_TIMEOUT_S + 60)  # past its barrier
 
     deploy_graph(app, name)
     handle = get_app_handle(name)

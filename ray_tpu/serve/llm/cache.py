@@ -9,6 +9,11 @@ table, which pages hold which token content) and the pools' *layout*
 through block tables and how new rows are written). Nothing else in the
 package spells the pool's shape.
 
+A family with recurrent (state-space) layers has a second kind of state
+beside the pages, fixed in size a lane: `StateLayout` (its buffers),
+`StateView` (how a program reads and writes them) and `StateSlots` (its
+counters), further down.
+
 Page 0 is reserved as a **null sink**: it is never handed out, padded
 lanes of a bucketed batch point their tables at it, and padded prefill
 positions scatter into it. Gathers through a padded table therefore
@@ -33,9 +38,10 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import math
 import threading
 from collections import OrderedDict
-from typing import Iterable, Sequence
+from typing import Any, Iterable, Sequence
 
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
@@ -75,8 +81,10 @@ class KVLayout:
     """How one K (or V) page pool lies on the device, and the only code
     that indexes it.
 
-    The pool is ``(n_layer, num_blocks, block_size, n_kv_head *
-    head_dim)``: one token's heads side by side in one lane-dense row
+    The pool is ``(kv_layers, num_blocks, block_size, n_kv_head *
+    head_dim)``, `kv_layers` being the layers that HAVE keys and values
+    (every layer of a dense stack, 2 of 18 in the served hybrid): one
+    token's heads side by side in one lane-dense row
     (1280 lanes at gpt2-large), so the bf16 tile ``(8, 128)(2, 1)`` over
     ``(block_size, row)`` pads nothing and XLA keeps the array row-major.
     Three things together keep every serve program from copying the pool
@@ -86,7 +94,7 @@ class KVLayout:
     does in place on the donated buffer.
     """
 
-    n_layer: int
+    kv_layers: int
     num_blocks: int
     block_size: int
     n_kv_head: int
@@ -100,7 +108,7 @@ class KVLayout:
 
     @property
     def shape(self) -> tuple[int, int, int, int]:
-        return (self.n_layer, self.num_blocks, self.block_size, self.row)
+        return (self.kv_layers, self.num_blocks, self.block_size, self.row)
 
     def shard_ways(self, tensor_ways: int) -> int:
         """Over how many `tensor` shards the row splits: whole heads
@@ -111,7 +119,7 @@ class KVLayout:
 
     def block_bytes(self, dtype_bytes: int, tensor_ways: int = 1) -> int:
         """Bytes one page takes on one device, K and V together."""
-        return (2 * self.n_layer * self.block_size * self.row
+        return (2 * self.kv_layers * self.block_size * self.row
                 * dtype_bytes // self.shard_ways(tensor_ways))
 
     def spec(self, mesh) -> PartitionSpec:
@@ -143,14 +151,14 @@ class KVLayout:
                            self.n_kv_head, self.head_dim)
 
     def write(self, pages, block_ids, offsets, rows):
-        """Store ``rows (n_layer, N, n_kv_head, head_dim)`` at slots
+        """Store ``rows (kv_layers, N, n_kv_head, head_dim)`` at slots
         ``(block_ids[i], offsets[i])`` of every layer. Every scatter
         dimension leads and the row is the only window: a leading ``:``
         would make XLA transpose the pool around the scatter."""
-        n_layer, n = rows.shape[:2]
-        layers = jnp.arange(n_layer)[:, None]
+        kv_layers, n = rows.shape[:2]
+        layers = jnp.arange(kv_layers)[:, None]
         return pages.at[layers, block_ids[None, :], offsets[None, :]].set(
-            rows.reshape(n_layer, n, self.row))
+            rows.reshape(kv_layers, n, self.row))
 
     def page_block(self) -> tuple:
         """BlockSpec shape of one page for a Pallas kernel: tile-aligned
@@ -160,6 +168,143 @@ class KVLayout:
     def page_index(self, layer, page) -> tuple:
         """Block index of page `page` of layer `layer` for `page_block`."""
         return (layer, page, 0, 0)
+
+
+@dataclasses.dataclass(frozen=True)
+class StateLayout:
+    """The second kind of cached state: what a recurrent (state-space)
+    layer carries from one token to the next. It does not grow with the
+    sequence, so it is not paged: every lane slot (`Sequence.slot`, the
+    index the runner's `slot_tokens` uses too) owns one row of every part,
+    in every layer that has state. A part's buffer is ``(layers, slots,
+    *shape)`` in its own dtype (a Mamba-2 layer: the last 3 conv inputs in
+    bf16, a part each, and the SSM state in float32).
+
+    Like `KVLayout` this is the only code that spells the buffers' shape;
+    the traced reads and writes are `StateView`'s."""
+
+    layers: int  # layers that HAVE recurrent state
+    slots: int
+    # (name, shape a lane and layer, dtype), one entry a part
+    parts: tuple[tuple[str, tuple[int, ...], Any], ...]
+
+    def shape(self, part: tuple) -> tuple[int, ...]:
+        return (self.layers, self.slots) + tuple(part[1])
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes one lane's state takes, all layers and parts."""
+        return sum(self.layers * math.prod(shape) * jnp.dtype(dt).itemsize
+                   for _, shape, dt in self.parts)
+
+    @property
+    def nbytes(self) -> int:
+        return self.slots * self.slot_bytes
+
+    def zeros(self, mesh=None) -> dict:
+        """Empty buffers, replicated when there is a mesh."""
+        device = (NamedSharding(mesh, PartitionSpec())
+                  if mesh is not None else None)
+        return {part[0]: jnp.zeros(self.shape(part), part[2], device=device)
+                for part in self.parts}
+
+
+class StateView:
+    """A program's handle on the state buffers while it is traced: the
+    model's layers read and write through it, the runner takes `buffers`
+    back when the forward returns. Every write replaces the buffer it
+    touches by an update of itself at static layer (and dynamic slot)
+    indices, which XLA does in place on the donated buffer.
+
+    Two shapes of program:
+
+    - **one lane** (a prompt or a chunk of it; `slots` a scalar): `lane`
+      gives that slot's rows, zeros when the program runs the sequence's
+      first rows (`fresh`: a reused slot starts from zero, whatever its
+      last owner left), `set_lane` stores them. A negative slot (warm-up)
+      stores nothing;
+    - **every slot** (decode; `slots` (Sb,), -1 for a padded lane): the
+      state is updated where it lies, all slots of a layer in one
+      elementwise pass (`all` / `set_all`), so the lanes' inputs go to
+      slot order (`to_slots`: zeros for a slot no lane of the step owns,
+      `owned` False there, which the layer must turn into "written back
+      as read") and its outputs come back (`from_slots`). Both are
+      gathers: which lane owns a slot is worked out once a program."""
+
+    def __init__(self, layout: StateLayout, buffers: dict, slots,
+                 fresh=None):
+        self.layout = layout
+        self.buffers = dict(buffers)
+        self.slots = slots
+        self.fresh = fresh
+        if jnp.ndim(slots) == 1:
+            # lane_of[s]: the lane of this step that owns slot s, or -1
+            # (one 32-wide scatter a program; a padded lane's -1 is
+            # dropped as out of range)
+            self.lane_of = jnp.full((layout.slots,), -1, jnp.int32).at[
+                jnp.where(slots >= 0, slots, layout.slots)].set(
+                    jnp.arange(slots.shape[0], dtype=jnp.int32), mode="drop")
+            self.owned = self.lane_of >= 0
+
+    # ------------------------------------------------------------ one lane
+
+    def lane(self, layer: int) -> dict:
+        slot = jnp.maximum(self.slots, 0)
+        return {name: jnp.where(self.fresh, jnp.zeros((), buf.dtype),
+                                buf[layer, slot])
+                for name, buf in self.buffers.items()}
+
+    def set_lane(self, layer: int, rows: dict) -> None:
+        slot = jnp.maximum(self.slots, 0)
+        for name, new in rows.items():
+            buf = self.buffers[name]
+            new = jnp.where(self.slots >= 0, new.astype(buf.dtype),
+                            buf[layer, slot])
+            self.buffers[name] = buf.at[layer, slot].set(new)
+
+    # ---------------------------------------------------------- every slot
+
+    def to_slots(self, x):
+        """x (Sb, ...) in lane order -> (slots, ...) in slot order, zeros
+        where no lane of the step owns the slot."""
+        rows = x[jnp.maximum(self.lane_of, 0)]
+        return jnp.where(
+            self.owned.reshape((-1,) + (1,) * (x.ndim - 1)), rows,
+            jnp.zeros((), x.dtype))
+
+    def from_slots(self, y):
+        """y (slots, ...) -> (Sb, ...); a padded lane reads slot 0."""
+        return y[jnp.maximum(self.slots, 0)]
+
+    def all(self, layer: int) -> dict:
+        return {name: buf[layer] for name, buf in self.buffers.items()}
+
+    def set_all(self, layer: int, name: str, new) -> None:
+        buf = self.buffers[name]
+        self.buffers[name] = buf.at[layer].set(new.astype(buf.dtype))
+
+
+class StateSlots:
+    """The bookkeeping half of the recurrent state (the buffers live in
+    the ModelRunner, as the pages do): how much there is, and how often a
+    slot was started from zero. Every admission of a stateful family,
+    a recompute after preemption too, starts its slot from zero, because
+    a prefix hit would hand over pages of K and V but no state: where
+    prefix reuse was asked for (`prefix_declined`), each reset is also a
+    prefix match not attempted. Written by the engine's one stepping
+    thread."""
+
+    def __init__(self, layout: StateLayout, prefix_declined: bool):
+        self.layout = layout
+        self.prefix_declined = prefix_declined
+        self.resets = 0
+
+    def stats(self) -> dict:
+        lay = self.layout
+        return {"slots": lay.slots, "layers": lay.layers,
+                "bytes": lay.nbytes, "slot_bytes": lay.slot_bytes,
+                "resets": self.resets,
+                "prefix_declined": self.prefix_declined}
 
 
 class BlockPool:
@@ -356,7 +501,7 @@ class BlockPool:
 
 def auto_num_blocks(
     *,
-    n_layer: int,
+    kv_layers: int,
     n_kv_head: int,
     head_dim: int,
     block_size: int,
@@ -365,11 +510,15 @@ def auto_num_blocks(
     max_batch_size: int,
     memory_fraction: float = 0.3,
     tensor_ways: int = 1,
+    state_bytes: int = 0,
     device=None,
 ) -> int:
     """Size the pool off device memory (reference: vLLM's gpu memory
     profiling, here a static estimate: params are already resident, so
-    take `memory_fraction` of the device's bytes_limit for KV).
+    take `memory_fraction` of the device's bytes_limit for KV and, for a
+    family that has it, the recurrent state: `state_bytes`, what
+    `StateLayout.nbytes` says the lanes' state takes, comes out of the
+    same budget first).
 
     The CPU backend reports no memory and gets "every lane can reach
     max_model_len, twice over" (tests). A TPU that reports none is an
@@ -379,7 +528,7 @@ def auto_num_blocks(
     # the layout's own sharding rule: sizing must not assume a split
     # the runner won't make
     per_block = KVLayout(
-        n_layer, 0, block_size, n_kv_head, head_dim
+        kv_layers, 0, block_size, n_kv_head, head_dim
     ).block_bytes(dtype_bytes, tensor_ways)
     if device is None:
         import jax
@@ -393,5 +542,5 @@ def auto_num_blocks(
         raise RuntimeError(
             f"{device} reports no memory_stats()['bytes_limit']; cannot "
             f"size the KV pool — pass num_blocks explicitly")
-    budget = int(stats["bytes_limit"] * memory_fraction)
+    budget = int(stats["bytes_limit"] * memory_fraction) - state_bytes
     return max(floor + 1, budget // per_block)

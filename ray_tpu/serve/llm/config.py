@@ -70,9 +70,11 @@ class EngineConfig:
     (`cache.auto_num_blocks`); tests pass small explicit pools to force
     preemption."""
 
-    model: str = "gpt2"  # adapter key: "gpt2" | "llama"
+    model: str = "gpt2"  # a key of `runner.adapters()`
     preset: str = "tiny"  # model-config preset name on the config class
-    model_config: Any = None  # overrides preset when given
+    # a config object (used instead of the preset) or a dict of its
+    # fields laid over the preset (a JSON file's way to say the same)
+    model_config: Any = None
     block_size: int = 16  # tokens per KV page
     num_blocks: int | None = None  # physical pages incl. the null page
     memory_fraction: float = 0.3  # of device memory, when auto-sizing

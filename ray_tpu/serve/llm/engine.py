@@ -26,13 +26,14 @@ from typing import Any, Sequence as Seq
 
 import numpy as np
 
-from ray_tpu.serve.llm.cache import BlockPool, auto_num_blocks
+from ray_tpu.serve.llm.cache import BlockPool, StateSlots, auto_num_blocks
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams
 from ray_tpu.serve.llm.runner import (
     DecodeItem,
     Launched,
     ModelRunner,
     adapters,
+    state_layout_of,
 )
 from ray_tpu.serve.llm.scheduler import (
     DecodeWork,
@@ -141,7 +142,8 @@ class LLMEngine:
             raise ValueError(
                 f"unknown model {config.model!r}; have {sorted(reg)}")
         adapter = reg[config.model]
-        if config.model_config is not None:
+        if config.model_config is not None \
+                and not isinstance(config.model_config, dict):
             cfg = config.model_config
         else:
             try:
@@ -150,6 +152,8 @@ class LLMEngine:
                 raise ValueError(
                     f"unknown preset {config.preset!r} for "
                     f"{config.model}; have {sorted(adapter.presets)}")
+            if config.model_config:  # a dict: fields laid over the preset
+                cfg = dataclasses.replace(cfg, **config.model_config)
         self.model_cfg = cfg
         max_len = config.max_model_len or cfg.block_size
         if max_len > cfg.block_size:
@@ -168,10 +172,20 @@ class LLMEngine:
                 adapter.init_fn(jax.random.PRNGKey(config.seed), cfg))
             self._startup["init_params"] = time.perf_counter() - t0
 
+        # what the family caches beside K and V, read off the adapter: a
+        # recurrent state a lane slot, or nothing
+        state_layout = state_layout_of(adapter, cfg, config.max_batch_size)
+        spec_cfg = config.speculative
+        if spec_cfg and state_layout is not None:
+            raise ValueError(
+                f"speculative decoding is not available for "
+                f"{config.model!r}: the family carries recurrent state, "
+                f"and a rejected draft would have to roll it back "
+                f"(ROADMAP.md, recurrent state snapshots)")
         num_blocks = config.num_blocks
         if num_blocks is None:
             num_blocks = auto_num_blocks(
-                n_layer=cfg.n_layer,
+                kv_layers=adapter.kv_layers(cfg),
                 n_kv_head=adapter.kv_heads(cfg),
                 head_dim=cfg.head_dim,
                 block_size=config.block_size,
@@ -181,6 +195,7 @@ class LLMEngine:
                 memory_fraction=config.memory_fraction,
                 tensor_ways=(dict(mesh.shape).get("tensor", 1)
                              if mesh is not None else 1),
+                state_bytes=(state_layout.nbytes if state_layout else 0),
             )
         max_blocks_per_seq = (max_len + config.block_size - 1) \
             // config.block_size
@@ -192,16 +207,21 @@ class LLMEngine:
                 f"or lower max_model_len")
 
         # prefix reuse needs the prefill-from-offset (chunk) program:
-        # with chunking disabled the pool runs as a plain allocator
+        # with chunking disabled the pool runs as a plain allocator. And
+        # a prefix hit hands over pages of K and V but no recurrent
+        # state: for a family that has it no prefix is looked up (each
+        # admission that would have is counted, `stats()["state"]`)
         chunking = config.prefill_chunk_size > 0
+        prefix = config.enable_prefix_cache and chunking
+        self.state_slots = StateSlots(state_layout, prefix_declined=prefix) \
+            if state_layout is not None else None
         self.pool = BlockPool(
             num_blocks, config.block_size,
-            enable_prefix_cache=(config.enable_prefix_cache and chunking))
+            enable_prefix_cache=(prefix and state_layout is None))
         # speculative decoding: proposer on the host, verify program on
         # the device; greedy outputs stay bit-identical to spec-off
         from ray_tpu.serve.llm.spec import build_proposer
 
-        spec_cfg = config.speculative
         self._proposer = build_proposer(spec_cfg) if spec_cfg else None
         self._spec_k = spec_cfg.num_draft_tokens if spec_cfg else 0
         t0 = time.perf_counter()
@@ -220,10 +240,11 @@ class LLMEngine:
             use_paged_attention=config.use_paged_attention,
         )
         # weights cast to their resident dtypes and placed, both pools
-        # allocated. Nothing keeps the tree as given (float32 for GPT-2)
-        # beyond this constructor: it is freed before warm-up
+        # and the lanes' state allocated. Nothing keeps the tree as given
+        # (float32 for GPT-2) beyond this constructor: it is freed before
+        # warm-up
         jax.block_until_ready((self.runner.params, self.runner.k_pages,
-                               self.runner.v_pages))
+                               self.runner.v_pages, self.runner.state))
         self._startup["build_runner"] = time.perf_counter() - t0
         # seconds by phase of the step loop, step counts and bytes
         # fetched to the host by step kind: plain numbers, written by
@@ -251,6 +272,9 @@ class LLMEngine:
             # its value so scheduler chunks match the compiled buckets
             chunk_size=(self.runner.prefill_chunk_size or 0),
             spec_tokens=self._spec_k)
+        # the router's experts this replica holds, for the routing account
+        self._held = (adapter.held_experts(cfg)
+                      if adapter.held_experts is not None else None)
 
         self._ids = itertools.count()
         self._streams: dict[int, RequestStream] = {}  # guarded_by(_lock)
@@ -436,6 +460,20 @@ class LLMEngine:
             "Sampled ids dropped at commit: their lane had ended (an "
             "eos in the step before, an abort) while the program ran",
             tag_keys=tags)
+        # recurrent state (a family that has it): what the lanes' slots
+        # hold, and the two things it changes for a request
+        self._m_state_bytes = Gauge(
+            "serve_llm_state_bytes",
+            "Bytes of recurrent state held for the lane slots, all "
+            "layers and parts (0: the family has none)", tag_keys=tags)
+        self._m_state_resets = Counter(
+            "serve_llm_state_resets_total",
+            "Lane slots started from zero by a program that ran a "
+            "sequence's first rows (admissions, recomputes included)",
+            tag_keys=tags)
+        self._m_state_bytes.set(
+            self.state_slots.layout.nbytes if self.state_slots else 0,
+            tags=self._m_tags)
         self._moe: dict[str, dict] = {}
         self._spec_proposed_total = 0
         self._spec_accepted_total = 0
@@ -703,8 +741,17 @@ class LLMEngine:
         calls = c.shape[0] * c.shape[1]
         acc = self._moe.setdefault(kind, {
             "pairs": 0, "expert_pairs": np.zeros(c.shape[2], np.int64),
-            "experts_touched": 0, "layer_calls": 0})
+            "experts_touched": 0, "layer_calls": 0, "held_pairs": 0,
+            "held_experts_touched": 0})
         acc["pairs"] += pairs
+        # the pairs this replica's experts computed: all of them, unless
+        # it holds a share of the router's experts
+        held = (pairs, touched)
+        if self._held is not None:
+            here = c[:, :, self._held[0]:self._held[0] + self._held[1]]
+            held = (int(here.sum()), int(np.count_nonzero(here)))
+        acc["held_pairs"] += held[0]
+        acc["held_experts_touched"] += held[1]
         acc["experts_touched"] += touched
         acc["layer_calls"] += calls
         acc["expert_pairs"] += c.sum(axis=(0, 1))
@@ -720,6 +767,10 @@ class LLMEngine:
         seq = work.seq
         sp = seq.sampling
         tokens = seq.refill_tokens[work.start:work.end]
+        if work.start == 0 and self.state_slots is not None:
+            # the program zeroes the slot; no prefix was looked up
+            self.state_slots.resets += 1
+            self._m_state_resets.inc(tags=self._m_tags)
         if work.start == 0 and work.is_last:
             # whole prompt in one go and nothing cached: the
             # monolithic program skips the context gather
@@ -1166,9 +1217,15 @@ class LLMEngine:
             "spec_proposed": self._spec_proposed_total,
             "spec_accepted": self._spec_accepted_total,
             "paged_attention": self.runner.use_paged_attention,
+            # recurrent state: slots, bytes, slots started from zero,
+            # admissions that looked up no prefix; {} for a family with
+            # none
+            "state": (self.state_slots.stats() if self.state_slots
+                      else {}),
             # routed experts by step kind: pairs computed, the same per
-            # expert (summed over layers), experts touched and layers run
-            # (both summed over programs and layers); {} for a dense model
+            # expert (summed over layers), those on the experts held here,
+            # experts touched and layers run (both summed over programs
+            # and layers); {} for a dense model
             "moe": {kind: {**acc, "expert_pairs":
                            acc["expert_pairs"].tolist()}
                     for kind, acc in list(self._moe.items())},

@@ -1,8 +1,10 @@
 """ModelRunner: jit-compiled paged prefill / decode steps.
 
 Owns the device-side half of the KV cache (one K and one V pool, laid
-out, read and written only through `cache.KVLayout`) and the compiled
-programs that touch it:
+out, read and written only through `cache.KVLayout`), for a family with
+recurrent layers also the lanes' state buffers (`cache.StateLayout`,
+read and written through `cache.StateView`), and the compiled programs
+that touch them:
 
 - **prefill**: full-sequence forward of one prompt (padded to a length
   bucket), scattering every position's K/V into its page and sampling
@@ -55,7 +57,7 @@ import numpy as np
 
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ray_tpu.serve.llm.cache import KVLayout
+from ray_tpu.serve.llm.cache import KVLayout, StateLayout, StateView
 from ray_tpu.util import tracing
 
 # The step loop's phases, as `engine.stats()["step_phase_seconds"]` and
@@ -84,6 +86,19 @@ class ModelAdapter:
     chunk_fn: Callable
     rules_fn: Callable  # () -> PartitionRules
     kv_heads: Callable[[Any], int]
+    # how many layers HAVE keys and values: the pool's leading dimension
+    # and the k / v a forward returns (every layer, unless the family
+    # says otherwise)
+    kv_layers: Callable[[Any], int] = lambda cfg: cfg.n_layer
+    # recurrent state a lane carries beside its pages: cfg -> (layers
+    # that have it, ((name, shape a lane and layer, dtype), ...)); None:
+    # the family has none. With it, every forward above also takes
+    # `state=` (a cache.StateView on the lane's or the lanes' slots) and
+    # the two prefill forwards `n_valid=`, the rows that are not padding
+    state_fn: Callable | None = None
+    # cfg -> (offset, count) of the router's experts this replica holds,
+    # for the routing account; None: all of them, or none
+    held_experts: Callable | None = None
     # (params, cfg) -> the tree as the runner holds it: each leaf in the
     # dtype the forwards consume it in, one already there as the same
     # buffer (llama: `init_llama` creates every leaf in `param_dtype`)
@@ -101,7 +116,7 @@ class ModelAdapter:
 
 def adapters() -> dict[str, ModelAdapter]:
     """Model registry (lazy imports keep `import ray_tpu.serve` light)."""
-    from ray_tpu.models import gpt2, llama
+    from ray_tpu.models import gpt2, llama, nemotron_h
 
     return {
         "gpt2": ModelAdapter(
@@ -143,7 +158,35 @@ def adapters() -> dict[str, ModelAdapter]:
             decode_paged_fn=llama.llama_decode_paged_kv,
             verify_paged_fn=llama.llama_verify_paged_kv,
         ),
+        "nemotron_h": ModelAdapter(
+            name="nemotron_h",
+            config_cls=nemotron_h.NemotronHConfig,
+            presets={
+                "tiny": nemotron_h.NemotronHConfig.tiny,
+                "nano_30b_a3b_l18_ep4":
+                    nemotron_h.NemotronHConfig.nano_30b_a3b_l18_ep4,
+            },
+            init_fn=nemotron_h.init_nemotron_h,
+            prefill_fn=nemotron_h.nemotron_h_prefill_kv,
+            decode_fn=nemotron_h.nemotron_h_decode_kv,
+            chunk_fn=nemotron_h.nemotron_h_prefill_chunk_kv,
+            rules_fn=nemotron_h.nemotron_h_partition_rules,
+            kv_heads=lambda cfg: cfg.num_key_value_heads,
+            kv_layers=lambda cfg: cfg.n_kv_layers,
+            state_fn=lambda cfg: (cfg.n_ssm_layers, cfg.state_parts()),
+            held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
+        ),
     }
+
+
+def state_layout_of(adapter: ModelAdapter, cfg: Any,
+                    slots: int) -> StateLayout | None:
+    """The lanes' recurrent state for `slots` lane slots, as the family's
+    adapter describes it; None for a family that has none."""
+    if adapter.state_fn is None:
+        return None
+    layers, parts = adapter.state_fn(cfg)
+    return StateLayout(layers, slots, parts)
 
 
 class DecodeItem(NamedTuple):
@@ -295,8 +338,11 @@ class ModelRunner:
         # pallas interpret mode off-TPU (CPU CI); real kernel on TPU
         self._interpret = jax.default_backend() != "tpu"
 
-        self.layout = KVLayout(cfg.n_layer, num_blocks, block_size,
-                               adapter.kv_heads(cfg), cfg.head_dim)
+        self.layout = KVLayout(adapter.kv_layers(cfg), num_blocks,
+                               block_size, adapter.kv_heads(cfg),
+                               cfg.head_dim)
+        # a lane slot's recurrent state, for a family that has it
+        self.state_layout = state_layout_of(adapter, cfg, max_batch_size)
         # pages are mutated functionally; serialize compute just in case
         # a stats probe races the step loop
         self._jit_lock = threading.Lock()
@@ -306,6 +352,10 @@ class ModelRunner:
         self._install(params, adapter.resident_fn(params, cfg))
         self.k_pages = self.layout.zeros(cfg.dtype, mesh)
         self.v_pages = self.layout.zeros(cfg.dtype, mesh)
+        # {} for a family without recurrent state: the programs take and
+        # return it all the same, and compile to what they were
+        self.state = (self.state_layout.zeros(mesh)
+                      if self.state_layout is not None else {})
         # the last sampled id of every lane slot (see the module's head)
         self.slot_tokens = jnp.zeros(
             (max_batch_size,), jnp.int32,
@@ -316,11 +366,12 @@ class ModelRunner:
         self._step_counter = 0
         # donation elides the pages copy per step; CPU jax would only
         # warn "donation is not implemented", so gate on backend
-        donate = (1, 2) if jax.default_backend() == "tpu" else ()
+        donate = (1, 2, 4) if jax.default_backend() == "tpu" else ()
         self._prefill_jit = jax.jit(self._prefill_impl, donate_argnums=donate)
         self._decode_jit = jax.jit(self._decode_impl, donate_argnums=donate)
         self._chunk_jit = jax.jit(self._chunk_impl, donate_argnums=donate)
-        self._verify_jit = jax.jit(self._verify_impl, donate_argnums=donate)
+        self._verify_jit = jax.jit(self._verify_impl,
+                                   donate_argnums=donate[:2])
         self.phases = tracing.PhaseClock("llm.", STEP_PHASES)
         # bytes of device results copied to the host, ever (tokens and
         # logits, every `np.asarray` / `int()` of a program's output)
@@ -391,24 +442,36 @@ class ModelRunner:
         return slot_tokens.at[
             jnp.where(slots >= 0, slots, out_of_range)].set(nxt, mode="drop")
 
-    def _prefill_impl(self, params, k_pages, v_pages, slot_tokens, tokens,
-                      last_idx, block_ids, offsets, slot, temp, topk, topp,
-                      step):
+    def _forward(self, fn, state, slots, *args, fresh=None, **extra):
+        """A family's forward on `args` -> (what it returns, the lanes'
+        state buffers after it). A family with recurrent state also gets
+        `state=`, the view its layers read and write the buffers through
+        at `slots`, and `extra`; any other is called as it always was."""
+        if self.state_layout is None:
+            return fn(*args), state
+        view = StateView(self.state_layout, state, slots, fresh)
+        return fn(*args, state=view, **extra), view.buffers
+
+    def _prefill_impl(self, params, k_pages, v_pages, slot_tokens, state,
+                      tokens, last_idx, block_ids, offsets, slot, temp, topk,
+                      topp, step):
         """tokens (1, Tb); block_ids/offsets (Tb,) map position t to its
-        page slot (padded positions -> null page 0)."""
-        logits, k, v, *aux = self.adapter.prefill_fn(
-            params, tokens, self.cfg)
+        page slot (padded positions -> null page 0). A sequence's first
+        rows: its slot's recurrent state starts from zero."""
+        (logits, k, v, *aux), state = self._forward(
+            self.adapter.prefill_fn, state, slot, params, tokens, self.cfg,
+            fresh=True, n_valid=last_idx + 1)
         # (L, 1, Tb, HK, D) -> (L, Tb, HK, D)
         k_pages = self.layout.write(k_pages, block_ids, offsets, k[:, 0])
         v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
         nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
         slot_tokens = self._keep_sampled(slot_tokens, slot, nxt)
-        return nxt, last, k_pages, v_pages, slot_tokens, tuple(aux)
+        return nxt, last, k_pages, v_pages, slot_tokens, state, tuple(aux)
 
-    def _chunk_impl(self, params, k_pages, v_pages, slot_tokens, tokens,
-                    start, last_idx, block_ids, offsets, table, slot, temp,
-                    topk, topp, step):
+    def _chunk_impl(self, params, k_pages, v_pages, slot_tokens, state,
+                    tokens, start, last_idx, block_ids, offsets, table, slot,
+                    temp, topk, topp, step):
         """Prefill a chunk of ONE sequence from a position offset.
 
         tokens (1, Tb) at absolute positions start..start+Tb-1 (padded
@@ -416,21 +479,22 @@ class ModelRunner:
         table, read for context (positions < start); block_ids/
         offsets (Tb,) map chunk position t to its page slot. `start` is
         traced, so one compiled program per chunk-length bucket serves
-        every offset."""
+        every offset. Recurrent state is carried chunk to chunk in the
+        lane's slot, from zero where `start` is 0."""
         Tb = tokens.shape[1]
         C = self.max_blocks_per_seq * self.block_size
         ctx_mask = (jnp.arange(C)[None, :] < start)  # (1, C)
         chunk_mask = (jnp.arange(Tb)[None, :] <= last_idx)  # (1, Tb)
-        logits, k, v, *aux = self.adapter.chunk_fn(
-            params, tokens, start,
+        (logits, k, v, *aux), state = self._forward(
+            self.adapter.chunk_fn, state, slot, params, tokens, start,
             self._ctx_reader(k_pages, v_pages, table[None]), ctx_mask,
-            chunk_mask, self.cfg)
+            chunk_mask, self.cfg, fresh=start == 0, n_valid=last_idx + 1)
         k_pages = self.layout.write(k_pages, block_ids, offsets, k[:, 0])
         v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
         nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
         slot_tokens = self._keep_sampled(slot_tokens, slot, nxt)
-        return nxt, last, k_pages, v_pages, slot_tokens, tuple(aux)
+        return nxt, last, k_pages, v_pages, slot_tokens, state, tuple(aux)
 
     def _verify_impl(self, params, k_pages, v_pages, tokens, start,
                      n_draft, block_ids, offsets, table, temps, topks,
@@ -480,15 +544,18 @@ class ModelRunner:
         emitted = jnp.where(jnp.arange(W) <= n_acc, target, -1)
         return emitted, n_acc, lg, k_pages, v_pages, tuple(aux)
 
-    def _decode_impl(self, params, k_pages, v_pages, slot_tokens, tokens,
-                     slots, positions, tables, temps, topks, topps, step):
+    def _decode_impl(self, params, k_pages, v_pages, slot_tokens, state,
+                     tokens, slots, positions, tables, temps, topks, topps,
+                     step):
         """tokens/slots/positions/temps (Sb,); tables (Sb,
         max_blocks_per_seq). Run the model's decode step, each layer
         reading its dense context through the tables, scatter the new
         K/V at each lane's position, sample. With paged attention the
         gather disappears: the kernel indexes pages in place through the
         block table. A lane whose token is -1 feeds the id an earlier
-        program left at its slot."""
+        program left at its slot. Recurrent state moves one step in the
+        slots of the step's lanes; a padded lane (slot -1) and a slot no
+        lane owns keep theirs."""
         Bs = self.block_size
         tokens = jnp.where(tokens >= 0, tokens,
                            slot_tokens[jnp.maximum(slots, 0)])
@@ -499,10 +566,10 @@ class ModelRunner:
         else:
             C = self.max_blocks_per_seq * Bs
             ctx_mask = jnp.arange(C)[None, :] < positions[:, None]
-            logits, k_new, v_new, *aux = self.adapter.decode_fn(
-                params, tokens, positions,
-                self._ctx_reader(k_pages, v_pages, tables), ctx_mask,
-                self.cfg)
+            (logits, k_new, v_new, *aux), state = self._forward(
+                self.adapter.decode_fn, state, slots, params, tokens,
+                positions, self._ctx_reader(k_pages, v_pages, tables),
+                ctx_mask, self.cfg)
         block_ids = jnp.take_along_axis(
             tables, (positions // Bs)[:, None], axis=1)[:, 0]
         offsets = positions % Bs
@@ -510,7 +577,7 @@ class ModelRunner:
         v_pages = self.layout.write(v_pages, block_ids, offsets, v_new)
         nxt = self._sample(logits, temps, topks, topps, step)
         slot_tokens = self._keep_sampled(slot_tokens, slots, nxt)
-        return nxt, logits, k_pages, v_pages, slot_tokens, tuple(aux)
+        return nxt, logits, k_pages, v_pages, slot_tokens, state, tuple(aux)
 
     # -------------------------------------------------------------- host
 
@@ -593,9 +660,10 @@ class ModelRunner:
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
                 (nxt, last, self.k_pages, self.v_pages, self.slot_tokens,
-                 aux) = self._prefill_jit(
+                 self.state, aux) = self._prefill_jit(
                     self.params, self.k_pages, self.v_pages,
-                    self.slot_tokens, toks, np.int32(n - 1), block_ids,
+                    self.slot_tokens, self.state, toks, np.int32(n - 1),
+                    block_ids,
                     offsets, np.int32(slot), temp, topk, topp,
                     np.int32(self._step_counter))
             self._note_compile("prefill", self._prefill_jit, before,
@@ -648,9 +716,9 @@ class ModelRunner:
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
                 (nxt, last, self.k_pages, self.v_pages, self.slot_tokens,
-                 aux) = self._chunk_jit(
+                 self.state, aux) = self._chunk_jit(
                     self.params, self.k_pages, self.v_pages,
-                    self.slot_tokens, toks, np.int32(start),
+                    self.slot_tokens, self.state, toks, np.int32(start),
                     np.int32(n - 1), block_ids, offsets, tab,
                     np.int32(slot), temp, topk, topp,
                     np.int32(self._step_counter))
@@ -695,10 +763,10 @@ class ModelRunner:
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
                 (nxt, logits, self.k_pages, self.v_pages, self.slot_tokens,
-                 aux) = self._decode_jit(
+                 self.state, aux) = self._decode_jit(
                     self.params, self.k_pages, self.v_pages,
-                    self.slot_tokens, toks, slots, poss, tables, temps,
-                    topks, topps, np.int32(self._step_counter))
+                    self.slot_tokens, self.state, toks, slots, poss, tables,
+                    temps, topks, topps, np.int32(self._step_counter))
             self._note_compile("decode", self._decode_jit, before,
                                time.perf_counter() - t0)
         return Launched((nxt, logits), aux, S)
@@ -856,9 +924,11 @@ class ModelRunner:
         self._install(params, jax.tree.map(cast, params, self.params))
 
     def reset_cache(self) -> None:
-        """Zero the pages (tests); allocator state lives in BlockPool."""
+        """Zero the pages and the lanes' state (tests); allocator state
+        lives in BlockPool."""
         self.k_pages = jnp.zeros_like(self.k_pages)
         self.v_pages = jnp.zeros_like(self.v_pages)
+        self.state = jax.tree.map(jnp.zeros_like, self.state)
 
     def compiled_signatures(self) -> int:
         """Number of distinct compiled programs so far — the

@@ -208,6 +208,15 @@ CATALOG: list[dict] = [
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "pairs of the most loaded expert over the mean expert's, "
              "cumulative, by step kind (1.0: an even router)"},
+    # recurrent state (a family without it writes 0 and nothing more)
+    {"name": "serve_llm_state_bytes", "type": "gauge",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "bytes of recurrent state held for the lane slots, all "
+             "layers and parts (0: the family has none)"},
+    {"name": "serve_llm_state_resets_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "lane slots started from zero by a program that ran a "
+             "sequence's first rows (admissions, recomputes included)"},
     # jax's own account of its compiles (every process that compiles)
     {"name": "jax_compile_seconds_total", "type": "counter",
      "where": "ray_tpu/util/tracing.py",

@@ -20,13 +20,13 @@ import pytest
 
 from ray_tpu.serve.llm.cache import KVLayout, auto_num_blocks
 
-# (n_layer, num_blocks, block_size, n_kv_head, head_dim)
+# (kv_layers, num_blocks, block_size, n_kv_head, head_dim)
 LAYOUTS = {"mha": (3, 12, 4, 4, 16), "gqa": (2, 10, 16, 8, 128)}
 
 
 def _numpy_pool(layout, rng):
     """The pool as plain numpy holds it: (L, pages, Bs, HK, D)."""
-    return rng.normal(size=(layout.n_layer, layout.num_blocks,
+    return rng.normal(size=(layout.kv_layers, layout.num_blocks,
                             layout.block_size, layout.n_kv_head,
                             layout.head_dim)).astype(np.float32)
 
@@ -129,7 +129,7 @@ def test_pool_shards_whole_heads_over_tensor(cpu_mesh8, n_kv_head, sharded):
             return {"bytes_limit": 1 << 30}
 
     sized = {ways: auto_num_blocks(
-        n_layer=2, n_kv_head=n_kv_head, head_dim=16, block_size=4,
+        kv_layers=2, n_kv_head=n_kv_head, head_dim=16, block_size=4,
         dtype_bytes=2, max_model_len=64, max_batch_size=2,
         memory_fraction=0.5, tensor_ways=ways, device=Dev())
         for ways in (1, 2)}
@@ -193,14 +193,19 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-# model -> (family, preset, lanes, pages, monolithic prefill bucket): the
-# serve cells' engines as benchmark/configs/ has them
-MODELS = {"gpt2-large": ("gpt2", "large", 8, 512, 512),
-          "olmoe-1b-7b": ("llama", "olmoe_1b_7b_l8", 16, 1088, 256)}
+# model -> (family, preset, lanes, pages, monolithic prefill bucket,
+# max_model_len): the serve cells' engines as benchmark/configs/ has them
+MODELS = {"gpt2-large": ("gpt2", "large", 8, 512, 512, 1024),
+          "olmoe-1b-7b": ("llama", "olmoe_1b_7b_l8", 16, 1088, 256, 1024),
+          "nemotron-3-nano-30b-a3b": (
+              "nemotron_h", "nano_30b_a3b_l18_ep4", 32, 5184, 256, 2560)}
 # A program's temporaries, bytes. With no weight cast in any program they
 # are activations: the AOT compile reads 1.1-105.8 MB for gpt2-large (the
 # most in prefill-512; 1.55-1.64 GB while the float32 stacks were cast
-# inside) and 4.4-136.4 MB for OLMoE (decode-16)
+# inside), 4.4-136.4 MB for OLMoE (decode-16), and 62.8 MB (decode-32),
+# 75.9 MB (chunk-256) and 91.2 MB (prefill-256) for the nemotron_h cut
+# (527.3 MB in chunk-256 while the conv window was one (3, 6144) part a
+# slot, which XLA relaid out around every program)
 TEMP_BOUND = 0.3e9
 
 
@@ -215,7 +220,7 @@ def served_runner(one_chip, request):
     shapes of the leaves the adapter cast, for the `convert` assertion."""
     from ray_tpu.serve.llm.runner import ModelRunner, adapters
 
-    family, preset, lanes, pages, _ = MODELS[request.param]
+    family, preset, lanes, pages, _, max_len = MODELS[request.param]
     adapter = adapters()[family]
     cfg = adapter.presets[preset]()
 
@@ -231,33 +236,44 @@ def served_runner(one_chip, request):
     cast = [r for g, r in zip(jax.tree.leaves(given),
                               jax.tree.leaves(params)) if g.dtype != r.dtype]
     runner = ModelRunner(adapter, cfg, params, block_size=16, num_blocks=2,
-                         max_model_len=1024, max_batch_size=lanes,
+                         max_model_len=max_len, max_batch_size=lanes,
                          prefill_chunk_size=256, num_draft_tokens=4)
     runner._interpret = False  # the kernel as the chip compiles it
     pool = jax.ShapeDtypeStruct(
         dataclasses.replace(runner.layout, num_blocks=pages).shape,
         cfg.dtype, sharding=one_chip)
     assert runner.weights["cast_leaves"] == 0  # resident shapes given
-    return request.param, runner, params, pool, cast
+    # the lanes' recurrent state at its real size ({}: the family has none)
+    state = {}
+    if runner.state_layout is not None:
+        state = {part[0]: jax.ShapeDtypeStruct(
+            runner.state_layout.shape(part), part[2], sharding=one_chip)
+            for part in runner.state_layout.parts}
+    return request.param, runner, params, (pool, state), cast
 
 
 # program -> (runner method, argument shapes after (params, k, v), rows
 # sampled); "m" is max_blocks_per_seq, "p" the monolithic prefill bucket,
 # "s" the decode lanes; temperature, top-k and top-p follow, then the step.
 # Prefill, chunk and decode take the device-resident last sampled ids
-# first ("s" of them) and the slot(s) they leave theirs at (PR 31)
+# first ("s" of them) and the slot(s) they leave theirs at (PR 31), and
+# behind the ids the lanes' recurrent state ("state": {} but for a family
+# that has it)
 PROGRAMS = {
-    "prefill": ("_prefill_impl", [(("s",), "i"), ((1, "p"), "i"), ((), "i"),
+    "prefill": ("_prefill_impl", [(("s",), "i"), "state", ((1, "p"), "i"),
+                                  ((), "i"),
                                   (("p",), "i"), (("p",), "i"),
                                   ((), "i")], 1),
-    "chunk-256": ("_chunk_impl", [(("s",), "i"), ((1, 256), "i"), ((), "i"),
-                                  ((), "i"), ((256,), "i"), ((256,), "i"),
+    "chunk-256": ("_chunk_impl", [(("s",), "i"), "state", ((1, 256), "i"),
+                                  ((), "i"), ((), "i"), ((256,), "i"),
+                                  ((256,), "i"),
                                   (("m",), "i"), ((), "i")], 1),
     "verify-5": ("_verify_impl", [((1, 5), "i"), ((), "i"), ((), "i"),
                                   ((5,), "i"), ((5,), "i"),
                                   (("m",), "i")], 5),
-    "decode": ("_decode_impl", [(("s",), "i"), (("s",), "i"), (("s",), "i"),
-                                (("s",), "i"), (("s", "m"), "i")], "s"),
+    "decode": ("_decode_impl", [(("s",), "i"), "state", (("s",), "i"),
+                                (("s",), "i"), (("s",), "i"),
+                                (("s", "m"), "i")], "s"),
 }
 
 
@@ -266,17 +282,21 @@ PROGRAMS = {
     ("decode", False), ("decode", True), ("verify-5", True)])
 def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
                                           paged, monkeypatch):
-    """No `copy` of the pool's size in any program, and no `convert` of a
-    weight: gpt2-large's resident tree holds what the forwards cast
-    (embeddings, kernels, biases) in bf16 and the layer norms in float32,
-    OLMoE's (16 KV heads of 128, 8 layers, 1,088 pages) is created in the
-    compute dtype. With the cast gone gpt2-large's temporaries are
-    activations only (the paged programs are gpt2-large's alone)."""
+    """No `copy` of the pool's size, or of a part of the recurrent state's,
+    in any program, and no `convert` of a weight: gpt2-large's resident
+    tree holds what the forwards cast (embeddings, kernels, biases) in bf16
+    and the layer norms in float32, OLMoE's (16 KV heads of 128, 8 layers,
+    1,088 pages) and the nemotron_h cut's (2 layers with K and V, 8 with
+    0.55 GB of state for 32 lanes) are created in the compute dtype. With
+    the cast gone gpt2-large's temporaries are activations only (the paged
+    programs are gpt2-large's alone)."""
     # kernels are chosen by `jax.default_backend()`: take the chip's side
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    model, runner, params, pool, cast = served_runner
+    model, runner, params, (pool, state), cast = served_runner
     if paged and model != "gpt2-large":
         pytest.skip("no cell serves this model through the paged kernel")
+    if program == "verify-5" and state:
+        pytest.skip("the engine refuses speculation for a stateful family")
     method, shapes, lanes = PROGRAMS[program]
     sizes = {"m": runner.max_blocks_per_seq, "p": MODELS[model][4],
              "s": runner.max_batch_size}
@@ -287,11 +307,12 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
             tuple(sizes.get(d, d) for d in shape),
             jnp.int32 if kind == "i" else jnp.float32, sharding=one_chip)
 
-    args = [arg(*s) for s in shapes] + [
+    args = [state if s == "state" else arg(*s) for s in shapes] + [
         arg((lanes,), "f"), arg((lanes,), "i"), arg((lanes,), "f"),
         arg((), "i")]
     runner.use_paged_attention = paged
-    compiled = jax.jit(getattr(runner, method), donate_argnums=(1, 2)) \
+    donate = (1, 2) if program == "verify-5" else (1, 2, 4)
+    compiled = jax.jit(getattr(runner, method), donate_argnums=donate) \
         .lower(params, pool, pool, *args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text or not paged
@@ -300,12 +321,19 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
         return [tuple(map(int, m.group(1).split(","))) for m in re.finditer(
             r"= \w+\[([\d,]+)\]\S* " + opcode + r"\(", text)]
 
+    # the pool, which the SSM part of the state is larger than
     pool_elements = math.prod(pool.shape)
+    assert all(math.prod(a.shape) >= pool_elements
+               for name, a in state.items() if name == "ssm")
     assert not [r for r in results("copy")
                 if math.prod(r) >= pool_elements]
     assert compiled.memory_analysis().temp_size_in_bytes < TEMP_BOUND
     # what the adapter cast, or the family creates in the compute dtype
-    weights = cast or [a for a in jax.tree.leaves(params) if a.ndim >= 2]
+    # (but nemotron_h's `conv_w`, a (4, 6144) filter applied in float32
+    # beside the state)
+    weights = cast or [
+        a for path, a in jax.tree_util.tree_leaves_with_path(params)
+        if a.ndim >= 2 and "conv_w" not in jax.tree_util.keystr(path)]
     assert weights and all(a.dtype == runner.cfg.dtype for a in weights)
     # a weight, a layer of a stack, or an expert of a layer
     held = {a.shape[i:] for a in weights for i in range(a.ndim - 1)}

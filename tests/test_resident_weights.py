@@ -74,17 +74,18 @@ def both_sides():
     def run(tree):
         out = {}
         ids, none = r.slot_tokens, i32(-1)  # the resident sampled ids
-        _, out["prefill"], k, v, ids, _ = jax.jit(r._prefill_impl)(
-            tree, r.k_pages, r.v_pages, ids,
+        st = r.state  # {}: gpt2 carries no recurrent state
+        _, out["prefill"], k, v, ids, st, _ = jax.jit(r._prefill_impl)(
+            tree, r.k_pages, r.v_pages, ids, st,
             np.arange(1, 9, dtype=i32)[None], i32(7), table[pos // 4],
             (pos % 4).astype(i32), none, *greedy, i32(1))
-        _, out["chunk"], k, v, ids, _ = jax.jit(r._chunk_impl)(
-            tree, k, v, ids, np.arange(9, 17, dtype=i32)[None], i32(8),
+        _, out["chunk"], k, v, ids, st, _ = jax.jit(r._chunk_impl)(
+            tree, k, v, ids, st, np.arange(9, 17, dtype=i32)[None], i32(8),
             i32(5), np.where(pos < 6, table[(8 + pos) // 4], 0).astype(i32),
             (pos % 4).astype(i32), table, none, *greedy, i32(2))
         tables = np.stack([table, np.zeros_like(table)])
-        _, out["decode"], k, v, ids, _ = jax.jit(r._decode_impl)(
-            tree, k, v, ids, np.asarray([5, 1], i32),
+        _, out["decode"], k, v, ids, st, _ = jax.jit(r._decode_impl)(
+            tree, k, v, ids, st, np.asarray([5, 1], i32),
             np.asarray([-1, -1], i32), np.asarray([14, 0], i32), tables,
             np.zeros(2, f32), np.zeros(2, i32), np.ones(2, f32), i32(3))
         out["pools"] = np.stack([np.asarray(k, f32), np.asarray(v, f32)])

@@ -426,3 +426,54 @@ def test_grpc_ingress_unary_and_streaming(cluster):
     channel.close()
     serve.delete("gapp")
     serve.delete("gstream")
+
+
+class _ConstructingReplica:
+    """Stands in for a replica handle: `alive` answers only after
+    `not_yet` pings, as an actor still inside a heavy __init__ does."""
+
+    def __init__(self, not_yet, error):
+        self.not_yet, self.error, self.pings = not_yet, error, 0
+        self.alive = self
+
+    def remote(self):
+        self.pings += 1
+        return self
+
+
+@pytest.mark.parametrize("error", ["GetTimeoutError",
+                                   "ActorUnavailableError"])
+def test_readiness_barrier_waits_for_a_constructing_replica(
+        monkeypatch, error):
+    """A replica that compiles for minutes is 'not yet', not 'failed':
+    the barrier pings until its own deadline, which no caller shortens,
+    and `serve.run` waits past it (an 18-block stack's cold warm-up took
+    143-185 s on a v5e host against a barrier of 180 s: PERF.md, PR 32)."""
+    import inspect
+    import time
+
+    from ray_tpu.core import exceptions as exc
+    from ray_tpu.serve import api
+
+    now = [0.0]
+    monkeypatch.setattr(time, "monotonic", lambda: now[0])
+    monkeypatch.setattr(time, "sleep",
+                        lambda s: now.__setitem__(0, now[0] + s))
+
+    def get(ref, timeout=None):
+        if ref.pings <= ref.not_yet:
+            now[0] += 30.0
+            raise getattr(exc, ref.error)("still constructing")
+        return True
+
+    monkeypatch.setattr(ray_tpu, "get", get)
+    slow = _ConstructingReplica(9, error)  # 9 x (30 + 1) s = 279 s
+    api._wait_replicas_ready([slow])
+    assert slow.pings == 10 and now[0] > 180.0
+    now[0] = 0.0
+    never = _ConstructingReplica(10 ** 6, error)
+    with pytest.raises(exc.ActorUnavailableError):
+        api._wait_replicas_ready([never])
+    assert now[0] >= api.REPLICA_READY_TIMEOUT_S >= 600.0
+    assert inspect.signature(api._wait_replicas_ready).parameters[
+        "timeout"].default == api.REPLICA_READY_TIMEOUT_S
