@@ -27,6 +27,7 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.parallel.sharding import PartitionRules, constrain
 from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.context_attention import attend_cached, causal_rows
 
 Params = Any
 
@@ -283,10 +284,11 @@ def gpt2_loss(params: Params, batch: dict, cfg: GPT2Config) -> jax.Array:
 # KV-cache inference steps (serve.llm). Prefill runs the full-sequence
 # forward and additionally returns every layer's K/V heads; decode runs
 # ONE token per sequence against cached context K/V that each layer
-# fetches with ``read_ctx(layer) -> (k_ctx, v_ctx)`` inside the layer scan
-# (the page pool, its layout and the scatter of new rows belong to
-# ray_tpu/serve/llm — the model layer only owns the math, so parity with
-# the training forward is checkable function-against-function).
+# reads from ``ctx`` inside the layer scan, in tiles and to the lane's
+# length (ops/context_attention.py; the page pool, its layout and the
+# scatter of new rows belong to ray_tpu/serve/llm — the model layer only
+# owns the math, so parity with the training forward is checkable
+# function-against-function).
 
 
 def gpt2_prefill_kv(
@@ -310,21 +312,14 @@ def gpt2_prefill_kv(
     return logits.astype(jnp.float32), k, v
 
 
-def _chunk_block(x, p, k_ctx, v_ctx, ctx_mask, chunk_mask, cfg: GPT2Config,
-                 attend=None):
+def _chunk_block(x, p, attend, cfg: GPT2Config):
     """Chunked-prefill block step. x (B, T, E) holds a CHUNK of the
-    sequence at absolute positions start..start+T-1; k_ctx/v_ctx
-    (B, C, H, D) hold the already-cached context for positions < start
-    (ctx_mask (B, C) marks valid slots); chunk_mask (B, T) marks real
-    (non-padded) chunk positions. Attention is context + causal within
-    the chunk. Returns (x, (k, v)) with k/v (B, T, H, D) — the chunk's
-    cache contribution.
-
-    With ``attend`` set (paged-attention path) the dense context math
-    is replaced by ``attend(q, k, v) -> (B, T, H, D)``: k_ctx/v_ctx are
-    then this layer's page-pool arrays captured by the closure and the
-    masking lives inside the kernel; projections/MLP stay shared with
-    the dense path."""
+    sequence at absolute positions start..start+T-1. Attention is the
+    cached context + causal within the chunk, and is the caller's:
+    ``attend(q, k, v) -> (B, T, H, D)`` (the dense programs' tiled read,
+    `_attend_cached`, or the paged-attention kernel); projections/MLP are
+    shared. Returns (x, (k, v)) with k/v (B, T, H, D) — the chunk's cache
+    contribution."""
     B, T, E = x.shape
     dt = cfg.dtype
     H, D = cfg.n_head, cfg.head_dim
@@ -333,24 +328,7 @@ def _chunk_block(x, p, k_ctx, v_ctx, ctx_mask, chunk_mask, cfg: GPT2Config,
     qkv = constrain(qkv, ("data", "fsdp"), None, "tensor")
     q, k, v = (t.reshape(B, T, H, D) for t in jnp.split(qkv, 3, axis=-1))
 
-    if attend is not None:
-        att = attend(q, k, v).reshape(B, T, E)
-    else:
-        scale = 1.0 / (D**0.5)
-        s_ctx = jnp.einsum("bthd,bchd->bhtc", q, k_ctx).astype(jnp.float32)
-        s_own = jnp.einsum("bthd,bshd->bhts", q, k).astype(jnp.float32)
-        s = jnp.concatenate([s_ctx, s_own], axis=-1) * scale
-        causal = jnp.tril(jnp.ones((T, T), dtype=bool))
-        valid = jnp.concatenate(
-            [jnp.broadcast_to(ctx_mask[:, None, :],
-                              (B, T, ctx_mask.shape[1])),
-             causal[None] & chunk_mask[:, None, :]], axis=-1)
-        s = jnp.where(valid[:, None, :, :], s, -1e30)
-        probs = jax.nn.softmax(s, axis=-1).astype(dt)
-        C = k_ctx.shape[1]
-        att = jnp.einsum("bhtc,bchd->bthd", probs[..., :C], v_ctx) \
-            + jnp.einsum("bhts,bshd->bthd", probs[..., C:], v)
-        att = att.reshape(B, T, E)
+    att = attend(q, k, v).reshape(B, T, E)
     att = att @ p["attn_proj"]["kernel"].astype(dt) + p["attn_proj"]["bias"].astype(dt)
     x = x + constrain(att, ("data", "fsdp"), None, None)
 
@@ -363,12 +341,22 @@ def _chunk_block(x, p, k_ctx, v_ctx, ctx_mask, chunk_mask, cfg: GPT2Config,
     return x, (k, v)
 
 
+def _attend_cached(ctx, layer, own_valid, cfg: GPT2Config):
+    """``attend(q, k, v)`` of the dense programs: q, k, v (B, T, H, D)
+    against lane b's cached context ``ctx`` of layer `layer` (read in
+    tiles, to the lane's length: ops/context_attention.py) and the
+    program's own rows where `own_valid` (B, T, T) allows."""
+    def attend(q, k, v):
+        return attend_cached(q[:, :, :, None], k, v, own_valid, ctx, layer,
+                             cfg.dtype)[:, :, :, 0]
+    return attend
+
+
 def gpt2_prefill_chunk_kv(
     params: Params,
     tokens: jax.Array,
     start: jax.Array,
-    read_ctx,
-    ctx_mask: jax.Array,
+    ctx,
     chunk_mask: jax.Array,
     cfg: GPT2Config,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -377,12 +365,12 @@ def gpt2_prefill_chunk_kv(
 
     tokens (B, T) sit at absolute positions start..start+T-1 (start is
     a traced scalar, so one compiled program serves every offset);
-    ``read_ctx(layer)`` gives that layer's k_ctx/v_ctx (B, C, H, D), the
-    cached context for positions < start (called inside the layer scan:
-    one layer's context exists at a time), ctx_mask (B, C) marks its
-    valid slots and chunk_mask (B, T) the chunk's real tokens. Returns
-    (logits (B, T, Vp) f32, k, v (L, B, T, H, D)) — the caller scatters
-    k/v into the paged cache at the chunk's positions.
+    ``ctx`` (an ops/context_attention.py `CachedContext`) is the cached
+    context for positions < start, read layer by layer inside the layer
+    scan (one layer's context exists at a time) and only as far as
+    ``ctx.lengths`` reach; chunk_mask (B, T) marks the chunk's real
+    tokens. Returns (logits (B, T, Vp) f32, k, v (L, B, T, H, D)) — the
+    caller scatters k/v into the paged cache at the chunk's positions.
     """
     B, T = tokens.shape
     dt = cfg.dtype
@@ -396,10 +384,12 @@ def gpt2_prefill_chunk_kv(
     x = wte[tokens] + params["wpe"].astype(dt)[pos]
     x = constrain(x, ("data", "fsdp"), None, None)
 
+    own_valid = causal_rows(chunk_mask)
+
     def body(carry, xs):
         p, layer = xs
-        kc, vc = read_ctx(layer)
-        return _chunk_block(carry, p, kc, vc, ctx_mask, chunk_mask, cfg)
+        return _chunk_block(carry, p,
+                            _attend_cached(ctx, layer, own_valid, cfg), cfg)
 
     x, (k, v) = jax.lax.scan(
         body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
@@ -409,13 +399,11 @@ def gpt2_prefill_chunk_kv(
     return logits.astype(jnp.float32), k, v
 
 
-def _decode_block(x, p, k_ctx, v_ctx, ctx_mask, cfg: GPT2Config,
-                  attend=None):
-    """Single-token block step. x (B, E); k_ctx/v_ctx (B, C, H, D) hold
-    the sequence's cached context (padded; ctx_mask (B, C) marks valid
-    slots). Returns (x, (k_new, v_new)) with k_new/v_new (B, H, D).
-    ``attend(q, k, v) -> (B, H, D)`` swaps in the paged-attention
-    kernel (see `_chunk_block`)."""
+def _decode_block(x, p, attend, cfg: GPT2Config):
+    """Single-token block step. x (B, E); ``attend(q, k, v) -> (B, 1, H,
+    D)`` on q, k, v (B, 1, H, D) is the cached context + the token itself
+    (see `_chunk_block`). Returns (x, (k_new, v_new)) with k_new/v_new
+    (B, H, D)."""
     B, E = x.shape
     dt = cfg.dtype
     H, D = cfg.n_head, cfg.head_dim
@@ -424,21 +412,7 @@ def _decode_block(x, p, k_ctx, v_ctx, ctx_mask, cfg: GPT2Config,
     qkv = constrain(qkv, ("data", "fsdp"), "tensor")
     q, k, v = (t.reshape(B, H, D) for t in jnp.split(qkv, 3, axis=-1))
 
-    if attend is not None:
-        att = attend(q, k, v).reshape(B, E)
-    else:
-        scale = 1.0 / (D**0.5)
-        # context scores + the token's own (diagonal) score, f32 softmax
-        s_ctx = jnp.einsum("bhd,bchd->bhc", q, k_ctx).astype(jnp.float32)
-        s_own = jnp.sum(q * k, axis=-1, dtype=jnp.float32)
-        s = jnp.concatenate([s_ctx, s_own[:, :, None]], axis=-1) * scale
-        valid = jnp.concatenate(
-            [ctx_mask, jnp.ones((B, 1), dtype=bool)], axis=-1)
-        s = jnp.where(valid[:, None, :], s, -1e30)
-        probs = jax.nn.softmax(s, axis=-1).astype(dt)
-        att = jnp.einsum("bhc,bchd->bhd", probs[..., :-1], v_ctx) \
-            + probs[..., -1:] * v
-        att = att.reshape(B, E)
+    att = attend(q[:, None], k[:, None], v[:, None]).reshape(B, E)
     att = att @ p["attn_proj"]["kernel"].astype(dt) + p["attn_proj"]["bias"].astype(dt)
     x = x + constrain(att, ("data", "fsdp"), None)
 
@@ -455,25 +429,25 @@ def gpt2_decode_kv(
     params: Params,
     tokens: jax.Array,
     positions: jax.Array,
-    read_ctx,
-    ctx_mask: jax.Array,
+    ctx,
     cfg: GPT2Config,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One decode step for a batch of sequences.
 
-    tokens/positions (B,) i32; ``read_ctx(layer)`` gives that layer's
-    k_ctx/v_ctx (B, C, H, D) cached context (see
-    gpt2_prefill_chunk_kv); ctx_mask (B, C). Returns (logits (B, Vp) f32,
-    k_new, v_new (L, B, H, D)) — the caller scatters k_new/v_new into
-    the cache at each sequence's current position.
+    tokens/positions (B,) i32; ``ctx`` is the lanes' cached context,
+    ``ctx.lengths`` their positions (see gpt2_prefill_chunk_kv). Returns
+    (logits (B, Vp) f32, k_new, v_new (L, B, H, D)) — the caller scatters
+    k_new/v_new into the cache at each sequence's current position.
     """
     dt = cfg.dtype
     x = params["wte"].astype(dt)[tokens] + params["wpe"].astype(dt)[positions]
 
+    own_valid = jnp.ones((tokens.shape[0], 1, 1), dtype=bool)
+
     def body(carry, xs):
         p, layer = xs
-        kc, vc = read_ctx(layer)
-        return _decode_block(carry, p, kc, vc, ctx_mask, cfg)
+        return _decode_block(carry, p,
+                             _attend_cached(ctx, layer, own_valid, cfg), cfg)
 
     x, (k_new, v_new) = jax.lax.scan(
         body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
@@ -516,14 +490,11 @@ def gpt2_decode_paged_kv(
         p, layer = xs
 
         def attend(q, k, v):
-            o = paged_attention(q[:, None], k[:, None], v[:, None],
-                                k_pages, v_pages, tables, positions,
-                                layout=layout, layer=layer,
-                                interpret=interpret)
-            return o[:, 0]
+            return paged_attention(q, k, v, k_pages, v_pages, tables,
+                                   positions, layout=layout, layer=layer,
+                                   interpret=interpret)
 
-        return _decode_block(carry, p, None, None, None, cfg,
-                             attend=attend)
+        return _decode_block(carry, p, attend, cfg)
 
     x, (k_new, v_new) = jax.lax.scan(
         body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
@@ -567,8 +538,7 @@ def gpt2_verify_paged_kv(
                                    ctx_len, layout=layout, layer=layer,
                                    interpret=interpret)
 
-        return _chunk_block(carry, p, None, None, None, None, cfg,
-                            attend=attend)
+        return _chunk_block(carry, p, attend, cfg)
 
     x, (k, v) = jax.lax.scan(
         body, x, (params["blocks"], jnp.arange(cfg.n_layer)))

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.moe import routed_experts
 from ray_tpu.ops.attention import causal_attention
+from ray_tpu.ops.context_attention import attend_cached, causal_rows
 from ray_tpu.parallel.sharding import PartitionRules, constrain
 
 Params = Any
@@ -363,77 +364,65 @@ def _rope_chunk(x, start, theta: float):
     return rotated.astype(x.dtype)
 
 
-def _chunk_block(x, p, k_ctx, v_ctx, ctx_mask, chunk_mask, start,
-                 cfg: LlamaConfig, attend=None):
+def _attend_cached(ctx, layer, own_valid, cfg: LlamaConfig):
+    """``attend(q, k, v)`` of the dense programs: q (B, T, H, D), k and v
+    (B, T, Hkv, D) post-rope, against lane b's cached context ``ctx`` of
+    layer `layer` (read in tiles, to the lane's length:
+    ops/context_attention.py) and the program's own rows where
+    `own_valid` (B, T, T) allows. Query head h reads KV head ``h //
+    (H // Hkv)``; K and V are never repeated."""
+    def attend(q, k, v):
+        B, T, H, D = q.shape
+        HK = cfg.n_kv_head
+        return attend_cached(q.reshape(B, T, HK, H // HK, D), k, v,
+                             own_valid, ctx, layer, cfg.dtype
+                             ).reshape(B, T, H, D)
+    return attend
+
+
+def _chunk_block(x, p, attend, start, cfg: LlamaConfig):
     """Chunked-prefill block step; see models/gpt2.py `_chunk_block`.
-    x (B, T, E) at absolute positions start..start+T-1; k_ctx/v_ctx
-    (B, C, Hkv, D) post-rope cached context. Returns (x, (k, v)) with
-    k/v (B, T, Hkv, D) post-rope, pre-GQA-replication — the cached
-    layout. ``attend(q, k, v) -> (B, T, H, D)`` (k/v pre-replication)
-    swaps in the paged-attention kernel, which does the GQA head
-    mapping itself."""
+    x (B, T, E) at absolute positions start..start+T-1. Returns (x, (k,
+    v)) with k/v (B, T, Hkv, D) post-rope, pre-GQA-replication — the
+    cached layout. ``attend(q, k, v) -> (B, T, H, D)`` (k/v
+    pre-replication) is the cached context + causal within the chunk:
+    `_attend_cached`, or the paged-attention kernel; both do the GQA head
+    mapping themselves."""
     B, T, E = x.shape
     dt = cfg.dtype
-    hd = cfg.head_dim
-    H, HK = cfg.n_head, cfg.n_kv_head
 
     q, k, v = _qkv(_rmsnorm(x, p["ln_attn"], cfg.rms_eps), p, cfg)
     q = _rope_chunk(q, start, cfg.rope_theta)
     k = _rope_chunk(k, start, cfg.rope_theta)
-    k_cache, v_cache = k, v
 
-    if attend is not None:
-        att = attend(q, k, v).reshape(B, T, E) @ p["wo"].astype(dt)
-    else:
-        rep = H // HK
-        kce = jnp.repeat(k_ctx, rep, axis=2)
-        vce = jnp.repeat(v_ctx, rep, axis=2)
-        ke = jnp.repeat(k, rep, axis=2)
-        ve = jnp.repeat(v, rep, axis=2)
-
-        scale = 1.0 / (hd**0.5)
-        s_ctx = jnp.einsum("bthd,bchd->bhtc", q, kce).astype(jnp.float32)
-        s_own = jnp.einsum("bthd,bshd->bhts", q, ke).astype(jnp.float32)
-        s = jnp.concatenate([s_ctx, s_own], axis=-1) * scale
-        causal = jnp.tril(jnp.ones((T, T), dtype=bool))
-        valid = jnp.concatenate(
-            [jnp.broadcast_to(ctx_mask[:, None, :],
-                              (B, T, ctx_mask.shape[1])),
-             causal[None] & chunk_mask[:, None, :]], axis=-1)
-        s = jnp.where(valid[:, None, :, :], s, -1e30)
-        probs = jax.nn.softmax(s, axis=-1).astype(dt)
-        C = k_ctx.shape[1]
-        att = jnp.einsum("bhtc,bchd->bthd", probs[..., :C], vce) \
-            + jnp.einsum("bhts,bshd->bthd", probs[..., C:], ve)
-        att = att.reshape(B, T, E) @ p["wo"].astype(dt)
+    att = attend(q, k, v).reshape(B, T, E) @ p["wo"].astype(dt)
     x = x + constrain(att, ("data", "fsdp"), None, None)
 
     y, counts = _ffn(_rmsnorm(x, p["ln_mlp"], cfg.rms_eps), p, cfg)
-    return x + y, (k_cache, v_cache, counts)
+    return x + y, (k, v, counts)
 
 
 def llama_prefill_chunk_kv(
     params: Params,
     tokens: jax.Array,
     start: jax.Array,
-    read_ctx,
-    ctx_mask: jax.Array,
+    ctx,
     chunk_mask: jax.Array,
     cfg: LlamaConfig,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Chunked prefill from a position offset; see gpt2_prefill_chunk_kv.
-    ``read_ctx(layer)`` gives k_ctx/v_ctx (B, C, Hkv, D); returns
+    ``ctx`` is the cached context (rows (Hkv, D), post-rope); returns
     (logits (B, T, Vp) f32, k, v (L, B, T, Hkv, D))."""
     dt = cfg.dtype
     wte = constrain(params["wte"].astype(dt), None, None)
     x = wte[tokens]
     x = constrain(x, ("data", "fsdp"), None, None)
+    own_valid = causal_rows(chunk_mask)
 
     def body(carry, xs):
         p, layer = xs
-        kc, vc = read_ctx(layer)
-        return _chunk_block(carry, p, kc, vc, ctx_mask, chunk_mask,
-                            start, cfg)
+        return _chunk_block(
+            carry, p, _attend_cached(ctx, layer, own_valid, cfg), start, cfg)
 
     x, (k, v, counts) = jax.lax.scan(
         body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
@@ -442,42 +431,20 @@ def llama_prefill_chunk_kv(
     return (logits, k, v) + _aux(counts)
 
 
-def _decode_block(x, p, k_ctx, v_ctx, ctx_mask, positions, cfg: LlamaConfig,
-                  attend=None):
-    """Single-token block step; x (B, E), k_ctx/v_ctx (B, C, Hkv, D)
-    post-rope cached context, ctx_mask (B, C), positions (B,).
-    Returns (x, (k_new, v_new)) with k_new/v_new (B, Hkv, D).
-    ``attend(q, k, v) -> (B, H, D)`` (k/v pre-replication) swaps in the
-    paged-attention kernel (see `_chunk_block`)."""
+def _decode_block(x, p, attend, positions, cfg: LlamaConfig):
+    """Single-token block step; x (B, E), positions (B,). Returns (x,
+    (k_new, v_new)) with k_new/v_new (B, Hkv, D). ``attend(q, k, v) ->
+    (B, 1, H, D)`` on q (B, 1, H, D), k/v (B, 1, Hkv, D) is the cached
+    context + the token itself (see `_chunk_block`)."""
     B, E = x.shape
     dt = cfg.dtype
-    hd = cfg.head_dim
-    H, HK = cfg.n_head, cfg.n_kv_head
 
     q, k, v = _qkv(_rmsnorm(x, p["ln_attn"], cfg.rms_eps), p, cfg)
     q = _rope_at(q, positions, cfg.rope_theta)
     k = _rope_at(k, positions, cfg.rope_theta)
 
-    if attend is not None:
-        att = attend(q, k, v).reshape(B, E) @ p["wo"].astype(dt)
-    else:
-        rep = H // HK
-        kce = jnp.repeat(k_ctx, rep, axis=2)
-        vce = jnp.repeat(v_ctx, rep, axis=2)
-        ke = jnp.repeat(k, rep, axis=1)
-        ve = jnp.repeat(v, rep, axis=1)
-
-        scale = 1.0 / (hd**0.5)
-        s_ctx = jnp.einsum("bhd,bchd->bhc", q, kce).astype(jnp.float32)
-        s_own = jnp.sum(q * ke, axis=-1, dtype=jnp.float32)
-        s = jnp.concatenate([s_ctx, s_own[:, :, None]], axis=-1) * scale
-        valid = jnp.concatenate(
-            [ctx_mask, jnp.ones((B, 1), dtype=bool)], axis=-1)
-        s = jnp.where(valid[:, None, :], s, -1e30)
-        probs = jax.nn.softmax(s, axis=-1).astype(dt)
-        att = jnp.einsum("bhc,bchd->bhd", probs[..., :-1], vce) \
-            + probs[..., -1:] * ve
-        att = att.reshape(B, E) @ p["wo"].astype(dt)
+    att = attend(q[:, None], k[:, None], v[:, None]).reshape(B, E) \
+        @ p["wo"].astype(dt)
     x = x + constrain(att, ("data", "fsdp"), None)
 
     y, counts = _ffn(_rmsnorm(x, p["ln_mlp"], cfg.rms_eps), p, cfg)
@@ -488,20 +455,20 @@ def llama_decode_kv(
     params: Params,
     tokens: jax.Array,
     positions: jax.Array,
-    read_ctx,
-    ctx_mask: jax.Array,
+    ctx,
     cfg: LlamaConfig,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One decode step; see gpt2_decode_kv. ``read_ctx(layer)`` gives
-    k_ctx/v_ctx (B, C, Hkv, D); returns (logits (B, Vp) f32, k_new,
-    v_new (L, B, Hkv, D))."""
+    """One decode step; see gpt2_decode_kv. ``ctx`` is the lanes' cached
+    context; returns (logits (B, Vp) f32, k_new, v_new (L, B, Hkv, D))."""
     dt = cfg.dtype
     x = params["wte"].astype(dt)[tokens]
+    own_valid = jnp.ones((tokens.shape[0], 1, 1), dtype=bool)
 
     def body(carry, xs):
         p, layer = xs
-        kc, vc = read_ctx(layer)
-        return _decode_block(carry, p, kc, vc, ctx_mask, positions, cfg)
+        return _decode_block(
+            carry, p, _attend_cached(ctx, layer, own_valid, cfg), positions,
+            cfg)
 
     x, (k_new, v_new, counts) = jax.lax.scan(
         body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
@@ -539,14 +506,11 @@ def llama_decode_paged_kv(
         p, layer = xs
 
         def attend(q, k, v):
-            o = paged_attention(q[:, None], k[:, None], v[:, None],
-                                k_pages, v_pages, tables, positions,
-                                layout=layout, layer=layer,
-                                interpret=interpret)
-            return o[:, 0]
+            return paged_attention(q, k, v, k_pages, v_pages, tables,
+                                   positions, layout=layout, layer=layer,
+                                   interpret=interpret)
 
-        return _decode_block(carry, p, None, None, None, positions,
-                             cfg, attend=attend)
+        return _decode_block(carry, p, attend, positions, cfg)
 
     x, (k_new, v_new, counts) = jax.lax.scan(
         body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
@@ -583,8 +547,7 @@ def llama_verify_paged_kv(
                                    ctx_len, layout=layout, layer=layer,
                                    interpret=interpret)
 
-        return _chunk_block(carry, p, None, None, None, None, start,
-                            cfg, attend=attend)
+        return _chunk_block(carry, p, attend, start, cfg)
 
     x, (k, v, counts) = jax.lax.scan(
         body, x, (params["blocks"], jnp.arange(cfg.n_layer)))

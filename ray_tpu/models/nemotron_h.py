@@ -50,6 +50,11 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.moe import routed_experts
+from ray_tpu.ops.context_attention import (
+    attend_cached,
+    causal_rows,
+    softmax_over,
+)
 from ray_tpu.parallel.sharding import PartitionRules
 
 Params = Any
@@ -466,25 +471,22 @@ def _qkv(h, p, cfg: NemotronHConfig):
 
 def _attend(q, segments, p, cfg: NemotronHConfig):
     """q (B, T, HK, R, hd) against the rows of every segment ``(keys,
-    values (B, S, HK, hd), valid (B, T, S))`` (the cached context, the
-    program's own rows) under one softmax in float32 -> (B, T, D) after
-    `wo`. The segments' scores are joined, never their rows (the gathered
-    context is not copied again), and K and V are never repeated R
-    times: the heads of a group share them in the product."""
+    values (B, S, HK, hd), valid (B, T, S))`` under one softmax in
+    float32 -> (B, T, D) after `wo`. K and V are never repeated R times:
+    the heads of a group share them in the product."""
     B, T = q.shape[:2]
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    s = jnp.concatenate(
-        [jnp.where(valid[:, None, None],
-                   jnp.einsum("btgrd,bsgd->bgrts", q, keys)
-                   .astype(jnp.float32) * scale, -1e30)
-         for keys, _, valid in segments], axis=-1)
-    probs = jax.nn.softmax(s, axis=-1).astype(cfg.dtype)
-    att, at = 0, 0
-    for _, values, valid in segments:
-        n = valid.shape[-1]
-        att = att + jnp.einsum("bgrts,bsgd->btgrd", probs[..., at:at + n],
-                               values)
-        at += n
+    att = softmax_over(q, segments, 1.0 / math.sqrt(cfg.head_dim), cfg.dtype)
+    return att.reshape(B, T, -1) @ p["wo"].astype(cfg.dtype)
+
+
+def _attend_cached(q, k, v, own_valid, ctx, index: int, p,
+                   cfg: NemotronHConfig):
+    """As `_attend`, of the program's own rows k, v (B, T, HK, hd) where
+    `own_valid` (B, T, T) allows and the lanes' cached context ``ctx`` of
+    attention layer `index`, read in tiles and to the lane's length
+    (ops/context_attention.py)."""
+    B, T = q.shape[:2]
+    att = attend_cached(q, k, v, own_valid, ctx, index, cfg.dtype)
     return att.reshape(B, T, -1) @ p["wo"].astype(cfg.dtype)
 
 
@@ -566,33 +568,29 @@ def nemotron_h_prefill_kv(params: Params, tokens: jax.Array,
 
 
 def nemotron_h_prefill_chunk_kv(params: Params, tokens: jax.Array, start,
-                                read_ctx, ctx_mask, chunk_mask,
-                                cfg: NemotronHConfig, *, state, n_valid):
-    """A chunk at positions start..start+T-1: ``read_ctx(i)`` gives the
-    i-th attention layer's cached k_ctx / v_ctx (1, C, HK, hd), the
+                                ctx, chunk_mask, cfg: NemotronHConfig, *,
+                                state, n_valid):
+    """A chunk at positions start..start+T-1: ``ctx`` holds the attention
+    layers' cached context (rows (HK, hd)) for positions < start, the
     Mamba layers start from the state the lane's last chunk left."""
-    T = tokens.shape[1]
-    own = jnp.tril(jnp.ones((T, T), bool))[None] & chunk_mask[:, None, :]
-    cached = jnp.broadcast_to(ctx_mask[:, None, :],
-                              (1, T, ctx_mask.shape[1]))
+    own = causal_rows(chunk_mask)
 
     def mamba(h, p, i):
         return _mamba_rows(h[0], p, cfg, state, i, n_valid)[None]
 
     def attention(h, p, i):
         q, k, v = _qkv(h, p, cfg)
-        kc, vc = read_ctx(i)
-        return _attend(q, [(kc, vc, cached), (k, v, own)], p, cfg), k, v
+        return _attend_cached(q, k, v, own, ctx, i, p, cfg), k, v
 
     x = params["wte"].astype(cfg.dtype)[tokens]
     return _stack(params, x, cfg, mamba, attention)
 
 
 def nemotron_h_decode_kv(params: Params, tokens: jax.Array, positions,
-                         read_ctx, ctx_mask, cfg: NemotronHConfig, *,
-                         state):
-    """One token a lane: tokens (B,) -> (logits (B, Vp) f32, k_new, v_new
-    (n_kv_layers, B, HK, hd), pairs)."""
+                         ctx, cfg: NemotronHConfig, *, state):
+    """One token a lane: tokens (B,), against the lanes' cached context
+    ``ctx`` -> (logits (B, Vp) f32, k_new, v_new (n_kv_layers, B, HK,
+    hd), pairs)."""
     B = tokens.shape[0]
     own = jnp.ones((B, 1, 1), bool)
 
@@ -601,9 +599,8 @@ def nemotron_h_decode_kv(params: Params, tokens: jax.Array, positions,
 
     def attention(h, p, i):
         q, k, v = _qkv(h, p, cfg)
-        kc, vc = read_ctx(i)
-        out = _attend(q[:, None], [(kc, vc, ctx_mask[:, None]),
-                                   (k[:, None], v[:, None], own)], p, cfg)
+        out = _attend_cached(q[:, None], k[:, None], v[:, None], own, ctx,
+                             i, p, cfg)
         return out[:, 0], k, v
 
     x = params["wte"].astype(cfg.dtype)[tokens]

@@ -1,0 +1,172 @@
+"""Attention over a lane's cached context, read to the lane's length.
+
+The dense serve programs (decode, chunk, verify of every family) attend a
+program's query rows to two segments under one softmax: the lane's cached
+context, which lives in the KV page pool, and the program's own rows,
+which are scattered into the pool only after the step. This module is the
+one place that does it, for all families: they bring q grouped by KV head
+(``R`` query heads a KV head: 1 for gpt2, ``n_head // n_kv_head`` for
+llama and nemotron_h), their own K and V rows, which own rows a query row
+may see, and a `CachedContext`.
+
+The context is read in **tiles** of whole pages (`KVLayout.tile_pages`),
+and only the tiles that the lanes reach. The lanes of a program form
+groups of `CachedContext.group` consecutive rows, and a group reads
+``ceil(longest length in the group / tile)`` tiles: a count traced from
+the lengths, the same in every layer. The runner orders a decode step's
+lanes by length, longest first, so a group's lanes are alike and the rows
+that reach a tile are the program's first rows: tile t is read once, for
+all of them together (`attend_cached`), under a running softmax (max, sum
+and accumulator in float32). A slot past a lane's length had the weight
+``exp(-1e30 - m) = 0`` when all ``max_model_len`` slots were read and
+masked; not reading it changes no term of the softmax, only the order of
+a float32 sum.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+
+@dataclasses.dataclass(frozen=True)
+class CachedContext:
+    """The cached context of a program's lanes, as the runner hands it to
+    a family's forward: the pools, the lanes' block tables and how many
+    cached slots of each lane are real."""
+
+    layout: Any  # serve/llm/cache.py KVLayout
+    k_pages: jax.Array
+    v_pages: jax.Array
+    tables: jax.Array  # (B, pages a lane) i32, whole tiles
+    lengths: jax.Array  # (B,) i32: slots [0, lengths[b]) hold lane b's rows
+    group: int  # lanes a group: consecutive rows, B a multiple of it
+    # (B // group,) i32: the tiles that group g, or any group behind it,
+    # reaches (for lanes ordered longest first: the tiles of group g)
+    reach: jax.Array
+
+    @classmethod
+    def of(cls, layout, k_pages, v_pages, tables, lengths, group: int = 1):
+        """`tables` (B, n) padded with the null page to whole tiles, and
+        each group's tile count, worked out once a program."""
+        per = layout.tile_pages
+        pad = -tables.shape[1] % per
+        if pad:
+            tables = jnp.pad(tables, ((0, 0), (0, pad)))
+        tile = per * layout.block_size
+        longest = jnp.max(lengths.reshape(-1, group), axis=1)
+        return cls(layout, k_pages, v_pages, tables, lengths, group,
+                   jax.lax.cummax((longest + tile - 1) // tile, reverse=True))
+
+    def read(self, layer, tables):
+        """Layer `layer`'s keys and values through `tables` (G, n)."""
+        return (self.layout.read(self.k_pages, layer, tables),
+                self.layout.read(self.v_pages, layer, tables))
+
+
+def causal_rows(chunk_mask):
+    """`own_valid` (B, T, T) of a chunk or a window: row t sees its own
+    rows up to t, of those `chunk_mask` (B, T) marks as real."""
+    T = chunk_mask.shape[1]
+    return jnp.tril(jnp.ones((T, T), dtype=bool))[None] \
+        & chunk_mask[:, None, :]
+
+
+def _scores(q, keys, valid, scale):
+    """q (G, T, HK, R, D) against keys (G, S, HK, D) -> (G, HK, R, T, S)
+    float32, -1e30 where `valid` (G, T, S) is false."""
+    s = jnp.einsum("btgrd,bsgd->bgrts", q, keys).astype(jnp.float32)
+    return jnp.where(valid[:, None, None], s * scale, -1e30)
+
+
+def softmax_over(q, segments, scale, dtype):
+    """One softmax, in float32, over the rows of every segment ``(keys,
+    values (G, S, HK, D), valid (G, T, S))``: every slot read and masked
+    (a whole prompt's own rows; the tests' full-width reference). The
+    segments' scores are joined, never their rows, and K and V are never
+    repeated R times: the query heads of a KV head share them in the
+    product."""
+    s = jnp.concatenate([_scores(q, keys, valid, scale)
+                         for keys, _, valid in segments], axis=-1)
+    probs = jax.nn.softmax(s, axis=-1).astype(dtype)
+    att, at = 0, 0
+    for _, values, valid in segments:
+        n = valid.shape[-1]
+        att = att + jnp.einsum("bgrts,bsgd->btgrd", probs[..., at:at + n],
+                               values)
+        at += n
+    return att
+
+
+def _weigh(p, values, dtype):
+    return jnp.einsum("bgrts,bsgd->btgrd", p.astype(dtype), values,
+                      preferred_element_type=jnp.float32)
+
+
+def _per_row(a):  # (G, HK, R, T) -> (G, T, HK, R, 1)
+    return jnp.transpose(a, (0, 3, 1, 2))[..., None]
+
+
+def _own_rows(q, k, v, own_valid, scale, dtype):
+    """The running softmax (max, sum, accumulator: float32) started on
+    the program's own rows, of which every query row sees at least one:
+    a masked slot's weight ``exp(-1e30 - m)`` is 0 from here on."""
+    s = _scores(q, k, own_valid, scale)
+    m = jnp.max(s, axis=-1)
+    p = jnp.exp(s - m[..., None])
+    return m, jnp.sum(p, axis=-1), _weigh(p, v, dtype)
+
+
+def _tile_step(ctx, layer, scale, dtype, q, tables, lengths):
+    """``step(t, carry)``: tile t of these lanes' context folded into
+    their running softmax."""
+    per = ctx.layout.tile_pages
+    tile = per * ctx.layout.block_size
+
+    def step(t, carry):
+        m, l, acc = carry
+        kc, vc = ctx.read(layer, jax.lax.dynamic_slice_in_dim(
+            tables, t * per, per, axis=1))
+        slot = t * tile + jnp.arange(tile)
+        valid = jnp.broadcast_to(
+            slot[None, None, :] < lengths[:, None, None],
+            (q.shape[0], q.shape[1], tile))
+        s = _scores(q, kc, valid, scale)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[..., None])
+        return (m_new, alpha * l + jnp.sum(p, axis=-1),
+                _per_row(alpha) * acc + _weigh(p, vc, dtype))
+
+    return step
+
+
+def attend_cached(q, k, v, own_valid, ctx: CachedContext, layer, dtype):
+    """q (B, T, HK, R, D) attends, under one softmax scaled by
+    ``1 / sqrt(D)``, to lane b's cached rows ``[0, ctx.lengths[b])`` of
+    layer `layer` and to the program's own rows k, v (B, T, HK, D) where
+    `own_valid` (B, T, T) allows -> (B, T, HK, R, D) in `dtype`.
+
+    Tile by tile: tile t is read for the rows of every group that reaches
+    it, which are the first rows of the program, whatever the lanes'
+    order (no group behind a group reaches further than `ctx.reach` says
+    of it). One loop a group, last group first: it runs the tiles that its
+    group reaches and the groups behind it did not, on all rows up to its
+    group's, and leaves its group's rows finished."""
+    B, G = q.shape[0], ctx.group
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    carry = _own_rows(q, k, v, own_valid, scale, dtype)
+    done, at = [], 0
+    for rows in range(B, 0, -G):  # the groups' ends, last group first
+        upto = ctx.reach[rows // G - 1]
+        m, l, acc = jax.lax.fori_loop(
+            at, upto, _tile_step(ctx, layer, scale, dtype, q[:rows],
+                                 ctx.tables[:rows], ctx.lengths[:rows]),
+            carry)
+        done.append((acc[rows - G:] / _per_row(l[rows - G:])).astype(dtype))
+        carry = (m[:rows - G], l[:rows - G], acc[:rows - G])
+        at = upto
+    return jnp.concatenate(done[::-1])

@@ -1,13 +1,15 @@
 """Paged attention — pallas TPU kernel over the serve.llm KV page pool.
 
-The dense decode/verify programs gather, layer by layer, each lane's
-pages into a ``(S, max_blocks_per_seq * block_size, H_kv, D)`` context
-before attending — O(max_model_len) HBM traffic per step regardless of
-how long the sequence actually is. This kernel is the
-vLLM-PagedAttention shape instead (PAPERS.md): queries index the page
-pool *in place* through the block table, one page per grid step, with
-the layer index, the table and the context lengths delivered via scalar
-prefetch so the page id is known before the page's DMA is issued.
+The dense decode/verify programs gather, layer by layer, a group of
+lanes' pages into a ``(lanes, tiles * tile, H_kv, D)`` context before
+attending: whole tiles of pages up to the group's longest lane
+(ops/context_attention.py), relaid out into heads on the way. This
+kernel is the vLLM-PagedAttention shape instead (PAPERS.md): queries
+index the page pool *in place* through the block table, one page per
+grid step (every page of the table is a step, pages past the lane's
+length skipped inside it), with the layer index, the table and the
+context lengths delivered via scalar prefetch so the page id is known
+before the page's DMA is issued.
 
 Operands:
 

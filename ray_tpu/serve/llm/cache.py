@@ -47,6 +47,15 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
 
+# Elements of K (or of V) in one tile of a lane's cached context, for each
+# layer that has keys and values: the unit in which the dense serve
+# programs read it (`KVLayout.tile_pages`, ops/context_attention.py). A
+# step of the read costs every such layer some twenty small operations
+# (1.5 us a step on the v5e, and an event each in a device trace) beside
+# the tile's own bytes, so a deeper stack takes larger tiles.
+TILE_ELEMENTS_A_LAYER = 32 * 1024
+
+
 class CacheExhausted(Exception):
     """Raised by alloc() when the pool cannot satisfy a request; the
     scheduler turns this into preemption, not an error."""
@@ -109,6 +118,17 @@ class KVLayout:
     @property
     def shape(self) -> tuple[int, int, int, int]:
         return (self.kv_layers, self.num_blocks, self.block_size, self.row)
+
+    @property
+    def tile_pages(self) -> int:
+        """Pages in one tile of a lane's context: the largest power of
+        two whose rows hold at most `TILE_ELEMENTS_A_LAYER` a layer of
+        the pool (32 pages, 512 slots, at gpt2-large's 36 layers of 1280;
+        8 pages at 8 layers of OLMoE's 2048-wide row; 16 pages at the
+        nemotron_h cut's 2 layers of 256)."""
+        pages = max(1, TILE_ELEMENTS_A_LAYER * self.kv_layers
+                    // (self.block_size * self.row))
+        return 1 << (pages.bit_length() - 1)
 
     def shard_ways(self, tensor_ways: int) -> int:
         """Over how many `tensor` shards the row splits: whole heads

@@ -253,6 +253,9 @@ class LLMEngine:
         self._steps = {"decode": 0, "prefill": 0}
         self._d2h = {"decode": 0, "prefill": 0}
         self._fetched_seen = 0
+        # the runner's context counts as the metric has seen them
+        self._ctx_seen = {kind: dict(n) for kind, n in
+                          self.runner.context_slots.items()}
         # steps launched and not yet read, oldest first: at most one
         # between two calls of step(), two inside one (the one being
         # read and the one behind it). Touched under _step_lock only
@@ -425,6 +428,12 @@ class LLMEngine:
             "Bytes of device results fetched to the host by engine "
             "steps (sampled tokens and logits), by step kind",
             tag_keys=("model", "kind"))
+        self._m_ctx = Counter(
+            "serve_llm_ctx_slots_total",
+            "Slots of cached context by kind of program: read as "
+            "launched (whole tiles, to the longest lane of a group), "
+            "valid (below a lane's length), full (every row to "
+            "max_model_len)", tag_keys=("model", "kind", "what"))
         # routed experts: what the programs report of their routing, by
         # step kind (a dense model's programs report nothing)
         moe_tags = ("model", "kind")
@@ -730,6 +739,14 @@ class LLMEngine:
         self._d2h[kind] += fetched
         self._m_d2h.inc(fetched,
                         tags={"model": self.config.model, "kind": kind})
+        for ctx_kind, now in self.runner.context_slots.items():
+            seen = self._ctx_seen[ctx_kind]
+            for what, n in now.items():
+                if n != seen[what]:
+                    self._m_ctx.inc(n - seen[what], tags={
+                        "model": self.config.model, "kind": ctx_kind,
+                        "what": what})
+                    seen[what] = n
         if self.runner.expert_pairs:  # never, for a dense model
             self._note_routing(kind, self.runner.take_expert_pairs())
 
@@ -1149,9 +1166,12 @@ class LLMEngine:
             wall = time.perf_counter() - t0
             spent = {k: v - before[k]
                      for k, v in tracing.compile_totals().items()}
-            # what warm-up fetched and routed is no step's
+            # what warm-up fetched, routed and read is no step's
             self._fetched_seen = self.runner.fetched_bytes
             self.runner.take_expert_pairs()
+            for kind, n in self.runner.context_slots.items():
+                n.update(dict.fromkeys(n, 0))
+                self._ctx_seen[kind] = dict(n)
         up = self._startup
         up["warmup"] += wall
         up["warmup_trace"] += spent["trace"]
@@ -1204,6 +1224,10 @@ class LLMEngine:
             "step_phase_seconds": dict(self.phases.seconds),
             "steps": dict(self._steps),
             "d2h_bytes": dict(self._d2h),
+            # slots of cached context by kind of program: read as
+            # launched, valid, and what a read to max_model_len would be
+            "context": {kind: dict(n) for kind, n in
+                        self.runner.context_slots.items()},
             # steps launched while the one before was unread, or not, by
             # kind; why not, by reason; sampled ids no stream got
             "overlap": {k: dict(v) if isinstance(v, dict) else v

@@ -21,6 +21,17 @@ to powers of two up to ``max_batch_size``; block tables are always
 padded to the fixed width ``max_blocks_per_seq``. Total programs =
 #length-buckets + #batch-buckets.
 
+The table's width is the program's shape, not what it reads: a lane's
+cached context is read in tiles of whole pages (`KVLayout.tile_pages`)
+and only as many as a small group of lanes reaches, a count the program
+takes from the lanes' positions (a chunk's `start`) at run time
+(ops/context_attention.py). A decode program's rows are the step's lanes
+by position, longest first, in groups of `lanes_per_group` consecutive
+rows, so that a short lane is not read to a long one's length; `collect`
+hands the results back in the caller's order. `context_slots` counts
+what was read, what of it was valid and what a read to
+``max_model_len`` would have been.
+
 Padded lanes/positions point at **page 0** (the pool's null sink), so
 every gather/scatter is in-bounds; the attention mask keeps null-page
 garbage out of the softmax.
@@ -57,6 +68,7 @@ import numpy as np
 
 from jax.sharding import NamedSharding, PartitionSpec
 
+from ray_tpu.ops.context_attention import CachedContext
 from ray_tpu.serve.llm.cache import KVLayout, StateLayout, StateView
 from ray_tpu.util import tracing
 
@@ -80,10 +92,10 @@ class ModelAdapter:
     # family with routed experts appends their pairs per layer and
     # expert, (L, n_experts) i32, which the programs hand on to the host
     prefill_fn: Callable
-    # read_ctx(layer) -> (k_ctx, v_ctx), that layer's cached context
-    decode_fn: Callable  # (params, toks, pos, read_ctx, mask, cfg) -> ...
-    # (params, toks, start, read_ctx, ctx_mask, chunk_mask, cfg) -> ...
-    chunk_fn: Callable
+    # ctx: the lanes' cached context (ops/context_attention.py
+    # CachedContext), which the layers read as far as the lanes reach
+    decode_fn: Callable  # (params, toks, pos, ctx, cfg) -> ...
+    chunk_fn: Callable  # (params, toks, start, ctx, chunk_mask, cfg) -> ...
     rules_fn: Callable  # () -> PartitionRules
     kv_heads: Callable[[Any], int]
     # how many layers HAVE keys and values: the pool's leading dimension
@@ -208,7 +220,18 @@ class Launched(NamedTuple):
 
     results: tuple  # (nxt, logits), on the device
     aux: tuple  # the family's extras (routed experts' pairs)
-    rows: int | None  # decode: the real lanes; None: one row, a scalar id
+    # decode: row j of the program is the caller's lane `order[j]` (the
+    # real lanes, longest first); None: one row, a scalar id
+    order: np.ndarray | None
+
+
+# What the programs read of their lanes' cached context, in slots, by
+# kind of program: `slots_read` as launched (tiles x tile x the lanes of
+# a group), `slots_valid` of them below a lane's length, `slots_full`
+# what reading every row to `max_model_len` would have (rows x slots a
+# table holds). The monolithic prefill program reads none.
+CONTEXT_KINDS = ("decode", "prefill", "verify")
+CONTEXT_COUNTS = ("slots_read", "slots_valid", "slots_full")
 
 
 def _ordered_bits(x):
@@ -377,6 +400,8 @@ class ModelRunner:
         # logits, every `np.asarray` / `int()` of a program's output)
         self.fetched_bytes = 0
         self.expert_pairs: list[np.ndarray] = []
+        self.context_slots = {kind: dict.fromkeys(CONTEXT_COUNTS, 0)
+                              for kind in CONTEXT_KINDS}
         # compile observability: warmup() should account for ALL misses;
         # a mid-stream miss afterwards is the recompile bug these catch
         from ray_tpu.util.metrics import Counter, Histogram
@@ -425,13 +450,29 @@ class ModelRunner:
         sampled = jax.random.categorical(key, logits / safe, axis=-1)
         return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
 
-    def _ctx_reader(self, k_pages, v_pages, tables):
-        """``read_ctx(layer) -> (k_ctx, v_ctx)``, each (B, C, HK, D), for
-        the dense forwards to call inside their layer scan; tables
-        (B, max_blocks_per_seq)."""
-        read = self.layout.read
-        return lambda layer: (read(k_pages, layer, tables),
-                              read(v_pages, layer, tables))
+    @staticmethod
+    def lanes_per_group(rows: int) -> int:
+        """Lanes that share one bound on their context read, in a decode
+        program of `rows` lanes: each lane its own up to 4 rows, then 2,
+        and 8 groups from 16 rows on. A group costs every layer one loop
+        (about 5 us on the v5e, run or not), a wider group reads its short
+        lanes to its longest: at 8 rows of gpt2-large and tiles of 128
+        slots, 2 a group is 0.7 ms of 5.1 under 1 a group where all lanes
+        are short and 0.1 ms over it where their lengths are spread; at 16
+        rows of OLMoE 2 and 4 are even and 1 is 0.2 ms behind (PERF.md,
+        PR 33)."""
+        return 1 if rows < 8 else max(2, rows // 8)
+
+    def _note_context(self, kind: str, lengths, group: int = 1) -> None:
+        """Count what a program launched on lanes of `lengths` (as the
+        program has them: ordered, padded) reads of their context."""
+        tile = self.layout.tile_pages * self.block_size
+        longest = np.max(np.reshape(lengths, (-1, group)), axis=1)
+        n = self.context_slots[kind]
+        n["slots_read"] += int(np.sum(-(-longest // tile)) * tile * group)
+        n["slots_valid"] += int(np.sum(lengths))
+        n["slots_full"] += (np.size(lengths) * self.max_blocks_per_seq
+                            * self.block_size)
 
     @staticmethod
     def _keep_sampled(slot_tokens, slots, nxt):
@@ -482,12 +523,11 @@ class ModelRunner:
         every offset. Recurrent state is carried chunk to chunk in the
         lane's slot, from zero where `start` is 0."""
         Tb = tokens.shape[1]
-        C = self.max_blocks_per_seq * self.block_size
-        ctx_mask = (jnp.arange(C)[None, :] < start)  # (1, C)
         chunk_mask = (jnp.arange(Tb)[None, :] <= last_idx)  # (1, Tb)
         (logits, k, v, *aux), state = self._forward(
             self.adapter.chunk_fn, state, slot, params, tokens, start,
-            self._ctx_reader(k_pages, v_pages, table[None]), ctx_mask,
+            CachedContext.of(self.layout, k_pages, v_pages, table[None],
+                             start[None]),
             chunk_mask, self.cfg, fresh=start == 0, n_valid=last_idx + 1)
         k_pages = self.layout.write(k_pages, block_ids, offsets, k[:, 0])
         v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
@@ -525,13 +565,12 @@ class ModelRunner:
                 params, tokens, start, self.layout, k_pages, v_pages,
                 table, self.cfg, interpret=self._interpret)
         else:
-            C = self.max_blocks_per_seq * self.block_size
-            ctx_mask = (jnp.arange(C)[None, :] < start)  # (1, C)
             chunk_mask = (jnp.arange(W)[None, :] <= n_draft)  # (1, W)
             logits, k, v, *aux = self.adapter.chunk_fn(
                 params, tokens, start,
-                self._ctx_reader(k_pages, v_pages, table[None]),
-                ctx_mask, chunk_mask, self.cfg)
+                CachedContext.of(self.layout, k_pages, v_pages, table[None],
+                                 start[None]),
+                chunk_mask, self.cfg)
         k_pages = self.layout.write(k_pages, block_ids, offsets, k[:, 0])
         v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
         lg = logits[0]  # (W, Vp)
@@ -549,10 +588,12 @@ class ModelRunner:
                      step):
         """tokens/slots/positions/temps (Sb,); tables (Sb,
         max_blocks_per_seq). Run the model's decode step, each layer
-        reading its dense context through the tables, scatter the new
-        K/V at each lane's position, sample. With paged attention the
-        gather disappears: the kernel indexes pages in place through the
-        block table. A lane whose token is -1 feeds the id an earlier
+        reading its lanes' context through the tables as far as the
+        longest lane of each group of `lanes_per_group` rows reaches (the
+        caller orders the rows by position), scatter the new K/V at each
+        lane's position, sample. With paged attention the gather
+        disappears: the kernel indexes pages in place through the block
+        table. A lane whose token is -1 feeds the id an earlier
         program left at its slot. Recurrent state moves one step in the
         slots of the step's lanes; a padded lane (slot -1) and a slot no
         lane owns keep theirs."""
@@ -564,12 +605,13 @@ class ModelRunner:
                 params, tokens, positions, self.layout, k_pages, v_pages,
                 tables, self.cfg, interpret=self._interpret)
         else:
-            C = self.max_blocks_per_seq * Bs
-            ctx_mask = jnp.arange(C)[None, :] < positions[:, None]
             (logits, k_new, v_new, *aux), state = self._forward(
                 self.adapter.decode_fn, state, slots, params, tokens,
-                positions, self._ctx_reader(k_pages, v_pages, tables),
-                ctx_mask, self.cfg)
+                positions,
+                CachedContext.of(self.layout, k_pages, v_pages, tables,
+                                 positions,
+                                 self.lanes_per_group(tokens.shape[0])),
+                self.cfg)
         block_ids = jnp.take_along_axis(
             tables, (positions // Bs)[:, None], axis=1)[:, 0]
         offsets = positions % Bs
@@ -599,12 +641,16 @@ class ModelRunner:
     def collect(self, launched: Launched) -> tuple:
         """Wait for a launched program and read its results: (sampled
         id, its logits row) of a prefill or a chunk, (ids, logits rows)
-        of a decode's real lanes."""
+        of a decode's real lanes, in the order the caller gave them."""
         nxt, logits = self._fetch(*launched.results, aux=launched.aux)
-        if launched.rows is None:
+        if launched.order is None:
             return int(nxt), logits
-        return ([int(t) for t in nxt[:launched.rows]],
-                logits[:launched.rows])
+        # back to the caller's order: its lane i ran as row `row_of[i]`
+        # (a view of the rows, not a copy, where the two orders agree)
+        row_of = np.argsort(launched.order)
+        if np.array_equal(row_of, np.arange(len(row_of))):
+            row_of = slice(len(row_of))
+        return [int(t) for t in nxt[row_of]], logits[row_of]
 
     def take_expert_pairs(self) -> list[np.ndarray]:
         """The (L, n_experts) pairs-per-expert arrays of the programs run
@@ -710,6 +756,7 @@ class ModelRunner:
             temp = np.asarray([temperature], np.float32)
             topk = np.asarray([top_k], np.int32)
             topp = np.asarray([top_p], np.float32)
+            self._note_context("prefill", [start])
             self._step_counter += 1
         with self.phases.phase("dispatch"):
             before = tracing.jit_cache_size(self._chunk_jit)
@@ -736,12 +783,16 @@ class ModelRunner:
             token_ids, start, table, temperature, top_k, top_p))
 
     def launch_decode(self, items: Sequence[DecodeItem]) -> Launched:
-        """Enqueue one decode step for up to max_batch_size sequences."""
+        """Enqueue one decode step for up to max_batch_size sequences.
+        The program's rows are the lanes by position, longest first
+        (padded rows, position 0, last), so that consecutive rows read
+        about as much context; `collect` undoes the order."""
         with self.phases.phase("prepare"):
             S = len(items)
             if not 0 < S <= self.max_batch_size:
                 raise ValueError(f"decode batch of {S}")
             Sb = self.decode_bucket(S)
+            order = np.argsort([-it.pos for it in items], kind="stable")
             toks = np.zeros((Sb,), np.int32)
             slots = np.full((Sb,), -1, np.int32)
             poss = np.zeros((Sb,), np.int32)
@@ -749,7 +800,7 @@ class ModelRunner:
             temps = np.zeros((Sb,), np.float32)
             topks = np.zeros((Sb,), np.int32)
             topps = np.ones((Sb,), np.float32)
-            for i, it in enumerate(items):
+            for i, it in enumerate(items[j] for j in order):
                 toks[i] = it.token
                 slots[i] = it.slot
                 poss[i] = it.pos
@@ -757,6 +808,8 @@ class ModelRunner:
                 temps[i] = it.temperature
                 topks[i] = it.top_k
                 topps[i] = it.top_p
+            if not self.use_paged_attention:
+                self._note_context("decode", poss, self.lanes_per_group(Sb))
             self._step_counter += 1
         with self.phases.phase("dispatch"):
             before = tracing.jit_cache_size(self._decode_jit)
@@ -769,7 +822,7 @@ class ModelRunner:
                     temps, topks, topps, np.int32(self._step_counter))
             self._note_compile("decode", self._decode_jit, before,
                                time.perf_counter() - t0)
-        return Launched((nxt, logits), aux, S)
+        return Launched((nxt, logits), aux, order)
 
     def decode(self, items: Sequence[DecodeItem]
                ) -> tuple[list[int], np.ndarray]:
@@ -816,6 +869,8 @@ class ModelRunner:
             temps = np.full((W,), temperature, np.float32)
             topks = np.full((W,), top_k, np.int32)
             topps = np.full((W,), top_p, np.float32)
+            if not self.use_paged_attention:
+                self._note_context("verify", [pos])
             self._step_counter += 1
         with self.phases.phase("dispatch"):
             before = tracing.jit_cache_size(self._verify_jit)

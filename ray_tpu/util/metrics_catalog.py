@@ -171,6 +171,11 @@ CATALOG: list[dict] = [
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "bytes of device results (tokens, logits) the engine's "
              "steps fetched to the host, by step kind"},
+    {"name": "serve_llm_ctx_slots_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "slots of cached context by kind of program: read as "
+             "launched, valid, and what reading every row to "
+             "max_model_len would be"},
     {"name": "serve_llm_steps_launched_total", "type": "counter",
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "step programs enqueued, by step kind and by whether the "

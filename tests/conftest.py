@@ -40,3 +40,18 @@ def cpu_mesh8():
     from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
     return build_mesh(MeshSpec(data=2, fsdp=2, tensor=2))
+
+
+@pytest.fixture
+def context_tile_pages(monkeypatch):
+    """``set(pages)``: the serve programs read a lane's cached context in
+    tiles of `pages` pages for the rest of the test. The toy models' rows
+    fit a whole table into one tile of the real size
+    (`KVLayout.tile_pages`), which would leave the tiled read's loop at
+    one step."""
+    def set_pages(pages: int) -> None:
+        from ray_tpu.serve.llm.cache import KVLayout
+
+        monkeypatch.setattr(KVLayout, "tile_pages",
+                            property(lambda self: pages))
+    return set_pages

@@ -338,3 +338,35 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
     # a weight, a layer of a stack, or an expert of a layer
     held = {a.shape[i:] for a in weights for i in range(a.ndim - 1)}
     assert not [r for r in results("convert") if r in held]
+    if program == "decode" and not paged:
+        assert not _full_width_contexts(text, runner, [
+            pool, *params_and_state(params, state)])
+
+
+def params_and_state(params, state):
+    return jax.tree.leaves(params) + list(state.values())
+
+
+# the context of every lane gathered to `max_model_len`, as the decode
+# programs of before PR 33 held it (PERF_LEDGER.jsonl, PR 32, `device_ops`)
+FULL_WIDTH = ("[8,1024,20,64]", "[8,64,16,1280]", "[1024,16,2048]",
+              "[16,1024,16,128]", "[32,2560,2,128]", "[5120,16,256]")
+
+
+def _full_width_contexts(text, runner, arguments):
+    """Arrays of a compiled decode program that hold as much as the
+    context of all its lanes read to `max_model_len`: the named shapes of
+    before, or anything of that size that is not a program argument (the
+    pool, a weight, the state) or a layer or an expert of one. A group of
+    lanes reads at most its own lanes' context, an eighth of that."""
+    whole = (runner.max_batch_size * runner.max_blocks_per_seq
+             * runner.block_size * runner.layout.row)
+    known = {a.shape[i:] for a in arguments for i in range(a.ndim)}
+    shapes = {tuple(map(int, m.group(1).split(",")))
+              for m in re.finditer(r"\w+\[([\d,]+)\]", text)}
+    def inner(s):  # a slice of a stack keeps its leading 1
+        return s[next(i for i, d in enumerate(s + (0,)) if d != 1):]
+
+    return [s for s in sorted(shapes)
+            if math.prod(s) >= whole and inner(s) not in known] \
+        + [s for s in FULL_WIDTH if s in text]

@@ -148,13 +148,19 @@ def test_whole_prompt_prefill_matches_the_reference(params, tokens, want):
         assert _worst(last, want[n - 1]) < TOL
 
 
-@pytest.mark.parametrize("chunk", [8, 16, 32])
-def test_chunked_prefill_then_decode_match_the_reference(params, tokens,
-                                                         want, chunk):
+@pytest.mark.parametrize("chunk,tile_pages", [(8, None), (16, None),
+                                              (32, None), (8, 1), (16, 2)])
+def test_chunked_prefill_then_decode_match_the_reference(
+        params, tokens, want, chunk, tile_pages, context_tile_pages):
     """The chunked form against the recurrence: a prompt of 37 tokens in
     chunks of `chunk` rows (8: one SSD chunk a program; 16 and 32: two and
     four, the last program padded), state carried in the lane's slot, then
-    four decode steps."""
+    four decode steps. The attention layers read the cached context as
+    these toy rows make it (one tile holds the table), and in tiles of one
+    and two pages, so that chunks start at tile edges and decode steps
+    read a part of the table."""
+    if tile_pages:
+        context_tile_pages(tile_pages)
     r = _runner(params, prefill_chunk_size=chunk)
     table = [3, 7, 2, 9, 5, 11]
     n, at = 37, 0

@@ -64,11 +64,19 @@ def test_full_forward_matches_the_reference(params, tokens):
     assert _worst(got, want) < TOL
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
-def test_prefill_chunk_and_decode_match_the_reference(params, tokens, paged):
+@pytest.mark.parametrize("paged", [False, True, "page-tiles"],
+                         ids=["dense", "paged", "dense-page-tiles"])
+def test_prefill_chunk_and_decode_match_the_reference(params, tokens, paged,
+                                                      context_tile_pages):
     """A 16-token prefill, a second chunk that crosses into a new page and
     ends mid-page, then three decode steps through the paged cache: each
-    step's logits against the reference's one full pass."""
+    step's logits against the reference's one full pass. The dense
+    programs read the context as these toy rows make it (one tile holds
+    the table) and in tiles of one page (the chunk reads two, the decode
+    steps four of a table's eight)."""
+    if paged == "page-tiles":
+        context_tile_pages(1)
+        paged = False
     want, _ = ref.forward(params, jnp.asarray(tokens), ARCH)
     want = np.asarray(want)
     r = ModelRunner(adapters()["llama"], CFG, params, block_size=8,

@@ -136,7 +136,16 @@ def _parity_case(name, cfg, forward):
         np.testing.assert_allclose(logits[0], full[t], atol=1e-4)
 
 
-def test_decode_parity_gpt2():
+@pytest.fixture(params=["one-tile", "page-tiles"])
+def context_tiles(request, context_tile_pages):
+    """The context read as these toy rows make it (one tile holds the
+    whole table), and in tiles of one page: positions 13..20 then read
+    two and three of a table's four."""
+    if request.param == "page-tiles":
+        context_tile_pages(1)
+
+
+def test_decode_parity_gpt2(context_tiles):
     from ray_tpu.models import gpt2
 
     cfg = dataclasses.replace(gpt2.GPT2Config.tiny(), dtype=jnp.float32,
@@ -144,7 +153,7 @@ def test_decode_parity_gpt2():
     _parity_case("gpt2", cfg, gpt2.gpt2_forward)
 
 
-def test_decode_parity_llama():
+def test_decode_parity_llama(context_tiles):
     from ray_tpu.models import llama
 
     _parity_case("llama", llama.LlamaConfig.tiny(), llama.llama_forward)

@@ -412,10 +412,15 @@ def test_step_reports_work_until_nothing_is_in_flight():
     _assert_drained(eng)
 
 
+@pytest.mark.parametrize("lengths", [(7, 5), (5, 7)],
+                         ids=["longest-first", "longest-last"])
 @pytest.mark.parametrize("model", MODELS)
-def test_a_decode_lane_takes_its_unread_id_from_the_device(model):
+def test_a_decode_lane_takes_its_unread_id_from_the_device(model, lengths):
     """Runner level: a lane fed `token=-1` reads what the program before
-    it left at its slot, and computes what the host's id computes."""
+    it left at its slot, and computes what the host's id computes. The
+    decode program runs its lanes longest first (PR 33), and `collect`
+    hands lane i's id and logits back to lane i whichever way the caller
+    had them, with the program before still unread."""
     from ray_tpu.serve.llm.runner import DecodeItem, ModelRunner, adapters
 
     fam = model if model != "olmoe" else "llama"
@@ -431,7 +436,7 @@ def test_a_decode_lane_takes_its_unread_id_from_the_device(model):
                            max_batch_size=4, prefill_chunk_size=8)
 
     tables = [[3, 7, 2, 9], [5, 1, 8, 4]]
-    prompts = _prompts([7, 5], seed=2)
+    prompts = _prompts(lengths, seed=2)
 
     def run(on_device):
         r = runner()
@@ -445,7 +450,21 @@ def test_a_decode_lane_takes_its_unread_id_from_the_device(model):
             toks, logits = r.decode(items)
             rows.append(logits)
         assert np.asarray(r.slot_tokens)[[2, 0]].tolist() == toks
-        return first, toks, np.stack(rows), r
+        # a step in flight: two programs launched, then read in turn, give
+        # each lane what one program at a time, fed by the host, gives it
+        def items(step, fed):
+            return [DecodeItem(fed[i], len(prompts[i]) + step, tables[i],
+                               0.0, slot=(2, 0)[i]) for i in range(2)]
+
+        if on_device:
+            ahead = r.launch_decode(items(3, [-1, -1]))
+            behind = r.launch_decode(items(4, [-1, -1]))
+            (ids, _), (ids2, _) = r.collect(ahead), r.collect(behind)
+        else:
+            ids, _ = r.decode(items(3, toks))
+            ids2, _ = r.decode(items(4, ids))
+        assert np.asarray(r.slot_tokens)[[2, 0]].tolist() == ids2
+        return first, toks + ids + ids2, np.stack(rows), r
 
     a, b = run(True), run(False)
     assert a[:2] == b[:2]
