@@ -21,6 +21,18 @@ and accumulator in float32). A slot past a lane's length had the weight
 ``exp(-1e30 - m) = 0`` when all ``max_model_len`` slots were read and
 masked; not reading it changes no term of the softmax, only the order of
 a float32 sum.
+
+A kind of layer with a **window** (`KVLayout.window`: a row sees itself
+and the ``window - 1`` rows before it) has a lower end to its read as
+well: of lane b's cached slots only ``(lengths[b] - window, lengths[b])``
+can be seen by any row of the program, and they lie in
+`KVLayout.window_pages` pages from the page that holds the first of them.
+Those pages are one tile whose first page is the lane's own, read once
+for all lanes with no loop (`_window_tile`), and the mask over the own
+rows gets the window too. K and V rows may differ in width. A layer with
+a **sink** (one learned scalar a query head that joins the softmax as a
+column with no value) starts its running softmax from ``(m, l) = (sink,
+1)`` and a zero accumulator.
 """
 
 from __future__ import annotations
@@ -45,13 +57,19 @@ class CachedContext:
     lengths: jax.Array  # (B,) i32: slots [0, lengths[b]) hold lane b's rows
     group: int  # lanes a group: consecutive rows, B a multiple of it
     # (B // group,) i32: the tiles that group g, or any group behind it,
-    # reaches (for lanes ordered longest first: the tiles of group g)
-    reach: jax.Array
+    # reaches (for lanes ordered longest first: the tiles of group g);
+    # None for a window kind, which reads one tile a lane
+    reach: jax.Array | None
 
     @classmethod
     def of(cls, layout, k_pages, v_pages, tables, lengths, group: int = 1):
         """`tables` (B, n) padded with the null page to whole tiles, and
-        each group's tile count, worked out once a program."""
+        each group's tile count, worked out once a program; for a window
+        kind, padded so that a window's pages from any page on are in
+        range."""
+        if layout.window is not None:
+            tables = jnp.pad(tables, ((0, 0), (0, layout.window_pages)))
+            return cls(layout, k_pages, v_pages, tables, lengths, group, None)
         per = layout.tile_pages
         pad = -tables.shape[1] % per
         if pad:
@@ -82,15 +100,21 @@ def _scores(q, keys, valid, scale):
     return jnp.where(valid[:, None, None], s * scale, -1e30)
 
 
-def softmax_over(q, segments, scale, dtype):
+def softmax_over(q, segments, scale, dtype, sink=None):
     """One softmax, in float32, over the rows of every segment ``(keys,
     values (G, S, HK, D), valid (G, T, S))``: every slot read and masked
     (a whole prompt's own rows; the tests' full-width reference). The
     segments' scores are joined, never their rows, and K and V are never
     repeated R times: the query heads of a KV head share them in the
-    product."""
-    s = jnp.concatenate([_scores(q, keys, valid, scale)
-                         for keys, _, valid in segments], axis=-1)
+    product. `sink` (HK, R) joins as one more column, which has no value
+    and is dropped after the softmax."""
+    scores = [_scores(q, keys, valid, scale) for keys, _, valid in segments]
+    if sink is not None:
+        with jax.named_scope("attn.sink"):
+            scores.append(jnp.broadcast_to(
+                sink.astype(jnp.float32)[None, :, :, None, None],
+                scores[0].shape[:-1] + (1,)))
+    s = jnp.concatenate(scores, axis=-1)
     probs = jax.nn.softmax(s, axis=-1).astype(dtype)
     att, at = 0, 0
     for _, values, valid in segments:
@@ -120,6 +144,30 @@ def _own_rows(q, k, v, own_valid, scale, dtype):
     return m, jnp.sum(p, axis=-1), _weigh(p, v, dtype)
 
 
+def _fold(carry, s, values, dtype):
+    """Scores s (G, HK, R, T, S) and their values folded into a running
+    softmax ``(m, l, acc)``."""
+    m, l, acc = carry
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new[..., None])
+    return (m_new, alpha * l + jnp.sum(p, axis=-1),
+            _per_row(alpha) * acc + _weigh(p, values, dtype))
+
+
+def _from_sink(q, k, v, own_valid, scale, dtype, sink):
+    """The running softmax started from the sink's column, ``(m, l) =
+    (sink, 1)`` with nothing accumulated (the column has no value), then
+    the program's own rows."""
+    G, T, HK, R, _ = q.shape
+    with jax.named_scope("attn.sink"):
+        m = jnp.broadcast_to(sink.astype(jnp.float32)[None, :, :, None],
+                             (G, HK, R, T))
+        carry = (m, jnp.ones_like(m),
+                 jnp.zeros((G, T, HK, R, v.shape[-1]), jnp.float32))
+    return _fold(carry, _scores(q, k, own_valid, scale), v, dtype)
+
+
 def _tile_step(ctx, layer, scale, dtype, q, tables, lengths):
     """``step(t, carry)``: tile t of these lanes' context folded into
     their running softmax."""
@@ -127,46 +175,77 @@ def _tile_step(ctx, layer, scale, dtype, q, tables, lengths):
     tile = per * ctx.layout.block_size
 
     def step(t, carry):
-        m, l, acc = carry
         kc, vc = ctx.read(layer, jax.lax.dynamic_slice_in_dim(
             tables, t * per, per, axis=1))
         slot = t * tile + jnp.arange(tile)
         valid = jnp.broadcast_to(
             slot[None, None, :] < lengths[:, None, None],
             (q.shape[0], q.shape[1], tile))
-        s = _scores(q, kc, valid, scale)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        return (m_new, alpha * l + jnp.sum(p, axis=-1),
-                _per_row(alpha) * acc + _weigh(p, vc, dtype))
+        return _fold(carry, _scores(q, kc, valid, scale), vc, dtype)
 
     return step
 
 
-def attend_cached(q, k, v, own_valid, ctx: CachedContext, layer, dtype):
+def _window_tile(ctx, layer, scale, dtype, q, carry):
+    """The one tile of a window kind: for lane b the `window_pages` pages
+    from the page that holds slot ``lengths[b] - window + 1`` on, the
+    first a row of the program can see; row t, at position ``lengths[b]
+    + t``, sees the slots above ``position - window`` of them."""
+    lay = ctx.layout
+    T = q.shape[1]
+    first = jnp.maximum(ctx.lengths - (lay.window - 1), 0) // lay.block_size
+    kc, vc = ctx.read(layer, jnp.take_along_axis(
+        ctx.tables, first[:, None] + jnp.arange(lay.window_pages)[None],
+        axis=1))
+    slot = first[:, None] * lay.block_size \
+        + jnp.arange(lay.window_pages * lay.block_size)[None]  # (B, S)
+    position = ctx.lengths[:, None] + jnp.arange(T)[None]  # (B, T)
+    valid = (slot[:, None, :] < ctx.lengths[:, None, None]) \
+        & (slot[:, None, :] > position[:, :, None] - lay.window)
+    m, l, acc = _fold(carry, _scores(q, kc, valid, scale), vc, dtype)
+    return (acc / _per_row(l)).astype(dtype)
+
+
+def attend_cached(q, k, v, own_valid, ctx: CachedContext, layer, dtype,
+                  sink=None):
     """q (B, T, HK, R, D) attends, under one softmax scaled by
     ``1 / sqrt(D)``, to lane b's cached rows ``[0, ctx.lengths[b])`` of
-    layer `layer` and to the program's own rows k, v (B, T, HK, D) where
-    `own_valid` (B, T, T) allows -> (B, T, HK, R, D) in `dtype`.
+    layer `layer` and to the program's own rows k (B, T, HK, D), v (B, T,
+    HK, Dv) where `own_valid` (B, T, T) allows -> (B, T, HK, R, Dv) in
+    `dtype`. In a window kind (`ctx.layout.window`) row t, at position
+    ``ctx.lengths[b] + t``, sees of both only the rows above ``position -
+    window``. `sink` (HK, R): a column of the softmax with no value.
 
     Tile by tile: tile t is read for the rows of every group that reaches
     it, which are the first rows of the program, whatever the lanes'
     order (no group behind a group reaches further than `ctx.reach` says
     of it). One loop a group, last group first: it runs the tiles that its
     group reaches and the groups behind it did not, on all rows up to its
-    group's, and leaves its group's rows finished."""
+    group's, and leaves its group's rows finished. A window kind reads
+    its one tile a lane instead (`_window_tile`)."""
     B, G = q.shape[0], ctx.group
     scale = 1.0 / (q.shape[-1] ** 0.5)
-    carry = _own_rows(q, k, v, own_valid, scale, dtype)
-    done, at = [], 0
-    for rows in range(B, 0, -G):  # the groups' ends, last group first
-        upto = ctx.reach[rows // G - 1]
-        m, l, acc = jax.lax.fori_loop(
-            at, upto, _tile_step(ctx, layer, scale, dtype, q[:rows],
-                                 ctx.tables[:rows], ctx.lengths[:rows]),
-            carry)
-        done.append((acc[rows - G:] / _per_row(l[rows - G:])).astype(dtype))
-        carry = (m[:rows - G], l[:rows - G], acc[:rows - G])
-        at = upto
-    return jnp.concatenate(done[::-1])
+    window = ctx.layout.window
+    if window is not None:
+        T = q.shape[1]
+        own_valid = own_valid & (
+            jnp.arange(T)[:, None] - jnp.arange(T)[None, :] < window)
+    if sink is None:
+        carry = _own_rows(q, k, v, own_valid, scale, dtype)
+    else:
+        carry = _from_sink(q, k, v, own_valid, scale, dtype, sink)
+    with jax.named_scope("attn.ctx_read"):
+        if window is not None:
+            return _window_tile(ctx, layer, scale, dtype, q, carry)
+        done, at = [], 0
+        for rows in range(B, 0, -G):  # the groups' ends, last group first
+            upto = ctx.reach[rows // G - 1]
+            m, l, acc = jax.lax.fori_loop(
+                at, upto, _tile_step(ctx, layer, scale, dtype, q[:rows],
+                                     ctx.tables[:rows], ctx.lengths[:rows]),
+                carry)
+            done.append(
+                (acc[rows - G:] / _per_row(l[rows - G:])).astype(dtype))
+            carry = (m[:rows - G], l[:rows - G], acc[:rows - G])
+            at = upto
+        return jnp.concatenate(done[::-1])

@@ -1,13 +1,23 @@
 """Block KV-cache pool: fixed-size pages, refcounts, and a
 content-addressed prefix index (automatic prefix caching).
 
-The device arrays themselves live in the ModelRunner (one K and one V
-pool per model). This module owns the *bookkeeping* (`BlockPool`: which
-physical pages are free, each sequence's logical-block -> physical-page
-table, which pages hold which token content) and the pools' *layout*
-(`KVLayout`: their shape and sharding, how a layer's context is read
-through block tables and how new rows are written). Nothing else in the
-package spells the pool's shape.
+The device arrays themselves live in the ModelRunner: one K and one V
+pool a KIND of layer that has keys and values. Most families have one
+kind (every such layer alike); a family that mixes full and window
+attention has two (`KVKind`), each with its own head count, its own K and
+V row widths and, for a window kind, the window. This module owns the
+*bookkeeping* (`BlockPool`: which physical pages are free, which pages
+hold which token content; `KVPools`: a model's pools, one a kind, and
+what is counted of them) and the pools' *layout* (`KVLayout`: their shape
+and sharding, how a layer's context is read through block tables and how
+new rows are written). Nothing else in the package spells a pool's shape.
+
+A sequence has a block table a kind (scheduler.py). A full kind's table
+grows with the sequence. A window kind's holds only the pages that some
+later row can still see: a page wholly behind the window of the next row
+to be planned goes back to its pool's free list while the sequence runs
+(its table entry becomes the null page), so a lane costs at most
+`KVLayout.lane_pages` pages of that pool however long it gets.
 
 A family with recurrent (state-space) layers has a second kind of state
 beside the pages, fixed in size a lane: `StateLayout` (its buffers),
@@ -41,14 +51,14 @@ import hashlib
 import math
 import threading
 from collections import OrderedDict
-from typing import Any, Iterable, Sequence
+from typing import Any, Iterable, NamedTuple, Sequence
 
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
 
-# Elements of K (or of V) in one tile of a lane's cached context, for each
-# layer that has keys and values: the unit in which the dense serve
+# Elements of K in one tile of a lane's cached context, for each layer
+# of the pool's kind: the unit in which the dense serve
 # programs read it (`KVLayout.tile_pages`, ops/context_attention.py). A
 # step of the read costs every such layer some twenty small operations
 # (1.5 us a step on the v5e, and an event each in a device trace) beside
@@ -85,22 +95,45 @@ def chain_hashes(tokens: Sequence[int], block_size: int,
     return out
 
 
+def window_lane_pages(window: int, rows: int, block_size: int) -> int:
+    """Most pages of a window kind a lane holds while a program of
+    `rows` rows is planned: the window behind its first row and the rows
+    themselves, wherever the pages' edges fall."""
+    return (window + rows - 2) // block_size + 2
+
+
+class KVKind(NamedTuple):
+    """One kind of layer that has keys and values, as a family's adapter
+    describes it (`ModelAdapter.kv_kinds`): how many layers, their KV
+    heads, the width of a K head and of a V head, and the window: a row
+    sees itself and the `window - 1` rows before it, or every earlier row
+    (None)."""
+
+    name: str
+    layers: int
+    n_kv_head: int
+    head_dim: int
+    v_head_dim: int
+    window: int | None = None
+
+
 @dataclasses.dataclass(frozen=True)
 class KVLayout:
-    """How one K (or V) page pool lies on the device, and the only code
-    that indexes it.
+    """How the K and the V page pool of one kind of layer lie on the
+    device, and the only code that indexes them.
 
-    The pool is ``(kv_layers, num_blocks, block_size, n_kv_head *
-    head_dim)``, `kv_layers` being the layers that HAVE keys and values
-    (every layer of a dense stack, 2 of 18 in the served hybrid): one
-    token's heads side by side in one lane-dense row
-    (1280 lanes at gpt2-large), so the bf16 tile ``(8, 128)(2, 1)`` over
-    ``(block_size, row)`` pads nothing and XLA keeps the array row-major.
-    Three things together keep every serve program from copying the pool
-    whole (each alone leaves the copies; PERF.md, PR 26): this row, a
-    context read per layer inside the layer scan (`read`), and a scatter
-    indexed on all three leading dimensions (`write`), which XLA then
-    does in place on the donated buffer.
+    The K pool is ``(kv_layers, num_blocks, block_size, n_kv_head *
+    head_dim)`` and the V pool the same with `v_head_dim` (as wide as K's
+    unless the family says otherwise), `kv_layers` being the layers of
+    this kind (every layer of a dense stack, 2 of 18 in nemotron_h's cut,
+    2 full and 5 window layers in mimo_v2's): one token's heads side by
+    side in one lane-dense row (1280 lanes at gpt2-large), so the bf16
+    tile ``(8, 128)(2, 1)`` over ``(block_size, row)`` pads nothing and
+    XLA keeps the array row-major. Three things together keep every serve
+    program from copying a pool whole (each alone leaves the copies;
+    PERF.md, PR 26): this row, a context read per layer inside the layer
+    scan (`read`), and a scatter indexed on all three leading dimensions
+    (`write`), which XLA then does in place on the donated buffer.
     """
 
     kv_layers: int
@@ -108,38 +141,65 @@ class KVLayout:
     block_size: int
     n_kv_head: int
     head_dim: int
+    v_head_dim: int | None = None  # None: as wide as a K head
+    window: int | None = None  # None: a row sees every earlier row
+
+    @classmethod
+    def of(cls, kind: KVKind, num_blocks: int, block_size: int):
+        return cls(kind.layers, num_blocks, block_size, kind.n_kv_head,
+                   kind.head_dim, kind.v_head_dim, kind.window)
 
     @property
     def row(self) -> int:
-        """Lanes of one token's row: head ``h`` is ``[h * head_dim,
+        """Lanes of one token's K row: head ``h`` is ``[h * head_dim,
         (h + 1) * head_dim)``."""
         return self.n_kv_head * self.head_dim
 
     @property
+    def v_row(self) -> int:
+        return self.n_kv_head * (self.v_head_dim or self.head_dim)
+
+    @property
     def shape(self) -> tuple[int, int, int, int]:
+        """Of the K pool (and of the V pool where the rows are alike)."""
         return (self.kv_layers, self.num_blocks, self.block_size, self.row)
+
+    @property
+    def v_shape(self) -> tuple[int, int, int, int]:
+        return self.shape[:3] + (self.v_row,)
 
     @property
     def tile_pages(self) -> int:
         """Pages in one tile of a lane's context: the largest power of
-        two whose rows hold at most `TILE_ELEMENTS_A_LAYER` a layer of
+        two whose K rows hold at most `TILE_ELEMENTS_A_LAYER` a layer of
         the pool (32 pages, 512 slots, at gpt2-large's 36 layers of 1280;
         8 pages at 8 layers of OLMoE's 2048-wide row; 16 pages at the
-        nemotron_h cut's 2 layers of 256)."""
+        nemotron_h cut's 2 layers of 256; 4 pages at mimo_v2's 2 full
+        layers of 768)."""
         pages = max(1, TILE_ELEMENTS_A_LAYER * self.kv_layers
                     // (self.block_size * self.row))
         return 1 << (pages.bit_length() - 1)
 
+    @property
+    def window_pages(self) -> int:
+        """Pages that hold every cached slot a program's rows can see in
+        a window kind, wherever the window starts in its first page: the
+        ``window - 1`` slots before the program's first row."""
+        return max(0, self.window - 2) // self.block_size + 2
+
+    def lane_pages(self, rows: int) -> int:
+        return window_lane_pages(self.window, rows, self.block_size)
+
     def shard_ways(self, tensor_ways: int) -> int:
-        """Over how many `tensor` shards the row splits: whole heads
-        only (contiguous head blocks), else the pool is replicated."""
+        """Over how many `tensor` shards the rows split: whole heads
+        only (contiguous head blocks), else the pools are replicated."""
         if tensor_ways > 1 and self.n_kv_head % tensor_ways == 0:
             return tensor_ways
         return 1
 
     def block_bytes(self, dtype_bytes: int, tensor_ways: int = 1) -> int:
         """Bytes one page takes on one device, K and V together."""
-        return (2 * self.kv_layers * self.block_size * self.row
+        return (self.kv_layers * self.block_size * (self.row + self.v_row)
                 * dtype_bytes // self.shard_ways(tensor_ways))
 
     def spec(self, mesh) -> PartitionSpec:
@@ -148,37 +208,40 @@ class KVLayout:
             return PartitionSpec(None, None, None, "tensor")
         return PartitionSpec()
 
-    def zeros(self, dtype, mesh=None):
-        """An empty pool, placed by `spec` when there is a mesh."""
-        if mesh is None:
-            return jnp.zeros(self.shape, dtype)
-        return jnp.zeros(self.shape, dtype,
-                         device=NamedSharding(mesh, self.spec(mesh)))
+    def zeros(self, dtype, mesh=None) -> tuple:
+        """An empty (K pool, V pool), placed by `spec` when there is a
+        mesh."""
+        device = (NamedSharding(mesh, self.spec(mesh))
+                  if mesh is not None else None)
+        return (jnp.zeros(self.shape, dtype, device=device),
+                jnp.zeros(self.v_shape, dtype, device=device))
 
     def read(self, pages, layer, tables):
-        """Layer `layer`'s context for block tables ``(..., n)``:
-        ``(..., n * block_size, n_kv_head, head_dim)``, slot ``c`` being
-        position ``c`` of the table's sequence. `layer` may be traced:
-        call it inside the layer scan, so one layer's pages are gathered
-        at a time (gathering every layer at once makes XLA transpose
-        the result)."""
+        """Layer `layer`'s context for block tables ``(..., n)``, from
+        the K or the V pool: ``(..., n * block_size, n_kv_head, head
+        width)``, slot ``c`` being position ``c`` of the table's
+        sequence. `layer` may be traced: call it inside the layer scan,
+        so one layer's pages are gathered at a time (gathering every
+        layer at once makes XLA transpose the result)."""
         # one gather indexed by (layer, page): `pages[layer][tables]`
         # first copies the layer out of the pool (21 MB a layer at
         # gpt2-large; a tenth of both serve cells, PERF.md PR 26)
         ctx = pages[layer, tables]  # (..., n, block_size, row)
         return ctx.reshape(*tables.shape[:-1],
                            tables.shape[-1] * self.block_size,
-                           self.n_kv_head, self.head_dim)
+                           self.n_kv_head,
+                           pages.shape[-1] // self.n_kv_head)
 
     def write(self, pages, block_ids, offsets, rows):
-        """Store ``rows (kv_layers, N, n_kv_head, head_dim)`` at slots
-        ``(block_ids[i], offsets[i])`` of every layer. Every scatter
-        dimension leads and the row is the only window: a leading ``:``
-        would make XLA transpose the pool around the scatter."""
+        """Store ``rows (kv_layers, N, n_kv_head, head width)`` at slots
+        ``(block_ids[i], offsets[i])`` of every layer of the K or the V
+        pool. Every scatter dimension leads and the row is the only
+        window: a leading ``:`` would make XLA transpose the pool around
+        the scatter."""
         kv_layers, n = rows.shape[:2]
         layers = jnp.arange(kv_layers)[:, None]
         return pages.at[layers, block_ids[None, :], offsets[None, :]].set(
-            rows.reshape(kv_layers, n, self.row))
+            rows.reshape(kv_layers, n, pages.shape[-1]))
 
     def page_block(self) -> tuple:
         """BlockSpec shape of one page for a Pallas kernel: tile-aligned
@@ -519,26 +582,103 @@ class BlockPool:
             }
 
 
+class KVPools:
+    """A model's page pools, one `BlockPool` a kind of KV layer, and what
+    is counted of them by kind (`stats`, the engine's
+    ``stats()["kv"]``). The first kind is the one whose pool
+    `EngineConfig.num_blocks` sizes and whose pages the prefix index
+    addresses; a window kind's pool is sized off the lanes
+    (`window_pool_blocks`).
+
+    A family with a window kind takes no prefix match: a hit at a page
+    boundary would be good only if the window's pages before that
+    boundary were still registered, and those are released while the
+    sequence runs. Where prefix reuse was asked for, each admission is
+    counted as a match declined (`prefix_declined`). Counters are written
+    by the engine's one stepping thread."""
+
+    def __init__(self, kinds: Sequence[KVKind], pools: Sequence[BlockPool],
+                 prefix_declined: bool = False):
+        self.kinds = tuple(kinds)
+        self.pools = tuple(pools)
+        self.prefix_declined = prefix_declined
+        n = len(self.pools)
+        self.released = [0] * n  # pages released behind the window
+        self.largest_table = [0] * n  # most pages a sequence held at once
+        self.prefix_taken = 0  # admissions that took a prefix match
+        self.prefix_declines = 0  # admissions that looked none up
+
+    @classmethod
+    def single(cls, pool: BlockPool) -> "KVPools":
+        """One full kind over `pool` (its layers and widths are the
+        runner's to know, not the bookkeeping's)."""
+        return cls((KVKind("full", 0, 0, 0, 0),), (pool,))
+
+    @property
+    def windowed(self) -> bool:
+        return any(k.window is not None for k in self.kinds)
+
+    def stats(self) -> dict:
+        out = {}
+        for i, (kind, pool) in enumerate(zip(self.kinds, self.pools)):
+            out[kind.name] = {
+                "pages_used": pool.num_used(),
+                "pages_free": pool.num_free(),
+                "pages_total": pool.usable_blocks,
+                "window": kind.window,
+                "released_behind_window": self.released[i],
+                "largest_table": self.largest_table[i],
+                "prefix_taken": self.prefix_taken if i == 0 else 0,
+                "prefix_declined": self.prefix_declines if i == 0 else 0,
+            }
+        return out
+
+
+def window_pool_blocks(layout: KVLayout, rows: int, lanes: int) -> int:
+    """Pages of a window kind's pool, the null page included: every lane
+    at its bound for programs of `rows` rows (`KVLayout.lane_pages`), a
+    page each for the decode step planned ahead, and two spare a lane."""
+    return lanes * (layout.lane_pages(rows) + 1) + 2 * lanes
+
+
+def blocks_by_kind(kinds: Sequence[KVKind], num_blocks, block_size: int,
+                   rows: int, lanes: int) -> tuple[int, ...]:
+    """The pools' sizes, one a kind: `num_blocks` as it is where it
+    gives one a kind, else the first kind's, every window kind behind it
+    sized off the lanes (`window_pool_blocks`) and any other kind as the
+    first."""
+    if not isinstance(num_blocks, int):
+        if len(num_blocks) != len(kinds):
+            raise ValueError(f"{len(num_blocks)} pool sizes for "
+                             f"{len(kinds)} kinds of KV layer")
+        return tuple(num_blocks)
+    return (num_blocks,) + tuple(
+        num_blocks if kind.window is None else window_pool_blocks(
+            KVLayout.of(kind, 0, block_size), rows, lanes)
+        for kind in kinds[1:])
+
+
 def auto_num_blocks(
     *,
-    kv_layers: int,
-    n_kv_head: int,
-    head_dim: int,
+    kinds: Sequence[KVKind],
     block_size: int,
     dtype_bytes: int,
     max_model_len: int,
     max_batch_size: int,
+    chunk_rows: int = 0,
     memory_fraction: float = 0.3,
     tensor_ways: int = 1,
     state_bytes: int = 0,
     device=None,
 ) -> int:
-    """Size the pool off device memory (reference: vLLM's gpu memory
-    profiling, here a static estimate: params are already resident, so
-    take `memory_fraction` of the device's bytes_limit for KV and, for a
-    family that has it, the recurrent state: `state_bytes`, what
-    `StateLayout.nbytes` says the lanes' state takes, comes out of the
-    same budget first).
+    """Size the first kind's pool off device memory (reference: vLLM's
+    gpu memory profiling, here a static estimate: params are already
+    resident, so take `memory_fraction` of the device's bytes_limit for
+    KV). What is fixed in size comes out of the same budget first: the
+    recurrent state of a family that has it (`state_bytes`, what
+    `StateLayout.nbytes` says the lanes' state takes) and the pools of
+    its window kinds (`window_pool_blocks` at programs of `chunk_rows`
+    rows; 0: whole prompts).
 
     The CPU backend reports no memory and gets "every lane can reach
     max_model_len, twice over" (tests). A TPU that reports none is an
@@ -547,9 +687,12 @@ def auto_num_blocks(
     """
     # the layout's own sharding rule: sizing must not assume a split
     # the runner won't make
-    per_block = KVLayout(
-        kv_layers, 0, block_size, n_kv_head, head_dim
-    ).block_bytes(dtype_bytes, tensor_ways)
+    layouts = [KVLayout.of(k, 0, block_size) for k in kinds]
+    per_block = layouts[0].block_bytes(dtype_bytes, tensor_ways)
+    fixed = state_bytes + sum(
+        window_pool_blocks(lay, chunk_rows or max_model_len, max_batch_size)
+        * lay.block_bytes(dtype_bytes, tensor_ways)
+        for lay in layouts[1:] if lay.window is not None)
     if device is None:
         import jax
 
@@ -562,5 +705,5 @@ def auto_num_blocks(
         raise RuntimeError(
             f"{device} reports no memory_stats()['bytes_limit']; cannot "
             f"size the KV pool — pass num_blocks explicitly")
-    budget = int(stats["bytes_limit"] * memory_fraction) - state_bytes
+    budget = int(stats["bytes_limit"] * memory_fraction) - fixed
     return max(floor + 1, budget // per_block)

@@ -76,7 +76,10 @@ class EngineConfig:
     # fields laid over the preset (a JSON file's way to say the same)
     model_config: Any = None
     block_size: int = 16  # tokens per KV page
-    num_blocks: int | None = None  # physical pages incl. the null page
+    # physical pages incl. the null page: of the pool of the family's
+    # first kind of KV layer (a window kind's is then sized off the
+    # lanes, `cache.window_pool_blocks`), or a list, one size a kind
+    num_blocks: "int | list[int] | None" = None
     memory_fraction: float = 0.3  # of device memory, when auto-sizing
     max_model_len: int | None = None  # default: model cfg block_size
     max_batch_size: int = 8  # concurrent decode lanes

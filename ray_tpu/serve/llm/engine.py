@@ -26,13 +26,21 @@ from typing import Any, Sequence as Seq
 
 import numpy as np
 
-from ray_tpu.serve.llm.cache import BlockPool, StateSlots, auto_num_blocks
+from ray_tpu.serve.llm.cache import (
+    BlockPool,
+    KVLayout,
+    KVPools,
+    StateSlots,
+    auto_num_blocks,
+    blocks_by_kind,
+)
 from ray_tpu.serve.llm.config import EngineConfig, SamplingParams
 from ray_tpu.serve.llm.runner import (
     DecodeItem,
     Launched,
     ModelRunner,
     adapters,
+    chunk_rows,
     state_layout_of,
 )
 from ray_tpu.serve.llm.scheduler import (
@@ -175,6 +183,10 @@ class LLMEngine:
         # what the family caches beside K and V, read off the adapter: a
         # recurrent state a lane slot, or nothing
         state_layout = state_layout_of(adapter, cfg, config.max_batch_size)
+        # the kinds of layer that have keys and values, read off the
+        # adapter too: a pool and a block table each
+        kinds = adapter.kv_kinds(cfg)
+        windowed = any(kind.window is not None for kind in kinds)
         spec_cfg = config.speculative
         if spec_cfg and state_layout is not None:
             raise ValueError(
@@ -182,42 +194,64 @@ class LLMEngine:
                 f"{config.model!r}: the family carries recurrent state, "
                 f"and a rejected draft would have to roll it back "
                 f"(ROADMAP.md, recurrent state snapshots)")
+        if spec_cfg and windowed:
+            raise ValueError(
+                f"speculative decoding is not available for "
+                f"{config.model!r}: the family has window attention "
+                f"layers, whose pages are given back behind the window as "
+                f"rows are planned, before a draft is accepted or "
+                f"rejected (ROADMAP.md, speculation with a window kind)")
+        chunking = config.prefill_chunk_size > 0
+        rows = chunk_rows(config.prefill_chunk_size, config.block_size,
+                          max_len) if chunking else 0
         num_blocks = config.num_blocks
         if num_blocks is None:
             num_blocks = auto_num_blocks(
-                kv_layers=adapter.kv_layers(cfg),
-                n_kv_head=adapter.kv_heads(cfg),
-                head_dim=cfg.head_dim,
+                kinds=kinds,
                 block_size=config.block_size,
                 dtype_bytes=jax.numpy.dtype(cfg.dtype).itemsize,
                 max_model_len=max_len,
                 max_batch_size=config.max_batch_size,
+                chunk_rows=rows,
                 memory_fraction=config.memory_fraction,
                 tensor_ways=(dict(mesh.shape).get("tensor", 1)
                              if mesh is not None else 1),
                 state_bytes=(state_layout.nbytes if state_layout else 0),
             )
+        # a size a kind: `num_blocks` is the first kind's pool, or one a
+        # kind; a window kind's is sized off the lanes otherwise
+        sizes = blocks_by_kind(kinds, num_blocks, config.block_size,
+                               rows or max_len, config.max_batch_size)
         max_blocks_per_seq = (max_len + config.block_size - 1) \
             // config.block_size
-        if num_blocks - 1 < max_blocks_per_seq:
-            raise ValueError(
-                f"pool of {num_blocks} blocks cannot hold one "
-                f"max_model_len={max_len} sequence "
-                f"({max_blocks_per_seq} blocks needed); raise num_blocks "
-                f"or lower max_model_len")
+        for kind, n in zip(kinds, sizes):
+            need = max_blocks_per_seq if kind.window is None else \
+                KVLayout.of(kind, 0, config.block_size).lane_pages(
+                    rows or max_len)
+            if n - 1 < need:
+                raise ValueError(
+                    f"pool of {n} blocks cannot hold one "
+                    f"max_model_len={max_len} sequence "
+                    f"({need} blocks of the {kind.name} kind needed); "
+                    f"raise num_blocks or lower max_model_len")
 
         # prefix reuse needs the prefill-from-offset (chunk) program:
         # with chunking disabled the pool runs as a plain allocator. And
         # a prefix hit hands over pages of K and V but no recurrent
         # state: for a family that has it no prefix is looked up (each
-        # admission that would have is counted, `stats()["state"]`)
-        chunking = config.prefill_chunk_size > 0
+        # admission that would have is counted, `stats()["state"]`). Nor
+        # for a family with a window kind, whose pages before a matched
+        # boundary are gone (counted too, `stats()["kv"]`)
         prefix = config.enable_prefix_cache and chunking
         self.state_slots = StateSlots(state_layout, prefix_declined=prefix) \
             if state_layout is not None else None
-        self.pool = BlockPool(
-            num_blocks, config.block_size,
-            enable_prefix_cache=(prefix and state_layout is None))
+        self.kv = KVPools(
+            kinds,
+            [BlockPool(n, config.block_size, enable_prefix_cache=(
+                prefix and state_layout is None and not windowed
+                and i == 0)) for i, n in enumerate(sizes)],
+            prefix_declined=prefix and windowed)
+        self.pool = self.kv.pools[0]  # the one the prefix index addresses
         # speculative decoding: proposer on the host, verify program on
         # the device; greedy outputs stay bit-identical to spec-off
         from ray_tpu.serve.llm.spec import build_proposer
@@ -228,7 +262,7 @@ class LLMEngine:
         self.runner = ModelRunner(
             adapter, cfg, params,
             block_size=config.block_size,
-            num_blocks=num_blocks,
+            num_blocks=sizes,
             max_model_len=max_len,
             max_batch_size=config.max_batch_size,
             prefill_bucket_min=config.prefill_bucket_min,
@@ -269,7 +303,7 @@ class LLMEngine:
             "drains": dict.fromkeys(DRAIN_REASONS, 0),
             "discarded_tokens": 0}
         self.scheduler = Scheduler(
-            self.pool, max_batch_size=config.max_batch_size,
+            self.kv, max_batch_size=config.max_batch_size,
             max_model_len=max_len,
             # the runner rounds the chunk to a page-aligned size; reuse
             # its value so scheduler chunks match the compiled buckets
@@ -483,6 +517,30 @@ class LLMEngine:
         self._m_state_bytes.set(
             self.state_slots.layout.nbytes if self.state_slots else 0,
             tags=self._m_tags)
+        # KV pages by kind of layer (one kind for most families)
+        kv_tags = ("model", "kind")
+        self._m_kv_used = Gauge(
+            "serve_llm_kv_pages_used",
+            "Pages of a kind's pool held by sequences", tag_keys=kv_tags)
+        self._m_kv_free = Gauge(
+            "serve_llm_kv_pages_free",
+            "Pages of a kind's pool that can be allocated",
+            tag_keys=kv_tags)
+        self._m_kv_largest = Gauge(
+            "serve_llm_kv_largest_table",
+            "Most pages of a kind one sequence has held at once",
+            tag_keys=kv_tags)
+        self._m_kv_released = Counter(
+            "serve_llm_kv_released_total",
+            "Pages given back behind the window while their sequence ran "
+            "(a window kind only)", tag_keys=kv_tags)
+        self._m_kv_prefix = Counter(
+            "serve_llm_kv_prefix_total",
+            "Admissions by what became of their prefix lookup: taken (a "
+            "match of at least a page) or declined (none looked up: the "
+            "family has a window kind)", tag_keys=("model", "outcome"))
+        self._kv_seen = {"pools": [None] * len(self.kv.pools),
+                         "taken": 0, "declined": 0}
         self._moe: dict[str, dict] = {}
         self._spec_proposed_total = 0
         self._spec_accepted_total = 0
@@ -731,6 +789,7 @@ class LLMEngine:
             self._m_prefix_misses.inc(misses - lm, tags=self._m_tags)
         if evict > le:
             self._m_prefix_evict.inc(evict - le, tags=self._m_tags)
+        self._note_kv()
         if tokens:
             self._note_tokens(tokens)
         self._steps[kind] += 1
@@ -749,6 +808,29 @@ class LLMEngine:
                     seen[what] = n
         if self.runner.expert_pairs:  # never, for a dense model
             self._note_routing(kind, self.runner.take_expert_pairs())
+
+    def _note_kv(self) -> None:
+        """The pools by kind of KV layer onto the metrics page: only what
+        moved since the last step (most steps move nothing)."""
+        kv, seen = self.kv, self._kv_seen
+        for i, (kind, pool) in enumerate(zip(kv.kinds, kv.pools)):
+            now = (pool.num_free(), kv.largest_table[i], kv.released[i])
+            if now == seen["pools"][i]:
+                continue
+            tags = {"model": self.config.model, "kind": kind.name}
+            self._m_kv_used.set(pool.usable_blocks - now[0], tags=tags)
+            self._m_kv_free.set(now[0], tags=tags)
+            self._m_kv_largest.set(now[1], tags=tags)
+            gone = now[2] - (seen["pools"][i] or (0, 0, 0))[2]
+            if gone:
+                self._m_kv_released.inc(gone, tags=tags)
+            seen["pools"][i] = now
+        for outcome, n in (("taken", kv.prefix_taken),
+                           ("declined", kv.prefix_declines)):
+            if n > seen[outcome]:
+                self._m_kv_prefix.inc(n - seen[outcome], tags={
+                    "model": self.config.model, "outcome": outcome})
+                seen[outcome] = n
 
     def _note_routing(self, kind: str, routed: list) -> None:
         """Account the step's routed-expert layers: `routed` holds one
@@ -792,10 +874,10 @@ class LLMEngine:
             # whole prompt in one go and nothing cached: the
             # monolithic program skips the context gather
             return self.runner.launch_prefill(
-                tokens, seq.table, sp.temperature, sp.top_k, sp.top_p,
+                tokens, work.tables, sp.temperature, sp.top_k, sp.top_p,
                 seq.slot)
         return self.runner.launch_chunk(
-            tokens, work.start, seq.table, sp.temperature, sp.top_k,
+            tokens, work.start, work.tables, sp.temperature, sp.top_k,
             sp.top_p, seq.slot)
 
     def _commit_prefill(self, flight: "_Flight", nxt: int, last) -> int:
@@ -859,15 +941,17 @@ class LLMEngine:
         lanes with a draft are set aside (`flight.drafted`) for one
         verify dispatch each once the plain lanes are committed."""
         flight.plain, unread = flight.sampled, flight.unread
+        tables = flight.work.tables  # of `sampled`, lane for lane
         if self._proposer is not None:
             with self.phases.phase("prepare"):
-                flight.plain = []
-                for s in flight.sampled:
+                flight.plain, tables = [], []
+                for s, t in zip(flight.sampled, flight.work.tables):
                     d = self._propose_for(s)
                     if d:
                         flight.drafted.append((s, d))
                     else:
                         flight.plain.append(s)
+                        tables.append(t)
                 unread = [0] * len(flight.plain)  # never launched ahead
         if not flight.plain:
             return
@@ -878,10 +962,10 @@ class LLMEngine:
         # the id its last program left on the device
         with self.phases.phase("prepare"):
             items = [DecodeItem(s.last_token if n == 0 else -1,
-                                s.pos + n - 1, s.table,
+                                s.pos + n - 1, t,
                                 s.sampling.temperature, s.sampling.top_k,
                                 s.sampling.top_p, s.slot)
-                     for s, n in zip(flight.plain, unread)]
+                     for s, n, t in zip(flight.plain, unread, tables)]
         flight.handle = self.runner.launch_decode(items)
 
     def _propose_for(self, seq: Sequence) -> list[int]:
@@ -943,7 +1027,7 @@ class LLMEngine:
         t0 = time.perf_counter()
         try:
             tokens, logits = self.runner.verify(
-                seq.last_token, seq.pos - 1, draft, seq.table,
+                seq.last_token, seq.pos - 1, draft, seq.tables,
                 sp.temperature, sp.top_k, sp.top_p)
         except Exception as e:  # noqa: BLE001
             with self._lock:
@@ -1172,6 +1256,9 @@ class LLMEngine:
             for kind, n in self.runner.context_slots.items():
                 n.update(dict.fromkeys(n, 0))
                 self._ctx_seen[kind] = dict(n)
+            for by in self.runner.context_by_kind.values():
+                for n in by.values():
+                    n.update(dict.fromkeys(n, 0))
         up = self._startup
         up["warmup"] += wall
         up["warmup_trace"] += spent["trace"]
@@ -1208,6 +1295,8 @@ class LLMEngine:
             phase_totals = dict(self._phase_totals)
             finished = self._finished_requests
         d.update({
+            # the page pools by kind of KV layer (cache.KVPools.stats)
+            "kv": self.kv.stats(),
             "model": self.config.model,
             "block_size": self.pool.block_size,
             "max_batch_size": self.config.max_batch_size,
@@ -1228,6 +1317,10 @@ class LLMEngine:
             # launched, valid, and what a read to max_model_len would be
             "context": {kind: dict(n) for kind, n in
                         self.runner.context_slots.items()},
+            # the same a kind of KV layer: {kv kind: {program: counts}}
+            "context_by_kind": {
+                name: {kind: dict(n) for kind, n in by.items()}
+                for name, by in self.runner.context_by_kind.items()},
             # steps launched while the one before was unread, or not, by
             # kind; why not, by reason; sampled ids no stream got
             "overlap": {k: dict(v) if isinstance(v, dict) else v

@@ -1,7 +1,9 @@
 """ModelRunner: jit-compiled paged prefill / decode steps.
 
-Owns the device-side half of the KV cache (one K and one V pool, laid
-out, read and written only through `cache.KVLayout`), for a family with
+Owns the device-side half of the KV cache (a K and a V pool for each
+kind of layer that has keys and values, `ModelAdapter.kv_kinds`: one kind
+for most families, a full and a window kind for mimo_v2; laid out, read
+and written only through `cache.KVLayout`), for a family with
 recurrent layers also the lanes' state buffers (`cache.StateLayout`,
 read and written through `cache.StateView`), and the compiled programs
 that touch them:
@@ -69,7 +71,13 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ray_tpu.ops.context_attention import CachedContext
-from ray_tpu.serve.llm.cache import KVLayout, StateLayout, StateView
+from ray_tpu.serve.llm.cache import (
+    KVKind,
+    KVLayout,
+    StateLayout,
+    StateView,
+    blocks_by_kind,
+)
 from ray_tpu.util import tracing
 
 # The step loop's phases, as `engine.stats()["step_phase_seconds"]` and
@@ -90,18 +98,21 @@ class ModelAdapter:
     init_fn: Callable  # (key, cfg) -> params
     # (params, tokens, cfg) -> (logits, k, v), as every forward below; a
     # family with routed experts appends their pairs per layer and
-    # expert, (L, n_experts) i32, which the programs hand on to the host
+    # expert, (L, n_experts) i32, which the programs hand on to the host.
+    # k and v are stacked over the layers that have them; a family with
+    # several kinds of such layers returns a tuple of stacks, one a kind
     prefill_fn: Callable
     # ctx: the lanes' cached context (ops/context_attention.py
-    # CachedContext), which the layers read as far as the lanes reach
+    # CachedContext; a tuple of them, one a kind, for several kinds),
+    # which the layers read as far as the lanes reach
     decode_fn: Callable  # (params, toks, pos, ctx, cfg) -> ...
     chunk_fn: Callable  # (params, toks, start, ctx, chunk_mask, cfg) -> ...
     rules_fn: Callable  # () -> PartitionRules
-    kv_heads: Callable[[Any], int]
-    # how many layers HAVE keys and values: the pool's leading dimension
-    # and the k / v a forward returns (every layer, unless the family
-    # says otherwise)
-    kv_layers: Callable[[Any], int] = lambda cfg: cfg.n_layer
+    # cfg -> the kinds of layer that HAVE keys and values (cache.KVKind:
+    # layers, KV heads, K and V head widths, window): a pool pair and a
+    # block table a kind, in this order, the first being the kind whose
+    # pool `num_blocks` sizes
+    kv_kinds: Callable[[Any], tuple]
     # recurrent state a lane carries beside its pages: cfg -> (layers
     # that have it, ((name, shape a lane and layer, dtype), ...)); None:
     # the family has none. With it, every forward above also takes
@@ -128,7 +139,12 @@ class ModelAdapter:
 
 def adapters() -> dict[str, ModelAdapter]:
     """Model registry (lazy imports keep `import ray_tpu.serve` light)."""
-    from ray_tpu.models import gpt2, llama, nemotron_h
+    from ray_tpu.models import gpt2, llama, mimo_v2, nemotron_h
+
+    def one_kind(layers, heads):
+        """Every layer with keys and values alike, K as wide as V."""
+        return lambda cfg: (KVKind("full", layers(cfg), heads(cfg),
+                                   cfg.head_dim, cfg.head_dim),)
 
     return {
         "gpt2": ModelAdapter(
@@ -146,7 +162,8 @@ def adapters() -> dict[str, ModelAdapter]:
             decode_fn=gpt2.gpt2_decode_kv,
             chunk_fn=gpt2.gpt2_prefill_chunk_kv,
             rules_fn=gpt2.gpt2_partition_rules,
-            kv_heads=lambda cfg: cfg.n_head,
+            kv_kinds=one_kind(lambda cfg: cfg.n_layer,
+                              lambda cfg: cfg.n_head),
             resident_fn=gpt2.gpt2_resident_params,
             decode_paged_fn=gpt2.gpt2_decode_paged_kv,
             verify_paged_fn=gpt2.gpt2_verify_paged_kv,
@@ -166,7 +183,8 @@ def adapters() -> dict[str, ModelAdapter]:
             decode_fn=llama.llama_decode_kv,
             chunk_fn=llama.llama_prefill_chunk_kv,
             rules_fn=llama.llama_partition_rules,
-            kv_heads=lambda cfg: cfg.n_kv_head,
+            kv_kinds=one_kind(lambda cfg: cfg.n_layer,
+                              lambda cfg: cfg.n_kv_head),
             decode_paged_fn=llama.llama_decode_paged_kv,
             verify_paged_fn=llama.llama_verify_paged_kv,
         ),
@@ -183,9 +201,24 @@ def adapters() -> dict[str, ModelAdapter]:
             decode_fn=nemotron_h.nemotron_h_decode_kv,
             chunk_fn=nemotron_h.nemotron_h_prefill_chunk_kv,
             rules_fn=nemotron_h.nemotron_h_partition_rules,
-            kv_heads=lambda cfg: cfg.num_key_value_heads,
-            kv_layers=lambda cfg: cfg.n_kv_layers,
+            kv_kinds=one_kind(lambda cfg: cfg.n_kv_layers,
+                              lambda cfg: cfg.num_key_value_heads),
             state_fn=lambda cfg: (cfg.n_ssm_layers, cfg.state_parts()),
+            held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
+        ),
+        "mimo_v2": ModelAdapter(
+            name="mimo_v2",
+            config_cls=mimo_v2.MimoV2Config,
+            presets={
+                "tiny": mimo_v2.MimoV2Config.tiny,
+                "v2_5_l7_ep16": mimo_v2.MimoV2Config.v2_5_l7_ep16,
+            },
+            init_fn=mimo_v2.init_mimo_v2,
+            prefill_fn=mimo_v2.mimo_v2_prefill_kv,
+            decode_fn=mimo_v2.mimo_v2_decode_kv,
+            chunk_fn=mimo_v2.mimo_v2_prefill_chunk_kv,
+            rules_fn=mimo_v2.mimo_v2_partition_rules,
+            kv_kinds=lambda cfg: tuple(KVKind(*k) for k in cfg.kv_kinds()),
             held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
         ),
     }
@@ -206,7 +239,9 @@ class DecodeItem(NamedTuple):
     # device yet, the program takes it from `slot`
     token: int
     pos: int  # its absolute position (== tokens written so far)
-    table: Sequence[int]  # physical page ids, logical order
+    # physical page ids, logical order; one such list a kind for a
+    # family with several kinds of KV layer
+    table: Sequence
     temperature: float
     top_k: int = 0  # 0: disabled
     top_p: float = 1.0  # 1.0: disabled
@@ -227,11 +262,19 @@ class Launched(NamedTuple):
 
 # What the programs read of their lanes' cached context, in slots, by
 # kind of program: `slots_read` as launched (tiles x tile x the lanes of
-# a group), `slots_valid` of them below a lane's length, `slots_full`
-# what reading every row to `max_model_len` would have (rows x slots a
-# table holds). The monolithic prefill program reads none.
+# a group; a window kind: its one tile a lane), `slots_valid` of them
+# that a row can see (below a lane's length, and inside the window),
+# `slots_reach` what a read from slot 0 to every lane's length would
+# touch, `slots_full` what reading every row to `max_model_len` would
+# (rows x slots a table holds). The monolithic prefill program reads none.
 CONTEXT_KINDS = ("decode", "prefill", "verify")
-CONTEXT_COUNTS = ("slots_read", "slots_valid", "slots_full")
+CONTEXT_COUNTS = ("slots_read", "slots_valid", "slots_reach", "slots_full")
+
+
+def _by_kind(x) -> tuple:
+    """A program argument or result a kind of KV layer: a family with
+    one kind may give and take it bare."""
+    return tuple(x) if isinstance(x, (tuple, list)) else (x,)
 
 
 def _ordered_bits(x):
@@ -284,6 +327,15 @@ def truncation_cutoff(logits, scale, top_k, top_p):
     return _from_ordered_bits(below + 1)
 
 
+def chunk_rows(prefill_chunk_size: int, block_size: int,
+               max_model_len: int) -> int:
+    """The rows of a prefill chunk: offsets and chunks must stay
+    page-aligned, so the size rounds up to whole pages (and never exceeds
+    `max_model_len`)."""
+    c = max(block_size, prefill_chunk_size)
+    return min(-(-c // block_size) * block_size, max_model_len)
+
+
 def _next_pow2(n: int, lo: int) -> int:
     b = lo
     while b < n:
@@ -321,7 +373,7 @@ class ModelRunner:
         params: Any,
         *,
         block_size: int,
-        num_blocks: int,
+        num_blocks: "int | Sequence[int]",
         max_model_len: int,
         max_batch_size: int,
         prefill_bucket_min: int = 16,
@@ -335,7 +387,6 @@ class ModelRunner:
         self.cfg = cfg
         self.mesh = mesh
         self.block_size = block_size
-        self.num_blocks = num_blocks
         self.max_model_len = max_model_len
         self.max_batch_size = max_batch_size
         self.prefill_bucket_min = prefill_bucket_min
@@ -343,9 +394,8 @@ class ModelRunner:
         # chunk size rounds up to a block multiple (and never exceeds
         # max_model_len). None disables chunking (monolithic prefill).
         if prefill_chunk_size is not None:
-            c = max(block_size, prefill_chunk_size)
-            c = ((c + block_size - 1) // block_size) * block_size
-            prefill_chunk_size = min(c, max_model_len)
+            prefill_chunk_size = chunk_rows(prefill_chunk_size, block_size,
+                                            max_model_len)
         self.prefill_chunk_size = prefill_chunk_size
         self.max_blocks_per_seq = (
             max_model_len + block_size - 1) // block_size
@@ -361,9 +411,17 @@ class ModelRunner:
         # pallas interpret mode off-TPU (CPU CI); real kernel on TPU
         self._interpret = jax.default_backend() != "tpu"
 
-        self.layout = KVLayout(adapter.kv_layers(cfg), num_blocks,
-                               block_size, adapter.kv_heads(cfg),
-                               cfg.head_dim)
+        # a layout, a pool pair and a block table a kind of KV layer;
+        # `num_blocks` an int: the first kind's pool, a window kind's
+        # sized off the lanes (cache.blocks_by_kind)
+        kinds = adapter.kv_kinds(cfg)
+        self.num_blocks = blocks_by_kind(
+            kinds, num_blocks, block_size,
+            self.prefill_chunk_size or max_model_len, max_batch_size)
+        self.layouts = tuple(KVLayout.of(kind, n, block_size)
+                             for kind, n in zip(kinds, self.num_blocks))
+        self.kv_names = tuple(kind.name for kind in kinds)
+        self.layout = self.layouts[0]  # the paged kernels' (one kind)
         # a lane slot's recurrent state, for a family that has it
         self.state_layout = state_layout_of(adapter, cfg, max_batch_size)
         # pages are mutated functionally; serialize compute just in case
@@ -373,8 +431,9 @@ class ModelRunner:
         self.weights = {"resident_bytes": 0, "cast_leaves": 0,
                         "installs": 0}
         self._install(params, adapter.resident_fn(params, cfg))
-        self.k_pages = self.layout.zeros(cfg.dtype, mesh)
-        self.v_pages = self.layout.zeros(cfg.dtype, mesh)
+        # K pools and V pools, one of each a kind
+        self.k_pages, self.v_pages = zip(*(
+            lay.zeros(cfg.dtype, mesh) for lay in self.layouts))
         # {} for a family without recurrent state: the programs take and
         # return it all the same, and compile to what they were
         self.state = (self.state_layout.zeros(mesh)
@@ -402,6 +461,10 @@ class ModelRunner:
         self.expert_pairs: list[np.ndarray] = []
         self.context_slots = {kind: dict.fromkeys(CONTEXT_COUNTS, 0)
                               for kind in CONTEXT_KINDS}
+        # the same a kind of KV layer
+        self.context_by_kind = {
+            name: {kind: dict.fromkeys(CONTEXT_COUNTS, 0)
+                   for kind in CONTEXT_KINDS} for name in self.kv_names}
         # compile observability: warmup() should account for ALL misses;
         # a mid-stream miss afterwards is the recompile bug these catch
         from ray_tpu.util.metrics import Counter, Histogram
@@ -465,14 +528,25 @@ class ModelRunner:
 
     def _note_context(self, kind: str, lengths, group: int = 1) -> None:
         """Count what a program launched on lanes of `lengths` (as the
-        program has them: ordered, padded) reads of their context."""
-        tile = self.layout.tile_pages * self.block_size
-        longest = np.max(np.reshape(lengths, (-1, group)), axis=1)
-        n = self.context_slots[kind]
-        n["slots_read"] += int(np.sum(-(-longest // tile)) * tile * group)
-        n["slots_valid"] += int(np.sum(lengths))
-        n["slots_full"] += (np.size(lengths) * self.max_blocks_per_seq
-                            * self.block_size)
+        program has them: ordered, padded) reads of their context, in
+        every kind of KV layer."""
+        reach = int(np.sum(lengths))
+        full = np.size(lengths) * self.max_blocks_per_seq * self.block_size
+        total = self.context_slots[kind]
+        for name, lay in zip(self.kv_names, self.layouts):
+            if lay.window is None:
+                tile = lay.tile_pages * self.block_size
+                longest = np.max(np.reshape(lengths, (-1, group)), axis=1)
+                read = int(np.sum(-(-longest // tile)) * tile * group)
+                valid = reach
+            else:
+                read = np.size(lengths) * lay.window_pages * self.block_size
+                valid = int(np.sum(np.minimum(lengths, lay.window - 1)))
+            by = self.context_by_kind[name][kind]
+            for what, n in (("slots_read", read), ("slots_valid", valid),
+                            ("slots_reach", reach), ("slots_full", full)):
+                total[what] += n
+                by[what] += n
 
     @staticmethod
     def _keep_sampled(slot_tokens, slots, nxt):
@@ -493,18 +567,43 @@ class ModelRunner:
         view = StateView(self.state_layout, state, slots, fresh)
         return fn(*args, state=view, **extra), view.buffers
 
+    def _context(self, k_pages, v_pages, tables, lengths, group: int = 1):
+        """The lanes' cached context as a family's forward takes it: a
+        `CachedContext`, or one a kind for several kinds of KV layer."""
+        ctx = tuple(CachedContext.of(lay, kp, vp, t, lengths, group)
+                    for lay, kp, vp, t in zip(
+                        self.layouts, _by_kind(k_pages), _by_kind(v_pages),
+                        tables))
+        return ctx[0] if len(ctx) == 1 else ctx
+
+    def _write(self, k_pages, v_pages, block_ids, offsets, k, v,
+               lane: int | None = None):
+        """The rows a forward returned, k and v (layers of the kind, [1,]
+        N, HK, width) a kind, stored in their kind's pools at the slots
+        ``(block_ids, offsets)`` of the kind's table (`lane` 0: the one
+        lane of a prompt's or a chunk's program). Returns (K pools, V
+        pools), a tuple each."""
+        pick = (lambda a: a) if lane is None else (lambda a: a[:, lane])
+        pools = [(lay.write(kp, ids, offsets, pick(kr)),
+                  lay.write(vp, ids, offsets, pick(vr)))
+                 for lay, kp, vp, ids, kr, vr in zip(
+                     self.layouts, _by_kind(k_pages), _by_kind(v_pages),
+                     _by_kind(block_ids), _by_kind(k), _by_kind(v))]
+        return tuple(p[0] for p in pools), tuple(p[1] for p in pools)
+
     def _prefill_impl(self, params, k_pages, v_pages, slot_tokens, state,
                       tokens, last_idx, block_ids, offsets, slot, temp, topk,
                       topp, step):
         """tokens (1, Tb); block_ids/offsets (Tb,) map position t to its
         page slot (padded positions -> null page 0). A sequence's first
-        rows: its slot's recurrent state starts from zero."""
+        rows: its slot's recurrent state starts from zero. Here and in
+        the programs below, the pools, `block_ids` and the tables are one
+        a kind of KV layer (a tuple; bare for one kind)."""
         (logits, k, v, *aux), state = self._forward(
             self.adapter.prefill_fn, state, slot, params, tokens, self.cfg,
             fresh=True, n_valid=last_idx + 1)
-        # (L, 1, Tb, HK, D) -> (L, Tb, HK, D)
-        k_pages = self.layout.write(k_pages, block_ids, offsets, k[:, 0])
-        v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
+        k_pages, v_pages = self._write(k_pages, v_pages, block_ids, offsets,
+                                       k, v, lane=0)
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
         nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
         slot_tokens = self._keep_sampled(slot_tokens, slot, nxt)
@@ -526,11 +625,11 @@ class ModelRunner:
         chunk_mask = (jnp.arange(Tb)[None, :] <= last_idx)  # (1, Tb)
         (logits, k, v, *aux), state = self._forward(
             self.adapter.chunk_fn, state, slot, params, tokens, start,
-            CachedContext.of(self.layout, k_pages, v_pages, table[None],
-                             start[None]),
+            self._context(k_pages, v_pages,
+                          [t[None] for t in _by_kind(table)], start[None]),
             chunk_mask, self.cfg, fresh=start == 0, n_valid=last_idx + 1)
-        k_pages = self.layout.write(k_pages, block_ids, offsets, k[:, 0])
-        v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
+        k_pages, v_pages = self._write(k_pages, v_pages, block_ids, offsets,
+                                       k, v, lane=0)
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
         nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
         slot_tokens = self._keep_sampled(slot_tokens, slot, nxt)
@@ -562,17 +661,19 @@ class ModelRunner:
         W = tokens.shape[1]
         if self.use_paged_attention:
             logits, k, v, *aux = self.adapter.verify_paged_fn(
-                params, tokens, start, self.layout, k_pages, v_pages,
-                table, self.cfg, interpret=self._interpret)
+                params, tokens, start, self.layout, *_by_kind(k_pages),
+                *_by_kind(v_pages), *_by_kind(table), self.cfg,
+                interpret=self._interpret)
         else:
             chunk_mask = (jnp.arange(W)[None, :] <= n_draft)  # (1, W)
             logits, k, v, *aux = self.adapter.chunk_fn(
                 params, tokens, start,
-                CachedContext.of(self.layout, k_pages, v_pages, table[None],
-                                 start[None]),
+                self._context(k_pages, v_pages,
+                              [t[None] for t in _by_kind(table)],
+                              start[None]),
                 chunk_mask, self.cfg)
-        k_pages = self.layout.write(k_pages, block_ids, offsets, k[:, 0])
-        v_pages = self.layout.write(v_pages, block_ids, offsets, v[:, 0])
+        k_pages, v_pages = self._write(k_pages, v_pages, block_ids, offsets,
+                                       k, v, lane=0)
         lg = logits[0]  # (W, Vp)
         target = self._sample(lg, temps, topks, topps, step)  # (W,)
         # target[j] is the model's own token FOR position start+j+1;
@@ -602,21 +703,22 @@ class ModelRunner:
                            slot_tokens[jnp.maximum(slots, 0)])
         if self.use_paged_attention:
             logits, k_new, v_new, *aux = self.adapter.decode_paged_fn(
-                params, tokens, positions, self.layout, k_pages, v_pages,
-                tables, self.cfg, interpret=self._interpret)
+                params, tokens, positions, self.layout, *_by_kind(k_pages),
+                *_by_kind(v_pages), *_by_kind(tables), self.cfg,
+                interpret=self._interpret)
         else:
             (logits, k_new, v_new, *aux), state = self._forward(
                 self.adapter.decode_fn, state, slots, params, tokens,
                 positions,
-                CachedContext.of(self.layout, k_pages, v_pages, tables,
-                                 positions,
-                                 self.lanes_per_group(tokens.shape[0])),
+                self._context(k_pages, v_pages, _by_kind(tables), positions,
+                              self.lanes_per_group(tokens.shape[0])),
                 self.cfg)
-        block_ids = jnp.take_along_axis(
-            tables, (positions // Bs)[:, None], axis=1)[:, 0]
+        block_ids = tuple(jnp.take_along_axis(
+            t, (positions // Bs)[:, None], axis=1)[:, 0]
+            for t in _by_kind(tables))
         offsets = positions % Bs
-        k_pages = self.layout.write(k_pages, block_ids, offsets, k_new)
-        v_pages = self.layout.write(v_pages, block_ids, offsets, v_new)
+        k_pages, v_pages = self._write(k_pages, v_pages, block_ids, offsets,
+                                       k_new, v_new)
         nxt = self._sample(logits, temps, topks, topps, step)
         slot_tokens = self._keep_sampled(slot_tokens, slots, nxt)
         return nxt, logits, k_pages, v_pages, slot_tokens, state, tuple(aux)
@@ -680,8 +782,35 @@ class ModelRunner:
             raise ValueError(f"chunk of {n} tokens exceeds chunk size {cap}")
         return min(_next_pow2(n, self.prefill_bucket_min), cap)
 
+    def _lists(self, table) -> Sequence:
+        """A lane's page lists, one a kind of KV layer: `table` as it is
+        where it gives one a kind, or one flat list taken for every kind
+        (the one kind's; the null table of warm-up)."""
+        flat = len(table) == 0 or np.ndim(table[0]) == 0
+        return [table] * len(self.layouts) if flat else table
+
+    def _tables(self, table) -> tuple:
+        """A lane's block tables as the programs take them, one a kind:
+        (max_blocks_per_seq,) i32, the null page behind a table's end."""
+        out = []
+        for t in self._lists(table):
+            tab = np.zeros((self.max_blocks_per_seq,), np.int32)
+            tab[:len(t)] = t
+            out.append(tab)
+        return tuple(out)
+
+    def _block_ids(self, tables: tuple, positions, width: int) -> tuple:
+        """The pages of `positions` in each kind's table, padded with the
+        null page to `width` rows."""
+        out = []
+        for tab in tables:
+            ids = np.zeros((width,), np.int32)
+            ids[:len(positions)] = tab[positions // self.block_size]
+            out.append(ids)
+        return tuple(out)
+
     def launch_prefill(self, token_ids: Sequence[int],
-                       table: Sequence[int], temperature: float,
+                       table: Sequence, temperature: float,
                        top_k: int = 0, top_p: float = 1.0,
                        slot: int = -1) -> Launched:
         """Enqueue one prompt's monolithic prefill. `table` must cover
@@ -692,11 +821,9 @@ class ModelRunner:
             Tb = self.prefill_bucket(n)
             toks = np.zeros((1, Tb), np.int32)
             toks[0, :n] = token_ids
-            block_ids = np.zeros((Tb,), np.int32)
             offsets = np.arange(Tb, dtype=np.int32) % self.block_size
-            pos = np.arange(n)
-            block_ids[:n] = np.asarray(
-                table, np.int32)[pos // self.block_size]
+            block_ids = self._block_ids(self._tables(table), np.arange(n),
+                                        Tb)
             temp = np.asarray([temperature], np.float32)
             topk = np.asarray([top_k], np.int32)
             topp = np.asarray([top_p], np.float32)
@@ -716,7 +843,7 @@ class ModelRunner:
                                time.perf_counter() - t0)
         return Launched((nxt, last), aux, None)
 
-    def prefill(self, token_ids: Sequence[int], table: Sequence[int],
+    def prefill(self, token_ids: Sequence[int], table: Sequence,
                 temperature: float, top_k: int = 0, top_p: float = 1.0
                 ) -> tuple[int, np.ndarray]:
         """Run one prompt through monolithic prefill; returns (first
@@ -725,7 +852,7 @@ class ModelRunner:
             token_ids, table, temperature, top_k, top_p))
 
     def launch_chunk(self, token_ids: Sequence[int], start: int,
-                     table: Sequence[int], temperature: float,
+                     table: Sequence, temperature: float,
                      top_k: int = 0, top_p: float = 1.0,
                      slot: int = -1) -> Launched:
         """Enqueue a prefill-from-offset: `token_ids` (<=
@@ -744,11 +871,8 @@ class ModelRunner:
             Tb = self.chunk_bucket(n)
             toks = np.zeros((1, Tb), np.int32)
             toks[0, :n] = token_ids
-            tab = np.zeros((self.max_blocks_per_seq,), np.int32)
-            tab[:len(table)] = table
-            block_ids = np.zeros((Tb,), np.int32)
-            pos = start + np.arange(n)
-            block_ids[:n] = tab[pos // self.block_size]
+            tab = self._tables(table)
+            block_ids = self._block_ids(tab, start + np.arange(n), Tb)
             # padded tail positions keep in-range offsets but target
             # page 0
             offsets = np.asarray(
@@ -774,7 +898,7 @@ class ModelRunner:
         return Launched((nxt, last), aux, None)
 
     def prefill_chunk(self, token_ids: Sequence[int], start: int,
-                      table: Sequence[int], temperature: float,
+                      table: Sequence, temperature: float,
                       top_k: int = 0, top_p: float = 1.0
                       ) -> tuple[int, np.ndarray]:
         """`launch_chunk`, then its results: (sampled next token,
@@ -796,15 +920,23 @@ class ModelRunner:
             toks = np.zeros((Sb,), np.int32)
             slots = np.full((Sb,), -1, np.int32)
             poss = np.zeros((Sb,), np.int32)
-            tables = np.zeros((Sb, self.max_blocks_per_seq), np.int32)
+            tables = tuple(
+                np.zeros((Sb, self.max_blocks_per_seq), np.int32)
+                for _ in self.layouts)
             temps = np.zeros((Sb,), np.float32)
             topks = np.zeros((Sb,), np.int32)
             topps = np.ones((Sb,), np.float32)
+            # one list a kind, or one flat list for every kind: the same
+            # form in every item of a step
+            first = items[0].table
+            flat = len(first) == 0 or np.ndim(first[0]) == 0
             for i, it in enumerate(items[j] for j in order):
                 toks[i] = it.token
                 slots[i] = it.slot
                 poss[i] = it.pos
-                tables[i, :len(it.table)] = it.table
+                for k, rows in enumerate(tables):
+                    t = it.table if flat else it.table[k]
+                    rows[i, :len(t)] = t
                 temps[i] = it.temperature
                 topks[i] = it.top_k
                 topps[i] = it.top_p
@@ -856,15 +988,14 @@ class ModelRunner:
             toks = np.zeros((1, W), np.int32)
             toks[0, 0] = token
             toks[0, 1:1 + n_draft] = draft
-            tab = np.zeros((self.max_blocks_per_seq,), np.int32)
-            tab[:len(table)] = table
+            tab = self._tables(table)
             positions = pos + np.arange(W)
             # padded tail rows write to the null page at in-range offsets
-            block_ids = np.where(
+            block_ids = tuple(np.where(
                 np.arange(W) <= n_draft,
-                tab[np.minimum(positions, self.max_model_len - 1)
-                    // self.block_size],
-                0).astype(np.int32)
+                t[np.minimum(positions, self.max_model_len - 1)
+                  // self.block_size],
+                0).astype(np.int32) for t in tab)
             offsets = np.asarray(positions % self.block_size, np.int32)
             temps = np.full((W,), temperature, np.float32)
             topks = np.full((W,), top_k, np.int32)
@@ -981,8 +1112,8 @@ class ModelRunner:
     def reset_cache(self) -> None:
         """Zero the pages and the lanes' state (tests); allocator state
         lives in BlockPool."""
-        self.k_pages = jnp.zeros_like(self.k_pages)
-        self.v_pages = jnp.zeros_like(self.v_pages)
+        self.k_pages = jax.tree.map(jnp.zeros_like, self.k_pages)
+        self.v_pages = jax.tree.map(jnp.zeros_like, self.v_pages)
         self.state = jax.tree.map(jnp.zeros_like, self.state)
 
     def compiled_signatures(self) -> int:

@@ -45,6 +45,23 @@ in flight is known to end (`max_tokens`, `max_model_len`) leaves
 away; only its pages wait for the commit. What the plan cannot make
 without the results, a preemption, it refuses (`NeedsResults`).
 
+KV by kind of layer (cache.py `KVPools`): a sequence has a block table
+a kind. A full kind's covers the whole sequence, allocated at admission
+and grown a page at a time by decode. A **window kind's** covers only
+what a later row can still see: admission allocates the first chunk's
+pages, every later chunk and decode step first gives back the pages
+wholly behind the window of its first row (`_cover`), then allocates what
+its own rows need, so the table holds at most `KVLayout.lane_pages`
+pages. The pages are given back when the step that no longer needs them
+is PLANNED: every program that read them was planned, and so enqueued,
+before it, and every program that may write them as another lane's is
+planned, and so enqueued, after it. The device runs programs in the
+order they were enqueued, which is what makes the reuse safe with a step
+in flight (and what already made it safe to free a finished lane's pages
+while the step behind its last still runs). A family with a window kind
+takes no prefix match (`KVPools`): its admissions are counted as matches
+declined. Exhaustion of either kind's pool preempts alike.
+
 The scheduler owns no locks: the engine serializes calls. The pool's
 internal `_lock` is a leaf — taken inside pool calls only, never
 around scheduler state — so there is no lock-order cycle with the
@@ -61,7 +78,9 @@ from collections import deque
 from ray_tpu.serve.llm.cache import (
     BlockPool,
     CacheExhausted,
+    KVPools,
     hash_page,
+    window_lane_pages,
 )
 from ray_tpu.serve.llm.config import SamplingParams
 
@@ -86,7 +105,12 @@ class Sequence:
     sampling: SamplingParams
     state: SeqState = SeqState.WAITING
     generated: list[int] = dataclasses.field(default_factory=list)
-    table: list[int] = dataclasses.field(default_factory=list)
+    # physical page ids in logical order, one table a kind of KV layer;
+    # a window kind's leading `released[kind]` entries are the null page
+    # (their pages went back to the pool behind the window)
+    tables: list[list[int]] = dataclasses.field(
+        default_factory=lambda: [[]])
+    released: list[int] = dataclasses.field(default_factory=lambda: [0])
     last_token: int = -1  # input to the next decode step
     # tokens sampled by steps that are launched and not yet committed
     # (0..2: the step whose results are being read, and the one behind)
@@ -152,6 +176,11 @@ class Sequence:
     _hashes: list[int] = dataclasses.field(default_factory=list)
 
     @property
+    def table(self) -> list[int]:
+        """The first kind's table (the only one, for most families)."""
+        return self.tables[0]
+
+    @property
     def refill_tokens(self) -> list[int]:
         """What prefill must run over: the original prompt plus anything
         generated before a preemption (recompute-style resume)."""
@@ -206,18 +235,30 @@ class PrefillWork:
     start: int = 0
     end: int = 0
     is_last: bool = True
+    # the sequence's tables as this program reads and writes them, one a
+    # kind (`Scheduler._tables_now`)
+    tables: list[list[int]] = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
 class DecodeWork:
     seqs: list[Sequence]
+    # each lane's tables as this step reads and writes them
+    tables: list[list[list[int]]] = dataclasses.field(default_factory=list)
 
 
 class Scheduler:
-    def __init__(self, pool: BlockPool, *, max_batch_size: int,
+    def __init__(self, pool: "BlockPool | KVPools", *, max_batch_size: int,
                  max_model_len: int, chunk_size: int = 0,
                  spec_tokens: int = 0):
-        self.pool = pool
+        # a pool a kind of KV layer; `pool`: the first kind's, which the
+        # prefix index addresses
+        self.kv = pool if isinstance(pool, KVPools) else KVPools.single(pool)
+        self.pool = self.kv.pools[0]
+        # (index, window) of the window kinds: most families have none
+        self._windows = [(i, kind.window)
+                         for i, kind in enumerate(self.kv.kinds)
+                         if kind.window is not None]
         self.max_batch_size = max_batch_size
         self.max_model_len = max_model_len
         # page-aligned by construction (the engine rounds it); 0 means
@@ -278,22 +319,24 @@ class Scheduler:
             return work
         pending = [s for s in self.running if s.prefill_pending]
         ready = [s for s in self.running if not s.prefill_pending]
+        # a continuation chunk; alternates with decode when both kinds of
+        # work exist so a long prompt can't monopolize steps, and goes on
+        # alone while nothing is decodable yet
         if pending and not (self._last_was_prefill and ready):
-            # continuation chunk; alternate with decode when both kinds
-            # of work exist so a long prompt can't monopolize steps
             self._last_was_prefill = True
-            return self._next_chunk(pending[0])
+            work = self._next_chunk(pending[0], may_preempt)
+            # None: the chunk's own lane was preempted to make room
+            return work or self.schedule(may_preempt)
         if not ready:
-            if pending:  # nothing decodable yet: keep prefilling
-                self._last_was_prefill = True
-                return self._next_chunk(pending[0])
             return None
         self._last_was_prefill = False
         self._grow_tables_or_preempt(may_preempt)
         ready = [s for s in self.running if not s.prefill_pending]
         if not ready:
             return None
-        return DecodeWork(ready)
+        if not self._windows:  # tables that only grow: handed over as they are
+            return DecodeWork(ready, [s.tables for s in ready])
+        return DecodeWork(ready, [self._tables_now(s) for s in ready])
 
     def _try_admit(self) -> PrefillWork | None:
         if not (self.waiting and len(self.running) < self.max_batch_size):
@@ -307,7 +350,20 @@ class Scheduler:
         t_match = time.monotonic()
         matched = self.pool.match_prefix(
             seq.page_hashes((total - 1) // bs, bs))
-        if not self.pool.can_alloc(n_pages - len(matched)):
+        # a window kind holds the first chunk's rows to begin with, and
+        # is admitted only where its pool has the most it will ever hold
+        # (window + a chunk) free: a lane let into a pool nearly dry would
+        # be the next victim, and admitted again on the pages it gave up
+        rows = min(total, self.chunk_size or total)
+        first = self.pool.blocks_for_tokens(rows)
+        need = [n_pages - len(matched)] + [
+            n_pages if kind.window is None else first
+            for kind in self.kv.kinds[1:]]
+        room = [n if kind.window is None
+                else max(n, window_lane_pages(kind.window, rows, bs))
+                for kind, n in zip(self.kv.kinds, need)]
+        if not all(pool.can_alloc(n)
+                   for pool, n in zip(self.kv.pools, room)):
             if matched:
                 self.pool.free(matched)  # drop the refs; stay queued
             return None
@@ -321,7 +377,13 @@ class Scheduler:
         self.waiting.popleft()
         self.prefix_hit_pages += len(matched)
         self.prefix_miss_pages += n_pages - len(matched)
-        seq.table = matched + self.pool.alloc(n_pages - len(matched))
+        self.kv.prefix_taken += bool(matched)
+        self.kv.prefix_declines += self.kv.prefix_declined
+        seq.tables = [pool.alloc(n)
+                      for pool, n in zip(self.kv.pools, need)]
+        seq.tables[0] = matched + seq.tables[0]
+        seq.released = [0] * len(self.kv.pools)
+        self._note_tables(seq)
         seq.prefilled = len(matched) * bs
         seq.prefill_target = total
         seq.cached_tokens = seq.prefilled
@@ -331,20 +393,91 @@ class Scheduler:
         self.running.append(seq)
         return self._next_chunk(seq)
 
-    def _next_chunk(self, seq: Sequence) -> PrefillWork:
+    def _next_chunk(self, seq: Sequence, may_preempt: bool = True
+                    ) -> PrefillWork | None:
+        """The next chunk of `seq`'s prompt. A window kind's table first
+        moves on with it, which may find its pool dry: preempt (LIFO)
+        until it fits, as a decode step does; None where the victim was
+        `seq` itself."""
         total = seq.prefill_target
         start = seq.prefilled
         end = min(total, start + (self.chunk_size or total))
+        while True:
+            try:
+                self._cover(seq, end, first_row=start)
+                break
+            except CacheExhausted:
+                if not self._preempt_for(seq, may_preempt):
+                    return None
         seq.prefilled = end  # issued == done: the engine runs it now
         return PrefillWork(seq=seq, start=start, end=end,
-                           is_last=(end == total))
+                           is_last=(end == total),
+                           tables=self._tables_now(seq))
+
+    def _tables_now(self, seq: Sequence) -> list[list[int]]:
+        """`seq`'s tables for the program being planned. The engine
+        launches it only after it has planned the step behind it, which
+        gives back more of a window kind's pages: that kind's table is
+        copied as it is now. A full kind's only grows, by pages no
+        earlier program reads, and is handed over as it is."""
+        if not self._windows:
+            return seq.tables
+        return [table if kind.window is None else list(table)
+                for kind, table in zip(self.kv.kinds, seq.tables)]
+
+    def _cover(self, seq: Sequence, upto: int, first_row: int) -> None:
+        """Make every table of `seq` hold the pages of positions
+        ``[.., upto)`` for a program whose first row is at `first_row`:
+        a window kind gives back the pages no row from there on can see
+        (all slots at or below ``first_row - window``), then every kind
+        grows to `upto`. Raises CacheExhausted with what it got kept."""
+        bs = self.pool.block_size
+        for i, window in self._windows:
+            table, gone = seq.tables[i], seq.released[i]
+            behind = min(max(0, first_row - window + 1) // bs, len(table))
+            if behind > gone:
+                self.kv.pools[i].free(table[gone:behind])
+                table[gone:behind] = [0] * (behind - gone)
+                self.kv.released[i] += behind - gone
+                seq.released[i] = behind
+        needed = self.pool.blocks_for_tokens(upto)
+        grown = False
+        for table, pool in zip(seq.tables, self.kv.pools):
+            if len(table) < needed:
+                table.extend(pool.alloc(needed - len(table)))
+                grown = True
+        if grown:
+            self._note_tables(seq)
+
+    def _note_tables(self, seq: Sequence) -> None:
+        for i, table in enumerate(seq.tables):
+            self.kv.largest_table[i] = max(self.kv.largest_table[i],
+                                           len(table) - seq.released[i])
+
+    def _preempt_for(self, seq: Sequence, may_preempt: bool) -> bool:
+        """A pool is dry under `seq`: preempt the most recently admitted
+        lane. False where that was `seq` itself (preempted, or retired as
+        the sole runner that cannot fit)."""
+        if not may_preempt:
+            raise NeedsResults from None
+        victim = self.running[-1]
+        if victim is seq and len(self.running) == 1:
+            # sole runner and the pool can't grow it: engine
+            # guarantees pool >= one max-len sequence, so this
+            # is unreachable unless misconfigured — fail loud
+            self._retire(seq, "error:cache_exhausted")
+            self.retired_in_schedule.append(seq)
+            return False
+        self.preempt(victim)
+        return victim is not seq
 
     def _grow_tables_or_preempt(self, may_preempt: bool = True) -> None:
         """Every decoding lane must own the page its next token writes
-        into; preempt (LIFO) until the survivors all fit. Lanes still
-        mid-prefill already own their whole table (admission allocates
-        it), so they pass through untouched. A lane with a token in
-        flight is taken one position on."""
+        into, in every kind of KV layer, and a window kind's table moves
+        on behind it (`_cover`); preempt (LIFO) until the survivors all
+        fit. Lanes still mid-prefill pass through untouched: their
+        tables move with their chunks. A lane with a token in flight is
+        taken one position on."""
         i = 0
         while i < len(self.running):
             seq = self.running[i]
@@ -355,29 +488,23 @@ class Scheduler:
                 self._retire(seq, "length")
                 self.retired_in_schedule.append(seq)
                 continue
-            # the decode step writes KV at position pos-1, so the table
-            # must cover pos tokens
-            needed = self.pool.blocks_for_tokens(pos)
-            if len(seq.table) >= needed:
-                i += 1
+            if seq.prefill_pending:
+                i += 1  # its tables move with its chunks
+                continue
+            # the decode step feeds the token at position pos-1 and
+            # writes KV there, so the tables must cover pos tokens
+            if not self._windows and len(seq.tables[0]) \
+                    >= self.pool.blocks_for_tokens(pos):
+                i += 1  # the common turn: nothing to give back or to grow
                 continue
             try:
-                seq.table.extend(self.pool.alloc(needed - len(seq.table)))
+                self._cover(seq, pos, first_row=pos - 1)
                 i += 1
             except CacheExhausted:
-                if not may_preempt:
-                    raise NeedsResults from None
-                victim = self.running[-1]
-                if victim is seq and len(self.running) == 1:
-                    # sole runner and the pool can't grow it: engine
-                    # guarantees pool >= one max-len sequence, so this
-                    # is unreachable unless misconfigured — fail loud
-                    self._retire(seq, "error:cache_exhausted")
-                    self.retired_in_schedule.append(seq)
+                if not self._preempt_for(seq, may_preempt) \
+                        and not self.running:
                     return
-                self.preempt(victim)
-                if victim is seq:
-                    continue  # re-examine slot i (new occupant)
+                # re-examine slot i (the same lane, or a new occupant)
         # speculative headroom is best-effort: a drafted run commits up
         # to spec_tokens + 1 positions in one step, so try to cover
         # pos + spec_tokens — but NEVER preempt for it; under pressure
@@ -408,8 +535,7 @@ class Scheduler:
         seq._preempt_wait = True
         self.running.remove(seq)
         self._release_slot(seq)
-        self.pool.free(seq.table)
-        seq.table = []
+        self._free_tables(seq)
         seq.prefilled = 0
         seq.prefill_target = 0
         seq.cached_tokens = 0
@@ -470,10 +596,17 @@ class Scheduler:
             self._free_slots.append(seq.slot)
             seq.slot = -1
 
+    def _free_tables(self, seq: Sequence) -> None:
+        """Every page `seq` still holds, back to its kind's pool."""
+        for pool, table, released in zip(self.kv.pools, seq.tables,
+                                         seq.released):
+            pool.free(table[released:])
+        seq.tables = [[] for _ in self.kv.pools]
+        seq.released = [0] * len(self.kv.pools)
+
     def _finish(self, seq: Sequence, reason: str) -> None:
         self._release_slot(seq)
-        self.pool.free(seq.table)
-        seq.table = []
+        self._free_tables(seq)
         seq.state = SeqState.FINISHED
         seq.finish_reason = reason
 

@@ -222,6 +222,25 @@ CATALOG: list[dict] = [
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "lane slots started from zero by a program that ran a "
              "sequence's first rows (admissions, recomputes included)"},
+    # KV pages by kind of layer (`kind`: "full" for most families, "full"
+    # and "window" for one that mixes full and window attention)
+    {"name": "serve_llm_kv_pages_used", "type": "gauge",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "pages of a kind's pool held by sequences"},
+    {"name": "serve_llm_kv_pages_free", "type": "gauge",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "pages of a kind's pool that can be allocated"},
+    {"name": "serve_llm_kv_largest_table", "type": "gauge",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "most pages of a kind one sequence has held at once"},
+    {"name": "serve_llm_kv_released_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "pages given back behind the window while their sequence "
+             "ran (a window kind only)"},
+    {"name": "serve_llm_kv_prefix_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "admissions by what became of their prefix lookup: taken, "
+             "or declined (a family with a window kind looks none up)"},
     # jax's own account of its compiles (every process that compiles)
     {"name": "jax_compile_seconds_total", "type": "counter",
      "where": "ray_tpu/util/tracing.py",

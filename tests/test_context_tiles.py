@@ -247,12 +247,14 @@ def test_the_counts_add_up_on_a_scripted_run(small_tiles):
     # chunks: start 0 reads nothing, 8 one tile, 16 two
     assert got["prefill"] == {"slots_read": 0 + TILE + 2 * TILE,
                               "slots_valid": 0 + 8 + 16,
+                              "slots_reach": 0 + 8 + 16,
                               "slots_full": 3 * C}
     # 4 decode programs of one lane (the first token is the last chunk's),
     # at positions 20, 21, 22, 23: three tiles each
     assert len(tokens) == 5
     assert got["decode"] == {"slots_read": 4 * 3 * TILE,
                              "slots_valid": 20 + 21 + 22 + 23,
+                             "slots_reach": 20 + 21 + 22 + 23,
                              "slots_full": 4 * C}
     assert got["verify"] == dict.fromkeys(got["verify"], 0)
     after = exported()
@@ -284,7 +286,8 @@ def test_a_group_reads_to_its_longest_lane_and_padding_costs_nothing(
     tiles = [-(-max(ordered[i:i + 2]) // TILE) for i in range(0, 16, 2)]
     assert r.context_slots["decode"] == {
         "slots_read": sum(tiles) * TILE * 2,
-        "slots_valid": sum(positions), "slots_full": 16 * 48}
+        "slots_valid": sum(positions), "slots_reach": sum(positions),
+        "slots_full": 16 * 48}
     assert tiles[-2:] == [0, 0]  # the padded groups read nothing
 
 

@@ -18,7 +18,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.serve.llm.cache import KVLayout, auto_num_blocks
+from ray_tpu.serve.llm.cache import (
+    KVKind,
+    KVLayout,
+    auto_num_blocks,
+    blocks_by_kind,
+)
 
 # (kv_layers, num_blocks, block_size, n_kv_head, head_dim)
 LAYOUTS = {"mha": (3, 12, 4, 4, 16), "gqa": (2, 10, 16, 8, 128)}
@@ -105,7 +110,7 @@ def test_pool_shards_whole_heads_over_tensor(cpu_mesh8, n_kv_head, sharded):
     the pool's size on a device follows the same rule."""
     layout = KVLayout(2, 6, 4, n_kv_head, 16)
     rng = np.random.RandomState(2)
-    pages = layout.zeros(jnp.float32, cpu_mesh8)
+    pages, _ = layout.zeros(jnp.float32, cpu_mesh8)
     want = layout.shape[:3] + (layout.row // 2 if sharded else layout.row,)
     assert pages.sharding.shard_shape(pages.shape) == want
     assert layout.shard_ways(2) == (2 if sharded else 1)
@@ -116,7 +121,8 @@ def test_pool_shards_whole_heads_over_tensor(cpu_mesh8, n_kv_head, sharded):
         pages = jax.jit(layout.write)(pages, block_ids, offsets, rows)
         ctx = jax.jit(layout.read)(pages, jnp.int32(1), tables)
     assert pages.sharding.shard_shape(pages.shape) == want
-    plain = layout.write(layout.zeros(jnp.float32), block_ids, offsets, rows)
+    plain = layout.write(layout.zeros(jnp.float32)[0], block_ids, offsets,
+                         rows)
     np.testing.assert_array_equal(np.asarray(pages), np.asarray(plain))
     np.testing.assert_array_equal(
         np.asarray(ctx), np.asarray(layout.read(plain, 1, tables)))
@@ -129,7 +135,7 @@ def test_pool_shards_whole_heads_over_tensor(cpu_mesh8, n_kv_head, sharded):
             return {"bytes_limit": 1 << 30}
 
     sized = {ways: auto_num_blocks(
-        kv_layers=2, n_kv_head=n_kv_head, head_dim=16, block_size=4,
+        kinds=(KVKind("full", 2, n_kv_head, 16, 16),), block_size=4,
         dtype_bytes=2, max_model_len=64, max_batch_size=2,
         memory_fraction=0.5, tensor_ways=ways, device=Dev())
         for ways in (1, 2)}
@@ -166,7 +172,7 @@ def test_runner_decodes_the_same_on_a_tensor_mesh(cpu_mesh8, model):
     np.testing.assert_allclose(got_logits, want_logits, atol=2e-4)
     ways = r.layout.shard_ways(2)
     assert ways == 2
-    assert r.k_pages.sharding.shard_shape(r.k_pages.shape)[-1] \
+    assert r.k_pages[0].sharding.shard_shape(r.k_pages[0].shape)[-1] \
         == r.layout.row // ways
 
 
@@ -198,14 +204,18 @@ def one_chip():
 MODELS = {"gpt2-large": ("gpt2", "large", 8, 512, 512, 1024),
           "olmoe-1b-7b": ("llama", "olmoe_1b_7b_l8", 16, 1088, 256, 1024),
           "nemotron-3-nano-30b-a3b": (
-              "nemotron_h", "nano_30b_a3b_l18_ep4", 32, 5184, 256, 2560)}
+              "nemotron_h", "nano_30b_a3b_l18_ep4", 32, 5184, 256, 2560),
+          # two kinds of KV layer: 12,288 pages of the full kind, the
+          # window kind's 896 sized off the 32 lanes
+          "mimo-v2.5": ("mimo_v2", "v2_5_l7_ep16", 32, 12288, 256, 8704)}
 # A program's temporaries, bytes. With no weight cast in any program they
 # are activations: the AOT compile reads 1.1-105.8 MB for gpt2-large (the
 # most in prefill-512; 1.55-1.64 GB while the float32 stacks were cast
 # inside), 4.4-136.4 MB for OLMoE (decode-16), and 62.8 MB (decode-32),
 # 75.9 MB (chunk-256) and 91.2 MB (prefill-256) for the nemotron_h cut
 # (527.3 MB in chunk-256 while the conv window was one (3, 6144) part a
-# slot, which XLA relaid out around every program)
+# slot, which XLA relaid out around every program); the mimo_v2 cut's are
+# printed by the test (PERF.md section 6, PR 34)
 TEMP_BOUND = 0.3e9
 
 
@@ -235,13 +245,23 @@ def served_runner(one_chip, request):
         lambda k: adapter.resident_fn(init(k), cfg), jax.random.PRNGKey(0)))
     cast = [r for g, r in zip(jax.tree.leaves(given),
                               jax.tree.leaves(params)) if g.dtype != r.dtype]
-    runner = ModelRunner(adapter, cfg, params, block_size=16, num_blocks=2,
+    kinds = adapter.kv_kinds(cfg)
+    runner = ModelRunner(adapter, cfg, params, block_size=16,
+                         num_blocks=[2] * len(kinds),
                          max_model_len=max_len, max_batch_size=lanes,
                          prefill_chunk_size=256, num_draft_tokens=4)
     runner._interpret = False  # the kernel as the chip compiles it
-    pool = jax.ShapeDtypeStruct(
-        dataclasses.replace(runner.layout, num_blocks=pages).shape,
-        cfg.dtype, sharding=one_chip)
+    # (K pools, V pools) at their real sizes, one of each a kind of KV
+    # layer; bare where the family has one kind
+    real = [dataclasses.replace(lay, num_blocks=n) for lay, n in zip(
+        runner.layouts, blocks_by_kind(kinds, pages, 16, 256, lanes))]
+    pool = tuple(
+        tuple(jax.ShapeDtypeStruct(shape, cfg.dtype, sharding=one_chip)
+              for shape in shapes)
+        for shapes in ([lay.shape for lay in real],
+                       [lay.v_shape for lay in real]))
+    if len(kinds) == 1:
+        pool = (pool[0][0], pool[1][0])
     assert runner.weights["cast_leaves"] == 0  # resident shapes given
     # the lanes' recurrent state at its real size ({}: the family has none)
     state = {}
@@ -258,22 +278,23 @@ def served_runner(one_chip, request):
 # Prefill, chunk and decode take the device-resident last sampled ids
 # first ("s" of them) and the slot(s) they leave theirs at (PR 31), and
 # behind the ids the lanes' recurrent state ("state": {} but for a family
-# that has it)
+# that has it). "k": one such argument a kind of KV layer (block ids and
+# tables), bare where the family has one kind
 PROGRAMS = {
     "prefill": ("_prefill_impl", [(("s",), "i"), "state", ((1, "p"), "i"),
                                   ((), "i"),
-                                  (("p",), "i"), (("p",), "i"),
+                                  (("p",), "k"), (("p",), "i"),
                                   ((), "i")], 1),
     "chunk-256": ("_chunk_impl", [(("s",), "i"), "state", ((1, 256), "i"),
-                                  ((), "i"), ((), "i"), ((256,), "i"),
+                                  ((), "i"), ((), "i"), ((256,), "k"),
                                   ((256,), "i"),
-                                  (("m",), "i"), ((), "i")], 1),
+                                  (("m",), "k"), ((), "i")], 1),
     "verify-5": ("_verify_impl", [((1, 5), "i"), ((), "i"), ((), "i"),
-                                  ((5,), "i"), ((5,), "i"),
-                                  (("m",), "i")], 5),
+                                  ((5,), "k"), ((5,), "i"),
+                                  (("m",), "k")], 5),
     "decode": ("_decode_impl", [(("s",), "i"), "state", (("s",), "i"),
                                 (("s",), "i"), (("s",), "i"),
-                                (("s", "m"), "i")], "s"),
+                                (("s", "m"), "k")], "s"),
 }
 
 
@@ -297,15 +318,20 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
         pytest.skip("no cell serves this model through the paged kernel")
     if program == "verify-5" and state:
         pytest.skip("the engine refuses speculation for a stateful family")
+    if program == "verify-5" and len(runner.layouts) > 1:
+        pytest.skip("the engine refuses speculation with a window kind")
     method, shapes, lanes = PROGRAMS[program]
     sizes = {"m": runner.max_blocks_per_seq, "p": MODELS[model][4],
              "s": runner.max_batch_size}
     lanes = sizes.get(lanes, lanes)
 
     def arg(shape, kind):
-        return jax.ShapeDtypeStruct(
+        one = jax.ShapeDtypeStruct(
             tuple(sizes.get(d, d) for d in shape),
-            jnp.int32 if kind == "i" else jnp.float32, sharding=one_chip)
+            jnp.float32 if kind == "f" else jnp.int32, sharding=one_chip)
+        if kind == "k" and len(runner.layouts) > 1:
+            return (one,) * len(runner.layouts)
+        return one
 
     args = [state if s == "state" else arg(*s) for s in shapes] + [
         arg((lanes,), "f"), arg((lanes,), "i"), arg((lanes,), "f"),
@@ -313,16 +339,18 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
     runner.use_paged_attention = paged
     donate = (1, 2) if program == "verify-5" else (1, 2, 4)
     compiled = jax.jit(getattr(runner, method), donate_argnums=donate) \
-        .lower(params, pool, pool, *args).compile()
+        .lower(params, *pool, *args).compile()
     text = compiled.as_text()
+    print(f"{model} {program}: temporaries "
+          f"{compiled.memory_analysis().temp_size_in_bytes / 1e6:.1f} MB")
     assert "tpu_custom_call" in text or not paged
 
     def results(opcode):
         return [tuple(map(int, m.group(1).split(","))) for m in re.finditer(
             r"= \w+\[([\d,]+)\]\S* " + opcode + r"\(", text)]
 
-    # the pool, which the SSM part of the state is larger than
-    pool_elements = math.prod(pool.shape)
+    # the smallest pool, which the SSM part of the state is larger than
+    pool_elements = min(math.prod(a.shape) for a in jax.tree.leaves(pool))
     assert all(math.prod(a.shape) >= pool_elements
                for name, a in state.items() if name == "ssm")
     assert not [r for r in results("copy")
@@ -340,7 +368,7 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
     assert not [r for r in results("convert") if r in held]
     if program == "decode" and not paged:
         assert not _full_width_contexts(text, runner, [
-            pool, *params_and_state(params, state)])
+            *jax.tree.leaves(pool), *params_and_state(params, state)])
 
 
 def params_and_state(params, state):

@@ -109,7 +109,7 @@ def _state(runner):
 
 def test_the_adapter_says_what_the_family_caches():
     ad = adapters()["nemotron_h"]
-    assert ad.kv_layers(CFG) == 1 and CFG.n_layer == 7
+    assert [k.layers for k in ad.kv_kinds(CFG)] == [1] and CFG.n_layer == 7
     layers, parts = ad.state_fn(CFG)
     assert layers == 3
     # the SSM state in float32 whatever the compute dtype (`assumed`)

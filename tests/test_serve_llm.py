@@ -737,6 +737,57 @@ def test_llm_deployment_8_concurrent_streams(llm_cluster):
         serve.delete("llm")
 
 
+def test_llm_deployment_streams_a_family_with_two_kinds_of_kv_layer(
+        llm_cluster):
+    """`serve.run` of the mimo_v2 family through the same LLMServer,
+    engine, scheduler and runner: prompts of several windows stream
+    token by token, the tokens are those of an engine driven in this
+    process on the same seeded weights, and the replica's status carries
+    the pools by kind, the window kind's pages given back on the way."""
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.serve.llm import (
+        EngineConfig,
+        LLMEngine,
+        SamplingParams,
+        build_llm_app,
+    )
+
+    engine_config = {"block_size": 4, "num_blocks": 96, "max_model_len": 64,
+                     "max_batch_size": 4, "prefill_chunk_size": 8}
+    rng = np.random.RandomState(6)
+    prompts = [rng.randint(1, 500, size=n).tolist() for n in (5, 19, 33, 26)]
+    local = LLMEngine(EngineConfig(model="mimo_v2", preset="tiny",
+                                   **engine_config))
+    want = [local.generate(p, SamplingParams(max_tokens=12), drive=True)
+            ["token_ids"] for p in prompts]
+    handle = serve.run(build_llm_app(
+        model="mimo_v2", preset="tiny", engine_config=engine_config,
+        max_ongoing_requests=8), name="llm-mimo")
+    try:
+        sh = handle.options(stream=True, generator_backpressure=64)
+        gens = [sh.remote({"prompt": p, "max_tokens": 12}) for p in prompts]
+        got = []
+        for gen in gens:
+            *toks, final = [ray_tpu.get(r, timeout=180) for r in gen]
+            assert [e["index"] for e in toks] == list(range(12))
+            assert final["done"] and final["finish_reason"] == "length"
+            got.append(final["token_ids"])
+        assert got == want
+
+        from ray_tpu.util.state import llm_status
+
+        stats, = llm_status("llm-mimo")
+        assert stats["model"] == "mimo_v2"
+        assert stats["running"] == 0 and stats["waiting"] == 0
+        assert list(stats["kv"]) == ["full", "window"]
+        assert stats["kv"]["window"]["released_behind_window"] > 0
+        assert stats["kv"]["window"]["pages_used"] == 0
+        assert stats["kv"]["window"]["largest_table"] <= 5
+    finally:
+        serve.delete("llm-mimo")
+
+
 def test_affinity_routing_concentrates_shared_prefix(llm_cluster):
     """Prefix-affinity routing: requests sharing a prompt prefix carry
     the same affinity key, rendezvous onto ONE of two replicas, and

@@ -22,7 +22,7 @@ from ray_tpu.serve.llm import (
     SpeculativeConfig,
 )
 
-MODELS = ("gpt2", "olmoe", "nemotron_h")
+MODELS = ("gpt2", "olmoe", "nemotron_h", "mimo_v2")
 
 
 def _engine(model="gpt2", **overrides):
@@ -35,6 +35,10 @@ def _engine(model="gpt2", **overrides):
             gpt2.GPT2Config.tiny(), dtype=jnp.float32, remat=False))
     elif model == "nemotron_h":  # recurrent state beside the pages
         kw.update(model="nemotron_h", preset="tiny")
+    elif model == "mimo_v2":  # two kinds of KV layer; a window of 8 whose
+        # pages go back to their pool, and to other lanes, as rows are
+        # planned (release is always on)
+        kw.update(model="mimo_v2", preset="tiny")
     else:  # the routed-expert block through the llama path, float32
         kw.update(model="llama", preset="olmoe_tiny")
     kw.update(overrides)
@@ -184,7 +188,7 @@ def _eos_case(model="gpt2", first=2):
     return list(zip(prompts, sps)), lane, k
 
 
-@pytest.mark.parametrize("model", ("gpt2", "nemotron_h"))
+@pytest.mark.parametrize("model", ("gpt2", "nemotron_h", "mimo_v2"))
 def test_eos_mid_flight_costs_one_discarded_lane_step(model):
     """The step behind an eos ran its lane once more: that id is counted
     and dropped, no event follows the eos, and the other lanes of that
@@ -294,6 +298,33 @@ def test_a_pool_that_forces_preemption_drains_and_gives_the_same_output():
     o = _assert_counters_add_up(ahead)
     assert o["drains"]["preempt"] > 0 and o["discarded_tokens"] == 0
     _assert_drained(ahead)
+
+
+def test_a_window_pool_that_runs_dry_drains_and_gives_the_same_tokens():
+    """The same where it is the window kind's pool alone that runs dry
+    under a lane's next chunk or step: the plan refuses, the loop reads
+    the step in flight and schedules again. A lane is admitted only where
+    that pool has its bound free, and how much is free when a plan looks
+    depends on what is in flight, so the two loops may admit at different
+    steps and batch their lanes differently: the tokens are the same, the
+    log-probs the same to a rounding (another bucket's program)."""
+    kw = dict(model="mimo_v2", num_blocks=[96, 10], enable_prefix_cache=False)
+    reqs = [(p, SamplingParams(max_tokens=16, logprobs=True))
+            for p in _prompts([30, 9, 28, 12])]
+    ahead, drained = _engine(**kw), _never_ahead(_engine(**kw))
+    got, want = _serve(ahead, reqs), _serve(drained, reqs)
+    assert [o["tokens"] for o in got] == [o["tokens"] for o in want]
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a["logprobs"], b["logprobs"], atol=1e-5)
+    assert sum(o["preemptions"] for o in want) > 0
+    o = _assert_counters_add_up(ahead)
+    assert o["drains"]["preempt"] > 0 and o["discarded_tokens"] == 0
+    _assert_drained(ahead)
+    _assert_drained(drained)
+    for engine in (ahead, drained):
+        kv = engine.stats()["kv"]
+        assert kv["window"]["pages_used"] == kv["full"]["pages_used"] == 0
+        assert kv["window"]["largest_table"] <= 5
 
 
 def test_update_weights_mid_stream_never_splits_a_step():
@@ -426,7 +457,7 @@ def test_a_decode_lane_takes_its_unread_id_from_the_device(model, lengths):
     fam = model if model != "olmoe" else "llama"
     adapter = adapters()[fam]
     cfg = adapter.presets["olmoe_tiny" if model == "olmoe" else "tiny"]()
-    if model != "nemotron_h":  # its tiny preset is float32 as it is
+    if model not in ("nemotron_h", "mimo_v2"):  # tiny presets float32 as they are
         cfg = dataclasses.replace(cfg, dtype=jnp.float32, remat=False)
     params = adapter.init_fn(jax.random.PRNGKey(0), cfg)
 
