@@ -4,8 +4,10 @@ Two paths:
 - `causal_attention_reference`: plain jnp einsum formulation — XLA fuses
   this well and it runs on any backend (CPU tests, interpret mode).
 - `flash_attention`: pallas TPU kernel (ray_tpu.ops.flash_attention) with
-  online softmax and block-sparse causal masking, used on TPU for long
-  sequences.
+  online softmax, used on TPU for long sequences: it reads and writes
+  `(B, T, H, D)` where it lies (two 64-wide heads a 128-lane block, or one
+  head of a multiple of 128), computes no score tile above the diagonal
+  and masks only the tiles the diagonal crosses.
 
 Softmax statistics are computed in float32 regardless of input dtype
 (bfloat16 accumulation loses too much precision on long sequences).
@@ -58,7 +60,10 @@ def sharded_flash_attention(q, k, v, mesh, *, interpret: bool = False):
     partition a Mosaic kernel, so on a mesh it runs per shard inside a
     shard_map — batch over (data, fsdp), heads over tensor, the layout
     the models constrain q/k/v to. Attention mixes neither batch rows
-    nor heads, so the shards need no collective."""
+    nor heads, so the shards need no collective. The kernel picks its
+    layout from the shape it sees there, a shard's: an even count of
+    64-wide heads a shard is paired, an odd one takes the per-head path
+    (`flash_attention.plan`)."""
     from ray_tpu.ops.flash_attention import flash_attention
 
     kernel = functools.partial(flash_attention, causal=True,

@@ -30,13 +30,16 @@ def _lowers_for_tpu(fn, *args):
 
 # (H, H_kv, D): gpt2-small, and a grouped-query shape
 SHAPES = {"gpt2-small": (12, 12, 64), "gqa": (32, 8, 128)}
+# the flash kernel's three layouts: two heads a 128-lane block, one head a
+# block, and (H odd at D = 64) the heads moved beside the batch
+FLASH_SHAPES = dict(SHAPES, **{"gpt2-odd-heads": (3, 3, 64)})
 
 
-@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
 def test_flash_attention_lowers_for_tpu(shape):
     from ray_tpu.ops.flash_attention import flash_attention
 
-    H, _, D = SHAPES[shape]
+    H, _, D = FLASH_SHAPES[shape]
     q = jax.ShapeDtypeStruct((2, 1024, H, D), jnp.bfloat16)
     _lowers_for_tpu(flash_attention, q, q, q)
 

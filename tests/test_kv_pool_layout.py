@@ -398,3 +398,59 @@ def _full_width_contexts(text, runner, arguments):
     return [s for s in sorted(shapes)
             if math.prod(s) >= whole and inner(s) not in known] \
         + [s for s in FULL_WIDTH if s in text]
+
+
+# ------------------------------ the flash kernel's gradient, for the v5e
+
+# The train cells' attention, (32, 1024, 12, 64) bf16. "model": as
+# models/gpt2.py calls it, on (B, T, H*D) arrays seen as heads (XLA gives
+# a 4-D PARAMETER a layout that pads 12 x 64 to 16 x 128, which only a
+# program whose arguments are the heads themselves ever sees); "heads":
+# that program, the 4-D arguments ISSUE 35 names
+FLASH_SHAPE = (32, 1024, 12, 64)
+# Temporaries of forward and gradient, bytes: 906,066,432 while q, k, v,
+# o and their gradients were copied to (B*H, T, D) and back and the row
+# statistics ended in a dimension of 1; 0 ("model") and 151 MB ("heads":
+# the padded parameters unpadded) since PR 35
+FLASH_TEMP_BOUND = 450e6
+
+
+@pytest.mark.parametrize("arguments", ["model", "heads"])
+def test_flash_gradient_is_dense_on_the_v5e(one_chip, arguments):
+    """Compiled for the v5e, `grad(flash_attention)` at the train cells'
+    shape holds no operand or result of a kernel whose minor dimension is
+    1 (201 MB where 1.5 are meant), no transposing copy of a
+    `[.., 1024, 64]` tensor, and few temporaries; called as the model
+    calls it, no copy of anything as large as q at all."""
+    from ray_tpu.ops.flash_attention import flash_attention, plan
+
+    B, T, H, D = FLASH_SHAPE
+    assert plan(T, H, D)["heads_per_block"] == 2
+    shape = FLASH_SHAPE if arguments == "heads" else (B, T, H * D)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def attention(q, k, v):
+        q, k, v = (t.reshape(FLASH_SHAPE) for t in (q, k, v))
+        return flash_attention(q, k, v).reshape(shape)
+
+    def forward_and_gradient(q, k, v, do):
+        o, vjp = jax.vjp(attention, q, k, v)
+        return o, vjp(do)
+
+    compiled = jax.jit(forward_and_gradient).lower(x, x, x, x).compile()
+    text = compiled.as_text()
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    print(f"flash gradient, {arguments}: temporaries {temporaries / 1e6:.1f}"
+          " MB")
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 4  # forward, delta, dq, dkv
+    for line in calls:
+        head = line.split("custom_call_target")[0]
+        assert not re.search(r"\[[\d,]*,1\]", head), head
+    copies = [tuple(map(int, m.group(1).split(","))) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* copy\(", text)]
+    assert not [c for c in copies if c[-2:] == (T, D)]
+    if arguments == "model":
+        assert not [c for c in copies if math.prod(c) >= B * T * H * D]
+    assert temporaries < FLASH_TEMP_BOUND
