@@ -76,11 +76,19 @@ class LLMServer:
     def _step_loop(self):
         import time
 
-        idle = self.engine.phases.phase("idle")
+        engine = self.engine
+        idle = engine.phases.phase("idle")
         while self._alive:
-            if not self.engine.step():
+            if engine.step():
+                continue
+            # nothing queued, running or in flight: sleep, and look for
+            # work, under the idle phase, so that a poll is in the
+            # loop's account and not beside it
+            waiting = True
+            while waiting and self._alive:
                 with idle:
-                    time.sleep(0.002)  # nothing queued or running
+                    time.sleep(0.002)
+                    waiting = not engine.has_work()
 
     def __call__(self, payload: dict | None):
         payload = payload or {}

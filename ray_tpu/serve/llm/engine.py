@@ -9,12 +9,13 @@ event.
 
 Engine metrics flow through `ray_tpu.util.metrics`, so every replica's
 numbers land on the process /metrics surface the dashboard scrapes:
-tokens/s, TTFT, per-step latency, queue depth, cache utilization,
-preemptions.
+tokens generated, TTFT, the gap between two tokens by what the loop did
+in it, per-step latency, queue depth, cache utilization, preemptions.
 """
 
 from __future__ import annotations
 
+import bisect
 import collections
 import contextlib
 import dataclasses
@@ -56,6 +57,76 @@ from ray_tpu.util import tracing
 _FINAL = object()
 # why a step was read with none launched behind it
 DRAIN_REASONS = ("speculation", "preempt", "swap", "abort", "idle", "error")
+# Upper edges, in ms, of the buckets every duration the loop accounts
+# for is counted in (token gaps, turns, a stream's hand-off): steps of
+# 0.1 ms to 20 ms, fine enough that a percentile read off the counts can
+# be laid beside one taken on a client's clock, then doubling to 20.48 s.
+# Bucket i holds [edges[i-1], edges[i]); one more above the last edge.
+GAP_EDGES_MS = tuple(round(0.1 * i, 1) for i in range(1, 201)) + tuple(
+    20.0 * 2 ** i for i in range(1, 11))
+# What the loop did between two tokens of one request, first match wins:
+# the request was preempted and recomputed; a prefill step (a whole
+# prompt or a chunk, of any request) was read; the step that sampled the
+# token was launched with nothing unread ahead of it (the loop had
+# stopped overlapping, for one of DRAIN_REASONS, or had paused); none of
+# these, two decode steps back to back.
+GAP_CAUSES = ("after_preempt", "after_prefill", "after_drain", "decode")
+# `serve_llm_itl_ms`'s boundaries: the same gaps for an operator
+ITL_BOUNDS_MS = (0.5, 1, 2, 3, 4, 5, 6, 8, 10, 15, 25, 50, 100, 250, 1000)
+SLOW_TURN_MS = 250.0  # ten times the longest sound turn of any cell
+
+
+def gap_bucket(ms: float) -> int:
+    """The bucket of GAP_EDGES_MS that a duration of `ms` falls in."""
+    return bisect.bisect_right(GAP_EDGES_MS, ms)
+
+
+class _Durations:
+    """Counts of durations on GAP_EDGES_MS with their sum and their
+    largest: one writer, readers copy."""
+
+    __slots__ = ("counts", "sum_ms", "max_ms")
+
+    def __init__(self):
+        self.counts = [0] * (len(GAP_EDGES_MS) + 1)
+        self.sum_ms = 0.0
+        self.max_ms = 0.0
+
+    def add(self, ms: float) -> None:
+        self.counts[gap_bucket(ms)] += 1
+        self.sum_ms += ms
+        if ms > self.max_ms:
+            self.max_ms = ms
+
+
+class StreamAccount:
+    """The replica's half of every stream's hand-off, summed over the
+    engine's streams: each folds its own counts in every 64 items and at
+    its end (`RequestStream`), under the lock."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.items = 0
+        self.pickup_s = 0.0
+        self.ship_s = 0.0
+        self.max_ms = 0.0
+        self.handoff = [0] * (len(GAP_EDGES_MS) + 1)
+
+    def fold(self, items: int, pickup_s: float, ship_s: float,
+             max_ms: float, buckets: dict) -> None:
+        with self._lock:
+            self.items += items
+            self.pickup_s += pickup_s
+            self.ship_s += ship_s
+            self.max_ms = max(self.max_ms, max_ms)
+            for i, n in buckets.items():
+                self.handoff[i] += n
+
+    def stats(self) -> dict:
+        with self._lock:
+            return {"items": self.items, "pickup_s": self.pickup_s,
+                    "ship_s": self.ship_s, "edges_ms": list(GAP_EDGES_MS),
+                    "handoff": list(self.handoff), "max_ms": self.max_ms}
 
 
 @dataclasses.dataclass
@@ -69,7 +140,6 @@ class _Flight:
     sampled: list[Sequence]
     unread: list[int]
     ver: int  # the weight version its program runs on
-    stalled: bool  # a prefill that holds decode-ready lanes back
     ahead: bool  # planned while the step before it was unread
     t0: float  # perf_counter at its planning
     handle: Launched | None = None
@@ -86,17 +156,40 @@ class RequestStream:
 
     Yields ``{"token": id, "index": n}`` dicts as tokens are produced,
     then raises StopIteration; `final()` returns the summary event
-    (token_ids, finish_reason, counts) once the stream is drained."""
+    (token_ids, finish_reason, counts) once the stream is drained.
 
-    def __init__(self, seq_id: int):
+    It times its own half of the hand-off to the client, on the
+    consumer's thread: an item's **pickup** (the loop put it down ->
+    the consumer took it up: the consumer's wake) and its **ship** (the
+    consumer was handed it -> it asked for the next: what it spent
+    serialising and sending it; also an `llm.stream.ship` event in a
+    device profile). Kept on the stream without a lock and folded into
+    `account`, where there is one, every FOLD_EVERY items and at the
+    stream's end."""
+
+    FOLD_EVERY = 64
+
+    def __init__(self, seq_id: int, account: StreamAccount | None = None):
         self.seq_id = seq_id
         self._q: "queue.Queue[Any]" = queue.Queue()
         self._final: dict | None = None
         self._ended = False  # sentinel consumed (iteration or next_event)
+        self._account = account
+        # the item handed out and not yet asked beyond: when it was
+        # handed out, its pickup, and the profile's event over its ship
+        self._out: tuple | None = None
+        # not yet folded: items, pickup and ship seconds, the longest
+        # hand-off in ms, hand-offs by bucket of GAP_EDGES_MS
+        self._n = 0
+        self._pickup_s = 0.0
+        self._ship_s = 0.0
+        self._max_ms = 0.0
+        self._buckets: dict[int, int] = {}
 
     # engine side -----------------------------------------------------
     def _emit(self, ev: dict) -> None:
-        self._q.put(ev)
+        # the time it was put down travels beside the event, not in it
+        self._q.put((ev, time.perf_counter()))
 
     def _close(self, final: dict) -> None:
         self._final = final
@@ -107,11 +200,8 @@ class RequestStream:
         return self
 
     def __next__(self):
-        if self._ended:
-            raise StopIteration
-        ev = self._q.get()
-        if ev is _FINAL:
-            self._ended = True
+        ev = self.next_event()
+        if ev is None:
             raise StopIteration
         return ev
 
@@ -121,15 +211,44 @@ class RequestStream:
         event arrives within `timeout` seconds."""
         if self._ended:
             return None
+        out = self._out
+        if out is not None:  # asked for the next: the last is sent
+            now = time.perf_counter()
+            self._out = None
+            handed, pickup, ship = out
+            ship.__exit__(None, None, None)
+            self._n += 1
+            self._pickup_s += pickup
+            self._ship_s += now - handed
+            ms = (pickup + now - handed) * 1e3
+            if ms > self._max_ms:
+                self._max_ms = ms
+            i = gap_bucket(ms)
+            self._buckets[i] = self._buckets.get(i, 0) + 1
+            if self._n >= self.FOLD_EVERY:
+                self._fold()
         try:
-            ev = self._q.get(timeout=timeout)
+            item = self._q.get(timeout=timeout)
         except queue.Empty:
             raise TimeoutError(
                 f"no token event within {timeout}s") from None
-        if ev is _FINAL:
+        if item is _FINAL:
             self._ended = True
+            self._fold()
             return None
+        ev, put_down = item
+        ship = tracing.annotate("llm.stream.ship")
+        ship.__enter__()
+        now = time.perf_counter()
+        self._out = (now, now - put_down, ship)
         return ev
+
+    def _fold(self) -> None:
+        if self._account is not None and self._n:
+            self._account.fold(self._n, self._pickup_s, self._ship_s,
+                               self._max_ms, self._buckets)
+        self._n, self._pickup_s, self._ship_s = 0, 0.0, 0.0
+        self._max_ms, self._buckets = 0.0, {}
 
     def final(self) -> dict | None:
         return self._final
@@ -295,6 +414,23 @@ class LLMEngine:
         # read and the one behind it). Touched under _step_lock only
         self._flights: collections.deque[_Flight] = collections.deque()
         self._last_collect = 0.0  # perf_counter at the last step's end
+        # every gap between two tokens of one request, by what the loop
+        # did in it (GAP_CAUSES), and the tokens that had none: a verify
+        # dispatch's after its first. `_prefill_reads`: prefill steps
+        # read so far, which a sequence remembers at each of its tokens
+        self._gaps = {cause: _Durations() for cause in GAP_CAUSES}
+        self._burst_tokens = 0
+        self._prefill_reads = 0
+        # the same gaps on `serve_llm_itl_ms`'s boundaries, since the
+        # last step's bookkeeping: {cause: [{bucket: n}, their sum in ms]}
+        self._itl_pending: dict[str, list] = {}
+        # the loop's own wall clock (`perf_counter` at the first call of
+        # step()), and its turns by the kind of step they read: how
+        # long each took, and the seconds of those over SLOW_TURN_MS
+        self._loop_t0: float | None = None
+        self._turns = {kind: _Durations() for kind in ("decode", "prefill")}
+        self._slow_turn_s = dict.fromkeys(self._turns, 0.0)
+        self._stream_account = StreamAccount()
         # how often the next step was on the device before this one's
         # results were read, and why not when it was not (stats())
         self._overlap = {
@@ -321,7 +457,6 @@ class LLMEngine:
         # an abort): the loop lets them in before its next turn, which a
         # plain lock released and taken again at once never does
         self._urgent = 0  # guarded_by(_lock)
-        self._tokens_window: list[tuple[float, int]] = []  # (t, n)
         # weight hot-swap state: bumped only by update_weights(), which
         # holds _step_lock — so within one step() every sampled token
         # sees ONE version (no mid-decode-step version mix)
@@ -357,16 +492,25 @@ class LLMEngine:
         self._m_cache = Gauge(
             "serve_llm_cache_utilization",
             "KV pool pages in use / usable pages", tag_keys=tags)
-        self._m_tps = Gauge(
-            "serve_llm_tokens_per_sec",
-            "Generation throughput over the last ~5s", tag_keys=tags)
         self._m_ttft = Histogram(
             "serve_llm_ttft_ms", "Time to first token",
             boundaries=(1, 5, 10, 25, 50, 100, 250, 500, 1000, 5000),
             tag_keys=tags)
+        self._m_itl = Histogram(
+            "serve_llm_itl_ms",
+            "Gap between two streamed tokens of one request, where the "
+            "loop emits them, by what the loop did in it: after_preempt "
+            "(the request was recomputed), after_prefill (a prefill "
+            "step of any request was read between), after_drain (the "
+            "sampling step was launched with none unread ahead of it), "
+            "decode (two decode steps back to back)",
+            boundaries=ITL_BOUNDS_MS, tag_keys=("model", "cause"))
+        self._itl_tags = {cause: {"model": self.config.model, "cause": cause}
+                          for cause in GAP_CAUSES}
         self._m_step = Histogram(
             "serve_llm_step_ms", "Engine step latency",
-            boundaries=(1, 5, 10, 25, 50, 100, 250, 500, 1000),
+            boundaries=(1, 2, 3, 4, 5, 6, 8, 10, 12, 15, 20, 25, 50, 100,
+                        250, 500, 1000),
             tag_keys=("model", "kind"))
         self._m_prefix_hits = Counter(
             "serve_llm_prefix_cache_hits_total",
@@ -385,12 +529,6 @@ class LLMEngine:
         self._m_chunks = Counter(
             "serve_llm_prefill_chunks_total",
             "Prefill chunks executed", tag_keys=tags)
-        self._m_stall = Histogram(
-            "serve_llm_prefill_stall_ms",
-            "Decode stall imposed by a prefill step that ran while "
-            "decode-ready lanes were waiting",
-            boundaries=(1, 5, 10, 25, 50, 100, 250, 500, 1000),
-            tag_keys=tags)
         self._m_swaps = Counter(
             "serve_llm_weight_swaps_total",
             "Weight hot-swaps installed at a step boundary",
@@ -552,19 +690,6 @@ class LLMEngine:
         self._m_weight_bytes.set(w["resident_bytes"], tags=self._m_tags)
         self._m_weight_cast.set(w["cast_leaves"], tags=self._m_tags)
 
-    def _note_tokens(self, n: int) -> None:
-        self._m_tokens.inc(n, tags=self._m_tags)
-        now = time.monotonic()
-        self._tokens_window.append((now, n))
-        cutoff = now - 5.0
-        while self._tokens_window and self._tokens_window[0][0] < cutoff:
-            self._tokens_window.pop(0)
-        span = max(1e-3, now - self._tokens_window[0][0]) \
-            if self._tokens_window else 1.0
-        self._m_tps.set(
-            sum(k for _, k in self._tokens_window) / span,
-            tags=self._m_tags)
-
     # ------------------------------------------------------------ intake
 
     def add_request(self, prompt: Seq[int],
@@ -582,7 +707,7 @@ class LLMEngine:
         from ray_tpu.utils.events import child_trace
 
         seq.trace = child_trace(tracing.current_trace())
-        stream = RequestStream(seq.seq_id)
+        stream = RequestStream(seq.seq_id, self._stream_account)
         with self._lock:
             # validate (scheduler.add raises on over-long prompts) BEFORE
             # registering the stream, or rejected requests leak entries
@@ -626,76 +751,87 @@ class LLMEngine:
         Serialized: concurrent callers queue behind `_step_lock` (the
         deployment runs a single loop thread; tests may drive from
         several)."""
-        while self._urgent:  # a swap or an abort waits for the lock
-            time.sleep(0.0002)
-        with self._step_lock:
+        t_in = time.perf_counter()
+        if self._loop_t0 is None:
+            self._loop_t0 = t_in
+        if self._urgent or not self._step_lock.acquire(blocking=False):
+            with self.phases.phase("yield"):
+                while self._urgent:  # a swap or an abort wants the lock
+                    time.sleep(0.0002)
+                self._step_lock.acquire()
+        try:
             planned = []
-            if not self._flights:
-                first, closed = self._plan(ahead=False)
-                if first is None:
-                    return closed  # nothing to run
-                planned.append(first)
-            if self._proposer is not None:
-                # drafts are read from the tokens this step commits
-                self._note_drain("speculation")
-            else:
-                behind, _ = self._plan(ahead=True)
-                if behind is not None:
-                    planned.append(behind)
-            self._turn(planned)
+            with self.phases.phase("schedule"):
+                if not self._flights:
+                    first, closed = self._plan(ahead=False)
+                    if first is None:
+                        return closed  # nothing to run
+                    planned.append(first)
+                if self._proposer is not None:
+                    # drafts are read from the tokens this step commits
+                    self._note_drain("speculation")
+                else:
+                    behind, _ = self._plan(ahead=True)
+                    if behind is not None:
+                        planned.append(behind)
+            self._turn(planned, t_in)
             return True
+        finally:
+            self._step_lock.release()
 
     def _plan(self, ahead: bool) -> "tuple[_Flight | None, bool]":
-        """One scheduler decision. `ahead`: a step is in flight and its
-        results are unread, so every one of its lanes is taken to
-        continue. Returns the step to launch, if there is one, and
-        whether `schedule()` itself closed a sequence out."""
-        with self.phases.phase("schedule"):
-            t0 = time.perf_counter()
-            with self._lock:
-                pre = self.scheduler.preemption_count
-                try:
-                    # may preempt lanes, unless their tokens are in flight
-                    work = self.scheduler.schedule(may_preempt=not ahead)
-                except NeedsResults:
-                    self._note_drain("preempt")
-                    return None, False
-                d_pre = self.scheduler.preemption_count - pre
-                retired = self.scheduler.take_retired()
-                # lanes a prefill step is holding back
-                stalled = isinstance(work, PrefillWork) and any(
-                    s is not work.seq and not s.prefill_pending
-                    for s in self.scheduler.running)
-            if d_pre:
-                self._m_preempt.inc(d_pre, tags=self._m_tags)
-            for s in retired:  # schedule() closed these out itself
-                self._finalize(s)
-            if work is None:
-                if ahead:
-                    self._note_drain("idle")
-                return None, retired != []
-            if isinstance(work, PrefillWork):
-                kind = "prefill"
-                sampled = [work.seq] if work.is_last else []
-            else:
-                kind, sampled = "decode", work.seqs
-            # how many of a lane's tokens the host will not have read
-            # when this step is launched
-            unread = [s.inflight for s in sampled]
-            for s in sampled:
-                s.inflight += 1
-            return _Flight(kind, work, sampled, unread, self._weight_version,
-                           stalled, ahead, t0), retired != []
+        """One scheduler decision, inside step()'s `schedule` phase.
+        `ahead`: a step is in flight and its results are unread, so
+        every one of its lanes is taken to continue. Returns the step to
+        launch, if there is one, and whether `schedule()` itself closed
+        a sequence out."""
+        t0 = time.perf_counter()
+        with self._lock:
+            pre = self.scheduler.preemption_count
+            try:
+                # may preempt lanes, unless their tokens are in flight
+                work = self.scheduler.schedule(may_preempt=not ahead)
+            except NeedsResults:
+                self._note_drain("preempt")
+                return None, False
+            d_pre = self.scheduler.preemption_count - pre
+            retired = self.scheduler.take_retired()
+        if d_pre:
+            self._m_preempt.inc(d_pre, tags=self._m_tags)
+        for s in retired:  # schedule() closed these out itself
+            self._finalize(s)
+        if work is None:
+            if ahead:
+                self._note_drain("idle")
+            return None, retired != []
+        if isinstance(work, PrefillWork):
+            kind = "prefill"
+            sampled = [work.seq] if work.is_last else []
+        else:
+            kind, sampled = "decode", work.seqs
+        # how many of a lane's tokens the host will not have read
+        # when this step is launched
+        unread = [s.inflight for s in sampled]
+        for s in sampled:
+            s.inflight += 1
+        return _Flight(kind, work, sampled, unread, self._weight_version,
+                       ahead, t0), retired != []
 
-    def _turn(self, planned: "list[_Flight]") -> None:
+    def _turn(self, planned: "list[_Flight]", t_in: float) -> None:
         """Launch what was planned, then collect the oldest step in
         flight: one `llm.step.<kind>` interval, named after the step
-        whose results it reads."""
+        whose results it reads. The turn began at `t_in` (with the wait
+        for the engine and the planning, in step()) and is written down
+        by that kind."""
         oldest = self._flights[0] if self._flights else planned[0]
         with tracing.annotate("llm.step." + oldest.kind):
             for flight in planned:
                 self._launch(flight)
             self._collect(self._flights.popleft())
+        ms = (time.perf_counter() - t_in) * 1e3
+        self._turns[oldest.kind].add(ms)
+        if ms > SLOW_TURN_MS:
+            self._slow_turn_s[oldest.kind] += ms / 1e3
 
     def _drain(self, reason: str | None) -> None:
         """Read every step in flight: for a caller that holds
@@ -703,7 +839,7 @@ class LLMEngine:
         if self._flights and reason:
             self._note_drain(reason)
         while self._flights:
-            self._turn([])
+            self._turn([], time.perf_counter())
 
     def _note_drain(self, reason: str) -> None:
         self._overlap["drains"][reason] += 1
@@ -714,9 +850,6 @@ class LLMEngine:
         """Enqueue a planned step's program; nothing is read."""
         which = "launched_ahead" if flight.ahead else "launched_drained"
         self._overlap[which][flight.kind] += 1
-        self._m_launched.inc(tags={
-            "model": self.config.model, "kind": flight.kind,
-            "ahead": "1" if flight.ahead else "0"})
         try:
             if flight.kind == "prefill":
                 flight.handle = self._launch_prefill(flight.work)
@@ -728,10 +861,12 @@ class LLMEngine:
 
     def _collect(self, flight: "_Flight") -> None:
         """Wait for a launched step, commit and emit what it sampled,
-        and write the step down."""
+        write the step down, and let its device results go."""
         for s in flight.sampled:
             s.inflight -= 1
-        tokens = 0
+        if flight.kind == "prefill":
+            self._prefill_reads += 1
+        tokens, nxt, logits = 0, None, None
         try:
             if flight.error is not None:
                 raise flight.error
@@ -761,15 +896,21 @@ class LLMEngine:
             now = time.perf_counter()
             step_ms = (now - max(flight.t0, self._last_collect)) * 1e3
             self._last_collect = now
-            self._bookkeep(flight.kind, tokens, flight.stalled, step_ms)
+            self._bookkeep(flight.kind, tokens, flight.ahead, step_ms)
+        with self.phases.phase("release"):
+            # the last references to the program's results on the device
+            # and to their copies on the host (a lane's logits row is
+            # 200 KB at gpt2-large: freeing it is a system call)
+            flight.handle = nxt = logits = None
 
-    def _bookkeep(self, kind: str, tokens: int, stalled: bool,
+    def _bookkeep(self, kind: str, tokens: int, ahead: bool,
                   step_ms: float) -> None:
         """What a step writes down once its program's results are out:
         the step's metrics, the scheduler's gauges, the prefix cache's
-        counters, the token rate, and the bytes it fetched."""
-        if stalled:
-            self._m_stall.observe(step_ms, tags=self._m_tags)
+        counters, the tokens and their gaps, and the bytes it fetched."""
+        self._m_launched.inc(tags={
+            "model": self.config.model, "kind": kind,
+            "ahead": "1" if ahead else "0"})
         self._m_step.observe(
             step_ms, tags={"model": self.config.model, "kind": kind})
         depth = self.scheduler.depth()
@@ -791,7 +932,11 @@ class LLMEngine:
             self._m_prefix_evict.inc(evict - le, tags=self._m_tags)
         self._note_kv()
         if tokens:
-            self._note_tokens(tokens)
+            self._m_tokens.inc(tokens, tags=self._m_tags)
+        for cause, (buckets, total_ms) in self._itl_pending.items():
+            self._m_itl.observe_buckets(buckets, total_ms,
+                                        tags=self._itl_tags[cause])
+        self._itl_pending.clear()
         self._steps[kind] += 1
         fetched = self.runner.fetched_bytes - self._fetched_seen
         self._fetched_seen += fetched
@@ -908,6 +1053,7 @@ class LLMEngine:
                 seq.token_versions.append(ver)
                 done = self.scheduler.commit_token(seq, nxt)
         with self.phases.phase("emit"):
+            self._note_gap(seq, time.perf_counter(), flight.ahead)
             self._emit_token(seq, nxt, ver)
             if done:
                 self._finalize(seq)
@@ -990,10 +1136,10 @@ class LLMEngine:
         ended while the program ran (an eos in the step before it, an
         abort) gets nothing: its id is dropped."""
         ver = flight.ver
-        lanes = [(i, s, tok) for i, (s, tok) in enumerate(
-            zip(flight.plain, next_tokens))
-            if s.state is SeqState.RUNNING]
         with self.phases.phase("commit"):
+            lanes = [(i, s, tok) for i, (s, tok) in enumerate(
+                zip(flight.plain, next_tokens))
+                if s.state is SeqState.RUNNING]
             self._discard(len(next_tokens) - len(lanes))
             for i, s, tok in lanes:
                 if s.sampling.logprobs:
@@ -1009,11 +1155,40 @@ class LLMEngine:
                     if self.scheduler.commit_token(s, tok):
                         finished.append(s)
         with self.phases.phase("emit"):
+            now = time.perf_counter()  # one reading for the step's lanes
             for _, s, tok in lanes:
+                self._note_gap(s, now, flight.ahead)
                 self._emit_token(s, tok, ver)
             for s in finished:
                 self._finalize(s)
         return len(lanes)
+
+    def _note_gap(self, seq: Sequence, now: float, ahead: bool) -> None:
+        """A token of `seq` is emitted at `now` by a step launched
+        `ahead` of the read before it or not: the gap since the
+        request's last token goes to one of GAP_CAUSES, first match
+        wins. Its first token has none."""
+        last = seq.emit_at
+        if last is not None:
+            if seq.preemptions != seq.emit_preemptions:
+                cause = "after_preempt"
+            elif self._prefill_reads != seq.emit_prefills:
+                cause = "after_prefill"
+            elif not ahead:
+                cause = "after_drain"
+            else:
+                cause = "decode"
+            ms = (now - last) * 1e3
+            self._gaps[cause].add(ms)
+            pending = self._itl_pending.get(cause)
+            if pending is None:
+                pending = self._itl_pending[cause] = [{}, 0.0]
+            i = bisect.bisect_left(ITL_BOUNDS_MS, ms)
+            pending[0][i] = pending[0].get(i, 0) + 1
+            pending[1] += ms
+        seq.emit_at = now
+        seq.emit_prefills = self._prefill_reads
+        seq.emit_preemptions = seq.preemptions
 
     def _verify_one(self, seq: Sequence, draft: list[int],
                     ver: int) -> int:
@@ -1024,7 +1199,9 @@ class LLMEngine:
         eos / max_tokens) and emit with explicit stream indices.
         Returns the tokens committed."""
         sp = seq.sampling
-        t0 = time.perf_counter()
+        # the dispatch's latency is the runner's three phases of it
+        spent = self.phases.seconds
+        before = spent["prepare"] + spent["dispatch"] + spent["fetch"]
         try:
             tokens, logits = self.runner.verify(
                 seq.last_token, seq.pos - 1, draft, seq.tables,
@@ -1036,7 +1213,8 @@ class LLMEngine:
             return 0
         with self.phases.phase("commit"):
             self._m_verify_ms.observe(
-                (time.perf_counter() - t0) * 1e3, tags=self._m_tags)
+                (spent["prepare"] + spent["dispatch"] + spent["fetch"]
+                 - before) * 1e3, tags=self._m_tags)
             n_acc = len(tokens) - 1
             self._spec_proposed_total += len(draft)
             self._spec_accepted_total += n_acc
@@ -1064,6 +1242,10 @@ class LLMEngine:
                         break
         with self.phases.phase("emit"):
             base = len(seq.generated) - len(committed)
+            # the dispatch's first token carries the gap; the rest of
+            # its run comes with it
+            self._note_gap(seq, time.perf_counter(), ahead=False)
+            self._burst_tokens += len(committed) - 1
             for j, tok in enumerate(committed):
                 self._emit_token(seq, tok, ver, index=base + j)
             if done:
@@ -1250,7 +1432,9 @@ class LLMEngine:
             wall = time.perf_counter() - t0
             spent = {k: v - before[k]
                      for k, v in tracing.compile_totals().items()}
-            # what warm-up fetched, routed and read is no step's
+            # what warm-up fetched, routed, read and timed is no step's
+            self.phases.seconds.update(
+                dict.fromkeys(self.phases.seconds, 0.0))
             self._fetched_seen = self.runner.fetched_bytes
             self.runner.take_expert_pairs()
             for kind, n in self.runner.context_slots.items():
@@ -1311,6 +1495,30 @@ class LLMEngine:
             # seconds by phase, steps and bytes fetched by step kind,
             # and where this replica's start went
             "step_phase_seconds": dict(self.phases.seconds),
+            # the loop's wall time since its first call of step(), and
+            # its turns by the kind of step they read; what `wall_s`
+            # holds beyond the phases' sum ran under no phase (or is the
+            # phase the loop is in right now)
+            "loop": {
+                "wall_s": (time.perf_counter() - self._loop_t0
+                           if self._loop_t0 is not None else 0.0),
+                "turns": {kind: {
+                    "count": sum(t.counts), "wall_s": t.sum_ms / 1e3,
+                    "max_ms": t.max_ms, "hist": list(t.counts),
+                    "over_250ms_s": self._slow_turn_s[kind]}
+                    for kind, t in self._turns.items()}},
+            # every gap between two tokens of one request, where the
+            # loop emits them, by cause (GAP_CAUSES), on `edges_ms`;
+            # `burst`: tokens a verify dispatch committed after its first
+            "token_gaps": {
+                "edges_ms": list(GAP_EDGES_MS),
+                "by_cause": {c: list(d.counts)
+                             for c, d in self._gaps.items()},
+                "sum_ms": {c: d.sum_ms for c, d in self._gaps.items()},
+                "max_ms": {c: d.max_ms for c, d in self._gaps.items()},
+                "burst": self._burst_tokens},
+            # the replica's half of the streams' hand-off to the client
+            "stream": self._stream_account.stats(),
             "steps": dict(self._steps),
             "d2h_bytes": dict(self._d2h),
             # slots of cached context by kind of program: read as

@@ -82,10 +82,15 @@ from ray_tpu.util import tracing
 
 # The step loop's phases, as `engine.stats()["step_phase_seconds"]` and
 # as `llm.<phase>` events in a device profile. The engine times
-# schedule, commit, emit and bookkeep; the runner prepare, dispatch and
-# fetch; the deployment's loop idle.
+# schedule, commit, emit, bookkeep, release (a read step's device
+# results let go) and yield (the loop waiting for a swap or an abort to
+# be done with the engine); the runner prepare, dispatch and fetch (the
+# wait for the program, the copy, and the rows put back in the caller's
+# order); the deployment's loop idle. Between them they hold a turn of
+# the loop but for the statements between two phases, which
+# `engine.stats()["loop"]` measures.
 STEP_PHASES = ("schedule", "prepare", "dispatch", "fetch", "commit",
-               "emit", "bookkeep", "idle")
+               "emit", "bookkeep", "idle", "release", "yield")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -731,28 +736,29 @@ class ModelRunner:
         forward returned beside (logits, k, v): nothing, or the routed
         experts' pairs per layer and expert, kept for the engine
         (`take_expert_pairs`)."""
-        with self.phases.phase("fetch"):
-            out = [np.asarray(r) for r in results]
-            self.fetched_bytes += sum(a.nbytes for a in out)
-            if aux:  # routed experts only
-                extra = [np.asarray(r) for r in aux]
-                self.fetched_bytes += sum(a.nbytes for a in extra)
-                self.expert_pairs.extend(extra)
+        out = [np.asarray(r) for r in results]
+        self.fetched_bytes += sum(a.nbytes for a in out)
+        if aux:  # routed experts only
+            extra = [np.asarray(r) for r in aux]
+            self.fetched_bytes += sum(a.nbytes for a in extra)
+            self.expert_pairs.extend(extra)
         return out
 
     def collect(self, launched: Launched) -> tuple:
         """Wait for a launched program and read its results: (sampled
         id, its logits row) of a prefill or a chunk, (ids, logits rows)
         of a decode's real lanes, in the order the caller gave them."""
-        nxt, logits = self._fetch(*launched.results, aux=launched.aux)
-        if launched.order is None:
-            return int(nxt), logits
-        # back to the caller's order: its lane i ran as row `row_of[i]`
-        # (a view of the rows, not a copy, where the two orders agree)
-        row_of = np.argsort(launched.order)
-        if np.array_equal(row_of, np.arange(len(row_of))):
-            row_of = slice(len(row_of))
-        return [int(t) for t in nxt[row_of]], logits[row_of]
+        with self.phases.phase("fetch"):
+            nxt, logits = self._fetch(*launched.results, aux=launched.aux)
+            if launched.order is None:
+                return int(nxt), logits
+            # back to the caller's order: its lane i ran as row
+            # `row_of[i]` (a view of the rows, not a copy, where the two
+            # orders agree)
+            row_of = np.argsort(launched.order)
+            if np.array_equal(row_of, np.arange(len(row_of))):
+                row_of = slice(len(row_of))
+            return [int(t) for t in nxt[row_of]], logits[row_of]
 
     def take_expert_pairs(self) -> list[np.ndarray]:
         """The (L, n_experts) pairs-per-expert arrays of the programs run
@@ -1015,10 +1021,11 @@ class ModelRunner:
                         np.int32(self._step_counter))
             self._note_compile("verify", self._verify_jit, before,
                                time.perf_counter() - t0)
-        n_acc, emitted, logits = self._fetch(n_acc, emitted, logits,
-                                             aux=aux)
-        n_em = int(n_acc) + 1
-        return [int(t) for t in emitted[:n_em]], logits[:n_em]
+        with self.phases.phase("fetch"):
+            n_acc, emitted, logits = self._fetch(n_acc, emitted, logits,
+                                                 aux=aux)
+            n_em = int(n_acc) + 1
+            return [int(t) for t in emitted[:n_em]], logits[:n_em]
 
     def warmup(self) -> int:
         """Compile every (bucket, kind) program up front so no request

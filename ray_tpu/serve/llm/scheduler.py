@@ -147,6 +147,13 @@ class Sequence:
     enqueued_at: float = dataclasses.field(default_factory=time.monotonic)
     first_token_at: float | None = None
     finish_reason: str | None = None
+    # the engine's token-gap account (`LLMEngine._note_gap`): the
+    # loop's `perf_counter` at this request's last emitted token, and
+    # what the engine's count of prefill steps read and `preemptions`
+    # stood at then
+    emit_at: float | None = None
+    emit_prefills: int = 0
+    emit_preemptions: int = 0
     # ---- latency attribution (the per-request waterfall) ----
     # Interval accounting: `_mark` is where attribution left off; every
     # phase transition charges [_mark, now) to ONE phase and advances

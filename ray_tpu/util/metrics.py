@@ -152,6 +152,25 @@ class Histogram(Metric):
             self._sums[k] = self._sums.get(k, 0.0) + value
             self._totals[k] = self._totals.get(k, 0) + 1
 
+    def observe_buckets(self, buckets: "dict[int, int]", total: float,
+                        tags: dict | None = None):
+        """Observations a caller bucketed itself, on this histogram's
+        boundaries: `buckets[i]` values in (boundaries[i-1],
+        boundaries[i]], i = len(boundaries) for those above them all;
+        `total`: their sum. One locked call for many values, for a loop
+        too hot to make one a value."""
+        k = self._key(tags)
+        with self._lock:
+            mine = self._counts.get(k)
+            if mine is None:
+                mine = self._counts[k] = [0] * (len(self.boundaries) + 1)
+            seen = 0
+            for i, n in buckets.items():
+                mine[i] += n  # IndexError: not a bucket of this histogram
+                seen += n
+            self._sums[k] = self._sums.get(k, 0.0) + total
+            self._totals[k] = self._totals.get(k, 0) + seen
+
     def sum_total(self) -> float:
         """Sum of all observed values across every tag combination —
         the cheap 'how much time went here so far' probe waterfall

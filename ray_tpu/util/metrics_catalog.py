@@ -113,11 +113,12 @@ CATALOG: list[dict] = [
     {"name": "serve_llm_cache_utilization", "type": "gauge",
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "KV pool pages in use / usable"},
-    {"name": "serve_llm_tokens_per_sec", "type": "gauge",
-     "where": "ray_tpu/serve/llm/engine.py",
-     "what": "generation throughput (~5s window)"},
     {"name": "serve_llm_ttft_ms", "type": "histogram",
      "where": "ray_tpu/serve/llm/engine.py", "what": "time to first token"},
+    {"name": "serve_llm_itl_ms", "type": "histogram",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "gap between two streamed tokens of one request, by what "
+             "the loop did in it (cause)"},
     {"name": "serve_llm_step_ms", "type": "histogram",
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "engine step latency, by kind"},
@@ -135,9 +136,6 @@ CATALOG: list[dict] = [
      "what": "refcount-0 pages retained for prefix reuse"},
     {"name": "serve_llm_prefill_chunks_total", "type": "counter",
      "where": "ray_tpu/serve/llm/engine.py", "what": "prefill chunks run"},
-    {"name": "serve_llm_prefill_stall_ms", "type": "histogram",
-     "where": "ray_tpu/serve/llm/engine.py",
-     "what": "decode stall imposed by a prefill step"},
     {"name": "serve_llm_compile_misses_total", "type": "counter",
      "where": "ray_tpu/serve/llm/runner.py",
      "what": "prefill/decode calls that triggered an XLA compile"},

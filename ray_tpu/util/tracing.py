@@ -74,9 +74,12 @@ class PhaseClock:
     """Seconds a loop spent in each of its phases, always on: one
     `perf_counter` pair per phase into a plain dict (`seconds`), and the
     same interval as an `annotate(prefix + name)` event for a device
-    profile. Phases do not nest: they are the leaves, so `seconds` sums
-    to the loop's wall time. One thread drives the loop; readers copy
-    `seconds`."""
+    profile. Phases do not nest: they are the leaves. Whether they add
+    up to the loop's wall time is the loop's to see to, not this
+    class's: what runs between two phases is in none (the serve
+    engine's loop takes its own wall time and holds the remainder
+    under a hundredth of it, `LLMEngine.stats()["loop"]`). One thread
+    drives the loop; readers copy `seconds`."""
 
     def __init__(self, prefix: str, names: tuple[str, ...]):
         self.prefix = prefix
@@ -93,14 +96,16 @@ class _Phase:
         self.clock = clock
         self.name = name
 
+    # the event lies inside the interval that is timed, so that what the
+    # event costs is in the phase's seconds and not between two phases
     def __enter__(self):
+        self.t0 = time.perf_counter()
         self.ann = annotate(self.clock.prefix + self.name)
         self.ann.__enter__()
-        self.t0 = time.perf_counter()
 
     def __exit__(self, *exc):
-        self.clock.seconds[self.name] += time.perf_counter() - self.t0
         self.ann.__exit__(*exc)
+        self.clock.seconds[self.name] += time.perf_counter() - self.t0
 
 
 @contextlib.contextmanager

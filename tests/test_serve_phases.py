@@ -165,9 +165,11 @@ def test_phase_seconds_sum_to_the_loops_wall_time():
     spent = {k: after["step_phase_seconds"][k] - v
              for k, v in before["step_phase_seconds"].items()}
     assert set(spent) == {"schedule", "prepare", "dispatch", "fetch",
-                          "commit", "emit", "bookkeep", "idle"}
+                          "commit", "emit", "bookkeep", "idle", "release",
+                          "yield"}
     assert all(v >= 0 for v in spent.values())
     assert spent["idle"] == 0  # only the deployment's loop idles
+    assert spent["yield"] == 0  # no swap and no abort wanted the engine
     assert sum(spent.values()) == pytest.approx(wall, rel=0.05)
     counted = sum(after["steps"][k] - before["steps"][k]
                   for k in ("decode", "prefill"))
