@@ -25,10 +25,33 @@ beside the pages, fixed in size a lane: `StateLayout` (its buffers),
 counters), further down.
 
 Page 0 is reserved as a **null sink**: it is never handed out, padded
-lanes of a bucketed batch point their tables at it, and padded prefill
-positions scatter into it. Gathers through a padded table therefore
-always hit a legal page, and the attention mask (not the allocator)
-is what keeps garbage out of the softmax.
+lanes of a bucketed batch point their tables at it, and so does every
+group of `block_size` positions of a prompt's or a chunk's program that
+is all padding. Gathers through a padded table therefore always hit a
+legal page, and the attention mask (not the allocator) is what keeps
+garbage out of the softmax.
+
+A prompt's and a chunk's programs store their rows a page at a time
+(`KVLayout.write_pages`: their rows start on a page's edge and their
+buckets are whole pages), so the padded rows of the LAST page that holds
+a valid row land in that page, the sequence's own, behind its frontier,
+where a speculative verify's rejected rows land too. That is safe
+because, each held by a test of tests/test_kv_page_write.py or
+tests/test_kv_pool_layout.py:
+
+- every read of a lane's context is masked by the lane's length, so a
+  slot at or behind the frontier is never seen;
+- the row that belongs in such a slot is written (by the next chunk, by
+  the decode step at that position, by a verify dispatch from the
+  frontier on) before any program reads the slot;
+- only FULL pages are registered with the prefix index, and a page is
+  full only once real rows have overwritten every slot of it, so no other
+  sequence ever shares a page with padding in it;
+- a chunk owns every page its rows fall in while it runs, in a window
+  kind too (`lane_pages`): a page is given back only behind the window.
+
+Several all-padding groups share page 0 in one scatter; which of them
+lands there is nobody's business, as it was with padded rows.
 
 Prefix caching (reference shape: vLLM's automatic prefix caching):
 
@@ -132,8 +155,11 @@ class KVLayout:
     XLA keeps the array row-major. Three things together keep every serve
     program from copying a pool whole (each alone leaves the copies;
     PERF.md, PR 26): this row, a context read per layer inside the layer
-    scan (`read`), and a scatter indexed on all three leading dimensions
-    (`write`), which XLA then does in place on the donated buffer.
+    scan (`read`), and a scatter whose every indexed dimension leads:
+    all three of them where single rows are stored (`write`: decode,
+    verify), layer and page where whole pages are (`write_pages`: a
+    prompt's and a chunk's programs, a sixteenth of the indices at pages
+    of 16). XLA does either in place on the donated buffer.
     """
 
     kv_layers: int
@@ -242,6 +268,36 @@ class KVLayout:
         layers = jnp.arange(kv_layers)[:, None]
         return pages.at[layers, block_ids[None, :], offsets[None, :]].set(
             rows.reshape(kv_layers, n, pages.shape[-1]))
+
+    def whole_pages(self, n: int) -> bool:
+        """Whether `n` rows that start on a page's edge are whole pages
+        (`write_pages` then stores them a page at a time)."""
+        return n % self.block_size == 0
+
+    def group_pages(self, n: int) -> int:
+        """Pages that `n` rows starting on a page's edge fall in: the ids
+        `write_pages` takes."""
+        return -(-n // self.block_size)
+
+    def write_pages(self, pages, page_ids, rows):
+        """Store ``rows (kv_layers, N, n_kv_head, head width)``, which
+        start on a page's edge, in every layer of the K or the V pool:
+        group ``g`` of `block_size` rows in page ``page_ids[g]``
+        (`group_pages(N)` ids). Whole pages (`whole_pages(N)`: every
+        bucket of a prompt's or a chunk's program at the defaults) are
+        one scatter on (layer, page) whose window is a whole ``(block_size,
+        row)`` page: a sixteenth of the indices of `write` at pages of 16,
+        and the count of indices is what a scatter costs (PERF.md, PR 37).
+        Anything else is stored row by row, to the same slots."""
+        kv_layers, n = rows.shape[:2]
+        if not self.whole_pages(n):
+            at = jnp.arange(n)
+            return self.write(pages, page_ids[at // self.block_size],
+                              at % self.block_size, rows)
+        layers = jnp.arange(kv_layers)[:, None]
+        return pages.at[layers, page_ids[None, :]].set(
+            rows.reshape(kv_layers, n // self.block_size, self.block_size,
+                         pages.shape[-1]))
 
     def page_block(self) -> tuple:
         """BlockSpec shape of one page for a Pallas kernel: tile-aligned

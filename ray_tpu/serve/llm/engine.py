@@ -677,8 +677,16 @@ class LLMEngine:
             "Admissions by what became of their prefix lookup: taken (a "
             "match of at least a page) or declined (none looked up: the "
             "family has a window kind)", tag_keys=("model", "outcome"))
+        self._m_kv_rows = Counter(
+            "serve_llm_kv_rows_written_total",
+            "Valid rows of K (and as many of V) stored in a kind's pools, "
+            "by path: paged (a prompt's or a chunk's program, a page at "
+            "a time) or rowwise (decode, verify, a bucket that is not "
+            "whole pages)", tag_keys=("model", "kind", "path"))
         self._kv_seen = {"pools": [None] * len(self.kv.pools),
-                         "taken": 0, "declined": 0}
+                         "taken": 0, "declined": 0,
+                         "rows": {name: dict(n) for name, n in
+                                  self.runner.rows_written.items()}}
         self._moe: dict[str, dict] = {}
         self._spec_proposed_total = 0
         self._spec_accepted_total = 0
@@ -976,6 +984,13 @@ class LLMEngine:
                 self._m_kv_prefix.inc(n - seen[outcome], tags={
                     "model": self.config.model, "outcome": outcome})
                 seen[outcome] = n
+        for name, now in self.runner.rows_written.items():
+            for path, n in now.items():
+                if n != seen["rows"][name][path]:
+                    self._m_kv_rows.inc(n - seen["rows"][name][path], tags={
+                        "model": self.config.model, "kind": name,
+                        "path": path})
+                    seen["rows"][name][path] = n
 
     def _note_routing(self, kind: str, routed: list) -> None:
         """Account the step's routed-expert layers: `routed` holds one
@@ -1443,6 +1458,9 @@ class LLMEngine:
             for by in self.runner.context_by_kind.values():
                 for n in by.values():
                     n.update(dict.fromkeys(n, 0))
+            for name, n in self.runner.rows_written.items():
+                n.update(dict.fromkeys(n, 0))
+                self._kv_seen["rows"][name] = dict(n)
         up = self._startup
         up["warmup"] += wall
         up["warmup_trace"] += spent["trace"]
@@ -1479,8 +1497,12 @@ class LLMEngine:
             phase_totals = dict(self._phase_totals)
             finished = self._finished_requests
         d.update({
-            # the page pools by kind of KV layer (cache.KVPools.stats)
-            "kv": self.kv.stats(),
+            # the page pools by kind of KV layer (cache.KVPools.stats),
+            # and the rows the programs stored in them, by path
+            "kv": {name: {**pool, **{
+                "rows_written_" + path: n for path, n in
+                self.runner.rows_written[name].items()}}
+                for name, pool in self.kv.stats().items()},
             "model": self.config.model,
             "block_size": self.pool.block_size,
             "max_batch_size": self.config.max_batch_size,

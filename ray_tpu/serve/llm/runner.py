@@ -9,8 +9,10 @@ read and written through `cache.StateView`), and the compiled programs
 that touch them:
 
 - **prefill**: full-sequence forward of one prompt (padded to a length
-  bucket), scattering every position's K/V into its page and sampling
-  the first generated token from the last valid position's logits;
+  bucket), storing its K/V a page at a time (a bucket is whole pages,
+  `KVLayout.write_pages`) and sampling the first generated token from
+  the last valid position's logits; a **chunk** does the same from a
+  page's edge against the context already cached;
 - **decode**: one token for a batch of sequences (padded to a batch
   bucket), reading each lane's pages through its block table layer by
   layer, attending with a validity mask, scattering the new K/V at the
@@ -34,9 +36,13 @@ hands the results back in the caller's order. `context_slots` counts
 what was read, what of it was valid and what a read to
 ``max_model_len`` would have been.
 
-Padded lanes/positions point at **page 0** (the pool's null sink), so
-every gather/scatter is in-bounds; the attention mask keeps null-page
-garbage out of the softmax.
+Padded lanes, and the pages of a prompt's or a chunk's bucket that hold
+no valid row, point at **page 0** (the pool's null sink), so every
+gather/scatter is in-bounds; the attention mask keeps null-page garbage,
+and the padded rows behind a sequence's frontier in its own last page
+(cache.py's head), out of the softmax. `rows_written` counts the valid
+rows stored, by kind of KV layer and by path (a page at a time, or row
+by row: decode, verify).
 
 A program is **launched** (`launch_prefill`, `launch_chunk`,
 `launch_decode`: inputs prepared, the jitted call made, nothing read)
@@ -470,6 +476,12 @@ class ModelRunner:
         self.context_by_kind = {
             name: {kind: dict.fromkeys(CONTEXT_COUNTS, 0)
                    for kind in CONTEXT_KINDS} for name in self.kv_names}
+        # valid rows of K (and as many of V) stored by kind of KV layer
+        # and by path: a page at a time (`KVLayout.write_pages` on whole
+        # pages: a prompt's and a chunk's programs) or row by row (decode,
+        # verify, a bucket that is not whole pages)
+        self.rows_written = {name: {"paged": 0, "rowwise": 0}
+                             for name in self.kv_names}
         # compile observability: warmup() should account for ALL misses;
         # a mid-stream miss afterwards is the recompile bug these catch
         from ray_tpu.util.metrics import Counter, Histogram
@@ -581,48 +593,69 @@ class ModelRunner:
                         tables))
         return ctx[0] if len(ctx) == 1 else ctx
 
+    def _store(self, k_pages, v_pages, ids, k, v, store):
+        """``store(layout, pool, ids of the kind, rows of the kind)`` for
+        the K and the V pool of every kind of KV layer. Returns (K pools,
+        V pools), a tuple each."""
+        pools = [(store(lay, kp, i, kr), store(lay, vp, i, vr))
+                 for lay, kp, vp, i, kr, vr in zip(
+                     self.layouts, _by_kind(k_pages), _by_kind(v_pages),
+                     _by_kind(ids), _by_kind(k), _by_kind(v))]
+        return tuple(p[0] for p in pools), tuple(p[1] for p in pools)
+
     def _write(self, k_pages, v_pages, block_ids, offsets, k, v,
                lane: int | None = None):
         """The rows a forward returned, k and v (layers of the kind, [1,]
-        N, HK, width) a kind, stored in their kind's pools at the slots
-        ``(block_ids, offsets)`` of the kind's table (`lane` 0: the one
-        lane of a prompt's or a chunk's program). Returns (K pools, V
-        pools), a tuple each."""
+        N, HK, width) a kind, stored row by row in their kind's pools at
+        the slots ``(block_ids, offsets)`` of the kind's table: a decode
+        step's one row a lane, a verify dispatch's rows from a frontier
+        that is on no page's edge (`lane` 0: its one lane)."""
         pick = (lambda a: a) if lane is None else (lambda a: a[:, lane])
-        pools = [(lay.write(kp, ids, offsets, pick(kr)),
-                  lay.write(vp, ids, offsets, pick(vr)))
-                 for lay, kp, vp, ids, kr, vr in zip(
-                     self.layouts, _by_kind(k_pages), _by_kind(v_pages),
-                     _by_kind(block_ids), _by_kind(k), _by_kind(v))]
-        return tuple(p[0] for p in pools), tuple(p[1] for p in pools)
+        return self._store(
+            k_pages, v_pages, block_ids, k, v,
+            lambda lay, pool, ids, rows: lay.write(pool, ids, offsets,
+                                                   pick(rows)))
+
+    def _write_pages(self, k_pages, v_pages, page_ids, k, v):
+        """The rows of a prompt's or a chunk's one lane, k and v (layers
+        of the kind, 1, Tb, HK, width) a kind, which start on a page's
+        edge, stored in their kind's pools a page at a time: group g of
+        `block_size` rows in page ``page_ids[g]`` of the kind
+        (`KVLayout.write_pages`)."""
+        return self._store(
+            k_pages, v_pages, page_ids, k, v,
+            lambda lay, pool, ids, rows: lay.write_pages(pool, ids,
+                                                         rows[:, 0]))
 
     def _prefill_impl(self, params, k_pages, v_pages, slot_tokens, state,
-                      tokens, last_idx, block_ids, offsets, slot, temp, topk,
+                      tokens, last_idx, page_ids, slot, temp, topk,
                       topp, step):
-        """tokens (1, Tb); block_ids/offsets (Tb,) map position t to its
-        page slot (padded positions -> null page 0). A sequence's first
+        """tokens (1, Tb); page_ids (pages of Tb rows,) the page of each
+        group of `block_size` positions (the null page 0 for a group that
+        is all padding; the padded rows of the last valid page land in
+        that page, behind the sequence's frontier). A sequence's first
         rows: its slot's recurrent state starts from zero. Here and in
-        the programs below, the pools, `block_ids` and the tables are one
-        a kind of KV layer (a tuple; bare for one kind)."""
+        the programs below, the pools, the page or block ids and the
+        tables are one a kind of KV layer (a tuple; bare for one kind)."""
         (logits, k, v, *aux), state = self._forward(
             self.adapter.prefill_fn, state, slot, params, tokens, self.cfg,
             fresh=True, n_valid=last_idx + 1)
-        k_pages, v_pages = self._write(k_pages, v_pages, block_ids, offsets,
-                                       k, v, lane=0)
+        k_pages, v_pages = self._write_pages(k_pages, v_pages, page_ids,
+                                             k, v)
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
         nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
         slot_tokens = self._keep_sampled(slot_tokens, slot, nxt)
         return nxt, last, k_pages, v_pages, slot_tokens, state, tuple(aux)
 
     def _chunk_impl(self, params, k_pages, v_pages, slot_tokens, state,
-                    tokens, start, last_idx, block_ids, offsets, table, slot,
+                    tokens, start, last_idx, page_ids, table, slot,
                     temp, topk, topp, step):
         """Prefill a chunk of ONE sequence from a position offset.
 
-        tokens (1, Tb) at absolute positions start..start+Tb-1 (padded
-        tail -> null page); table (maxB,) is the sequence's full block
-        table, read for context (positions < start); block_ids/
-        offsets (Tb,) map chunk position t to its page slot. `start` is
+        tokens (1, Tb) at absolute positions start..start+Tb-1, `start`
+        on a page's edge; table (maxB,) is the sequence's full block
+        table, read for context (positions < start); page_ids as in
+        `_prefill_impl`, the pages from `start` on. `start` is
         traced, so one compiled program per chunk-length bucket serves
         every offset. Recurrent state is carried chunk to chunk in the
         lane's slot, from zero where `start` is 0."""
@@ -633,8 +666,8 @@ class ModelRunner:
             self._context(k_pages, v_pages,
                           [t[None] for t in _by_kind(table)], start[None]),
             chunk_mask, self.cfg, fresh=start == 0, n_valid=last_idx + 1)
-        k_pages, v_pages = self._write(k_pages, v_pages, block_ids, offsets,
-                                       k, v, lane=0)
+        k_pages, v_pages = self._write_pages(k_pages, v_pages, page_ids,
+                                             k, v)
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
         nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
         slot_tokens = self._keep_sampled(slot_tokens, slot, nxt)
@@ -805,14 +838,22 @@ class ModelRunner:
             out.append(tab)
         return tuple(out)
 
-    def _block_ids(self, tables: tuple, positions, width: int) -> tuple:
-        """The pages of `positions` in each kind's table, padded with the
-        null page to `width` rows."""
+    def _page_ids(self, tables: tuple, start: int, n: int, width: int
+                  ) -> tuple:
+        """The pages of a program's `width` rows from position `start` (on
+        a page's edge), one id a group of `block_size` rows and kind of KV
+        layer: the table's page for a group that holds one of the `n`
+        valid rows, the null page for a group that is all padding. The
+        rows are counted as written, by the path the program takes."""
+        first = start // self.block_size
         out = []
-        for tab in tables:
-            ids = np.zeros((width,), np.int32)
-            ids[:len(positions)] = tab[positions // self.block_size]
+        for lay, tab, written in zip(self.layouts, tables,
+                                     self.rows_written.values()):
+            ids = np.zeros((lay.group_pages(width),), np.int32)
+            valid = lay.group_pages(n)
+            ids[:valid] = tab[first:first + valid]
             out.append(ids)
+            written["paged" if lay.whole_pages(width) else "rowwise"] += n
         return tuple(out)
 
     def launch_prefill(self, token_ids: Sequence[int],
@@ -827,9 +868,7 @@ class ModelRunner:
             Tb = self.prefill_bucket(n)
             toks = np.zeros((1, Tb), np.int32)
             toks[0, :n] = token_ids
-            offsets = np.arange(Tb, dtype=np.int32) % self.block_size
-            block_ids = self._block_ids(self._tables(table), np.arange(n),
-                                        Tb)
+            page_ids = self._page_ids(self._tables(table), 0, n, Tb)
             temp = np.asarray([temperature], np.float32)
             topk = np.asarray([top_k], np.int32)
             topp = np.asarray([top_p], np.float32)
@@ -842,8 +881,7 @@ class ModelRunner:
                  self.state, aux) = self._prefill_jit(
                     self.params, self.k_pages, self.v_pages,
                     self.slot_tokens, self.state, toks, np.int32(n - 1),
-                    block_ids,
-                    offsets, np.int32(slot), temp, topk, topp,
+                    page_ids, np.int32(slot), temp, topk, topp,
                     np.int32(self._step_counter))
             self._note_compile("prefill", self._prefill_jit, before,
                                time.perf_counter() - t0)
@@ -878,11 +916,7 @@ class ModelRunner:
             toks = np.zeros((1, Tb), np.int32)
             toks[0, :n] = token_ids
             tab = self._tables(table)
-            block_ids = self._block_ids(tab, start + np.arange(n), Tb)
-            # padded tail positions keep in-range offsets but target
-            # page 0
-            offsets = np.asarray(
-                (start + np.arange(Tb)) % self.block_size, np.int32)
+            page_ids = self._page_ids(tab, start, n, Tb)
             temp = np.asarray([temperature], np.float32)
             topk = np.asarray([top_k], np.int32)
             topp = np.asarray([top_p], np.float32)
@@ -896,7 +930,7 @@ class ModelRunner:
                  self.state, aux) = self._chunk_jit(
                     self.params, self.k_pages, self.v_pages,
                     self.slot_tokens, self.state, toks, np.int32(start),
-                    np.int32(n - 1), block_ids, offsets, tab,
+                    np.int32(n - 1), page_ids, tab,
                     np.int32(slot), temp, topk, topp,
                     np.int32(self._step_counter))
             self._note_compile("prefill_chunk", self._chunk_jit, before,
@@ -948,6 +982,8 @@ class ModelRunner:
                 topps[i] = it.top_p
             if not self.use_paged_attention:
                 self._note_context("decode", poss, self.lanes_per_group(Sb))
+            for written in self.rows_written.values():
+                written["rowwise"] += S
             self._step_counter += 1
         with self.phases.phase("dispatch"):
             before = tracing.jit_cache_size(self._decode_jit)
@@ -1008,6 +1044,8 @@ class ModelRunner:
             topps = np.full((W,), top_p, np.float32)
             if not self.use_paged_attention:
                 self._note_context("verify", [pos])
+            for written in self.rows_written.values():
+                written["rowwise"] += n_draft + 1
             self._step_counter += 1
         with self.phases.phase("dispatch"):
             before = tracing.jit_cache_size(self._verify_jit)

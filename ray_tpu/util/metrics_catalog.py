@@ -239,6 +239,12 @@ CATALOG: list[dict] = [
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "admissions by what became of their prefix lookup: taken, "
              "or declined (a family with a window kind looks none up)"},
+    {"name": "serve_llm_kv_rows_written_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "valid rows of K (and as many of V) stored in a kind's "
+             "pools, by path: paged (a prompt's or a chunk's program, a "
+             "page at a time) or rowwise (decode, verify, a bucket that "
+             "is not whole pages)"},
     # jax's own account of its compiles (every process that compiles)
     {"name": "jax_compile_seconds_total", "type": "counter",
      "where": "ray_tpu/util/tracing.py",

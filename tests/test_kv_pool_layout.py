@@ -25,19 +25,21 @@ from ray_tpu.serve.llm.cache import (
     blocks_by_kind,
 )
 
-# (kv_layers, num_blocks, block_size, n_kv_head, head_dim)
-LAYOUTS = {"mha": (3, 12, 4, 4, 16), "gqa": (2, 10, 16, 8, 128)}
+# (kv_layers, num_blocks, block_size, n_kv_head, head_dim[, v_head_dim]);
+# "narrow_v": a V row narrower than its K row, as mimo_v2's kinds have
+LAYOUTS = {"mha": (3, 12, 4, 4, 16), "gqa": (2, 10, 16, 8, 128),
+           "narrow_v": (2, 12, 8, 2, 24, 16)}
 
 
-def _numpy_pool(layout, rng):
+def _numpy_pool(layout, rng, width=None):
     """The pool as plain numpy holds it: (L, pages, Bs, HK, D)."""
     return rng.normal(size=(layout.kv_layers, layout.num_blocks,
                             layout.block_size, layout.n_kv_head,
-                            layout.head_dim)).astype(np.float32)
+                            width or layout.head_dim)).astype(np.float32)
 
 
 def _as_device(layout, pool):
-    return jnp.asarray(pool.reshape(layout.shape))
+    return jnp.asarray(pool.reshape(pool.shape[:3] + (-1,)))
 
 
 @pytest.mark.parametrize("name", sorted(LAYOUTS))
@@ -45,7 +47,7 @@ def test_decode_rows_written_then_read_match_numpy(name):
     """One new row per lane, padded lanes writing to the null page 0 and
     reading it back through a table of zeros."""
     layout = KVLayout(*LAYOUTS[name])
-    L, P, Bs, HK, D = LAYOUTS[name]
+    L, P, Bs, HK, D = LAYOUTS[name][:5]
     rng = np.random.RandomState(0)
     ref = _numpy_pool(layout, rng)
     pages = _as_device(layout, ref)
@@ -80,7 +82,7 @@ def test_chunk_rows_crossing_a_page_match_numpy(name):
     """A chunk that starts mid-table and crosses page boundaries, its
     padded tail pointed at the null page."""
     layout = KVLayout(*LAYOUTS[name])
-    L, P, Bs, HK, D = LAYOUTS[name]
+    L, P, Bs, HK, D = LAYOUTS[name][:5]
     rng = np.random.RandomState(1)
     ref = _numpy_pool(layout, rng)
     table = np.asarray([3, 6, 1, 5], np.int32)
@@ -101,6 +103,182 @@ def test_chunk_rows_crossing_a_page_match_numpy(name):
         np.testing.assert_array_equal(ctx[0, start:start + n],
                                       rows[layer, :n])
         np.testing.assert_array_equal(ctx[0, :start], ref[layer, 3])
+
+
+# ------------------------------------------------- whole pages at a time
+
+# the K pool and the V pool of each layout (the V rows of "narrow_v" are
+# narrower): `write_pages` takes each pool's own row width, as `write`
+POOLS = [(name, pool) for name in sorted(LAYOUTS) for pool in ("k", "v")]
+
+
+def _page_case(name, pool, seed):
+    layout = KVLayout(*LAYOUTS[name])
+    width = layout.head_dim if pool == "k" else (
+        layout.v_head_dim or layout.head_dim)
+    rng = np.random.RandomState(seed)
+    return layout, width, rng, _numpy_pool(layout, rng, width)
+
+
+def _rows(layout, width, rng, n):
+    return rng.normal(size=(layout.kv_layers, n, layout.n_kv_head,
+                            width)).astype(np.float32)
+
+
+def _page_ids(layout, table, start, n, Tb):
+    """As `ModelRunner._page_ids` builds them: the table's page for a
+    group with a valid row, the null page for a group of padding."""
+    ids = np.zeros((layout.group_pages(Tb),), np.int32)
+    valid = layout.group_pages(n)
+    first = start // layout.block_size
+    ids[:valid] = table[first:first + valid]
+    return ids
+
+
+def _rowwise(layout, pages, table, start, n, rows):
+    """The write of before PR 37: a padded row to the null page."""
+    pos = start + np.arange(rows.shape[1])
+    block_ids = np.where(np.arange(rows.shape[1]) < n,
+                         table[np.minimum(pos // layout.block_size,
+                                          len(table) - 1)], 0)
+    return layout.write(pages, block_ids, pos % layout.block_size, rows)
+
+
+@pytest.mark.parametrize("name,pool", POOLS)
+def test_page_rows_crossing_pages_match_numpy(name, pool):
+    """A chunk of whole pages that starts mid-table: every row lands at
+    (table[t // Bs], t % Bs), nothing else moves, not even page 0."""
+    layout, width, rng, ref = _page_case(name, pool, 3)
+    Bs = layout.block_size
+    table = np.asarray([3, 6, 1, 5], np.int32)
+    start, Tb = Bs, 3 * Bs  # pages 6, 1, 5
+    rows = _rows(layout, width, rng, Tb)
+    ids = _page_ids(layout, table, start, Tb, Tb)
+    np.testing.assert_array_equal(ids, [6, 1, 5])
+    pages = jax.jit(layout.write_pages)(_as_device(layout, ref), ids, rows)
+    for t in range(Tb):
+        ref[:, table[(start + t) // Bs], (start + t) % Bs] = rows[:, t]
+    np.testing.assert_array_equal(np.asarray(pages).reshape(ref.shape), ref)
+    if pool == "k":
+        ctx = np.asarray(layout.read(pages, 1, table[None]))
+        np.testing.assert_array_equal(ctx[0, start:start + Tb], rows[1])
+
+
+@pytest.mark.parametrize("name,pool", POOLS)
+def test_half_valid_last_page_keeps_padding_behind_the_frontier(name, pool):
+    """Two full pages, a half-valid one and a group of padding: the valid
+    slots are the row-wise write's, the slots behind the frontier of the
+    half-valid page hold that group's padded rows, the all-padding group
+    went to page 0, and every other page is untouched."""
+    layout, width, rng, ref = _page_case(name, pool, 4)
+    Bs = layout.block_size
+    table = np.asarray([7, 2, 9, 4, 8], np.int32)
+    start, n, Tb = Bs, 2 * Bs + Bs // 2, 4 * Bs  # pages 2, 9, half of 4
+    rows = _rows(layout, width, rng, Tb)
+    ids = _page_ids(layout, table, start, n, Tb)
+    np.testing.assert_array_equal(ids, [2, 9, 4, 0])
+    dev = _as_device(layout, ref)
+    got = np.asarray(jax.jit(layout.write_pages)(dev, ids, rows)
+                     ).reshape(ref.shape)
+    old = np.asarray(_rowwise(layout, dev, table, start, n, rows)
+                     ).reshape(ref.shape)
+    # below the frontier: what the row-wise write leaves
+    for page in (2, 9):
+        np.testing.assert_array_equal(got[:, page], old[:, page])
+    np.testing.assert_array_equal(got[:, 4, :Bs // 2], old[:, 4, :Bs // 2])
+    # behind it, in the sequence's own page: that group's padded rows
+    np.testing.assert_array_equal(
+        got[:, 4, Bs // 2:],
+        rows[:, 2 * Bs + Bs // 2:3 * Bs].reshape(got[:, 4, Bs // 2:].shape))
+    # the null page took the group of padding, whole
+    np.testing.assert_array_equal(
+        got[:, 0], rows[:, 3 * Bs:].reshape(got[:, 0].shape))
+    untouched = [p for p in range(layout.num_blocks) if p not in (0, 2, 9, 4)]
+    np.testing.assert_array_equal(got[:, untouched], ref[:, untouched])
+
+
+@pytest.mark.parametrize("name,pool", POOLS)
+def test_groups_of_padding_land_on_the_null_page_only(name, pool):
+    """One valid row in a bucket of four pages: its page and page 0 move,
+    page 0 holding one of the three groups of padding, whole."""
+    layout, width, rng, ref = _page_case(name, pool, 5)
+    Bs = layout.block_size
+    table = np.asarray([5, 3], np.int32)
+    rows = _rows(layout, width, rng, 4 * Bs)
+    ids = _page_ids(layout, table, 0, 1, 4 * Bs)
+    np.testing.assert_array_equal(ids, [5, 0, 0, 0])
+    got = np.asarray(jax.jit(layout.write_pages)(
+        _as_device(layout, ref), ids, rows)).reshape(ref.shape)
+    np.testing.assert_array_equal(got[:, 5, 0], rows[:, 0])
+    assert any(np.array_equal(
+        got[:, 0], rows[:, g * Bs:(g + 1) * Bs].reshape(got[:, 0].shape))
+        for g in (1, 2, 3))
+    untouched = [p for p in range(layout.num_blocks) if p not in (0, 5)]
+    np.testing.assert_array_equal(got[:, untouched], ref[:, untouched])
+
+
+@pytest.mark.parametrize("name,pool", POOLS)
+def test_write_pages_and_write_agree_below_the_frontier(name, pool):
+    """Random (start, n, Tb), whole pages and not: every slot below the
+    frontier, and every page the program's rows do not fall in (but page
+    0), is what the row-wise write leaves."""
+    layout, width, rng, ref = _page_case(name, pool, 6)
+    Bs = layout.block_size
+    write_pages = jax.jit(layout.write_pages)
+    for case in range(12):
+        table = rng.permutation(np.arange(1, layout.num_blocks))[:8] \
+            .astype(np.int32)
+        groups = int(rng.choice([1, 2, 4]))
+        Tb = groups * Bs if case % 4 else max(1, Bs // 2)
+        start = Bs * int(rng.randint(0, 8 - layout.group_pages(Tb) + 1))
+        n = int(rng.randint(1, Tb + 1))
+        rows = _rows(layout, width, rng, Tb)
+        dev = _as_device(layout, ref)
+        got = np.asarray(write_pages(
+            dev, _page_ids(layout, table, start, n, Tb), rows)
+        ).reshape(ref.shape)
+        old = np.asarray(_rowwise(layout, dev, table, start, n, rows)
+                         ).reshape(ref.shape)
+        ctx = lambda a: a[:, table].reshape(  # noqa: E731
+            (layout.kv_layers, 8 * Bs) + a.shape[3:])
+        np.testing.assert_array_equal(ctx(got)[:, :start + n],
+                                      ctx(old)[:, :start + n])
+        own = set(table[start // Bs:start // Bs + layout.group_pages(n)])
+        others = [p for p in range(1, layout.num_blocks) if p not in own]
+        np.testing.assert_array_equal(got[:, others], ref[:, others])
+
+
+def _scatter_indices(fn, *args):
+    """Shapes of the index operand of every scatter `fn` traces to."""
+    return [eqn.invars[1].aval.shape
+            for eqn in jax.make_jaxpr(fn)(*args).eqns
+            if eqn.primitive.name.startswith("scatter")]
+
+
+@pytest.mark.parametrize("name", sorted(LAYOUTS))
+def test_which_path_is_a_matter_of_shape(name):
+    """Whole pages: one index a layer and PAGE, the window a whole page.
+    Rows that are not whole pages (a bucket under `block_size`): one index
+    a layer and ROW, to the slots the page-wise write would give them."""
+    layout, width, rng, ref = _page_case(name, "k", 7)
+    L, Bs = layout.kv_layers, layout.block_size
+    dev = _as_device(layout, ref)
+    whole, part = _rows(layout, width, rng, 2 * Bs), \
+        _rows(layout, width, rng, Bs + Bs // 2)
+    ids = np.asarray([4, 7], np.int32)
+    assert layout.whole_pages(2 * Bs) and not layout.whole_pages(Bs // 2)
+    assert layout.group_pages(Bs + Bs // 2) == 2
+    assert _scatter_indices(layout.write_pages, dev, ids, whole) \
+        == [(L, 2, 2)]
+    assert _scatter_indices(layout.write_pages, dev, ids, part) \
+        == [(L, Bs + Bs // 2, 3)]
+    assert _scatter_indices(layout.write, dev, np.zeros(2 * Bs, np.int32),
+                            np.zeros(2 * Bs, np.int32), whole) \
+        == [(L, 2 * Bs, 3)]
+    got = np.asarray(layout.write_pages(dev, ids, part)).reshape(ref.shape)
+    ref[:, 4] = part[:, :Bs]
+    ref[:, 7, :Bs // 2] = part[:, Bs:]
+    np.testing.assert_array_equal(got, ref)
 
 
 @pytest.mark.parametrize("n_kv_head,sharded", [(4, True), (3, False)])
@@ -278,16 +456,16 @@ def served_runner(one_chip, request):
 # Prefill, chunk and decode take the device-resident last sampled ids
 # first ("s" of them) and the slot(s) they leave theirs at (PR 31), and
 # behind the ids the lanes' recurrent state ("state": {} but for a family
-# that has it). "k": one such argument a kind of KV layer (block ids and
-# tables), bare where the family has one kind
+# that has it). "k": one such argument a kind of KV layer (page or block
+# ids and tables), bare where the family has one kind. A prompt's and a
+# chunk's programs take one page id a group of 16 rows ("g" of them in the
+# monolithic prefill bucket, 16 in a chunk of 256) since PR 37
 PROGRAMS = {
     "prefill": ("_prefill_impl", [(("s",), "i"), "state", ((1, "p"), "i"),
-                                  ((), "i"),
-                                  (("p",), "k"), (("p",), "i"),
+                                  ((), "i"), (("g",), "k"),
                                   ((), "i")], 1),
     "chunk-256": ("_chunk_impl", [(("s",), "i"), "state", ((1, 256), "i"),
-                                  ((), "i"), ((), "i"), ((256,), "k"),
-                                  ((256,), "i"),
+                                  ((), "i"), ((), "i"), ((16,), "k"),
                                   (("m",), "k"), ((), "i")], 1),
     "verify-5": ("_verify_impl", [((1, 5), "i"), ((), "i"), ((), "i"),
                                   ((5,), "k"), ((5,), "i"),
@@ -322,7 +500,7 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
         pytest.skip("the engine refuses speculation with a window kind")
     method, shapes, lanes = PROGRAMS[program]
     sizes = {"m": runner.max_blocks_per_seq, "p": MODELS[model][4],
-             "s": runner.max_batch_size}
+             "g": MODELS[model][4] // 16, "s": runner.max_batch_size}
     lanes = sizes.get(lanes, lanes)
 
     def arg(shape, kind):
@@ -369,6 +547,41 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
     if program == "decode" and not paged:
         assert not _full_width_contexts(text, runner, [
             *jax.tree.leaves(pool), *params_and_state(params, state)])
+    if program in ("prefill", "chunk-256"):
+        # a prompt's and a chunk's rows are stored a page at a time: each
+        # pool's scatter has one update a layer and PAGE of the program's
+        # rows (36 x 16 at gpt2-large's chunk of 256, where it had 36 x
+        # 256), its window one whole (16, row) page
+        rows = sizes["p"] if program == "prefill" else 256
+        updates = _pool_scatters(text, jax.tree.leaves(pool))
+        print(f"{model} {program}: pool scatters {updates}")
+        assert len(updates) == len(jax.tree.leaves(pool))
+        for shape, n, window in updates:
+            assert n == shape[0] * rows // 16
+            assert window == (16, shape[-1])
+
+
+def _pool_scatters(text, pools):
+    """(pool shape, updates, window) of every scatter of a compiled
+    program whose result has as many elements as one of `pools` and ends
+    in its row: the updates are the elements of its `updates` operand over
+    those of its window (`update_window_dims`)."""
+    shape_of = {m.group(1): tuple(map(int, m.group(2).split(",")))
+                for m in re.finditer(r"(%[\w.\-]+) = \w+\[([\d,]+)\]", text)}
+    found = []
+    for m in re.finditer(
+            r"= \w+\[([\d,]+)\]\S* scatter\(([^)]*)\), "
+            r"update_window_dims=\{([\d,]*)\}", text):
+        result = tuple(map(int, m.group(1).split(",")))
+        pool = next((a.shape for a in pools
+                     if math.prod(a.shape) == math.prod(result)
+                     and a.shape[-1] == result[-1]), None)
+        if pool is None:
+            continue
+        updates = shape_of[m.group(2).split(",")[-1].strip()]
+        window = tuple(updates[int(d)] for d in m.group(3).split(","))
+        found.append((pool, math.prod(updates) // math.prod(window), window))
+    return found
 
 
 def params_and_state(params, state):

@@ -485,7 +485,13 @@ def test_the_other_families_programs_lower_as_before(which):
     before a model had kinds of KV layer, windows and sinks (recorded at
     PR 33's commit by this very function; source locations are not in the
     text, and the results' pytree paths, which name no operation, are
-    dropped)."""
+    dropped). The three `decode` hashes are PR 33's still: the program
+    whose step sets the open-loop cells' token gap is the parent's. The
+    six `prefill` and `chunk` hashes were recorded anew at PR 37, by
+    `_lowered` as it stands, because those programs changed by design:
+    they take one page id a group of `block_size` rows in place of (block
+    ids, offsets) and store their rows a page at a time (pages of 4 and
+    chunks of 8 here: two whole pages)."""
     family, program = which.split(".")
     assert _lowered(family)[program] == HLO_AT_THE_PARENT[which]
 
@@ -512,11 +518,10 @@ def _lowered(family, _cache={}):
     texts = {
         "prefill": jax.jit(r._prefill_impl).lower(
             params, pool, pool, ids, state, S((1, 8), i32), S((), i32),
-            S((8,), i32), S((8,), i32), S((), i32), *one),
+            S((2,), i32), S((), i32), *one),
         "chunk": jax.jit(r._chunk_impl).lower(
             params, pool, pool, ids, state, S((1, 8), i32), S((), i32),
-            S((), i32), S((8,), i32), S((8,), i32), S((m,), i32),
-            S((), i32), *one),
+            S((), i32), S((2,), i32), S((m,), i32), S((), i32), *one),
         "decode": jax.jit(r._decode_impl).lower(
             params, pool, pool, ids, state, S((4,), i32), S((4,), i32),
             S((4,), i32), S((4, m), i32), S((4,), f32), S((4,), i32),

@@ -497,8 +497,9 @@ def test_the_new_metrics_are_listed_for_the_open_loop_cells_alone():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     mine = [m for m in bench["per_layer"] if m["name"] in READINGS]
-    assert [m["name"] for m in bench["per_layer"]][-5:] \
-        == [m["name"] for m in mine] and len(mine) == 5
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(mine[0]["name"])  # appended together, in this order
+    assert names[at:at + 5] == [m["name"] for m in mine] and len(mine) == 5
     for m in mine:
         assert m["moves"] == "itl_p95_ms" and m["better"] == "lower"
         assert m["workloads"] == ["serve-gpt2-large-chat-steady",
