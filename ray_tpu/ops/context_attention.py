@@ -33,6 +33,21 @@ rows gets the window too. K and V rows may differ in width. A layer with
 a **sink** (one learned scalar a query head that joins the softmax as a
 column with no value) starts its running softmax from ``(m, l) = (sink,
 1)`` and a zero accumulator.
+
+A kind of layer that **selects** (`KVLayout.select`: latent attention
+under a learned indexer) caches two rows a token, the latent row, which
+is key and value of every query head at once, and the indexer's key, and
+a query row attends only to the `select` slots, cached or the program's
+own, that its indexer scores highest (`attend_selected`): an index-score
+pass over the indexer keys in tiles to the lanes' lengths, by the same
+groups, an exact top-k over cached and own slots together, and one
+softmax over what was chosen: the latent tiles a lane reaches are folded
+under the choice as their mask, by the same groups again, in a chunk and
+in a decode step alike (PERF.md section 6, PR 40, findings 2 and 8: an
+XLA gather of the chosen rows alone was measured for both; it costs a
+chunk 15 times the fold, and a decode step more than the fold below a
+mean context of about 6k slots a lane. Reading the chosen rows only is
+a kernel's to do).
 """
 
 from __future__ import annotations
@@ -246,6 +261,161 @@ def attend_cached(q, k, v, own_valid, ctx: CachedContext, layer, dtype,
                 carry)
             done.append(
                 (acc[rows - G:] / _per_row(l[rows - G:])).astype(dtype))
+            carry = (m[:rows - G], l[:rows - G], acc[:rows - G])
+            at = upto
+        return jnp.concatenate(done[::-1])
+
+
+# --------------------------------------------------------------------------
+# A kind that selects: latent rows chosen by an indexer (`KVLayout.select`;
+# `ctx.k_pages` holds the latent rows, `ctx.v_pages` the indexer's keys).
+
+MASKED = -1e30  # an index score no slot that can be seen has
+
+
+def index_scores(qi, ki, w, valid):
+    """The indexer's scores of query rows qi (G, T, HI, Di) with head
+    weights w (G, T, HI) float32 against keys ki (G, S, Di): ``sum_j w[t,
+    j] relu(qi[t, j] . ki[s])`` -> (G, T, S) float32, `MASKED` where
+    `valid` (G, T, S) is false."""
+    s = jnp.einsum("bthd,bsd->bths", qi, ki,
+                   preferred_element_type=jnp.float32)
+    return jnp.where(valid, jnp.einsum("bths,bth->bts", jax.nn.relu(s), w),
+                     MASKED)
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(b >> 31 != 0, ~b, b | jnp.uint32(0x80000000))
+
+
+def _kth_largest(keys, k: int):
+    """The k-th largest of each row of keys (..., S) uint32, S > k: the
+    largest value that k of the row reach, found a bit at a time, each a
+    masked count over the row. (`lax.top_k` sorts: 2.6 ms against 0.29
+    for 256 rows of 17,664 on the v5e, PERF.md, PR 40.)"""
+    def grow(i, kth):
+        bit = jax.lax.shift_left(jnp.uint32(1), (31 - i).astype(jnp.uint32))
+        reach = jnp.sum(keys >= (kth | bit), axis=-1, keepdims=True)
+        return jnp.where(reach >= k, kth | bit, kth)
+
+    return jax.lax.fori_loop(
+        0, 32, grow, jnp.zeros(keys.shape[:-1] + (1,), jnp.uint32))
+
+
+def select_mask(scores, k: int):
+    """Which slots each row attends to: the k of largest `scores` (..., S)
+    among those not `MASKED` (all of them while there are no more than
+    k), of equal scores the earliest, exactly. Nothing is searched for
+    where no row has more than k slots to choose from."""
+    seen = scores > MASKED / 2
+    if scores.shape[-1] <= k:
+        return seen
+
+    def cut(x):
+        keys = _ordered_bits(x)
+        kth = _kth_largest(keys, k)
+        above = keys > kth
+        tied = keys == kth
+        spare = k - jnp.sum(above, axis=-1, keepdims=True)
+        return above | (tied & (jnp.cumsum(tied, axis=-1) <= spare))
+
+    return seen & jax.lax.cond(
+        jnp.max(jnp.sum(seen, axis=-1)) > k, cut,
+        lambda x: jnp.ones(x.shape, bool), scores)
+
+
+def _cached_index_scores(ctx, layer, qi, w):
+    """The index scores of the program's rows against their lanes' cached
+    indexer keys -> (B, T, slots the padded tables hold) float32, `MASKED`
+    past a lane's length: tile t computed once, for the rows of every
+    group that reaches it (`attend_cached`'s loops)."""
+    lay, B, G = ctx.layout, qi.shape[0], ctx.group
+    per = lay.tile_pages
+    tile = per * lay.block_size
+    slots = ctx.tables.shape[1] * lay.block_size
+
+    def tiles_of(rows):
+        def step(t, buf):
+            keys = lay.read(ctx.v_pages, layer, jax.lax.dynamic_slice_in_dim(
+                ctx.tables[:rows], t * per, per, axis=1))[:, :, 0]
+            s = index_scores(qi[:rows], keys, w[:rows],
+                             jnp.ones((), bool))
+            return jax.lax.dynamic_update_slice(buf, s, (0, 0, t * tile))
+        return step
+
+    buf = jnp.full((B, qi.shape[1], slots), MASKED, jnp.float32)
+    at = 0
+    for rows in range(B, 0, -G):  # the groups' ends, last group first
+        upto = ctx.reach[rows // G - 1]
+        buf = jax.lax.fori_loop(at, upto, tiles_of(rows), buf)
+        at = upto
+    return jnp.where(jnp.arange(slots)[None, None, :]
+                     < ctx.lengths[:, None, None], buf, MASKED)
+
+
+def attend_selected(q, latent, qi, ki, w, own_valid, ctx: CachedContext,
+                    layer, dtype, *, values: int, scale: float,
+                    with_choice: bool = False):
+    """Absorbed latent attention under the indexer's choice. q (B, T, H,
+    row) are the heads' queries on a latent row, `latent` (B, T, row) the
+    program's own rows, whose first `values` lanes are also the values;
+    qi (B, T, HI, Di), ki (B, T, Di), w (B, T, HI) the indexer's queries,
+    own keys and head weights. Row t of lane b, at position
+    ``ctx.lengths[b] + t``, attends under one softmax (scores times
+    `scale`) to the ``ctx.layout.select`` highest-scored of its lane's
+    cached slots and the own rows `own_valid` (B, T, T) allows, taken
+    together -> (B, T, H, values) in `dtype`; `with_choice`: and the
+    choice (B, T, cached slots + T) bool, for the benchmark's parity.
+
+    The lanes' latent tiles are folded as far as their groups reach
+    (`ctx.reach`), the choice as their mask (`_fold_chosen`)."""
+    q = q[:, :, None]  # (B, T, 1, H, row): one KV head, H query heads
+    own = latent[:, :, None]  # (B, T, 1, row)
+    with jax.named_scope("attn.index.score"):
+        with jax.named_scope("attn.ctx_read"):
+            cached = _cached_index_scores(ctx, layer, qi, w)
+        scores = jnp.concatenate(
+            [cached, index_scores(qi, ki, w, own_valid)], axis=-1)
+    with jax.named_scope("attn.index.topk"):
+        chosen = select_mask(scores, ctx.layout.select)
+    with jax.named_scope("attn.mla.core"):
+        att = _fold_chosen(q, own, chosen, ctx, layer, dtype, values, scale)
+    return (att, chosen) if with_choice else att
+
+
+def _fold_chosen(q, own, chosen, ctx, layer, dtype, values: int, scale):
+    """The lanes' latent tiles folded under the choice as their mask, by
+    `attend_cached`'s loops: tile t read once for the rows of every group
+    that reaches it. q (B, T, 1, H, row), own (B, T, 1, row), chosen (B,
+    T, cached slots + T) -> (B, T, H, values)."""
+    lay, B, G = ctx.layout, q.shape[0], ctx.group
+    n_cached = chosen.shape[-1] - q.shape[1]
+    # a row whose own rows were all passed over starts from weights that
+    # the first chosen cached slot scales to nothing (`_fold`)
+    carry = _own_rows(q, own, own[..., :values], chosen[..., n_cached:],
+                      scale, dtype)
+    per = lay.tile_pages
+    tile = per * lay.block_size
+
+    def tiles_of(rows):
+        def step(t, carry):
+            latent = lay.read(ctx.k_pages, layer, jax.lax.dynamic_slice_in_dim(
+                ctx.tables[:rows], t * per, per, axis=1))
+            valid = jax.lax.dynamic_slice_in_dim(chosen[:rows], t * tile,
+                                                 tile, axis=2)
+            return _fold(carry, _scores(q[:rows], latent, valid, scale),
+                         latent[..., :values], dtype)
+        return step
+
+    with jax.named_scope("attn.ctx_read"):
+        done, at = [], 0
+        for rows in range(B, 0, -G):  # the groups' ends, last group first
+            upto = ctx.reach[rows // G - 1]
+            m, l, acc = jax.lax.fori_loop(at, upto, tiles_of(rows), carry)
+            done.append((acc[rows - G:] / _per_row(l[rows - G:]))
+                        .astype(dtype)[:, :, 0])
             carry = (m[:rows - G], l[:rows - G], acc[:rows - G])
             at = upto
         return jnp.concatenate(done[::-1])

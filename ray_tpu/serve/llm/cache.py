@@ -5,7 +5,12 @@ The device arrays themselves live in the ModelRunner: one K and one V
 pool a KIND of layer that has keys and values. Most families have one
 kind (every such layer alike); a family that mixes full and window
 attention has two (`KVKind`), each with its own head count, its own K and
-V row widths and, for a window kind, the window. This module owns the
+V row widths and, for a window kind, the window. A **latent** kind
+(`KVKind.select`: latent attention under a learned indexer) keeps no
+values: a token's first row is its latent row, key and value of every
+head at once, and its second row, in the pool where another kind keeps V,
+is the indexer's key, both under one block table and one page count.
+This module owns the
 *bookkeeping* (`BlockPool`: which physical pages are free, which pages
 hold which token content; `KVPools`: a model's pools, one a kind, and
 what is counted of them) and the pools' *layout* (`KVLayout`: their shape
@@ -130,7 +135,10 @@ class KVKind(NamedTuple):
     describes it (`ModelAdapter.kv_kinds`): how many layers, their KV
     heads, the width of a K head and of a V head, and the window: a row
     sees itself and the `window - 1` rows before it, or every earlier row
-    (None)."""
+    (None). `select` makes it a latent kind: one head, `head_dim` the
+    latent row's lanes, `v_head_dim` the indexer key's, and a row sees of
+    the earlier rows and itself only the `select` its indexer scores
+    highest."""
 
     name: str
     layers: int
@@ -138,6 +146,7 @@ class KVKind(NamedTuple):
     head_dim: int
     v_head_dim: int
     window: int | None = None
+    select: int | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -160,6 +169,13 @@ class KVLayout:
     verify), layer and page where whole pages are (`write_pages`: a
     prompt's and a chunk's programs, a sixteenth of the indices at pages
     of 16). XLA does either in place on the donated buffer.
+
+    A latent kind (`select`) has the same two pools, read and written the
+    same way, holding other rows: the first the latent rows (at GLM-5 512
+    + 64 lanes and 64 of padding, which the family adds: 4.5 lane tiles
+    make XLA:TPU copy the pool around every program, 5 do not), the second
+    the indexer's keys (128 lanes). No values are stored: the attention
+    takes them from the latent row.
     """
 
     kv_layers: int
@@ -169,11 +185,12 @@ class KVLayout:
     head_dim: int
     v_head_dim: int | None = None  # None: as wide as a K head
     window: int | None = None  # None: a row sees every earlier row
+    select: int | None = None  # a latent kind: the slots a row's indexer picks
 
     @classmethod
     def of(cls, kind: KVKind, num_blocks: int, block_size: int):
         return cls(kind.layers, num_blocks, block_size, kind.n_kv_head,
-                   kind.head_dim, kind.v_head_dim, kind.window)
+                   kind.head_dim, kind.v_head_dim, kind.window, kind.select)
 
     @property
     def row(self) -> int:
@@ -201,9 +218,12 @@ class KVLayout:
         the pool (32 pages, 512 slots, at gpt2-large's 36 layers of 1280;
         8 pages at 8 layers of OLMoE's 2048-wide row; 16 pages at the
         nemotron_h cut's 2 layers of 256; 4 pages at mimo_v2's 2 full
-        layers of 768)."""
+        layers of 768). A latent kind's tile is sized by the indexer
+        key's row, the one read to every lane's length (64 pages, 1,024
+        slots, at GLM-5's 5 layers of 128)."""
+        row = self.row if self.select is None else self.v_row
         pages = max(1, TILE_ELEMENTS_A_LAYER * self.kv_layers
-                    // (self.block_size * self.row))
+                    // (self.block_size * row))
         return 1 << (pages.bit_length() - 1)
 
     @property
@@ -222,6 +242,12 @@ class KVLayout:
         if tensor_ways > 1 and self.n_kv_head % tensor_ways == 0:
             return tensor_ways
         return 1
+
+    def token_bytes(self, dtype_bytes: int) -> dict:
+        """Bytes a token takes in this kind's layers, by sort of row."""
+        k, v = ("k", "v") if self.select is None else ("latent", "index")
+        return {k: self.kv_layers * self.row * dtype_bytes,
+                v: self.kv_layers * self.v_row * dtype_bytes}
 
     def block_bytes(self, dtype_bytes: int, tensor_ways: int = 1) -> int:
         """Bytes one page takes on one device, K and V together."""
@@ -682,6 +708,7 @@ class KVPools:
                 "pages_free": pool.num_free(),
                 "pages_total": pool.usable_blocks,
                 "window": kind.window,
+                "select": kind.select,
                 "released_behind_window": self.released[i],
                 "largest_table": self.largest_table[i],
                 "prefix_taken": self.prefix_taken if i == 0 else 0,

@@ -320,6 +320,14 @@ class LLMEngine:
                 f"layers, whose pages are given back behind the window as "
                 f"rows are planned, before a draft is accepted or "
                 f"rejected (ROADMAP.md, speculation with a window kind)")
+        if spec_cfg and any(kind.select is not None for kind in kinds):
+            raise ValueError(
+                f"speculative decoding is not available for "
+                f"{config.model!r}: the family's rows choose the slots "
+                f"they attend to among the cached rows and the program's "
+                f"own, and the verify program stores a drafted run's rows "
+                f"before it knows which of them stay (ROADMAP.md, "
+                f"speculation with a latent kind)")
         chunking = config.prefill_chunk_size > 0
         rows = chunk_rows(config.prefill_chunk_size, config.block_size,
                           max_len) if chunking else 0
@@ -605,7 +613,9 @@ class LLMEngine:
             "Slots of cached context by kind of program: read as "
             "launched (whole tiles, to the longest lane of a group), "
             "valid (below a lane's length), full (every row to "
-            "max_model_len)", tag_keys=("model", "kind", "what"))
+            "max_model_len); of a latent kind also scored (indexer keys "
+            "read) and selected (slots a row can attend after its "
+            "indexer's choice)", tag_keys=("model", "kind", "what"))
         # routed experts: what the programs report of their routing, by
         # step kind (a dense model's programs report nothing)
         moe_tags = ("model", "kind")
@@ -1501,8 +1511,13 @@ class LLMEngine:
             # and the rows the programs stored in them, by path
             "kv": {name: {**pool, **{
                 "rows_written_" + path: n for path, n in
-                self.runner.rows_written[name].items()}}
-                for name, pool in self.kv.stats().items()},
+                self.runner.rows_written[name].items()},
+                # bytes a token takes in the kind's layers, by sort of
+                # row: k and v, or a latent kind's latent and index rows
+                "token_bytes": lay.token_bytes(
+                    self.runner.k_pages[i].dtype.itemsize)}
+                for i, (lay, (name, pool)) in enumerate(zip(
+                    self.runner.layouts, self.kv.stats().items()))},
             "model": self.config.model,
             "block_size": self.pool.block_size,
             "max_batch_size": self.config.max_batch_size,

@@ -150,7 +150,7 @@ class ModelAdapter:
 
 def adapters() -> dict[str, ModelAdapter]:
     """Model registry (lazy imports keep `import ray_tpu.serve` light)."""
-    from ray_tpu.models import gpt2, llama, mimo_v2, nemotron_h
+    from ray_tpu.models import glm_dsa, gpt2, llama, mimo_v2, nemotron_h
 
     def one_kind(layers, heads):
         """Every layer with keys and values alike, K as wide as V."""
@@ -232,6 +232,21 @@ def adapters() -> dict[str, ModelAdapter]:
             kv_kinds=lambda cfg: tuple(KVKind(*k) for k in cfg.kv_kinds()),
             held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
         ),
+        "glm_dsa": ModelAdapter(
+            name="glm_dsa",
+            config_cls=glm_dsa.GlmDsaConfig,
+            presets={
+                "tiny": glm_dsa.GlmDsaConfig.tiny,
+                "glm_5_l5_ep32": glm_dsa.GlmDsaConfig.glm_5_l5_ep32,
+            },
+            init_fn=glm_dsa.init_glm_dsa,
+            prefill_fn=glm_dsa.glm_dsa_prefill_kv,
+            decode_fn=glm_dsa.glm_dsa_decode_kv,
+            chunk_fn=glm_dsa.glm_dsa_prefill_chunk_kv,
+            rules_fn=glm_dsa.glm_dsa_partition_rules,
+            kv_kinds=lambda cfg: tuple(KVKind(*k) for k in cfg.kv_kinds()),
+            held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
+        ),
     }
 
 
@@ -278,8 +293,15 @@ class Launched(NamedTuple):
 # `slots_reach` what a read from slot 0 to every lane's length would
 # touch, `slots_full` what reading every row to `max_model_len` would
 # (rows x slots a table holds). The monolithic prefill program reads none.
+# A latent kind (`KVLayout.select`) reads two sorts of row: `slots_scored`
+# are the indexer keys read (whole tiles, to a group's longest lane),
+# `slots_selected` the cached slots a row can attend after the choice
+# (the lesser of `select` and the lane's length, a lane), and `slots_read`
+# the latent rows read: the same whole tiles, folded under the choice as
+# their mask. Only a family with such a kind counts the two.
 CONTEXT_KINDS = ("decode", "prefill", "verify")
 CONTEXT_COUNTS = ("slots_read", "slots_valid", "slots_reach", "slots_full")
+SELECT_COUNTS = ("slots_scored", "slots_selected")
 
 
 def _by_kind(x) -> tuple:
@@ -470,11 +492,13 @@ class ModelRunner:
         # logits, every `np.asarray` / `int()` of a program's output)
         self.fetched_bytes = 0
         self.expert_pairs: list[np.ndarray] = []
-        self.context_slots = {kind: dict.fromkeys(CONTEXT_COUNTS, 0)
+        counts = CONTEXT_COUNTS + SELECT_COUNTS * any(
+            lay.select is not None for lay in self.layouts)
+        self.context_slots = {kind: dict.fromkeys(counts, 0)
                               for kind in CONTEXT_KINDS}
         # the same a kind of KV layer
         self.context_by_kind = {
-            name: {kind: dict.fromkeys(CONTEXT_COUNTS, 0)
+            name: {kind: dict.fromkeys(counts, 0)
                    for kind in CONTEXT_KINDS} for name in self.kv_names}
         # valid rows of K (and as many of V) stored by kind of KV layer
         # and by path: a page at a time (`KVLayout.write_pages` on whole
@@ -551,17 +575,21 @@ class ModelRunner:
         full = np.size(lengths) * self.max_blocks_per_seq * self.block_size
         total = self.context_slots[kind]
         for name, lay in zip(self.kv_names, self.layouts):
+            scored = selected = 0
             if lay.window is None:
                 tile = lay.tile_pages * self.block_size
                 longest = np.max(np.reshape(lengths, (-1, group)), axis=1)
                 read = int(np.sum(-(-longest // tile)) * tile * group)
                 valid = reach
+                if lay.select is not None:
+                    scored = read
+                    selected = int(np.sum(np.minimum(lengths, lay.select)))
             else:
                 read = np.size(lengths) * lay.window_pages * self.block_size
                 valid = int(np.sum(np.minimum(lengths, lay.window - 1)))
             by = self.context_by_kind[name][kind]
-            for what, n in (("slots_read", read), ("slots_valid", valid),
-                            ("slots_reach", reach), ("slots_full", full)):
+            for what, n in zip(by, (read, valid, reach, full, scored,
+                                    selected)):
                 total[what] += n
                 by[what] += n
 

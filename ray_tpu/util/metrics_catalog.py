@@ -173,7 +173,9 @@ CATALOG: list[dict] = [
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "slots of cached context by kind of program: read as "
              "launched, valid, and what reading every row to "
-             "max_model_len would be"},
+             "max_model_len would be; of a latent kind also scored "
+             "(indexer keys read) and selected (slots attended after "
+             "the indexer's choice)"},
     {"name": "serve_llm_steps_launched_total", "type": "counter",
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "step programs enqueued, by step kind and by whether the "

@@ -385,7 +385,10 @@ MODELS = {"gpt2-large": ("gpt2", "large", 8, 512, 512, 1024),
               "nemotron_h", "nano_30b_a3b_l18_ep4", 32, 5184, 256, 2560),
           # two kinds of KV layer: 12,288 pages of the full kind, the
           # window kind's 896 sized off the 32 lanes
-          "mimo-v2.5": ("mimo_v2", "v2_5_l7_ep16", 32, 12288, 256, 8704)}
+          "mimo-v2.5": ("mimo_v2", "v2_5_l7_ep16", 32, 12288, 256, 8704),
+          # a latent kind: a pool of latent rows (576 lanes) and a pool of
+          # indexer keys (128 lanes) under one table, 24,576 pages
+          "glm-5": ("glm_dsa", "glm_5_l5_ep32", 32, 24576, 256, 16768)}
 # A program's temporaries, bytes. With no weight cast in any program they
 # are activations: the AOT compile reads 1.1-105.8 MB for gpt2-large (the
 # most in prefill-512; 1.55-1.64 GB while the float32 stacks were cast
@@ -395,6 +398,11 @@ MODELS = {"gpt2-large": ("gpt2", "large", 8, 512, 512, 1024),
 # slot, which XLA relaid out around every program); the mimo_v2 cut's are
 # printed by the test (PERF.md section 6, PR 34)
 TEMP_BOUND = 0.3e9
+# the glm_dsa cut's chunk folds latent tiles of 1,024 slots under the
+# indexer's choice: the scores of 64 heads x 256 rows on a tile are 67 MB in
+# float32, and the program holds a few at once (413 MB in chunk-256, 90 MB
+# in decode-32, 103 MB in prefill-256: the AOT compile, PR 40)
+TEMP_BOUNDS = {"glm-5": 0.5e9}
 
 
 @pytest.fixture(scope="module", params=sorted(MODELS))
@@ -498,6 +506,8 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
         pytest.skip("the engine refuses speculation for a stateful family")
     if program == "verify-5" and len(runner.layouts) > 1:
         pytest.skip("the engine refuses speculation with a window kind")
+    if program == "verify-5" and runner.layout.select is not None:
+        pytest.skip("the engine refuses speculation with a latent kind")
     method, shapes, lanes = PROGRAMS[program]
     sizes = {"m": runner.max_blocks_per_seq, "p": MODELS[model][4],
              "g": MODELS[model][4] // 16, "s": runner.max_batch_size}
@@ -533,7 +543,8 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
                for name, a in state.items() if name == "ssm")
     assert not [r for r in results("copy")
                 if math.prod(r) >= pool_elements]
-    assert compiled.memory_analysis().temp_size_in_bytes < TEMP_BOUND
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < TEMP_BOUNDS.get(model, TEMP_BOUND)
     # what the adapter cast, or the family creates in the compute dtype
     # (but nemotron_h's `conv_w`, a (4, 6144) filter applied in float32
     # beside the state)
