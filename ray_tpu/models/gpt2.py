@@ -316,9 +316,8 @@ def _chunk_block(x, p, attend, cfg: GPT2Config):
     """Chunked-prefill block step. x (B, T, E) holds a CHUNK of the
     sequence at absolute positions start..start+T-1. Attention is the
     cached context + causal within the chunk, and is the caller's:
-    ``attend(q, k, v) -> (B, T, H, D)`` (the dense programs' tiled read,
-    `_attend_cached`, or the paged-attention kernel); projections/MLP are
-    shared. Returns (x, (k, v)) with k/v (B, T, H, D) — the chunk's cache
+    ``attend(q, k, v) -> (B, T, H, D)`` (`_attend_cached`);
+    projections/MLP are shared. Returns (x, (k, v)) with k/v (B, T, H, D) — the chunk's cache
     contribution."""
     B, T, E = x.shape
     dt = cfg.dtype
@@ -454,97 +453,6 @@ def gpt2_decode_kv(
     x = _layer_norm(x, params["lnf"]["scale"], params["lnf"]["bias"])
     logits = x @ params["wte"].astype(dt).T
     return logits.astype(jnp.float32), k_new, v_new
-
-
-# --------------------------------------------------------------------------
-# Paged-attention inference steps: same block math (projections, MLP,
-# residuals shared via the `attend` hook), but the attention core is the
-# ops/paged_attention.py kernel indexing the page pool in place — no
-# dense context gather. k_pages/v_pages are the pool arrays as `layout`
-# (serve/llm/cache.py KVLayout) describes them; the scan walks layer
-# indices and the kernel picks the layer's pages out of the whole pool.
-
-
-def gpt2_decode_paged_kv(
-    params: Params,
-    tokens: jax.Array,
-    positions: jax.Array,
-    layout,
-    k_pages: jax.Array,
-    v_pages: jax.Array,
-    tables: jax.Array,
-    cfg: GPT2Config,
-    *,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One decode step against the page pool. tokens/positions (B,);
-    tables (B, max_blocks_per_seq). Returns (logits (B, Vp) f32,
-    k_new, v_new (L, B, H, D)) — caller scatters, like gpt2_decode_kv."""
-    from ray_tpu.ops.paged_attention import paged_attention
-
-    dt = cfg.dtype
-    x = params["wte"].astype(dt)[tokens] \
-        + params["wpe"].astype(dt)[positions]
-
-    def body(carry, xs):
-        p, layer = xs
-
-        def attend(q, k, v):
-            return paged_attention(q, k, v, k_pages, v_pages, tables,
-                                   positions, layout=layout, layer=layer,
-                                   interpret=interpret)
-
-        return _decode_block(carry, p, attend, cfg)
-
-    x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
-    x = _layer_norm(x, params["lnf"]["scale"], params["lnf"]["bias"])
-    logits = x @ params["wte"].astype(dt).T
-    return logits.astype(jnp.float32), k_new, v_new
-
-
-def gpt2_verify_paged_kv(
-    params: Params,
-    tokens: jax.Array,
-    start: jax.Array,
-    layout,
-    k_pages: jax.Array,
-    v_pages: jax.Array,
-    table: jax.Array,
-    cfg: GPT2Config,
-    *,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Speculative verify window against the page pool: tokens (1, W)
-    at absolute positions start..start+W-1, table (max_blocks_per_seq,)
-    covering cached positions < start. Causal within the window (no
-    chunk mask — a window row only ever attends rows before it, and
-    rows past the draft count are discarded by the caller). Returns
-    (logits (1, W, Vp) f32, k, v (L, 1, W, H, D))."""
-    from ray_tpu.ops.paged_attention import paged_attention
-
-    B, T = tokens.shape
-    dt = cfg.dtype
-    pos = jnp.clip(start + jnp.arange(T), 0, cfg.block_size - 1)
-    x = params["wte"].astype(dt)[tokens] + params["wpe"].astype(dt)[pos]
-    tables = table[None]  # (1, maxB)
-    ctx_len = jnp.reshape(jnp.asarray(start, jnp.int32), (1,))
-
-    def body(carry, xs):
-        p, layer = xs
-
-        def attend(q, k, v):
-            return paged_attention(q, k, v, k_pages, v_pages, tables,
-                                   ctx_len, layout=layout, layer=layer,
-                                   interpret=interpret)
-
-        return _chunk_block(carry, p, attend, cfg)
-
-    x, (k, v) = jax.lax.scan(
-        body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
-    x = _layer_norm(x, params["lnf"]["scale"], params["lnf"]["bias"])
-    logits = x @ params["wte"].astype(dt).T
-    return logits.astype(jnp.float32), k, v
 
 
 def count_params(params: Params) -> int:

@@ -385,9 +385,8 @@ def _chunk_block(x, p, attend, start, cfg: LlamaConfig):
     x (B, T, E) at absolute positions start..start+T-1. Returns (x, (k,
     v)) with k/v (B, T, Hkv, D) post-rope, pre-GQA-replication — the
     cached layout. ``attend(q, k, v) -> (B, T, H, D)`` (k/v
-    pre-replication) is the cached context + causal within the chunk:
-    `_attend_cached`, or the paged-attention kernel; both do the GQA head
-    mapping themselves."""
+    pre-replication) is the cached context + causal within the chunk
+    (`_attend_cached`, which does the GQA head mapping itself)."""
     B, T, E = x.shape
     dt = cfg.dtype
 
@@ -473,85 +472,6 @@ def llama_decode_kv(
     x, (k_new, v_new, counts) = jax.lax.scan(
         body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
     return (_head(x, params, cfg), k_new, v_new) + _aux(counts)
-
-
-# --------------------------------------------------------------------------
-# Paged-attention inference steps — see models/gpt2.py: same block math
-# through the `attend` hook, attention core is the ops/paged_attention
-# kernel over the page pool that `layout` (serve/llm/cache.py KVLayout)
-# describes. The kernel does the GQA head mapping, so K/V stay
-# pre-replication.
-
-
-def llama_decode_paged_kv(
-    params: Params,
-    tokens: jax.Array,
-    positions: jax.Array,
-    layout,
-    k_pages: jax.Array,
-    v_pages: jax.Array,
-    tables: jax.Array,
-    cfg: LlamaConfig,
-    *,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One decode step against the page pool; see llama_decode_kv.
-    Returns (logits (B, Vp) f32, k_new, v_new (L, B, Hkv, D))."""
-    from ray_tpu.ops.paged_attention import paged_attention
-
-    dt = cfg.dtype
-    x = params["wte"].astype(dt)[tokens]
-
-    def body(carry, xs):
-        p, layer = xs
-
-        def attend(q, k, v):
-            return paged_attention(q, k, v, k_pages, v_pages, tables,
-                                   positions, layout=layout, layer=layer,
-                                   interpret=interpret)
-
-        return _decode_block(carry, p, attend, positions, cfg)
-
-    x, (k_new, v_new, counts) = jax.lax.scan(
-        body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
-    return (_head(x, params, cfg), k_new, v_new) + _aux(counts)
-
-
-def llama_verify_paged_kv(
-    params: Params,
-    tokens: jax.Array,
-    start: jax.Array,
-    layout,
-    k_pages: jax.Array,
-    v_pages: jax.Array,
-    table: jax.Array,
-    cfg: LlamaConfig,
-    *,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Speculative verify window against the page pool; see
-    gpt2_verify_paged_kv. tokens (1, W) at positions start..start+W-1.
-    Returns (logits (1, W, Vp) f32, k, v (L, 1, W, Hkv, D))."""
-    from ray_tpu.ops.paged_attention import paged_attention
-
-    dt = cfg.dtype
-    x = params["wte"].astype(dt)[tokens]
-    tables = table[None]  # (1, maxB)
-    ctx_len = jnp.reshape(jnp.asarray(start, jnp.int32), (1,))
-
-    def body(carry, xs):
-        p, layer = xs
-
-        def attend(q, k, v):
-            return paged_attention(q, k, v, k_pages, v_pages, tables,
-                                   ctx_len, layout=layout, layer=layer,
-                                   interpret=interpret)
-
-        return _chunk_block(carry, p, attend, start, cfg)
-
-    x, (k, v, counts) = jax.lax.scan(
-        body, x, (params["blocks"], jnp.arange(cfg.n_layer)))
-    return (_head(x, params, cfg), k, v) + _aux(counts)
 
 
 def llama_loss(params: Params, batch: dict, cfg: LlamaConfig) -> jax.Array:

@@ -22,6 +22,14 @@ and accumulator in float32). A slot past a lane's length had the weight
 masked; not reading it changes no term of the softmax, only the order of
 a float32 sum.
 
+A program with few rows a lane (a decode step, a verify window) reads a
+full kind's context with one Pallas kernel a layer instead of the tile
+loops (`reads_by_kernel`, ops/paged_attention.py): pages copied several
+a step to each lane's own length, the heads' products formed on the page
+rows as they lie. The loops stay the path of every other program (a
+chunk's 256 rows are a different, MXU-bound trade), of a kind the kernel
+does not take, and of the CPU.
+
 A kind of layer with a **window** (`KVLayout.window`: a row sees itself
 and the ``window - 1`` rows before it) has a lower end to its read as
 well: of lane b's cached slots only ``(lengths[b] - window, lengths[b])``
@@ -98,6 +106,31 @@ class CachedContext:
         """Layer `layer`'s keys and values through `tables` (G, n)."""
         return (self.layout.read(self.k_pages, layer, tables),
                 self.layout.read(self.v_pages, layer, tables))
+
+
+# Most rows a lane of a program whose read the kernel takes: a decode
+# step's one, a verify window's K + 1 (a chunk's bucket starts at 16)
+KERNEL_ROWS = 8
+
+
+def reads_by_kernel(layout, rows: int, sink: bool = False) -> bool:
+    """Whether a program of `rows` rows a lane reads the cached context
+    of a kind of layer whose pools lie as `layout` with the Pallas kernel
+    (ops/paged_attention.py) and not with the tile loops below: few rows
+    a lane, every earlier row seen (no window, no selection), K and V
+    heads of one width and no sink, rows and pages that are whole tiles
+    of the chip's memory, pools that lie whole on one chip (under the
+    mesh in force: a pool split by heads over `tensor` keeps the loops,
+    which XLA partitions), on a TPU. The one place that picks the path:
+    `attend_cached` asks it for the program it traces, the runner for
+    what it counts as read (`ModelRunner._note_context`)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    tensor_ways = dict(mesh.shape).get("tensor", 1)
+    return (jax.default_backend() == "tpu" and rows <= KERNEL_ROWS
+            and layout.window is None and layout.select is None
+            and not sink and layout.v_row == layout.row
+            and layout.row % 128 == 0 and layout.block_size % 16 == 0
+            and layout.shard_ways(tensor_ways) == 1)
 
 
 def causal_rows(chunk_mask):
@@ -231,13 +264,23 @@ def attend_cached(q, k, v, own_valid, ctx: CachedContext, layer, dtype,
     ``ctx.lengths[b] + t``, sees of both only the rows above ``position -
     window``. `sink` (HK, R): a column of the softmax with no value.
 
-    Tile by tile: tile t is read for the rows of every group that reaches
+    A program of few rows a lane hands the whole of it to the kernel
+    where `reads_by_kernel` allows. Otherwise,
+    tile by tile: tile t is read for the rows of every group that reaches
     it, which are the first rows of the program, whatever the lanes'
     order (no group behind a group reaches further than `ctx.reach` says
     of it). One loop a group, last group first: it runs the tiles that its
     group reaches and the groups behind it did not, on all rows up to its
     group's, and leaves its group's rows finished. A window kind reads
     its one tile a lane instead (`_window_tile`)."""
+    if reads_by_kernel(ctx.layout, q.shape[1], sink is not None):
+        from ray_tpu.ops.paged_attention import paged_attention
+
+        with jax.named_scope("attn.ctx_read"):
+            return paged_attention(
+                q, k, v, own_valid, ctx.k_pages, ctx.v_pages, ctx.tables,
+                ctx.lengths, layout=ctx.layout, layer=layer, dtype=dtype,
+                interpret=jax.default_backend() != "tpu")
     B, G = q.shape[0], ctx.group
     scale = 1.0 / (q.shape[-1] ** 0.5)
     window = ctx.layout.window

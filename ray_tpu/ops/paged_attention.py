@@ -1,58 +1,41 @@
-"""Paged attention — pallas TPU kernel over the serve.llm KV page pool.
+"""The cached-context read of a decode or verify step, one Pallas TPU
+kernel a layer over the serve.llm KV page pool.
 
-The dense decode/verify programs gather, layer by layer, a group of
-lanes' pages into a ``(lanes, tiles * tile, H_kv, D)`` context before
-attending: whole tiles of pages up to the group's longest lane
-(ops/context_attention.py), relaid out into heads on the way. This
-kernel is the vLLM-PagedAttention shape instead (PAPERS.md): queries
-index the page pool *in place* through the block table, one page per
-grid step (every page of the table is a step, pages past the lane's
-length skipped inside it), with the layer index, the table and the
-context lengths delivered via scalar prefetch so the page id is known
-before the page's DMA is issued.
+`ops/context_attention.py` `attend_cached` hands a program with few rows
+a lane (decode, T = 1; verify, T = K + 1) to `paged_attention` below
+where its predicate (`context_attention.reads_by_kernel`) allows; every
+other program keeps the XLA tile loops there. The kernel
 
-Operands:
+- takes the K and the V pool whole, as they lie (`KVLayout.shape`: one
+  lane-dense row of ``n_kv_head * head_dim`` a token), in HBM
+  (``pl.ANY``), with the layer index, the lanes' block tables and their
+  lengths by scalar prefetch, and copies `pages_a_step` pages a step
+  into double-buffered VMEM with its own async copies: the copies of
+  step n + 1, which may be the next lane's first, are started before
+  step n is computed (the shape of JAX's public
+  ``pallas.ops.tpu.paged_attention``);
+- stops at each lane's own length, to the page: a lane of 96 slots
+  copies 6 pages, a padded lane of a bucket none. One flat loop runs
+  the steps of every lane, ``max(1, ceil(length / slots a step))`` a
+  lane: nothing is grouped, ordered or bounded by the longest lane;
+- forms the products on the page rows as they lie, on the MXU: the
+  lane's queries come as a block-diagonal ``(C, row)`` matrix, column
+  block ``c = (r, t, h)`` holding query head ``(h, r)`` of row t in KV
+  head h's lanes and zero elsewhere, so that ``Q_bd (C, row) . K_pages
+  (slots, row)^T`` is every head's scores at once, and ``P (C, slots) @
+  V_pages (slots, v_row)`` holds every head's output in its own lanes of
+  its own rows (the diagonal blocks; the rest is discarded). Operands in
+  the pool's dtype, float32 accumulation, the probabilities cast to the
+  pool's dtype before the value product as `context_attention._weigh`
+  does;
+- keeps the running softmax (max, sum, accumulator: float32) in VMEM
+  across a lane's steps, started on the program's own rows (which are
+  never in the pages: they are scattered after the step), under the
+  same softmax.
 
-- ``q``                (S, W, H, D)  — W query positions per sequence:
-  W=1 is plain decode, W=K+1 is the speculative verify window;
-- ``own_k``/``own_v``  (S, W, H_kv, D) — the window's OWN keys/values
-  (they are never in the pages: decode/verify scatter them after the
-  step), attended causally within the window;
-- ``k_pages``/``v_pages`` — the whole pool as the runner holds it, and
-  ``layout`` — the `serve/llm/cache.py` ``KVLayout`` that says how it
-  lies (one lane-dense row of ``H_kv * D`` per token, head ``h`` in
-  lanes ``[h * D, (h + 1) * D)``) and gives the kernel its page block;
-  ``layer`` (a traced i32, or an int) selects the layer. The models scan
-  over layer INDICES and close over the whole pool: slicing the pool per
-  layer, or reshaping it, would copy it;
-- ``tables``           (S, max_blocks_per_seq) i32 — logical page i of
-  sequence s lives in physical page ``tables[s, i]`` (padding points at
-  the null page 0, which the length mask excludes anyway);
-- ``ctx_len``          (S,) i32 — valid cached slots (positions
-  < ctx_len[s] are real; everything else in the mapped pages is
-  garbage past the lane's frontier).
-
-Blocking. One grid step holds one whole page ``(block_size, H_kv * D)``,
-which the ``(8, 128)`` tiling divides (768 lanes at gpt2-small, 1280 at
-gpt2-large, 1024 at 8 x 128), and nothing in the kernel ever leaves that
-row: queries are regrouped outside the kernel to ``(S, W, rep,
-H_kv * D)`` (head ``h = hk * rep + r``, so one query row lines up with
-one page row, lane for lane), the window's own keys and values are
-flattened the same way, and the online-softmax state (running max, sum,
-f32 accumulator; VMEM scratch like ops/flash_attention.py) is kept per
-lane, every lane of a head holding that head's value. Grid is
-(S, max_blocks_per_seq), pages innermost and sequential; pages wholly
-past ``ctx_len`` are skipped with ``pl.when``; the final grid step folds
-in the causal own-window block and normalizes. The arithmetic is a VPU
-multiply per (window row, group member) over all KV heads at once, and
-the sum over a head's ``D`` lanes is a butterfly of lane rotations
-(``_head_sums``; ``D`` a power of two) that leaves the total in every
-lane of the head — decode is bound by reading pages, and there is no
-in-kernel relayout.
-
-``interpret=True`` runs the same kernel through the pallas interpreter
-on CPU (tests, parity gates); on TPU it compiles for real. The dense
-reference (`paged_attention_reference`) is the parity oracle.
+``interpret=True`` runs the same kernel through the Pallas interpreter
+on the CPU (the tests); `paged_attention_reference` is its full-width
+oracle, every slot of the tables read and masked.
 """
 
 from __future__ import annotations
@@ -64,149 +47,203 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
+MASKED = -1e30  # the score of a slot no row sees; exp(MASKED - m) is 0
+
+# K and V bytes a step copies, at most: a step costs about a microsecond
+# beside its bytes (its pages' copies are issued one by one, its products
+# wait for them), so it moves a MB or two: 16 pages of 82 KB at gpt2-large
+# (631 GB/s of valid rows at 4 lanes x 1,000 slots on the v5e, 524 at 8
+# pages), 16 of 131 KB at OLMoE (708), 128 of 16 KB at the nemotron_h cut
+# (221: a copy is one page, and at 8 KB a copy their issue is what is
+# left; PERF.md section 6, PR 41)
+STEP_BYTES = 2 * 1024 * 1024
+# a step's slots are whole lane tiles of the scores where the pages allow
+STEP_SLOTS_MIN = 128
+# the program's own rows join as one more block, padded to a sublane
+# tile of the pool's dtype
+OWN_ROWS = 16
 
 
-def _head_sums(x, head_dim):
-    """x (N, H_kv * D) -> the same shape, every lane holding the sum over
-    its own head's D lanes: log2(D) butterfly steps, lane i adding lane
-    i ^ d (heads are D-aligned and D is a power of two, so the partner
-    never leaves the head)."""
-    row = x.shape[1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    d = 1
-    while d < head_dim:
-        x = x + jnp.where((lane & d) != 0, pltpu.roll(x, d, 1),
-                          pltpu.roll(x, row - d, 1))
-        d *= 2
-    return x
+def pages_a_step(layout, itemsize: int, table_pages: int) -> int:
+    """Pages the kernel copies a step for pools of `layout`: the largest
+    power of two under `STEP_BYTES` of K and V, at least `STEP_SLOTS_MIN`
+    slots, at most the table."""
+    page = layout.block_size * (layout.row + layout.v_row) * itemsize
+    pages = max(1, STEP_BYTES // page)
+    pages = 1 << (pages.bit_length() - 1)
+    return min(max(pages, -(-STEP_SLOTS_MIN // layout.block_size)),
+               table_pages)
 
 
-def _paged_kernel(layer_ref, tables_ref, ctxlen_ref, q_ref, ko_ref, vo_ref,
-                  kp_ref, vp_ref, o_ref, acc, m_s, l_s, *, scale, nb, bs,
-                  head_dim):
-    del layer_ref, tables_ref  # consumed by the index maps
-    s_i = pl.program_id(0)
-    b = pl.program_id(1)
-    W, rep = q_ref.shape[0], q_ref.shape[1]
+def _read_kernel(layer_ref, tables_ref, lengths_ref, q_ref, ko_ref, vo_ref,
+                 bias_ref, kp_ref, vp_ref, o_ref, k_buf, v_buf, sems, m_s,
+                 l_s, acc_s, *, scale, pages, bs, heads, v_width):
+    lanes, rows = o_ref.shape[0], o_ref.shape[1]
+    C, v_row = acc_s.shape
+    S = pages * bs
+    layer = layer_ref[0]
 
-    @pl.when(b == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, -jnp.inf)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc[...] = jnp.zeros_like(acc)
+    def copies(lane, blk, slot, j):
+        page = tables_ref[lane, blk * pages + j]
+        dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
+        return (pltpu.make_async_copy(kp_ref.at[layer, page],
+                                      k_buf.at[slot, dst], sems.at[0, slot]),
+                pltpu.make_async_copy(vp_ref.at[layer, page],
+                                      v_buf.at[slot, dst], sems.at[1, slot]))
 
-    ctx = ctxlen_ref[s_i]
+    def each_page(lane, blk, slot, do):
+        """`do` on the copies of block `blk`'s pages below the lane's
+        length: none for a lane with no context."""
+        left = lengths_ref[lane] - blk * S
+        n = jnp.clip((left + bs - 1) // bs, 0, pages)
 
-    def _accum(i, k, v, valid):
-        # row i = (w, r): one query per KV head, flattened like a page
-        # row, q (1, row); k/v (N, row) f32; valid (N, row). State rows
-        # are (1, row), a head's value repeated over its lanes.
-        one = pl.ds(i, 1)
-        q = q_ref[i // rep, pl.ds(i % rep, 1), :].astype(jnp.float32)
-        s = _head_sums(k * q, head_dim) * scale
-        s = jnp.where(valid, s, DEFAULT_MASK_VALUE)
-        m_prev = m_s[one, :]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
+        def one(j, carry):
+            for c in copies(lane, blk, slot, j):
+                do(c)
+            return carry
+
+        jax.lax.fori_loop(0, n, one, 0)
+
+    def fold(s, values):
+        """Scores s (C, n) float32 and their values (n, v_row) folded
+        into the running softmax."""
+        m = m_s[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
-        l_s[one, :] = alpha * l_s[one, :] + jnp.sum(p, axis=0,
-                                                    keepdims=True)
-        acc[one, :] = acc[one, :] * alpha + jnp.sum(p * v, axis=0,
-                                                    keepdims=True)
-        m_s[one, :] = m_new
+        l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_s[...] = alpha * acc_s[...] + jnp.dot(
+            p.astype(values.dtype), values,
+            preferred_element_type=jnp.float32)
+        m_s[...] = m_new
 
-    @pl.when(b * bs < ctx)
-    def _page():
-        k = kp_ref[...].astype(jnp.float32)
-        v = vp_ref[...].astype(jnp.float32)
-        cols = b * bs + jax.lax.broadcasted_iota(jnp.int32, k.shape, 0)
-        for i in range(W * rep):
-            _accum(i, k, v, cols < ctx)
+    def scores(q, keys):  # (C, row) . (n, row)^T -> (C, n) float32
+        return jax.lax.dot_general(
+            q, keys, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
 
-    @pl.when(b == nb - 1)
-    def _own_and_emit():
-        k = ko_ref[...].astype(jnp.float32)
-        v = vo_ref[...].astype(jnp.float32)
-        x = jax.lax.broadcasted_iota(jnp.int32, k.shape, 0)
-        for i in range(W * rep):
-            _accum(i, k, v, x <= i // rep)
-            l = l_s[pl.ds(i, 1), :]
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[i // rep, pl.ds(i % rep, 1), :] = (
-                acc[pl.ds(i, 1), :] / l_safe).astype(o_ref.dtype)
+    def step(n, at):
+        lane, blk = at
+        slot = n % 2
+        length = lengths_ref[lane]
+        last = (blk + 1) * S >= length
+        nxt = (jnp.where(last, lane + 1, lane), jnp.where(last, 0, blk + 1))
+
+        @pl.when(nxt[0] < lanes)
+        def _():
+            each_page(*nxt, 1 - slot, lambda c: c.start())
+
+        q = q_ref[lane]
+
+        @pl.when(blk == 0)
+        def _():  # the running softmax starts on the program's own rows
+            m_s[...] = jnp.full_like(m_s, MASKED)
+            l_s[...] = jnp.zeros_like(l_s)
+            acc_s[...] = jnp.zeros_like(acc_s)
+            fold(scores(q, ko_ref[lane]) + bias_ref[lane], vo_ref[lane])
+
+        @pl.when(blk * S < length)
+        def _():
+            each_page(lane, blk, slot, lambda c: c.wait())
+            s = scores(q, k_buf[slot])
+            at_slot = blk * S + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            fold(jnp.where(at_slot < length, s, MASKED), v_buf[slot])
+
+        @pl.when(last)
+        def _():
+            # row c = (rt, h) of the accumulator holds head h's output
+            # in lanes [h * v_width, (h + 1) * v_width)
+            out = acc_s[...] / l_s[...]
+            c = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+            lane_at = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+            picked = []
+            for rt in range(rows):
+                h = c - rt * heads
+                own = (h >= 0) & (h < heads) & (lane_at >= h * v_width) \
+                    & (lane_at < (h + 1) * v_width)
+                picked.append(jnp.sum(jnp.where(own, out, 0.0), axis=0,
+                                      keepdims=True))
+            o_ref[lane] = jnp.concatenate(picked, axis=0).astype(o_ref.dtype)
+
+        return nxt
+
+    # a slot past a lane's length is never copied: what a buffer holds
+    # there must be a number (its weight is 0)
+    v_buf[...] = jnp.zeros_like(v_buf)
+    each_page(0, 0, 0, lambda c: c.start())
+    total = jax.lax.fori_loop(
+        0, lanes,
+        lambda b, n: n + jnp.maximum(1, (lengths_ref[b] + S - 1) // S), 0)
+    jax.lax.fori_loop(0, total, step, (0, 0))
 
 
-def paged_attention(q, own_k, own_v, k_pages, v_pages, tables, ctx_len,
-                    *, layout, layer=0, sm_scale: float | None = None,
-                    interpret: bool = False):
-    """One layer of paged attention; see the module docstring for the
-    operand layout. Returns (S, W, H, D) in q's dtype. Every query row
-    attends [cached slots < ctx_len[s]] ++ [own window, causally]."""
-    S, W, H, D = q.shape
-    HK = own_k.shape[2]
-    rep = H // HK
-    if D & (D - 1):
-        raise ValueError(f"head_dim {D} is not a power of two")
-    bs, row = layout.block_size, layout.row
-    maxB = tables.shape[1]
-    scale = sm_scale if sm_scale is not None else 1.0 / (D**0.5)
-    # head h = hk * rep + r  ->  (S, W, rep, HK * D): a query row then
-    # lines up with a page row, lane for lane
-    qg = q.reshape(S, W, HK, rep, D).swapaxes(2, 3).reshape(S, W, rep, row)
-    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
-    own = vmem((None, W, row), lambda s, b, l, t, c: (s, 0, 0))
-    page = vmem(layout.page_block(),
-                lambda s, b, l, t, c: layout.page_index(l[0], t[s, b]))
-    qspec = vmem((None, W, rep, row), lambda s, b, l, t, c: (s, 0, 0, 0))
-    state = pltpu.VMEM((W * rep, row), jnp.float32)
+def paged_attention(q, k, v, own_valid, k_pages, v_pages, tables, lengths,
+                    *, layout, layer, dtype, interpret: bool = False):
+    """q (B, T, HK, R, D) attends, under one softmax scaled by ``1 /
+    sqrt(D)``, to lane b's cached rows ``[0, lengths[b])`` of layer
+    `layer` (a traced i32, or an int) of the pools, through ``tables (B,
+    pages a lane)``, and to the program's own rows k (B, T, HK, D), v (B,
+    T, HK, Dv) where `own_valid` (B, T, T) allows, of which every row
+    sees one -> (B, T, HK, R, Dv) in `dtype`. The pools lie as `layout`
+    (serve/llm/cache.py `KVLayout`) says; a table entry at or past
+    ``ceil(lengths[b] / block_size)`` is never read."""
+    B, T, HK, R, D = q.shape
+    Dv = v.shape[-1]
+    rows, C = R * T, HK * R * T
+    row, v_row = HK * D, HK * Dv
+    pages = pages_a_step(layout, k_pages.dtype.itemsize, tables.shape[1])
+    S = pages * layout.block_size
+    # column block c = (r, t, h): query head (h, r) of row t in KV head
+    # h's lanes of a page row, zero in every other head's
+    qr = jnp.transpose(q, (0, 3, 1, 2, 4)).reshape(B, rows, 1, row)
+    in_head = (jnp.arange(row) // D)[None, :] == jnp.arange(HK)[:, None]
+    q_bd = jnp.where(in_head, qr, 0).reshape(B, C, row)
+    pad = -T % OWN_ROWS
+    seen = jnp.broadcast_to(own_valid[:, None, :, None, :],
+                            (B, R, T, HK, T)).reshape(B, C, T)
+    bias = jnp.pad(jnp.where(seen, 0.0, MASKED).astype(jnp.float32),
+                   ((0, 0), (0, 0), (0, pad)), constant_values=MASKED)
+    own = ((0, 0), (0, pad), (0, 0))
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, scale=scale, nb=maxB, bs=bs,
-                          head_dim=D),
-        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        functools.partial(_read_kernel, scale=1.0 / (D ** 0.5), pages=pages,
+                          bs=layout.block_size, heads=HK, v_width=Dv),
+        out_shape=jax.ShapeDtypeStruct((B, rows, v_row), dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(S, maxB),
-            in_specs=[qspec, own, own, page, page],
-            out_specs=qspec,
-            scratch_shapes=[state, state, state],
-        ),
+            grid=(1,),
+            in_specs=[whole, whole, whole, whole, in_hbm, in_hbm],
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((2, S, row), k_pages.dtype),
+                pltpu.VMEM((2, S, v_row), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((C, 1), jnp.float32),
+                pltpu.VMEM((C, 1), jnp.float32),
+                pltpu.VMEM((C, v_row), jnp.float32),
+            ]),
+        name="ctx_read_paged",
         interpret=interpret,
     )(jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
-      tables.astype(jnp.int32), ctx_len.astype(jnp.int32),
-      qg, own_k.reshape(S, W, row), own_v.reshape(S, W, row),
-      k_pages, v_pages)
-    return out.reshape(S, W, rep, HK, D).swapaxes(2, 3).reshape(S, W, H, D)
+      tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      q_bd, jnp.pad(k.reshape(B, T, row), own),
+      jnp.pad(v.reshape(B, T, v_row), own), bias, k_pages, v_pages)
+    return jnp.transpose(out.reshape(B, R, T, HK, Dv), (0, 2, 3, 1, 4))
 
 
-def paged_attention_reference(q, own_k, own_v, k_pages, v_pages, tables,
-                              ctx_len, *, layout, layer=0):
-    """Dense jnp oracle for the kernel (tests): read the layer's pages
-    through the table, mask by ctx_len, causal own window. Same operand
-    layout."""
-    S, W, H, D = q.shape
-    HK = own_k.shape[2]
-    rep = H // HK
-    k_ctx = layout.read(k_pages, layer, tables)  # (S, C, HK, D)
-    v_ctx = layout.read(v_pages, layer, tables)
-    C = k_ctx.shape[1]
-    k_ctx = jnp.repeat(k_ctx, rep, axis=2)
-    v_ctx = jnp.repeat(v_ctx, rep, axis=2)
-    ko = jnp.repeat(own_k, rep, axis=2)
-    vo = jnp.repeat(own_v, rep, axis=2)
-    scale = 1.0 / (D**0.5)
-    s_ctx = jnp.einsum("swhd,schd->shwc", q, k_ctx).astype(jnp.float32)
-    s_own = jnp.einsum("swhd,sxhd->shwx", q, ko).astype(jnp.float32)
-    s = jnp.concatenate([s_ctx, s_own], axis=-1) * scale
-    ctx_valid = jnp.arange(C)[None, :] < ctx_len[:, None]  # (S, C)
-    causal = jnp.tril(jnp.ones((W, W), dtype=bool))
-    valid = jnp.concatenate(
-        [jnp.broadcast_to(ctx_valid[:, None, :], (S, W, C)),
-         jnp.broadcast_to(causal[None], (S, W, W))], axis=-1)
-    s = jnp.where(valid[:, None, :, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    att = jnp.einsum("shwc,schd->swhd", p[..., :C],
-                     v_ctx.astype(jnp.float32)) \
-        + jnp.einsum("shwx,sxhd->swhd", p[..., C:],
-                     vo.astype(jnp.float32))
-    return att.astype(q.dtype)
+def paged_attention_reference(q, k, v, own_valid, k_pages, v_pages, tables,
+                              lengths, *, layout, layer, dtype):
+    """The kernel's oracle (tests), same operands: every slot the tables
+    hold read and masked by the lane's length, one full-width softmax
+    (`context_attention.softmax_over`)."""
+    from ray_tpu.ops.context_attention import softmax_over
+
+    B, T = q.shape[:2]
+    keys = layout.read(k_pages, layer, tables)
+    values = layout.read(v_pages, layer, tables)
+    cached = jnp.broadcast_to(
+        jnp.arange(keys.shape[1])[None, None, :] < lengths[:, None, None],
+        (B, T, keys.shape[1]))
+    return softmax_over(q, [(keys, values, cached), (k, v, own_valid)],
+                        1.0 / (q.shape[-1] ** 0.5), dtype)

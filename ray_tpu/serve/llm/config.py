@@ -95,8 +95,11 @@ class EngineConfig:
     # speculative decoding: SpeculativeConfig | dict | None (off).
     # See serve/llm/spec.py — greedy outputs stay bit-identical.
     speculative: Any = None
-    # paged-attention pallas kernel for decode + verify (interpret mode
-    # on CPU, real kernel on TPU). Off => dense gathered-context math.
+    # read by nothing: which programs read their context with the Pallas
+    # kernel is the code's choice (`context_attention.reads_by_kernel`).
+    # The field is here only because the five serve configurations under
+    # benchmark/configs/ carry the key as false and `from_dict` refuses
+    # an unknown one; it goes when they drop it (ROADMAP.md D2)
     use_paged_attention: bool = False
 
     def __post_init__(self):
@@ -106,6 +109,12 @@ class EngineConfig:
             raise ValueError("max_batch_size must be >= 1")
         if self.prefill_chunk_size < 0:
             raise ValueError("prefill_chunk_size must be >= 0")
+        if self.use_paged_attention:
+            raise ValueError(
+                "use_paged_attention is no choice any more: a decode or "
+                "verify step reads its context with the Pallas kernel "
+                "wherever the code can (ops/context_attention.py "
+                "reads_by_kernel); leave the key out, or false")
         from ray_tpu.serve.llm.spec import SpeculativeConfig
         self.speculative = SpeculativeConfig.from_payload(self.speculative)
 

@@ -398,7 +398,6 @@ class LLMEngine:
             mesh=mesh,
             sample_seed=config.seed + 1,
             num_draft_tokens=self._spec_k,
-            use_paged_attention=config.use_paged_attention,
         )
         # weights cast to their resident dtypes and placed, both pools
         # and the lanes' state allocated. Nothing keeps the tree as given
@@ -586,13 +585,6 @@ class LLMEngine:
             "Speculative verify dispatch latency (one drafted run)",
             boundaries=(1, 5, 10, 25, 50, 100, 250, 500, 1000),
             tag_keys=tags)
-        self._m_paged = Gauge(
-            "serve_llm_paged_attn_enabled",
-            "1 when decode/verify run the pallas paged-attention "
-            "kernel, 0 on the dense fallback", tag_keys=tags)
-        self._m_paged.set(
-            1.0 if self.runner.use_paged_attention else 0.0,
-            tags=self._m_tags)
         self._m_weight_bytes = Gauge(
             "serve_llm_weight_bytes",
             "Bytes of the resident parameter tree, each leaf in the "
@@ -1562,7 +1554,9 @@ class LLMEngine:
             # launched, valid, and what a read to max_model_len would be
             "context": {kind: dict(n) for kind, n in
                         self.runner.context_slots.items()},
-            # the same a kind of KV layer: {kv kind: {program: counts}}
+            # the same a kind of KV layer: {kv kind: {program: counts}},
+            # with `kernel_steps`: the launches whose read of the kind
+            # ran the Pallas kernel (ops/paged_attention.py)
             "context_by_kind": {
                 name: {kind: dict(n) for kind, n in by.items()}
                 for name, by in self.runner.context_by_kind.items()},
@@ -1578,7 +1572,6 @@ class LLMEngine:
             "weights": dict(self.runner.weights),
             "spec_proposed": self._spec_proposed_total,
             "spec_accepted": self._spec_accepted_total,
-            "paged_attention": self.runner.use_paged_attention,
             # recurrent state: slots, bytes, slots started from zero,
             # admissions that looked up no prefix; {} for a family with
             # none

@@ -32,9 +32,12 @@ takes from the lanes' positions (a chunk's `start`) at run time
 (ops/context_attention.py). A decode program's rows are the step's lanes
 by position, longest first, in groups of `lanes_per_group` consecutive
 rows, so that a short lane is not read to a long one's length; `collect`
-hands the results back in the caller's order. `context_slots` counts
-what was read, what of it was valid and what a read to
-``max_model_len`` would have been.
+hands the results back in the caller's order. Where
+`context_attention.reads_by_kernel` allows (a decode's or a verify's
+few rows a lane, on a TPU) a Pallas kernel reads each lane to its own
+length instead, a page the unit (ops/paged_attention.py).
+`context_slots` counts what was read by the path taken, what of it was
+valid and what a read to ``max_model_len`` would have been.
 
 Padded lanes, and the pages of a prompt's or a chunk's bucket that hold
 no valid row, point at **page 0** (the pool's null sink), so every
@@ -76,6 +79,7 @@ import numpy as np
 
 from jax.sharding import NamedSharding, PartitionSpec
 
+from ray_tpu.ops import context_attention
 from ray_tpu.ops.context_attention import CachedContext
 from ray_tpu.serve.llm.cache import (
     KVKind,
@@ -137,15 +141,6 @@ class ModelAdapter:
     # dtype the forwards consume it in, one already there as the same
     # buffer (llama: `init_llama` creates every leaf in `param_dtype`)
     resident_fn: Callable = lambda params, cfg: params
-    # paged-attention entry points (ops/paged_attention.py kernel in the
-    # attention core instead of dense gathered context); None => family
-    # has no paged path and the engine falls back to dense
-    # (params, toks, pos, layout, k_pages, v_pages, tables, cfg,
-    #  interpret) -> ...
-    decode_paged_fn: Callable | None = None
-    # (params, toks, start, layout, k_pages, v_pages, table, cfg,
-    #  interpret) -> ...
-    verify_paged_fn: Callable | None = None
 
 
 def adapters() -> dict[str, ModelAdapter]:
@@ -176,8 +171,6 @@ def adapters() -> dict[str, ModelAdapter]:
             kv_kinds=one_kind(lambda cfg: cfg.n_layer,
                               lambda cfg: cfg.n_head),
             resident_fn=gpt2.gpt2_resident_params,
-            decode_paged_fn=gpt2.gpt2_decode_paged_kv,
-            verify_paged_fn=gpt2.gpt2_verify_paged_kv,
         ),
         "llama": ModelAdapter(
             name="llama",
@@ -196,8 +189,6 @@ def adapters() -> dict[str, ModelAdapter]:
             rules_fn=llama.llama_partition_rules,
             kv_kinds=one_kind(lambda cfg: cfg.n_layer,
                               lambda cfg: cfg.n_kv_head),
-            decode_paged_fn=llama.llama_decode_paged_kv,
-            verify_paged_fn=llama.llama_verify_paged_kv,
         ),
         "nemotron_h": ModelAdapter(
             name="nemotron_h",
@@ -288,7 +279,8 @@ class Launched(NamedTuple):
 
 # What the programs read of their lanes' cached context, in slots, by
 # kind of program: `slots_read` as launched (tiles x tile x the lanes of
-# a group; a window kind: its one tile a lane), `slots_valid` of them
+# a group; on the kernel's path whole pages to each lane's length; a
+# window kind: its one tile a lane), `slots_valid` of them
 # that a row can see (below a lane's length, and inside the window),
 # `slots_reach` what a read from slot 0 to every lane's length would
 # touch, `slots_full` what reading every row to `max_model_len` would
@@ -414,7 +406,6 @@ class ModelRunner:
         mesh=None,
         sample_seed: int = 0,
         num_draft_tokens: int = 0,
-        use_paged_attention: bool = False,
     ):
         self.adapter = adapter
         self.cfg = cfg
@@ -437,12 +428,6 @@ class ModelRunner:
         # accept/reject outcome — `n_draft` and `start` are traced
         self.num_draft_tokens = num_draft_tokens
         self.spec_width = num_draft_tokens + 1 if num_draft_tokens else 0
-        # paged attention only when the family provides the entry points
-        self.use_paged_attention = bool(
-            use_paged_attention and adapter.decode_paged_fn is not None
-            and adapter.verify_paged_fn is not None)
-        # pallas interpret mode off-TPU (CPU CI); real kernel on TPU
-        self._interpret = jax.default_backend() != "tpu"
 
         # a layout, a pool pair and a block table a kind of KV layer;
         # `num_blocks` an int: the first kind's pool, a window kind's
@@ -454,7 +439,6 @@ class ModelRunner:
         self.layouts = tuple(KVLayout.of(kind, n, block_size)
                              for kind, n in zip(kinds, self.num_blocks))
         self.kv_names = tuple(kind.name for kind in kinds)
-        self.layout = self.layouts[0]  # the paged kernels' (one kind)
         # a lane slot's recurrent state, for a family that has it
         self.state_layout = state_layout_of(adapter, cfg, max_batch_size)
         # pages are mutated functionally; serialize compute just in case
@@ -497,8 +481,11 @@ class ModelRunner:
         self.context_slots = {kind: dict.fromkeys(counts, 0)
                               for kind in CONTEXT_KINDS}
         # the same a kind of KV layer
+        # and the launches whose read of the kind ran the kernel
+        # (`_by_kernel`: which kinds' does, by a program's rows a lane)
+        self._by_kernel: dict[int, list[bool]] = {}
         self.context_by_kind = {
-            name: {kind: dict.fromkeys(counts, 0)
+            name: {kind: dict.fromkeys(counts + ("kernel_steps",), 0)
                    for kind in CONTEXT_KINDS} for name in self.kv_names}
         # valid rows of K (and as many of V) stored by kind of KV layer
         # and by path: a page at a time (`KVLayout.write_pages` on whole
@@ -567,16 +554,31 @@ class ModelRunner:
         PR 33)."""
         return 1 if rows < 8 else max(2, rows // 8)
 
-    def _note_context(self, kind: str, lengths, group: int = 1) -> None:
-        """Count what a program launched on lanes of `lengths` (as the
-        program has them: ordered, padded) reads of their context, in
-        every kind of KV layer."""
+    def _note_context(self, kind: str, lengths, group: int = 1,
+                      rows: int = 1) -> None:
+        """Count what a program of `rows` rows a lane launched on lanes of
+        `lengths` (as the program has them: ordered, padded) reads of
+        their context, in every kind of KV layer, by the path the program
+        takes there (`reads_by_kernel`): the kernel's whole pages to each
+        lane's own length, and a launch counted in `kernel_steps`, or the
+        loops' whole tiles to each group's longest lane."""
         reach = int(np.sum(lengths))
         full = np.size(lengths) * self.max_blocks_per_seq * self.block_size
         total = self.context_slots[kind]
-        for name, lay in zip(self.kv_names, self.layouts):
+        if rows not in self._by_kernel:
+            with self._mesh_ctx():  # the mesh the program is traced under
+                self._by_kernel[rows] = [
+                    context_attention.reads_by_kernel(lay, rows)
+                    for lay in self.layouts]
+        for name, lay, kernel in zip(self.kv_names, self.layouts,
+                                     self._by_kernel[rows]):
             scored = selected = 0
-            if lay.window is None:
+            if kernel:
+                read = int(np.sum(-(-np.asarray(lengths) // self.block_size))
+                           * self.block_size)
+                valid = reach
+                self.context_by_kind[name][kind]["kernel_steps"] += 1
+            elif lay.window is None:
                 tile = lay.tile_pages * self.block_size
                 longest = np.max(np.reshape(lengths, (-1, group)), axis=1)
                 read = int(np.sum(-(-longest // tile)) * tile * group)
@@ -588,8 +590,8 @@ class ModelRunner:
                 read = np.size(lengths) * lay.window_pages * self.block_size
                 valid = int(np.sum(np.minimum(lengths, lay.window - 1)))
             by = self.context_by_kind[name][kind]
-            for what, n in zip(by, (read, valid, reach, full, scored,
-                                    selected)):
+            for what, n in zip(total, (read, valid, reach, full, scored,
+                                       selected)):
                 total[what] += n
                 by[what] += n
 
@@ -725,19 +727,12 @@ class ModelRunner:
         Returns (emitted (W,), n_acc scalar, logits (W, Vp), pages, the
         forward's extras): the caller commits emitted[:n_acc + 1]."""
         W = tokens.shape[1]
-        if self.use_paged_attention:
-            logits, k, v, *aux = self.adapter.verify_paged_fn(
-                params, tokens, start, self.layout, *_by_kind(k_pages),
-                *_by_kind(v_pages), *_by_kind(table), self.cfg,
-                interpret=self._interpret)
-        else:
-            chunk_mask = (jnp.arange(W)[None, :] <= n_draft)  # (1, W)
-            logits, k, v, *aux = self.adapter.chunk_fn(
-                params, tokens, start,
-                self._context(k_pages, v_pages,
-                              [t[None] for t in _by_kind(table)],
-                              start[None]),
-                chunk_mask, self.cfg)
+        chunk_mask = (jnp.arange(W)[None, :] <= n_draft)  # (1, W)
+        logits, k, v, *aux = self.adapter.chunk_fn(
+            params, tokens, start,
+            self._context(k_pages, v_pages,
+                          [t[None] for t in _by_kind(table)], start[None]),
+            chunk_mask, self.cfg)
         k_pages, v_pages = self._write(k_pages, v_pages, block_ids, offsets,
                                        k, v, lane=0)
         lg = logits[0]  # (W, Vp)
@@ -755,30 +750,23 @@ class ModelRunner:
                      step):
         """tokens/slots/positions/temps (Sb,); tables (Sb,
         max_blocks_per_seq). Run the model's decode step, each layer
-        reading its lanes' context through the tables as far as the
-        longest lane of each group of `lanes_per_group` rows reaches (the
-        caller orders the rows by position), scatter the new K/V at each
-        lane's position, sample. With paged attention the gather
-        disappears: the kernel indexes pages in place through the block
-        table. A lane whose token is -1 feeds the id an earlier
+        reading its lanes' context through the tables: with the kernel to
+        each lane's own length where `reads_by_kernel` allows, else as far
+        as the longest lane of each group of `lanes_per_group` rows
+        reaches (the caller orders the rows by position); scatter the new
+        K/V at each lane's position, sample. A lane whose token is -1
+        feeds the id an earlier
         program left at its slot. Recurrent state moves one step in the
         slots of the step's lanes; a padded lane (slot -1) and a slot no
         lane owns keep theirs."""
         Bs = self.block_size
         tokens = jnp.where(tokens >= 0, tokens,
                            slot_tokens[jnp.maximum(slots, 0)])
-        if self.use_paged_attention:
-            logits, k_new, v_new, *aux = self.adapter.decode_paged_fn(
-                params, tokens, positions, self.layout, *_by_kind(k_pages),
-                *_by_kind(v_pages), *_by_kind(tables), self.cfg,
-                interpret=self._interpret)
-        else:
-            (logits, k_new, v_new, *aux), state = self._forward(
-                self.adapter.decode_fn, state, slots, params, tokens,
-                positions,
-                self._context(k_pages, v_pages, _by_kind(tables), positions,
-                              self.lanes_per_group(tokens.shape[0])),
-                self.cfg)
+        (logits, k_new, v_new, *aux), state = self._forward(
+            self.adapter.decode_fn, state, slots, params, tokens, positions,
+            self._context(k_pages, v_pages, _by_kind(tables), positions,
+                          self.lanes_per_group(tokens.shape[0])),
+            self.cfg)
         block_ids = tuple(jnp.take_along_axis(
             t, (positions // Bs)[:, None], axis=1)[:, 0]
             for t in _by_kind(tables))
@@ -948,7 +936,7 @@ class ModelRunner:
             temp = np.asarray([temperature], np.float32)
             topk = np.asarray([top_k], np.int32)
             topp = np.asarray([top_p], np.float32)
-            self._note_context("prefill", [start])
+            self._note_context("prefill", [start], rows=Tb)
             self._step_counter += 1
         with self.phases.phase("dispatch"):
             before = tracing.jit_cache_size(self._chunk_jit)
@@ -1008,8 +996,7 @@ class ModelRunner:
                 temps[i] = it.temperature
                 topks[i] = it.top_k
                 topps[i] = it.top_p
-            if not self.use_paged_attention:
-                self._note_context("decode", poss, self.lanes_per_group(Sb))
+            self._note_context("decode", poss, self.lanes_per_group(Sb))
             for written in self.rows_written.values():
                 written["rowwise"] += S
             self._step_counter += 1
@@ -1070,8 +1057,7 @@ class ModelRunner:
             temps = np.full((W,), temperature, np.float32)
             topks = np.full((W,), top_k, np.int32)
             topps = np.full((W,), top_p, np.float32)
-            if not self.use_paged_attention:
-                self._note_context("verify", [pos])
+            self._note_context("verify", [pos], rows=W)
             for written in self.rows_written.values():
                 written["rowwise"] += n_draft + 1
             self._step_counter += 1

@@ -161,10 +161,6 @@ CATALOG: list[dict] = [
     {"name": "serve_llm_verify_step_ms", "type": "histogram",
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "speculative verify step latency (K+1-wide program)"},
-    {"name": "serve_llm_paged_attn_enabled", "type": "gauge",
-     "where": "ray_tpu/serve/llm/engine.py",
-     "what": "1 when decode/verify run the pallas paged-attention "
-             "kernel, 0 on the dense gather fallback"},
     {"name": "serve_llm_d2h_bytes_total", "type": "counter",
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "bytes of device results (tokens, logits) the engine's "

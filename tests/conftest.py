@@ -55,3 +55,25 @@ def context_tile_pages(monkeypatch):
         monkeypatch.setattr(KVLayout, "tile_pages",
                             property(lambda self: pages))
     return set_pages
+
+
+@pytest.fixture
+def read_by_kernel(monkeypatch):
+    """``set(on)``: for the rest of the test (or until set again) the
+    serve programs built from here on pick their context read as on a TPU
+    (`on` true: a decode's and a verify's read of a full kind with K and V
+    alike goes to the Pallas kernel, interpreted here; whatever the
+    interpreter has no use for, whole tiles of the chip's memory, is not
+    asked) or as the CPU does (false: the loops). It patches the one
+    predicate that picks the path, `context_attention.reads_by_kernel`."""
+    from ray_tpu.ops import context_attention as ca
+
+    def on_tpu(layout, rows, sink=False):
+        return (rows <= ca.KERNEL_ROWS and layout.window is None
+                and layout.select is None and not sink
+                and layout.v_row == layout.row)
+
+    def set_path(on: bool) -> None:
+        monkeypatch.setattr(ca, "reads_by_kernel",
+                            on_tpu if on else (lambda *a, **k: False))
+    return set_path

@@ -4,7 +4,9 @@ to pass on the CPU, and the compile cache lands where the rule says.
 
 None of this shows that anything runs on a chip — `chip_smoke.py` through
 the chip tool does. Cross-lowering is the check that caught a paged kernel
-whose blocks Mosaic refuses (it had only ever run in interpret mode)."""
+whose blocks Mosaic refuses (it had only ever run in interpret mode);
+tests/test_kv_pool_layout.py compiles the kernel that reads the cached
+context now, inside the serve programs, for a described v5e."""
 
 import json
 import os
@@ -60,22 +62,25 @@ def test_paged_attention_lowers_for_tpu(shape, width):
     S, L, pages, bs, max_blocks = 8, 4, 256, 16, 64
     layout = KVLayout(L, pages, bs, HK, D)
     bf16 = jnp.bfloat16
-    q = jax.ShapeDtypeStruct((S, width, H, D), bf16)
+    q = jax.ShapeDtypeStruct((S, width, HK, H // HK, D), bf16)
     own = jax.ShapeDtypeStruct((S, width, HK, D), bf16)
+    own_valid = jax.ShapeDtypeStruct((S, width, width), jnp.bool_)
     pool = jax.ShapeDtypeStruct(layout.shape, bf16)
     tables = jax.ShapeDtypeStruct((S, max_blocks), jnp.int32)
     ctx = jax.ShapeDtypeStruct((S,), jnp.int32)
     layer = jax.ShapeDtypeStruct((), jnp.int32)
     _lowers_for_tpu(
-        lambda q, ok, ov, kp, vp, t, c, l: paged_attention(
-            q, ok, ov, kp, vp, t, c, layout=layout, layer=l),
-        q, own, own, pool, pool, tables, ctx, layer)
+        lambda q, ok, ov, seen, kp, vp, t, c, l: paged_attention(
+            q, ok, ov, seen, kp, vp, t, c, layout=layout, layer=l,
+            dtype=bf16),
+        q, own, own, own_valid, pool, pool, tables, ctx, layer)
 
 
 def test_paged_attention_layer_of_whole_pool_matches_reference():
     """The models hand the kernel the whole pool, as its KVLayout shapes
     it, and a traced layer index (slicing the pool per layer, or
     reshaping it, would copy it)."""
+    from ray_tpu.ops.context_attention import causal_rows
     from ray_tpu.ops.paged_attention import (
         paged_attention,
         paged_attention_reference,
@@ -90,17 +95,20 @@ def test_paged_attention_layer_of_whole_pool_matches_reference():
     tables = rng.permutation(np.arange(1, pages))[:S * maxB] \
         .reshape(S, maxB).astype(np.int32)
     ctx = np.asarray([9, maxB * bs], np.int32)
-    q = rng.normal(size=(S, W, H, D)).astype(np.float32)
+    q = rng.normal(size=(S, W, HK, H // HK, D)).astype(np.float32)
     ok = rng.normal(size=(S, W, HK, D)).astype(np.float32)
     ov = rng.normal(size=(S, W, HK, D)).astype(np.float32)
+    operands = (q, ok, ov, causal_rows(jnp.ones((S, W), bool)),
+                jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+                jnp.asarray(ctx))
     for layer in range(L):
         out = jax.jit(lambda l: paged_attention(
-            q, ok, ov, kp, vp, tables, ctx, layout=layout, layer=l,
+            *operands, layout=layout, layer=l, dtype=jnp.float32,
             interpret=True))(jnp.int32(layer))
         # the oracle on the same pool, and on the layer's rows spelled
         # out with numpy: (pages, bs, HK * D) -> heads of D lanes
-        ref = paged_attention_reference(q, ok, ov, kp, vp, tables, ctx,
-                                        layout=layout, layer=layer)
+        ref = paged_attention_reference(*operands, layout=layout,
+                                        layer=layer, dtype=jnp.float32)
         k_ctx = kp[layer][tables].reshape(S, maxB * bs, HK, D)
         np.testing.assert_array_equal(
             np.asarray(layout.read(kp, layer, tables)), k_ctx)

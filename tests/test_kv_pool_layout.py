@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.ops import context_attention
 from ray_tpu.serve.llm.cache import (
     KVKind,
     KVLayout,
@@ -348,10 +349,10 @@ def test_runner_decodes_the_same_on_a_tensor_mesh(cpu_mesh8, model):
     got, got_logits, r = run(cpu_mesh8)
     assert got == want
     np.testing.assert_allclose(got_logits, want_logits, atol=2e-4)
-    ways = r.layout.shard_ways(2)
+    ways = r.layouts[0].shard_ways(2)
     assert ways == 2
     assert r.k_pages[0].sharding.shard_shape(r.k_pages[0].shape)[-1] \
-        == r.layout.row // ways
+        == r.layouts[0].row // ways
 
 
 # ----------------------------------------------- compiled for the v5e
@@ -436,7 +437,6 @@ def served_runner(one_chip, request):
                          num_blocks=[2] * len(kinds),
                          max_model_len=max_len, max_batch_size=lanes,
                          prefill_chunk_size=256, num_draft_tokens=4)
-    runner._interpret = False  # the kernel as the chip compiles it
     # (K pools, V pools) at their real sizes, one of each a kind of KV
     # layer; bare where the family has one kind
     real = [dataclasses.replace(lay, num_blocks=n) for lay, n in zip(
@@ -484,6 +484,13 @@ PROGRAMS = {
 }
 
 
+# the running softmax that a tile loop of `attend_cached` carries (as
+# benchmark/attn_ops.py finds it): (t, m, l, acc, ...) for G lanes of T rows
+_SOFTMAX_CARRY = re.compile(
+    r"\(s32\[\], f32\[(\d+),(\d+),(\d+),(\d+)\], f32\[\1,\2,\3,\4\], "
+    r"f32\[\1,\4,\2,\3,(\d+)\]")
+
+
 @pytest.mark.parametrize("program,paged", [
     ("prefill", False), ("chunk-256", False), ("verify-5", False),
     ("decode", False), ("decode", True), ("verify-5", True)])
@@ -495,43 +502,79 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
     and the layer norms in float32, OLMoE's (16 KV heads of 128, 8 layers,
     1,088 pages) and the nemotron_h cut's (2 layers with K and V, 8 with
     0.55 GB of state for 32 lanes) are created in the compute dtype. With
-    the cast gone gpt2-large's temporaries are activations only (the paged
-    programs are gpt2-large's alone)."""
+    the cast gone gpt2-large's temporaries are activations only.
+
+    `paged`: decode and verify as the chip compiles them, a full kind
+    whose K and V are alike read by the Pallas kernel (gpt2-large, OLMoE,
+    the nemotron_h cut: one `tpu_custom_call` in the layer scan, no loop
+    that carries a running softmax); not `paged`: the same programs on the
+    tile loops, which the mimo_v2 cut's full kind (K 192, V 128) and every
+    chunk keep. The pool is copied on neither path."""
     # kernels are chosen by `jax.default_backend()`: take the chip's side
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model, runner, params, (pool, state), cast = served_runner
-    if paged and model != "gpt2-large":
-        pytest.skip("no cell serves this model through the paged kernel")
+    by_kernel = [context_attention.reads_by_kernel(
+        lay, 5 if program == "verify-5" else 1) for lay in runner.layouts]
+    if paged and not any(by_kernel):
+        pytest.skip("no kind of this model's KV layers is the kernel's")
+    if not paged and any(by_kernel):  # else: as the chip compiles it
+        monkeypatch.setattr(context_attention, "reads_by_kernel",
+                            lambda *a, **k: False)
     if program == "verify-5" and state:
         pytest.skip("the engine refuses speculation for a stateful family")
     if program == "verify-5" and len(runner.layouts) > 1:
         pytest.skip("the engine refuses speculation with a window kind")
-    if program == "verify-5" and runner.layout.select is not None:
+    if program == "verify-5" and runner.layouts[0].select is not None:
         pytest.skip("the engine refuses speculation with a latent kind")
     method, shapes, lanes = PROGRAMS[program]
     sizes = {"m": runner.max_blocks_per_seq, "p": MODELS[model][4],
              "g": MODELS[model][4] // 16, "s": runner.max_batch_size}
-    lanes = sizes.get(lanes, lanes)
 
-    def arg(shape, kind):
-        one = jax.ShapeDtypeStruct(
-            tuple(sizes.get(d, d) for d in shape),
-            jnp.float32 if kind == "f" else jnp.int32, sharding=one_chip)
-        if kind == "k" and len(runner.layouts) > 1:
-            return (one,) * len(runner.layouts)
-        return one
+    def compiled_for(sizes):
+        def arg(shape, kind):
+            one = jax.ShapeDtypeStruct(
+                tuple(sizes.get(d, d) for d in shape),
+                jnp.float32 if kind == "f" else jnp.int32, sharding=one_chip)
+            if kind == "k" and len(runner.layouts) > 1:
+                return (one,) * len(runner.layouts)
+            return one
 
-    args = [state if s == "state" else arg(*s) for s in shapes] + [
-        arg((lanes,), "f"), arg((lanes,), "i"), arg((lanes,), "f"),
-        arg((), "i")]
-    runner.use_paged_attention = paged
-    donate = (1, 2) if program == "verify-5" else (1, 2, 4)
-    compiled = jax.jit(getattr(runner, method), donate_argnums=donate) \
-        .lower(params, *pool, *args).compile()
+        n = sizes.get(lanes, lanes)
+        args = [state if s == "state" else arg(*s) for s in shapes] + [
+            arg((n,), "f"), arg((n,), "i"), arg((n,), "f"), arg((), "i")]
+        donate = (1, 2) if program == "verify-5" else (1, 2, 4)
+        return jax.jit(getattr(runner, method), donate_argnums=donate) \
+            .lower(params, *pool, *args).compile()
+
+    def reads_of(text):
+        """(the read's kernels, the running-softmax carries of its loops)
+        in a compiled program."""
+        return ([line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line
+                 and "ctx_read_paged" in line],
+                [tuple(map(int, m.groups()))
+                 for m in _SOFTMAX_CARRY.finditer(text)])
+
+    compiled = compiled_for(sizes)
     text = compiled.as_text()
     print(f"{model} {program}: temporaries "
           f"{compiled.memory_analysis().temp_size_in_bytes / 1e6:.1f} MB")
-    assert "tpu_custom_call" in text or not paged
+    kernels, carries = reads_of(text)
+    if paged:
+        # the read is one kernel in the scanned layer, nothing of it loops
+        # (the nemotron_h cut unrolls its two layers with K and V)
+        assert len(kernels) in (1, runner.layouts[0].kv_layers), len(kernels)
+        assert not carries, carries
+        # gpt2-large's smaller decode buckets alike
+        for n in (1, 2, 4) if (model, program) == ("gpt2-large",
+                                                   "decode") else ():
+            kernels, carries = reads_of(
+                compiled_for({**sizes, "s": n}).as_text())
+            assert len(kernels) == 1 and not carries, (n, carries)
+    elif program != "prefill":
+        # a chunk's 256 rows, the mimo_v2 cut's full kind (K 192, V 128)
+        # and the glm_dsa cut's latent kind keep their loops on the chip
+        assert carries and not kernels
 
     def results(opcode):
         return [tuple(map(int, m.group(1).split(","))) for m in re.finditer(
@@ -612,7 +655,7 @@ def _full_width_contexts(text, runner, arguments):
     pool, a weight, the state) or a layer or an expert of one. A group of
     lanes reads at most its own lanes' context, an eighth of that."""
     whole = (runner.max_batch_size * runner.max_blocks_per_seq
-             * runner.block_size * runner.layout.row)
+             * runner.block_size * runner.layouts[0].row)
     known = {a.shape[i:] for a in arguments for i in range(a.ndim)}
     shapes = {tuple(map(int, m.group(1).split(",")))
               for m in re.finditer(r"\w+\[([\d,]+)\]", text)}

@@ -510,7 +510,7 @@ def _lowered(family, _cache={}):
     r = ModelRunner(adapter, cfg, params, block_size=4, num_blocks=16,
                     max_model_len=32, max_batch_size=4, prefill_chunk_size=8)
     S, i32, f32 = jax.ShapeDtypeStruct, jnp.int32, jnp.float32
-    pool = S(r.layout.shape, cfg.dtype)
+    pool = S(r.layouts[0].shape, cfg.dtype)
     ids = S((4,), i32)
     state = jax.tree.map(lambda a: S(a.shape, a.dtype), r.state)
     m = r.max_blocks_per_seq

@@ -67,22 +67,24 @@ def test_full_forward_matches_the_reference(params, tokens):
 @pytest.mark.parametrize("paged", [False, True, "page-tiles"],
                          ids=["dense", "paged", "dense-page-tiles"])
 def test_prefill_chunk_and_decode_match_the_reference(params, tokens, paged,
-                                                      context_tile_pages):
+                                                      context_tile_pages,
+                                                      read_by_kernel):
     """A 16-token prefill, a second chunk that crosses into a new page and
     ends mid-page, then three decode steps through the paged cache: each
     step's logits against the reference's one full pass. The dense
     programs read the context as these toy rows make it (one tile holds
     the table) and in tiles of one page (the chunk reads two, the decode
-    steps four of a table's eight)."""
+    steps four of a table's eight); `paged`: the decode steps read it
+    with the Pallas kernel, as on a TPU."""
     if paged == "page-tiles":
         context_tile_pages(1)
         paged = False
+    read_by_kernel(paged)
     want, _ = ref.forward(params, jnp.asarray(tokens), ARCH)
     want = np.asarray(want)
     r = ModelRunner(adapters()["llama"], CFG, params, block_size=8,
                     num_blocks=16, max_model_len=64, max_batch_size=2,
-                    prefill_chunk_size=16, use_paged_attention=paged)
-    assert r.use_paged_attention == paged
+                    prefill_chunk_size=16)
     table = [3, 7, 2, 9, 5]
     _, last = r.prefill(tokens[:16].tolist(), table, 0.0)
     assert _worst(last, want[15]) < TOL
@@ -92,6 +94,9 @@ def test_prefill_chunk_and_decode_match_the_reference(params, tokens, paged,
         _, logits = r.decode([DecodeItem(int(tokens[pos]), pos, table, 0.0)])
         assert _worst(logits[0], want[pos]) < TOL
     assert len(r.take_expert_pairs()) == 5  # one (L, E) array a program
+    by = r.context_by_kind["full"]
+    assert by["decode"]["kernel_steps"] == (3 if paged else 0)
+    assert by["prefill"]["kernel_steps"] == 0  # a chunk keeps its loop
 
 
 def test_no_pair_is_dropped_under_skew(params):
@@ -147,9 +152,10 @@ def test_pair_counts_are_the_references_routing(params, tokens):
             == CFG.n_experts_per_tok * len(tokens)).all()
 
 
-def test_engine_serves_olmoe_dense_and_paged_alike():
+def test_engine_serves_olmoe_dense_and_paged_alike(read_by_kernel):
     """`EngineConfig(model="llama", preset="olmoe_tiny")` end to end: the
-    greedy streams of the dense and the paged engine are equal, the
+    greedy streams of the engine whose decode steps read their context
+    with the loops and of the one that reads it with the kernel are equal, the
     engine accounts its routing by step kind, and a weight swap is taken
     up (other streams after it, the first ones again after swapping
     back)."""
@@ -157,18 +163,27 @@ def test_engine_serves_olmoe_dense_and_paged_alike():
     sp = SamplingParams(max_tokens=6, temperature=0.0)
 
     def build(paged):
+        read_by_kernel(paged)
         return LLMEngine(EngineConfig(
             model="llama", preset="olmoe_tiny", block_size=8, num_blocks=64,
             max_model_len=64, max_batch_size=4, prefill_chunk_size=16,
-            use_paged_attention=paged, seed=0))
+            seed=0))
 
     def streams(eng):
         return [eng.generate(p, sp, drive=True)["token_ids"]
                 for p in prompts]
 
-    dense, paged = build(False), build(True)
+    # a program takes its path when it is first traced: one engine's
+    # streams while its predicate stands
+    dense = build(False)
     first = streams(dense)
+    paged = build(True)
     assert streams(paged) == first
+    ran = paged.stats()["context_by_kind"]["full"]["decode"]["kernel_steps"]
+    assert ran == paged.stats()["steps"]["decode"] > 0
+    assert dense.stats()["context_by_kind"]["full"]["decode"][
+        "kernel_steps"] == 0
+    read_by_kernel(False)
     moe = dense.stats()["moe"]
     k, L, E = CFG.n_experts_per_tok, CFG.n_layer, CFG.n_experts
     for kind in ("prefill", "decode"):
