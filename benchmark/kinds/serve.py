@@ -23,7 +23,7 @@ import tempfile
 import threading
 import time
 
-from benchmark import stats, traffic_gen
+from benchmark import stall, stats, traffic_gen
 from benchmark.device import memory_peak, profiler_options
 from benchmark.model_api import load, sizes
 from ray_tpu.serve.llm.deployment import LLMServer
@@ -31,7 +31,15 @@ from ray_tpu.serve.llm.deployment import LLMServer
 APP = "bench-llm"
 CHECK_PROMPT_LENS = (24, 64, 130, 300)  # the last crosses the 256 chunk
 CHECK_MAX_TOKENS = 8
-TRACE_AFTER_S, TRACE_FOR_S = 5.0, 4.0
+# 2 s of profile: at 32 lanes and long contexts the profiler's events of
+# 4 s took `trace_stop` past the harness's patience (306.7 s in long-decode
+# at PR 36). The trace is the window's last TRACE_FOR_S seconds and ends with
+# it, so that `trace_stop`, which slows every step of the replica for as long
+# as it runs (a decode turn 9.7 ms where 7.5; my chip runs, PR 43), starts
+# once the "after" snapshot is taken and runs in the run's tail alone, outside
+# the window whose counters the readers take. It runs in a thread of its own
+TRACE_FOR_S = 2.0
+TRACE_STOP_TIMEOUT_S = 300.0
 POLL_S = 0.5
 DRAIN_TIMEOUT_S = 90.0
 
@@ -240,35 +248,52 @@ def latencies(records, worst_ms: float) -> tuple[list, list]:
     return ttft, gaps
 
 
-def _watch(t0: float, seconds: float, trace_dir: str, observed: dict):
-    """The traced run's side thread: engine stats and the metrics page at
-    the window's edges, `running` polled twice a second, and a profiler
-    trace of TRACE_FOR_S seconds inside the window."""
+def _watch(t0: float, seconds: float, trace_dir: str | None,
+           observed: dict):
+    """Every run's side thread: the engine's stats at the window's two
+    edges ("before", "after": taken when the window opens and when it ends,
+    whatever else is still going on, so that what a reader takes as the
+    window's delta holds nothing of the run's tail). In the traced run
+    (`trace_dir`) also the metrics page at both edges, the stats polled
+    twice a second, and a profiler trace of the window's last TRACE_FOR_S
+    seconds, started and stopped by a thread of its own: `trace_stop` waits
+    for "after", may take minutes, and neither the polls nor "after" wait
+    for it."""
     from ray_tpu.util import state
 
     def snapshot():
-        return {"stats": replica_call("engine_stats"),
-                "page": state.cluster_metrics()}
+        snap = {"stats": replica_call("engine_stats")}
+        if trace_dir:
+            snap["page"] = state.cluster_metrics()
+        return snap
 
+    def trace():
+        time.sleep(max(0.0, t0 + seconds - TRACE_FOR_S - time.monotonic()))
+        replica_call("trace_start", trace_dir)
+        time.sleep(TRACE_FOR_S)
+        after_taken.wait(timeout=30.0)  # the window's delta holds no stop
+        t_stop = time.monotonic()
+        replica_call("trace_stop", timeout=TRACE_STOP_TIMEOUT_S)
+        observed["trace_stop_s"] = time.monotonic() - t_stop
+
+    after_taken = threading.Event()
     time.sleep(max(0.0, t0 - time.monotonic()))
     observed["before"] = snapshot()
-    polls, tracing, traced = [], False, False
-    while time.monotonic() < t0 + seconds:
-        now = time.monotonic() - t0
-        if not tracing and not traced and now >= TRACE_AFTER_S:
-            replica_call("trace_start", trace_dir)
-            tracing = True
-            t_trace = time.monotonic()
-        elif tracing and time.monotonic() - t_trace >= TRACE_FOR_S:
-            replica_call("trace_stop", timeout=300)
-            tracing, traced = False, True
-        else:
-            polls.append(replica_call("engine_stats"))
+    tracer = threading.Thread(target=trace, daemon=True) if trace_dir \
+        else None
+    if tracer:
+        tracer.start()
+    polls = []
+    while tracer and time.monotonic() < t0 + seconds - POLL_S:
+        polls.append(replica_call("engine_stats"))
         time.sleep(POLL_S)
-    if tracing:
-        replica_call("trace_stop", timeout=300)
-    observed["polls"] = polls
+    time.sleep(max(0.0, t0 + seconds - time.monotonic()))
     observed["after"] = snapshot()
+    after_taken.set()
+    observed["after_late_s"] = time.monotonic() - (t0 + seconds)
+    if tracer:
+        observed["polls"] = polls
+        tracer.join()
 
 
 def check_outputs(load_gen: Load, config: dict, seed: int,
@@ -310,12 +335,11 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
     observed: dict = {}
     try:
         t0 = time.monotonic() + preroll + 0.05
-        watcher = None
-        if trace:
-            watcher = threading.Thread(
-                target=_watch, args=(t0, seconds, trace_dir, observed),
-                daemon=True)
-            watcher.start()
+        watcher = threading.Thread(
+            target=_watch, args=(t0, seconds, trace_dir, observed),
+            daemon=True)
+        watcher.start()
+        ticker = stall.Ticker(t0, t0 + seconds).start()
         if traffic["loop"] == "open":
             requests = traffic_gen.open_loop(traffic, seed, seconds, vocab)
             late = load_gen.open_loop(requests, t0)
@@ -328,14 +352,22 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
         time.sleep(max(0.0, t0 + seconds - time.monotonic()))
         backlog_end = load_gen.in_flight()
         stuck = load_gen.drain()
-        if watcher is not None:
-            watcher.join(timeout=600)
+        watcher.join(timeout=600)
+        ticker.join()
         stats_now = replica_call("engine_stats")
+        stalled = stall.report(
+            observed.get("before", {}).get("stats"),
+            observed.get("after", {}).get("stats"), stats_now, late, ticker,
+            observed.get("polls"), hist=trace)
         memory_peak = replica_call("device_memory")
         t_check = time.monotonic()
         ok_ref, worst = check_outputs(load_gen, config, seed, vocab)
-        print(f"[serve] output check took {time.monotonic() - t_check:.1f} s",
-              flush=True)
+        print(f"[serve] output check took {time.monotonic() - t_check:.1f} s"
+              + (f"; trace_stop took {observed['trace_stop_s']:.1f} s"
+                 if "trace_stop_s" in observed else "")
+              + f"; the window's last snapshot came "
+              f"{observed.get('after_late_s', float('nan')):.3f} s after "
+              "its end", flush=True)
         events = None
         if trace:
             from benchmark import trace_reduce
@@ -360,8 +392,10 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
         print(f"[serve] open loop: {len(measured)} requests, "
               f"ttft p50={stats.percentile(ttft, 50):.1f} "
               f"p85={ttft_p85:.1f} ms, {len(gaps)} gaps "
-              f"p50={stats.percentile(gaps, 50):.2f} "
-              f"p95={end_to_end['itl_p95_ms']:.2f} ms, generator at most "
+              f"p50={stats.percentile(gaps, 50):.3f} "
+              f"p95={end_to_end['itl_p95_ms']:.3f} "
+              f"p99={stats.percentile(gaps, 99):.3f} ms (engine p95 "
+              f"{stalled['engine_itl_p95_ms']}), generator at most "
               f"{late * 1e3:.2f} ms late, in flight at the end "
               f"{backlog_end}", flush=True)
     times, amounts = credited_events(load_gen.records, t0, t0 + seconds)
@@ -383,15 +417,27 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
               "kind": stats_now["device_kind"],
               "count": stats_now["device_count"],
               "memory_peak_bytes": memory_peak}
+    # every number `correct` compares, beside its limit (run.py prints them
+    # last on standard error and puts them last in the result's line)
+    compared = {
+        "logprob_gap_max_nats": {"value": worst if worst == worst else None,
+                                 "limit": config["logprob_tolerance"]},
+        "failed_requests": {"value": len(failed), "limit": 0},
+        "stuck_requests": {"value": stuck, "limit": 0},
+        "left_in_engine": {"value": stats_now["running"]
+                           + stats_now["waiting"], "limit": 0},
+        "chips": {"value": device["count"], "limit": cell["chips"]}}
     return {
         "correct": bool(ok_ref and not failed and not stuck and drained
                         and device["count"] == cell["chips"]),
+        "compared": compared,
         "attempted": len(measured),
         "failed": len(failed),
         "end_to_end": end_to_end,
         "device": device,
         "observed": {
-            **observed,  # before, after, polls: the traced run's watcher
+            **observed,  # before, after; polls: the traced run's watcher
+            "stall": stalled,
             "events": events, "ready_s": ready_s,
             "tokens_per_s": tokens_per_s, "backlog_end": backlog_end,
             "ttft_p85_ms": ttft_p85,

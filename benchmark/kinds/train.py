@@ -228,6 +228,12 @@ def run(cell: dict, config: dict, traffic: dict, *, seed: int,
             print(f"[train] CHECK FAILED: {name}", flush=True)
     return {
         "correct": all(checks.values()),
+        # every number `correct` compares, beside its limit
+        "compared": {
+            "first_loss_diff": {"value": diff, "limit": tol},
+            "compiles_in_window": {"value": final["compiles_in_window"],
+                                   "limit": 0},
+            "chips": {"value": n, "limit": chips}},
         "attempted": final["steps"],
         "failed": 0,
         "end_to_end": {

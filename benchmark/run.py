@@ -95,6 +95,12 @@ def result_line(bench: dict, cell: dict, result: dict, trace: bool) -> dict:
         print(f"[trace] devices={reduced['devices']} busy_s="
               f"{reduced['busy_s']:.4f} window_s={reduced['window_s']:.4f} "
               f"idle_share={reduced['idle_share']:.4f}", flush=True)
+    # what `correct` compared, each number beside its limit: last in the
+    # line, and the last lines on standard error
+    line["compared"] = result.get("compared", {})
+    for name, c in line["compared"].items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr, flush=True)
     return line
 
 

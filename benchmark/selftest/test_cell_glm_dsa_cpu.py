@@ -55,7 +55,6 @@ def test_serve_cell_of_the_latent_kind(cluster, monkeypatch):
 
     monkeypatch.setattr(serve_kind, "CHECK_PROMPT_LENS", (5, 20, 40, 70))
     monkeypatch.setattr(serve_kind, "CHECK_MAX_TOKENS", 4)
-    monkeypatch.setattr(serve_kind, "TRACE_AFTER_S", 0.5)
     monkeypatch.setattr(serve_kind, "TRACE_FOR_S", 1.0)
     traffic = {"kind": "serve", "loop": "closed", "base_seed": 3,
                "clients": 6, "preroll_s": 0.5, "cycle_requests": 8,

@@ -75,13 +75,15 @@ def test_train_cell(cluster, chips):
 
 
 @pytest.mark.parametrize("loop", ["open", "closed"])
-def test_serve_cell(cluster, loop, monkeypatch):
+def test_serve_cell(cluster, loop, monkeypatch, capsys):
+    import json
+
+    from benchmark import stall
     from benchmark.kinds import serve as serve_kind
     from ray_tpu import serve
 
     monkeypatch.setattr(serve_kind, "CHECK_PROMPT_LENS", (5, 12, 40))
     monkeypatch.setattr(serve_kind, "CHECK_MAX_TOKENS", 4)
-    monkeypatch.setattr(serve_kind, "TRACE_AFTER_S", 0.5)
     monkeypatch.setattr(serve_kind, "TRACE_FOR_S", 1.0)
     lens = {"prompt_len": {"dist": "uniform", "min": 8, "max": 48},
             "output_len": {"dist": "uniform", "min": 2, "max": 6}}
@@ -113,3 +115,21 @@ def test_serve_cell(cluster, loop, monkeypatch):
     assert readers.histogram_mean(obs, "serve_llm_step_ms",
                                   kind="decode") > 0
     assert readers.counter_delta(obs, "preemptions") is not None
+    # the window's "after" was taken when the window ended, and the trace
+    # was stopped beside it
+    assert obs["after_late_s"] < 1.0 and obs["trace_stop_s"] >= 0.0
+    # the run's one stall line, printed and parsed back, every field there
+    said = stall.parse(capsys.readouterr().out)
+    assert said is not None and set(stall.FIELDS) <= set(said)
+    assert said == json.loads(json.dumps(obs["stall"]))
+    assert said["gaps"]["pooled"]["n"] > 0
+    assert said["engine_itl_p95_ms"] > 0 and said["ticker_max_ms"] > 0
+    # every program was warmed up before the window
+    assert said["compiled"] == 0 and said["phase_s"]["dispatch"] > 0
+    assert all({"max_ms", "over_250ms_s", "window_top_ms"} <= set(t)
+               for t in said["turns"].values())
+    assert (said["generator_late_ms"] > 0) == (loop == "open")
+    # what `correct` compared, each beside its limit
+    assert r["compared"]["logprob_gap_max_nats"]["value"] \
+        <= r["compared"]["logprob_gap_max_nats"]["limit"] == 0.05
+    assert r["compared"]["failed_requests"] == {"value": 0, "limit": 0}

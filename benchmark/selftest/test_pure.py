@@ -298,20 +298,25 @@ def test_result_line_has_the_contracts_keys(bench):
         "end_to_end": {m["name"]: 1.5 for m in bench["end_to_end"]},
         "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
                    "memory_peak_bytes": 123},
+        "compared": {"logprob_gap_max_nats": {"value": 0.01, "limit": 0.05}},
         "observed": {"events": synthetic_trace(), "ready_s": 2.0,
                      "config": json.load(open(os.path.join(
                          ROOT, "benchmark/configs/gpt2-large.json")))},
     }
     line = runner.result_line(bench, cell, result, trace=False)
     assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+                         "device", "compared"}
+    # what `correct` compared comes last, each number beside its limit
+    assert list(line)[-1] == "compared"
+    assert line["compared"] == result["compared"]
     assert set(line["device"]) == {"platform", "kind", "count",
                                    "memory_peak_bytes"}
     assert "setup_s" in line["metrics"]
     assert all(set(v) == {"value", "unit"} for v in line["metrics"].values())
     traced = runner.result_line(bench, cell, result, trace=True)
     assert set(traced) == {"correct", "attempted", "failed", "metrics",
-                           "device", "breakdown"}
+                           "device", "breakdown", "compared"}
+    assert list(traced)[-1] == "compared"
     assert {"busy_s", "window_s"} <= set(traced["device"])
     assert set(traced["breakdown"]) == {"device_ops", "idle_gaps"}
     # readers with nothing to read are left out, not reported as zero
