@@ -3,7 +3,9 @@
 One implementation serves the llama-family MoE block (OLMoE: 64 SwiGLU
 experts, 8 a token, softmax), the nemotron_h block (128 relu2 experts of
 which a chip holds a share, 6 a token, sigmoid with a selection bias, one
-shared expert) and the stand-alone `moe_layer` (GELU experts):
+shared expert), the mimo_v2, glm_dsa and lfm2 blocks (SwiGLU experts of
+which a chip holds a share, sigmoid with a selection bias) and the
+stand-alone `moe_layer` (GELU experts):
 
 - **route** (`moe.route`): router logits and scores (softmax over all
   experts, or a sigmoid each) in float32, `lax.top_k` (of the scores plus
@@ -57,13 +59,14 @@ from jax.sharding import PartitionSpec as P
 
 def route(x: jax.Array, router: jax.Array, k: int, norm_topk: bool, *,
           score: str = "softmax", select_bias: jax.Array | None = None,
-          scale: float = 1.0):
+          scale: float = 1.0, norm_eps: float = 0.0):
     """x (N, Dm), router (Dm, E) -> (weights (N, k) f32, experts (N, k)
     i32, pairs per expert (E,) i32, scores (N, E) f32). `score` is
     "softmax" (over ALL experts) or "sigmoid" (each expert for itself);
     the k largest of score + `select_bias` (E,) are chosen, and their
     weights are the scores WITHOUT the bias, summing to one only when
-    `norm_topk`, times `scale`."""
+    `norm_topk` (divided by their sum plus `norm_eps`, which a family
+    whose published router has one gives: lfm2's 1e-6), times `scale`."""
     with jax.named_scope("moe.route"):
         logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
         if score == "softmax":
@@ -79,7 +82,10 @@ def route(x: jax.Array, router: jax.Array, k: int, norm_topk: bool, *,
                 probs + select_bias.astype(jnp.float32), k)
             weights = jnp.take_along_axis(probs, experts, axis=-1)
         if norm_topk:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            total = jnp.sum(weights, axis=-1, keepdims=True)
+            if norm_eps:
+                total = total + norm_eps
+            weights = weights / total
         if scale != 1.0:
             weights = weights * scale
         counts = jnp.zeros((router.shape[-1],), jnp.int32).at[
@@ -99,6 +105,7 @@ def routed_experts(
     scale: float = 1.0,
     held: tuple[int, int] | None = None,
     shared: Callable | None = None,
+    norm_eps: float = 0.0,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """x (N, Dm) -> (out (N, Dm), pairs per expert (E,) i32, router scores
     (N, E) f32). ``expert_fn(rows, mm)`` is one expert's feed-forward
@@ -111,11 +118,11 @@ def routed_experts(
     E (the router's load is the model's, whatever is held), the result is
     the part the held experts give, and what the absent ones would have
     added is left out. ``shared(x) -> (N, Dm)`` is an expert every row
-    goes through, added to the routed sum."""
+    goes through, added to the routed sum. `norm_eps` is `route`'s."""
     N = x.shape[0]
     weights, experts, counts, probs = route(
         x, router, k, norm_topk, score=score, select_bias=select_bias,
-        scale=scale)
+        scale=scale, norm_eps=norm_eps)
     with jax.named_scope("moe.dispatch"):
         per_expert = jnp.zeros((N, router.shape[-1]), weights.dtype).at[
             jnp.arange(N)[:, None], experts].set(weights)

@@ -456,19 +456,33 @@ class StateSlots:
     a recompute after preemption too, starts its slot from zero, because
     a prefix hit would hand over pages of K and V but no state: where
     prefix reuse was asked for (`prefix_declined`), each reset is also a
-    prefix match not attempted. Written by the engine's one stepping
-    thread."""
+    prefix match not attempted. Beside the resets (prefill programs that
+    started a slot fresh): the prefill programs that started from the
+    state an earlier chunk left in the slot (`carried`), and the decode
+    programs by their rows (the lanes' bucket) with the slots their lanes
+    owned, the others written back as read. Written by the engine's one
+    stepping thread."""
 
     def __init__(self, layout: StateLayout, prefix_declined: bool):
         self.layout = layout
         self.prefix_declined = prefix_declined
         self.resets = 0
+        self.carried = 0
+        self.decode_steps: dict[int, int] = {}  # rows of the program: steps
+        self.decode_lanes = 0  # slots owned, summed over the steps
+
+    def note_decode(self, rows: int, lanes: int) -> None:
+        self.decode_steps[rows] = self.decode_steps.get(rows, 0) + 1
+        self.decode_lanes += lanes
 
     def stats(self) -> dict:
         lay = self.layout
         return {"slots": lay.slots, "layers": lay.layers,
                 "bytes": lay.nbytes, "slot_bytes": lay.slot_bytes,
-                "resets": self.resets,
+                "resets": self.resets, "carried": self.carried,
+                "decode_steps": {str(rows): n for rows, n in
+                                 sorted(self.decode_steps.items())},
+                "decode_lanes": self.decode_lanes,
                 "prefix_declined": self.prefix_declined}
 
 

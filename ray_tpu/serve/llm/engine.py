@@ -23,6 +23,7 @@ import itertools
 import queue
 import threading
 import time
+import weakref
 from typing import Any, Sequence as Seq
 
 import numpy as np
@@ -74,6 +75,13 @@ GAP_CAUSES = ("after_preempt", "after_prefill", "after_drain", "decode")
 # `serve_llm_itl_ms`'s boundaries: the same gaps for an operator
 ITL_BOUNDS_MS = (0.5, 1, 2, 3, 4, 5, 6, 8, 10, 15, 25, 50, 100, 250, 1000)
 SLOW_TURN_MS = 250.0  # ten times the longest sound turn of any cell
+# the engines alive in this process (a replica has one), for code that
+# runs beside an engine and was handed its weights alone
+_ENGINES: "weakref.WeakSet[LLMEngine]" = weakref.WeakSet()
+
+
+def engines() -> "list[LLMEngine]":
+    return list(_ENGINES)
 
 
 def gap_bucket(ms: float) -> int:
@@ -263,6 +271,7 @@ class LLMEngine:
 
         # before the first compile, so that start-up's share is counted
         tracing.watch_compiles()
+        _ENGINES.add(self)
         self.config = config
         reg = adapters()
         if config.model not in reg:
@@ -654,6 +663,16 @@ class LLMEngine:
             "Lane slots started from zero by a program that ran a "
             "sequence's first rows (admissions, recomputes included)",
             tag_keys=tags)
+        self._m_state_carried = Counter(
+            "serve_llm_state_carried_total",
+            "Prefill programs that started from the recurrent state an "
+            "earlier chunk of their sequence left in the lane's slot",
+            tag_keys=tags)
+        self._m_state_lanes = Counter(
+            "serve_llm_state_decode_lanes_total",
+            "Lane slots whose recurrent state a decode program moved one "
+            "step on (the slots its lanes owned), summed over the steps",
+            tag_keys=tags)
         self._m_state_bytes.set(
             self.state_slots.layout.nbytes if self.state_slots else 0,
             tags=self._m_tags)
@@ -1028,10 +1047,14 @@ class LLMEngine:
         seq = work.seq
         sp = seq.sampling
         tokens = seq.refill_tokens[work.start:work.end]
-        if work.start == 0 and self.state_slots is not None:
-            # the program zeroes the slot; no prefix was looked up
-            self.state_slots.resets += 1
-            self._m_state_resets.inc(tags=self._m_tags)
+        if self.state_slots is not None:
+            if work.start == 0:
+                # the program zeroes the slot; no prefix was looked up
+                self.state_slots.resets += 1
+                self._m_state_resets.inc(tags=self._m_tags)
+            else:  # it starts from what the lane's last chunk left
+                self.state_slots.carried += 1
+                self._m_state_carried.inc(tags=self._m_tags)
         if work.start == 0 and work.is_last:
             # whole prompt in one go and nothing cached: the
             # monolithic program skips the context gather
@@ -1129,6 +1152,10 @@ class LLMEngine:
                                 s.sampling.temperature, s.sampling.top_k,
                                 s.sampling.top_p, s.slot)
                      for s, n, t in zip(flight.plain, unread, tables)]
+        if self.state_slots is not None:
+            self.state_slots.note_decode(
+                self.runner.decode_bucket(len(items)), len(items))
+            self._m_state_lanes.inc(len(items), tags=self._m_tags)
         flight.handle = self.runner.launch_decode(items)
 
     def _propose_for(self, seq: Sequence) -> list[int]:
@@ -1572,9 +1599,11 @@ class LLMEngine:
             "weights": dict(self.runner.weights),
             "spec_proposed": self._spec_proposed_total,
             "spec_accepted": self._spec_accepted_total,
-            # recurrent state: slots, bytes, slots started from zero,
-            # admissions that looked up no prefix; {} for a family with
-            # none
+            # recurrent state: slots, bytes, prefill programs that
+            # started a slot from zero (admissions that looked up no
+            # prefix) and that started from a carried state, decode
+            # steps by rows with the slots they owned; {} for a family
+            # with none
             "state": (self.state_slots.stats() if self.state_slots
                       else {}),
             # routed experts by step kind: pairs computed, the same per

@@ -145,7 +145,14 @@ class ModelAdapter:
 
 def adapters() -> dict[str, ModelAdapter]:
     """Model registry (lazy imports keep `import ray_tpu.serve` light)."""
-    from ray_tpu.models import glm_dsa, gpt2, llama, mimo_v2, nemotron_h
+    from ray_tpu.models import (
+        glm_dsa,
+        gpt2,
+        lfm2,
+        llama,
+        mimo_v2,
+        nemotron_h,
+    )
 
     def one_kind(layers, heads):
         """Every layer with keys and values alike, K as wide as V."""
@@ -236,6 +243,23 @@ def adapters() -> dict[str, ModelAdapter]:
             chunk_fn=glm_dsa.glm_dsa_prefill_chunk_kv,
             rules_fn=glm_dsa.glm_dsa_partition_rules,
             kv_kinds=lambda cfg: tuple(KVKind(*k) for k in cfg.kv_kinds()),
+            held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
+        ),
+        "lfm2": ModelAdapter(
+            name="lfm2",
+            config_cls=lfm2.Lfm2Config,
+            presets={
+                "tiny": lfm2.Lfm2Config.tiny,
+                "lfm2_8b_a1b_ep4": lfm2.Lfm2Config.lfm2_8b_a1b_ep4,
+            },
+            init_fn=lfm2.init_lfm2,
+            prefill_fn=lfm2.lfm2_prefill_kv,
+            decode_fn=lfm2.lfm2_decode_kv,
+            chunk_fn=lfm2.lfm2_prefill_chunk_kv,
+            rules_fn=lfm2.lfm2_partition_rules,
+            kv_kinds=one_kind(lambda cfg: cfg.n_kv_layers,
+                              lambda cfg: cfg.num_key_value_heads),
+            state_fn=lambda cfg: (cfg.n_conv_layers, cfg.state_parts()),
             held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
         ),
     }

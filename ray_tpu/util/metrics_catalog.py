@@ -218,6 +218,14 @@ CATALOG: list[dict] = [
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "lane slots started from zero by a program that ran a "
              "sequence's first rows (admissions, recomputes included)"},
+    {"name": "serve_llm_state_carried_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "prefill programs that started from the recurrent state an "
+             "earlier chunk of their sequence left in the lane's slot"},
+    {"name": "serve_llm_state_decode_lanes_total", "type": "counter",
+     "where": "ray_tpu/serve/llm/engine.py",
+     "what": "lane slots whose recurrent state a decode program moved "
+             "one step on (the slots its lanes owned), summed over steps"},
     # KV pages by kind of layer (`kind`: "full" for most families, "full"
     # and "window" for one that mixes full and window attention)
     {"name": "serve_llm_kv_pages_used", "type": "gauge",

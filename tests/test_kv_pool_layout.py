@@ -389,7 +389,10 @@ MODELS = {"gpt2-large": ("gpt2", "large", 8, 512, 512, 1024),
           "mimo-v2.5": ("mimo_v2", "v2_5_l7_ep16", 32, 12288, 256, 8704),
           # a latent kind: a pool of latent rows (576 lanes) and a pool of
           # indexer keys (128 lanes) under one table, 24,576 pages
-          "glm-5": ("glm_dsa", "glm_5_l5_ep32", 32, 24576, 256, 16768)}
+          "glm-5": ("glm_dsa", "glm_5_l5_ep32", 32, 24576, 256, 16768),
+          # 64 lanes: 6 layers with K and V beside 18 with two rows of
+          # conv state a lane, 20,480 pages
+          "lfm2-8b-a1b": ("lfm2", "lfm2_8b_a1b_ep4", 64, 20480, 256, 8576)}
 # A program's temporaries, bytes. With no weight cast in any program they
 # are activations: the AOT compile reads 1.1-105.8 MB for gpt2-large (the
 # most in prefill-512; 1.55-1.64 GB while the float32 stacks were cast
