@@ -28,6 +28,7 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.parallel.sharding import PartitionRules, constrain
 from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.context_attention import attend_cached, causal_rows
+from ray_tpu.ops.cross_entropy import cross_entropy
 
 Params = Any
 
@@ -268,16 +269,9 @@ def gpt2_forward(params: Params, tokens: jax.Array, cfg: GPT2Config) -> jax.Arra
 def gpt2_loss(params: Params, batch: dict, cfg: GPT2Config) -> jax.Array:
     """Next-token cross entropy; positions past vocab_size are masked."""
     logits = gpt2_forward(params, batch["tokens"], cfg)
-    targets = batch["targets"]
-    V = cfg.padded_vocab
-    mask = jnp.arange(V) < cfg.vocab_size
-    logits = jnp.where(mask, logits, -1e9)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    weights = batch.get("weights")
-    if weights is None:
-        return -jnp.mean(ll)
-    return -jnp.sum(ll * weights) / jnp.maximum(jnp.sum(weights), 1.0)
+    return cross_entropy(logits, batch["targets"],
+                         vocab_size=cfg.vocab_size,
+                         weights=batch.get("weights"))
 
 
 # --------------------------------------------------------------------------

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from ray_tpu.models.moe import routed_experts
 from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.context_attention import attend_cached, causal_rows
+from ray_tpu.ops.cross_entropy import cross_entropy
 from ray_tpu.parallel.sharding import PartitionRules, constrain
 
 Params = Any
@@ -476,10 +477,5 @@ def llama_decode_kv(
 
 def llama_loss(params: Params, batch: dict, cfg: LlamaConfig) -> jax.Array:
     logits = llama_forward(params, batch["tokens"], cfg)
-    V = cfg.padded_vocab
-    mask = jnp.arange(V) < cfg.vocab_size
-    logits = jnp.where(mask, logits, -1e9)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, batch["targets"][..., None],
-                             axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    return cross_entropy(logits, batch["targets"],
+                         vocab_size=cfg.vocab_size)

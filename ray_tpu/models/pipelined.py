@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu.ops.cross_entropy import cross_entropy
 from ray_tpu.parallel.pipeline import pipeline_apply_interleaved
 from ray_tpu.parallel.ring_attention import ring_attention
 
@@ -115,9 +116,7 @@ def pipelined_loss(params, batch, cfg: PipelinedConfig, mesh,
                        out_specs=P(None, "fsdp", None), check_vma=False)
     h = sm(blocks, h)
     logits = _rms(h * params["ln_f"]) @ params["head"]
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-    return jnp.mean(nll)
+    return cross_entropy(logits, targets)
 
 
 # ---------------------------------------------------------------------------
@@ -234,9 +233,7 @@ def stage_apply(cfg: PipelinedConfig, stage_params: dict, stage_idx: int,
     logits = _rms(h * stage_params["ln_f"]) @ stage_params["head"]
     if targets is None:
         return logits
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-    return jnp.mean(nll)
+    return cross_entropy(logits, targets)
 
 
 def pipelined_shardings(params, cfg: PipelinedConfig, mesh):

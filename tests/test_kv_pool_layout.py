@@ -359,9 +359,10 @@ def test_runner_decodes_the_same_on_a_tensor_mesh(cpu_mesh8, model):
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A described (not attached) v5e chip. Only libtpu's absence skips:
-    a topology that cannot be described where libtpu is must fail."""
+def v5e():
+    """The four described (not attached) chips of a v5e 2x2 host. Only
+    libtpu's absence skips: a topology that cannot be described where
+    libtpu is must fail."""
     pytest.importorskip("libtpu")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
@@ -373,9 +374,14 @@ def one_chip():
     # persistent cache but not read back here: keep these out of it
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    yield list(topo.devices)
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return jax.sharding.SingleDeviceSharding(v5e[0])
 
 
 # model -> (family, preset, lanes, pages, monolithic prefill bucket,
@@ -724,3 +730,80 @@ def test_flash_gradient_is_dense_on_the_v5e(one_chip, arguments):
     if arguments == "model":
         assert not [c for c in copies if math.prod(c) >= B * T * H * D]
     assert temporaries < FLASH_TEMP_BOUND
+
+
+# --------------------------------- the train step's loss head, for the v5e
+
+
+def _materialised(text):
+    """(name, result) of every instruction of a compiled module that
+    writes its result to memory: those outside the fused computations."""
+    fused, out = False, []
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            fused = line.lstrip("%").startswith("fused_computation")
+        m = re.match(r"\s+(?:ROOT )?(\S+) = (\(.*?\)|\S+) [\w-]+\(", line)
+        if m and not fused:
+            out.append(m.groups())
+    return out
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_train_step_writes_no_float32_logits(v5e, chips):
+    """Compiled for the v5e, gpt2-small's train step (the train cells'
+    program at 4 x 256 a chip) writes the logits out in bf16, as the
+    head's product returns them, and nothing else of their size in any
+    dtype: the loss reads them by reductions (`ops/cross_entropy.py`),
+    which fuse with their producer. While the
+    target's log-probability was gathered from `log_softmax`, the step
+    wrote `logits - max` out as `f32[B,T,50304]` for the gather to index,
+    15 ms of a 282 ms step at 32 x 1024 (PERF.md section 6, PR 45)."""
+    import optax
+
+    from ray_tpu.models.gpt2 import (
+        GPT2Config,
+        gpt2_loss,
+        gpt2_partition_rules,
+        init_gpt2,
+    )
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+    from ray_tpu.train.spmd import (
+        TrainState,
+        batch_shardings,
+        make_train_step,
+        state_shardings,
+    )
+
+    B, T = 4, 256
+    cfg = GPT2Config.small()
+    tx = optax.adamw(3e-4, weight_decay=0.1)
+    mesh = build_mesh(MeshSpec(data=-1), devices=v5e[:chips])
+
+    def described(shapes, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            shapes, shardings)
+
+    state = jax.eval_shape(
+        lambda key: TrainState.create(init_gpt2(key, cfg), tx),
+        jax.random.PRNGKey(0))
+    batch = {name: jax.ShapeDtypeStruct((B * chips, T), jnp.int32)
+             for name in ("tokens", "targets")}
+    step = make_train_step(lambda p, b: gpt2_loss(p, b, cfg), tx)
+    with jax.set_mesh(mesh):
+        compiled = step.jitted.lower(
+            described(state, state_shardings(gpt2_partition_rules(), state,
+                                             mesh)),
+            described(batch, batch_shardings(mesh, batch))).compile()
+
+    results = _materialised(compiled.as_text())
+    assert len(results) > 100
+    # a chip's share of the logits, under any leading shape
+    elements = B * T * cfg.padded_vocab
+    wide = {}
+    for name, result in results:
+        for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]", result):
+            if math.prod(map(int, dims.split(","))) >= elements:
+                wide.setdefault(dtype, []).append(name)
+    print(f"train step on {chips} chip(s): {wide}")
+    assert set(wide) == {"bf16"}, wide
