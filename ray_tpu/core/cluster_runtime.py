@@ -329,6 +329,8 @@ class ClusterRuntime:
                              oneway=True)
         self.server.register("resolve", self._h_resolve)
         self.server.register("stream_item", self._h_stream_item, oneway=True)
+        self.server.register("stream_items", self._h_stream_items,
+                             oneway=True)
         self.server.register("stream_end", self._h_stream_end, oneway=True)
         self.server.register("stream_next", self._h_stream_next)
         self.server.register("stream_state", self._h_stream_state)
@@ -1462,11 +1464,32 @@ class ClusterRuntime:
                 self._owned.pop(stream.sentinel, None)
 
     def _h_stream_item(self, msg, frames):
-        task_id, index, oid = msg["task_id"], msg["index"], msg["oid"]
-        loc = msg.get("location")
+        self._register_stream_items(
+            [(msg["task_id"], msg["index"], msg["oid"], msg.get("location"),
+              frames[0])], msg.get("producer"))
+
+    def _h_stream_items(self, msg, frames):
+        """One step's items of a pushed producer (`core/stream_push.py`):
+        ids in the header, the inline payloads end to end in one frame."""
+        blob = memoryview(frames[0])
+        entries, off = [], 0
+        for task_id, index, oid, size, loc in msg["items"]:
+            entries.append((task_id, index, oid, loc, blob[off:off + size]))
+            off += size
+        self._register_stream_items(entries, msg.get("producer"))
+
+    def _register_stream_items(self, entries, producer):
+        """Own each (task_id, index, oid, location, inline payload) and
+        enter it in its stream's order book: one take of `_lock` for them
+        all, one notify a stream."""
+        streams: dict[bytes, tuple] = {}  # task_id -> (stream, its items)
+        orphans = []
         with self._lock:
-            stream = self._streams.get(task_id)
-            if stream is not None:
+            for task_id, index, oid, loc, payload in entries:
+                stream = self._streams.get(task_id)
+                if stream is None:
+                    orphans.append((oid, loc))
+                    continue
                 st = self._owned.get(oid)
                 if st is None:
                     st = _Owned()
@@ -1475,7 +1498,7 @@ class ClusterRuntime:
                 # producer may live on a different node, and the item oid
                 # is deterministic in (task_id, index)
                 if loc is None:
-                    st.inline = bytes(frames[0])
+                    st.inline = bytes(payload)
                     st.size = len(st.inline)
                     st.location = None
                     st.store_name = None
@@ -1485,20 +1508,22 @@ class ClusterRuntime:
                     st.store_name = loc.get("store_name")
                     st.size = loc.get("size", 0)
                 st.event.set()
-        orphan = stream is None
-        if stream is not None:
+                streams.setdefault(task_id, (stream, []))[1].append(
+                    (index, oid, loc))
+        for stream, items in streams.values():
             with stream.cond:
                 if stream.closed:
                     # lost the race with _h_stream_close: its free sweep
-                    # ran off `items` before this index landed — undo the
+                    # ran off `items` before these landed — undo the
                     # registration and free the bytes ourselves
-                    orphan = True
+                    orphans.extend((oid, loc) for _, oid, loc in items)
                 else:
-                    stream.items[index] = oid
-                    if msg.get("producer"):
-                        stream.producer = msg["producer"]
+                    for index, oid, _ in items:
+                        stream.items[index] = oid
+                    if producer:
+                        stream.producer = producer
                     stream.cond.notify_all()
-        if orphan:
+        for oid, loc in orphans:
             with self._lock:
                 st = self._owned.get(oid)
                 if st is not None and self._refcounts.get(oid, 0) == 0 \

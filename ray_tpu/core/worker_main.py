@@ -27,6 +27,11 @@ from ray_tpu.core.rpc import Batcher
 from ray_tpu.core.ids import ActorID, NodeID, ObjectID, TaskID
 from ray_tpu.core.object_store import open_store
 from ray_tpu.core.specs import INLINE_THRESHOLD, ActorSpec, RefArg, TaskSpec
+from ray_tpu.core.stream_push import (
+    StreamShipper,
+    StreamWriter,
+    is_stream_source,
+)
 
 
 class WorkerRuntime(ClusterRuntime):
@@ -71,6 +76,8 @@ class WorkerRuntime(ClusterRuntime):
         # (reference: generator execution + backpressure in _raylet.pyx)
         self._active_streams: dict[bytes, threading.Event] = {}
         self._active_streams_lock = threading.Lock()
+        # pushed streams' one way out, made at the first stream source
+        self._stream_shipper: StreamShipper | None = None
         self.server.register("stream_cancel", self._h_stream_cancel,
                              oneway=True)
         self.server.register("execute_task", self._h_execute_task, oneway=True)
@@ -196,7 +203,11 @@ class WorkerRuntime(ClusterRuntime):
         """Drain a user generator, shipping each yielded value to the
         owner as a stream_item (inline or via the local shm store). Sends
         the terminating stream_end; returns the item count (the sentinel
-        result). Honors owner backpressure and cancel."""
+        result). Honors owner backpressure and cancel. A stream source
+        (`core/stream_push.py`) is pushed instead, unless the call asks
+        for backpressure: then it is iterated here like any generator."""
+        if not backpressure and is_stream_source(gen):
+            return self._push_stream(owner, task_id, gen)
         cancel = threading.Event()
         with self._active_streams_lock:
             self._active_streams[task_id] = cancel
@@ -273,6 +284,32 @@ class WorkerRuntime(ClusterRuntime):
                                 {"task_id": task_id, "count": produced,
                                  "producer": self.address})
         return produced
+
+    def _push_stream(self, owner: str, task_id: bytes, source) -> int:
+        """Tell a stream source its writer and sleep until its stream is
+        over: what is put there leaves through the process's shipper, a
+        `stream_items` message a flush of the producer's loop.
+        `stream_end` goes out from here once the stream's last item is
+        sent."""
+        with self._active_streams_lock:
+            if self._stream_shipper is None:
+                self._stream_shipper = StreamShipper(self)
+            writer = StreamWriter(self._stream_shipper, owner, task_id)
+            self._active_streams[task_id] = writer
+        try:
+            source.stream_to(writer)
+        finally:
+            writer.close()
+            writer.wait()
+            with self._active_streams_lock:
+                self._active_streams.pop(task_id, None)
+        if writer.error is not None:
+            raise writer.error
+        self.client.send_oneway(owner, "stream_end",
+                                {"task_id": task_id,
+                                 "count": writer.produced,
+                                 "producer": self.address})
+        return writer.produced
 
     # ------------------------------------------------------------ normal tasks
 

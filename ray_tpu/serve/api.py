@@ -11,6 +11,8 @@ import threading
 import weakref
 from typing import Any
 
+from ray_tpu.core.stream_push import is_stream_source
+
 _CONTROLLER_NAME = "__serve_controller"
 _log = logging.getLogger("ray_tpu.serve")
 
@@ -193,6 +195,27 @@ def deployment(_cls=None, *, name: str | None = None, num_replicas: int = 1,
     return wrap(_cls) if _cls is not None else wrap
 
 
+class _HeldSource:
+    """A replica method's stream source, holding the replica's
+    ongoing-request count until its stream is over, pushed or pulled."""
+
+    def __init__(self, source, release):
+        self._source = source
+        self._release = release
+
+    def stream_to(self, writer):
+        try:
+            self._source.stream_to(writer)
+        finally:
+            self._release()
+
+    def __iter__(self):
+        try:
+            yield from self._source
+        finally:
+            self._release()
+
+
 class _Replica:
     """Replica actor: hosts one instance of the deployment class
     (reference: replica actors, serve/_private/replica.py)."""
@@ -227,13 +250,14 @@ class _Replica:
                 self._ongoing -= 1
 
     def handle_stream_request(self, method: str, args, kwargs):
-        """Streaming variant: a GENERATOR method — called with
-        num_returns="streaming" so each yielded chunk ships to the
-        caller as produced (reference: replica response streaming over
-        the generator protocol, serve/_private/replica.py). Being a
-        generator itself keeps the ongoing-request count held until the
-        stream is drained or dropped, so autoscaling sees streams as
-        live load."""
+        """Streaming variant — called with num_returns="streaming" so
+        each chunk ships to the caller as produced (reference: replica
+        response streaming over the generator protocol,
+        serve/_private/replica.py). The ongoing-request count is held
+        until the stream is drained or dropped, so autoscaling sees
+        streams as live load. A method that returns a stream source
+        (`core/stream_push.py`: the LLM engine's tokens) is handed
+        through as one; everything else is drained as a generator."""
         with self._lock:
             self._ongoing += 1
         try:
@@ -241,14 +265,26 @@ class _Replica:
                 result = self._fn(*args, **kwargs)
             else:
                 result = getattr(self._instance, method)(*args, **kwargs)
+        except BaseException:
+            self._stream_over()
+            raise
+        if is_stream_source(result):
+            return _HeldSource(result, self._stream_over)
+        return self._drain_stream(result)
+
+    def _drain_stream(self, result):
+        try:
             if hasattr(result, "__iter__") and not isinstance(
                     result, (str, bytes, dict, list, tuple)):
                 yield from result
             else:
                 yield result
         finally:
-            with self._lock:
-                self._ongoing -= 1
+            self._stream_over()
+
+    def _stream_over(self):
+        with self._lock:
+            self._ongoing -= 1
 
     def ongoing(self) -> int:
         return self._ongoing

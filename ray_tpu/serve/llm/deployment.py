@@ -1,9 +1,9 @@
 """Serve integration: LLM engine replicas behind a DeploymentHandle.
 
 Each replica of the deployment owns one `LLMEngine` plus a daemon
-step-loop thread; `__call__` is a generator, so callers stream tokens
-through ``handle.options(stream=True).remote(payload)`` (one ObjectRef
-per token event) or over the HTTP proxy's NDJSON path — the same
+step-loop thread; `__call__` returns a `TokenSource`, so callers stream
+tokens through ``handle.options(stream=True).remote(payload)`` (one
+ObjectRef per token event) or over the HTTP proxy's NDJSON path — the same
 streaming generator protocol every other serve deployment uses.
 
 Payload schema (JSON-friendly)::
@@ -46,6 +46,50 @@ def prompt_affinity_key(prompt: Sequence[int],
 
     return format(hash_page(0, [int(t) for t in prompt[:prefix_len]]),
                   "016x")
+
+
+class TokenSource:
+    """One request's token events and its final event, as a stream source
+    (`core/stream_push.py`). Through a replica's worker it is PUSHED:
+    `stream_to(writer)` enters the request with the writer, the engine's
+    loop puts each step's events there and flushes them with the other
+    lanes', and this thread sleeps until the final event is sent or the
+    consumer lets go. Iterated (a call with backpressure, the local-mode
+    runtime, in-process users) it is the generator it always was. Either
+    way the request enters the engine when the stream is started, and a
+    consumer gone mid-stream releases the decode lane and the KV pages
+    instead of generating to max_tokens for nobody."""
+
+    def __init__(self, engine, prompt, sampling: SamplingParams,
+                 tokens: bool = True):
+        self.engine = engine
+        self.prompt = prompt
+        self.sampling = sampling
+        self.tokens = tokens  # False: the final event alone
+
+    def stream_to(self, writer) -> None:
+        stream = self.engine.add_request(
+            self.prompt, self.sampling, writer=writer, tokens=self.tokens)
+        try:
+            writer.wait()
+        finally:
+            self._let_go(stream)
+
+    def __iter__(self):
+        stream = self.engine.add_request(self.prompt, self.sampling)
+        try:
+            if self.tokens:
+                yield from stream
+            else:
+                for _ in stream:
+                    pass
+            yield stream.final()
+        finally:
+            self._let_go(stream)
+
+    def _let_go(self, stream) -> None:
+        if stream.final() is None:
+            self.engine.abort_request(stream, "client_disconnected")
 
 
 class LLMServer:
@@ -96,21 +140,9 @@ class LLMServer:
         if not prompt:
             raise ValueError("payload needs a non-empty 'prompt' "
                              "(list of token ids)")
-        sampling = SamplingParams.from_payload(payload)
-        stream = self.engine.add_request(prompt, sampling)
-        try:
-            if payload.get("stream", True):
-                yield from stream
-            else:
-                for _ in stream:
-                    pass
-            yield stream.final()
-        finally:
-            # consumer gone mid-stream (GeneratorExit / replica
-            # teardown): release the decode lane + KV pages instead of
-            # generating to max_tokens for nobody
-            if stream.final() is None:
-                self.engine.abort_request(stream, "client_disconnected")
+        return TokenSource(self.engine, prompt,
+                           SamplingParams.from_payload(payload),
+                           payload.get("stream", True))
 
     def update_weights(self, version: int, weights) -> dict:
         """Install new engine params (weight hot-swap). `weights` is a

@@ -109,8 +109,9 @@ class _Durations:
 
 class StreamAccount:
     """The replica's half of every stream's hand-off, summed over the
-    engine's streams: each folds its own counts in every 64 items and at
-    its end (`RequestStream`), under the lock."""
+    engine's streams, under the lock. A pulled stream folds its own
+    counts in every 64 items and at its end (`RequestStream`); pushed
+    items are folded a message, by the flush that sends it (`shipping`)."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -119,10 +120,17 @@ class StreamAccount:
         self.ship_s = 0.0
         self.max_ms = 0.0
         self.handoff = [0] * (len(GAP_EDGES_MS) + 1)
+        self.pushed = 0    # items that left through the shipper
+        self.messages = 0  # `stream_items` messages they left in
 
     def fold(self, items: int, pickup_s: float, ship_s: float,
-             max_ms: float, buckets: dict) -> None:
+             max_ms: float, buckets: dict, messages: int = 0) -> None:
+        """`messages`: the pushed messages the items left in, none for a
+        pulled stream's."""
         with self._lock:
+            if messages:
+                self.pushed += items
+                self.messages += messages
             self.items += items
             self.pickup_s += pickup_s
             self.ship_s += ship_s
@@ -130,11 +138,50 @@ class StreamAccount:
             for i, n in buckets.items():
                 self.handoff[i] += n
 
+    def shipping(self, put_times: list) -> "_Shipping":
+        """What a `StreamWriter.put` names as its observer: the context
+        a flush holds over one message, from taking its items up (each
+        put down at its `put_times` reading) to handing the message to
+        the socket."""
+        return _Shipping(self, put_times)
+
     def stats(self) -> dict:
         with self._lock:
             return {"items": self.items, "pickup_s": self.pickup_s,
                     "ship_s": self.ship_s, "edges_ms": list(GAP_EDGES_MS),
-                    "handoff": list(self.handoff), "max_ms": self.max_ms}
+                    "handoff": list(self.handoff), "max_ms": self.max_ms,
+                    "pushed": self.pushed, "messages": self.messages}
+
+
+class _Shipping:
+    """One pushed message in the account, and one `llm.stream.ship`
+    event in a device profile, on the flushing thread's line (the
+    loop's, inside `llm.emit`)."""
+
+    __slots__ = ("account", "put_times", "taken", "event")
+
+    def __init__(self, account: StreamAccount, put_times: list):
+        self.account = account
+        self.put_times = put_times
+
+    def __enter__(self):
+        self.event = tracing.annotate("llm.stream.ship")
+        self.event.__enter__()
+        self.taken = time.perf_counter()
+
+    def __exit__(self, *exc):
+        sent = time.perf_counter()
+        self.event.__exit__(*exc)
+        ship = sent - self.taken
+        pickup, longest, buckets = 0.0, 0.0, {}
+        for put_down in self.put_times:
+            pickup += self.taken - put_down
+            ms = (sent - put_down) * 1e3
+            longest = max(longest, ms)
+            i = gap_bucket(ms)
+            buckets[i] = buckets.get(i, 0) + 1
+        n = len(self.put_times)
+        self.account.fold(n, pickup, ship * n, longest, buckets, messages=1)
 
 
 @dataclasses.dataclass
@@ -173,12 +220,24 @@ class RequestStream:
     serialising and sending it; also an `llm.stream.ship` event in a
     device profile). Kept on the stream without a lock and folded into
     `account`, where there is one, every FOLD_EVERY items and at the
-    stream's end."""
+    stream's end.
+
+    A stream made with a `writer` (a `core.stream_push.StreamWriter`:
+    the request came through a replica's worker) is PUSHED instead:
+    the loop puts each event, and the final one, on the writer, nobody
+    iterates, and the loop's one flush after a step's emit sends the
+    events of every lane in one message and accounts for them
+    (`StreamAccount.shipping`: pickup = put down -> the flush took it
+    up, ship = taken up -> its message handed to the socket).
+    `tokens=False` pushes the final event alone."""
 
     FOLD_EVERY = 64
 
-    def __init__(self, seq_id: int, account: StreamAccount | None = None):
+    def __init__(self, seq_id: int, account: StreamAccount | None = None,
+                 writer=None, tokens: bool = True):
         self.seq_id = seq_id
+        self._writer = writer
+        self._tokens = tokens
         self._q: "queue.Queue[Any]" = queue.Queue()
         self._final: dict | None = None
         self._ended = False  # sentinel consumed (iteration or next_event)
@@ -196,12 +255,19 @@ class RequestStream:
 
     # engine side -----------------------------------------------------
     def _emit(self, ev: dict) -> None:
-        # the time it was put down travels beside the event, not in it
-        self._q.put((ev, time.perf_counter()))
+        if self._writer is None:
+            # the time it was put down travels beside the event, not in it
+            self._q.put((ev, time.perf_counter()))
+        elif self._tokens:
+            self._writer.put(ev, self._account)
 
     def _close(self, final: dict) -> None:
         self._final = final
-        self._q.put(_FINAL)
+        if self._writer is None:
+            self._q.put(_FINAL)
+        else:  # the summary is the stream's last item, and no token
+            self._writer.put(final)
+            self._writer.close()
 
     # consumer side ---------------------------------------------------
     def __iter__(self):
@@ -436,6 +502,9 @@ class LLMEngine:
         # read so far, which a sequence remembers at each of its tokens
         self._gaps = {cause: _Durations() for cause in GAP_CAUSES}
         self._burst_tokens = 0
+        # a writer some lane of the step being read pushed an event to:
+        # the step's events are flushed through it once, after its emit
+        self._pushed_to = None
         self._prefill_reads = 0
         # the same gaps on `serve_llm_itl_ms`'s boundaries, since the
         # last step's bookkeeping: {cause: [{bucket: n}, their sum in ms]}
@@ -722,8 +791,11 @@ class LLMEngine:
     # ------------------------------------------------------------ intake
 
     def add_request(self, prompt: Seq[int],
-                    sampling: SamplingParams | None = None
+                    sampling: SamplingParams | None = None,
+                    *, writer=None, tokens: bool = True
                     ) -> RequestStream:
+        """`writer`: push the stream's events there instead of keeping
+        them for an iterator (`RequestStream`)."""
         sampling = sampling or SamplingParams()
         prompt = [int(t) for t in prompt]
         if not prompt:
@@ -736,7 +808,8 @@ class LLMEngine:
         from ray_tpu.utils.events import child_trace
 
         seq.trace = child_trace(tracing.current_trace())
-        stream = RequestStream(seq.seq_id, self._stream_account)
+        stream = RequestStream(seq.seq_id, self._stream_account, writer,
+                               tokens)
         with self._lock:
             # validate (scheduler.add raises on over-long prompts) BEFORE
             # registering the stream, or rejected requests leak entries
@@ -919,6 +992,11 @@ class LLMEngine:
                     tokens = self._commit_decode(flight, nxt, logits)
                 for s, d in flight.drafted:
                     tokens += self._verify_one(s, d, flight.ver)
+        if self._pushed_to is not None:
+            # the step's events of every pushed lane, one message an owner
+            with self.phases.phase("emit"):
+                self._pushed_to.flush()
+            self._pushed_to = None
         with self.phases.phase("bookkeep"):
             # the wall time this step cost: from the end of the step
             # before it, or from its own planning after a pause
@@ -1323,6 +1401,7 @@ class LLMEngine:
                 ev["logprob"] = seq.logprobs[idx]
                 ev["weight_version"] = version
             stream._emit(ev)
+            self._pushed_to = stream._writer or self._pushed_to
 
     def _finalize(self, seq: Sequence) -> None:
         with self._lock:
