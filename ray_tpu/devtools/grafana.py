@@ -1,11 +1,11 @@
 """Grafana dashboard generator — one panel per catalog metric.
 
-``python -m ray_tpu.devtools.grafana [-o dashboards/ray_tpu.json]``
-regenerates the committed dashboard from `ray_tpu.util.metrics_catalog`
-(the machine-readable metric registry). Deterministic output: same
-catalog, byte-identical JSON — which is what lets the CI drift gate
-assert the committed file matches a regeneration, so dashboard, docs,
-and code cannot diverge silently.
+``python -m ray_tpu.devtools.grafana [-o PATH]`` writes the dashboard
+(to stdout without `-o`) from `ray_tpu.util.metrics_catalog` (the
+machine-readable metric registry). Nothing generated is committed: an
+operator generates the file for the checkout they run. Deterministic
+output: same catalog, byte-identical JSON; the gate in
+tests/test_observability4.py holds one panel a catalogued metric.
 
 Panel expression by type (the cluster /metrics page is the datasource,
 every series tagged node=/proc= by the aggregation layer):
@@ -20,6 +20,8 @@ Rows group panels by metric prefix (train/serve_llm/object_store/...).
 from __future__ import annotations
 
 import json
+import os
+import sys
 
 from ray_tpu.util.metrics_catalog import CATALOG
 
@@ -134,13 +136,16 @@ def main(argv=None):
     import argparse
 
     ap = argparse.ArgumentParser(prog="python -m ray_tpu.devtools.grafana")
-    ap.add_argument("-o", "--output", default="dashboards/ray_tpu.json")
+    ap.add_argument("-o", "--output", default=None,
+                    help="file to write (default: stdout)")
     args = ap.parse_args(argv)
-    import os
-
+    text = dashboard_json()
+    if args.output is None:
+        sys.stdout.write(text)
+        return 0
     os.makedirs(os.path.dirname(args.output) or ".", exist_ok=True)
     with open(args.output, "w") as f:
-        f.write(dashboard_json())
+        f.write(text)
     print(f"wrote {args.output} ({len(CATALOG)} metrics)")
     return 0
 

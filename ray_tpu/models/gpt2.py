@@ -230,11 +230,11 @@ def gpt2_forward(params: Params, tokens: jax.Array, cfg: GPT2Config) -> jax.Arra
         # "none" disables remat.
         import os as _os
 
-        # Default "full" is MEASURED fastest on v5e-class chips for
-        # GPT-2-small (see PERF_NOTES.md): full recompute 0.354 MFU vs
-        # save_flash 0.338, save_dots 0.339, none 0.320 — at this
-        # model size the HBM traffic of saving residuals costs more
-        # than the recompute FLOPs. Larger models (activation-bound)
+        # Default "full": at GPT-2-small's size the HBM traffic of
+        # saving residuals cost more than the recompute's FLOPs when
+        # the four policies were last compared on a v5e, which was
+        # before PR 35 changed what the flash kernel saves (PERF.md §7
+        # keeps the question open). Larger, activation-bound models
         # should flip to save_flash/save_dots via this env lever.
         mode = _os.environ.get("RAY_TPU_REMAT_POLICY", "full")
         if mode == "save_flash":

@@ -5,7 +5,8 @@ float`` — pure, host-side, cheap. The registry lets serialized configs
 name a reward by string (configs stay pure data, shippable to rollout
 actors) instead of cloudpickling closures.
 
-The toy tasks are the closed-loop demonstrators for bench_rl.py: a
+The toy tasks are the closed-loop demonstrators
+(tests/test_rllib_llm.py::test_flywheel_closed_loop_smoke): a
 reward a program can verify exactly (RLAX-style "verifiable task"), on
 prompts that share a common system prefix so rollouts exercise the
 serve.llm prefix cache the way real RLHF sampling does.

@@ -1,7 +1,7 @@
 """Speculative decoding: draft proposers + config.
 
 Decode emits one token per program dispatch, so tokens/s is pinned to
-the dispatch floor PERF_NOTES measured (~4 ms/step on the CPU rig).
+the floor of one dispatch a step.
 Speculative decoding amortizes that floor: a cheap host-side *proposer*
 guesses the next K tokens, a single ``verify`` dispatch (runner.py)
 scores all K+1 positions at once, and an in-jit acceptance rule keeps

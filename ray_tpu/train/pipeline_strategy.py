@@ -124,7 +124,6 @@ class PipelineStageWorker:
         self._vel: list[Any] | None = None
         self._spans: list[tuple[float, float]] = []
         self._cpu_busy = 0.0        # work seconds inside ops (see busy_s)
-        self.emulate: tuple[float, float] | None = None
         self._last_state_bytes: dict[str, int] = {}
 
     def ensure_cpu_devices(self, n: int) -> bool:
@@ -148,22 +147,11 @@ class PipelineStageWorker:
     def load_stage(self, cfg_kwargs: dict, params_blob: bytes, lr: float,
                    num_microbatches: int, num_repeats: int = 1,
                    zero_stage: int = 0, data_parallel: int = 1,
-                   momentum: float = 0.0,
-                   emulate_ms: tuple | None = None) -> int:
+                   momentum: float = 0.0) -> int:
         """Install this worker's config + its R virtual-stage param
         chunks (params_blob: cloudpickled list, chunk r == virtual
         stage r*S + stage). Returns the worker's parameter count (the
-        driver logs the split).
-
-        `emulate_ms=(fwd_ms, bwd_ms)` switches the worker into schedule
-        emulation: ops sleep a modeled per-chunk duration (the full
-        stage's cost split across R virtual-stage chunks) instead of
-        running XLA, while everything else — submission order, FIFO
-        execution, activation hand-off through the object store, span
-        and busy accounting — stays the real path. Sleeping workers
-        overlap even on a single host core, so the measured bubble
-        reflects schedule quality plus real dispatch overhead rather
-        than host CPU contention (see the pipeline bench)."""
+        driver logs the split)."""
         import jax
 
         from ray_tpu.models.pipelined import PipelinedConfig
@@ -175,8 +163,6 @@ class PipelineStageWorker:
         self.zero_stage = int(zero_stage)
         self.data_parallel = int(data_parallel)
         self.momentum = float(momentum)
-        self.emulate = (tuple(float(x) / 1e3 for x in emulate_ms)
-                        if emulate_ms else None)
         chunks = cloudpickle.loads(params_blob)
         if not isinstance(chunks, list):  # single-chunk (flat) callers
             chunks = [chunks]
@@ -349,15 +335,6 @@ class PipelineStageWorker:
         c0 = time.process_time()
         v = r * self.num_stages + self.stage
         last = v == self.num_stages * self.num_repeats - 1
-        if self.emulate is not None:
-            dur = self.emulate[0] / self.num_repeats
-            time.sleep(dur)
-            self._cpu_busy += dur
-            self._saved[(r, mb)] = (payload,)
-            t1 = time.perf_counter()
-            self._spans.append((t0, t1))
-            self._trace("fwd", t0, t1, r, mb)
-            return 0.0 if last else payload
         x = self._put_batch(payload)
         if last:
             tgt = self._put_batch(targets)
@@ -390,14 +367,6 @@ class PipelineStageWorker:
         c0 = time.process_time()
         v = r * self.num_stages + self.stage
         saved = self._saved.pop((r, mb))
-        if self.emulate is not None:
-            dur = self.emulate[1] / self.num_repeats
-            time.sleep(dur)
-            self._cpu_busy += dur
-            t1 = time.perf_counter()
-            self._spans.append((t0, t1))
-            self._trace("bwd", t0, t1, r, mb)
-            return True if v == 0 else saved[0]
         if grad is None:
             seed = jnp.float32(1.0 / self.num_microbatches)
         else:
@@ -517,8 +486,7 @@ class PipelineStrategy:
                  resources_per_worker: dict | None = None,
                  placement_strategy: str = "PACK",
                  num_repeats: int = 1, zero_stage: int = 0,
-                 data_parallel: int = 1, momentum: float = 0.0,
-                 emulate_ms: tuple | None = None):
+                 data_parallel: int = 1, momentum: float = 0.0):
         import jax
 
         from ray_tpu.models.pipelined import (
@@ -535,7 +503,6 @@ class PipelineStrategy:
         self.zero_stage = int(zero_stage)
         self.data_parallel = int(data_parallel)
         self.momentum = float(momentum)
-        self.emulate_ms = tuple(emulate_ms) if emulate_ms else None
         self.num_microbatches = int(
             num_microbatches or self.cfg.num_microbatches)
         if self.zero_stage not in (0, 1, 2, 3):
@@ -582,8 +549,7 @@ class PipelineStrategy:
                     cloudpickle.dumps(
                         [jax.tree.map(np.asarray, c) for c in stages[s]]),
                     lr, self.num_microbatches, self.num_repeats,
-                    self.zero_stage, self.data_parallel, self.momentum,
-                    self.emulate_ms)
+                    self.zero_stage, self.data_parallel, self.momentum)
                 for s in range(num_stages)
             ]
         except Exception:

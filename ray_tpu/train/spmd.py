@@ -66,7 +66,7 @@ class StepWaterfall:
     went). OFF by default — the instrumented step checks one bool, so
     attribution costs nothing when disabled; when enabled it adds a
     device sync per step (that is the point: a profiling run, not a
-    record run — `bench.py --trace` turns it on).
+    record run — `RAY_TPU_STEP_WATERFALL=1` turns it on).
 
     Phases per step: ``data_wait`` (caller-reported input fetch, see
     `note_data_wait`), ``h2d`` (host->device transfer of numpy batch
@@ -81,7 +81,7 @@ class StepWaterfall:
     collective share cannot be wall-timed from the host; instead the
     compiled step's collective op census (counts by op, from the HLO)
     is recorded alongside — see ``program_collectives`` in
-    `summary()` and the `bench.py --trace` table."""
+    `summary()` and `table()`."""
 
     def __init__(self):
         # "0"/"false"/"" all mean OFF — an operator writing =0 to be

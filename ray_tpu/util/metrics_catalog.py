@@ -9,13 +9,12 @@ tests/test_observability4.py):
   a catalog entry, and vice versa;
 - the DOCS: every catalog name must appear in OBSERVABILITY.md's
   catalog table, and every metric named there must exist here;
-- the DASHBOARD: ``python -m ray_tpu.devtools.grafana`` generates
-  dashboards/ray_tpu.json from this catalog (one panel per metric,
-  typed expressions), and the committed JSON must match a regeneration.
+- the DASHBOARD: ``python -m ray_tpu.devtools.grafana [-o PATH]``
+  generates it from this catalog on request (one panel per metric,
+  typed expressions); no generated copy is committed.
 
 Adding a metric therefore means: construct it, add its row here, add
-its OBSERVABILITY.md row, regenerate the dashboard. Forgetting any of
-the four fails the gate.
+its OBSERVABILITY.md row. Forgetting any of the three fails the gate.
 """
 
 from __future__ import annotations

@@ -28,7 +28,7 @@ import time
 
 from ray_tpu.utils.events import TaskEventLog, child_trace
 
-# spans recorded before/without ray_tpu.init() (bench.py, bare LLMEngine)
+# spans recorded before/without ray_tpu.init() (a bare LLMEngine)
 _fallback_log = TaskEventLog()
 _fallback_ctx = threading.local()
 

@@ -9,6 +9,7 @@ that has chips.
 """
 
 import os
+import sys
 
 # Must happen before the first jax backend initialization.
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
@@ -21,6 +22,30 @@ jax.config.update("jax_platforms", "cpu")
 
 
 import pytest  # noqa: E402
+
+
+def _stop_leaked_runtime(module_name: str) -> None:
+    """Shut down a runtime that is still up and fail, naming the module
+    that left it. Left alone it fails every later test of this xdist
+    worker at set-up (`ray_tpu.init() called twice`), under the names of
+    modules that did nothing wrong."""
+    ray_tpu = sys.modules.get("ray_tpu")
+    if ray_tpu is None or not ray_tpu.is_initialized():
+        return
+    ray_tpu.shutdown()
+    pytest.fail(f"{module_name} left a ray_tpu runtime running: a test "
+                "or fixture of it called ray_tpu.init(), or used the "
+                "API without it, and never ray_tpu.shutdown()")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_runtime_left_running(request):
+    """Module-scoped because fixtures all over tests/ hold a runtime for
+    a whole module on purpose; autouse, so it is set up before and torn
+    down after every other fixture of the module. Its value is the check
+    itself, for the one test of it."""
+    yield _stop_leaked_runtime
+    _stop_leaked_runtime(request.module.__name__)
 
 
 @pytest.fixture

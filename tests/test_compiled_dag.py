@@ -290,7 +290,11 @@ def test_chaos_mid_chain_death_falls_back_cleanly(ray_boot):
     try:
         assert dag.execute(1).get() == 7
         ray_tpu.kill(actors[1])
-        time.sleep(0.3)
+        # until the head has seen the death, not for a fixed time
+        deadline = time.monotonic() + 60
+        while _actor_state(actors[1])[0] != "DEAD" and \
+                time.monotonic() < deadline:
+            time.sleep(0.05)
         refs = [dag.execute(i) for i in range(4)]
         for r in refs:
             with pytest.raises(RayTpuError):

@@ -228,3 +228,20 @@ def test_options_validation(ray_local):
         @ray.remote(bogus_option=1)
         def f():
             pass
+
+
+def test_a_module_that_leaves_a_runtime_up_is_named(no_runtime_left_running):
+    """conftest's module fixture: a runtime still up when a module's
+    last fixture has gone is shut down and charged to that module, by
+    name, so the next module's tests start clean."""
+    import ray_tpu
+
+    check = no_runtime_left_running
+    check("tests.some_module")  # nothing is up: nothing is said
+    ray_tpu.init(local_mode=True, num_cpus=1)
+    with pytest.raises(pytest.fail.Exception,
+                       match="tests.some_module left a ray_tpu runtime"):
+        check("tests.some_module")
+    assert not ray_tpu.is_initialized()
+    ray_tpu.init(local_mode=True, num_cpus=1)  # and init() works again
+    ray_tpu.shutdown()

@@ -187,22 +187,31 @@ def test_health_loop_replaces_killed_replica(cluster):
         serve.delete("heal")
 
 
-def test_unary_failover_single_replica_rides_out_heal(cluster):
+def test_unary_failover_single_replica_rides_out_heal(cluster, tmp_path):
     """ActorDiedError on a unary call is transparent: the relay retries
     with backoff until the replacement takes traffic — even when the
-    dead replica was the ONLY one."""
+    dead replica was the ONLY one. The call takes its replica down
+    itself, once, so it meets the outage whatever the host's clock
+    does (a kill from outside raced the heal under six workers)."""
+    died_once = str(tmp_path / "died-once")
 
     @serve.deployment(num_replicas=1, health_check_period_s=0.3)
     class Solo:
+        def __init__(self, path):
+            self.path = path
+
         def __call__(self, x):
+            if x == 5 and not os.path.exists(self.path):
+                with open(self.path, "w") as f:
+                    f.write("x")
+                os._exit(1)  # as chaos_exit does: no reply, no cleanup
             return x * 3
 
-    h = serve.run(Solo.bind(), name="solo")
+    h = serve.run(Solo.bind(died_once), name="solo")
     try:
         assert ray_tpu.get(h.remote(2), timeout=60) == 6
         before = _failovers("solo")
-        chaos.kill_replica("solo")
-        # submitted into the outage window: must converge, not error
+        # answered by the replacement: must converge, not error
         assert ray_tpu.get(h.remote(5), timeout=120) == 15
         assert _failovers("solo") > before
         _wait_healed("solo", target=1)
