@@ -5,7 +5,8 @@ Nemotron-3 hybrids (`model_type` nemotron_h). Every block is
 ``x = x + mixer(rmsnorm(x))`` with ONE mixer, and `layer_pattern`, a
 string over three letters, says which:
 
-- ``M``, a Mamba-2 mixer: ``in_proj`` to z | xBC | dt, a causal depthwise
+- ``M``, a Mamba-2 mixer (models/mamba2.py, shared with granite_hybrid):
+  ``in_proj`` to z | xBC | dt, a causal depthwise
   convolution over xBC then SiLU, the selective state-space recurrence
   per head ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``,
   ``y_t = S_t C_t + D x_t`` (B and C shared by the heads of a group), a
@@ -49,6 +50,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import mamba2
+from ray_tpu.models.mamba2 import ssd_chunked  # noqa: F401 - the family's name for it
 from ray_tpu.models.moe import routed_experts
 from ray_tpu.ops.context_attention import (
     attend_cached,
@@ -134,18 +137,20 @@ class NemotronHConfig:
     def conv_dim(self) -> int:
         return self.d_inner + 2 * self.n_groups * self.ssm_state_size
 
+    @property
+    def mamba(self) -> mamba2.Mamba2Sizes:
+        """What the shared mixer (models/mamba2.py) asks of a family."""
+        return mamba2.Mamba2Sizes(
+            heads=self.mamba_num_heads, head_dim=self.mamba_head_dim,
+            state=self.ssm_state_size, groups=self.n_groups,
+            conv_kernel=self.conv_kernel, chunk=self.chunk_size,
+            eps=self.layer_norm_epsilon, dtype=self.dtype)
+
     def state_parts(self) -> tuple:
         """(name, shape a lane and layer, dtype) of a Mamba layer's
         recurrent state, for `cache.StateLayout`: the conv window a part
-        a row (`conv0` the oldest), so that each buffer is (layers,
-        slots, conv_dim) and tiles without padding (a (3, conv_dim)
-        window a slot pads 3 rows to 16 and XLA relays the buffer out
-        around every program), and the SSM state."""
-        return tuple(
-            (f"conv{j}", (self.conv_dim,), self.dtype)
-            for j in range(self.conv_kernel - 1)) + (
-            ("ssm", (self.mamba_num_heads, self.mamba_head_dim,
-                     self.ssm_state_size), jnp.float32),)
+        a row and the SSM state (`Mamba2Sizes.state_parts`)."""
+        return self.mamba.state_parts()
 
     @staticmethod
     def tiny() -> "NemotronHConfig":
@@ -289,7 +294,7 @@ def init_nemotron_h(key: jax.Array, cfg: NemotronHConfig) -> Params:
 
 
 # --------------------------------------------------------------------------
-# the three mixers, each written once
+# the three mixers, each written once (the Mamba-2 one in models/mamba2.py)
 
 
 def _rmsnorm(x, scale, eps):
@@ -298,163 +303,17 @@ def _rmsnorm(x, scale, eps):
     return (x32 * rms * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def _ssm_inputs(h, p, cfg: NemotronHConfig):
-    """Normed rows h (..., D) -> z (..., d_inner), xBC (..., conv_dim)
-    before the convolution, dt (..., H) before its bias."""
-    with jax.named_scope("ssm.in_proj"):
-        zxbcdt = h @ p["in_proj"].astype(cfg.dtype)
-    return jnp.split(zxbcdt, (cfg.d_inner, cfg.d_inner + cfg.conv_dim),
-                     axis=-1)
-
-
-def _ssm_split(xbc, dt, p, cfg: NemotronHConfig):
-    """The convolution's output (..., conv_dim) f32 and raw dt -> x
-    (..., H, P), B and C (..., G, N) in `dtype`, dt (..., H) f32 after
-    its bias and softplus, A (H,) f32."""
-    H, P = cfg.mamba_num_heads, cfg.mamba_head_dim
-    G, N = cfg.n_groups, cfg.ssm_state_size
-    xbc = jax.nn.silu(xbc).astype(cfg.dtype)
-    x, B, C = jnp.split(xbc, (H * P, H * P + G * N), axis=-1)
-    lead = xbc.shape[:-1]
-    dt = jax.nn.softplus(dt.astype(jnp.float32)
-                         + p["dt_bias"].astype(jnp.float32))
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    return (x.reshape(*lead, H, P), B.reshape(*lead, G, N),
-            C.reshape(*lead, G, N), dt, A)
-
-
-def _ssm_output(y, x, z, p, cfg: NemotronHConfig):
-    """y (..., H, P) f32 from the recurrence -> the mixer's output
-    (..., D): the skip ``D x``, the gate under its grouped norm, and
-    `out_proj`."""
-    G = cfg.n_groups
-    with jax.named_scope("ssm.gate_norm"):
-        y = y + p["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
-        lead = y.shape[:-2]
-        y = y.reshape(*lead, cfg.d_inner) \
-            * jax.nn.silu(z.astype(jnp.float32))
-        g = y.reshape(*lead, G, cfg.d_inner // G)
-        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
-                              + cfg.layer_norm_epsilon)
-        y = (g.reshape(*lead, cfg.d_inner)
-             * p["gate_norm"].astype(jnp.float32)).astype(cfg.dtype)
-    with jax.named_scope("ssm.out_proj"):
-        return y @ p["out_proj"].astype(cfg.dtype)
-
-
-def ssd_chunked(x, B, C, dt, A, state, chunk: int):
-    """The recurrence over T rows in its chunked form. x (T, H, P), B and
-    C (T, G, N), dt (T, H) f32 (0 for a row that must not count), A (H,),
-    state (H, P, N) f32 -> (y (T, H, P) f32 without the skip, the state
-    after the last row). Inside a chunk of Q rows, with ``a = dt A`` and
-    ``cum`` its running sum: ``y_t = sum_{s<=t} exp(cum_t - cum_s)
-    (C_t . B_s) dt_s x_s + exp(cum_t) S_0 C_t`` and ``S_Q = exp(cum_Q)
-    S_0 + sum_s exp(cum_Q - cum_s) dt_s x_s B_s^T``; a `lax.scan` carries
-    the state from chunk to chunk. The decay and the state are float32;
-    the products take their operands as the backend's default precision
-    gives them (bf16 on the MXU) and accumulate in float32."""
-    T, H, P = x.shape
-    G, N = B.shape[1:]
-    R = H // G  # heads that share a group's B and C
-    Q = chunk if T % chunk == 0 else T
-    if T % Q or (Q != chunk and T > chunk):
-        raise ValueError(f"{T} rows do not divide into chunks of {chunk}")
-    f32 = jnp.float32
-    xdt = (x.astype(f32) * dt[..., None]).reshape(T // Q, Q, G, R, P)
-    a = (dt * A).reshape(T // Q, Q, G, R)
-    Bc = B.astype(f32).reshape(T // Q, Q, G, N)
-    Cc = C.astype(f32).reshape(T // Q, Q, G, N)
-    causal = jnp.tril(jnp.ones((Q, Q), bool))
-
-    def one(S, xs):
-        xdt, a, Bq, Cq = xs
-        cum = jnp.cumsum(a, axis=0)  # (Q, G, R)
-        # (G, R, t, s): the decay from row s to row t, 0 above the diagonal
-        seg = cum.transpose(1, 2, 0)[:, :, :, None] \
-            - cum.transpose(1, 2, 0)[:, :, None, :]
-        decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
-        cb = jnp.einsum("tgn,sgn->gts", Cq, Bq)
-        y = jnp.einsum("grts,sgrp->tgrp", cb[:, None] * decay, xdt)
-        y = y + jnp.exp(cum)[..., None] * jnp.einsum(
-            "tgn,grpn->tgrp", Cq, S)
-        to_end = jnp.exp(cum[-1][None] - cum)  # (Q, G, R)
-        S = jnp.exp(cum[-1])[..., None, None] * S + jnp.einsum(
-            "sgrp,sgn->grpn", xdt * to_end[..., None], Bq)
-        return S, y
-
-    S, y = jax.lax.scan(one, state.astype(f32).reshape(G, R, P, N),
-                        (xdt, a, Bc, Cc))
-    return y.reshape(T, H, P), S.reshape(H, P, N)
-
-
-def _window(state: dict, cfg: NemotronHConfig):
-    """The conv window (..., K-1, C) of a layer's state parts."""
-    return jnp.stack([state[f"conv{j}"]
-                      for j in range(cfg.conv_kernel - 1)], axis=-2)
-
-
 def _mamba_rows(h, p, cfg: NemotronHConfig, view, index: int, n_valid):
-    """The Mamba mixer on one lane's normed rows h (T, D), from the state
-    in the lane's slot (zero on a sequence's first rows) and leaving the
-    state after row ``n_valid - 1`` there."""
-    T = h.shape[0]
-    K = cfg.conv_kernel
-    state = view.lane(index)
-    z, xbc, dt = _ssm_inputs(h, p, cfg)
-    with jax.named_scope("ssm.conv"):
-        # window[j] is the input K-1-j rows back; rows of the lane's
-        # earlier programs come from its slot
-        seen = jnp.concatenate([_window(state, cfg).astype(xbc.dtype), xbc])
-        w = p["conv_w"].astype(jnp.float32)
-        conv = p["conv_b"].astype(jnp.float32) + sum(
-            w[j] * seen[j:j + T].astype(jnp.float32) for j in range(K))
-        # the last K-1 REAL inputs: padded rows leave the window alone
-        window = jax.lax.dynamic_slice_in_dim(seen, n_valid, K - 1)
-    x, B, C, dt, A = _ssm_split(conv, dt, p, cfg)
-    dt = jnp.where(jnp.arange(T)[:, None] < n_valid, dt, 0.0)
-    with jax.named_scope("ssm.scan"):
-        y, ssm = ssd_chunked(x, B, C, dt, A, state["ssm"], cfg.chunk_size)
-    view.set_lane(index, {"ssm": ssm, **{
-        f"conv{j}": window[j] for j in range(K - 1)}})
-    return _ssm_output(y, x, z, p, cfg)
+    """The Mamba mixer (models/mamba2.py) on one lane's normed rows h (T,
+    D), from the state in the lane's slot and leaving the state after row
+    ``n_valid - 1`` there."""
+    return mamba2.rows(h, p, cfg.mamba, view, index, n_valid)
 
 
 def _mamba_step(h, p, cfg: NemotronHConfig, view, index: int):
-    """One step of the recurrence for a decode batch h (Sb, D). Both parts
-    of the state are updated where they lie, every slot of the layer in
-    one elementwise pass: a slot that no lane of this step owns keeps its
-    conv window, and gets dt = 0 and x = 0, and ``1 * S + 0`` is S to the
-    bit."""
-    G = cfg.n_groups
-    R = cfg.mamba_num_heads // G
-    z, xbc, dt = _ssm_inputs(h, p, cfg)
-    state = view.all(index)
-    with jax.named_scope("ssm.conv"):
-        window = _window(state, cfg)  # (slots, K-1, C)
-        seen = jnp.concatenate(
-            [window, view.to_slots(xbc).astype(window.dtype)[:, None]], 1)
-        for j in range(cfg.conv_kernel - 1):  # the window moves one row on
-            view.set_all(index, f"conv{j}", jnp.where(
-                view.owned[:, None], seen[:, j + 1], window[:, j]))
-        conv = p["conv_b"].astype(jnp.float32) + jnp.einsum(
-            "kc,bkc->bc", p["conv_w"].astype(jnp.float32),
-            view.from_slots(seen).astype(jnp.float32))
-    x, B, C, dt, A = _ssm_split(conv, dt, p, cfg)
-    with jax.named_scope("ssm.step"):
-        f32 = jnp.float32
-        S = state["ssm"]  # (slots, H, P, N) f32
-        slots, H, P, N = S.shape
-        dts = view.to_slots(dt)  # (slots, H); 0 where no lane
-        xdt = view.to_slots(x.astype(f32)) * dts[..., None]
-        Bs = view.to_slots(B.astype(f32))  # (slots, G, N)
-        Cs = view.to_slots(C.astype(f32))
-        S5 = S.reshape(slots, G, R, P, N)
-        new = jnp.exp(dts * A).reshape(slots, G, R, 1, 1) * S5 \
-            + xdt.reshape(slots, G, R, P, 1) * Bs[:, :, None, None, :]
-        y = jnp.sum(new * Cs[:, :, None, None, :], axis=-1)
-        view.set_all(index, "ssm", new.reshape(S.shape))
-        y = view.from_slots(y.reshape(slots, H, P))
-    return _ssm_output(y, x, z, p, cfg)
+    """One step of the recurrence for a decode batch h (Sb, D), every
+    slot of the layer updated where it lies."""
+    return mamba2.step(h, p, cfg.mamba, view, index)
 
 
 def _qkv(h, p, cfg: NemotronHConfig):

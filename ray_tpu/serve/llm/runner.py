@@ -148,6 +148,7 @@ def adapters() -> dict[str, ModelAdapter]:
     from ray_tpu.models import (
         glm_dsa,
         gpt2,
+        granite_hybrid,
         lfm2,
         llama,
         mimo_v2,
@@ -260,6 +261,25 @@ def adapters() -> dict[str, ModelAdapter]:
             kv_kinds=one_kind(lambda cfg: cfg.n_kv_layers,
                               lambda cfg: cfg.num_key_value_heads),
             state_fn=lambda cfg: (cfg.n_conv_layers, cfg.state_parts()),
+            held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
+        ),
+        "granite_hybrid": ModelAdapter(
+            name="granite_hybrid",
+            config_cls=granite_hybrid.GraniteHybridConfig,
+            presets={
+                "tiny": granite_hybrid.GraniteHybridConfig.tiny,
+                "h_small": granite_hybrid.GraniteHybridConfig.h_small,
+                "h_small_l10_ep4":
+                    granite_hybrid.GraniteHybridConfig.h_small_l10_ep4,
+            },
+            init_fn=granite_hybrid.init_granite_hybrid,
+            prefill_fn=granite_hybrid.granite_hybrid_prefill_kv,
+            decode_fn=granite_hybrid.granite_hybrid_decode_kv,
+            chunk_fn=granite_hybrid.granite_hybrid_prefill_chunk_kv,
+            rules_fn=granite_hybrid.granite_hybrid_partition_rules,
+            kv_kinds=one_kind(lambda cfg: cfg.n_kv_layers,
+                              lambda cfg: cfg.num_key_value_heads),
+            state_fn=lambda cfg: (cfg.n_ssm_layers, cfg.state_parts()),
             held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
         ),
     }

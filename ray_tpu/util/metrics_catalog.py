@@ -208,7 +208,8 @@ CATALOG: list[dict] = [
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "pairs of the most loaded expert over the mean expert's, "
              "cumulative, by step kind (1.0: an even router)"},
-    # recurrent state (a family without it writes 0 and nothing more)
+    # recurrent state (nemotron_h, lfm2, granite_hybrid; a family
+    # without it writes 0 and nothing more)
     {"name": "serve_llm_state_bytes", "type": "gauge",
      "where": "ray_tpu/serve/llm/engine.py",
      "what": "bytes of recurrent state held for the lane slots, all "

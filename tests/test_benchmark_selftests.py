@@ -1,0 +1,29 @@
+"""The benchmark's own self-tests that no tier-1 command collected
+(`benchmark/selftest/` is run by hand): the pure reductions and the
+BENCHMARK.json rules (`test_pure`), the knees and the stall line
+(`test_knees_and_stall`), and what the granite-4.0-h-small cell stands on
+(`test_ssm_g1_metrics`: its three readers; `test_cell_granite_hybrid_cpu`:
+the cell's rehearsal on the CPU at `tiny`).
+
+Each test of those files is collected here under its own name, so that it
+counts, and runs, as one test: the functions are the files' own (marks and
+parametrisation with them), and the fixtures they ask for come along."""
+
+import importlib
+
+MODULES = ("test_pure", "test_knees_and_stall", "test_ssm_g1_metrics",
+           "test_cell_granite_hybrid_cpu")
+
+
+def _is_fixture(obj) -> bool:
+    return type(obj).__name__ == "FixtureFunctionDefinition" \
+        or hasattr(obj, "_pytestfixturefunction")
+
+
+for _module in MODULES:
+    _selftest = importlib.import_module(f"benchmark.selftest.{_module}")
+    for _name, _obj in list(vars(_selftest).items()):
+        if _is_fixture(_obj):
+            assert globals().setdefault(_name, _obj) is _obj, _name
+        elif _name.startswith("test_") and callable(_obj):
+            globals()[f"test_{_module[5:]}__{_name[5:]}"] = _obj

@@ -398,7 +398,11 @@ MODELS = {"gpt2-large": ("gpt2", "large", 8, 512, 512, 1024),
           "glm-5": ("glm_dsa", "glm_5_l5_ep32", 32, 24576, 256, 16768),
           # 64 lanes: 6 layers with K and V beside 18 with two rows of
           # conv state a lane, 20,480 pages
-          "lfm2-8b-a1b": ("lfm2", "lfm2_8b_a1b_ep4", 64, 20480, 256, 8576)}
+          "lfm2-8b-a1b": ("lfm2", "lfm2_8b_a1b_ep4", 64, 20480, 256, 8576),
+          # 64 lanes with a float32 part: 1 layer with K and V beside 9
+          # with 2.4 GB of Mamba-2 state, 7,232 pages
+          "granite-4.0-h-small": (
+              "granite_hybrid", "h_small_l10_ep4", 64, 7232, 256, 1792)}
 # A program's temporaries, bytes. With no weight cast in any program they
 # are activations: the AOT compile reads 1.1-105.8 MB for gpt2-large (the
 # most in prefill-512; 1.55-1.64 GB while the float32 stacks were cast
