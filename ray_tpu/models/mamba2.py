@@ -35,6 +35,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import ssm_step
+
 
 @dataclasses.dataclass(frozen=True)
 class Mamba2Sizes:
@@ -213,9 +215,12 @@ def rows(h, p, s: Mamba2Sizes, view, index: int, n_valid):
 def step(h, p, s: Mamba2Sizes, view, index: int):
     """One step of the recurrence for a decode batch h (Sb, D). Both parts
     of the state are updated where they lie, every slot of the layer in
-    one elementwise pass: a slot that no lane of this step owns keeps its
-    conv window, and gets dt = 0 and x = 0, and ``1 * S + 0`` is S to the
-    bit."""
+    one pass: a slot that no lane of this step owns keeps its conv window,
+    and gets dt = 0 and x = 0, and ``1 * S + 0`` is S to the bit. Where
+    `ssm_step.steps_by_kernel` allows, the SSM state's pass is the Pallas
+    kernel's (`ops/ssm_step.py`: the read-out formed inside the in-place
+    update); elsewhere the jnp form below, whose read-out XLA makes a
+    second pass over the state."""
     G = s.groups
     R = s.heads // G
     z, xbc, dt = _inputs(h, p, s)
@@ -234,16 +239,21 @@ def step(h, p, s: Mamba2Sizes, view, index: int):
     x, B, C, dt, A = _split(conv, dt, p, s)
     with jax.named_scope("ssm.step"):
         f32 = jnp.float32
-        S = state["ssm"]  # (slots, H, P, N) f32
-        slots, H, P, N = S.shape
         dts = view.to_slots(dt)  # (slots, H); 0 where no lane
         xdt = view.to_slots(x.astype(f32)) * dts[..., None]
         Bs = view.to_slots(B.astype(f32))  # (slots, G, N)
         Cs = view.to_slots(C.astype(f32))
-        S5 = S.reshape(slots, G, R, P, N)
-        new = jnp.exp(dts * A).reshape(slots, G, R, 1, 1) * S5 \
-            + xdt.reshape(slots, G, R, P, 1) * Bs[:, :, None, None, :]
-        y = jnp.sum(new * Cs[:, :, None, None, :], axis=-1)
-        view.set_all(index, "ssm", new.reshape(S.shape))
-        y = view.from_slots(y.reshape(slots, H, P))
+        if ssm_step.steps_by_kernel(view.layout):
+            y = view.in_place("ssm", lambda buf: ssm_step.ssm_step(
+                buf, index, jnp.exp(dts * A), xdt, Bs, Cs))
+        else:
+            S = state["ssm"]  # (slots, H, P, N) f32
+            slots, H, P, N = S.shape
+            S5 = S.reshape(slots, G, R, P, N)
+            new = jnp.exp(dts * A).reshape(slots, G, R, 1, 1) * S5 \
+                + xdt.reshape(slots, G, R, P, 1) * Bs[:, :, None, None, :]
+            y = jnp.sum(new * Cs[:, :, None, None, :], axis=-1)
+            view.set_all(index, "ssm", new.reshape(S.shape))
+            y = y.reshape(slots, H, P)
+        y = view.from_slots(y)
     return _output(y, x, z, p, s)

@@ -448,6 +448,13 @@ class StateView:
         buf = self.buffers[name]
         self.buffers[name] = buf.at[layer].set(new.astype(buf.dtype))
 
+    def in_place(self, name: str, update):
+        """Hand part `name`'s buffer, every layer of it as it lies, to
+        `update` (a kernel that aliases it to its first result) and take
+        it back: ``update(buffer) -> (buffer, out)``; gives `out`."""
+        self.buffers[name], out = update(self.buffers[name])
+        return out
+
 
 class StateSlots:
     """The bookkeeping half of the recurrent state (the buffers live in
@@ -460,8 +467,10 @@ class StateSlots:
     started a slot fresh): the prefill programs that started from the
     state an earlier chunk left in the slot (`carried`), and the decode
     programs by their rows (the lanes' bucket) with the slots their lanes
-    owned, the others written back as read. Written by the engine's one
-    stepping thread."""
+    owned, the others written back as read, and those of them whose
+    one-step recurrence went through the Pallas kernel (`kernel_steps`:
+    the runner says which path its programs take, `ops/ssm_step.py`).
+    Written by the engine's one stepping thread."""
 
     def __init__(self, layout: StateLayout, prefix_declined: bool):
         self.layout = layout
@@ -470,10 +479,12 @@ class StateSlots:
         self.carried = 0
         self.decode_steps: dict[int, int] = {}  # rows of the program: steps
         self.decode_lanes = 0  # slots owned, summed over the steps
+        self.kernel_steps = 0
 
-    def note_decode(self, rows: int, lanes: int) -> None:
+    def note_decode(self, rows: int, lanes: int, kernel: bool) -> None:
         self.decode_steps[rows] = self.decode_steps.get(rows, 0) + 1
         self.decode_lanes += lanes
+        self.kernel_steps += kernel
 
     def stats(self) -> dict:
         lay = self.layout
@@ -483,6 +494,7 @@ class StateSlots:
                 "decode_steps": {str(rows): n for rows, n in
                                  sorted(self.decode_steps.items())},
                 "decode_lanes": self.decode_lanes,
+                "kernel_steps": self.kernel_steps,
                 "prefix_declined": self.prefix_declined}
 
 

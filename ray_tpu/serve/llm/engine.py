@@ -1232,7 +1232,8 @@ class LLMEngine:
                      for s, n, t in zip(flight.plain, unread, tables)]
         if self.state_slots is not None:
             self.state_slots.note_decode(
-                self.runner.decode_bucket(len(items)), len(items))
+                self.runner.decode_bucket(len(items)), len(items),
+                self.runner.state_by_kernel)
             self._m_state_lanes.inc(len(items), tags=self._m_tags)
         flight.handle = self.runner.launch_decode(items)
 

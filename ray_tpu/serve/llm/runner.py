@@ -69,6 +69,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 import time
 from typing import Any, Callable, NamedTuple, Sequence
@@ -79,7 +80,7 @@ import numpy as np
 
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ray_tpu.ops import context_attention
+from ray_tpu.ops import context_attention, ssm_step
 from ray_tpu.ops.context_attention import CachedContext
 from ray_tpu.serve.llm.cache import (
     KVKind,
@@ -863,6 +864,15 @@ class ModelRunner:
     def _mesh_ctx(self):
         return (jax.set_mesh(self.mesh) if self.mesh is not None
                 else contextlib.nullcontext())
+
+    @functools.cached_property
+    def state_by_kernel(self) -> bool:
+        """Whether the decode programs step the lanes' SSM state with the
+        Pallas kernel (`ssm_step.steps_by_kernel`, asked under the mesh
+        the programs are traced under): what `StateSlots` counts."""
+        with self._mesh_ctx():
+            return self.state_layout is not None \
+                and ssm_step.steps_by_kernel(self.state_layout)
 
     def prefill_bucket(self, n: int) -> int:
         if n > self.max_model_len:

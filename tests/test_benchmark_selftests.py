@@ -3,7 +3,10 @@
 BENCHMARK.json rules (`test_pure`), the knees and the stall line
 (`test_knees_and_stall`), and what the granite-4.0-h-small cell stands on
 (`test_ssm_g1_metrics`: its three readers; `test_cell_granite_hybrid_cpu`:
-the cell's rehearsal on the CPU at `tiny`).
+the cell's rehearsal on the CPU at `tiny`), and what PR 49 added to the
+benchmark (`test_ssm_kernel_metric`: the reader of the kernel's counter;
+`test_ssm_step_labels`: both families' readers on the labels of a decode
+program whose recurrence is the `ssm_step` custom call).
 
 Each test of those files is collected here under its own name, so that it
 counts, and runs, as one test: the functions are the files' own (marks and
@@ -12,7 +15,8 @@ parametrisation with them), and the fixtures they ask for come along."""
 import importlib
 
 MODULES = ("test_pure", "test_knees_and_stall", "test_ssm_g1_metrics",
-           "test_cell_granite_hybrid_cpu")
+           "test_cell_granite_hybrid_cpu", "test_ssm_kernel_metric",
+           "test_ssm_step_labels")
 
 
 def _is_fixture(obj) -> bool:

@@ -76,6 +76,28 @@ def test_paged_attention_lowers_for_tpu(shape, width):
         q, own, own, own_valid, pool, pool, tables, ctx, layer)
 
 
+# (heads, groups): granite_hybrid's mixer and nemotron_h's, at their widths
+SSM_SHAPES = {"one-group": (128, 1), "eight-groups": (64, 8)}
+
+
+@pytest.mark.parametrize("shape", sorted(SSM_SHAPES))
+def test_ssm_step_lowers_for_tpu(shape):
+    from ray_tpu.ops.ssm_step import ssm_step
+
+    H, G = SSM_SHAPES[shape]
+    layers, slots, P, N = 2, 8, 64, 128
+    f32 = jnp.float32
+    text = _lowers_for_tpu(
+        lambda buf, decay, xdt, B, C: ssm_step(buf, 1, decay, xdt, B, C),
+        jax.ShapeDtypeStruct((layers, slots, H, P, N), f32),
+        jax.ShapeDtypeStruct((slots, H), f32),
+        jax.ShapeDtypeStruct((slots, H, P), f32),
+        jax.ShapeDtypeStruct((slots, G, N), f32),
+        jax.ShapeDtypeStruct((slots, G, N), f32))
+    # the state buffer is the kernel's first result, in place
+    assert "output_tuple_indices = [0], operand_index = 5" in text
+
+
 def test_paged_attention_layer_of_whole_pool_matches_reference():
     """The models hand the kernel the whole pool, as its KVLayout shapes
     it, and a traced layer index (slicing the pool per layer, or

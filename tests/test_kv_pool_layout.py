@@ -509,7 +509,8 @@ _SOFTMAX_CARRY = re.compile(
     ("decode", False), ("decode", True), ("verify-5", True)])
 def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
                                           paged, monkeypatch):
-    """No `copy` of the pool's size, or of a part of the recurrent state's,
+    """No `copy` of the pool's size, or of a part of the recurrent state's
+    (aliased through the `ssm_step` kernels of a decode program or not),
     in any program, and no `convert` of a weight: gpt2-large's resident
     tree holds what the forwards cast (embeddings, kernels, biases) in bf16
     and the layer norms in float32, OLMoE's (16 KV heads of 128, 8 layers,
@@ -614,6 +615,16 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
     if program == "decode" and not paged:
         assert not _full_width_contexts(text, runner, [
             *jax.tree.leaves(pool), *params_and_state(params, state)])
+    if program == "decode" and "ssm" in state:
+        # the one-step recurrence passes over a layer's state once: one
+        # `ssm_step` kernel a Mamba layer, its FIRST result the state
+        # buffer it is given (the readers of benchmark/ssm_ops.py and
+        # ssm_g1_ops.py tell a state update by that), and nothing else of
+        # the program reads the buffer (a read-out beside an in-place
+        # update was a fusion of its own over a whole layer of it: PR 49)
+        steps, others = _state_steps(text, state["ssm"])
+        assert len(steps) == runner.state_layout.layers, steps
+        assert not others, others
     if program in ("prefill", "chunk-256"):
         # a prompt's and a chunk's rows are stored a page at a time: each
         # pool's scatter has one update a layer and PAGE of the program's
@@ -626,6 +637,30 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
         for shape, n, window in updates:
             assert n == shape[0] * rows // 16
             assert window == (16, shape[-1])
+
+
+def _state_steps(text, ssm):
+    """(the result types of the `ssm_step` kernels that take the SSM
+    state buffer `ssm` and give it back first, every other operation of
+    the entry computation that takes the buffer) in a compiled program;
+    the buffer handed on (a tuple's element, the program's result) does
+    not read it."""
+    shape = "f32[" + ",".join(map(str, ssm.shape)) + "]"
+    entry = text[text.index("\nENTRY "):]
+    held, steps, others = set(), [], []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\((.*)$",
+            entry, re.M):
+        name, result, opcode, rest = m.groups()
+        reads = held & set(re.findall(r"%([\w.-]+)", rest.split("), ")[0]))
+        if shape in result:
+            held.add(name)
+        if opcode == "custom-call" and "ssm_step" in name and reads:
+            assert result.startswith("(" + shape), result
+            steps.append(result)
+        elif reads and opcode not in ("get-tuple-element", "tuple"):
+            others.append((name, opcode))
+    return steps, others
 
 
 def _pool_scatters(text, pools):
