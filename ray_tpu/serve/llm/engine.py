@@ -1471,8 +1471,8 @@ class LLMEngine:
         """Emit the request's waterfall as child spans: one parent
         `llm.request` over [enqueue, close] plus one child per nonzero
         phase, laid out contiguously in waterfall order (phases
-        interleave in real time — chunked prefill alternates with
-        decode — so the contiguous layout is the readable summary, and
+        interleave in real time — a round of prefill chunks, then a
+        decode step — so the contiguous layout is the readable summary, and
         the durations are the exact per-phase totals). All hang off the
         request's propagated trace context."""
         from ray_tpu.utils.events import child_trace

@@ -17,9 +17,18 @@ Policy, chosen per step by `schedule()`:
   skipped entirely; only the remaining pages are allocated and only the
   remaining tokens are prefilled;
 - an admitted sequence prefills its (unmatched) prompt in page-aligned
-  **chunks** of at most `chunk_size` tokens. Continuation chunks
-  alternate with decode steps, so one long prompt stalls the decode
-  batch by at most one chunk's latency instead of its whole prefill;
+  **chunks** of at most `chunk_size` tokens. Continuation chunks and
+  decode steps go in **rounds**: a decode step counts the lanes still
+  prefilling, and that many chunks (the oldest such lane's first, an
+  admission's first chunk among them) run before the next decode step,
+  so every lane that holds a slot moves once a round: a chunk if it is
+  prefilling, a token if it is decoding. One lane prefilling is one
+  chunk, then one decode step. A decoding lane thus waits for at most
+  one chunk of each lane that holds a slot (fewer than `max_batch_size`)
+  between two of its tokens, whatever the prompts' lengths, and one long
+  prompt stalls the batch by one chunk a round, not by its whole
+  prefill. Admissions' first chunks run back to back with no bound of
+  their own: a free lane is filled at once;
 - otherwise **decode** every fully-prefilled sequence in one batched
   step; before it, any lane crossing a page boundary gets one new page;
   if the pool is dry, the **most recently admitted** lane is preempted
@@ -280,7 +289,18 @@ class Scheduler:
         self.preemption_count = 0
         self.prefix_hit_pages = 0
         self.prefix_miss_pages = 0
-        self._last_was_prefill = False
+        # continuation chunks this round may still issue before its decode
+        # step: the lanes that were prefilling when the last one was planned,
+        # less the chunks issued since (under 0 where more were)
+        self._chunks_due = 0
+        self._round_chunks = 0  # continuation chunks since that step
+        # what the rounds came to (`depth()`): decode steps planned and the
+        # ready lanes summed over them, continuation chunks, and the decode
+        # steps that more than one continuation chunk preceded
+        self.decode_steps = 0
+        self.decode_lanes = 0
+        self.continuation_chunks = 0
+        self.multi_chunk_rounds = 0
         # sequences retired INSIDE schedule() (length cap backstop,
         # cache_exhausted fail-loud) — the engine drains these every
         # step so their streams still get closed
@@ -322,25 +342,36 @@ class Scheduler:
             self._release_slot(seq)
         work = self._try_admit()
         if work is not None:
-            self._last_was_prefill = True
+            self._chunks_due -= 1  # the newcomer's chunk of this round
             return work
         pending = [s for s in self.running if s.prefill_pending]
         ready = [s for s in self.running if not s.prefill_pending]
-        # a continuation chunk; alternates with decode when both kinds of
-        # work exist so a long prompt can't monopolize steps, and goes on
-        # alone while nothing is decodable yet
-        if pending and not (self._last_was_prefill and ready):
-            self._last_was_prefill = True
+        # a continuation chunk, the oldest prefilling lane's: as many a
+        # round as lanes were prefilling at its start, so that the decode
+        # step behind them takes every lane they made ready and a long
+        # prompt can't monopolize steps; they go on alone while nothing
+        # is decodable yet
+        if pending and (self._chunks_due > 0 or not ready):
             work = self._next_chunk(pending[0], may_preempt)
-            # None: the chunk's own lane was preempted to make room
-            return work or self.schedule(may_preempt)
+            if work is None:  # the chunk's own lane was preempted for room
+                return self.schedule(may_preempt)
+            self._chunks_due -= 1
+            self._round_chunks += 1
+            self.continuation_chunks += 1
+            return work
         if not ready:
             return None
-        self._last_was_prefill = False
         self._grow_tables_or_preempt(may_preempt)
         ready = [s for s in self.running if not s.prefill_pending]
         if not ready:
             return None
+        # the round ends here and the next begins: a chunk for each lane
+        # still prefilling, then the next decode step
+        self.decode_steps += 1
+        self.decode_lanes += len(ready)
+        self.multi_chunk_rounds += self._round_chunks > 1
+        self._chunks_due = len(self.running) - len(ready)
+        self._round_chunks = 0
         if not self._windows:  # tables that only grow: handed over as they are
             return DecodeWork(ready, [s.tables for s in ready])
         return DecodeWork(ready, [self._tables_now(s) for s in ready])
@@ -638,4 +669,9 @@ class Scheduler:
             "prefix_hit_pages": self.prefix_hit_pages,
             "prefix_miss_pages": self.prefix_miss_pages,
             "prefix_evictions": ps["evictions"],
+            # the rounds (see __init__)
+            "decode_steps": self.decode_steps,
+            "decode_lanes": self.decode_lanes,
+            "continuation_chunks": self.continuation_chunks,
+            "multi_chunk_rounds": self.multi_chunk_rounds,
         }

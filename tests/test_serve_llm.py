@@ -9,6 +9,8 @@ cache layout, rope positions, or masking shows up here first.
 """
 
 import dataclasses
+import math
+import random
 import sys
 import threading
 
@@ -332,6 +334,169 @@ def test_scheduler_chunks_interleave_with_decode():
     sched.commit_token(s2, 45)
     w = sched.schedule()  # both lanes decode together now
     assert isinstance(w, DecodeWork) and w.seqs == [s1, s2]
+
+
+def _long_seq(i, n_prompt, max_tokens=8):
+    """A prompt of its own token ids: no page is matched and skipped."""
+    return Sequence(seq_id=i, prompt=list(range(1000 * i, 1000 * i + n_prompt)),
+                    sampling=SamplingParams(max_tokens=max_tokens))
+
+
+def _ready_lanes(sched, n):
+    """`n` lanes admitted by one chunk each and decode-ready."""
+    seqs = [_mk_seq(i, 4, max_tokens=64) for i in range(n)]
+    for s in seqs:
+        sched.add(s)
+        assert isinstance(sched.schedule(), PrefillWork)
+        sched.commit_token(s, 7)
+    return seqs
+
+
+def _shape(sched, work):
+    """A decision as ("chunk", seq_id, start) or ("decode", seq_ids)."""
+    if isinstance(work, PrefillWork):
+        if work.is_last:
+            sched.commit_token(work.seq, 7)
+        return ("chunk", work.seq.seq_id, work.start)
+    for s in work.seqs:
+        sched.commit_token(s, 7)
+    return ("decode", [s.seq_id for s in work.seqs])
+
+
+@pytest.mark.parametrize("chunks_a, chunks_b, expected", [
+    # two lanes prefilling beside two ready ones: two chunks, the oldest
+    # lane's first, then ONE decode step of every ready lane
+    (4, 4, [("chunk", 10, 8), ("chunk", 10, 16), ("decode", [0, 1]),
+            ("chunk", 10, 24), ("chunk", 11, 8), ("decode", [0, 1, 10]),
+            ("chunk", 11, 16), ("decode", [0, 1, 10]),
+            ("chunk", 11, 24), ("decode", [0, 1, 10, 11])]),
+    # the oldest ends inside the round: its second chunk is the next lane's
+    (2, 4, [("chunk", 10, 8), ("chunk", 11, 8), ("decode", [0, 1, 10]),
+            ("chunk", 11, 16), ("decode", [0, 1, 10]),
+            ("chunk", 11, 24), ("decode", [0, 1, 10, 11])]),
+])
+def test_scheduler_round_gives_each_prefilling_lane_a_chunk(
+        chunks_a, chunks_b, expected):
+    pool = BlockPool(num_blocks=256, block_size=4)
+    sched = Scheduler(pool, max_batch_size=8, max_model_len=128,
+                      chunk_size=8)
+    _ready_lanes(sched, 2)
+    sched.add(_long_seq(10, 8 * chunks_a, max_tokens=64))
+    sched.add(_long_seq(11, 8 * chunks_b, max_tokens=64))
+    # admissions first, their first chunks back to back, then the decode
+    # step that opens the round with two lanes prefilling
+    assert [_shape(sched, sched.schedule()) for _ in range(3)] == [
+        ("chunk", 10, 0), ("chunk", 11, 0), ("decode", [0, 1])]
+    assert [_shape(sched, sched.schedule())
+            for _ in expected] == expected
+    d = sched.depth()
+    assert d["multi_chunk_rounds"] == sum(  # a chunk behind a chunk
+        a[0] == b[0] == "chunk" for a, b in zip(expected, expected[1:]))
+    assert d["continuation_chunks"] == sum(
+        e[0] == "chunk" for e in expected)
+
+
+@pytest.mark.parametrize("newcomer", [None, 4, 16])
+def test_scheduler_one_lane_prefilling_alternates_as_before(newcomer):
+    """With at most one lane prefilling a round is one chunk and one
+    decode step, an admission's first chunk being the round's chunk:
+    the alternation the scheduler always had, decision for decision."""
+    pool = BlockPool(num_blocks=256, block_size=4)
+    sched = Scheduler(pool, max_batch_size=8, max_model_len=128,
+                      chunk_size=8)
+    _ready_lanes(sched, 2)
+    sched.add(_long_seq(10, 32, max_tokens=64))
+    got = [_shape(sched, sched.schedule()) for _ in range(4)]
+    assert got == [("chunk", 10, 0), ("decode", [0, 1]),
+                   ("chunk", 10, 8), ("decode", [0, 1])]
+    if newcomer is None:
+        expected = [("chunk", 10, 16), ("decode", [0, 1]),
+                    ("chunk", 10, 24), ("decode", [0, 1, 10])]
+    elif newcomer <= 8:
+        # a newcomer of one chunk takes the round's chunk: the lane
+        # prefilling waits a round, a decoding lane for one chunk
+        sched.add(_long_seq(11, newcomer, max_tokens=64))
+        expected = [("chunk", 11, 0), ("decode", [0, 1, 11]),
+                    ("chunk", 10, 16), ("decode", [0, 1, 11]),
+                    ("chunk", 10, 24), ("decode", [0, 1, 10, 11])]
+    else:
+        # a second lane prefilling: from the next decode step on, two
+        # chunks a round
+        sched.add(_long_seq(11, newcomer, max_tokens=64))
+        expected = [("chunk", 11, 0), ("decode", [0, 1]),
+                    ("chunk", 10, 16), ("chunk", 10, 24),
+                    ("decode", [0, 1, 10]),
+                    ("chunk", 11, 8), ("decode", [0, 1, 10, 11])]
+    assert [_shape(sched, sched.schedule()) for _ in expected] == expected
+    assert sched.depth()["multi_chunk_rounds"] == (newcomer == 16)
+
+
+@pytest.fixture(scope="module")
+def saturated_rounds():
+    """A seeded closed loop through `schedule()` / `commit_token` alone:
+    128 callers on 64 lanes, prompts of 1 to 32 chunks (lognormal, mean
+    10.9, the lfm2 cell's), 256 output tokens each. Every decision is
+    written down with the lanes that were prefilling when it was made."""
+    rng = random.Random(50)
+    lanes, chunk = 64, 4
+    pool = BlockPool(num_blocks=lanes * 100 + 1, block_size=4,
+                     enable_prefix_cache=False)
+    sched = Scheduler(pool, max_batch_size=lanes, max_model_len=400,
+                      chunk_size=chunk)
+    chunks_drawn = []
+
+    def call():
+        n = min(32, max(1, math.ceil(rng.lognormvariate(math.log(8), 0.8))))
+        chunks_drawn.append(n)
+        sched.add(Sequence(seq_id=len(chunks_drawn), prompt=[1] * (n * chunk),
+                           sampling=SamplingParams(max_tokens=256)))
+
+    for _ in range(128):
+        call()
+    rounds = []  # a decode step: (continuation chunks before it, lanes
+    # prefilling at the step before it, its ready lanes)
+    since, prefilling = 0, None
+    while len(rounds) < 1536:
+        work = sched.schedule()
+        if isinstance(work, PrefillWork):
+            since += work.start > 0
+            done = [work.seq] if work.is_last else []
+        else:
+            rounds.append((since, prefilling, len(work.seqs)))
+            since = 0
+            prefilling = sum(s.prefill_pending for s in sched.running)
+            done = list(work.seqs)
+        for s in done:
+            if sched.commit_token(s, 7):
+                call()
+    return sched, rounds, chunks_drawn
+
+
+def test_saturated_rounds_never_pass_the_lanes_prefilling(saturated_rounds):
+    _, rounds, chunks_drawn = saturated_rounds
+    assert 10.4 < sum(chunks_drawn) / len(chunks_drawn) < 11.4
+    # before the first decode step nothing decodes and chunks go alone
+    for since, prefilling, _ in rounds[1:]:
+        assert since <= prefilling
+    assert max(since for since, _, _ in rounds[1:]) > 1
+
+
+def test_saturated_rounds_keep_the_lanes_decoding(saturated_rounds):
+    sched, rounds, _ = saturated_rounds
+    steady = [ready for _, _, ready in rounds[512:]]  # two answers in
+    assert sum(steady) / len(steady) >= 0.85 * sched.max_batch_size
+    assert sched.preemption_count == 0
+    assert len(sched.running) == sched.max_batch_size
+
+
+def test_scheduler_depth_counts_the_rounds(saturated_rounds):
+    sched, rounds, _ = saturated_rounds
+    d = sched.depth()
+    assert d["decode_steps"] == len(rounds)
+    assert d["decode_lanes"] == sum(ready for _, _, ready in rounds)
+    assert d["continuation_chunks"] == sum(n for n, _, _ in rounds)
+    assert d["multi_chunk_rounds"] == sum(n > 1 for n, _, _ in rounds)
+    assert 0 < d["multi_chunk_rounds"] < d["decode_steps"]
 
 
 # ------------------------------------------------------------ engine level
