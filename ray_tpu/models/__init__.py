@@ -1,5 +1,9 @@
 """Model zoo for the TPU-native framework (pure-JAX, mesh-shardable):
-GPT-2, Llama-family (RoPE/RMSNorm/SwiGLU/GQA), MoE layer."""
+GPT-2, Llama-family (RoPE/RMSNorm/SwiGLU/GQA), MoE layer. Eight families
+are served (`serve/llm/runner.py` `adapters()`: gpt2, llama, nemotron_h,
+mimo_v2, glm_dsa, lfm2, granite_hybrid, xing4); the six others are
+imported where they are used, and `mamba2.py` and `mla.py` hold what two
+of them share."""
 
 from ray_tpu.models.gpt2 import (
     GPT2Config,

@@ -5,7 +5,9 @@ routed experts beside a shared one.
 Fifth model family beside gpt2, llama, nemotron_h and mimo_v2, after
 Z.ai's GLM-5 (`model_type` glm_moe_dsa; its keys are DeepSeek-V3.2's).
 Every block is ``x = x + attn(rmsnorm(x)); x = x + ffn(rmsnorm(x))`` and
-every block's attention is the same:
+every block's attention is the same (its latent-attention parts and
+the feed-forwards are written in models/mla.py, which the xing4 family
+runs too; the indexer and the two ways through the attention are here):
 
 - **queries**: ``c_q = rmsnorm(h W_qa)`` (`q_lora_rank`), ``q = c_q W_qb``
   -> `num_attention_heads` heads of ``qk_nope_head_dim | qk_rope_head_dim``;
@@ -56,7 +58,13 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.moe import routed_experts
+from ray_tpu.models import mla
+from ray_tpu.models.mla import (
+    dense as _dense,
+    experts as _experts,
+    rmsnorm as _rmsnorm,
+    swiglu as _swiglu,  # noqa: F401 - the tests' name for it
+)
 from ray_tpu.ops.context_attention import (
     attend_selected,
     causal_rows,
@@ -135,6 +143,13 @@ class GlmDsaConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        return 1.0 / math.sqrt(self.qk_head_dim)
+
+    def rotate(self, x, positions):
+        return _rope(x, positions, self.rope_theta, self.qk_rope_head_dim)
 
     @property
     def latent_row(self) -> int:
@@ -294,13 +309,8 @@ def init_glm_dsa(key: jax.Array, cfg: GlmDsaConfig) -> Params:
 
 
 # --------------------------------------------------------------------------
-# the layer's parts, each written once
-
-
-def _rmsnorm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * rms * scale.astype(jnp.float32)).astype(x.dtype)
+# the layer's parts: latent attention's and the feed-forwards' are
+# models/mla.py's, which the xing4 family runs too; the indexer's are here
 
 
 def _layernorm(x, scale, bias, eps):
@@ -312,50 +322,7 @@ def _layernorm(x, scale, bias, eps):
 
 
 def _rope(x, positions, theta: float, width: int):
-    """The first `width` lanes of x (*positions.shape, [heads,] D) rotated
-    by `positions`, interleaved pairs ``(2i, 2i + 1)``; the other lanes
-    as they are."""
-    half = width // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions.astype(jnp.float32)[..., None] * freqs
-    if x.ndim == positions.ndim + 2:  # a heads dimension
-        angles = angles[..., None, :]
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    pairs = x[..., :width].astype(jnp.float32).reshape(
-        *x.shape[:-1], half, 2)
-    x1, x2 = pairs[..., 0], pairs[..., 1]
-    turned = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return jnp.concatenate(
-        [turned.reshape(*x.shape[:-1], width).astype(x.dtype),
-         x[..., width:]], axis=-1)
-
-
-def _queries(h, p, positions, cfg: GlmDsaConfig):
-    """Normed rows h (..., D) -> (q_nope (..., H, nope), q_pe (..., H,
-    rope) rotated, the normed query latent c_q (..., q_lora_rank))."""
-    dt = cfg.dtype
-    with jax.named_scope("attn.mla.q"):
-        c_q = _rmsnorm(h @ p["wq_a"].astype(dt), p["q_norm"],
-                       cfg.rms_norm_eps)
-        q = (c_q @ p["wq_b"].astype(dt)).reshape(
-            *h.shape[:-1], cfg.num_attention_heads, cfg.qk_head_dim)
-        q_pe = _rope(q[..., cfg.qk_nope_head_dim:], positions,
-                     cfg.rope_theta, cfg.qk_rope_head_dim)
-    return q[..., :cfg.qk_nope_head_dim], q_pe, c_q
-
-
-def _latent(h, p, positions, cfg: GlmDsaConfig):
-    """Normed rows h (..., D) -> their latent rows (..., latent_row):
-    ``[rmsnorm(c_kv) | k_pe rotated | zeros]``, what the pool holds."""
-    R = cfg.kv_lora_rank
-    with jax.named_scope("attn.mla.kv"):
-        ckv = h @ p["wkv_a"].astype(cfg.dtype)
-        return jnp.concatenate(
-            [_rmsnorm(ckv[..., :R], p["kv_norm"], cfg.rms_norm_eps),
-             _rope(ckv[..., R:], positions, cfg.rope_theta,
-                   cfg.qk_rope_head_dim),
-             jnp.zeros(ckv.shape[:-1] + (cfg.latent_pad,), ckv.dtype)],
-            axis=-1)
+    return mla.rope(x, positions, mla.plain_frequencies(theta, width), width)
 
 
 def _indexer(h, c_q, p, positions, cfg: GlmDsaConfig):
@@ -377,17 +344,9 @@ def _indexer(h, c_q, p, positions, cfg: GlmDsaConfig):
 def _projections(h, p, positions, cfg: GlmDsaConfig):
     """What both attention paths start from: (q_nope, q_pe, the latent
     rows, q^I, k^I, w)."""
-    q_nope, q_pe, c_q = _queries(h, p, positions, cfg)
-    return (q_nope, q_pe, _latent(h, p, positions, cfg),
+    q_nope, q_pe, c_q = mla.queries(h, p, positions, cfg)
+    return (q_nope, q_pe, mla.latent(h, p, positions, cfg),
             *_indexer(h, c_q, p, positions, cfg))
-
-
-def _output(att, p, cfg: GlmDsaConfig):
-    """att (B, T, H, vd) -> (B, T, D)."""
-    with jax.named_scope("attn.mla.out"):
-        B, T = att.shape[:2]
-        return att.astype(cfg.dtype).reshape(B, T, -1) \
-            @ p["wo"].astype(cfg.dtype)
 
 
 def _attend_rows(h, p, positions, seen, cfg: GlmDsaConfig):
@@ -395,23 +354,17 @@ def _attend_rows(h, p, positions, seen, cfg: GlmDsaConfig):
     rows up-projected to a K and a V head each, the indexer's choice
     among the rows `seen` (B, T, T) allows as the softmax's mask. ->
     (out (B, T, D), latent rows, indexer keys)."""
-    dt = cfg.dtype
     q_nope, q_pe, latent, qi, ki, w = _projections(h, p, positions, cfg)
     with jax.named_scope("attn.index.score"):
         scores = index_scores(qi, ki, w, seen)
     with jax.named_scope("attn.index.topk"):
         chosen = select_mask(scores, cfg.index_topk)
     with jax.named_scope("attn.mla.core"):
-        c_kv = latent[..., :cfg.kv_lora_rank]
-        k_pe = latent[..., cfg.kv_lora_rank:][..., :cfg.qk_rope_head_dim]
-        k_nope = jnp.einsum("bsr,rhd->bshd", c_kv, p["wk_b"].astype(dt))
-        v = jnp.einsum("bsr,rhd->bshd", c_kv, p["wv_b"].astype(dt))
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_pe[:, :, None], q_pe.shape)], -1)
+        k, v = mla.up_project(latent, q_pe, p, cfg)
         q = jnp.concatenate([q_nope, q_pe], axis=-1)
         att = softmax_over(q[:, :, :, None], [(k, v, chosen)],
-                           1.0 / math.sqrt(cfg.qk_head_dim), dt)[:, :, :, 0]
-    return _output(att, p, cfg), latent, ki
+                           cfg.softmax_scale, cfg.dtype)[:, :, :, 0]
+    return mla.output(att, p, cfg), latent, ki
 
 
 def _attend_latent(h, p, positions, own_valid, ctx, layer,
@@ -420,48 +373,14 @@ def _attend_latent(h, p, positions, own_valid, ctx, layer,
     cached latent rows and their own, absorbed: every head's query on the
     one latent row, ``W_kvb[v]`` after the softmax. -> (out (B, T, D),
     latent rows, indexer keys[, the rows' choice, `attend_selected`'s])."""
-    dt = cfg.dtype
     q_nope, q_pe, latent, qi, ki, w = _projections(h, p, positions, cfg)
-    with jax.named_scope("attn.mla.q"):
-        q = jnp.concatenate(
-            [jnp.einsum("bthd,rhd->bthr", q_nope, p["wk_b"].astype(dt)),
-             q_pe, jnp.zeros(q_pe.shape[:-1] + (cfg.latent_pad,), dt)],
-            axis=-1)
+    q = mla.absorbed_query(q_nope, q_pe, p, cfg)
     att = attend_selected(
-        q, latent, qi, ki, w, own_valid, ctx, layer, dt,
-        values=cfg.kv_lora_rank, scale=1.0 / math.sqrt(cfg.qk_head_dim),
+        q, latent, qi, ki, w, own_valid, ctx, layer, cfg.dtype,
+        values=cfg.kv_lora_rank, scale=cfg.softmax_scale,
         with_choice=with_choice)
     att, *choice = att if with_choice else (att,)
-    with jax.named_scope("attn.mla.out"):
-        att = jnp.einsum("bthr,rhd->bthd", att, p["wv_b"].astype(dt))
-    return (_output(att, p, cfg), latent, ki, *choice)
-
-
-def _swiglu(h, gate, up, down, dt):
-    return (jax.nn.silu(h @ gate.astype(dt)) * (h @ up.astype(dt))) \
-        @ down.astype(dt)
-
-
-def _dense(h, p, cfg: GlmDsaConfig):
-    with jax.named_scope("ffn.dense"):
-        return _swiglu(h, p["w_gate"], p["w_up"], p["w_down"], cfg.dtype)
-
-
-def _experts(h, p, cfg: GlmDsaConfig):
-    """Normed rows h (N, D) -> (the held experts' part of the routed sum
-    plus the shared expert, pairs per expert over ALL experts)."""
-    dt = cfg.dtype
-    wg, wu, wd = (p[n].astype(dt) for n in ("we_gate", "we_up", "we_down"))
-    y, counts, _ = routed_experts(
-        h, p["router"],
-        lambda a, mm: mm(jax.nn.silu(mm(a, wg)) * mm(a, wu), wd),
-        k=cfg.num_experts_per_tok, norm_topk=cfg.norm_topk_prob,
-        score="sigmoid", select_bias=p["router_bias"],
-        scale=cfg.routed_scaling_factor,
-        held=(cfg.expert_offset, cfg.experts_held),
-        shared=lambda a: _swiglu(a, p["ws_gate"], p["ws_up"], p["ws_down"],
-                                 dt))
-    return y, counts
+    return (mla.values_out(att, p, cfg), latent, ki, *choice)
 
 
 def _stack(params, x, cfg: GlmDsaConfig, attention):

@@ -56,6 +56,10 @@ XLA gather of the chosen rows alone was measured for both; it costs a
 chunk 15 times the fold, and a decode step more than the fold below a
 mean context of about 6k slots a lane. Reading the chosen rows only is
 a kernel's to do).
+
+A latent kind with NO indexer (`KVLayout.v_head_dim` 0: one pool) reads
+every cached slot of a lane (`attend_latent`): the same fold of the
+latent tiles by the same groups, the mask a lane's length.
 """
 
 from __future__ import annotations
@@ -428,26 +432,62 @@ def attend_selected(q, latent, qi, ki, w, own_valid, ctx: CachedContext,
     return (att, chosen) if with_choice else att
 
 
+def attend_latent(q, latent, own_valid, ctx: CachedContext, layer, dtype, *,
+                  values: int, scale: float):
+    """Absorbed latent attention over EVERY cached slot (a latent kind
+    with no indexer, `KVLayout.v_head_dim` 0). q (B, T, H, row) are the
+    heads' queries on a latent row, `latent` (B, T, row) the program's own
+    rows, whose first `values` lanes are also the values. Row t of lane b
+    attends under one softmax (scores times `scale`) to its lane's cached
+    slots ``[0, ctx.lengths[b])`` and the own rows `own_valid` (B, T, T)
+    allows -> (B, T, H, values) in `dtype`: the lanes' latent tiles folded
+    as far as their groups reach, each lane to its own length, one row
+    for all heads."""
+    q = q[:, :, None]  # (B, T, 1, H, row): one KV head, H query heads
+    own = latent[:, :, None]  # (B, T, 1, row)
+    tile = ctx.layout.tile_pages * ctx.layout.block_size
+
+    def below_length(rows, t):
+        slot = t * tile + jnp.arange(tile)
+        return jnp.broadcast_to(
+            slot[None, None, :] < ctx.lengths[:rows, None, None],
+            (rows, q.shape[1], tile))
+
+    return _fold_latent(q, own, lambda: own_valid, below_length, ctx, layer,
+                        dtype, values, scale)
+
+
 def _fold_chosen(q, own, chosen, ctx, layer, dtype, values: int, scale):
-    """The lanes' latent tiles folded under the choice as their mask, by
-    `attend_cached`'s loops: tile t read once for the rows of every group
-    that reaches it. q (B, T, 1, H, row), own (B, T, 1, row), chosen (B,
-    T, cached slots + T) -> (B, T, H, values)."""
-    lay, B, G = ctx.layout, q.shape[0], ctx.group
+    """The lanes' latent tiles folded under the choice as their mask. q
+    (B, T, 1, H, row), own (B, T, 1, row), chosen (B, T, cached slots +
+    T) -> (B, T, H, values)."""
     n_cached = chosen.shape[-1] - q.shape[1]
+    tile = ctx.layout.tile_pages * ctx.layout.block_size
     # a row whose own rows were all passed over starts from weights that
     # the first chosen cached slot scales to nothing (`_fold`)
-    carry = _own_rows(q, own, own[..., :values], chosen[..., n_cached:],
-                      scale, dtype)
+    return _fold_latent(
+        q, own, lambda: chosen[..., n_cached:],
+        lambda rows, t: jax.lax.dynamic_slice_in_dim(
+            chosen[:rows], t * tile, tile, axis=2),
+        ctx, layer, dtype, values, scale)
+
+
+def _fold_latent(q, own, own_valid, tile_valid, ctx, layer, dtype,
+                 values: int, scale):
+    """The lanes' latent tiles folded into a softmax started on the own
+    rows, by `attend_cached`'s loops: tile t read once for the rows of
+    every group that reaches it, ``tile_valid(rows, t)`` (rows, T, tile)
+    its mask for the program's first `rows` rows, ``own_valid()`` (B, T,
+    T) the own rows'."""
+    lay, B, G = ctx.layout, q.shape[0], ctx.group
+    carry = _own_rows(q, own, own[..., :values], own_valid(), scale, dtype)
     per = lay.tile_pages
-    tile = per * lay.block_size
 
     def tiles_of(rows):
         def step(t, carry):
             latent = lay.read(ctx.k_pages, layer, jax.lax.dynamic_slice_in_dim(
                 ctx.tables[:rows], t * per, per, axis=1))
-            valid = jax.lax.dynamic_slice_in_dim(chosen[:rows], t * tile,
-                                                 tile, axis=2)
+            valid = tile_valid(rows, t)
             return _fold(carry, _scores(q[:rows], latent, valid, scale),
                          latent[..., :values], dtype)
         return step

@@ -9,7 +9,9 @@ V row widths and, for a window kind, the window. A **latent** kind
 (`KVKind.select`: latent attention under a learned indexer) keeps no
 values: a token's first row is its latent row, key and value of every
 head at once, and its second row, in the pool where another kind keeps V,
-is the indexer's key, both under one block table and one page count.
+is the indexer's key, both under one block table and one page count; a
+latent kind with no indexer (`v_head_dim` 0: every earlier slot is read)
+has the first row alone, one pool a layer.
 This module owns the
 *bookkeeping* (`BlockPool`: which physical pages are free, which pages
 hold which token content; `KVPools`: a model's pools, one a kind, and
@@ -92,6 +94,11 @@ from jax.sharding import NamedSharding, PartitionSpec
 # (1.5 us a step on the v5e, and an event each in a device trace) beside
 # the tile's own bytes, so a deeper stack takes larger tiles.
 TILE_ELEMENTS_A_LAYER = 32 * 1024
+# Slots in one tile of a latent kind that is read whole (no indexer): a
+# decode step's loop step then reads 1.3 MB a lane at a 640-lane row,
+# against some 30 us of small operations a step, and a chunk's 256 rows
+# fold 19 GFLOP a step (serve/llm/cache.py `tile_pages`; PERF.md, PR 51)
+DENSE_LATENT_TILE_SLOTS = 1024
 
 
 class CacheExhausted(Exception):
@@ -138,7 +145,9 @@ class KVKind(NamedTuple):
     (None). `select` makes it a latent kind: one head, `head_dim` the
     latent row's lanes, `v_head_dim` the indexer key's, and a row sees of
     the earlier rows and itself only the `select` its indexer scores
-    highest."""
+    highest. A `v_head_dim` of 0 is a latent kind with NO indexer: one
+    pool a layer (the second is empty), the values the leading lanes of
+    the one row, and a row sees every earlier row."""
 
     name: str
     layers: int
@@ -147,6 +156,10 @@ class KVKind(NamedTuple):
     v_head_dim: int
     window: int | None = None
     select: int | None = None
+
+    @property
+    def latent(self) -> bool:
+        return self.select is not None or self.v_head_dim == 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,7 +188,9 @@ class KVLayout:
     + 64 lanes and 64 of padding, which the family adds: 4.5 lane tiles
     make XLA:TPU copy the pool around every program, 5 do not), the second
     the indexer's keys (128 lanes). No values are stored: the attention
-    takes them from the latent row.
+    takes them from the latent row. A latent kind without an indexer
+    (`v_head_dim` 0) has the first pool alone: the second has no lanes,
+    takes no memory, and what is stored in it is nothing.
     """
 
     kv_layers: int
@@ -183,7 +198,7 @@ class KVLayout:
     block_size: int
     n_kv_head: int
     head_dim: int
-    v_head_dim: int | None = None  # None: as wide as a K head
+    v_head_dim: int | None = None  # None: as wide as a K head; 0: no V row
     window: int | None = None  # None: a row sees every earlier row
     select: int | None = None  # a latent kind: the slots a row's indexer picks
 
@@ -200,7 +215,14 @@ class KVLayout:
 
     @property
     def v_row(self) -> int:
-        return self.n_kv_head * (self.v_head_dim or self.head_dim)
+        return self.n_kv_head * (self.head_dim if self.v_head_dim is None
+                                 else self.v_head_dim)
+
+    @property
+    def latent(self) -> bool:
+        """A kind whose first row is key and value of every query head:
+        under an indexer (`select`) or read whole (`v_head_dim` 0)."""
+        return self.select is not None or self.v_head_dim == 0
 
     @property
     def shape(self) -> tuple[int, int, int, int]:
@@ -220,7 +242,13 @@ class KVLayout:
         nemotron_h cut's 2 layers of 256; 4 pages at mimo_v2's 2 full
         layers of 768). A latent kind's tile is sized by the indexer
         key's row, the one read to every lane's length (64 pages, 1,024
-        slots, at GLM-5's 5 layers of 128)."""
+        slots, at GLM-5's 5 layers of 128); without an indexer its tile
+        is `DENSE_LATENT_TILE_SLOTS` slots whatever the layers: every
+        query head reads the one row, so a tile's products are heads
+        times a full kind's for the same bytes."""
+        if self.latent and self.select is None:
+            pages = max(1, DENSE_LATENT_TILE_SLOTS // self.block_size)
+            return 1 << (pages.bit_length() - 1)
         row = self.row if self.select is None else self.v_row
         pages = max(1, TILE_ELEMENTS_A_LAYER * self.kv_layers
                     // (self.block_size * row))
@@ -245,7 +273,7 @@ class KVLayout:
 
     def token_bytes(self, dtype_bytes: int) -> dict:
         """Bytes a token takes in this kind's layers, by sort of row."""
-        k, v = ("k", "v") if self.select is None else ("latent", "index")
+        k, v = ("latent", "index") if self.latent else ("k", "v")
         return {k: self.kv_layers * self.row * dtype_bytes,
                 v: self.kv_layers * self.v_row * dtype_bytes}
 
@@ -735,6 +763,7 @@ class KVPools:
                 "pages_total": pool.usable_blocks,
                 "window": kind.window,
                 "select": kind.select,
+                "latent": kind.latent,
                 "released_behind_window": self.released[i],
                 "largest_table": self.largest_table[i],
                 "prefix_taken": self.prefix_taken if i == 0 else 0,

@@ -395,14 +395,15 @@ class LLMEngine:
                 f"layers, whose pages are given back behind the window as "
                 f"rows are planned, before a draft is accepted or "
                 f"rejected (ROADMAP.md, speculation with a window kind)")
-        if spec_cfg and any(kind.select is not None for kind in kinds):
+        if spec_cfg and any(kind.latent for kind in kinds):
             raise ValueError(
                 f"speculative decoding is not available for "
                 f"{config.model!r}: the family's rows choose the slots "
                 f"they attend to among the cached rows and the program's "
-                f"own, and the verify program stores a drafted run's rows "
-                f"before it knows which of them stay (ROADMAP.md, "
-                f"speculation with a latent kind)")
+                f"own (or, without an indexer, no verify program over a "
+                f"latent row has been written), and the verify program "
+                f"stores a drafted run's rows before it knows which of "
+                f"them stay (ROADMAP.md, speculation with a latent kind)")
         chunking = config.prefill_chunk_size > 0
         rows = chunk_rows(config.prefill_chunk_size, config.block_size,
                           max_len) if chunking else 0

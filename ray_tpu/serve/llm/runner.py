@@ -145,7 +145,9 @@ class ModelAdapter:
 
 
 def adapters() -> dict[str, ModelAdapter]:
-    """Model registry (lazy imports keep `import ray_tpu.serve` light)."""
+    """Model registry: the eight families (lazy imports keep `import
+    ray_tpu.serve` light; no family's module builds anything at
+    import)."""
     from ray_tpu.models import (
         glm_dsa,
         gpt2,
@@ -154,6 +156,7 @@ def adapters() -> dict[str, ModelAdapter]:
         llama,
         mimo_v2,
         nemotron_h,
+        xing4,
     )
 
     def one_kind(layers, heads):
@@ -283,6 +286,22 @@ def adapters() -> dict[str, ModelAdapter]:
             state_fn=lambda cfg: (cfg.n_ssm_layers, cfg.state_parts()),
             held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
         ),
+        "xing4": ModelAdapter(
+            name="xing4",
+            config_cls=xing4.Xing4Config,
+            presets={
+                "tiny": xing4.Xing4Config.tiny,
+                "xing4_29b_a4b_l6_ep4":
+                    xing4.Xing4Config.xing4_29b_a4b_l6_ep4,
+            },
+            init_fn=xing4.init_xing4,
+            prefill_fn=xing4.xing4_prefill_kv,
+            decode_fn=xing4.xing4_decode_kv,
+            chunk_fn=xing4.xing4_prefill_chunk_kv,
+            rules_fn=xing4.xing4_partition_rules,
+            kv_kinds=lambda cfg: tuple(KVKind(*k) for k in cfg.kv_kinds()),
+            held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
+        ),
     }
 
 
@@ -336,9 +355,17 @@ class Launched(NamedTuple):
 # (the lesser of `select` and the lane's length, a lane), and `slots_read`
 # the latent rows read: the same whole tiles, folded under the choice as
 # their mask. Only a family with such a kind counts the two.
+# A latent kind with NO indexer (`KVLayout.v_head_dim` 0) has every row
+# attend every cached slot of its lane: `rows` are the real rows the
+# programs ran (a decode step's lanes, a chunk's rows that are not
+# padding) and `row_slots` the cached slots they attended, a row at a
+# time (a lane's length a decode row, the chunk's offset a chunk row):
+# their quotient says how long the contexts were. Only a family with
+# such a kind counts the two.
 CONTEXT_KINDS = ("decode", "prefill", "verify")
 CONTEXT_COUNTS = ("slots_read", "slots_valid", "slots_reach", "slots_full")
 SELECT_COUNTS = ("slots_scored", "slots_selected")
+DENSE_LATENT_COUNTS = ("rows", "row_slots")
 
 
 def _by_kind(x) -> tuple:
@@ -522,7 +549,9 @@ class ModelRunner:
         self.fetched_bytes = 0
         self.expert_pairs: list[np.ndarray] = []
         counts = CONTEXT_COUNTS + SELECT_COUNTS * any(
-            lay.select is not None for lay in self.layouts)
+            lay.select is not None for lay in self.layouts) \
+            + DENSE_LATENT_COUNTS * any(
+                lay.latent and lay.select is None for lay in self.layouts)
         self.context_slots = {kind: dict.fromkeys(counts, 0)
                               for kind in CONTEXT_KINDS}
         # the same a kind of KV layer
@@ -600,13 +629,15 @@ class ModelRunner:
         return 1 if rows < 8 else max(2, rows // 8)
 
     def _note_context(self, kind: str, lengths, group: int = 1,
-                      rows: int = 1) -> None:
+                      rows: int = 1, real: int = 0) -> None:
         """Count what a program of `rows` rows a lane launched on lanes of
         `lengths` (as the program has them: ordered, padded) reads of
         their context, in every kind of KV layer, by the path the program
         takes there (`reads_by_kernel`): the kernel's whole pages to each
         lane's own length, and a launch counted in `kernel_steps`, or the
-        loops' whole tiles to each group's longest lane."""
+        loops' whole tiles to each group's longest lane. `real`: the rows
+        that are not padding (a decode step's lanes, of one row each; the
+        one lane's rows of a chunk)."""
         reach = int(np.sum(lengths))
         full = np.size(lengths) * self.max_blocks_per_seq * self.block_size
         total = self.context_slots[kind]
@@ -635,10 +666,14 @@ class ModelRunner:
                 read = np.size(lengths) * lay.window_pages * self.block_size
                 valid = int(np.sum(np.minimum(lengths, lay.window - 1)))
             by = self.context_by_kind[name][kind]
-            for what, n in zip(total, (read, valid, reach, full, scored,
-                                       selected)):
-                total[what] += n
-                by[what] += n
+            found = {"slots_read": read, "slots_valid": valid,
+                     "slots_reach": reach, "slots_full": full,
+                     "slots_scored": scored, "slots_selected": selected,
+                     "rows": real,
+                     "row_slots": reach * (real if rows > 1 else 1)}
+            for what in total:
+                total[what] += found[what]
+                by[what] += found[what]
 
     @staticmethod
     def _keep_sampled(slot_tokens, slots, nxt):
@@ -990,7 +1025,7 @@ class ModelRunner:
             temp = np.asarray([temperature], np.float32)
             topk = np.asarray([top_k], np.int32)
             topp = np.asarray([top_p], np.float32)
-            self._note_context("prefill", [start], rows=Tb)
+            self._note_context("prefill", [start], rows=Tb, real=n)
             self._step_counter += 1
         with self.phases.phase("dispatch"):
             before = tracing.jit_cache_size(self._chunk_jit)
@@ -1050,7 +1085,8 @@ class ModelRunner:
                 temps[i] = it.temperature
                 topks[i] = it.top_k
                 topps[i] = it.top_p
-            self._note_context("decode", poss, self.lanes_per_group(Sb))
+            self._note_context("decode", poss, self.lanes_per_group(Sb),
+                               real=S)
             for written in self.rows_written.values():
                 written["rowwise"] += S
             self._step_counter += 1

@@ -6,7 +6,10 @@ BENCHMARK.json rules (`test_pure`), the knees and the stall line
 the cell's rehearsal on the CPU at `tiny`), and what PR 49 added to the
 benchmark (`test_ssm_kernel_metric`: the reader of the kernel's counter;
 `test_ssm_step_labels`: both families' readers on the labels of a decode
-program whose recurrence is the `ssm_step` custom call).
+program whose recurrence is the `ssm_step` custom call), and PR 51's
+(`test_cell_xing4_cpu`: the xing4 cell's files, sizes and rehearsal on the
+CPU at `tiny`; `test_mhc_metrics`, `test_mla_dense_metrics`: its five
+readers).
 
 Each test of those files is collected here under its own name, so that it
 counts, and runs, as one test: the functions are the files' own (marks and
@@ -16,7 +19,8 @@ import importlib
 
 MODULES = ("test_pure", "test_knees_and_stall", "test_ssm_g1_metrics",
            "test_cell_granite_hybrid_cpu", "test_ssm_kernel_metric",
-           "test_ssm_step_labels")
+           "test_ssm_step_labels", "test_cell_xing4_cpu", "test_mhc_metrics",
+           "test_mla_dense_metrics")
 
 
 def _is_fixture(obj) -> bool:

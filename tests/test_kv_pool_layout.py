@@ -402,7 +402,12 @@ MODELS = {"gpt2-large": ("gpt2", "large", 8, 512, 512, 1024),
           # 64 lanes with a float32 part: 1 layer with K and V beside 9
           # with 2.4 GB of Mamba-2 state, 7,232 pages
           "granite-4.0-h-small": (
-              "granite_hybrid", "h_small_l10_ep4", 64, 7232, 256, 1792)}
+              "granite_hybrid", "h_small_l10_ep4", 64, 7232, 256, 1792),
+          # a latent kind with no indexer: ONE pool of latent rows (640
+          # lanes; the second has no lanes), 49,152 pages, and a residual
+          # state of 4 streams of 3584 a row
+          "xing4.0-29b-a4b": (
+              "xing4", "xing4_29b_a4b_l6_ep4", 32, 49152, 256, 33280)}
 # A program's temporaries, bytes. With no weight cast in any program they
 # are activations: the AOT compile reads 1.1-105.8 MB for gpt2-large (the
 # most in prefill-512; 1.55-1.64 GB while the float32 stacks were cast
@@ -416,6 +421,9 @@ TEMP_BOUND = 0.3e9
 # indexer's choice: the scores of 64 heads x 256 rows on a tile are 67 MB in
 # float32, and the program holds a few at once (413 MB in chunk-256, 90 MB
 # in decode-32, 103 MB in prefill-256: the AOT compile, PR 40)
+# (the xing4 cut's chunk folds latent tiles of 1,024 slots for 32 heads x
+# 256 rows, 34 MB of float32 scores a tile: 142 MB in chunk-256, 75 MB in
+# decode-32, 55 MB in prefill-256, under the common bound: PR 51)
 TEMP_BOUNDS = {"glm-5": 0.5e9}
 
 
@@ -538,7 +546,7 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
         pytest.skip("the engine refuses speculation for a stateful family")
     if program == "verify-5" and len(runner.layouts) > 1:
         pytest.skip("the engine refuses speculation with a window kind")
-    if program == "verify-5" and runner.layouts[0].select is not None:
+    if program == "verify-5" and runner.layouts[0].latent:
         pytest.skip("the engine refuses speculation with a latent kind")
     method, shapes, lanes = PROGRAMS[program]
     sizes = {"m": runner.max_blocks_per_seq, "p": MODELS[model][4],
@@ -595,7 +603,9 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
             r"= \w+\[([\d,]+)\]\S* " + opcode + r"\(", text)]
 
     # the smallest pool, which the SSM part of the state is larger than
-    pool_elements = min(math.prod(a.shape) for a in jax.tree.leaves(pool))
+    # (a latent kind with no indexer has a second pool of no lanes)
+    pools = [a for a in jax.tree.leaves(pool) if math.prod(a.shape)]
+    pool_elements = min(math.prod(a.shape) for a in pools)
     assert all(math.prod(a.shape) >= pool_elements
                for name, a in state.items() if name == "ssm")
     assert not [r for r in results("copy")
@@ -607,7 +617,9 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
     # beside the state)
     weights = cast or [
         a for path, a in jax.tree_util.tree_leaves_with_path(params)
-        if a.ndim >= 2 and "conv_w" not in jax.tree_util.keystr(path)]
+        if a.ndim >= 2 and "conv_w" not in jax.tree_util.keystr(path)
+        # the xing4 streams' maps are float32 and applied in float32
+        and "hc_" not in jax.tree_util.keystr(path)]
     assert weights and all(a.dtype == runner.cfg.dtype for a in weights)
     # a weight, a layer of a stack, or an expert of a layer
     held = {a.shape[i:] for a in weights for i in range(a.ndim - 1)}
@@ -615,6 +627,25 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
     if program == "decode" and not paged:
         assert not _full_width_contexts(text, runner, [
             *jax.tree.leaves(pool), *params_and_state(params, state)])
+    if runner.adapter.name == "xing4":
+        # the residual state (rows, 4 x 3584) is read by a half-layer's
+        # maps and mixes where it lies: the one copy of a program's rows
+        # of it is the entry's (the token's row laid out four times),
+        # and none of its twelve half-layers adds another; the maps and
+        # every Sinkhorn iteration are ONE kernel a half-layer, not some
+        # eighty fusions of a 4 x 4 a row
+        rows = {"prefill": sizes["p"], "chunk-256": 256}.get(
+            program, runner.max_batch_size)
+        state_elements = rows * runner.cfg.hc_mult * runner.cfg.hidden_size
+        assert len([r for r in results("copy") + results("transpose")
+                    if math.prod(r) >= state_elements
+                    and r[-1] % runner.cfg.hidden_size == 0]) <= 1
+        maps = [line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line
+                and "mhc_maps" in line]
+        assert len(maps) == 2 * runner.cfg.n_layer, len(maps)
+        n = runner.cfg.hc_mult
+        assert not [r for r in results("fusion") if r[:2] == (n, n)]
     if program == "decode" and "ssm" in state:
         # the one-step recurrence passes over a layer's state once: one
         # `ssm_step` kernel a Mamba layer, its FIRST result the state
@@ -631,9 +662,9 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
         # rows (36 x 16 at gpt2-large's chunk of 256, where it had 36 x
         # 256), its window one whole (16, row) page
         rows = sizes["p"] if program == "prefill" else 256
-        updates = _pool_scatters(text, jax.tree.leaves(pool))
+        updates = _pool_scatters(text, pools)
         print(f"{model} {program}: pool scatters {updates}")
-        assert len(updates) == len(jax.tree.leaves(pool))
+        assert len(updates) == len(pools)
         for shape, n, window in updates:
             assert n == shape[0] * rows // 16
             assert window == (16, shape[-1])

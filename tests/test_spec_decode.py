@@ -76,7 +76,7 @@ def test_use_paged_attention_is_no_choice_any_more():
     """The field outlives the flag only for the benchmark's five older
     serve configurations, which carry the key as false (`from_dict`
     refuses an unknown key, and they are a `benchmark` issue's to edit;
-    the two added since leave it out): false is accepted and read by
+    the three added since leave it out): false is accepted and read by
     nothing, true is refused with the reason."""
     import glob
     import json
@@ -88,7 +88,7 @@ def test_use_paged_attention_is_no_choice_any_more():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     engines = [json.load(open(path)).get("engine") for path in sorted(
         glob.glob(os.path.join(root, "benchmark", "configs", "*.json")))]
-    assert sum(e is not None for e in engines) == 7
+    assert sum(e is not None for e in engines) == 8
     assert sum("use_paged_attention" in e for e in filter(None, engines)) \
         == 5
     for engine in filter(None, engines):
