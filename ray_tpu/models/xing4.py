@@ -34,9 +34,12 @@ of C = `hidden_size`:
   ABSORBED to the cached latent rows and their own
   (ops/context_attention.py `attend_latent`): one 640-lane row a slot
   serves all heads, so a decode step reads 1,280 B a slot and layer where
-  up-projected keys and values would be 20 KB, and a chunk's 256 rows pay
-  3.4 times the per-head products for not writing and reading those 20 KB
-  a slot again (PERF.md section 6, PR 51, has the arithmetic);
+  up-projected keys and values would be 20 KB (on the chip with the paged
+  Pallas kernel, ops/paged_attention.py, the row one KV head under 32
+  query heads: PERF.md section 6, PR 52), and a chunk's 256 rows, which
+  keep the tile loops, pay 3.4 times the per-head products for not
+  writing and reading those 20 KB a slot again (PERF.md section 6, PR 51,
+  has the arithmetic);
 - **feed-forward**: layers below `first_k_dense_replace` a dense SwiGLU,
   the others routed experts (sigmoid scores, a selection bias, weights
   normalised and times `routed_scaling_factor`, the shared expert on

@@ -23,12 +23,14 @@ masked; not reading it changes no term of the softmax, only the order of
 a float32 sum.
 
 A program with few rows a lane (a decode step, a verify window) reads a
-full kind's context with one Pallas kernel a layer instead of the tile
-loops (`reads_by_kernel`, ops/paged_attention.py): pages copied several
-a step to each lane's own length, the heads' products formed on the page
-rows as they lie. The loops stay the path of every other program (a
-chunk's 256 rows are a different, MXU-bound trade), of a kind the kernel
-does not take, and of the CPU.
+full kind's context, and a latent kind's that is read whole, with one
+Pallas kernel a layer instead of the tile loops (`reads_by_kernel`,
+ops/paged_attention.py): pages copied several a step to each lane's own
+length, the heads' products formed on the page rows as they lie. The
+loops stay the path of every other program (a chunk's 256 rows are a
+different, MXU-bound trade), of a kind the kernel does not take (a
+window, a selection, a sink, K and V of unlike widths), of a pool split
+over `tensor`, and of the CPU.
 
 A kind of layer with a **window** (`KVLayout.window`: a row sees itself
 and the ``window - 1`` rows before it) has a lower end to its read as
@@ -58,8 +60,10 @@ mean context of about 6k slots a lane. Reading the chosen rows only is
 a kernel's to do).
 
 A latent kind with NO indexer (`KVLayout.v_head_dim` 0: one pool) reads
-every cached slot of a lane (`attend_latent`): the same fold of the
-latent tiles by the same groups, the mask a lane's length.
+every cached slot of a lane (`attend_latent`): a decode step with the
+kernel, the latent row its one KV head and the row's first lanes its
+values (PERF.md section 6, PR 52); a chunk by the same fold of the latent
+tiles by the same groups, the mask a lane's length.
 """
 
 from __future__ import annotations
@@ -122,17 +126,19 @@ def reads_by_kernel(layout, rows: int, sink: bool = False) -> bool:
     of a kind of layer whose pools lie as `layout` with the Pallas kernel
     (ops/paged_attention.py) and not with the tile loops below: few rows
     a lane, every earlier row seen (no window, no selection), K and V
-    heads of one width and no sink, rows and pages that are whole tiles
-    of the chip's memory, pools that lie whole on one chip (under the
-    mesh in force: a pool split by heads over `tensor` keeps the loops,
-    which XLA partitions), on a TPU. The one place that picks the path:
-    `attend_cached` asks it for the program it traces, the runner for
-    what it counts as read (`ModelRunner._note_context`)."""
+    heads of one width or no V row at all (a latent kind read whole: its
+    values are in its K row) and no sink, rows and pages that are whole
+    tiles of the chip's memory, pools that lie whole on one chip (under
+    the mesh in force: a pool split by heads over `tensor` keeps the
+    loops, which XLA partitions), on a TPU. The one place that picks the
+    path: `attend_cached` and `attend_latent` ask it for the program they
+    trace, the runner for what it counts as read
+    (`ModelRunner._note_context`)."""
     mesh = jax.sharding.get_abstract_mesh()
     tensor_ways = dict(mesh.shape).get("tensor", 1)
     return (jax.default_backend() == "tpu" and rows <= KERNEL_ROWS
             and layout.window is None and layout.select is None
-            and not sink and layout.v_row == layout.row
+            and not sink and layout.v_row in (layout.row, 0)
             and layout.row % 128 == 0 and layout.block_size % 16 == 0
             and layout.shard_ways(tensor_ways) == 1)
 
@@ -258,6 +264,32 @@ def _window_tile(ctx, layer, scale, dtype, q, carry):
     return (acc / _per_row(l)).astype(dtype)
 
 
+def _read_by_kernel(q, k, v, own_valid, k_pages, v_pages, tables, lengths,
+                    layer, **static):
+    from ray_tpu.ops.paged_attention import paged_attention
+
+    return paged_attention(q, k, v, own_valid, k_pages, v_pages, tables,
+                           lengths, layer=layer, **static)
+
+
+_read_by_kernel_once = jax.jit(
+    _read_by_kernel, static_argnames=("layout", "dtype", "scale",
+                                      "interpret"))
+
+
+def _by_kernel(q, k, v, own_valid, ctx, layer, dtype, scale=None,
+               once: bool = False):
+    """The whole read handed to the Pallas kernel (interpreted off a
+    TPU: the tests). `once`: through one jitted function, the layer a
+    traced operand, so that a family that unrolls its layers traces and
+    lowers the kernel once a program and not once a layer."""
+    read = _read_by_kernel_once if once else _read_by_kernel
+    with jax.named_scope("attn.ctx_read"):
+        return read(q, k, v, own_valid, ctx.k_pages, ctx.v_pages, ctx.tables,
+                    ctx.lengths, layer, layout=ctx.layout, dtype=dtype,
+                    scale=scale, interpret=jax.default_backend() != "tpu")
+
+
 def attend_cached(q, k, v, own_valid, ctx: CachedContext, layer, dtype,
                   sink=None):
     """q (B, T, HK, R, D) attends, under one softmax scaled by
@@ -278,13 +310,7 @@ def attend_cached(q, k, v, own_valid, ctx: CachedContext, layer, dtype,
     group's, and leaves its group's rows finished. A window kind reads
     its one tile a lane instead (`_window_tile`)."""
     if reads_by_kernel(ctx.layout, q.shape[1], sink is not None):
-        from ray_tpu.ops.paged_attention import paged_attention
-
-        with jax.named_scope("attn.ctx_read"):
-            return paged_attention(
-                q, k, v, own_valid, ctx.k_pages, ctx.v_pages, ctx.tables,
-                ctx.lengths, layout=ctx.layout, layer=layer, dtype=dtype,
-                interpret=jax.default_backend() != "tpu")
+        return _by_kernel(q, k, v, own_valid, ctx, layer, dtype)
     B, G = q.shape[0], ctx.group
     scale = 1.0 / (q.shape[-1] ** 0.5)
     window = ctx.layout.window
@@ -440,11 +466,16 @@ def attend_latent(q, latent, own_valid, ctx: CachedContext, layer, dtype, *,
     rows, whose first `values` lanes are also the values. Row t of lane b
     attends under one softmax (scores times `scale`) to its lane's cached
     slots ``[0, ctx.lengths[b])`` and the own rows `own_valid` (B, T, T)
-    allows -> (B, T, H, values) in `dtype`: the lanes' latent tiles folded
-    as far as their groups reach, each lane to its own length, one row
-    for all heads."""
+    allows -> (B, T, H, values) in `dtype`. A program of few rows a lane
+    hands the whole of it to the kernel where `reads_by_kernel` allows,
+    the latent row one KV head that every query head reads; otherwise the
+    lanes' latent tiles are folded as far as their groups reach, each
+    lane to its own length, one row for all heads."""
     q = q[:, :, None]  # (B, T, 1, H, row): one KV head, H query heads
     own = latent[:, :, None]  # (B, T, 1, row)
+    if reads_by_kernel(ctx.layout, q.shape[1]):
+        return _by_kernel(q, own, own[..., :values], own_valid, ctx, layer,
+                          dtype, scale, once=True)[:, :, 0]
     tile = ctx.layout.tile_pages * ctx.layout.block_size
 
     def below_length(rows, t):

@@ -95,9 +95,11 @@ from jax.sharding import NamedSharding, PartitionSpec
 # the tile's own bytes, so a deeper stack takes larger tiles.
 TILE_ELEMENTS_A_LAYER = 32 * 1024
 # Slots in one tile of a latent kind that is read whole (no indexer): a
-# decode step's loop step then reads 1.3 MB a lane at a 640-lane row,
-# against some 30 us of small operations a step, and a chunk's 256 rows
-# fold 19 GFLOP a step (serve/llm/cache.py `tile_pages`; PERF.md, PR 51)
+# chunk's 256 rows fold 19 GFLOP a loop step at a 640-lane row, against
+# some 30 us of small operations a step (`tile_pages`; PERF.md, PR 51).
+# It sizes a chunk's tile alone on the chip: a decode step reads with the
+# kernel, by `paged_attention.pages_a_step` (PR 52), and takes this tile
+# only on the loops (the CPU, a pool split over `tensor`)
 DENSE_LATENT_TILE_SLOTS = 1024
 
 

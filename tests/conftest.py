@@ -87,7 +87,8 @@ def read_by_kernel(monkeypatch):
     """``set(on)``: for the rest of the test (or until set again) the
     serve programs built from here on pick their context read as on a TPU
     (`on` true: a decode's and a verify's read of a full kind with K and V
-    alike goes to the Pallas kernel, interpreted here; whatever the
+    alike, or of a latent kind read whole, which has no V row, goes to the
+    Pallas kernel, interpreted here; whatever the
     interpreter has no use for, whole tiles of the chip's memory, is not
     asked) or as the CPU does (false: the loops). It patches the one
     predicate that picks the path, `context_attention.reads_by_kernel`."""
@@ -96,7 +97,7 @@ def read_by_kernel(monkeypatch):
     def on_tpu(layout, rows, sink=False):
         return (rows <= ca.KERNEL_ROWS and layout.window is None
                 and layout.select is None and not sink
-                and layout.v_row == layout.row)
+                and layout.v_row in (layout.row, 0))
 
     def set_path(on: bool) -> None:
         monkeypatch.setattr(ca, "reads_by_kernel",

@@ -423,7 +423,8 @@ TEMP_BOUND = 0.3e9
 # in decode-32, 103 MB in prefill-256: the AOT compile, PR 40)
 # (the xing4 cut's chunk folds latent tiles of 1,024 slots for 32 heads x
 # 256 rows, 34 MB of float32 scores a tile: 142 MB in chunk-256, 75 MB in
-# decode-32, 55 MB in prefill-256, under the common bound: PR 51)
+# decode-32 on the loops and 31 MB with the kernel, 55 MB in prefill-256,
+# under the common bound: PRs 51 and 52)
 TEMP_BOUNDS = {"glm-5": 0.5e9}
 
 
@@ -529,9 +530,13 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
     `paged`: decode and verify as the chip compiles them, a full kind
     whose K and V are alike read by the Pallas kernel (gpt2-large, OLMoE,
     the nemotron_h cut: one `tpu_custom_call` in the layer scan, no loop
-    that carries a running softmax); not `paged`: the same programs on the
-    tile loops, which the mimo_v2 cut's full kind (K 192, V 128) and every
-    chunk keep. The pool is copied on neither path."""
+    that carries a running softmax), and since PR 52 the xing4 cut's
+    latent kind, which is read whole and has one pool (one call a layer
+    of the six it unrolls, its result every head's 512 values a lane, the
+    one pool its only pool-sized operand); not `paged`: the same programs
+    on the tile loops, which the mimo_v2 cut's full kind (K 192, V 128),
+    the glm_dsa cut's latent kind (read under its indexer's choice) and
+    every chunk keep. The pool is copied on neither path."""
     # kernels are chosen by `jax.default_backend()`: take the chip's side
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     model, runner, params, (pool, state), cast = served_runner
@@ -587,6 +592,16 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
         # (the nemotron_h cut unrolls its two layers with K and V)
         assert len(kernels) in (1, runner.layouts[0].kv_layers), len(kernels)
         assert not carries, carries
+        if runner.layouts[0].latent:
+            c = runner.cfg
+            assert len(kernels) == runner.layouts[0].kv_layers
+            pool_shape = "bf16[" + ",".join(
+                map(str, jax.tree.leaves(pool)[0].shape)) + "]"
+            for line in kernels:
+                assert f"= bf16[{sizes['s']},{c.num_attention_heads}," \
+                    f"{c.kv_lora_rank}]" in line, line[:200]
+                assert line.split("operand_layout_constraints")[1].split(
+                    "}}")[0].count(pool_shape) == 1, line[:900]
         # gpt2-large's smaller decode buckets alike
         for n in (1, 2, 4) if (model, program) == ("gpt2-large",
                                                    "decode") else ():

@@ -607,6 +607,48 @@ def test_served_logprobs_are_the_references(params, small_tiles):
     assert stats["moe"]  # the routed layers' pairs are reported
 
 
+def test_decode_steps_read_with_the_kernel_as_on_a_tpu(params, read_by_kernel,
+                                                       monkeypatch):
+    """The engine on the path a TPU takes (conftest `read_by_kernel`: the
+    Pallas kernel, interpreted, two pages a step): the same requests'
+    log-probs are the reference's still, every decode step's launch is
+    counted in `kernel_steps` and what it read in `slots_read` to each
+    lane's own page, and a chunk keeps its loops."""
+    from ray_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "STEP_BYTES", 1)
+    monkeypatch.setattr(pa, "STEP_SLOTS_MIN", 8)
+    monkeypatch.setattr(pa, "STARTS_A_TURN", 2)
+    read_by_kernel(True)
+    engine = _engine()
+    engine.update_weights(1, params)
+    rng = np.random.default_rng(5)
+    lengths = (70, 20, 45, 33)
+    prompts = [rng.integers(1, CFG.vocab_size, n).tolist() for n in lengths]
+    finals = _serve(engine, prompts, [8] * 4, logprobs=True)
+    cases = [{"prompt": p, "tokens": f["token_ids"]}
+             for p, f in zip(prompts, finals)]
+    wants = ref.serve_reference(params, None, cases, arch=ARCH)
+    for f, w in zip(finals, wants):
+        np.testing.assert_allclose(f["logprobs"], w, atol=2e-4)
+    stats = engine.stats()
+    by = stats["context_by_kind"]["latent"]
+    assert by["decode"]["kernel_steps"] == stats["steps"]["decode"] > 0
+    assert by["prefill"]["kernel_steps"] == 0
+    # 4 lanes x 7 steps, each lane read to the page (of 4 slots) it ends in
+    at = [n + i for n in lengths for i in range(7)]
+    assert by["decode"]["slots_read"] == sum(-(-n // 4) * 4 for n in at)
+    assert by["decode"]["slots_valid"] == by["decode"]["row_slots"] == sum(at)
+    assert by["decode"]["rows"] == 28
+    read_by_kernel(False)
+    loops = _engine()
+    loops.update_weights(1, params)
+    again = _serve(loops, prompts, [8] * 4)
+    assert [f["token_ids"] for f in again] == [f["token_ids"] for f in finals]
+    by = loops.stats()["context_by_kind"]["latent"]["decode"]
+    assert by["kernel_steps"] == 0 and by["slots_read"] > sum(at)
+
+
 def test_a_prefix_is_taken_on_the_latent_kind(params):
     """A latent page depends on the prefix alone (every stream starts as
     the token, the maps see only earlier rows through attention): the
