@@ -1560,6 +1560,9 @@ class LLMEngine:
             # what warm-up fetched, routed, read and timed is no step's
             self.phases.seconds.update(
                 dict.fromkeys(self.phases.seconds, 0.0))
+            for by_kind in (self.runner.launch, self.runner.fetch):
+                for n in by_kind.values():
+                    n.update(dict.fromkeys(n, 0))
             self._fetched_seen = self.runner.fetched_bytes
             self.runner.take_expert_pairs()
             for kind, n in self.runner.context_slots.items():
@@ -1658,6 +1661,17 @@ class LLMEngine:
             "stream": self._stream_account.stats(),
             "steps": dict(self._steps),
             "d2h_bytes": dict(self._d2h),
+            # the jitted call alone, by kind of program: calls, wall
+            # seconds, and the host arrays the calls handed the runtime
+            # with their bytes, beside the leaves every call hands over
+            # already on the device; and a fetch's wait for the program
+            # apart from the copy after it and the rows put back in the
+            # caller's order
+            "launch": {"resident_leaves": self.runner.resident_leaves,
+                       **{kind: dict(n) for kind, n in
+                          self.runner.launch.items()}},
+            "fetch": {kind: dict(n) for kind, n in
+                      self.runner.fetch.items()},
             # slots of cached context by kind of program: read as
             # launched, valid, and what a read to max_model_len would be
             "context": {kind: dict(n) for kind, n in

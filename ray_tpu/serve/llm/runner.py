@@ -56,7 +56,10 @@ in one call. What the next program needs of this one's results, the
 sampled ids, stays on the device: `slot_tokens` holds the last sampled
 id of every lane slot, each of these programs writes its `nxt` there,
 and a decode lane whose token the host has not read yet (`token` -1)
-takes it from its slot.
+takes it from its slot. Every jitted call and every read is accounted for
+by kind of program (`launch`: the call alone and the host arrays it was
+handed; `fetch`: the wait for the program apart from the copy after it
+and the rows put back in order).
 
 With a mesh, parameters are sharded via the model's own
 `parallel/sharding.py` partition rules and the cache pages are sharded
@@ -334,6 +337,7 @@ class DecodeItem(NamedTuple):
 class Launched(NamedTuple):
     """A program on its way: its device outputs, nothing read."""
 
+    kind: str  # "prefill" (a prompt's or a chunk's program) or "decode"
     results: tuple  # (nxt, logits), on the device
     aux: tuple  # the family's extras (routed experts' pairs)
     # decode: row j of the program is the caller's lane `order[j]` (the
@@ -547,6 +551,25 @@ class ModelRunner:
         # bytes of device results copied to the host, ever (tokens and
         # logits, every `np.asarray` / `int()` of a program's output)
         self.fetched_bytes = 0
+        # the jitted call alone, by kind of program (a prompt's and a
+        # chunk's are `prefill`): calls, their wall seconds (the
+        # `dispatch` phase less them is the wrapper: the cache probe,
+        # the mesh context, the wait for `_jit_lock`, `_note_compile`),
+        # and the host arrays a call handed the runtime beside the
+        # resident trees, each its own transfer, with their bytes
+        self.launch = {kind: {"calls": 0, "wall_s": 0.0, "host_arrays": 0,
+                              "host_bytes": 0} for kind in CONTEXT_KINDS}
+        # a fetch by kind of program: `wait_s` until the first result (the
+        # sampled ids, a few bytes) is on the host, so the wait for the
+        # program; `copy_s` the logits rows and a routed family's pairs
+        # after it; `order_s` a decode step's rows put back in the
+        # caller's order (a second copy of them where the orders differ)
+        self.fetch = {kind: {"wait_s": 0.0, "copy_s": 0.0, "order_s": 0.0}
+                      for kind in CONTEXT_KINDS}
+        # leaves every call hands over already on the device, beside the
+        # parameters' (`_install` counts those)
+        self._carried_leaves = len(jax.tree.leaves(
+            (self.k_pages, self.v_pages, self.slot_tokens, self.state)))
         self.expert_pairs: list[np.ndarray] = []
         counts = CONTEXT_COUNTS + SELECT_COUNTS * any(
             lay.select is not None for lay in self.layouts) \
@@ -859,18 +882,26 @@ class ModelRunner:
 
     # -------------------------------------------------------------- host
 
-    def _fetch(self, *results, aux=()) -> list[np.ndarray]:
+    def _fetch(self, kind: str, *results, aux=()) -> list[np.ndarray]:
         """Device results as numpy arrays: the wait for the program that
-        makes them, then the copy to the host. `aux` is what the family's
+        makes them (until the first of them, the sampled ids, is on the
+        host), then the copy of the rest, written down apart by `kind`
+        of program (`self.fetch`). `aux` is what the family's
         forward returned beside (logits, k, v): nothing, or the routed
         experts' pairs per layer and expert, kept for the engine
         (`take_expert_pairs`)."""
-        out = [np.asarray(r) for r in results]
+        t0 = time.perf_counter()
+        out = [np.asarray(results[0])]
+        t1 = time.perf_counter()
+        out += [np.asarray(r) for r in results[1:]]
         self.fetched_bytes += sum(a.nbytes for a in out)
         if aux:  # routed experts only
             extra = [np.asarray(r) for r in aux]
             self.fetched_bytes += sum(a.nbytes for a in extra)
             self.expert_pairs.extend(extra)
+        spent = self.fetch[kind]
+        spent["wait_s"] += t1 - t0
+        spent["copy_s"] += time.perf_counter() - t1
         return out
 
     def collect(self, launched: Launched) -> tuple:
@@ -878,16 +909,23 @@ class ModelRunner:
         id, its logits row) of a prefill or a chunk, (ids, logits rows)
         of a decode's real lanes, in the order the caller gave them."""
         with self.phases.phase("fetch"):
-            nxt, logits = self._fetch(*launched.results, aux=launched.aux)
+            nxt, logits = self._fetch(launched.kind, *launched.results,
+                                      aux=launched.aux)
             if launched.order is None:
                 return int(nxt), logits
             # back to the caller's order: its lane i ran as row
             # `row_of[i]` (a view of the rows, not a copy, where the two
-            # orders agree)
+            # orders agree; where they differ `logits[row_of]` is a
+            # gather, so the rows `_fetch` copied are copied a second
+            # time: 1.6 ms of a 64-lane step, PERF.md §5. A caller that
+            # took the rows by index would not need it)
+            t0 = time.perf_counter()
             row_of = np.argsort(launched.order)
             if np.array_equal(row_of, np.arange(len(row_of))):
                 row_of = slice(len(row_of))
-            return [int(t) for t in nxt[row_of]], logits[row_of]
+            out = [int(t) for t in nxt[row_of]], logits[row_of]
+            self.fetch[launched.kind]["order_s"] += time.perf_counter() - t0
+            return out
 
     def take_expert_pairs(self) -> list[np.ndarray]:
         """The (L, n_experts) pairs-per-expert arrays of the programs run
@@ -899,6 +937,23 @@ class ModelRunner:
     def _mesh_ctx(self):
         return (jax.set_mesh(self.mesh) if self.mesh is not None
                 else contextlib.nullcontext())
+
+    def _note_launch(self, kind: str, wall: float, host: tuple) -> None:
+        """One jitted call into `self.launch[kind]`: its wall seconds
+        (read inside `_jit_lock`) and the `host` arrays of the step it
+        was handed, counted as they are in hand."""
+        spent = self.launch[kind]
+        spent["calls"] += 1
+        spent["wall_s"] += wall
+        spent["host_arrays"] += len(host)
+        spent["host_bytes"] += sum(a.nbytes for a in host)
+
+    @property
+    def resident_leaves(self) -> int:
+        """Arrays every call hands the runtime already on the device: the
+        leaves of the parameters, the pools, the lane slots' last ids and
+        the lanes' state."""
+        return self._param_leaves + self._carried_leaves
 
     @functools.cached_property
     def state_by_kernel(self) -> bool:
@@ -979,18 +1034,23 @@ class ModelRunner:
             topp = np.asarray([top_p], np.float32)
             self._step_counter += 1
         with self.phases.phase("dispatch"):
+            last_row, slot, step = (np.int32(n - 1), np.int32(slot),
+                                    np.int32(self._step_counter))
             before = tracing.jit_cache_size(self._prefill_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
+                w0 = time.perf_counter()
                 (nxt, last, self.k_pages, self.v_pages, self.slot_tokens,
                  self.state, aux) = self._prefill_jit(
                     self.params, self.k_pages, self.v_pages,
-                    self.slot_tokens, self.state, toks, np.int32(n - 1),
-                    page_ids, np.int32(slot), temp, topk, topp,
-                    np.int32(self._step_counter))
+                    self.slot_tokens, self.state, toks, last_row,
+                    page_ids, slot, temp, topk, topp, step)
+                wall = time.perf_counter() - w0
             self._note_compile("prefill", self._prefill_jit, before,
                                time.perf_counter() - t0)
-        return Launched((nxt, last), aux, None)
+            self._note_launch("prefill", wall, (
+                toks, last_row, *page_ids, slot, temp, topk, topp, step))
+        return Launched("prefill", (nxt, last), aux, None)
 
     def prefill(self, token_ids: Sequence[int], table: Sequence,
                 temperature: float, top_k: int = 0, top_p: float = 1.0
@@ -1028,19 +1088,25 @@ class ModelRunner:
             self._note_context("prefill", [start], rows=Tb, real=n)
             self._step_counter += 1
         with self.phases.phase("dispatch"):
+            start, last_row, slot, step = (
+                np.int32(start), np.int32(n - 1), np.int32(slot),
+                np.int32(self._step_counter))
             before = tracing.jit_cache_size(self._chunk_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
+                w0 = time.perf_counter()
                 (nxt, last, self.k_pages, self.v_pages, self.slot_tokens,
                  self.state, aux) = self._chunk_jit(
                     self.params, self.k_pages, self.v_pages,
-                    self.slot_tokens, self.state, toks, np.int32(start),
-                    np.int32(n - 1), page_ids, tab,
-                    np.int32(slot), temp, topk, topp,
-                    np.int32(self._step_counter))
+                    self.slot_tokens, self.state, toks, start, last_row,
+                    page_ids, tab, slot, temp, topk, topp, step)
+                wall = time.perf_counter() - w0
             self._note_compile("prefill_chunk", self._chunk_jit, before,
                                time.perf_counter() - t0)
-        return Launched((nxt, last), aux, None)
+            self._note_launch("prefill", wall, (
+                toks, start, last_row, *page_ids, *tab, slot, temp, topk,
+                topp, step))
+        return Launched("prefill", (nxt, last), aux, None)
 
     def prefill_chunk(self, token_ids: Sequence[int], start: int,
                       table: Sequence, temperature: float,
@@ -1091,17 +1157,22 @@ class ModelRunner:
                 written["rowwise"] += S
             self._step_counter += 1
         with self.phases.phase("dispatch"):
+            step = np.int32(self._step_counter)
             before = tracing.jit_cache_size(self._decode_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
+                w0 = time.perf_counter()
                 (nxt, logits, self.k_pages, self.v_pages, self.slot_tokens,
                  self.state, aux) = self._decode_jit(
                     self.params, self.k_pages, self.v_pages,
                     self.slot_tokens, self.state, toks, slots, poss, tables,
-                    temps, topks, topps, np.int32(self._step_counter))
+                    temps, topks, topps, step)
+                wall = time.perf_counter() - w0
             self._note_compile("decode", self._decode_jit, before,
                                time.perf_counter() - t0)
-        return Launched((nxt, logits), aux, order)
+            self._note_launch("decode", wall, (
+                toks, slots, poss, *tables, temps, topks, topps, step))
+        return Launched("decode", (nxt, logits), aux, order)
 
     def decode(self, items: Sequence[DecodeItem]
                ) -> tuple[list[int], np.ndarray]:
@@ -1152,20 +1223,26 @@ class ModelRunner:
                 written["rowwise"] += n_draft + 1
             self._step_counter += 1
         with self.phases.phase("dispatch"):
+            pos, n_draft, step = (np.int32(pos), np.int32(n_draft),
+                                  np.int32(self._step_counter))
             before = tracing.jit_cache_size(self._verify_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
+                w0 = time.perf_counter()
                 emitted, n_acc, logits, self.k_pages, self.v_pages, aux = \
                     self._verify_jit(
                         self.params, self.k_pages, self.v_pages, toks,
-                        np.int32(pos), np.int32(n_draft), block_ids,
-                        offsets, tab, temps, topks, topps,
-                        np.int32(self._step_counter))
+                        pos, n_draft, block_ids, offsets, tab, temps,
+                        topks, topps, step)
+                wall = time.perf_counter() - w0
             self._note_compile("verify", self._verify_jit, before,
                                time.perf_counter() - t0)
+            self._note_launch("verify", wall, (
+                toks, pos, n_draft, *block_ids, offsets, *tab, temps,
+                topks, topps, step))
         with self.phases.phase("fetch"):
-            n_acc, emitted, logits = self._fetch(n_acc, emitted, logits,
-                                                 aux=aux)
+            n_acc, emitted, logits = self._fetch("verify", n_acc, emitted,
+                                                 logits, aux=aux)
             n_em = int(n_acc) + 1
             return [int(t) for t in emitted[:n_em]], logits[:n_em]
 
@@ -1228,6 +1305,7 @@ class ModelRunner:
             "installs": self.weights["installs"] + 1}
         with self._jit_lock:
             self.params, self.weights = resident, weights
+            self._param_leaves = len(leaves)
 
     def set_params(self, params: Any) -> None:
         """Install a new parameter pytree (weight hot-swap). The tree

@@ -10,7 +10,11 @@ program whose recurrence is the `ssm_step` custom call), and PR 51's
 (`test_cell_xing4_cpu`: the xing4 cell's files, sizes and rehearsal on the
 CPU at `tiny`; `test_mhc_metrics`, `test_mla_dense_metrics`: its five
 readers), and PR 52's (`test_mla_decode_read_metric`: the reader of the
-decode read's kernel, and what the loops' readers still find beside it).
+decode read's kernel, and what the loops' readers still find beside it),
+and PR 54's (`test_launch_account`: a launch split into its parts, on a
+hand-worked event list and on three launches cut from a chip trace, and the
+seven readers on it; `test_span_gaps`: the split of the device's gaps the
+launch account stands on, collected by no tier-1 command until then).
 
 Each test of those files is collected here under its own name, so that it
 counts, and runs, as one test: the functions are the files' own (marks and
@@ -21,7 +25,8 @@ import importlib
 MODULES = ("test_pure", "test_knees_and_stall", "test_ssm_g1_metrics",
            "test_cell_granite_hybrid_cpu", "test_ssm_kernel_metric",
            "test_ssm_step_labels", "test_cell_xing4_cpu", "test_mhc_metrics",
-           "test_mla_dense_metrics", "test_mla_decode_read_metric")
+           "test_mla_dense_metrics", "test_mla_decode_read_metric",
+           "test_launch_account", "test_span_gaps")
 
 
 def _is_fixture(obj) -> bool:

@@ -213,6 +213,171 @@ def test_steps_and_bytes_match_what_was_driven(engine):
     assert line, "serve_llm_d2h_bytes_total{kind=decode} not exposed"
 
 
+def _tiny_engine(model, **overrides):
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+
+    if model == "gpt2":
+        return LLMEngine(_config(enable_prefix_cache=False, **overrides))
+    # the routed-expert block through the llama path: its programs hand
+    # the host the experts' pairs beside the logits
+    return LLMEngine(EngineConfig(
+        model="llama", preset="olmoe_tiny", block_size=8, num_blocks=64,
+        max_model_len=64, max_batch_size=4, prefill_chunk_size=8, seed=0,
+        enable_prefix_cache=False, **overrides))
+
+
+def _launch_spies(runner):
+    """Every jitted call the runner makes from here on, by program:
+    [(leaves that are `jax.Array`s, leaves that are not, the bytes of
+    those), ...]."""
+    seen = {}
+    for name in ("prefill", "chunk", "decode", "verify"):
+        inner = getattr(runner, f"_{name}_jit")
+        calls = seen[name] = []
+
+        def call(*args, _inner=inner, _calls=calls):
+            leaves = jax.tree.leaves(args)
+            on_host = [x for x in leaves if not isinstance(x, jax.Array)]
+            _calls.append((len(leaves) - len(on_host), len(on_host),
+                           sum(x.nbytes for x in on_host)))
+            return _inner(*args)
+        call._cache_size = inner._cache_size
+        setattr(runner, f"_{name}_jit", call)
+    return seen
+
+
+def _by_kind_rose(after, before, what):
+    return {kind: {k: v - before[what][kind][k] for k, v in n.items()}
+            for kind, n in after[what].items() if isinstance(n, dict)}
+
+
+@pytest.mark.parametrize("model", ["gpt2", "olmoe"])
+def test_launch_and_fetch_accounts_count_what_was_run(model):
+    from ray_tpu.serve.llm.config import SamplingParams
+
+    eng = _tiny_engine(model)
+    runner = eng.runner
+    eng.warmup()
+    # what warm-up launched and fetched is no step's
+    st = eng.stats()
+    assert set(st["launch"]) == {"resident_leaves", *st["fetch"]} \
+        == {"resident_leaves", "prefill", "decode", "verify"}
+    for what in ("launch", "fetch"):
+        for kind in ("prefill", "decode", "verify"):
+            assert st[what][kind] == dict.fromkeys(st[what][kind], 0), \
+                (what, kind)
+    # the leaves every call hands over already on the device
+    assert st["launch"]["resident_leaves"] == len(jax.tree.leaves((
+        runner.params, runner.k_pages, runner.v_pages, runner.slot_tokens,
+        runner.state)))
+    seen = _launch_spies(runner)
+
+    def drive():
+        before = eng.stats()
+        streams = [eng.add_request(list(range(2, 2 + n)),
+                                   SamplingParams(max_tokens=4))
+                   for n in (5, 19, 11)]
+        while eng.has_work():
+            assert eng.step()
+        assert all(s.final()["finish_reason"] == "length" for s in streams)
+        after = eng.stats()
+        rose = {what: _by_kind_rose(after, before, what)
+                for what in ("launch", "fetch")}
+        rose["steps"] = {k: v - before["steps"][k]
+                         for k, v in after["steps"].items()}
+        for phase in ("fetch", "dispatch"):
+            rose[phase + "_phase"] = after["step_phase_seconds"][phase] \
+                - before["step_phase_seconds"][phase]
+        return rose
+
+    first = drive()
+    launch = first["launch"]
+    # a call a program run, by kind as `steps` has them
+    assert launch["prefill"]["calls"] == first["steps"]["prefill"] \
+        == len(seen["prefill"]) + len(seen["chunk"]) > 0
+    assert launch["decode"]["calls"] == first["steps"]["decode"] \
+        == len(seen["decode"]) > 0
+    assert launch["verify"] == dict.fromkeys(launch["verify"], 0)
+    # what each call handed the runtime: the resident trees' leaves (the
+    # one number), and the numpy arguments its launch passes
+    for kind, calls in (("prefill", seen["prefill"] + seen["chunk"]),
+                        ("decode", seen["decode"])):
+        assert {d for d, _, _ in calls} == {st["launch"]["resident_leaves"]}
+        assert launch[kind]["host_arrays"] == sum(h for _, h, _ in calls)
+        assert launch[kind]["host_bytes"] == sum(b for _, _, b in calls) \
+            > 4 * launch[kind]["host_arrays"]
+    # tokens, slots, positions, the kind's tables, three sampling arrays
+    # and the step's count; a chunk's program two more than a prompt's
+    assert {h for _, h, _ in seen["decode"]} == {8}
+    assert {h for _, h, _ in seen["chunk"]} == {10}
+    assert {h for _, h, _ in seen["prefill"]} <= {8}
+    # the jitted calls alone are part of the `dispatch` phase
+    assert 0 < sum(n["wall_s"] for n in launch.values()) \
+        < first["dispatch_phase"]
+    # the wait, the copy and the rows put back in order are parts of the
+    # `fetch` phase, and nearly all of it
+    parts = sum(sum(n.values()) for n in first["fetch"].values())
+    assert 0.5 * first["fetch_phase"] < parts <= first["fetch_phase"]
+    assert all(first["fetch"][kind][part] > 0
+               for kind in ("prefill", "decode")
+               for part in ("wait_s", "copy_s"))
+    # a prompt's one row has no order to be put back in
+    assert first["fetch"]["decode"]["order_s"] > 0 \
+        == first["fetch"]["prefill"]["order_s"]
+    # the same requests again: every count repeats exactly
+    again = drive()
+    counts = ("calls", "host_arrays", "host_bytes")
+    assert {kind: {k: n[k] for k in counts}
+            for kind, n in again["launch"].items()} \
+        == {kind: {k: n[k] for k in counts} for kind, n in launch.items()}
+    assert again["steps"] == first["steps"]
+    assert eng.stats()["launch"]["resident_leaves"] \
+        == st["launch"]["resident_leaves"]
+
+
+def test_fetch_parts_sum_to_no_more_than_the_fetch_phase(engine):
+    from ray_tpu.serve.llm.config import SamplingParams
+
+    before = engine.stats()
+    for n in (7, 20):
+        engine.generate(list(range(3, 3 + n)), SamplingParams(max_tokens=6),
+                        drive=True)
+    after = engine.stats()
+    rose = _by_kind_rose(after, before, "fetch")
+    parts = sum(sum(n.values()) for n in rose.values())
+    phase = after["step_phase_seconds"]["fetch"] \
+        - before["step_phase_seconds"]["fetch"]
+    assert 0.5 * phase < parts <= phase
+    assert rose["verify"] == {"wait_s": 0, "copy_s": 0, "order_s": 0}
+
+
+def test_a_verify_dispatch_writes_the_same_two_records():
+    from ray_tpu.serve.llm import SpeculativeConfig
+    from ray_tpu.serve.llm.config import SamplingParams
+
+    eng = _tiny_engine("gpt2", speculative=SpeculativeConfig(
+        method="ngram", num_draft_tokens=3))
+    seen = _launch_spies(eng.runner)
+    eng.generate([5, 6, 7, 5, 6, 7, 5, 6], SamplingParams(max_tokens=8),
+                 drive=True)
+    st = eng.stats()
+    assert st["launch"]["verify"]["calls"] == len(seen["verify"]) > 0
+    # the parameters and the pools: a verify program carries no ids, no
+    # state
+    assert {d for d, _, _ in seen["verify"]} == {len(jax.tree.leaves((
+        eng.runner.params, eng.runner.k_pages, eng.runner.v_pages)))}
+    assert st["launch"]["verify"]["host_arrays"] \
+        == sum(h for _, h, _ in seen["verify"])
+    assert st["launch"]["verify"]["host_bytes"] \
+        == sum(b for _, _, b in seen["verify"])
+    assert 0 < st["launch"]["verify"]["wall_s"] \
+        < st["step_phase_seconds"]["dispatch"]
+    assert st["fetch"]["verify"]["wait_s"] > 0
+    assert st["fetch"]["verify"]["order_s"] == 0
+    parts = sum(sum(n.values()) for n in st["fetch"].values())
+    assert parts <= st["step_phase_seconds"]["fetch"]
+
+
 # ---------------------------------------------------------------------------
 # (c) start-up
 # ---------------------------------------------------------------------------
@@ -398,3 +563,41 @@ def test_phase_clock_is_shared_by_engine_and_runner(engine):
     assert all(s.final() is not None for s in streams)
     after = engine.stats()["steps"]
     assert sum(after.values()) - sum(before.values()) == sum(done)
+
+
+# the tiny engine's warm-up on the parent commit (PR 52's tree, jax 0.9.0,
+# after `jax.clear_caches()`): jax's own reports of a trace, a lowering and
+# a compile, counted
+WARMUP_REPORTS = {"jaxpr_trace_duration": 1160,
+                  "jaxpr_to_mlir_module_duration": 5,
+                  "backend_compile_duration": 5}
+
+
+def test_warmup_traces_lowers_and_compiles_as_often_as_the_parent():
+    """A warm-up guard: `warmup()` of the tiny engine makes jax report as
+    many traces, lowerings and compiles as it did on the parent commit. It
+    catches a change that traces a program twice or adds a jitted helper
+    to every trace. It would NOT have caught PR 53, whose counts were the
+    parent's and whose traces only ran slower, in a replica on the chip's
+    host: that shows in `warmup_trace_lower_s` of a traced cell run alone
+    (PERF.md §6, PR 54). Last in this file: it empties jax's caches."""
+    import collections
+
+    from jax import monitoring
+
+    from ray_tpu.serve.llm import LLMEngine
+
+    seen = collections.Counter()
+
+    def listen(event, duration, **_):
+        seen[event.rsplit("/", 1)[-1]] += 1
+
+    jax.clear_caches()
+    e = LLMEngine(_config())
+    monitoring.register_event_duration_secs_listener(listen)
+    try:
+        programs = e.warmup()
+    finally:
+        monitoring.unregister_event_duration_listener(listen)
+    assert programs == WARMUP_REPORTS["backend_compile_duration"]
+    assert {k: seen[k] for k in WARMUP_REPORTS} == WARMUP_REPORTS
