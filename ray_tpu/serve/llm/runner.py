@@ -56,7 +56,13 @@ in one call. What the next program needs of this one's results, the
 sampled ids, stays on the device: `slot_tokens` holds the last sampled
 id of every lane slot, each of these programs writes its `nxt` there,
 and a decode lane whose token the host has not read yet (`token` -1)
-takes it from its slot. Every jitted call and every read is accounted for
+takes it from its slot. What a launch takes from the HOST is one int32
+array, its **pack** (`pack_layout`: tokens, positions, page ids, tables,
+the sampling fields and the step's count side by side, a float32 by its
+bits; filled where it lies, taken apart by static slices in the program's
+first lines): the runtime transfers every host argument apart, and a
+transfer costs what it costs whatever it holds (0.13-0.17 ms on a v5e's
+host, PERF.md §5). Every jitted call and every read is accounted for
 by kind of program (`launch`: the call alone and the host arrays it was
 handed; `fetch`: the wait for the program apart from the copy after it
 and the rows put back in order).
@@ -73,6 +79,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 import threading
 import time
 from typing import Any, Callable, NamedTuple, Sequence
@@ -371,6 +378,61 @@ CONTEXT_COUNTS = ("slots_read", "slots_valid", "slots_reach", "slots_full")
 SELECT_COUNTS = ("slots_scored", "slots_selected")
 DENSE_LATENT_COUNTS = ("rows", "row_slots")
 
+# the fields of a pack that hold the bits of a float32
+PACK_FLOATS = ("temps", "topps")
+
+
+@functools.lru_cache(maxsize=None)
+def pack_layout(kind: str, rows: int, kinds: int, blocks: int
+                ) -> tuple[int, dict[str, tuple[int, tuple]]]:
+    """A launch's pack, the one int32 array a `kind` program ("prefill",
+    "chunk", "decode", "verify") of `rows` (its bucket: `Tb`, `Sb`, `W`)
+    takes from the host: (its length, {field: (offset, shape)}), the
+    fields in the order the programs took them as arguments of their own.
+    `kinds`: the kinds of KV layer, which lead the shape of a field that
+    is one a kind; `blocks`: `max_blocks_per_seq`, the width of a table
+    and of a prompt's or a chunk's page ids, of which a bucket fills and
+    reads its first `group_pages(rows)`. Every length is `rows` times a
+    constant plus a constant, which is how a program finds its bucket
+    again from the array's length (`ModelRunner._unpacked`)."""
+    one = {"slot": (), "temps": (1,), "topks": (1,), "topps": (1,),
+           "step": ()}
+    lanes = {"temps": (rows,), "topks": (rows,), "topps": (rows,),
+             "step": ()}
+    shapes = {
+        "prefill": {"tokens": (1, rows), "last_idx": (),
+                    "page_ids": (kinds, blocks), **one},
+        "chunk": {"tokens": (1, rows), "start": (), "last_idx": (),
+                  "page_ids": (kinds, blocks), "table": (kinds, blocks),
+                  **one},
+        "decode": {"tokens": (rows,), "slots": (rows,),
+                   "positions": (rows,), "tables": (kinds, rows, blocks),
+                   **lanes},
+        "verify": {"tokens": (1, rows), "start": (), "n_draft": (),
+                   "block_ids": (kinds, rows), "offsets": (rows,),
+                   "table": (kinds, blocks), **lanes},
+    }[kind]
+    fields, at = {}, 0
+    for name, shape in shapes.items():
+        fields[name] = (at, shape)
+        at += math.prod(shape)
+    return at, fields
+
+
+def unpack(host, fields: dict) -> dict:
+    """The fields of a pack by name, each in its shape: of a numpy array
+    writable views (a launch fills them where they lie), of a traced one
+    its static slices; a float32 by its bits either way (`ndarray.view`,
+    `lax.bitcast_convert_type`), so no bit of a value moves."""
+    out = {}
+    for name, (at, shape) in fields.items():
+        x = host[at:at + math.prod(shape)]
+        if name in PACK_FLOATS:
+            x = x.view(np.float32) if isinstance(x, np.ndarray) \
+                else jax.lax.bitcast_convert_type(x, jnp.float32)
+        out[name] = x.reshape(shape)
+    return out
+
 
 def _by_kind(x) -> tuple:
     """A program argument or result a kind of KV layer: a family with
@@ -556,7 +618,8 @@ class ModelRunner:
         # `dispatch` phase less them is the wrapper: the cache probe,
         # the mesh context, the wait for `_jit_lock`, `_note_compile`),
         # and the host arrays a call handed the runtime beside the
-        # resident trees, each its own transfer, with their bytes
+        # resident trees, each its own transfer (one a call: its pack),
+        # with their bytes
         self.launch = {kind: {"calls": 0, "wall_s": 0.0, "host_arrays": 0,
                               "host_bytes": 0} for kind in CONTEXT_KINDS}
         # a fetch by kind of program: `wait_s` until the first result (the
@@ -761,38 +824,49 @@ class ModelRunner:
                                                          rows[:, 0]))
 
     def _prefill_impl(self, params, k_pages, v_pages, slot_tokens, state,
-                      tokens, last_idx, page_ids, slot, temp, topk,
-                      topp, step):
-        """tokens (1, Tb); page_ids (pages of Tb rows,) the page of each
+                      host):
+        """host: the launch's pack (`pack_layout`). tokens (1, Tb);
+        page_ids (pages of Tb rows,) the page of each
         group of `block_size` positions (the null page 0 for a group that
         is all padding; the padded rows of the last valid page land in
         that page, behind the sequence's frontier). A sequence's first
         rows: its slot's recurrent state starts from zero. Here and in
         the programs below, the pools, the page or block ids and the
         tables are one a kind of KV layer (a tuple; bare for one kind)."""
+        f = self._unpacked("prefill", host)
+        tokens, last_idx, slot = f["tokens"], f["last_idx"], f["slot"]
+        pages = self.layouts[0].group_pages(tokens.shape[1])
+        page_ids = tuple(ids[:pages] for ids in f["page_ids"])
         (logits, k, v, *aux), state = self._forward(
             self.adapter.prefill_fn, state, slot, params, tokens, self.cfg,
             fresh=True, n_valid=last_idx + 1)
         k_pages, v_pages = self._write_pages(k_pages, v_pages, page_ids,
                                              k, v)
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
-        nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
+        nxt = self._sample(last[None, :], f["temps"], f["topks"],
+                           f["topps"], f["step"])[0]
         slot_tokens = self._keep_sampled(slot_tokens, slot, nxt)
         return nxt, last, k_pages, v_pages, slot_tokens, state, tuple(aux)
 
     def _chunk_impl(self, params, k_pages, v_pages, slot_tokens, state,
-                    tokens, start, last_idx, page_ids, table, slot,
-                    temp, topk, topp, step):
+                    host):
         """Prefill a chunk of ONE sequence from a position offset.
 
-        tokens (1, Tb) at absolute positions start..start+Tb-1, `start`
+        host: the launch's pack (`pack_layout`). tokens (1, Tb) at
+        absolute positions start..start+Tb-1, `start`
         on a page's edge; table (maxB,) is the sequence's full block
         table, read for context (positions < start); page_ids as in
         `_prefill_impl`, the pages from `start` on. `start` is
         traced, so one compiled program per chunk-length bucket serves
         every offset. Recurrent state is carried chunk to chunk in the
         lane's slot, from zero where `start` is 0."""
+        f = self._unpacked("chunk", host)
+        tokens, start, last_idx, slot = (f["tokens"], f["start"],
+                                         f["last_idx"], f["slot"])
         Tb = tokens.shape[1]
+        pages = self.layouts[0].group_pages(Tb)
+        page_ids = tuple(ids[:pages] for ids in f["page_ids"])
+        table = tuple(f["table"])
         chunk_mask = (jnp.arange(Tb)[None, :] <= last_idx)  # (1, Tb)
         (logits, k, v, *aux), state = self._forward(
             self.adapter.chunk_fn, state, slot, params, tokens, start,
@@ -802,17 +876,17 @@ class ModelRunner:
         k_pages, v_pages = self._write_pages(k_pages, v_pages, page_ids,
                                              k, v)
         last = jnp.take(logits[0], last_idx, axis=0)  # (Vp,)
-        nxt = self._sample(last[None, :], temp, topk, topp, step)[0]
+        nxt = self._sample(last[None, :], f["temps"], f["topks"],
+                           f["topps"], f["step"])[0]
         slot_tokens = self._keep_sampled(slot_tokens, slot, nxt)
         return nxt, last, k_pages, v_pages, slot_tokens, state, tuple(aux)
 
-    def _verify_impl(self, params, k_pages, v_pages, tokens, start,
-                     n_draft, block_ids, offsets, table, temps, topks,
-                     topps, step):
+    def _verify_impl(self, params, k_pages, v_pages, host):
         """Score a drafted run of ONE sequence in one dispatch and
         accept/reject in-jit (no logits round-trip to host).
 
-        tokens (1, W) with W = num_draft_tokens + 1: row 0 is the last
+        host: the launch's pack (`pack_layout`). tokens (1, W) with
+        W = num_draft_tokens + 1: row 0 is the last
         committed token at traced position `start` (== pos - 1), rows
         1..n_draft the proposer's guesses at start+1.., padded tail to
         the static width. The program is the `prefill_chunk` shape —
@@ -829,6 +903,9 @@ class ModelRunner:
 
         Returns (emitted (W,), n_acc scalar, logits (W, Vp), pages, the
         forward's extras): the caller commits emitted[:n_acc + 1]."""
+        f = self._unpacked("verify", host)
+        tokens, start, n_draft = f["tokens"], f["start"], f["n_draft"]
+        block_ids, table = tuple(f["block_ids"]), tuple(f["table"])
         W = tokens.shape[1]
         chunk_mask = (jnp.arange(W)[None, :] <= n_draft)  # (1, W)
         logits, k, v, *aux = self.adapter.chunk_fn(
@@ -836,10 +913,11 @@ class ModelRunner:
             self._context(k_pages, v_pages,
                           [t[None] for t in _by_kind(table)], start[None]),
             chunk_mask, self.cfg)
-        k_pages, v_pages = self._write(k_pages, v_pages, block_ids, offsets,
-                                       k, v, lane=0)
+        k_pages, v_pages = self._write(k_pages, v_pages, block_ids,
+                                       f["offsets"], k, v, lane=0)
         lg = logits[0]  # (W, Vp)
-        target = self._sample(lg, temps, topks, topps, step)  # (W,)
+        target = self._sample(lg, f["temps"], f["topks"], f["topps"],
+                              f["step"])  # (W,)
         # target[j] is the model's own token FOR position start+j+1;
         # accept drafts while they match it, longest-prefix semantics
         match = (target[:-1] == tokens[0, 1:]) \
@@ -849,9 +927,9 @@ class ModelRunner:
         return emitted, n_acc, lg, k_pages, v_pages, tuple(aux)
 
     def _decode_impl(self, params, k_pages, v_pages, slot_tokens, state,
-                     tokens, slots, positions, tables, temps, topks, topps,
-                     step):
-        """tokens/slots/positions/temps (Sb,); tables (Sb,
+                     host):
+        """host: the launch's pack (`pack_layout`).
+        tokens/slots/positions/temps (Sb,); tables (Sb,
         max_blocks_per_seq). Run the model's decode step, each layer
         reading its lanes' context through the tables: with the kernel to
         each lane's own length where `reads_by_kernel` allows, else as far
@@ -862,6 +940,9 @@ class ModelRunner:
         program left at its slot. Recurrent state moves one step in the
         slots of the step's lanes; a padded lane (slot -1) and a slot no
         lane owns keep theirs."""
+        f = self._unpacked("decode", host)
+        tokens, slots, positions = f["tokens"], f["slots"], f["positions"]
+        tables = tuple(f["tables"])
         Bs = self.block_size
         tokens = jnp.where(tokens >= 0, tokens,
                            slot_tokens[jnp.maximum(slots, 0)])
@@ -876,7 +957,8 @@ class ModelRunner:
         offsets = positions % Bs
         k_pages, v_pages = self._write(k_pages, v_pages, block_ids, offsets,
                                        k_new, v_new)
-        nxt = self._sample(logits, temps, topks, topps, step)
+        nxt = self._sample(logits, f["temps"], f["topks"], f["topps"],
+                           f["step"])
         slot_tokens = self._keep_sampled(slot_tokens, slots, nxt)
         return nxt, logits, k_pages, v_pages, slot_tokens, state, tuple(aux)
 
@@ -948,6 +1030,35 @@ class ModelRunner:
         spent["host_arrays"] += len(host)
         spent["host_bytes"] += sum(a.nbytes for a in host)
 
+    def _layout(self, kind: str, rows: int) -> tuple[int, dict]:
+        """`pack_layout` of a `kind` program of `rows` at this runner's
+        shapes."""
+        return pack_layout(kind, rows, len(self.layouts),
+                           self.max_blocks_per_seq)
+
+    def _pack(self, kind: str, rows: int, **values
+              ) -> tuple[np.ndarray, dict]:
+        """A launch's pack (`pack_layout`) and its fields as views to fill
+        (`unpack`), all zeros as it comes (token 0, the null page, greedy)
+        but for `values`, each written to every element of its field.
+        A fresh array every launch, written by nobody once the jitted call
+        has it: the runtime reads a host buffer when it pleases (the CPU
+        backend may not copy it at all), and with a step in flight the
+        next launch is prepared before this one has run."""
+        size, layout = self._layout(kind, rows)
+        host = np.zeros((size,), np.int32)
+        fields = unpack(host, layout)
+        for name, value in values.items():
+            fields[name][...] = value
+        return host, fields
+
+    def _unpacked(self, kind: str, host) -> dict:
+        """The fields of the pack a `kind` program is traced on, its
+        bucket found again from the array's length (`pack_layout`)."""
+        base, one = (self._layout(kind, rows)[0] for rows in (0, 1))
+        rows = (host.shape[0] - base) // (one - base)
+        return unpack(host, self._layout(kind, rows)[1])
+
     @property
     def resident_leaves(self) -> int:
         """Arrays every call hands the runtime already on the device: the
@@ -988,33 +1099,29 @@ class ModelRunner:
         flat = len(table) == 0 or np.ndim(table[0]) == 0
         return [table] * len(self.layouts) if flat else table
 
-    def _tables(self, table) -> tuple:
-        """A lane's block tables as the programs take them, one a kind:
-        (max_blocks_per_seq,) i32, the null page behind a table's end."""
-        out = []
-        for t in self._lists(table):
-            tab = np.zeros((self.max_blocks_per_seq,), np.int32)
+    def _tables(self, table, out: np.ndarray) -> np.ndarray:
+        """A lane's block tables as the programs take them, one a kind,
+        written into `out` (kinds, max_blocks_per_seq), zeros as it comes:
+        the null page behind a table's end."""
+        for tab, t in zip(out, self._lists(table)):
             tab[:len(t)] = t
-            out.append(tab)
-        return tuple(out)
+        return out
 
-    def _page_ids(self, tables: tuple, start: int, n: int, width: int
-                  ) -> tuple:
+    def _page_ids(self, table, start: int, n: int, width: int,
+                  out: np.ndarray) -> None:
         """The pages of a program's `width` rows from position `start` (on
         a page's edge), one id a group of `block_size` rows and kind of KV
-        layer: the table's page for a group that holds one of the `n`
-        valid rows, the null page for a group that is all padding. The
-        rows are counted as written, by the path the program takes."""
+        layer, written into `out` (kinds, max_blocks_per_seq), zeros as it
+        comes: the lane's page for a group that holds one of the `n`
+        valid rows, the null page for a group that is all padding (and
+        behind the `group_pages(width)` ids the program reads). The rows
+        are counted as written, by the path the program takes."""
         first = start // self.block_size
-        out = []
-        for lay, tab, written in zip(self.layouts, tables,
-                                     self.rows_written.values()):
-            ids = np.zeros((lay.group_pages(width),), np.int32)
-            valid = lay.group_pages(n)
-            ids[:valid] = tab[first:first + valid]
-            out.append(ids)
+        for lay, t, ids, written in zip(self.layouts, self._lists(table),
+                                        out, self.rows_written.values()):
+            pages = t[first:first + lay.group_pages(n)]
+            ids[:len(pages)] = pages
             written["paged" if lay.whole_pages(width) else "rowwise"] += n
-        return tuple(out)
 
     def launch_prefill(self, token_ids: Sequence[int],
                        table: Sequence, temperature: float,
@@ -1026,16 +1133,13 @@ class ModelRunner:
         with self.phases.phase("prepare"):
             n = len(token_ids)
             Tb = self.prefill_bucket(n)
-            toks = np.zeros((1, Tb), np.int32)
-            toks[0, :n] = token_ids
-            page_ids = self._page_ids(self._tables(table), 0, n, Tb)
-            temp = np.asarray([temperature], np.float32)
-            topk = np.asarray([top_k], np.int32)
-            topp = np.asarray([top_p], np.float32)
+            host, f = self._pack("prefill", Tb, last_idx=n - 1, slot=slot,
+                                 temps=temperature, topks=top_k, topps=top_p)
+            f["tokens"][0, :n] = token_ids
+            self._page_ids(table, 0, n, Tb, f["page_ids"])
             self._step_counter += 1
+            f["step"][...] = self._step_counter
         with self.phases.phase("dispatch"):
-            last_row, slot, step = (np.int32(n - 1), np.int32(slot),
-                                    np.int32(self._step_counter))
             before = tracing.jit_cache_size(self._prefill_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
@@ -1043,13 +1147,11 @@ class ModelRunner:
                 (nxt, last, self.k_pages, self.v_pages, self.slot_tokens,
                  self.state, aux) = self._prefill_jit(
                     self.params, self.k_pages, self.v_pages,
-                    self.slot_tokens, self.state, toks, last_row,
-                    page_ids, slot, temp, topk, topp, step)
+                    self.slot_tokens, self.state, host)
                 wall = time.perf_counter() - w0
             self._note_compile("prefill", self._prefill_jit, before,
                                time.perf_counter() - t0)
-            self._note_launch("prefill", wall, (
-                toks, last_row, *page_ids, slot, temp, topk, topp, step))
+            self._note_launch("prefill", wall, (host,))
         return Launched("prefill", (nxt, last), aux, None)
 
     def prefill(self, token_ids: Sequence[int], table: Sequence,
@@ -1078,19 +1180,16 @@ class ModelRunner:
                     f"chunk start {start} not page-aligned "
                     f"(block_size={self.block_size})")
             Tb = self.chunk_bucket(n)
-            toks = np.zeros((1, Tb), np.int32)
-            toks[0, :n] = token_ids
-            tab = self._tables(table)
-            page_ids = self._page_ids(tab, start, n, Tb)
-            temp = np.asarray([temperature], np.float32)
-            topk = np.asarray([top_k], np.int32)
-            topp = np.asarray([top_p], np.float32)
+            host, f = self._pack("chunk", Tb, start=start, last_idx=n - 1,
+                                 slot=slot, temps=temperature, topks=top_k,
+                                 topps=top_p)
+            f["tokens"][0, :n] = token_ids
+            self._tables(table, f["table"])
+            self._page_ids(table, start, n, Tb, f["page_ids"])
             self._note_context("prefill", [start], rows=Tb, real=n)
             self._step_counter += 1
+            f["step"][...] = self._step_counter
         with self.phases.phase("dispatch"):
-            start, last_row, slot, step = (
-                np.int32(start), np.int32(n - 1), np.int32(slot),
-                np.int32(self._step_counter))
             before = tracing.jit_cache_size(self._chunk_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
@@ -1098,14 +1197,11 @@ class ModelRunner:
                 (nxt, last, self.k_pages, self.v_pages, self.slot_tokens,
                  self.state, aux) = self._chunk_jit(
                     self.params, self.k_pages, self.v_pages,
-                    self.slot_tokens, self.state, toks, start, last_row,
-                    page_ids, tab, slot, temp, topk, topp, step)
+                    self.slot_tokens, self.state, host)
                 wall = time.perf_counter() - w0
             self._note_compile("prefill_chunk", self._chunk_jit, before,
                                time.perf_counter() - t0)
-            self._note_launch("prefill", wall, (
-                toks, start, last_row, *page_ids, *tab, slot, temp, topk,
-                topp, step))
+            self._note_launch("prefill", wall, (host,))
         return Launched("prefill", (nxt, last), aux, None)
 
     def prefill_chunk(self, token_ids: Sequence[int], start: int,
@@ -1128,15 +1224,11 @@ class ModelRunner:
                 raise ValueError(f"decode batch of {S}")
             Sb = self.decode_bucket(S)
             order = np.argsort([-it.pos for it in items], kind="stable")
-            toks = np.zeros((Sb,), np.int32)
-            slots = np.full((Sb,), -1, np.int32)
-            poss = np.zeros((Sb,), np.int32)
-            tables = tuple(
-                np.zeros((Sb, self.max_blocks_per_seq), np.int32)
-                for _ in self.layouts)
-            temps = np.zeros((Sb,), np.float32)
-            topks = np.zeros((Sb,), np.int32)
-            topps = np.ones((Sb,), np.float32)
+            # a padded lane leaves its id nowhere and truncates nothing
+            host, f = self._pack("decode", Sb, slots=-1, topps=1.0)
+            toks, slots, poss, tables, temps, topks, topps = (
+                f[name] for name in ("tokens", "slots", "positions",
+                                     "tables", "temps", "topks", "topps"))
             # one list a kind, or one flat list for every kind: the same
             # form in every item of a step
             first = items[0].table
@@ -1156,8 +1248,8 @@ class ModelRunner:
             for written in self.rows_written.values():
                 written["rowwise"] += S
             self._step_counter += 1
+            f["step"][...] = self._step_counter
         with self.phases.phase("dispatch"):
-            step = np.int32(self._step_counter)
             before = tracing.jit_cache_size(self._decode_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
@@ -1165,13 +1257,11 @@ class ModelRunner:
                 (nxt, logits, self.k_pages, self.v_pages, self.slot_tokens,
                  self.state, aux) = self._decode_jit(
                     self.params, self.k_pages, self.v_pages,
-                    self.slot_tokens, self.state, toks, slots, poss, tables,
-                    temps, topks, topps, step)
+                    self.slot_tokens, self.state, host)
                 wall = time.perf_counter() - w0
             self._note_compile("decode", self._decode_jit, before,
                                time.perf_counter() - t0)
-            self._note_launch("decode", wall, (
-                toks, slots, poss, *tables, temps, topks, topps, step))
+            self._note_launch("decode", wall, (host,))
         return Launched("decode", (nxt, logits), aux, order)
 
     def decode(self, items: Sequence[DecodeItem]
@@ -1203,43 +1293,33 @@ class ModelRunner:
                 raise ValueError(
                     f"drafted run past max_model_len: pos {pos} + "
                     f"{n_draft} drafts >= {self.max_model_len}")
-            toks = np.zeros((1, W), np.int32)
-            toks[0, 0] = token
-            toks[0, 1:1 + n_draft] = draft
-            tab = self._tables(table)
+            host, f = self._pack("verify", W, start=pos, n_draft=n_draft,
+                                 temps=temperature, topks=top_k, topps=top_p)
+            f["tokens"][0, 0] = token
+            f["tokens"][0, 1:1 + n_draft] = draft
+            tab = self._tables(table, f["table"])
             positions = pos + np.arange(W)
             # padded tail rows write to the null page at in-range offsets
-            block_ids = tuple(np.where(
-                np.arange(W) <= n_draft,
-                t[np.minimum(positions, self.max_model_len - 1)
-                  // self.block_size],
-                0).astype(np.int32) for t in tab)
-            offsets = np.asarray(positions % self.block_size, np.int32)
-            temps = np.full((W,), temperature, np.float32)
-            topks = np.full((W,), top_k, np.int32)
-            topps = np.full((W,), top_p, np.float32)
+            f["block_ids"][:, :n_draft + 1] = tab[
+                :, positions[:n_draft + 1] // self.block_size]
+            f["offsets"][:] = positions % self.block_size
             self._note_context("verify", [pos], rows=W)
             for written in self.rows_written.values():
                 written["rowwise"] += n_draft + 1
             self._step_counter += 1
+            f["step"][...] = self._step_counter
         with self.phases.phase("dispatch"):
-            pos, n_draft, step = (np.int32(pos), np.int32(n_draft),
-                                  np.int32(self._step_counter))
             before = tracing.jit_cache_size(self._verify_jit)
             t0 = time.perf_counter()
             with self._mesh_ctx(), self._jit_lock:
                 w0 = time.perf_counter()
                 emitted, n_acc, logits, self.k_pages, self.v_pages, aux = \
                     self._verify_jit(
-                        self.params, self.k_pages, self.v_pages, toks,
-                        pos, n_draft, block_ids, offsets, tab, temps,
-                        topks, topps, step)
+                        self.params, self.k_pages, self.v_pages, host)
                 wall = time.perf_counter() - w0
             self._note_compile("verify", self._verify_jit, before,
                                time.perf_counter() - t0)
-            self._note_launch("verify", wall, (
-                toks, pos, n_draft, *block_ids, offsets, *tab, temps,
-                topks, topps, step))
+            self._note_launch("verify", wall, (host,))
         with self.phases.phase("fetch"):
             n_acc, emitted, logits = self._fetch("verify", n_acc, emitted,
                                                  logits, aux=aux)

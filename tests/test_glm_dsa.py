@@ -403,8 +403,11 @@ with open(os.path.join(os.path.dirname(__file__), "data",
 def test_mimo_v2s_programs_lower_as_before(which):
     """mimo_v2's prefill, chunk and decode programs at its tiny preset
     lower to the StableHLO they lowered to before a kind could choose its
-    slots (recorded at PR 37's commit by this very function; gpt2's,
-    llama's and nemotron_h's are held by tests/test_mimo_v2.py)."""
+    slots (gpt2's, llama's and nemotron_h's are held by
+    tests/test_mimo_v2.py). Recorded at PR 37's commit by this very
+    function, and anew at PR 56, whose programs take a launch's host
+    arguments as one array (tests/test_mimo_v2.py says what holds their
+    bodies)."""
     assert _lowered_mimo()[which] == HLO_AT_THE_PARENT[which]
 
 
@@ -418,25 +421,21 @@ def _lowered_mimo(_cache={}):
         jax.random.PRNGKey(0))
     r = ModelRunner(adapter, cfg, params, block_size=4, num_blocks=16,
                     max_model_len=32, max_batch_size=4, prefill_chunk_size=8)
-    S, i32, f32 = jax.ShapeDtypeStruct, jnp.int32, jnp.float32
+    S, i32 = jax.ShapeDtypeStruct, jnp.int32
     kp = tuple(S(lay.shape, cfg.dtype) for lay in r.layouts)
     vp = tuple(S(lay.v_shape, cfg.dtype) for lay in r.layouts)
-    n = len(r.layouts)
     ids = S((4,), i32)
-    m = r.max_blocks_per_seq
-    one = (S((1,), f32), S((1,), i32), S((1,), f32), S((), i32))
+
+    def host(kind, bucket):  # the launch's pack, by its length
+        return S((r._layout(kind, bucket)[0],), i32)
+
     texts = {
         "prefill": jax.jit(r._prefill_impl).lower(
-            params, kp, vp, ids, {}, S((1, 8), i32), S((), i32),
-            (S((2,), i32),) * n, S((), i32), *one),
+            params, kp, vp, ids, {}, host("prefill", 8)),
         "chunk": jax.jit(r._chunk_impl).lower(
-            params, kp, vp, ids, {}, S((1, 8), i32), S((), i32),
-            S((), i32), (S((2,), i32),) * n, (S((m,), i32),) * n,
-            S((), i32), *one),
+            params, kp, vp, ids, {}, host("chunk", 8)),
         "decode": jax.jit(r._decode_impl).lower(
-            params, kp, vp, ids, {}, S((4,), i32), S((4,), i32),
-            S((4,), i32), (S((4, m), i32),) * n, S((4,), f32), S((4,), i32),
-            S((4,), f32), S((), i32)),
+            params, kp, vp, ids, {}, host("decode", 4)),
     }
     _cache.update({
         "mimo_v2." + name: hashlib.sha256(re.sub(

@@ -17,7 +17,6 @@ import numpy as np
 import pytest
 
 from ray_tpu.serve.llm import SamplingParams, SpeculativeConfig
-from ray_tpu.serve.llm.cache import KVLayout
 from ray_tpu.serve.llm.runner import DecodeItem, ModelRunner, adapters
 # engines at tiny presets (pages of 4, chunks of 8: a chunk is two whole
 # pages), seeded prompts, and what each client saw of its request
@@ -28,24 +27,27 @@ BS = 4
 
 def _rowwise(patch):
     """The write of before PR 37, for engines BUILT AND RUN while `patch`
-    holds: the host hands a block id a ROW (the null page for a padded
-    row), the program scatters row by row."""
+    holds: a block id a ROW (the null page for a padded row), scattered
+    row by row. The program makes the ids itself, from the page ids and
+    the count of valid rows in its pack (since PR 56 a launch's one host
+    array has a page id a group of rows and no field for one a row)."""
+    unpacked = ModelRunner._unpacked
 
-    def block_ids(self, tables, start, n, width):
-        out = []
-        for tab in tables:
-            ids = np.zeros((width,), np.int32)
-            ids[:n] = tab[(start + np.arange(n)) // self.block_size]
-            out.append(ids)
-        return tuple(out)
+    def noting_the_valid_rows(self, kind, host):
+        fields = unpacked(self, kind, host)
+        self._traced_last_idx = fields.get("last_idx")
+        return fields
 
-    def write_rows(self, pages, ids, rows):
-        assert ids.shape[0] == rows.shape[1]
-        return self.write(pages, ids,
-                          jnp.arange(rows.shape[1]) % self.block_size, rows)
+    def write_rows(self, k_pages, v_pages, page_ids, k, v):
+        at = jnp.arange(jax.tree.leaves(k)[0].shape[2])  # the bucket's rows
+        ids = tuple(jnp.where(at <= self._traced_last_idx,
+                              pages[at // self.block_size], 0)
+                    for pages in page_ids)
+        return self._write(k_pages, v_pages, ids, at % self.block_size,
+                           k, v, lane=0)
 
-    patch.setattr(ModelRunner, "_page_ids", block_ids)
-    patch.setattr(KVLayout, "write_pages", write_rows)
+    patch.setattr(ModelRunner, "_unpacked", noting_the_valid_rows)
+    patch.setattr(ModelRunner, "_write_pages", write_rows)
 
 
 def _both(monkeypatch, run, **kw):
@@ -147,10 +149,9 @@ def test_two_kinds_of_kv_layer_with_pages_released_mid_prompt(monkeypatch):
     launches = []
     page_ids = ModelRunner._page_ids
 
-    def spy(self, tables, start, n, width):
-        ids = page_ids(self, tables, start, n, width)
-        launches.append((n, ids))
-        return ids
+    def spy(self, table, start, n, width, out):
+        page_ids(self, table, start, n, width, out)
+        launches.append((n, out.copy()))
 
     monkeypatch.setattr(ModelRunner, "_page_ids", spy)
     engine = _engine("mimo_v2")

@@ -480,29 +480,19 @@ def served_runner(one_chip, request):
     return request.param, runner, params, (pool, state), cast
 
 
-# program -> (runner method, argument shapes after (params, k, v), rows
-# sampled); "m" is max_blocks_per_seq, "p" the monolithic prefill bucket,
-# "s" the decode lanes; temperature, top-k and top-p follow, then the step.
-# Prefill, chunk and decode take the device-resident last sampled ids
-# first ("s" of them) and the slot(s) they leave theirs at (PR 31), and
-# behind the ids the lanes' recurrent state ("state": {} but for a family
-# that has it). "k": one such argument a kind of KV layer (page or block
-# ids and tables), bare where the family has one kind. A prompt's and a
-# chunk's programs take one page id a group of 16 rows ("g" of them in the
-# monolithic prefill bucket, 16 in a chunk of 256) since PR 37
+# program -> (runner method, the kind and the bucket of the pack it takes
+# from the host (`runner.pack_layout`: tokens, positions, page or block ids,
+# tables, the sampling fields and the step as ONE int32 array since PR 56,
+# eight to ten arguments until then), whether it takes, before the pack,
+# the device-resident last sampled ids ("s" of them, PR 31) and the lanes'
+# recurrent state ({} but for a family that has it)). "p" is the
+# monolithic prefill bucket, "s" the decode lanes. A prompt's and a
+# chunk's programs read one page id a group of 16 rows since PR 37
 PROGRAMS = {
-    "prefill": ("_prefill_impl", [(("s",), "i"), "state", ((1, "p"), "i"),
-                                  ((), "i"), (("g",), "k"),
-                                  ((), "i")], 1),
-    "chunk-256": ("_chunk_impl", [(("s",), "i"), "state", ((1, 256), "i"),
-                                  ((), "i"), ((), "i"), ((16,), "k"),
-                                  (("m",), "k"), ((), "i")], 1),
-    "verify-5": ("_verify_impl", [((1, 5), "i"), ((), "i"), ((), "i"),
-                                  ((5,), "k"), ((5,), "i"),
-                                  (("m",), "k")], 5),
-    "decode": ("_decode_impl", [(("s",), "i"), "state", (("s",), "i"),
-                                (("s",), "i"), (("s",), "i"),
-                                (("s", "m"), "k")], "s"),
+    "prefill": ("_prefill_impl", "prefill", "p", True),
+    "chunk-256": ("_chunk_impl", "chunk", 256, True),
+    "verify-5": ("_verify_impl", "verify", 5, False),
+    "decode": ("_decode_impl", "decode", "s", True),
 }
 
 
@@ -553,23 +543,16 @@ def test_no_serve_program_copies_the_pool(one_chip, served_runner, program,
         pytest.skip("the engine refuses speculation with a window kind")
     if program == "verify-5" and runner.layouts[0].latent:
         pytest.skip("the engine refuses speculation with a latent kind")
-    method, shapes, lanes = PROGRAMS[program]
-    sizes = {"m": runner.max_blocks_per_seq, "p": MODELS[model][4],
-             "g": MODELS[model][4] // 16, "s": runner.max_batch_size}
+    method, kind, bucket, carries_ids = PROGRAMS[program]
+    sizes = {"p": MODELS[model][4], "s": runner.max_batch_size}
 
     def compiled_for(sizes):
-        def arg(shape, kind):
-            one = jax.ShapeDtypeStruct(
-                tuple(sizes.get(d, d) for d in shape),
-                jnp.float32 if kind == "f" else jnp.int32, sharding=one_chip)
-            if kind == "k" and len(runner.layouts) > 1:
-                return (one,) * len(runner.layouts)
-            return one
+        def i32(n):
+            return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
 
-        n = sizes.get(lanes, lanes)
-        args = [state if s == "state" else arg(*s) for s in shapes] + [
-            arg((n,), "f"), arg((n,), "i"), arg((n,), "f"), arg((), "i")]
-        donate = (1, 2) if program == "verify-5" else (1, 2, 4)
+        host = i32(runner._layout(kind, sizes.get(bucket, bucket))[0])
+        args = [i32(sizes["s"]), state, host] if carries_ids else [host]
+        donate = (1, 2, 4) if carries_ids else (1, 2)
         return jax.jit(getattr(runner, method), donate_argnums=donate) \
             .lower(params, *pool, *args).compile()
 

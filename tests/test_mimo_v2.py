@@ -485,13 +485,16 @@ def test_the_other_families_programs_lower_as_before(which):
     before a model had kinds of KV layer, windows and sinks (recorded at
     PR 33's commit by this very function; source locations are not in the
     text, and the results' pytree paths, which name no operation, are
-    dropped). The three `decode` hashes are PR 33's still: the program
-    whose step sets the open-loop cells' token gap is the parent's. The
-    six `prefill` and `chunk` hashes were recorded anew at PR 37, by
-    `_lowered` as it stands, because those programs changed by design:
-    they take one page id a group of `block_size` rows in place of (block
-    ids, offsets) and store their rows a page at a time (pages of 4 and
-    chunks of 8 here: two whole pages)."""
+    dropped). The six `prefill` and `chunk` hashes were recorded anew at
+    PR 37, because those programs changed by design: they take one page
+    id a group of `block_size` rows in place of (block ids, offsets) and
+    store their rows a page at a time (pages of 4 and chunks of 8 here:
+    two whole pages). All nine were recorded anew at PR 56, by `_lowered`
+    as it stands, because every program's ARGUMENTS changed by design:
+    the eight to ten host arrays of a launch are one, the pack
+    (`runner.pack_layout`), sliced in the program's first lines. That the
+    bodies below those lines give what they gave is held to the bit by
+    tests/test_packed_launch.py, on results pinned before the change."""
     family, program = which.split(".")
     assert _lowered(family)[program] == HLO_AT_THE_PARENT[which]
 
@@ -509,23 +512,21 @@ def _lowered(family, _cache={}):
         jax.random.PRNGKey(0))
     r = ModelRunner(adapter, cfg, params, block_size=4, num_blocks=16,
                     max_model_len=32, max_batch_size=4, prefill_chunk_size=8)
-    S, i32, f32 = jax.ShapeDtypeStruct, jnp.int32, jnp.float32
+    S, i32 = jax.ShapeDtypeStruct, jnp.int32
     pool = S(r.layouts[0].shape, cfg.dtype)
     ids = S((4,), i32)
     state = jax.tree.map(lambda a: S(a.shape, a.dtype), r.state)
-    m = r.max_blocks_per_seq
-    one = (S((1,), f32), S((1,), i32), S((1,), f32), S((), i32))
+
+    def host(kind, bucket):  # the launch's pack, by its length
+        return S((r._layout(kind, bucket)[0],), i32)
+
     texts = {
         "prefill": jax.jit(r._prefill_impl).lower(
-            params, pool, pool, ids, state, S((1, 8), i32), S((), i32),
-            S((2,), i32), S((), i32), *one),
+            params, pool, pool, ids, state, host("prefill", 8)),
         "chunk": jax.jit(r._chunk_impl).lower(
-            params, pool, pool, ids, state, S((1, 8), i32), S((), i32),
-            S((), i32), S((2,), i32), S((m,), i32), S((), i32), *one),
+            params, pool, pool, ids, state, host("chunk", 8)),
         "decode": jax.jit(r._decode_impl).lower(
-            params, pool, pool, ids, state, S((4,), i32), S((4,), i32),
-            S((4,), i32), S((4, m), i32), S((4,), f32), S((4,), i32),
-            S((4,), f32), S((), i32)),
+            params, pool, pool, ids, state, host("decode", 4)),
     }
     _cache[family] = {
         name: hashlib.sha256(re.sub(

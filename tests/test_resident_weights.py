@@ -66,27 +66,39 @@ def both_sides():
                     num_blocks=16, max_model_len=32, max_batch_size=2,
                     prefill_chunk_size=8)
     assert r.weights["cast_leaves"] == CAST
-    i32, f32 = np.int32, np.float32
-    table = np.asarray([3, 7, 2, 9, 0, 0, 0, 0], i32)
-    greedy = (np.zeros(1, f32), np.zeros(1, i32), np.ones(1, f32))
+    table = [3, 7, 2, 9]
+
+    def packed(kind, bucket, **fields):
+        """The program's one host array (`ModelRunner._pack`) with `fields`
+        written in, the rest as a greedy launch that keeps its id nowhere
+        leaves them."""
+        host, f = r._pack(kind, bucket)
+        f["slots" if kind == "decode" else "slot"][...] = -1
+        f["topps"][...] = 1.0
+        for name, value in fields.items():
+            value = np.asarray(value)  # a scalar, or a field's first ids
+            f[name][(..., slice(value.shape[-1])) if value.ndim
+                    else ...] = value
+        return host
 
     def run(tree):
         out = {}
-        ids, none = r.slot_tokens, i32(-1)  # the resident sampled ids
+        ids = r.slot_tokens  # the resident sampled ids
         st = r.state  # {}: gpt2 carries no recurrent state
         _, out["prefill"], k, v, ids, st, _ = jax.jit(r._prefill_impl)(
-            tree, r.k_pages, r.v_pages, ids, st,
-            np.arange(1, 9, dtype=i32)[None], i32(7), table[:2], none,
-            *greedy, i32(1))
+            tree, r.k_pages, r.v_pages, ids, st, packed(
+                "prefill", 8, tokens=np.arange(1, 9), last_idx=7,
+                page_ids=table[:2], step=1))
         _, out["chunk"], k, v, ids, st, _ = jax.jit(r._chunk_impl)(
-            tree, k, v, ids, st, np.arange(9, 17, dtype=i32)[None], i32(8),
-            i32(5), table[2:4], table, none, *greedy, i32(2))
-        tables = np.stack([table, np.zeros_like(table)])
+            tree, k, v, ids, st, packed(
+                "chunk", 8, tokens=np.arange(9, 17), start=8,
+                last_idx=5, page_ids=table[2:4], table=table, step=2))
         _, out["decode"], k, v, ids, st, _ = jax.jit(r._decode_impl)(
-            tree, k, v, ids, st, np.asarray([5, 1], i32),
-            np.asarray([-1, -1], i32), np.asarray([14, 0], i32), tables,
-            np.zeros(2, f32), np.zeros(2, i32), np.ones(2, f32), i32(3))
-        out["pools"] = np.stack([np.asarray(k, f32), np.asarray(v, f32)])
+            tree, k, v, ids, st, packed(
+                "decode", 2, tokens=[5, 1], positions=[14, 0],
+                tables=[table, [0] * 4], step=3))
+        out["pools"] = np.stack([np.asarray(k, np.float32),
+                                 np.asarray(v, np.float32)])
         return {name: np.asarray(a) for name, a in out.items()}
 
     return run(r.params), run(given)

@@ -306,11 +306,14 @@ def test_launch_and_fetch_accounts_count_what_was_run(model):
         assert launch[kind]["host_arrays"] == sum(h for _, h, _ in calls)
         assert launch[kind]["host_bytes"] == sum(b for _, _, b in calls) \
             > 4 * launch[kind]["host_arrays"]
-    # tokens, slots, positions, the kind's tables, three sampling arrays
-    # and the step's count; a chunk's program two more than a prompt's
-    assert {h for _, h, _ in seen["decode"]} == {8}
-    assert {h for _, h, _ in seen["chunk"]} == {10}
-    assert {h for _, h, _ in seen["prefill"]} <= {8}
+    # tokens, slots, positions, the kind's tables, three sampling fields
+    # and the step's count travel as one array, the launch's pack (PR 56:
+    # they were eight arrays, ten of a chunk's program): one transfer
+    assert {h for _, h, _ in seen["decode"]} == {1}
+    assert {h for _, h, _ in seen["chunk"]} == {1}
+    assert {h for _, h, _ in seen["prefill"]} <= {1}
+    for kind in ("prefill", "decode"):
+        assert launch[kind]["host_arrays"] == launch[kind]["calls"]
     # the jitted calls alone are part of the `dispatch` phase
     assert 0 < sum(n["wall_s"] for n in launch.values()) \
         < first["dispatch_phase"]
