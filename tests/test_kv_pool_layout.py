@@ -8,6 +8,7 @@ a leading `:` window each made XLA copy both pools through a temporary in
 every program; any one of them coming back shows here as a pool-sized
 `copy` and as gigabytes of temporaries."""
 
+import collections
 import dataclasses
 import math
 import os
@@ -816,16 +817,12 @@ def _materialised(text):
     return out
 
 
-@pytest.mark.parametrize("chips", [1, 4])
-def test_train_step_writes_no_float32_logits(v5e, chips):
-    """Compiled for the v5e, gpt2-small's train step (the train cells'
-    program at 4 x 256 a chip) writes the logits out in bf16, as the
-    head's product returns them, and nothing else of their size in any
-    dtype: the loss reads them by reductions (`ops/cross_entropy.py`),
-    which fuse with their producer. While the
-    target's log-probability was gathered from `log_softmax`, the step
-    wrote `logits - max` out as `f32[B,T,50304]` for the gather to index,
-    15 ms of a 282 ms step at 32 x 1024 (PERF.md section 6, PR 45)."""
+@pytest.fixture(scope="module", params=[1, 4])
+def train_step(v5e, request):
+    """(chips, B, T, cfg, the compiled text) of gpt2-small's train step as
+    the train cells run it (32 x 1024 a chip, adamw, the state donated),
+    compiled for one described v5e chip and for a `data=4` mesh of the
+    four. About 15 s each."""
     import optax
 
     from ray_tpu.models.gpt2 import (
@@ -842,7 +839,7 @@ def test_train_step_writes_no_float32_logits(v5e, chips):
         state_shardings,
     )
 
-    B, T = 4, 256
+    chips, B, T = request.param, 32, 1024
     cfg = GPT2Config.small()
     tx = optax.adamw(3e-4, weight_decay=0.1)
     mesh = build_mesh(MeshSpec(data=-1), devices=v5e[:chips])
@@ -863,8 +860,19 @@ def test_train_step_writes_no_float32_logits(v5e, chips):
             described(state, state_shardings(gpt2_partition_rules(), state,
                                              mesh)),
             described(batch, batch_shardings(mesh, batch))).compile()
+    return chips, B, T, cfg, compiled.as_text()
 
-    results = _materialised(compiled.as_text())
+
+def test_train_step_writes_no_float32_logits(train_step):
+    """Compiled for the v5e, gpt2-small's train step writes the logits
+    out in bf16, as the head's product returns them, and nothing else of
+    their size in any dtype: the loss reads them by reductions
+    (`ops/cross_entropy.py`), which fuse with their producer. While the
+    target's log-probability was gathered from `log_softmax`, the step
+    wrote `logits - max` out as `f32[B,T,50304]` for the gather to index,
+    15 ms of a 282 ms step at 32 x 1024 (PERF.md section 6, PR 45)."""
+    chips, B, T, cfg, text = train_step
+    results = _materialised(text)
     assert len(results) > 100
     # a chip's share of the logits, under any leading shape
     elements = B * T * cfg.padded_vocab
@@ -875,3 +883,74 @@ def test_train_step_writes_no_float32_logits(v5e, chips):
                 wide.setdefault(dtype, []).append(name)
     print(f"train step on {chips} chip(s): {wide}")
     assert set(wide) == {"bf16"}, wide
+
+
+Fusion = collections.namedtuple(
+    "Fusion", "name result kind cycles operands product")
+
+
+def _fusions(text):
+    """Every fusion instruction of a compiled module: its name, result,
+    kind, the compiler's estimated cycles (or None), its operands' names,
+    and whether the fused computation holds a `convolution`, which is
+    what a product is on the TPU."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            bodies[name] = []
+        elif name:
+            bodies[name].append(line)
+    out = []
+    for lines in bodies.values():
+        for line in lines:
+            m = re.match(r"\s+(?:ROOT )?(\S+) = (\(.*?\)|\S+) fusion\((.*?)\)"
+                         r", kind=(\w+), calls=(\S+?),", line)
+            if m:
+                name, result, operands, kind, calls = m.groups()
+                cycles = re.search(r'"estimated_cycles":"(\d+)"', line)
+                out.append(Fusion(
+                    name, result, kind, cycles and int(cycles.group(1)),
+                    re.findall(r"%[\w.-]+", operands),
+                    any(" convolution(" in b for b in bodies[calls])))
+    return out
+
+
+def test_train_step_parts_the_head_product_from_the_update(train_step):
+    """Compiled for the v5e, the head's backward product `dlogits^T @ x`
+    (the gradient of `wte`, with the look-up's scatter-add result added
+    in) is an operation of its own, and `wte`'s adamw update an
+    elementwise one that also returns this leaf's term of the gradient
+    norm, as every other leaf's is. Until `make_train_step` fenced the
+    gradients from their consumers, one chip ran the update (param, mu
+    and nu, float32 in and out) as an output fusion BEHIND the product,
+    45.1 M estimated cycles and 31 ms a step at 32 x 1024 where the
+    product alone is 20.6 M and the update 2.4 M (PERF.md section 6, PR
+    60); on four chips the all-reduce already stood between them. The
+    fence stands BEFORE `optax.global_norm`: after it, every leaf's
+    squared-norm term becomes a reduction of its own that reads the
+    gradient a second time."""
+    chips, _, _, cfg, text = train_step
+    fusions = _fusions(text)
+    wte = f"[{cfg.padded_vocab},{cfg.n_embd}]"
+    for f in fusions:
+        if f.product:
+            assert f.result.count(wte) <= 1, f
+    # the norm's terms ride in the updates: no operation returns scalars
+    # alone from an operand as large as the smallest gradient
+    sizes = {name: max((math.prod(map(int, d.split(",")))
+                        for d in re.findall(r"\[([\d,]+)\]", result)),
+                       default=1)
+             for name, result in _materialised(text)}
+    alone = [f.name for f in fusions
+             if not re.search(r"\[\d", f.result)
+             and any(sizes.get(o, 1) >= cfg.n_embd for o in f.operands)]
+    assert not alone, alone
+    updates = [f for f in fusions
+               if f.result.count("f32" + wte) == 3 and "f32[]" in f.result]
+    assert [f.kind for f in updates] == ["kLoop"], updates
+    products = [f for f in fusions if f.product and wte in f.result]
+    assert [f.kind for f in products] == ["kOutput"], products
+    print(f"train step on {chips} chip(s): the product "
+          f"{products[0].name} {products[0].cycles:,} cycles, the update "
+          f"{updates[0].name} {updates[0].cycles:,} cycles")

@@ -282,3 +282,99 @@ def test_gather_share_gauge_populates_at_stage3(mesh):
     share = g._values.get((), None)
     assert share is not None, "gauge never set"
     assert 0.0 <= share <= 1.0, share
+
+
+def _plain_step(loss_fn, tx, mesh, rules, stage, accum):
+    """`make_train_step`'s program written out with NO fence between
+    the backward pass and the gradients' consumers: what the fenced
+    step is held to."""
+    import jax.numpy as jnp
+
+    from ray_tpu.train.spmd import TrainState, zero1_shardings
+
+    def pin(tree):
+        return jax.tree.map(jax.lax.with_sharding_constraint, tree,
+                            rules.shardings(tree, mesh))
+
+    def scatter(tree):
+        return jax.tree.map(jax.lax.with_sharding_constraint, tree,
+                            zero1_shardings(rules, tree, mesh))
+
+    def step(state, batch):
+        params = pin(state.params) if stage >= 3 else state.params
+        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        gnorm = optax.global_norm(grads)
+        held = state.params
+        if stage >= 1:
+            grads = scatter(pin(grads))
+            held = held if stage >= 3 else scatter(held)
+        if accum > 1:
+            acc = jax.tree.map(jnp.add, state.grad_accum, grads)
+            boundary = (state.step + 1) % accum == 0
+
+            def at_boundary(new, old):
+                return jax.tree.map(
+                    lambda a, b: jnp.where(boundary, a, b), new, old)
+
+            updates, opt = tx.update(
+                jax.tree.map(lambda a: a / accum, acc), state.opt_state,
+                held)
+            new = at_boundary(optax.apply_updates(held, updates), held)
+            opt = at_boundary(opt, state.opt_state)
+            acc = at_boundary(jax.tree.map(jnp.zeros_like, acc), acc)
+        else:
+            updates, opt = tx.update(grads, state.opt_state, held)
+            new, acc = optax.apply_updates(held, updates), state.grad_accum
+        if stage in (1, 2):
+            new = pin(new)
+        elif stage >= 3:
+            new = scatter(new)
+        return (TrainState(params=new, opt_state=opt, step=state.step + 1,
+                           grad_accum=acc),
+                {"loss": loss, "grad_norm": gnorm})
+
+    return jax.jit(step)
+
+
+@pytest.mark.parametrize("stage,accum", [(0, 1), (0, 2), (1, 1), (2, 1),
+                                         (2, 2), (3, 1)])
+def test_gradient_fence_is_the_identity_on_values(mesh, stage, accum):
+    """`make_train_step` fences the gradients from whatever consumes
+    them (one `optimization_barrier` after `value_and_grad`, which keeps
+    XLA:TPU from hanging the embedding's update behind the head's
+    backward product; TRAINING.md "What a step compiles to"). The fence
+    moves no value: three adamw steps give the loss, the gradient norm,
+    the params and the optimizer state of the same step written out
+    without it, on every rung of the ladder."""
+    rules, init_fn, loss_fn, batch = _gpt2_parts(mesh, seed=7)
+    tx = optax.adamw(3e-3, weight_decay=0.1)
+
+    def three_steps(step):
+        state = init_sharded_state(init_fn, tx, mesh, rules,
+                                   zero_stage=stage, accum_steps=accum)
+        seen = []
+        with jax.set_mesh(mesh):
+            for _ in range(3):
+                state, m = step(state, batch)
+                seen.append((float(m["loss"]), float(m["grad_norm"])))
+        return state, seen
+
+    fenced = make_train_step(loss_fn, tx, zero_stage=stage,
+                             mesh=mesh if stage else None,
+                             rules=rules if stage else None,
+                             accum_steps=accum, donate=False)
+    s_f, m_f = three_steps(fenced)
+    plain = _plain_step(loss_fn, tx, mesh, rules, stage, accum)
+    s_p, m_p = three_steps(plain)
+    np.testing.assert_allclose(m_f, m_p, rtol=0, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(s_f), jax.tree.leaves(s_p),
+                    strict=True):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=0, atol=1e-5)
+
+    # one fence over the whole tree, beside the one `jax.checkpoint`
+    # puts in a rematerialised block's backward pass
+    with jax.set_mesh(mesh):
+        fences = [f.lower(s_f, batch).as_text().count(
+            "optimization_barrier") for f in (fenced.jitted, plain)]
+    assert fences[0] == fences[1] + 1, fences
