@@ -1,11 +1,14 @@
 """Latent attention (MLA) and the feed-forwards that go with it, each part
 written once: what the glm_dsa family (models/glm_dsa.py: MLA under a
-learned indexer) and the xing4 family (models/xing4.py: MLA that reads
-every cached slot, YaRN frequencies) both run. Both are DeepSeek-V3's
-layer; what differs between them (the indexer; the residual path) stays
-in the family's own module.
+learned indexer), the xing4 family (models/xing4.py: MLA that reads
+every cached slot, YaRN frequencies) and the ling3 family
+(models/ling3.py: one such layer to five linear-attention layers, no
+low-rank query) run. All are DeepSeek-V3's layer; what differs between
+them (the indexer; the residual path; the other mixer) stays in the
+family's own module.
 
 - **queries**: ``c_q = rmsnorm(h W_qa)`` (`q_lora_rank`), ``q = c_q W_qb``
+  (`q_lora_rank` None: ``q = h W_q``, no latent and no norm)
   -> `num_attention_heads` heads of ``qk_nope_head_dim | qk_rope_head_dim``;
   the second part is rotated, **interleaved** pairs ``(2i, 2i + 1)``, at
   the family's frequencies (`cfg.rotate`: `rope` with `plain_frequencies`
@@ -19,11 +22,16 @@ in the family's own module.
   arithmetic;
 - scores are scaled by `cfg.softmax_scale` (``1 / sqrt(qk_head_dim)``,
   times YaRN's ``mscale^2`` where the family has one);
+- the two ways through a layer that reads EVERY cached slot (no indexer):
+  a whole prompt's own rows up-projected (`attend_rows`), a chunk's and a
+  decode step's rows absorbed against the cached latent rows and their
+  own (`attend_cached`);
 - the **dense** SwiGLU feed-forward, and the **routed experts**
   (models/moe.py): a sigmoid router with a selection bias (`noaux_tc`; one
-  group), weights normalised and times `routed_scaling_factor`, a shared
-  expert on every row; `experts_held` and `expert_offset` say which of
-  the router's experts this chip holds.
+  group, or the `topk_group` best of `n_group`), weights normalised and
+  times `routed_scaling_factor`, a shared expert on every row;
+  `experts_held` and `expert_offset` say which of the router's experts
+  this chip holds.
 
 A family's config brings the published field names (`num_attention_heads`,
 `q_lora_rank`, `kv_lora_rank`, `qk_nope_head_dim`, `qk_rope_head_dim`,
@@ -42,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models.moe import routed_experts
+from ray_tpu.ops.context_attention import attend_latent, softmax_over
 
 
 def rmsnorm(x, scale, eps):
@@ -105,12 +114,17 @@ def rope(x, positions, freqs, width: int):
 
 def queries(h, p, positions, cfg):
     """Normed rows h (..., D) -> (q_nope (..., H, nope), q_pe (..., H,
-    rope) rotated, the normed query latent c_q (..., q_lora_rank))."""
+    rope) rotated, the normed query latent c_q (..., q_lora_rank); None
+    where the family has no low-rank pair)."""
     dt = cfg.dtype
     with jax.named_scope("attn.mla.q"):
-        c_q = rmsnorm(h @ p["wq_a"].astype(dt), p["q_norm"],
-                      cfg.rms_norm_eps)
-        q = (c_q @ p["wq_b"].astype(dt)).reshape(
+        if cfg.q_lora_rank is None:
+            c_q, q = None, h @ p["wq"].astype(dt)
+        else:
+            c_q = rmsnorm(h @ p["wq_a"].astype(dt), p["q_norm"],
+                          cfg.rms_norm_eps)
+            q = c_q @ p["wq_b"].astype(dt)
+        q = q.reshape(
             *h.shape[:-1], cfg.num_attention_heads, cfg.qk_head_dim)
         q_pe = cfg.rotate(q[..., cfg.qk_nope_head_dim:], positions)
     return q[..., :cfg.qk_nope_head_dim], q_pe, c_q
@@ -171,6 +185,35 @@ def values_out(att, p, cfg):
     return output(att, p, cfg)
 
 
+def attend_rows(h, p, positions, seen, cfg):
+    """A whole prompt's own rows h (B, T, D), nothing cached: the latent
+    rows up-projected to a K and a V head each, `seen` (B, T, T) the
+    softmax's mask. -> (out (B, T, D), latent rows)."""
+    q_nope, q_pe, _ = queries(h, p, positions, cfg)
+    rows = latent(h, p, positions, cfg)
+    with jax.named_scope("attn.mla.core"):
+        k, v = up_project(rows, q_pe, p, cfg)
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        att = softmax_over(q[:, :, :, None], [(k, v, seen)],
+                           cfg.softmax_scale, cfg.dtype)[:, :, :, 0]
+    return output(att, p, cfg), rows
+
+
+def attend_cached(h, p, positions, own_valid, ctx, layer, cfg):
+    """Rows h (B, T, D) of a chunk or a decode step against every cached
+    latent row of their lanes and their own, absorbed: every head's query
+    on the one latent row, ``W_kvb[v]`` after the softmax. -> (out (B, T,
+    D), latent rows)."""
+    q_nope, q_pe, _ = queries(h, p, positions, cfg)
+    rows = latent(h, p, positions, cfg)
+    q = absorbed_query(q_nope, q_pe, p, cfg)
+    with jax.named_scope("attn.mla.dense"):
+        att = attend_latent(q, rows, own_valid, ctx, layer, cfg.dtype,
+                            values=cfg.kv_lora_rank,
+                            scale=cfg.softmax_scale)
+    return values_out(att, p, cfg), rows
+
+
 def swiglu(h, gate, up, down, dt):
     return (jax.nn.silu(h @ gate.astype(dt)) * (h @ up.astype(dt))) \
         @ down.astype(dt)
@@ -194,5 +237,8 @@ def experts(h, p, cfg):
         scale=cfg.routed_scaling_factor,
         held=(cfg.expert_offset, cfg.experts_held),
         shared=lambda a: swiglu(a, p["ws_gate"], p["ws_up"], p["ws_down"],
-                                dt))
+                                dt),
+        # a family whose config has no group fields routes without a limit
+        n_group=getattr(cfg, "n_group", 1),
+        topk_group=getattr(cfg, "topk_group", 1))
     return y, counts

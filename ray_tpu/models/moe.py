@@ -9,8 +9,10 @@ stand-alone `moe_layer` (GELU experts):
 
 - **route** (`moe.route`): router logits and scores (softmax over all
   experts, or a sigmoid each) in float32, `lax.top_k` (of the scores plus
-  a selection bias where the router has one), weights renormalised only
-  when asked, and the count of pairs per expert, over ALL experts;
+  a selection bias where the router has one; where the router has a group
+  limit, `n_group`, among the experts of its `topk_group` best groups
+  alone), weights renormalised only when asked, and the count of pairs per
+  expert, over ALL experts;
 - **dispatch** (`moe.dispatch`): the routing weights as an (N, E) matrix,
   zero where a token did not choose an expert, cut to the experts held
   here (`held`) where the stacked weights are a share of the router's;
@@ -59,14 +61,19 @@ from jax.sharding import PartitionSpec as P
 
 def route(x: jax.Array, router: jax.Array, k: int, norm_topk: bool, *,
           score: str = "softmax", select_bias: jax.Array | None = None,
-          scale: float = 1.0, norm_eps: float = 0.0):
+          scale: float = 1.0, norm_eps: float = 0.0, n_group: int = 1,
+          topk_group: int = 1):
     """x (N, Dm), router (Dm, E) -> (weights (N, k) f32, experts (N, k)
     i32, pairs per expert (E,) i32, scores (N, E) f32). `score` is
     "softmax" (over ALL experts) or "sigmoid" (each expert for itself);
     the k largest of score + `select_bias` (E,) are chosen, and their
     weights are the scores WITHOUT the bias, summing to one only when
     `norm_topk` (divided by their sum plus `norm_eps`, which a family
-    whose published router has one gives: lfm2's 1e-6), times `scale`."""
+    whose published router has one gives: lfm2's 1e-6), times `scale`.
+    `n_group` > 1 is DeepSeek-V3's group limit (`noaux_tc`): the experts
+    are `n_group` groups of neighbours, a group's score the sum of its
+    two largest biased scores, and only the experts of the `topk_group`
+    best groups can be chosen."""
     with jax.named_scope("moe.route"):
         logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
         if score == "softmax":
@@ -75,11 +82,14 @@ def route(x: jax.Array, router: jax.Array, k: int, norm_topk: bool, *,
             probs = jax.nn.sigmoid(logits)
         else:
             raise ValueError(f"unknown router score {score!r}")
-        if select_bias is None:
+        if select_bias is None and n_group == 1:
             weights, experts = jax.lax.top_k(probs, k)
         else:
-            _, experts = jax.lax.top_k(
-                probs + select_bias.astype(jnp.float32), k)
+            biased = probs if select_bias is None \
+                else probs + select_bias.astype(jnp.float32)
+            if n_group > 1:
+                biased = _within_best_groups(biased, n_group, topk_group)
+            _, experts = jax.lax.top_k(biased, k)
             weights = jnp.take_along_axis(probs, experts, axis=-1)
         if norm_topk:
             total = jnp.sum(weights, axis=-1, keepdims=True)
@@ -91,6 +101,19 @@ def route(x: jax.Array, router: jax.Array, k: int, norm_topk: bool, *,
         counts = jnp.zeros((router.shape[-1],), jnp.int32).at[
             experts.reshape(-1)].add(1)
     return weights, experts, counts, probs
+
+
+def _within_best_groups(biased, n_group: int, topk_group: int):
+    """Biased scores (N, E) with -inf outside each row's `topk_group`
+    best of `n_group` groups of E / n_group neighbouring experts, a
+    group scored by the sum of its two largest."""
+    N, E = biased.shape
+    groups = biased.reshape(N, n_group, E // n_group)
+    best_two = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(best_two, topk_group)
+    keep = jnp.zeros((N, n_group), bool).at[
+        jnp.arange(N)[:, None], kept].set(True)
+    return jnp.where(keep[:, :, None], groups, -jnp.inf).reshape(N, E)
 
 
 def routed_experts(
@@ -106,6 +129,8 @@ def routed_experts(
     held: tuple[int, int] | None = None,
     shared: Callable | None = None,
     norm_eps: float = 0.0,
+    n_group: int = 1,
+    topk_group: int = 1,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """x (N, Dm) -> (out (N, Dm), pairs per expert (E,) i32, router scores
     (N, E) f32). ``expert_fn(rows, mm)`` is one expert's feed-forward
@@ -118,11 +143,13 @@ def routed_experts(
     E (the router's load is the model's, whatever is held), the result is
     the part the held experts give, and what the absent ones would have
     added is left out. ``shared(x) -> (N, Dm)`` is an expert every row
-    goes through, added to the routed sum. `norm_eps` is `route`'s."""
+    goes through, added to the routed sum. `norm_eps`, `n_group` and
+    `topk_group` are `route`'s."""
     N = x.shape[0]
     weights, experts, counts, probs = route(
         x, router, k, norm_topk, score=score, select_bias=select_bias,
-        scale=scale, norm_eps=norm_eps)
+        scale=scale, norm_eps=norm_eps, n_group=n_group,
+        topk_group=topk_group)
     with jax.named_scope("moe.dispatch"):
         per_expert = jnp.zeros((N, router.shape[-1]), weights.dtype).at[
             jnp.arange(N)[:, None], experts].set(weights)

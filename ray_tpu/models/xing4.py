@@ -71,11 +71,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import mla
 from ray_tpu.ops import mhc_maps
-from ray_tpu.ops.context_attention import (
-    attend_latent,
-    causal_rows,
-    softmax_over,
-)
+from ray_tpu.ops.context_attention import causal_rows
 from ray_tpu.parallel.sharding import PartitionRules
 
 Params = Any
@@ -408,34 +404,11 @@ def mhc_post(X, y, post, res, cfg: Xing4Config):
 # the two ways through the attention
 
 
-def _attend_rows(h, p, positions, seen, cfg: Xing4Config):
-    """A whole prompt's own rows h (B, T, D), nothing cached: the latent
-    rows up-projected to a K and a V head each, `seen` (B, T, T) the
-    softmax's mask. -> (out (B, T, D), latent rows)."""
-    q_nope, q_pe, _ = mla.queries(h, p, positions, cfg)
-    latent = mla.latent(h, p, positions, cfg)
-    with jax.named_scope("attn.mla.core"):
-        k, v = mla.up_project(latent, q_pe, p, cfg)
-        q = jnp.concatenate([q_nope, q_pe], axis=-1)
-        att = softmax_over(q[:, :, :, None], [(k, v, seen)],
-                           cfg.softmax_scale, cfg.dtype)[:, :, :, 0]
-    return mla.output(att, p, cfg), latent
-
-
-def _attend_cached(h, p, positions, own_valid, ctx, layer,
-                   cfg: Xing4Config):
-    """Rows h (B, T, D) of a chunk or a decode step against every cached
-    latent row of their lanes and their own, absorbed: every head's query
-    on the one latent row, ``W_kvb[v]`` after the softmax. -> (out (B, T,
-    D), latent rows)."""
-    q_nope, q_pe, _ = mla.queries(h, p, positions, cfg)
-    latent = mla.latent(h, p, positions, cfg)
-    q = mla.absorbed_query(q_nope, q_pe, p, cfg)
-    with jax.named_scope("attn.mla.dense"):
-        att = attend_latent(q, latent, own_valid, ctx, layer, cfg.dtype,
-                            values=cfg.kv_lora_rank,
-                            scale=cfg.softmax_scale)
-    return mla.values_out(att, p, cfg), latent
+# a whole prompt's rows up-projected, a chunk's and a decode step's
+# absorbed: models/mla.py's, under the names benchmark/parity_xing4.py
+# calls them by
+_attend_rows = mla.attend_rows
+_attend_cached = mla.attend_cached
 
 
 def _stack(params, tokens, cfg: Xing4Config, attention):

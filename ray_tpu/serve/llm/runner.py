@@ -155,7 +155,7 @@ class ModelAdapter:
 
 
 def adapters() -> dict[str, ModelAdapter]:
-    """Model registry: the eight families (lazy imports keep `import
+    """Model registry: the nine families (lazy imports keep `import
     ray_tpu.serve` light; no family's module builds anything at
     import)."""
     from ray_tpu.models import (
@@ -163,6 +163,7 @@ def adapters() -> dict[str, ModelAdapter]:
         gpt2,
         granite_hybrid,
         lfm2,
+        ling3,
         llama,
         mimo_v2,
         nemotron_h,
@@ -310,6 +311,22 @@ def adapters() -> dict[str, ModelAdapter]:
             chunk_fn=xing4.xing4_prefill_chunk_kv,
             rules_fn=xing4.xing4_partition_rules,
             kv_kinds=lambda cfg: tuple(KVKind(*k) for k in cfg.kv_kinds()),
+            held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
+        ),
+        "ling3": ModelAdapter(
+            name="ling3",
+            config_cls=ling3.Ling3Config,
+            presets={
+                "tiny": ling3.Ling3Config.tiny,
+                "flash_l7_ep32": ling3.Ling3Config.flash_l7_ep32,
+            },
+            init_fn=ling3.init_ling3,
+            prefill_fn=ling3.ling3_prefill_kv,
+            decode_fn=ling3.ling3_decode_kv,
+            chunk_fn=ling3.ling3_prefill_chunk_kv,
+            rules_fn=ling3.ling3_partition_rules,
+            kv_kinds=lambda cfg: tuple(KVKind(*k) for k in cfg.kv_kinds()),
+            state_fn=lambda cfg: (cfg.n_kda_layers, cfg.state_parts()),
             held_experts=lambda cfg: (cfg.expert_offset, cfg.experts_held),
         ),
     }

@@ -88,7 +88,7 @@ def test_use_paged_attention_is_no_choice_any_more():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     engines = [json.load(open(path)).get("engine") for path in sorted(
         glob.glob(os.path.join(root, "benchmark", "configs", "*.json")))]
-    assert sum(e is not None for e in engines) == 8
+    assert sum(e is not None for e in engines) == 9
     assert sum("use_paged_attention" in e for e in filter(None, engines)) \
         == 5
     for engine in filter(None, engines):
