@@ -18,6 +18,7 @@ GPT-2-small DDP", BASELINE.json). TPU-first design decisions:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -123,45 +124,52 @@ def gpt2_resident_params(params: Params, cfg: GPT2Config) -> Params:
             "wpe": held(params["wpe"]), "blocks": blocks}
 
 
-def _dense_init(key, in_dim, out_dim, scale):
-    return jax.random.normal(key, (in_dim, out_dim), jnp.float32) * scale
-
-
+@functools.partial(jax.jit, static_argnames=("cfg",))
 def init_gpt2(key: jax.Array, cfg: GPT2Config) -> Params:
     """Initialize parameters (float32 master copy), GPT-2 init scheme:
-    normal(0.02), residual projections scaled by 1/sqrt(2*n_layer)."""
+    normal(0.02), residual projections scaled by 1/sqrt(2*n_layer). One
+    program for the whole tree, as `init_llama` is: compiled once and
+    found in the compile cache by the next process."""
     k = jax.random.split(key, 8)
     L, E, V = cfg.n_layer, cfg.n_embd, cfg.padded_vocab
     std = 0.02
     resid_std = 0.02 / math.sqrt(2 * cfg.n_layer)
 
-    def stack(idx, initializer):
+    def normal(kk, shape, scale):
+        # the draw and its scaling stay two roundings, as the eager calls
+        # this program replaced made them: left side by side, XLA
+        # multiplies `scale` into the sqrt(2) inside `normal` first, and a
+        # quarter of the elements come out an ulp away
+        # (tests/test_gpt2_init.py). Rounding to float32's own width
+        # changes no value and keeps the two products apart, in the same
+        # fusion; an `optimization_barrier` there splits it, and the
+        # v5e's compiler then takes 40 s over this program where 8
+        draw = jax.random.normal(kk, shape, jnp.float32)
+        return jax.lax.reduce_precision(draw, 8, 23) * scale
+
+    def stack(idx, shape, scale):
+        # a layer a key, drawn into place one layer at a time (no `vmap`
+        # over the keys: `init_llama`'s note)
         keys = jax.random.split(jax.random.fold_in(k[7], idx), L)
-        return jnp.stack([initializer(keys[i]) for i in range(L)])
-
-    def qkv(kk):
-        return _dense_init(kk, E, 3 * E, std)
-
-    def attn_proj(kk):
-        return _dense_init(kk, E, E, resid_std)
-
-    def mlp_fc(kk):
-        return _dense_init(kk, E, 4 * E, std)
-
-    def mlp_proj(kk):
-        return _dense_init(kk, 4 * E, E, resid_std)
+        return jax.lax.fori_loop(
+            0, L, lambda i, buf: buf.at[i].set(normal(keys[i], shape, scale)),
+            jnp.zeros((L,) + shape, jnp.float32))
 
     blocks = {
         "ln1": {"scale": jnp.ones((L, E)), "bias": jnp.zeros((L, E))},
-        "attn_qkv": {"kernel": stack(0, qkv), "bias": jnp.zeros((L, 3 * E))},
-        "attn_proj": {"kernel": stack(1, attn_proj), "bias": jnp.zeros((L, E))},
+        "attn_qkv": {"kernel": stack(0, (E, 3 * E), std),
+                     "bias": jnp.zeros((L, 3 * E))},
+        "attn_proj": {"kernel": stack(1, (E, E), resid_std),
+                      "bias": jnp.zeros((L, E))},
         "ln2": {"scale": jnp.ones((L, E)), "bias": jnp.zeros((L, E))},
-        "mlp_fc": {"kernel": stack(2, mlp_fc), "bias": jnp.zeros((L, 4 * E))},
-        "mlp_proj": {"kernel": stack(3, mlp_proj), "bias": jnp.zeros((L, E))},
+        "mlp_fc": {"kernel": stack(2, (E, 4 * E), std),
+                   "bias": jnp.zeros((L, 4 * E))},
+        "mlp_proj": {"kernel": stack(3, (4 * E, E), resid_std),
+                     "bias": jnp.zeros((L, E))},
     }
     return {
-        "wte": jax.random.normal(k[0], (V, E), jnp.float32) * std,
-        "wpe": jax.random.normal(k[1], (cfg.block_size, E), jnp.float32) * std,
+        "wte": normal(k[0], (V, E), std),
+        "wpe": normal(k[1], (cfg.block_size, E), std),
         "blocks": blocks,
         "lnf": {"scale": jnp.ones((E,)), "bias": jnp.zeros((E,))},
     }

@@ -365,9 +365,12 @@ class LLMEngine:
 
         # where a replica's start goes, host clock, seconds (stats())
         self._startup = dict.fromkeys(
-            ("init_params", "build_runner", "warmup", "warmup_trace",
-             "warmup_lower", "warmup_compile"), 0.0)
+            ("init_params", "build_runner", "init_compile", "warmup",
+             "warmup_trace", "warmup_lower", "warmup_compile"), 0.0)
         self._warmup_cache = {"hits": 0, "misses": 0}
+        # what making the weights and building the runner compiled, or
+        # loaded from the persistent cache: `init_compile` its seconds
+        compiled_before = tracing.compile_totals()
         if params is None:
             t0 = time.perf_counter()
             params = jax.block_until_ready(
@@ -482,6 +485,10 @@ class LLMEngine:
         jax.block_until_ready((self.runner.params, self.runner.k_pages,
                                self.runner.v_pages, self.runner.state))
         self._startup["build_runner"] = time.perf_counter() - t0
+        spent = tracing.compile_totals(since=compiled_before)
+        self._startup["init_compile"] = spent["backend_compile"]
+        self._init_cache = {k: int(spent[k])
+                            for k in ("programs", "hits", "misses")}
         # seconds by phase of the step loop, step counts and bytes
         # fetched to the host by step kind: plain numbers, written by
         # the one thread that steps (under _step_lock), copied by stats()
@@ -1555,8 +1562,7 @@ class LLMEngine:
             t0 = time.perf_counter()
             programs = self.runner.warmup()
             wall = time.perf_counter() - t0
-            spent = {k: v - before[k]
-                     for k, v in tracing.compile_totals().items()}
+            spent = tracing.compile_totals(since=before)
             # what warm-up fetched, routed, read and timed is no step's
             self.phases.seconds.update(
                 dict.fromkeys(self.phases.seconds, 0.0))
@@ -1689,6 +1695,7 @@ class LLMEngine:
             "in_flight": len(self._flights),
             "startup_seconds": dict(self._startup),
             "warmup_cache": dict(self._warmup_cache),
+            "init_cache": dict(self._init_cache),
             # the resident parameter tree: its bytes, the leaves the last
             # install converted to their resident dtype, installs so far
             "weights": dict(self.runner.weights),

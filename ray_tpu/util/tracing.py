@@ -231,12 +231,13 @@ _COMPILE_STAGES = {
 _CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 _CACHE_RESULTS = {
     "/jax/compilation_cache/cache_hits": "hits",
-    # jax counts a miss when it writes the entry, so a program too
-    # quick to be worth caching is neither
+    # jax counts a miss when it writes the entry, so a program that
+    # compiled under the cache's floor (`_compile_cache.py`: 0 but on the
+    # CPU, where jax's second stays) is neither
     "/jax/compilation_cache/cache_misses": "misses",
 }
 _compile_totals: dict[str, float] = dict.fromkeys(
-    (*_COMPILE_STAGES.values(), "cache_retrieval",
+    (*_COMPILE_STAGES.values(), "cache_retrieval", "programs",
      *_CACHE_RESULTS.values()), 0)
 _compile_lock = threading.Lock()
 _compile_watched = False
@@ -301,6 +302,8 @@ def watch_compiles() -> None:
             duration = _own_seconds(duration)
         with _compile_lock:
             _compile_totals[stage] += duration
+            # one `backend_compile` report a program compiled or loaded
+            _compile_totals["programs"] += stage == "backend_compile"
         seconds.inc(duration, tags={"stage": stage})
 
     def on_event(event: str, **_):
@@ -314,12 +317,16 @@ def watch_compiles() -> None:
     monitoring.register_event_listener(on_event)
 
 
-def compile_totals() -> dict[str, float]:
+def compile_totals(since: dict[str, float] | None = None) -> dict[str, float]:
     """Process-wide sums since `watch_compiles()`: seconds under
     `trace`, `lower`, `backend_compile`, `cache_retrieval`; counts under
-    `hits`, `misses`."""
+    `programs` (the `backend_compile` reports), `hits`, `misses`. With
+    `since`, an earlier reading: what was added after it."""
     with _compile_lock:
-        return dict(_compile_totals)
+        totals = dict(_compile_totals)
+    if since is not None:
+        totals = {k: v - since[k] for k, v in totals.items()}
+    return totals
 
 
 def dump(filename: str):

@@ -192,15 +192,21 @@ _REPORT = (
     "                  'rule': cc.configure()}))\n")
 
 
-def _cache_report(env_value):
-    env = {k: v for k, v in os.environ.items()
-           if k != "JAX_COMPILATION_CACHE_DIR"}
-    if env_value is not None:
-        env["JAX_COMPILATION_CACHE_DIR"] = env_value
-    out = subprocess.run([sys.executable, "-c", _REPORT], cwd=ROOT, env=env,
+def _child_report(code, without, **variables):
+    """The JSON a child process prints last, run from the checkout with
+    this environment less `without`, plus `variables`."""
+    env = {k: v for k, v in os.environ.items() if k not in without}
+    env.update(variables)
+    out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
                          capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def _cache_report(env_value):
+    given = {} if env_value is None \
+        else {"JAX_COMPILATION_CACHE_DIR": env_value}
+    return _child_report(_REPORT, ("JAX_COMPILATION_CACHE_DIR",), **given)
 
 
 def test_compile_cache_placed_from_outside_is_left_alone(tmp_path,
@@ -238,6 +244,64 @@ def test_compile_cache_reaches_a_process_that_imported_jax_first():
                          capture_output=True, text=True, timeout=120)
     assert out.stdout.strip().splitlines()[-1] == \
         os.path.join(ROOT, ".jax_cache"), out.stderr[-2000:]
+
+
+_FLOOR_REPORT = (
+    "import json, os, sys\n"
+    "{first}\n"
+    "import ray_tpu, jax\n"
+    "from ray_tpu import _compile_cache as cc\n"
+    "print(json.dumps({{\n"
+    "    'env': os.environ.get(cc.FLOOR_ENV),\n"
+    "    'jax': jax.config.jax_persistent_cache_min_compile_time_secs}}))\n")
+
+# (what the process finds in its environment, what it runs before
+# `import ray_tpu`) -> (the variable after the import, jax's floor)
+_FLOOR = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
+FLOOR_CASES = {
+    # set from outside: left alone, whatever the platform
+    "from-outside": ({_FLOOR: "0.25"}, "", ("0.25", 0.25)),
+    "from-outside-on-the-cpu": (
+        {_FLOOR: "0.25", "JAX_PLATFORMS": "cpu"}, "", ("0.25", 0.25)),
+    # unset and not pinned to the CPU: every compile is kept, and the
+    # children inherit the variable
+    "unset": ({}, "", ("0", 0.0)),
+    "unset-on-a-chip": ({"JAX_PLATFORMS": "tpu,cpu"}, "", ("0", 0.0)),
+    "unset-jax-imported-first": (
+        {},
+        "import jax\n"
+        "assert jax.config.jax_persistent_cache_min_compile_time_secs == 1",
+        ("0", 0.0)),
+    # pinned to the CPU (tier-1): jax's own second stays, or a test run
+    # writes some ten thousand files into the checkout
+    "cpu": ({"JAX_PLATFORMS": "cpu"}, "", (None, 1.0)),
+    "cpu-pinned-through-jax-config": (
+        {}, "import jax; jax.config.update('jax_platforms', 'cpu')",
+        (None, 1.0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLOOR_CASES))
+def test_compile_cache_floor(case):
+    """`jax_persistent_cache_min_compile_time_secs` by the rule of
+    `ray_tpu/_compile_cache.py`. No backend is initialised: the child only
+    reads what was configured."""
+    given, first, (env_out, floor) = FLOOR_CASES[case]
+    report = _child_report(_FLOOR_REPORT.format(first=first),
+                           (_FLOOR, "JAX_PLATFORMS"), **given)
+    assert report == {"env": env_out, "jax": floor}
+
+
+def test_compile_cache_floor_from_outside_writes_no_jax_config(monkeypatch):
+    from ray_tpu import _compile_cache as cc
+
+    monkeypatch.setenv(cc.FLOOR_ENV, "0.25")
+    monkeypatch.setenv(cc.ENV, "/somewhere/else")
+    writes = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a, **k: writes.append(a))
+    cc.configure()
+    assert writes == [] and os.environ[cc.FLOOR_ENV] == "0.25"
 
 
 # ------------------------------------------ what the chip turned up (PR 21)
