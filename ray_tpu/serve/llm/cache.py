@@ -556,7 +556,7 @@ class BlockPool:
         self._hash_of: dict[int, int] = {}  # guarded_by(_lock)
         # refcount-0 registered pages, oldest-first (eviction order)
         self._lru: "OrderedDict[int, None]" = OrderedDict()  # guarded_by(_lock)
-        # monotonic stat, read by the engine's metrics pump (hit/miss
+        # monotonic stat, read through `Scheduler.depth()` (hit/miss
         # accounting lives in the scheduler: only an admission that
         # actually goes through should count)
         self.evictions = 0  # guarded_by(_lock)

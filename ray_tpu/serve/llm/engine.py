@@ -21,6 +21,7 @@ import contextlib
 import dataclasses
 import itertools
 import queue
+import sys
 import threading
 import time
 import weakref
@@ -82,6 +83,40 @@ _ENGINES: "weakref.WeakSet[LLMEngine]" = weakref.WeakSet()
 
 def engines() -> "list[LLMEngine]":
     return list(_ENGINES)
+
+
+def _added(pick, finish=sum):
+    """A reader for one series of the metrics page (`collect=`), run on
+    the scraping thread under the locks `stats()` takes and no other:
+    `pick(engine)` -> a number or a ratio's parts, or those in dicts by the
+    tags after the model's; `finish` makes one number of what the engines
+    of one model and tag values hold (`of`: those alive)."""
+    def read(of=None) -> dict:
+        parts: dict[tuple, list] = {}
+        for eng in engines() if of is None else of:
+            for key, v in _leaves(pick(eng), (eng.config.model,)):
+                parts.setdefault(key, []).append(v)
+        return {key: float(finish(vs)) for key, vs in parts.items()}
+    return read
+
+
+def _leaves(v, key: tuple) -> list:
+    if not isinstance(v, dict):
+        return [(key, v)]
+    return [kv for k, sub in v.items() for kv in _leaves(sub, (*key, k))]
+
+
+def _ratio(parts: list) -> float:
+    """Of (numerator, denominator) an engine: of the two added."""
+    return sum(n for n, _ in parts) / (sum(d for _, d in parts) or 1)
+
+
+def _imbalance(parts: list) -> float:
+    """Of pairs by expert an engine: the most loaded over the mean, added."""
+    per = np.zeros(max(len(p) for p in parts), np.int64)
+    for p in parts:
+        per[:len(p)] += p
+    return per.max() / per.mean()
 
 
 def gap_bucket(ms: float) -> int:
@@ -337,7 +372,6 @@ class LLMEngine:
 
         # before the first compile, so that start-up's share is counted
         tracing.watch_compiles()
-        _ENGINES.add(self)
         self.config = config
         reg = adapters()
         if config.model not in reg:
@@ -489,16 +523,16 @@ class LLMEngine:
         self._startup["init_compile"] = spent["backend_compile"]
         self._init_cache = {k: int(spent[k])
                             for k in ("programs", "hits", "misses")}
-        # seconds by phase of the step loop, step counts and bytes
-        # fetched to the host by step kind: plain numbers, written by
-        # the one thread that steps (under _step_lock), copied by stats()
+        # seconds by phase of the step loop; by step kind the steps, the
+        # bytes fetched to the host and the routed experts' account; tokens
+        # generated and prefill programs committed: plain numbers, written
+        # by the one thread that steps (under _step_lock), copied by stats()
         self.phases = self.runner.phases
         self._steps = {"decode": 0, "prefill": 0}
         self._d2h = {"decode": 0, "prefill": 0}
-        self._fetched_seen = 0
-        # the runner's context counts as the metric has seen them
-        self._ctx_seen = {kind: dict(n) for kind, n in
-                          self.runner.context_slots.items()}
+        self._tokens = self._chunks = 0
+        self._moe: dict[str, dict] = {}
+        self._spec_proposed_total = self._spec_accepted_total = 0
         # steps launched and not yet read, oldest first: at most one
         # between two calls of step(), two inside one (the one being
         # read and the one behind it). Touched under _step_lock only
@@ -558,33 +592,37 @@ class LLMEngine:
         # llm_status()/engine_stats() aggregate of the waterfall
         self._phase_totals: dict[str, float] = {}  # guarded_by(_lock)
         self._finished_requests = 0  # guarded_by(_lock)
+        self._outcomes: dict[str, int] = {}  # guarded_by(_lock)
         self._build_metrics()
+        _ENGINES.add(self)  # built: the page reads it from here on
 
     # ----------------------------------------------------------- metrics
 
     def _build_metrics(self):
+        """The engine's series of the metrics page. A counter or a gauge
+        is a view (`_added`): asked for the page, it reads off the engines
+        alive a number `stats()` returns; only a histogram is written to,
+        where its observations happen. Every engine calls this, and a
+        registry keeps the first metric of a name."""
         from ray_tpu.util.metrics import Counter, Gauge, Histogram
 
-        tags = ("model",)
-        self._m_tags = {"model": self.config.model}
-        self._m_tokens = Counter(
-            "serve_llm_tokens_generated_total",
-            "Tokens generated by this engine", tag_keys=tags)
-        self._m_requests = Counter(
-            "serve_llm_requests_total",
-            "Requests finished, by outcome",
-            tag_keys=("model", "outcome"))
-        self._m_preempt = Counter(
-            "serve_llm_preemptions_total",
-            "Sequences preempted on cache exhaustion", tag_keys=tags)
-        self._m_queue = Gauge(
-            "serve_llm_queue_depth", "Waiting requests", tag_keys=tags)
-        self._m_running = Gauge(
-            "serve_llm_running", "Sequences in the decode set",
-            tag_keys=tags)
-        self._m_cache = Gauge(
-            "serve_llm_cache_utilization",
-            "KV pool pages in use / usable pages", tag_keys=tags)
+        def depth(key):
+            return _added(lambda e: e.scheduler.depth()[key])
+
+        def pools(key, finish=sum):  # by kind of KV layer
+            return _added(lambda e: {kind: pool[key] for kind, pool
+                                     in e.kv.stats().items()}, finish)
+
+        def state(key):  # of a family with recurrent state, or 0
+            return _added(lambda e: e.state_slots.stats()[key]
+                          if e.state_slots else 0)
+
+        def routed(key, finish=sum):  # by step kind
+            return _added(lambda e: {kind: acc[key] for kind, acc
+                                     in list(e._moe.items())}, finish)
+
+        tags, by_kind = ("model",), ("model", "kind")
+        self._tags = {"model": self.config.model}
         self._m_ttft = Histogram(
             "serve_llm_ttft_ms", "Time to first token",
             boundaries=(1, 5, 10, 25, 50, 100, 250, 500, 1000, 5000),
@@ -603,29 +641,9 @@ class LLMEngine:
         self._m_step = Histogram(
             "serve_llm_step_ms", "Engine step latency",
             boundaries=(1, 2, 3, 4, 5, 6, 8, 10, 12, 15, 20, 25, 50, 100,
-                        250, 500, 1000),
-            tag_keys=("model", "kind"))
-        self._m_prefix_hits = Counter(
-            "serve_llm_prefix_cache_hits_total",
-            "KV pages served from the prefix cache at admission",
-            tag_keys=tags)
-        self._m_prefix_misses = Counter(
-            "serve_llm_prefix_cache_misses_total",
-            "KV pages that had to be prefilled at admission",
-            tag_keys=tags)
-        self._m_prefix_evict = Counter(
-            "serve_llm_prefix_cache_evictions_total",
-            "Cached refcount-0 pages evicted for reuse", tag_keys=tags)
-        self._m_cached_blocks = Gauge(
-            "serve_llm_prefix_cached_blocks",
-            "Refcount-0 pages retained for prefix reuse", tag_keys=tags)
-        self._m_chunks = Counter(
-            "serve_llm_prefill_chunks_total",
-            "Prefill chunks executed", tag_keys=tags)
-        self._m_swaps = Counter(
-            "serve_llm_weight_swaps_total",
-            "Weight hot-swaps installed at a step boundary",
-            tag_keys=tags)
+                        250, 500, 1000), tag_keys=by_kind)
+        self._step_tags = {kind: {"model": self.config.model, "kind": kind}
+                           for kind in self._steps}
         self._m_swap_s = Histogram(
             "rl_weight_swap_seconds",
             "Wall time of a drain-free weight hot-swap (params install "
@@ -650,151 +668,186 @@ class LLMEngine:
             "per-step time is divided over tokens actually committed)",
             boundaries=(0.5, 1, 2.5, 5, 10, 25, 50, 100, 250, 500),
             tag_keys=tags)
-        # speculative decoding plane: proposed = draft tokens sent to
-        # verify; accepted + rejected = proposed (watchtower's
-        # spec-accept-collapse rule reads the accepted:rejected ratio)
-        self._m_spec_proposed = Counter(
-            "serve_llm_spec_proposed_total",
-            "Draft tokens proposed to the verify program", tag_keys=tags)
-        self._m_spec_accepted = Counter(
-            "serve_llm_spec_accepted_total",
-            "Draft tokens accepted by the verify program", tag_keys=tags)
-        self._m_spec_rejected = Counter(
-            "serve_llm_spec_rejected_total",
-            "Draft tokens rejected by the verify program", tag_keys=tags)
-        self._m_spec_ratio = Gauge(
-            "serve_llm_spec_accept_ratio",
-            "Cumulative draft acceptance ratio (accepted / proposed)",
-            tag_keys=tags)
         self._m_verify_ms = Histogram(
             "serve_llm_verify_step_ms",
             "Speculative verify dispatch latency (one drafted run)",
-            boundaries=(1, 5, 10, 25, 50, 100, 250, 500, 1000),
-            tag_keys=tags)
-        self._m_weight_bytes = Gauge(
-            "serve_llm_weight_bytes",
-            "Bytes of the resident parameter tree, each leaf in the "
-            "dtype the programs consume it in", tag_keys=tags)
-        self._m_weight_cast = Gauge(
-            "serve_llm_weight_cast_leaves",
-            "Leaves the last weight install had to convert to their "
-            "resident dtype (0: the tree arrived as it is held)",
-            tag_keys=tags)
-        self._note_weights()
-        self._m_d2h = Counter(
-            "serve_llm_d2h_bytes_total",
-            "Bytes of device results fetched to the host by engine "
-            "steps (sampled tokens and logits), by step kind",
-            tag_keys=("model", "kind"))
-        self._m_ctx = Counter(
-            "serve_llm_ctx_slots_total",
-            "Slots of cached context by kind of program: read as "
-            "launched (whole tiles, to the longest lane of a group), "
-            "valid (below a lane's length), full (every row to "
-            "max_model_len); of a latent kind also scored (indexer keys "
-            "read) and selected (slots a row can attend after its "
-            "indexer's choice)", tag_keys=("model", "kind", "what"))
-        # routed experts: what the programs report of their routing, by
-        # step kind (a dense model's programs report nothing)
-        moe_tags = ("model", "kind")
-        self._m_moe_pairs = Counter(
-            "serve_llm_moe_pairs_total",
-            "(token, expert) pairs the routed-expert layers computed, "
-            "padded rows included, by step kind", tag_keys=moe_tags)
-        self._m_moe_touched = Counter(
-            "serve_llm_moe_experts_touched_total",
-            "Experts that received at least one pair, summed over "
-            "programs and layers, by step kind", tag_keys=moe_tags)
-        self._m_moe_calls = Counter(
-            "serve_llm_moe_layer_calls_total",
-            "Routed-expert layers run (programs x layers), by step kind",
-            tag_keys=moe_tags)
-        self._m_moe_imbalance = Gauge(
-            "serve_llm_moe_load_imbalance",
-            "Pairs of the most loaded expert over the mean expert's, "
-            "cumulative, by step kind", tag_keys=moe_tags)
-        self._m_launched = Counter(
-            "serve_llm_steps_launched_total",
-            "Step programs enqueued, by step kind and by whether the "
-            "step before them was still unread (ahead=1) or the engine "
-            "had read everything (ahead=0)",
-            tag_keys=("model", "kind", "ahead"))
-        self._m_drains = Counter(
-            "serve_llm_step_drains_total",
-            "Steps read with none launched behind them, by what kept "
-            "the next one from being planned",
-            tag_keys=("model", "reason"))
-        self._m_discarded = Counter(
-            "serve_llm_discarded_tokens_total",
-            "Sampled ids dropped at commit: their lane had ended (an "
-            "eos in the step before, an abort) while the program ran",
-            tag_keys=tags)
-        # recurrent state (a family that has it): what the lanes' slots
-        # hold, and the two things it changes for a request
-        self._m_state_bytes = Gauge(
-            "serve_llm_state_bytes",
-            "Bytes of recurrent state held for the lane slots, all "
-            "layers and parts (0: the family has none)", tag_keys=tags)
-        self._m_state_resets = Counter(
-            "serve_llm_state_resets_total",
-            "Lane slots started from zero by a program that ran a "
-            "sequence's first rows (admissions, recomputes included)",
-            tag_keys=tags)
-        self._m_state_carried = Counter(
-            "serve_llm_state_carried_total",
-            "Prefill programs that started from the recurrent state an "
-            "earlier chunk of their sequence left in the lane's slot",
-            tag_keys=tags)
-        self._m_state_lanes = Counter(
-            "serve_llm_state_decode_lanes_total",
-            "Lane slots whose recurrent state a decode program moved one "
-            "step on (the slots its lanes owned), summed over the steps",
-            tag_keys=tags)
-        self._m_state_bytes.set(
-            self.state_slots.layout.nbytes if self.state_slots else 0,
-            tags=self._m_tags)
-        # KV pages by kind of layer (one kind for most families)
-        kv_tags = ("model", "kind")
-        self._m_kv_used = Gauge(
-            "serve_llm_kv_pages_used",
-            "Pages of a kind's pool held by sequences", tag_keys=kv_tags)
-        self._m_kv_free = Gauge(
-            "serve_llm_kv_pages_free",
-            "Pages of a kind's pool that can be allocated",
-            tag_keys=kv_tags)
-        self._m_kv_largest = Gauge(
-            "serve_llm_kv_largest_table",
-            "Most pages of a kind one sequence has held at once",
-            tag_keys=kv_tags)
-        self._m_kv_released = Counter(
-            "serve_llm_kv_released_total",
-            "Pages given back behind the window while their sequence ran "
-            "(a window kind only)", tag_keys=kv_tags)
-        self._m_kv_prefix = Counter(
-            "serve_llm_kv_prefix_total",
-            "Admissions by what became of their prefix lookup: taken (a "
-            "match of at least a page) or declined (none looked up: the "
-            "family has a window kind)", tag_keys=("model", "outcome"))
-        self._m_kv_rows = Counter(
-            "serve_llm_kv_rows_written_total",
-            "Valid rows of K (and as many of V) stored in a kind's pools, "
-            "by path: paged (a prompt's or a chunk's program, a page at "
-            "a time) or rowwise (decode, verify, a bucket that is not "
-            "whole pages)", tag_keys=("model", "kind", "path"))
-        self._kv_seen = {"pools": [None] * len(self.kv.pools),
-                         "taken": 0, "declined": 0,
-                         "rows": {name: dict(n) for name, n in
-                                  self.runner.rows_written.items()}}
-        self._moe: dict[str, dict] = {}
-        self._spec_proposed_total = 0
-        self._spec_accepted_total = 0
-        # counter deltas are computed against the last pump
-        self._last_prefix = (0, 0, 0)
+            boundaries=(1, 5, 10, 25, 50, 100, 250, 500, 1000), tag_keys=tags)
+        views = [
+            Counter("serve_llm_tokens_generated_total",
+                    "Tokens generated by this engine", tag_keys=tags,
+                    collect=_added(lambda e: e._tokens)),
+            Counter("serve_llm_requests_total",
+                    "Requests finished, by outcome",
+                    tag_keys=("model", "outcome"),
+                    collect=_added(lambda e: dict(e._outcomes))),
+            Counter("serve_llm_preemptions_total",
+                    "Sequences preempted on cache exhaustion", tag_keys=tags,
+                    collect=depth("preemptions")),
+            Gauge("serve_llm_queue_depth", "Waiting requests", tag_keys=tags,
+                  collect=depth("waiting")),
+            Gauge("serve_llm_running", "Sequences in the decode set",
+                  tag_keys=tags, collect=depth("running")),
+            Gauge("serve_llm_cache_utilization",
+                  "KV pool pages in use / usable pages", tag_keys=tags,
+                  collect=_added(lambda e: (e.pool.num_used(),
+                                            e.pool.usable_blocks), _ratio)),
+            Counter("serve_llm_prefix_cache_hits_total",
+                    "KV pages served from the prefix cache at admission",
+                    tag_keys=tags, collect=depth("prefix_hit_pages")),
+            Counter("serve_llm_prefix_cache_misses_total",
+                    "KV pages that had to be prefilled at admission",
+                    tag_keys=tags, collect=depth("prefix_miss_pages")),
+            Counter("serve_llm_prefix_cache_evictions_total",
+                    "Cached refcount-0 pages evicted for reuse", tag_keys=tags,
+                    collect=depth("prefix_evictions")),
+            Gauge("serve_llm_prefix_cached_blocks",
+                  "Refcount-0 pages retained for prefix reuse", tag_keys=tags,
+                  collect=depth("blocks_cached")),
+            Counter("serve_llm_prefill_chunks_total",
+                    "Prefill chunks executed", tag_keys=tags,
+                    collect=_added(lambda e: e._chunks)),
+            # every install but the constructor's
+            Counter("serve_llm_weight_swaps_total",
+                    "Weight hot-swaps installed at a step boundary",
+                    tag_keys=tags,
+                    collect=_added(
+                        lambda e: e.runner.weights["installs"] - 1)),
+            # speculative decoding plane: proposed = draft tokens sent to
+            # verify; accepted + rejected = proposed (watchtower's
+            # spec-accept-collapse rule reads the accepted:rejected ratio)
+            Counter("serve_llm_spec_proposed_total",
+                    "Draft tokens proposed to the verify program",
+                    tag_keys=tags,
+                    collect=_added(lambda e: e._spec_proposed_total)),
+            Counter("serve_llm_spec_accepted_total",
+                    "Draft tokens accepted by the verify program",
+                    tag_keys=tags,
+                    collect=_added(lambda e: e._spec_accepted_total)),
+            Counter("serve_llm_spec_rejected_total",
+                    "Draft tokens rejected by the verify program",
+                    tag_keys=tags,
+                    collect=_added(lambda e: e._spec_proposed_total
+                                   - e._spec_accepted_total)),
+            Gauge("serve_llm_spec_accept_ratio",
+                  "Cumulative draft acceptance ratio (accepted / proposed)",
+                  tag_keys=tags,
+                  collect=_added(lambda e: (e._spec_accepted_total,
+                                            e._spec_proposed_total), _ratio)),
+            Gauge("serve_llm_weight_bytes",
+                  "Bytes of the resident parameter tree, each leaf in the "
+                  "dtype the programs consume it in", tag_keys=tags,
+                  collect=_added(
+                      lambda e: e.runner.weights["resident_bytes"])),
+            Gauge("serve_llm_weight_cast_leaves",
+                  "Leaves the last weight install had to convert to their "
+                  "resident dtype (0: the tree arrived as it is held)",
+                  tag_keys=tags,
+                  collect=_added(lambda e: e.runner.weights["cast_leaves"])),
+            Counter("serve_llm_d2h_bytes_total",
+                    "Bytes of device results fetched to the host by engine "
+                    "steps (sampled tokens and logits), by step kind",
+                    tag_keys=by_kind, collect=_added(lambda e: e._d2h)),
+            Counter("serve_llm_ctx_slots_total",
+                    "Slots of cached context by kind of program: read as "
+                    "launched (whole tiles, to the longest lane of a group), "
+                    "valid (below a lane's length), full (every row to "
+                    "max_model_len); of a latent kind also scored (indexer "
+                    "keys read) and selected (slots a row can attend after "
+                    "its indexer's choice)",
+                    tag_keys=("model", "kind", "what"),
+                    collect=_added(lambda e: e.runner.context_slots)),
+            # routed experts: what the programs report of their routing, by
+            # step kind (a dense model's programs report nothing)
+            Counter("serve_llm_moe_pairs_total",
+                    "(token, expert) pairs the routed-expert layers "
+                    "computed, padded rows included, by step kind",
+                    tag_keys=by_kind, collect=routed("pairs")),
+            Counter("serve_llm_moe_experts_touched_total",
+                    "Experts that received at least one pair, summed over "
+                    "programs and layers, by step kind", tag_keys=by_kind,
+                    collect=routed("experts_touched")),
+            Counter("serve_llm_moe_layer_calls_total",
+                    "Routed-expert layers run (programs x layers), by step "
+                    "kind", tag_keys=by_kind, collect=routed("layer_calls")),
+            Gauge("serve_llm_moe_load_imbalance",
+                  "Pairs of the most loaded expert over the mean expert's, "
+                  "cumulative, by step kind", tag_keys=by_kind,
+                  collect=routed("expert_pairs", _imbalance)),
+            Counter("serve_llm_steps_launched_total",
+                    "Step programs enqueued, by step kind and by whether the "
+                    "step before them was still unread (ahead=1) or the "
+                    "engine had read everything (ahead=0)",
+                    tag_keys=("model", "kind", "ahead"),
+                    collect=_added(lambda e: {kind: {
+                        "1": n, "0": e._overlap["launched_drained"][kind]}
+                        for kind, n in e._overlap["launched_ahead"].items()})),
+            Counter("serve_llm_step_drains_total",
+                    "Steps read with none launched behind them, by what kept "
+                    "the next one from being planned",
+                    tag_keys=("model", "reason"),
+                    collect=_added(lambda e: e._overlap["drains"])),
+            Counter("serve_llm_discarded_tokens_total",
+                    "Sampled ids dropped at commit: their lane had ended (an "
+                    "eos in the step before, an abort) while the program ran",
+                    tag_keys=tags,
+                    collect=_added(lambda e: e._overlap["discarded_tokens"])),
+            # recurrent state (a family that has it): what the lanes' slots
+            # hold, and the two things it changes for a request
+            Gauge("serve_llm_state_bytes",
+                  "Bytes of recurrent state held for the lane slots, all "
+                  "layers and parts (0: the family has none)", tag_keys=tags,
+                  collect=state("bytes")),
+            Counter("serve_llm_state_resets_total",
+                    "Lane slots started from zero by a program that ran a "
+                    "sequence's first rows (admissions, recomputes included)",
+                    tag_keys=tags, collect=state("resets")),
+            Counter("serve_llm_state_carried_total",
+                    "Prefill programs that started from the recurrent state "
+                    "an earlier chunk of their sequence left in the lane's "
+                    "slot", tag_keys=tags, collect=state("carried")),
+            Counter("serve_llm_state_decode_lanes_total",
+                    "Lane slots whose recurrent state a decode program moved "
+                    "one step on (the slots its lanes owned), summed over "
+                    "the steps", tag_keys=tags, collect=state("decode_lanes")),
+            # KV pages by kind of layer (one kind for most families)
+            Gauge("serve_llm_kv_pages_used",
+                  "Pages of a kind's pool held by sequences", tag_keys=by_kind,
+                  collect=pools("pages_used")),
+            Gauge("serve_llm_kv_pages_free",
+                  "Pages of a kind's pool that can be allocated",
+                  tag_keys=by_kind, collect=pools("pages_free")),
+            Gauge("serve_llm_kv_largest_table",
+                  "Most pages of a kind one sequence has held at once",
+                  tag_keys=by_kind, collect=pools("largest_table", max)),
+            Counter("serve_llm_kv_released_total",
+                    "Pages given back behind the window while their sequence "
+                    "ran (a window kind only)", tag_keys=by_kind,
+                    collect=pools("released_behind_window")),
+            Counter("serve_llm_kv_prefix_total",
+                    "Admissions by what became of their prefix lookup: taken "
+                    "(a match of at least a page) or declined (none looked "
+                    "up: the family has a window kind)",
+                    tag_keys=("model", "outcome"),
+                    collect=_added(lambda e: {
+                        "taken": e.kv.prefix_taken,
+                        "declined": e.kv.prefix_declines})),
+            Counter("serve_llm_kv_rows_written_total",
+                    "Valid rows of K (and as many of V) stored in a kind's "
+                    "pools, by path: paged (a prompt's or a chunk's program, "
+                    "a page at a time) or rowwise (decode, verify, a bucket "
+                    "that is not whole pages)",
+                    tag_keys=("model", "kind", "path"),
+                    collect=_added(lambda e: e.runner.rows_written)),
+        ]
+        self._counters = [m for m in views if m.TYPE == "counter"]
 
-    def _note_weights(self) -> None:
-        w = self.runner.weights
-        self._m_weight_bytes.set(w["resident_bytes"], tags=self._m_tags)
-        self._m_weight_cast.set(w["cast_leaves"], tags=self._m_tags)
+    def __del__(self, going=sys.is_finalizing):
+        # what an engine counted stays in its counters when it goes (not
+        # when the interpreter does): none goes down inside a process
+        if not going():
+            for counter in self.__dict__.get("_counters", ()):
+                for key, n in counter.collect([self]).items():
+                    counter.inc(n, tags=dict(zip(counter.tag_keys, key)))
 
     # ------------------------------------------------------------ intake
 
@@ -823,7 +876,6 @@ class LLMEngine:
             # registering the stream, or rejected requests leak entries
             self.scheduler.add(seq)
             self._streams[seq.seq_id] = stream
-        self._m_queue.set(len(self.scheduler.waiting), tags=self._m_tags)
         return stream
 
     def generate(self, prompt: Seq[int],
@@ -879,7 +931,7 @@ class LLMEngine:
                     planned.append(first)
                 if self._proposer is not None:
                     # drafts are read from the tokens this step commits
-                    self._note_drain("speculation")
+                    self._overlap["drains"]["speculation"] += 1
                 else:
                     behind, _ = self._plan(ahead=True)
                     if behind is not None:
@@ -897,22 +949,18 @@ class LLMEngine:
         a sequence out."""
         t0 = time.perf_counter()
         with self._lock:
-            pre = self.scheduler.preemption_count
             try:
                 # may preempt lanes, unless their tokens are in flight
                 work = self.scheduler.schedule(may_preempt=not ahead)
             except NeedsResults:
-                self._note_drain("preempt")
+                self._overlap["drains"]["preempt"] += 1
                 return None, False
-            d_pre = self.scheduler.preemption_count - pre
             retired = self.scheduler.take_retired()
-        if d_pre:
-            self._m_preempt.inc(d_pre, tags=self._m_tags)
         for s in retired:  # schedule() closed these out itself
             self._finalize(s)
         if work is None:
             if ahead:
-                self._note_drain("idle")
+                self._overlap["drains"]["idle"] += 1
             return None, retired != []
         if isinstance(work, PrefillWork):
             kind = "prefill"
@@ -947,14 +995,9 @@ class LLMEngine:
         """Read every step in flight: for a caller that holds
         `_step_lock` and needs the engine between steps."""
         if self._flights and reason:
-            self._note_drain(reason)
+            self._overlap["drains"][reason] += 1
         while self._flights:
             self._turn([], time.perf_counter())
-
-    def _note_drain(self, reason: str) -> None:
-        self._overlap["drains"][reason] += 1
-        self._m_drains.inc(tags={"model": self.config.model,
-                                 "reason": reason})
 
     def _launch(self, flight: "_Flight") -> None:
         """Enqueue a planned step's program; nothing is read."""
@@ -983,7 +1026,7 @@ class LLMEngine:
             if flight.handle is not None:
                 nxt, logits = self.runner.collect(flight.handle)
         except Exception as e:  # noqa: BLE001
-            self._note_drain("error")
+            self._overlap["drains"]["error"] += 1
             work = flight.work
             lanes = [work.seq] if flight.kind == "prefill" else work.seqs
             lanes = [s for s in lanes if s.state is SeqState.RUNNING]
@@ -1011,93 +1054,28 @@ class LLMEngine:
             now = time.perf_counter()
             step_ms = (now - max(flight.t0, self._last_collect)) * 1e3
             self._last_collect = now
-            self._bookkeep(flight.kind, tokens, flight.ahead, step_ms)
+            self._bookkeep(flight.kind, tokens, step_ms)
         with self.phases.phase("release"):
             # the last references to the program's results on the device
             # and to their copies on the host (a lane's logits row is
             # 200 KB at gpt2-large: freeing it is a system call)
             flight.handle = nxt = logits = None
 
-    def _bookkeep(self, kind: str, tokens: int, ahead: bool,
-                  step_ms: float) -> None:
-        """What a step writes down once its program's results are out:
-        the step's metrics, the scheduler's gauges, the prefix cache's
-        counters, the tokens and their gaps, and the bytes it fetched."""
-        self._m_launched.inc(tags={
-            "model": self.config.model, "kind": kind,
-            "ahead": "1" if ahead else "0"})
-        self._m_step.observe(
-            step_ms, tags={"model": self.config.model, "kind": kind})
-        depth = self.scheduler.depth()
-        self._m_queue.set(depth["waiting"], tags=self._m_tags)
-        self._m_running.set(depth["running"], tags=self._m_tags)
-        self._m_cache.set(depth["cache_utilization"], tags=self._m_tags)
-        self._m_cached_blocks.set(depth["blocks_cached"],
-                                  tags=self._m_tags)
-        hits, misses, evict = (depth["prefix_hit_pages"],
-                               depth["prefix_miss_pages"],
-                               depth["prefix_evictions"])
-        lh, lm, le = self._last_prefix
-        self._last_prefix = (hits, misses, evict)
-        if hits > lh:
-            self._m_prefix_hits.inc(hits - lh, tags=self._m_tags)
-        if misses > lm:
-            self._m_prefix_misses.inc(misses - lm, tags=self._m_tags)
-        if evict > le:
-            self._m_prefix_evict.inc(evict - le, tags=self._m_tags)
-        self._note_kv()
-        if tokens:
-            self._m_tokens.inc(tokens, tags=self._m_tags)
+    def _bookkeep(self, kind: str, tokens: int, step_ms: float) -> None:
+        """What a step writes down once its results are out and nothing
+        else keeps: its latency and the tokens' gaps (the histograms), and
+        by its kind the step, the bytes it fetched and its routed experts."""
+        self._m_step.observe(step_ms, tags=self._step_tags[kind])
         for cause, (buckets, total_ms) in self._itl_pending.items():
             self._m_itl.observe_buckets(buckets, total_ms,
                                         tags=self._itl_tags[cause])
         self._itl_pending.clear()
+        self._tokens += tokens
         self._steps[kind] += 1
-        fetched = self.runner.fetched_bytes - self._fetched_seen
-        self._fetched_seen += fetched
-        self._d2h[kind] += fetched
-        self._m_d2h.inc(fetched,
-                        tags={"model": self.config.model, "kind": kind})
-        for ctx_kind, now in self.runner.context_slots.items():
-            seen = self._ctx_seen[ctx_kind]
-            for what, n in now.items():
-                if n != seen[what]:
-                    self._m_ctx.inc(n - seen[what], tags={
-                        "model": self.config.model, "kind": ctx_kind,
-                        "what": what})
-                    seen[what] = n
+        self._d2h[kind] += self.runner.fetched_bytes
+        self.runner.fetched_bytes = 0
         if self.runner.expert_pairs:  # never, for a dense model
             self._note_routing(kind, self.runner.take_expert_pairs())
-
-    def _note_kv(self) -> None:
-        """The pools by kind of KV layer onto the metrics page: only what
-        moved since the last step (most steps move nothing)."""
-        kv, seen = self.kv, self._kv_seen
-        for i, (kind, pool) in enumerate(zip(kv.kinds, kv.pools)):
-            now = (pool.num_free(), kv.largest_table[i], kv.released[i])
-            if now == seen["pools"][i]:
-                continue
-            tags = {"model": self.config.model, "kind": kind.name}
-            self._m_kv_used.set(pool.usable_blocks - now[0], tags=tags)
-            self._m_kv_free.set(now[0], tags=tags)
-            self._m_kv_largest.set(now[1], tags=tags)
-            gone = now[2] - (seen["pools"][i] or (0, 0, 0))[2]
-            if gone:
-                self._m_kv_released.inc(gone, tags=tags)
-            seen["pools"][i] = now
-        for outcome, n in (("taken", kv.prefix_taken),
-                           ("declined", kv.prefix_declines)):
-            if n > seen[outcome]:
-                self._m_kv_prefix.inc(n - seen[outcome], tags={
-                    "model": self.config.model, "outcome": outcome})
-                seen[outcome] = n
-        for name, now in self.runner.rows_written.items():
-            for path, n in now.items():
-                if n != seen["rows"][name][path]:
-                    self._m_kv_rows.inc(n - seen["rows"][name][path], tags={
-                        "model": self.config.model, "kind": name,
-                        "path": path})
-                    seen["rows"][name][path] = n
 
     def _note_routing(self, kind: str, routed: list) -> None:
         """Account the step's routed-expert layers: `routed` holds one
@@ -1121,12 +1099,6 @@ class LLMEngine:
         acc["experts_touched"] += touched
         acc["layer_calls"] += calls
         acc["expert_pairs"] += c.sum(axis=(0, 1))
-        tags = {"model": self.config.model, "kind": kind}
-        self._m_moe_pairs.inc(pairs, tags=tags)
-        self._m_moe_touched.inc(touched, tags=tags)
-        self._m_moe_calls.inc(calls, tags=tags)
-        per = acc["expert_pairs"]
-        self._m_moe_imbalance.set(float(per.max() / per.mean()), tags=tags)
 
     def _launch_prefill(self, work: PrefillWork) -> Launched:
         """One prefill program: the whole prompt, or a chunk of it."""
@@ -1137,10 +1109,8 @@ class LLMEngine:
             if work.start == 0:
                 # the program zeroes the slot; no prefix was looked up
                 self.state_slots.resets += 1
-                self._m_state_resets.inc(tags=self._m_tags)
             else:  # it starts from what the lane's last chunk left
                 self.state_slots.carried += 1
-                self._m_state_carried.inc(tags=self._m_tags)
         if work.start == 0 and work.is_last:
             # whole prompt in one go and nothing cached: the
             # monolithic program skips the context gather
@@ -1161,9 +1131,9 @@ class LLMEngine:
             if seq.state is not SeqState.RUNNING:
                 # aborted while the program ran: its pages may already
                 # belong to someone else
-                self._discard(len(flight.sampled))
+                self._overlap["discarded_tokens"] += len(flight.sampled)
                 return 0
-            self._m_chunks.inc(tags=self._m_tags)
+            self._chunks += 1
             seq.note_phase("prefill")  # chunk + its scheduling gap
             with self._lock:
                 # full pages covered by this chunk are now shareable
@@ -1185,17 +1155,10 @@ class LLMEngine:
                 self._finalize(seq)
         return 1
 
-    def _discard(self, n: int) -> None:
-        """Sampled ids that no stream gets: their lane had ended (an
-        eos the plan could not know, an abort) when they were read."""
-        if n:
-            self._overlap["discarded_tokens"] += n
-            self._m_discarded.inc(n, tags=self._m_tags)
-
     def _observe_ttft(self, seq: Sequence) -> None:
         now = time.monotonic()
         self._m_ttft.observe(
-            (now - seq.enqueued_at) * 1e3, tags=self._m_tags)
+            (now - seq.enqueued_at) * 1e3, tags=self._tags)
         # TTFT split for the SLO plane: queue vs prefill work
         ph = seq.phases
         self._m_slo_ttft.observe(
@@ -1242,7 +1205,6 @@ class LLMEngine:
             self.state_slots.note_decode(
                 self.runner.decode_bucket(len(items)), len(items),
                 self.runner.state_by_kernel)
-            self._m_state_lanes.inc(len(items), tags=self._m_tags)
         flight.handle = self.runner.launch_decode(items)
 
     def _propose_for(self, seq: Sequence) -> list[int]:
@@ -1271,7 +1233,7 @@ class LLMEngine:
             lanes = [(i, s, tok) for i, (s, tok) in enumerate(
                 zip(flight.plain, next_tokens))
                 if s.state is SeqState.RUNNING]
-            self._discard(len(next_tokens) - len(lanes))
+            self._overlap["discarded_tokens"] += len(next_tokens) - len(lanes)
             for i, s, tok in lanes:
                 if s.sampling.logprobs:
                     s.logprobs.append(self._logprob_of(
@@ -1345,19 +1307,10 @@ class LLMEngine:
         with self.phases.phase("commit"):
             self._m_verify_ms.observe(
                 (spent["prepare"] + spent["dispatch"] + spent["fetch"]
-                 - before) * 1e3, tags=self._m_tags)
+                 - before) * 1e3, tags=self._tags)
             n_acc = len(tokens) - 1
             self._spec_proposed_total += len(draft)
             self._spec_accepted_total += n_acc
-            self._m_spec_proposed.inc(len(draft), tags=self._m_tags)
-            if n_acc:
-                self._m_spec_accepted.inc(n_acc, tags=self._m_tags)
-            if len(draft) > n_acc:
-                self._m_spec_rejected.inc(len(draft) - n_acc,
-                                          tags=self._m_tags)
-            self._m_spec_ratio.set(
-                self._spec_accepted_total
-                / max(1, self._spec_proposed_total), tags=self._m_tags)
             seq.note_phase("verify", time.monotonic())
             committed: list[int] = []
             done = False
@@ -1418,8 +1371,6 @@ class LLMEngine:
         if stream is None:
             return  # already finalized (idempotent: no double-count)
         outcome = (seq.finish_reason or "unknown").split(":", 1)[0]
-        self._m_requests.inc(
-            tags={"model": self.config.model, "outcome": outcome})
         # ---- latency attribution: close the waterfall -----------------
         now = time.monotonic()
         # the tail interval (last step end -> this close): queue time if
@@ -1438,9 +1389,10 @@ class LLMEngine:
         if len(seq.generated) > 1 and dec_s > 0:
             self._m_slo_tpot.observe(
                 dec_s * 1e3 / (len(seq.generated) - 1),
-                tags=self._m_tags)
+                tags=self._tags)
         with self._lock:
             self._finished_requests += 1
+            self._outcomes[outcome] = self._outcomes.get(outcome, 0) + 1
             for k, v in seq.phases.items():
                 self._phase_totals[k] = self._phase_totals.get(k, 0.0) + v
         self._record_request_spans(seq, now)
@@ -1545,9 +1497,7 @@ class LLMEngine:
                         s.kv_stale = True
                     in_flight = len(running) + len(self.scheduler.waiting)
         dt = time.perf_counter() - t0
-        self._m_swaps.inc(tags=self._m_tags)
-        self._m_swap_s.observe(dt, tags=self._m_tags)
-        self._note_weights()
+        self._m_swap_s.observe(dt, tags=self._tags)
         return {"version": version, "previous_version": previous,
                 "swap_seconds": dt, "in_flight_streams": in_flight,
                 "registrations_dropped": dropped}
@@ -1569,17 +1519,12 @@ class LLMEngine:
             for by_kind in (self.runner.launch, self.runner.fetch):
                 for n in by_kind.values():
                     n.update(dict.fromkeys(n, 0))
-            self._fetched_seen = self.runner.fetched_bytes
+            self.runner.fetched_bytes = 0
             self.runner.take_expert_pairs()
-            for kind, n in self.runner.context_slots.items():
-                n.update(dict.fromkeys(n, 0))
-                self._ctx_seen[kind] = dict(n)
-            for by in self.runner.context_by_kind.values():
+            for by in (self.runner.context_slots, self.runner.rows_written,
+                       *self.runner.context_by_kind.values()):
                 for n in by.values():
                     n.update(dict.fromkeys(n, 0))
-            for name, n in self.runner.rows_written.items():
-                n.update(dict.fromkeys(n, 0))
-                self._kv_seen["rows"][name] = dict(n)
         up = self._startup
         up["warmup"] += wall
         up["warmup_trace"] += spent["trace"]
@@ -1615,6 +1560,7 @@ class LLMEngine:
         with self._lock:
             phase_totals = dict(self._phase_totals)
             finished = self._finished_requests
+            outcomes = dict(self._outcomes)
         d.update({
             # the page pools by kind of KV layer (cache.KVPools.stats),
             # and the rows the programs stored in them, by path
@@ -1637,6 +1583,9 @@ class LLMEngine:
             # per replica by util.state.llm_status()
             "phase_seconds": phase_totals,
             "finished_requests": finished,
+            "finished_by_outcome": outcomes,
+            "tokens_generated": self._tokens,
+            "prefill_chunks": self._chunks,
             # the step loop's own account (see OBSERVABILITY.md): host
             # seconds by phase, steps and bytes fetched by step kind,
             # and where this replica's start went

@@ -627,8 +627,9 @@ class ModelRunner:
         self._verify_jit = jax.jit(self._verify_impl,
                                    donate_argnums=donate[:2])
         self.phases = tracing.PhaseClock("llm.", STEP_PHASES)
-        # bytes of device results copied to the host, ever (tokens and
-        # logits, every `np.asarray` / `int()` of a program's output)
+        # bytes of device results copied to the host (tokens and logits,
+        # every `np.asarray` / `int()` of a program's output) since the
+        # engine last took them for the step it was writing down
         self.fetched_bytes = 0
         # the jitted call alone, by kind of program (a prompt's and a
         # chunk's are `prefill`): calls, their wall seconds (the

@@ -7,8 +7,7 @@ NamedShardings from partition rules (fsdp/tensor axes), the batch is
 sharded over (data, fsdp), and GSPMD inserts the reduce-scatter /
 all-gather traffic that DDP/ZeRO would do by hand.
 
-The ZeRO ladder (`zero_stage=0|1|2|3`; `shard_optimizer=True` is the
-back-compat spelling of stage 1): each rung shards one more
+The ZeRO ladder (`zero_stage=0|1|2|3`): each rung shards one more
 param-shaped component 1/N along the data axis ("Automatic
 Cross-Replica Sharding of Weight Update in Data-Parallel Training" —
 each replica owns a shard instead of a copy), all expressed as sharding
@@ -278,21 +277,16 @@ def zero_shardings(
     return rules.shardings(tree, mesh)
 
 
-def _resolve_zero_stage(zero_stage: int | None,
-                        shard_optimizer: bool) -> int:
-    """`zero_stage=None` defers to the legacy `shard_optimizer` bool
-    (True == stage 1); an explicit stage wins over the bool."""
-    if zero_stage is None:
-        return 1 if shard_optimizer else 0
-    if zero_stage not in (0, 1, 2, 3):
+def _resolve_zero_stage(zero_stage: int | None) -> int:
+    """The ladder's rung; `None` is stage 0."""
+    if zero_stage not in (None, 0, 1, 2, 3):
         raise ValueError(f"zero_stage must be 0|1|2|3, got {zero_stage}")
-    return int(zero_stage)
+    return int(zero_stage or 0)
 
 
 def state_shardings(
     rules: PartitionRules, state: TrainState, mesh: Mesh,
-    shard_optimizer: bool = False, data_axis: str = AXIS_DATA,
-    zero_stage: int | None = None,
+    data_axis: str = AXIS_DATA, zero_stage: int | None = None,
 ) -> TrainState:
     """NamedShardings for a TrainState. Optimizer moments are param-shaped
     subtrees whose tree paths *end with* the parameter's own path (e.g.
@@ -300,13 +294,12 @@ def state_shardings(
     match with `re.search` — shard them identically to their parameter;
     scalar leaves (step counts) fall through to the replicated catch-all.
 
-    `zero_stage` picks the ladder rung (`shard_optimizer=True` is the
-    stage-1 spelling): stage >= 1 lays the optimizer state out 1/N along
-    `data_axis`, stage >= 2 also the grad-accumulation buffer (when the
+    `zero_stage` picks the ladder rung: stage >= 1 lays the optimizer
+    state out 1/N along `data_axis`, stage >= 2 also the grad-accumulation buffer (when the
     state carries one), stage >= 3 also the resident params — each via
     `zero_shardings`. The train step reshards at its boundaries via
     constraints, so batch layouts are unchanged."""
-    stage = _resolve_zero_stage(zero_stage, shard_optimizer)
+    stage = _resolve_zero_stage(zero_stage)
     return TrainState(
         params=zero_shardings(rules, state.params, mesh, stage, "params",
                               data_axis),
@@ -389,7 +382,6 @@ def make_train_step(
     loss_fn: Callable[[PyTree, PyTree], jax.Array],
     tx: optax.GradientTransformation,
     donate: bool = True,
-    shard_optimizer: bool = False,
     mesh: Mesh | None = None,
     rules: PartitionRules | None = None,
     data_axis: str = AXIS_DATA,
@@ -404,8 +396,8 @@ def make_train_step(
     `jax.set_mesh(mesh)` so in-model `constrain` calls resolve.
 
     ``zero_stage`` picks the ladder rung (requires `mesh` + `rules` for
-    stage >= 1; `shard_optimizer=True` is the stage-1 spelling; pair
-    with a state from ``init_sharded_state`` at the same stage). All
+    stage >= 1; pair with a state from ``init_sharded_state`` at the
+    same stage). All
     rungs live inside the SAME jitted program as sharding constraints:
 
     - stage >= 1: grads are constrained first to their rule layout
@@ -431,7 +423,7 @@ def make_train_step(
     ``accum_steps`` composes with every stage (stage 0 accumulates in
     the rule layout): `state.step` counts microsteps, and the loss
     reported each call is the microbatch loss."""
-    stage = _resolve_zero_stage(zero_stage, shard_optimizer)
+    stage = _resolve_zero_stage(zero_stage)
     if stage >= 1 and (mesh is None or rules is None):
         raise ValueError(f"zero_stage={stage} needs mesh= and rules= "
                          "to derive the ZeRO layouts")
@@ -655,7 +647,6 @@ def init_sharded_state(
     tx: optax.GradientTransformation,
     mesh: Mesh,
     rules: PartitionRules,
-    shard_optimizer: bool = False,
     data_axis: str = AXIS_DATA,
     zero_stage: int | None = None,
     accum_steps: int = 1,
@@ -663,14 +654,13 @@ def init_sharded_state(
     """Initialize a TrainState directly into its sharded layout: the init
     is jitted with out_shardings so every shard is materialized on its
     owning device — no host-memory full copy (crucial for models larger
-    than one chip's HBM). ``zero_stage`` (or the legacy
-    ``shard_optimizer=True`` == stage 1) materializes each ladder
+    than one chip's HBM). ``zero_stage`` materializes each ladder
     component in its 1/N layout from the start — optimizer state
     (stage >= 1), the grad-accumulation buffer when ``accum_steps > 1``
     (stage >= 2), resident params (stage >= 3) — and reports the
     per-chip bytes on the `train_optimizer_state_bytes` /
     `train_grad_state_bytes` / `train_param_state_bytes` gauges."""
-    stage = _resolve_zero_stage(zero_stage, shard_optimizer)
+    stage = _resolve_zero_stage(zero_stage)
 
     def make():
         params = init_fn()

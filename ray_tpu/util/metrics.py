@@ -55,10 +55,15 @@ class Metric:
 
     def __init__(self, name: str, description: str = "",
                  tag_keys: Sequence[str] = (),
-                 registry: "Registry | None" = None):
+                 registry: "Registry | None" = None, collect=None):
+        """`collect`: a reader of numbers that are kept elsewhere, called
+        when the page is asked for: () -> {tag values, in `tag_keys`'
+        order: number}. They are shown added to what was `inc`'d or `set`
+        here, so nothing has to copy them in between two scrapes."""
         self.name = name
         self.description = description
         self.tag_keys = tuple(tag_keys)
+        self.collect = collect
         self._values: dict[tuple, float] = {}
         self._lock = threading.Lock()
         registered = (registry or _registry).register(self)
@@ -80,10 +85,13 @@ class Metric:
         lines = [f"# HELP {self.name} {self.description}",
                  f"# TYPE {self.name} {self.TYPE}"]
         with self._lock:
-            items = list(self._values.items())
+            items = dict(self._values)
+        if self.collect is not None:
+            for key, v in self.collect().items():
+                items[key] = items.get(key, 0.0) + v
         if not items:
             lines.append(f"{self.name} 0")
-        for key, v in items:
+        for key, v in items.items():
             lines.append(f"{self.name}{_fmt_tags(self._tags_of(key))} {v}")
         return lines
 

@@ -1,4 +1,4 @@
-"""ZeRO-1 sharded weight update (train/spmd.py shard_optimizer).
+"""ZeRO-1 sharded weight update (train/spmd.py zero_stage=1).
 
 Gates the three tentpole claims:
 - loss parity with the unsharded step (atol 1e-5, several steps) on
@@ -55,8 +55,8 @@ def _batch(mesh, vocab, B=8, T=64, seed=0):
 
 def _run(mesh, rules, init_fn, loss_fn, tx, batch, shard, steps):
     state = init_sharded_state(init_fn, tx, mesh, rules,
-                               shard_optimizer=shard)
-    step = make_train_step(loss_fn, tx, shard_optimizer=shard,
+                               zero_stage=1 if shard else 0)
+    step = make_train_step(loss_fn, tx, zero_stage=1 if shard else 0,
                            mesh=mesh if shard else None,
                            rules=rules if shard else None)
     losses = []
@@ -127,7 +127,7 @@ def test_optimizer_bytes_shrink_one_over_data_axis(mesh):
 
     s_r = init_sharded_state(init_fn, tx, mesh, rules)
     s_z = init_sharded_state(init_fn, tx, mesh, rules,
-                             shard_optimizer=True)
+                             zero_stage=1)
     b_r = optimizer_state_bytes(s_r.opt_state)
     b_z = optimizer_state_bytes(s_z.opt_state)
     assert b_r > 0
@@ -182,8 +182,8 @@ def test_zero1_program_restructures_collectives(mesh):
     def census(shard):
         state = init_sharded_state(
             lambda: init_gpt2(jax.random.PRNGKey(0), cfg), tx, mesh,
-            rules, shard_optimizer=shard)
-        step = make_train_step(loss_fn, tx, shard_optimizer=shard,
+            rules, zero_stage=1 if shard else 0)
+        step = make_train_step(loss_fn, tx, zero_stage=1 if shard else 0,
                                mesh=mesh if shard else None,
                                rules=rules if shard else None,
                                donate=False)
@@ -226,9 +226,9 @@ def test_waterfall_splits_collective_phase_and_censuses():
     batch = _batch(mesh, cfg.vocab_size)
     state = init_sharded_state(
         lambda: init_gpt2(jax.random.PRNGKey(0), cfg), tx, mesh, rules,
-        shard_optimizer=True)
+        zero_stage=1)
     step = make_train_step(lambda p, b: gpt2_loss(p, b, cfg), tx,
-                           shard_optimizer=True, mesh=mesh, rules=rules)
+                           zero_stage=1, mesh=mesh, rules=rules)
     spmd.waterfall.reset()
     spmd.enable_step_waterfall(True)
     try:

@@ -224,17 +224,14 @@ def test_zero3_program_carries_param_gathers(mesh):
 
 
 def test_resolve_zero_stage_back_compat():
-    """The shard_optimizer bool keeps meaning stage 1; explicit
-    zero_stage wins; out-of-range stages are rejected."""
+    """No stage named is stage 0; out-of-range stages are rejected."""
     from ray_tpu.train.spmd import _resolve_zero_stage
 
-    assert _resolve_zero_stage(None, False) == 0
-    assert _resolve_zero_stage(None, True) == 1
-    assert _resolve_zero_stage(2, False) == 2
-    assert _resolve_zero_stage(3, True) == 3
-    assert _resolve_zero_stage(0, True) == 0  # explicit wins
+    assert _resolve_zero_stage(None) == 0
+    assert _resolve_zero_stage(2) == 2
+    assert _resolve_zero_stage(3) == 3
     with pytest.raises(ValueError):
-        _resolve_zero_stage(4, False)
+        _resolve_zero_stage(4)
 
 
 def test_zero_shardings_component_rungs(mesh):
